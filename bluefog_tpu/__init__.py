@@ -12,7 +12,6 @@ Typical use::
     bf.init(topology_fn=lambda: bf.topology.ExponentialTwoGraph(8))
     x_avg = bf.neighbor_allreduce(x)          # x: [n_ranks, ...]
 """
-from . import compat                          # noqa: F401  (patches old jax)
 from . import topology
 from . import topology as topology_util       # reference-familiar alias
 from . import schedule

@@ -18,29 +18,48 @@ point has a pure-Python fallback, so the package works without a toolchain.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
 from typing import List, Optional, Sequence, Tuple
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_LIB_PATH = os.path.join(_HERE, "libbft_native.so")
 _SOURCES = ("timeline.cc", "schedule.cc", "loader.cc")
+
+
+def _lib_path() -> str:
+    """The library is named after a hash of its sources: a build is stale
+    exactly when the sources changed.  (Modification times do not survive a
+    copy of the tree, and a copied ``.so`` may predate the sources.)"""
+    h = hashlib.sha256()
+    for s in _SOURCES:
+        with open(os.path.join(_HERE, s), "rb") as f:
+            h.update(f.read())
+    return os.path.join(_HERE, f"libbft_native.{h.hexdigest()[:12]}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
-def _build() -> bool:
+def _build(lib_path: str) -> bool:
     srcs = [os.path.join(_HERE, s) for s in _SOURCES]
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",
-           "-o", _LIB_PATH] + srcs + ["-lpthread"]
+    tmp = f"{lib_path}.tmp{os.getpid()}"     # concurrent builders never
+    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC",    # share a file
+           "-o", tmp] + srcs + ["-lpthread"]
     try:
         r = subprocess.run(cmd, capture_output=True, timeout=120)
-        return r.returncode == 0
+        if r.returncode != 0:
+            return False
+        os.replace(tmp, lib_path)
+        return True
     except (OSError, subprocess.TimeoutExpired):
         return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -51,18 +70,12 @@ def load() -> Optional[ctypes.CDLL]:
             return _lib
         if _build_failed:
             return None
-        stale = (
-            not os.path.exists(_LIB_PATH)
-            or any(
-                os.path.getmtime(os.path.join(_HERE, s)) > os.path.getmtime(_LIB_PATH)
-                for s in _SOURCES
-            )
-        )
-        if stale and not _build():
+        lib_path = _lib_path()
+        if not os.path.exists(lib_path) and not _build(lib_path):
             _build_failed = True
             return None
         try:
-            lib = ctypes.CDLL(_LIB_PATH)
+            lib = ctypes.CDLL(lib_path)
         except OSError:
             _build_failed = True
             return None

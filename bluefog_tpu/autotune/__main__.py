@@ -1,8 +1,8 @@
 """CLI for the autotuner: tune, emit the plan, optionally apply + train.
 
 ``python -m bluefog_tpu.autotune --virtual-cpu --smoke --apply-steps 5``
-runs the end-to-end proof the smoke target and the hw_watch battery use:
-tune on a restricted space, print the plan as one JSON line, then apply
+runs the end-to-end proof the smoke target uses: tune on a restricted
+space, print the plan as one JSON line, then apply
 it, build the strategy + train step it prescribes, run N steps, and
 report donation/retrace health alongside the plan id.
 """

@@ -1,12 +1,12 @@
-"""Tier-2 evidence: banked on-hardware artifacts from ``docs/measured/``.
+"""Tier-2 evidence: banked on-hardware artifacts from ``docs/measured/``
+(created by the first banked trial; no on-chip row has been banked yet).
 
-Generalizes ``bench.py::_best_banked_config`` (which matches batch shape)
-to strategy-aware lookup: a banked bench artifact that recorded which
+Strategy-aware lookup: a banked bench artifact that recorded which
 algorithm it ran (schema ``bluefog-bench-2``) or a banked autotune trial
 can override the analytic pseudo-seconds for candidates on MATCHING
 hardware (device kind + chip count) — never steering a differently-sized
-mesh.  Only ``ok`` + ``on_accelerator`` artifacts count, so a CPU
-fallback or rescue line can never rank candidates.
+mesh.  Only ``ok`` + ``on_accelerator`` artifacts count, so a CPU run
+can never rank candidates.
 """
 from __future__ import annotations
 
@@ -81,8 +81,8 @@ def banked_step_time(algorithm: str, device_kind: Optional[str],
 
 def bank_trial(doc: dict, mdir: Optional[str] = None) -> Optional[str]:
     """Write one trial artifact immediately (incremental banking: a
-    mid-search death loses only the unfinished trial — the ``hw_watch``
-    discipline).  Returns the path, or None when the dir is unwritable
+    mid-search death loses only the unfinished trial).  Returns the path,
+    or None when the dir is unwritable
     (banking is best-effort; a read-only checkout must not kill a tune)."""
     mdir = mdir or measured_dir()
     name = "autotune_trial_{}.json".format(

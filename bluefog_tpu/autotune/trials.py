@@ -4,9 +4,9 @@ A trial dispatches the candidate's already-compiled probe program (the
 same executable tier-1 counted bytes from — the context program cache
 makes this free) a few times and takes the median wall-clock per step.
 Each trial's artifact is banked to ``docs/measured/`` the moment it
-finishes (incremental banking: a mid-search death loses nothing, the
-``tools/hw_watch.py`` discipline), marked ``on_accelerator`` only when it
-ran on real chips so a CPU trial can never steer a future hardware tune.
+finishes (incremental banking: a mid-search death loses nothing), marked
+``on_accelerator`` only when it ran on real chips so a CPU trial can
+never steer a future hardware tune.
 """
 from __future__ import annotations
 

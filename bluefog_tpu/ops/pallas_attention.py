@@ -46,13 +46,47 @@ def _merge_heads(x: jax.Array, B: int, H: int) -> jax.Array:
     return x.reshape(B, H, T, D).transpose(0, 2, 1, 3)
 
 
-def _q_blocking(Tq: int, block_q: int):
-    """q-blocking bounds VMEM: the score tile is [QB, Tk] instead of
+# Mosaic gives a v5e kernel 16 MiB of scoped VMEM.  These kernels block the
+# queries only: K and V (and dK, dV in the backward) stay whole, and the
+# body holds [block_q, Tk] f32 score tiles.  The two bounds below are
+# calibrated against Mosaic for v5e (tests/test_tpu_aot.py compiles every
+# row): the resident rows against the limit itself, the score tiles against
+# three quarters of it, which leaves room for the q/o/do tiles and Mosaic's
+# own temporaries.  Past them the kernel needs K/V blocking, which it does
+# not have yet.
+_VMEM_LIMIT = 16 * 1024 * 1024
+_SCORE_BUDGET = 12 * 1024 * 1024
+_LANE = 128
+_MIN_BLOCK_Q = 8
+
+
+def _q_blocking(Tq: int, Tk: int, D: int, block_q: int, backward: bool):
+    """Pick the q block: at most ``block_q`` rows, shrunk (to a power of
+    two) until the score tiles fit VMEM — the tile is [QB, Tk] instead of
     [Tq, Tk] (a 4k-token local block would otherwise need a 64 MB tile).
     Non-divisible Tq is padded up to a block multiple — never fall back to
     one full [Tq, Tk] tile, which is the exact blow-up blocking prevents.
-    Returns ``(qb, pad, Tp)`` with ``Tp = Tq + pad`` a multiple of ``qb``."""
+    Returns ``(qb, pad, Tp)`` with ``Tp = Tq + pad`` a multiple of ``qb``.
+
+    Raises ``ValueError`` at trace time when the shape cannot fit whatever
+    the block, so the caller never meets Mosaic's RESOURCE_EXHAUSTED."""
+    which = "backward" if backward else "forward"
+    lanes = -(-D // _LANE) * _LANE                    # minor dim pads to 128
+    rows = (4 if backward else 2) * Tk * lanes * 4    # K, V (, dK, dV) in f32
+    tiles = 3 if backward else 2                      # s, p (, dp) per q row
+    io = 8 if backward else 4          # q, o (, do, dq) tiles, double-buffered
+    fit = _SCORE_BUDGET // (tiles * Tk * 4 + io * lanes * 4)
+    if rows > _VMEM_LIMIT or fit < _MIN_BLOCK_Q:
+        raise ValueError(
+            f"flash attention {which}: Tk={Tk} keys at head_dim {D} do not "
+            f"fit the {_VMEM_LIMIT >> 20} MiB scoped VMEM limit (whole K/V "
+            f"rows need {rows} bytes; a {_MIN_BLOCK_Q}-row score block "
+            f"needs {tiles * _MIN_BLOCK_Q * Tk * 4} of "
+            f"{_SCORE_BUDGET}): the kernel blocks queries only — shard the "
+            "sequence further (ring attention) or use the XLA path")
     qb = min(block_q, Tq)
+    if qb > fit:
+        qb = 1 << (fit.bit_length() - 1)
     pad = (-Tq) % qb
     return qb, pad, Tq + pad
 
@@ -147,6 +181,8 @@ def attention_block_partial(
     """One K/V block's flash-attention partial, fully in VMEM.
     ``window > 0`` (needs ``causal``): sliding-window masking — keys more
     than ``window - 1`` tokens behind the query are masked.
+    ``block_q`` is an upper bound: the q block shrinks to what VMEM allows
+    at this ``Tk`` (see :func:`_q_blocking`).
 
     Returns ``(o_blk [B,Tq,H,D] f32, l_blk [B,Tq,H] f32, m_blk [B,Tq,H] f32)``
     relative to the block max ``m_blk`` (rows with no valid key get
@@ -159,7 +195,7 @@ def attention_block_partial(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    qb, pad, Tp = _q_blocking(Tq, block_q)
+    qb, pad, Tp = _q_blocking(Tq, Tk, D, block_q, backward=False)
     qr = _pad_rows(_split_heads(q), pad)
     kr, vr = _split_heads(k), _split_heads(v)
 
@@ -302,7 +338,7 @@ def attention_block_backward(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
 
-    qb, pad, Tp = _q_blocking(Tq, block_q)
+    qb, pad, Tp = _q_blocking(Tq, Tk, D, block_q, backward=True)
     qr = _pad_rows(_split_heads(q), pad)
     kr, vr = _split_heads(k), _split_heads(v)
     dor = _pad_rows(_split_heads(do), pad)

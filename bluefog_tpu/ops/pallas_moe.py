@@ -42,6 +42,40 @@ __all__ = ["grouped_ffn_pallas"]
 # bump and no correctness.
 _MIN_SUBLANE = 8
 
+# What a kernel may allocate on a v5e core (Mosaic's default scoped limit is
+# 16 MiB); the block of F is sized to stay under it with room for Mosaic's
+# own temporaries.
+_VMEM_BUDGET = 14 * 1024 * 1024
+_LANE = 128
+
+
+def _block_f(tile: int, D: int, F: int, x_bytes: int, w_bytes: int) -> int:
+    """Largest lane-aligned divisor of ``F`` whose working set fits
+    :data:`_VMEM_BUDGET`.  Per grid step the pipeline double-buffers the x
+    tile, one ``[D, bf]`` and one ``[bf, D]`` weight slice and the f32
+    output tile; the body adds the f32 casts of both slices (when they
+    are not f32 already) and the ``[tile, bf]`` activation.  An ``F`` that
+    is not a multiple of 128 can only be taken whole (a block's minor dim
+    is a lane multiple or the full dim)."""
+    def need(bf):
+        pipelined = 2 * (tile * D * x_bytes + 2 * D * bf * w_bytes
+                         + tile * D * 4)
+        casts = 2 * D * bf * 4 if w_bytes != 4 else 0
+        body = casts + 2 * tile * bf * 4 + tile * D * 4
+        return pipelined + body
+
+    if F % _LANE:
+        candidates = [F]
+    else:
+        candidates = [bf for bf in range(F, 0, -_LANE) if F % bf == 0]
+    for bf in candidates:
+        if need(bf) <= _VMEM_BUDGET:
+            return bf
+    raise ValueError(
+        f"grouped_ffn_pallas: tile {tile} x D {D} with the smallest F block "
+        f"({candidates[-1]} of {F}) needs {need(candidates[-1])} bytes of "
+        f"VMEM, over the {_VMEM_BUDGET}-byte budget (16 MiB scoped limit)")
+
 
 def _vma_of(x: jax.Array):
     # under shard_map the output varies over the same mesh axes as the input
@@ -52,10 +86,21 @@ def _grouped_kernel(eids_ref, x_ref, w1_ref, w2_ref, o_ref):
     x = x_ref[0].astype(jnp.float32)                   # [tile, D]
     u = jax.nn.gelu(jax.lax.dot_general(
         x, w1_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32))           # [tile, F]
-    o_ref[0] = jax.lax.dot_general(
+        preferred_element_type=jnp.float32))           # [tile, block_f]
+    part = jax.lax.dot_general(
         u, w2_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)            # [tile, D]
+    # gelu is elementwise in F, so the F blocks sum: the output block index
+    # ignores the F grid axis and stays resident as the accumulator
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        o_ref[0] = part
+
+    @pl.when(j > 0)
+    def _():
+        o_ref[0] += part
 
 
 def _forward(xt: jax.Array, tile_eid: jax.Array, w1: jax.Array,
@@ -65,21 +110,24 @@ def _forward(xt: jax.Array, tile_eid: jax.Array, w1: jax.Array,
         xt = jnp.pad(xt, ((0, 0), (0, _MIN_SUBLANE - real_tile), (0, 0)))
     G, tile, D = xt.shape
     _, _, F = w1.shape
+    bf = _block_f(tile, D, F, xt.dtype.itemsize, w1.dtype.itemsize)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                         # tile_eid
-        grid=(G,),
+        grid=(G, F // bf),
         in_specs=[
-            pl.BlockSpec((1, tile, D), lambda i, eids: (i, 0, 0)),
-            pl.BlockSpec((1, D, F), lambda i, eids: (eids[i], 0, 0)),
-            pl.BlockSpec((1, F, D), lambda i, eids: (eids[i], 0, 0)),
+            pl.BlockSpec((1, tile, D), lambda i, j, eids: (i, 0, 0)),
+            pl.BlockSpec((1, D, bf), lambda i, j, eids: (eids[i], 0, j)),
+            pl.BlockSpec((1, bf, D), lambda i, j, eids: (eids[i], j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, tile, D), lambda i, eids: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, tile, D), lambda i, j, eids: (i, 0, 0)),
     )
     out = pl.pallas_call(
         _grouped_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((G, tile, D), jnp.float32,
                                        vma=_vma_of(xt)),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(tile_eid.astype(jnp.int32), xt, w1, w2)
     if real_tile < tile:

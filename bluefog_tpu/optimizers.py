@@ -42,7 +42,7 @@ import numpy as np
 
 import optax
 from jax import lax
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from . import fusion, ops
@@ -1747,19 +1747,49 @@ def _comm_from_type(communication_type: str, kw):
 # Train-step builder
 # ---------------------------------------------------------------------------
 
+def _row_sharding(n: int, like=None):
+    """Where an ``[n, ...]`` stack belongs: one row per device.  ``like`` (a
+    placed stack) wins — a composed carving shards over its own mesh;
+    otherwise the context's rank axis when it has ``n`` devices.  ``None``
+    (leave it to the first step call) across processes and for widths
+    that are not the mesh's."""
+    if jax.process_count() > 1:
+        return None
+    sharding = getattr(like, "sharding", None)
+    if isinstance(sharding, NamedSharding) and sharding.spec \
+            and sharding.spec[0] is not None:
+        return NamedSharding(sharding.mesh, P(sharding.spec[0]))
+    if _mesh.is_initialized() and _mesh.size() == n:
+        return NamedSharding(_mesh.mesh(), P("rank"))
+    return None
+
+
+def _stack_rows(tree, n: int, sharding):
+    """``n`` copies of every leaf along a new leading axis, each device
+    materializing only its own row: broadcasting on the default device
+    first would put all ``n`` copies on device 0."""
+    def stack(t):
+        return jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), t)
+    if sharding is None:
+        return stack(tree)
+    return jax.jit(stack, out_shardings=sharding)(tree)
+
+
 def replicate(tree, n: Optional[int] = None):
-    """Stack n copies along a new leading rank axis (distributed tensor)."""
+    """Stack n copies along a new leading rank axis (distributed tensor),
+    sharded one row per device over the context mesh."""
     n = _mesh.size() if n is None else n
-    return jax.tree.map(lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), tree)
+    return _stack_rows(tree, n, _row_sharding(n))
 
 
 def init_distributed(strategy: DecentralizedOptimizer, dist_params):
-    """Initialize strategy state for distributed (rank-stacked) params."""
+    """Initialize strategy state for distributed (rank-stacked) params,
+    placed like them."""
+    first = jax.tree.leaves(dist_params)[0]
+    n = first.shape[0]
     template = jax.tree.map(lambda x: x[0], dist_params)
-    state = strategy.init(template)
-    n = jax.tree.leaves(dist_params)[0].shape[0]
-    state = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n,) + x.shape), state)
+    state = _stack_rows(strategy.init(template), n, _row_sharding(n, first))
     if strategy.pipelined:
         # the delayed-mixing carry must start from each rank's OWN params
         # (broadcasting the rank-0 template would silently teleport rank 0's

@@ -17,9 +17,9 @@ import sys
 
 
 def main() -> int:
-    import jax
     import numpy as np
 
+    from ..parallel import context as _ctx
     from ..parallel.compose import LMConfig, compose_parallelism, \
         init_lm_params
     from ..utils import metrics as _metrics
@@ -30,7 +30,9 @@ def main() -> int:
     tp = int(os.environ.get("BLUEFOG_SERVE_TP", "1"))
     scfg = ServeConfig.from_env()
     ep = scfg.moe_ep if scfg.moe_experts else 1
-    devices = jax.devices()
+    # every entry point starts here: logs the platform, and on a TPU places
+    # the libtpu flags and the persistent compile cache
+    devices = list(_ctx.init().devices)
     slice_sz = pp * tp * ep
     if len(devices) % slice_sz:
         print(f"bluefog-serve: {len(devices)} devices do not carve into "

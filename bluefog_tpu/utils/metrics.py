@@ -573,7 +573,7 @@ def maybe_start_from_env() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Artifact summary (bench.py / hw_watch embed this)
+# Artifact summary (bench.py embeds this)
 # ---------------------------------------------------------------------------
 
 def metrics_summary() -> dict:

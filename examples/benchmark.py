@@ -70,6 +70,12 @@ def main():
     bf.init(platform="cpu" if args.virtual_cpu else None,
             nodes_per_machine=4 if hier else None)
     n = bf.size()
+    dev = bf.devices()[0]
+    if dev.platform == "cpu" and not args.virtual_cpu:
+        sys.exit("benchmark: no accelerator — JAX picked the CPU. A rate "
+                 "from here is not a device number; pass --virtual-cpu for "
+                 "the 8-device CPU emulation.")
+    print(f"devices: {n} x {dev.device_kind} (platform {dev.platform})")
     topo = topology_util.ExponentialTwoGraph(n)
     bf.set_topology(topo, is_weighted=True)
     if hier:
@@ -187,7 +193,9 @@ def main():
         # steps axis after the rank axis (make_train_step's scan contract)
         xb = jnp.broadcast_to(xb[:, None], (xb.shape[0], spc) + xb.shape[1:])
         yb = jnp.broadcast_to(yb[:, None], (yb.shape[0], spc) + yb.shape[1:])
-    batch = (xb, yb)
+    # one row per device from the start, like replicate(): the first step
+    # call should not have to move the stacks off device 0
+    batch = (bf.shard_distributed(xb), bf.shard_distributed(yb))
     for _ in range(args.num_warmup):
         dist_params, dist_state, loss = step(dist_params, dist_state, batch)
     bf.hard_sync(loss)      # host-transfer barrier: see bf.hard_sync

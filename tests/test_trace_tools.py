@@ -1,8 +1,7 @@
-"""Tests for tools/trace_analyze.py (compute/comm/exposed-comm split) and
-tools/perf_fill.py (PERFORMANCE.md auto-fill) — the post-processing stages
-of the hw-watch battery.  The trace fixture is hand-written Chrome-trace
-JSON: deterministic intervals whose overlap arithmetic is checkable by
-hand, no profiler dependency."""
+"""Tests for tools/trace_analyze.py (compute/comm/exposed-comm split).
+The trace fixture is hand-written Chrome-trace JSON: deterministic
+intervals whose overlap arithmetic is checkable by hand, no profiler
+dependency."""
 import gzip
 import importlib.util
 import json
@@ -183,79 +182,3 @@ def test_top_exposed_comm_ops_on_obs_fixture():
         "all-reduce-done", "all-reduce-start"]
     assert [r["exposed_ms"] for r in rows] == [30.0, 20.0, 0.0, 0.0]
     assert sum(r["exposed_ms"] for r in rows) >= out["comm_exposed_ms"]
-
-
-def test_perf_fill_renders_and_is_idempotent(tmp_path, monkeypatch):
-    measured = tmp_path / "measured"
-    measured.mkdir()
-    (measured / "bench_rX.json").write_text(json.dumps({
-        "ok": True, "value": 321.5, "unit": "img/s/chip", "mfu": 0.41,
-        "vs_baseline": 1.19, "on_accelerator": True, "device": "TPU v5e"}))
-    (measured / "trace_split_rX.json").write_text(json.dumps({
-        "ok": True, "busy_ms": 1, "wall_ms": 2, "idle_ms": 1,
-        "compute_ms": 0.8, "comm_ms": 0.4, "comm_exposed_ms": 0.1,
-        "overlap_fraction": 0.75}))
-    monkeypatch.setenv("BLUEFOG_MEASURED_DIR", str(measured))
-    pf = _load("perf_fill")
-
-    filled = pf.fill("rX", dry_run=True)
-    assert "321.5 img/s/chip" in filled
-    assert "41.0%" in filled                      # MFU formatted
-    assert "overlap fraction 0.75" in filled
-    assert filled.count(pf.BEGIN) == 1
-    # the artifact above predates batch/steps_per_call: the config suffix
-    # must be omitted entirely, not rendered as a literal "bNone·kNone"
-    assert "bNone" not in filled and "kNone" not in filled
-    # idempotent: writing again replaces the marked block, not appends
-    open_orig = pf.PERF
-    try:
-        perf_copy = tmp_path / "PERFORMANCE.md"
-        perf_copy.write_text(open(open_orig).read())
-        pf.PERF = str(perf_copy)
-        pf.fill("rX")
-        once = perf_copy.read_text()
-        pf.fill("rX")
-        twice = perf_copy.read_text()
-        assert once.count(pf.BEGIN) == 1
-        assert twice.count(pf.BEGIN) == 1
-        assert "321.5" in once
-        # truncated-block recovery: BEGIN without END (kill mid-write)
-        # must not duplicate the block on the next fill
-        perf_copy.write_text(once[:once.index(pf.END)])
-        pf.fill("rX")
-        healed = perf_copy.read_text()
-        assert healed.count(pf.BEGIN) == 1
-        assert healed.count(pf.END) == 1
-    finally:
-        pf.PERF = open_orig
-
-
-def test_perf_fill_renders_config_suffix_and_roofline(tmp_path, monkeypatch):
-    """Artifacts WITH the r06 fields: the headline row carries the
-    b<batch>·k<steps> config, and a banked roofline renders with its
-    trusted/suspect verdicts."""
-    measured = tmp_path / "measured"
-    measured.mkdir()
-    (measured / "bench_rY.json").write_text(json.dumps({
-        "ok": True, "value": 1961.25, "unit": "img/s/chip", "mfu": 0.12,
-        "vs_baseline": 7.28, "on_accelerator": True, "device": "TPU v5e",
-        "batch_per_chip": 64, "steps_per_call": 5}))
-    (measured / "roofline_rY.json").write_text(json.dumps({
-        "ok": True, "device": "TPU v5 lite",
-        "mxu": [
-            {"probe": "mxu_bf16_8192", "tflops": 150.2,
-             "flops_per_sec": 150.2e12, "trusted": True, "suspect": False},
-            {"probe": "mxu_bf16_4096", "tflops": 641.0,
-             "flops_per_sec": 641e12, "trusted": False, "suspect": True,
-             "note": "rate tripwire"},
-        ],
-        "hbm": [{"probe": "hbm_rw_1024MiB", "gbps": 780.0,
-                 "dispatch_corrected_gbps": 800.0,
-                 "trusted": True, "suspect": False}]}))
-    monkeypatch.setenv("BLUEFOG_MEASURED_DIR", str(measured))
-    pf = _load("perf_fill")
-    filled = pf.fill("rY", dry_run=True)
-    assert "b64·k5" in filled
-    assert "150.2 TFLOP/s — trusted" in filled
-    assert "**SUSPECT, rejected**" in filled
-    assert "780.0 GB/s (dispatch-corrected 800.0)" in filled

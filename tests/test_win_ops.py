@@ -282,7 +282,7 @@ def test_win_put_wire_codecs(cpu_devices):
 
 def _zero_fills(closed_jaxpr, shape):
     """Eqns (recursively) that broadcast a literal 0 into ``shape``."""
-    import jax.core as jcore
+    import jax.extend.core as jcore      # jax 0.9 home of Jaxpr/Literal
     hits = []
 
     def walk(j):

@@ -132,7 +132,7 @@ def fetch(url, timeout=10.0):
 
 
 # ---------------------------------------------------------------------------
-# --virtual-cpu: the self-contained estate (CI smoke / hw_watch battery)
+# --virtual-cpu: the self-contained estate (CI smoke)
 # ---------------------------------------------------------------------------
 
 def _self_estate(n=8, steps=6, every=1):

@@ -164,9 +164,6 @@ def main():
     if args.virtual_cpu:
         jax.config.update("jax_platforms", "cpu")
 
-    from bluefog_tpu.utils.config import enable_compilation_cache
-    enable_compilation_cache()
-
     dev = jax.devices()[0]
     on_tpu = jax.default_backend() == "tpu"
     if dev.platform == "cpu" and not (args.virtual_cpu or args.allow_cpu):

@@ -401,8 +401,10 @@ def main():
     if args.virtual_cpu:
         jax.config.update("jax_platforms", "cpu")
 
-    from bluefog_tpu.utils.config import enable_compilation_cache
-    enable_compilation_cache()
+    # bf.init places the libtpu flags and the compile cache when the
+    # devices are TPUs; the carving below takes its own device slices
+    import bluefog_tpu as bf
+    bf.init(platform="cpu" if args.virtual_cpu else None)
 
     dev = jax.devices()[0]
     on_tpu = jax.default_backend() == "tpu"

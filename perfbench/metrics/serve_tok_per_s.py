@@ -1,0 +1,9 @@
+"""Completed output tokens per second: the MEDIAN over the window's slices
+(``slice_seconds`` of the traffic file; deliveries interpolated between
+scheduler steps, see estimators.slice_rates)."""
+from perfbench.harness import estimators
+
+
+def read(run):
+    readings = run["readings"].get("serve_tok_per_s")
+    return estimators.median(readings) if readings else None

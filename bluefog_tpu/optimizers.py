@@ -1832,7 +1832,7 @@ class _InstrumentedStep:
         self._warmup = max(int(warmup), 1)
         self._calls = 0
         self._jit_cache_baseline: Optional[int] = None
-        self._trace = ""                 # minted lazily when tracing is armed
+        self._trace = _tracing.new_trace("train")
 
     def __getattr__(self, name):
         fn = self.__dict__.get("_fn")
@@ -1847,13 +1847,19 @@ class _InstrumentedStep:
             return None
 
     def __call__(self, *args, **kwargs):
+        # the gossip round rides inside the fused step program, so the
+        # span covers compute + communication of this call and, around
+        # its `dispatch`, everything this wrapper does on the host
+        with _tracing.stage(self._trace, "train_step", cat="train",
+                            step=self._calls + 1,
+                            fused_k=self._steps_per_call) as st:
+            st.attrs["overlap"] = self._overlap        # ring only
+            return self._call(*args, **kwargs)
+
+    def _call(self, *args, **kwargs):
         import time as _time
         call = self._calls + 1
         _flight.record("step_begin", name="train_step", step=call)
-        traced = _tracing.enabled()
-        if traced and not self._trace:
-            self._trace = _tracing.new_trace("train")
-        tm0 = _time.monotonic() if traced else 0.0
         t0 = _time.perf_counter()
         try:
             # fault injection (zero-cost gate when no plan is installed): a
@@ -1862,7 +1868,8 @@ class _InstrumentedStep:
             # looks for real
             if _chaos._plan is not None:
                 _chaos.on_train_step(call)
-            out = self._fn(*args, **kwargs)
+            with _tracing.stage(self._trace, "dispatch", cat="train"):
+                out = self._fn(*args, **kwargs)
         except BaseException as e:
             # flush the black box before the exception unwinds the train
             # loop (the launcher/supervisor may take the process down next)
@@ -1885,13 +1892,6 @@ class _InstrumentedStep:
         _flight.record("step_end", name="train_step", step=self._calls,
                        dur_s=round(dt, 6), fused_k=self._steps_per_call,
                        overlap=self._overlap, donated=self._donated)
-        if traced:
-            # the gossip round rides inside the fused step program, so the
-            # span covers compute + communication of this call
-            _tracing.add_span(self._trace, "train_step", tm0,
-                              _time.monotonic(), cat="train",
-                              step=self._calls, fused_k=self._steps_per_call,
-                              overlap=self._overlap)
         from . import diagnostics as _diag
         # per-rank step-time table every call (a host-side numpy fill):
         # chaos-injected sleeps are attributed per step, not lumped into
@@ -1899,16 +1899,14 @@ class _InstrumentedStep:
         step_times = _diag.observe_step_time(dt)
         k = self._metrics_every_k
         if k and (self._calls == 1 or self._calls % k == 0):
-            tp0 = _time.monotonic() if traced else 0.0
-            _diag.diagnose_consensus(out[0], step_times=step_times)
-            # async-gossip states carry their staleness depth in the step
-            # output — a pure host read, no extra collective or compile
-            if len(out) > 1:
-                _diag.observe_async_staleness(out[1])
-            if traced:
-                _tracing.add_span(self._trace, "consensus_probe", tp0,
-                                  _time.monotonic(), cat="train",
-                                  step=self._calls)
+            with _tracing.stage(self._trace, "consensus_probe",
+                                cat="train", step=self._calls):
+                _diag.diagnose_consensus(out[0], step_times=step_times)
+                # async-gossip states carry their staleness depth in the
+                # step output — a pure host read, no extra collective or
+                # compile
+                if len(out) > 1:
+                    _diag.observe_async_staleness(out[1])
         if self._calls >= self._warmup:
             size = self._jit_cache_len()
             if (_metrics.in_steady_state() and size is not None

@@ -49,7 +49,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
@@ -490,12 +489,16 @@ class ServeEngine:
         self._slot_keys = np.zeros((m.dp, cc.rows, 2), np.uint32)
         self._seed_count = 0
         self._warm_sizes: Optional[Tuple[int, ...]] = None
-        self._engine_trace = ""          # minted lazily when tracing is armed
+        self._engine_trace = _tracing.new_trace("engine")
 
-    def _trace_id(self) -> str:
-        if not self._engine_trace:
-            self._engine_trace = _tracing.new_trace("engine")
-        return self._engine_trace
+    def _stage(self, name: str, **attrs) -> _tracing.stage:
+        """``bf:engine.<name>`` in the profiler's trace (and the ring when
+        armed).  Every device call is staged the same way: ``stage_in``
+        (host arrays onto the mesh), ``dispatch`` (the jitted call, until
+        it returns to Python), ``collect`` (the wait for the device, the
+        transfer back and the host bookkeeping after it)."""
+        return _tracing.stage(self._engine_trace, name, cat="engine",
+                              **attrs)
 
     # ------------------------------------------------------------------
     # device-side bodies (per-device shapes, leading [1, ...] sliced off)
@@ -872,12 +875,14 @@ class ServeEngine:
     def _seed_slot(self, replica: int, slot: int) -> None:
         """Deterministic per-admission PRNG key for the fused sampler."""
         self._seed_count += 1
-        key = jax.random.fold_in(
-            jax.random.fold_in(jax.random.PRNGKey(self.scfg.seed),
-                               replica * self.cache_cfg.rows + slot),
-            self._seed_count)
-        self._slot_keys[replica, slot] = np.asarray(
-            jax.random.key_data(key), np.uint32)
+        # four eager device calls and a read back, once per admission
+        with self._stage("seed_slot"):
+            key = jax.random.fold_in(
+                jax.random.fold_in(jax.random.PRNGKey(self.scfg.seed),
+                                   replica * self.cache_cfg.rows + slot),
+                self._seed_count)
+            self._slot_keys[replica, slot] = np.asarray(
+                jax.random.key_data(key), np.uint32)
 
     def _trash_vec(self, S: int) -> np.ndarray:
         return np.full((self.m.dp, S), self.cache_cfg.trash_slot, np.int32)
@@ -942,19 +947,17 @@ class ServeEngine:
         slot_id[replica] = row
         true_len = np.ones((R,), np.int32)
         true_len[replica] = len(tokens)
-        traced = _tracing.enabled()
-        t0 = time.monotonic() if traced else 0.0
-        nxt, logits, self.cache = self._prefill_jit(
-            self.params, self.cache, self._expand(toks),
-            self._expand(slot_id), self._expand(true_len))
-        self._check_retrace(f"prefill Tpad={Tpad}")
-        out = (int(self._collect(nxt)[replica]),
-               self._collect(logits)[replica])
-        if traced:
-            _tracing.add_span(self._trace_id(), "prefill_call", t0,
-                              time.monotonic(), cat="engine", Tpad=Tpad,
-                              replica=replica)
-        return out
+        with self._stage("prefill_call", Tpad=Tpad, tokens=len(tokens),
+                         replica=replica):
+            with self._stage("stage_in"):
+                args = (self.params, self.cache, self._expand(toks),
+                        self._expand(slot_id), self._expand(true_len))
+            with self._stage("dispatch"):
+                nxt, logits, self.cache = self._prefill_jit(*args)
+            with self._stage("collect"):
+                self._check_retrace(f"prefill Tpad={Tpad}")
+                return (int(self._collect(nxt)[replica]),
+                        self._collect(logits)[replica])
 
     def chunk_prefill(self, replica: int, slot: int, tokens: Sequence[int],
                       start: int, prefix_row: int) -> int:
@@ -992,27 +995,26 @@ class ServeEngine:
         return int(gen[replica, 0, len(tokens) - 1])
 
     def _chunk_call(self, toks, slots, lens, prows, plens) -> np.ndarray:
-        prows, plens = self._prefix_args(prows, plens, toks.shape[1])
-        traced = _tracing.enabled()
-        t0 = time.monotonic() if traced else 0.0
-        args = (self.params, self.cache,
-                self._expand(np.asarray(toks, np.int32)),
-                self._expand(np.asarray(slots, np.int32)),
-                self._expand(np.asarray(lens, np.int32)),
-                self._expand(prows) if prows is not None else None,
-                self._expand(plens) if plens is not None else None)
-        if self._moe:
-            gen, st, self.cache = self._chunk_jit(*args)
-            self._note_route_stats(st)
-        else:
-            gen, self.cache = self._chunk_jit(*args)
-        self._check_retrace(f"chunk S={toks.shape[1]} T={toks.shape[2]}")
-        out = self._collect(gen)
-        if traced:
-            _tracing.add_span(self._trace_id(), "chunk_call", t0,
-                              time.monotonic(), cat="engine",
-                              S=int(toks.shape[1]), T=int(toks.shape[2]))
-        return out
+        S, T = int(toks.shape[1]), int(toks.shape[2])
+        with self._stage("chunk_call", S=S, T=T):
+            with self._stage("stage_in"):
+                prows, plens = self._prefix_args(prows, plens, S)
+                args = (self.params, self.cache,
+                        self._expand(np.asarray(toks, np.int32)),
+                        self._expand(np.asarray(slots, np.int32)),
+                        self._expand(np.asarray(lens, np.int32)),
+                        self._expand(prows) if prows is not None else None,
+                        self._expand(plens) if plens is not None else None)
+            with self._stage("dispatch"):
+                if self._moe:
+                    gen, st, self.cache = self._chunk_jit(*args)
+                else:
+                    gen, self.cache = self._chunk_jit(*args)
+            with self._stage("collect"):
+                if self._moe:
+                    self._note_route_stats(st)
+                self._check_retrace(f"chunk S={S} T={T}")
+                return self._collect(gen)
 
     def decode(self, tokens: np.ndarray, slots: np.ndarray,
                lens: np.ndarray, prefix_rows: Optional[np.ndarray] = None,
@@ -1033,30 +1035,29 @@ class ServeEngine:
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
-        slots = np.asarray(slots, np.int32)
-        prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
-        keys = self._gather_keys(slots)
-        traced = _tracing.enabled()
-        t0 = time.monotonic() if traced else 0.0
-        args = (self.params, self.cache,
-                self._expand(np.asarray(tokens, np.int32)),
-                self._expand(slots),
-                self._expand(np.asarray(lens, np.int32)),
-                self._expand(prows) if prows is not None else None,
-                self._expand(plens) if plens is not None else None,
-                self._expand(keys))
-        if self._moe:
-            gen, keys, st, self.cache = self._decode_jit(*args)
-            self._note_route_stats(st)
-        else:
-            gen, keys, self.cache = self._decode_jit(*args)
-        self._scatter_keys(slots, self._collect(keys))
-        self._check_retrace(f"decode S={S}")
-        out = self._collect(gen)
-        if traced:
-            _tracing.add_span(self._trace_id(), "decode_call", t0,
-                              time.monotonic(), cat="engine", S=int(S))
-        return out
+        with self._stage("decode_call", S=int(S)):
+            with self._stage("stage_in"):
+                slots = np.asarray(slots, np.int32)
+                prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
+                keys = self._gather_keys(slots)
+                args = (self.params, self.cache,
+                        self._expand(np.asarray(tokens, np.int32)),
+                        self._expand(slots),
+                        self._expand(np.asarray(lens, np.int32)),
+                        self._expand(prows) if prows is not None else None,
+                        self._expand(plens) if plens is not None else None,
+                        self._expand(keys))
+            with self._stage("dispatch"):
+                if self._moe:
+                    gen, keys, st, self.cache = self._decode_jit(*args)
+                else:
+                    gen, keys, self.cache = self._decode_jit(*args)
+            with self._stage("collect"):
+                if self._moe:
+                    self._note_route_stats(st)
+                self._scatter_keys(slots, self._collect(keys))
+                self._check_retrace(f"decode S={S}")
+                return self._collect(gen)
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
                     lens: np.ndarray,
@@ -1085,16 +1086,25 @@ class ServeEngine:
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
-        prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
-        traced = _tracing.enabled()
-        t0 = time.monotonic() if traced else 0.0
-        drafts, self.cache = self._draft_jit(
-            self.params, self.cache, self._expand(tokens),
-            self._expand(slots), self._expand(lens),
-            self._expand(prows) if prows is not None else None,
-            self._expand(plens) if plens is not None else None)
-        self._check_retrace(f"draft S={S}")
-        drafts = self._collect(drafts)                  # [R, k, S]
+        with self._stage("spec_round", S=int(S), k=k) as round_:
+            emitted, counts, drafted, accepted = self._spec_round(
+                tokens, slots, lens, prefix_rows, prefix_lens)
+            round_.attrs.update(drafted=drafted, accepted=accepted)
+        return emitted, counts
+
+    def _spec_round(self, tokens, slots, lens, prefix_rows, prefix_lens):
+        k, S = self.scfg.spec_decode, tokens.shape[1]
+        with self._stage("stage_in"):
+            prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
+            args = (self.params, self.cache, self._expand(tokens),
+                    self._expand(slots), self._expand(lens),
+                    self._expand(prows) if prows is not None else None,
+                    self._expand(plens) if plens is not None else None)
+        with self._stage("dispatch"):
+            drafts, self.cache = self._draft_jit(*args)
+        with self._stage("collect"):
+            self._check_retrace(f"draft S={S}")
+            drafts = self._collect(drafts)              # [R, k, S]
         d = np.transpose(drafts, (0, 2, 1))             # [R, S, k]
         # verify chunk: [t0, d_1 .. d_k] per lane — the draft rows it
         # appended are overwritten with the (identical) target values and
@@ -1123,11 +1133,7 @@ class ServeEngine:
             _metrics.counter(
                 "bluefog_serve_spec_accepted_total",
                 "draft tokens accepted by the verify pass").inc(accepted)
-        if traced:
-            _tracing.add_span(self._trace_id(), "spec_round", t0,
-                              time.monotonic(), cat="engine", S=int(S), k=k,
-                              drafted=drafted, accepted=accepted)
-        return emitted, counts
+        return emitted, counts, drafted, accepted
 
     def idle_lane(self) -> Tuple[int, int, int]:
         """(token, slot, len) triple a padding lane should carry."""

@@ -117,6 +117,7 @@ class Scheduler:
         self._decode_calls = 0
         self._moe_load = None            # last ServeEngine.moe_load() snapshot
         self._slo = None                 # diagnostics.SLOEngine, if attached
+        self._sched_trace = _tracing.new_trace("sched")
         _flight.register_block("serve", self._flight_block)
 
     # ------------------------------------------------------------------
@@ -259,16 +260,23 @@ class Scheduler:
 
     # ------------------------------------------------------------------
 
+    def _stage(self, name: str, **attrs) -> _tracing.stage:
+        """``bf:serve.<name>`` in the profiler's trace (and the ring when
+        armed): the host stages of one step, between the engine's calls."""
+        return _tracing.stage(self._sched_trace, name, cat="serve", **attrs)
+
     def step(self) -> List[Request]:
         """One admit → decode → retire cycle; returns requests retired
         this cycle."""
-        self._admit()
-        retired = self._decode_once()
-        _metrics.gauge("bluefog_serve_queue_depth",
-                       "admission-queue depth after each scheduler step"
-                       ).set(self.pending)
-        if self._slo is not None:
-            self._slo.observe(self)
+        with self._stage("step"):
+            with self._stage("admit"):
+                self._admit()
+            retired = self._decode_once()
+            _metrics.gauge("bluefog_serve_queue_depth",
+                           "admission-queue depth after each scheduler step"
+                           ).set(self.pending)
+            if self._slo is not None:
+                self._slo.observe(self)
         return retired
 
     def attach_slo(self, engine) -> None:
@@ -286,11 +294,19 @@ class Scheduler:
 
     # ------------------------------------------------------------------
 
-    def _prefill_request(self, req: Request) -> int:
+    def _prefill_request(self, req: Request, waited: float) -> int:
         """Prefill one admitted request — through a shared prefix page when
         one matches — and return its first token.  Observes the TTFT
         histogram with the hit/cold split."""
-        t0 = time.monotonic()
+        with _tracing.stage(req.trace_id, "prefill", cat="serve",
+                            prompt_len=len(req.prompt),
+                            waited_us=int(waited * 1e6)) as st:
+            first, hit = self._prefill_tokens(req)
+            st.attrs.update(hit=hit, replica=req.replica,
+                            prefix_len=req.prefix_len)
+        return first
+
+    def _prefill_tokens(self, req: Request):
         r, pc = req.replica, self._prefix[req.replica]
         hit = False
         if pc is not None:
@@ -322,11 +338,7 @@ class Scheduler:
             "time to first token, by prefix-cache outcome",
             buckets=LATENCY_BUCKETS).observe(
                 req.first_token_at - req.submitted_at)
-        _tracing.add_span(req.trace_id, "prefill", t0, req.first_token_at,
-                          cat="serve", hit=hit, replica=r,
-                          prompt_len=len(req.prompt),
-                          prefix_len=req.prefix_len)
-        return first
+        return first, hit
 
     def _admit(self) -> None:
         # a lane needs a free KV slot AND a decode lane: never admit past
@@ -371,7 +383,7 @@ class Scheduler:
             _tracing.add_span(req.trace_id, "queue", q0, t0,
                               cat="serve", replica=target,
                               requeued=req.requeued)
-            first = self._prefill_request(req)
+            first = self._prefill_request(req, t0 - q0)
             req.generated.append(first)
             _metrics.counter(
                 "bluefog_tokens_generated_total",
@@ -391,24 +403,25 @@ class Scheduler:
             return []
         scfg = self.engine.scfg
         S = scfg.batch_bucket_for(busiest)
-        idle_tok, idle_slot, idle_len = self.engine.idle_lane()
         R = self.replicas
-        toks = np.full((R, S), idle_tok, np.int32)
-        slots = np.full((R, S), idle_slot, np.int32)
-        lens = np.full((R, S), idle_len, np.int32)
-        prows = np.full((R, S), idle_slot, np.int32)
-        plens = np.zeros((R, S), np.int32)
-        for r in range(R):
-            for i, slot in enumerate(lanes[r]):
-                req = self._active[r][slot]
-                toks[r, i] = req.generated[-1]
-                slots[r, i] = slot
-                lens[r, i] = req.next_pos
-                if req.prefix_row >= 0:
-                    prows[r, i] = req.prefix_row
-                    plens[r, i] = req.prefix_len
-        pargs = (prows, plens) if self._prefix[0] is not None else (None,
-                                                                    None)
+        with self._stage("pack", lanes=busiest, S=S):
+            idle_tok, idle_slot, idle_len = self.engine.idle_lane()
+            toks = np.full((R, S), idle_tok, np.int32)
+            slots = np.full((R, S), idle_slot, np.int32)
+            lens = np.full((R, S), idle_len, np.int32)
+            prows = np.full((R, S), idle_slot, np.int32)
+            plens = np.zeros((R, S), np.int32)
+            for r in range(R):
+                for i, slot in enumerate(lanes[r]):
+                    req = self._active[r][slot]
+                    toks[r, i] = req.generated[-1]
+                    slots[r, i] = slot
+                    lens[r, i] = req.next_pos
+                    if req.prefix_row >= 0:
+                        prows[r, i] = req.prefix_row
+                        plens[r, i] = req.prefix_len
+            pargs = (prows, plens) if self._prefix[0] is not None else (
+                None, None)
         t0 = time.monotonic()
         if scfg.spec_decode:
             emitted, counts = self.engine.spec_decode(toks, slots, lens,
@@ -420,13 +433,22 @@ class Scheduler:
             gen = self.engine.decode(toks, slots, lens, *pargs)
             steps = gen.shape[1]                          # [R, steps, S]
             gen_tokens = lambda r, i: [int(t) for t in gen[r, :, i]]
+            counts = None
         dt = time.monotonic() - t0
+        with self._stage("deliver"):
+            return self._deliver(lanes, gen_tokens, counts, steps, t0, dt)
+
+    def _deliver(self, lanes, gen_tokens, counts, steps, t0, dt
+                 ) -> List[Request]:
+        """Hand one fused call's tokens to their requests; retire the
+        finished ones."""
+        scfg = self.engine.scfg
         self._decode_calls += 1
         self._note_moe_load()
         traced = _tracing.enabled()
         n_tokens = 0
         retired: List[Request] = []
-        for r in range(R):
+        for r in range(self.replicas):
             for i, slot in enumerate(lanes[r]):
                 req = self._active[r][slot]
                 room = req.max_new_tokens - len(req.generated)

@@ -23,16 +23,33 @@ comparable.  Each rank's bundle carries one ``(monotonic, wall)`` anchor
 pair so ``tools/trace_report.py`` can place every rank's spans on a
 shared wall-clock axis when merging into Chrome-trace format.
 
+Stage spans: :class:`stage` is the one way the program times a stage of
+its own work as it happens.  It always opens a
+``jax.profiler.TraceAnnotation("bf:<cat>.<name>", **attrs)`` — free
+when no profiler session runs, and in any ``jax.profiler`` trace it puts
+the stage on the host plane, on the device planes' clock, with its
+entry-time attributes as the event's stats; nothing has to be armed for
+that.  When the ring is armed it also records the same interval here,
+under the plain ``name``.  The vocabulary (``bf:serve.step`` ⊃
+``admit``/``prefill``/``pack``/``deliver``; ``bf:engine.<call>`` ⊃
+``stage_in``/``dispatch``/``collect``, and ``seed_slot``;
+``bf:train.train_step`` ⊃ ``dispatch``) is tabled in
+``docs/OBSERVABILITY.md``.  Spans whose
+endpoints lie in the past (``queue``, ``request``, the per-rider
+``decode``) cannot be annotations and stay :func:`add_span`, ring only.
+
 Arming: ``BLUEFOG_TRACE=<dir>`` (or :func:`configure`) arms recording
+(the per-request ring; stage spans reach a profiler's trace without it)
 and directs :func:`flush` to ``<dir>/trace_rank<r>.trace.jsonl`` — one
 self-describing JSONL bundle per rank (a ``meta`` line, then one line
 per span), written atomically and flushed again at exit.  Producers:
 
 * the serve scheduler threads request spans (``cat="serve"``) and tags
   each :class:`~bluefog_tpu.serve.scheduler.Request` with its trace id;
-* the serve engine wraps its device calls (``cat="engine"``);
-* ``_InstrumentedStep`` emits per-call train-step and consensus-probe
-  spans (``cat="train"``).
+* the serve scheduler stages each step (``cat="serve"``, its own trace);
+* the serve engine stages its device calls (``cat="engine"``);
+* ``_InstrumentedStep`` stages the train step, its dispatch and the
+  consensus probe (``cat="train"``).
 """
 from __future__ import annotations
 
@@ -48,7 +65,7 @@ from .config import logger
 
 __all__ = [
     "SCHEMA", "ENV_TRACE", "enabled", "configure", "maybe_enable_from_env",
-    "new_trace", "add_span", "mark", "span", "spans", "dropped",
+    "new_trace", "add_span", "mark", "span", "stage", "spans", "dropped",
     "flush", "bundle_path", "capacity", "reset",
 ]
 
@@ -63,6 +80,7 @@ _seq = itertools.count(1)
 _last_seq = 0
 _trace_seq = itertools.count(1)
 _atexit_registered = False
+_annotation = None               # jax.profiler.TraceAnnotation, once jax is in
 
 
 def enabled() -> bool:
@@ -172,6 +190,43 @@ class span:
             self.id = add_span(self.trace, self.name, self._t0,
                                time.monotonic(), cat=self.cat,
                                parent=self.parent, **self.attrs)
+
+
+class stage:
+    """``with tracing.stage(trace, "decode_call", cat="engine", S=32):`` —
+    a stage of the program's own work, timed as it happens.
+
+    Always a ``bf:<cat>.<name>`` annotation in the profiler's trace (the
+    keyword attrs, known on entry, become the event's stats); a no-op
+    shell in a process that never imported jax.  Armed, the same interval
+    also lands in the ring as ``name`` with those attrs plus whatever the
+    block added to ``.attrs``."""
+
+    __slots__ = ("trace", "name", "cat", "parent", "attrs", "_ann", "_t0")
+
+    def __init__(self, trace: str, name: str, *, cat: str,
+                 parent: Optional[int] = None, **attrs: Any):
+        self.trace, self.name, self.cat = trace, name, cat
+        self.parent, self.attrs = parent, attrs
+
+    def __enter__(self) -> "stage":
+        global _annotation
+        if _annotation is None:
+            jax = sys.modules.get("jax")
+            _annotation = jax.profiler.TraceAnnotation if jax else False
+        self._ann = _annotation and _annotation(
+            f"bf:{self.cat}.{self.name}", **self.attrs)
+        if self._ann:
+            self._ann.__enter__()
+        self._t0 = time.monotonic() if _armed else None
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._t0 is not None and _armed:
+            add_span(self.trace, self.name, self._t0, time.monotonic(),
+                     cat=self.cat, parent=self.parent, **self.attrs)
+        if self._ann:
+            self._ann.__exit__(*exc)
 
 
 # ---------------------------------------------------------------------------

@@ -49,6 +49,7 @@ from bluefog_tpu.serve.kv_cache import KVCacheConfig, attend_rows, init_cache
 from bluefog_tpu.utils import chaos as bfchaos
 from bluefog_tpu.utils import flight as bfflight
 from bluefog_tpu.utils import metrics as bfm
+from test_tracing_stage import bf_events, inside
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
@@ -421,11 +422,13 @@ def _estate(cpu_devices, seed=99):
     return cfg, train_m, (step, state, train_params, toks), eng
 
 
-def test_e2e_serving_while_training_advances(cpu_devices):
+def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     """16 concurrent requests drain while the training fleet advances and
     the refresher pulls mid-traffic: staleness rises with train steps and
     drops to 0 on pull, pulled weights equal the training average, the KV
-    donation stays intact, and nothing retraces."""
+    donation stays intact, and nothing retraces — all under a profiler
+    session, whose trace holds the program's stage spans, nested, with
+    their entry attributes."""
     cfg, train_m, (step, state, train_params, toks), eng = \
         _estate(cpu_devices)
     refresher = WeightRefresher(eng, train_m, every=2)
@@ -441,18 +444,22 @@ def test_e2e_serving_while_training_advances(cpu_devices):
 
     train_done, stal_seen, pulls = 0, [], 0
     guard = 0
-    while not sched.done:
-        guard += 1
-        assert guard < 500, "scheduler failed to drain"
-        sched.step()
-        if train_done < 4:
-            train_params, state, _ = step(train_params, state, toks)
-            train_done += 1
-            refresher.note_train_step(train_done)
-            stal_seen.append(refresher.staleness())
-            if refresher.maybe_refresh(train_params, train_done):
-                pulls += 1
-                assert refresher.staleness() == 0.0   # gauge drops on pull
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while not sched.done:
+            guard += 1
+            assert guard < 500, "scheduler failed to drain"
+            sched.step()
+            if train_done < 4:
+                train_params, state, _ = step(train_params, state, toks)
+                train_done += 1
+                refresher.note_train_step(train_done)
+                stal_seen.append(refresher.staleness())
+                if refresher.maybe_refresh(train_params, train_done):
+                    pulls += 1
+                    assert refresher.staleness() == 0.0   # drops on pull
+    finally:
+        jax.profiler.stop_trace()
 
     assert len(sched.completed) == 16
     assert all(len(r.generated) == r.max_new_tokens for r in reqs)
@@ -472,6 +479,32 @@ def test_e2e_serving_while_training_advances(cpu_devices):
     assert cache_probe.is_deleted()               # donated into decode
     assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
     sched.close()
+
+    # the stage spans, unarmed, in the profiler's trace: step > decode_call
+    # > stage_in, dispatch, collect; step > admit > prefill > prefill_call
+    ev = bf_events(tmp_path)
+    steps = [e for e in ev if e[0] == "bf:serve.step"]
+    assert len(steps) == guard
+    assert steps[0][3] == {}
+    calls = inside(ev, "bf:engine.decode_call", "bf:serve.step")
+    assert len(calls) == guard and set(calls[0][3]) == {"S"}
+    for name in ("stage_in", "dispatch", "collect"):
+        assert len(inside(ev, "bf:engine." + name,
+                          "bf:engine.decode_call")) == guard, name
+    prefills = inside(ev, "bf:serve.prefill", "bf:serve.admit")
+    assert sorted(e[3]["prompt_len"] for e in prefills) == sorted(
+        len(r.prompt) for r in reqs)
+    assert all(set(e[3]) == {"prompt_len", "waited_us"}
+               and e[3]["waited_us"] >= 0 for e in prefills)
+    inner = inside(ev, "bf:engine.prefill_call", "bf:serve.prefill")
+    assert len(inner) == 16
+    assert all(e[3]["tokens"] <= e[3]["Tpad"] for e in inner)
+    assert len(inside(ev, "bf:engine.seed_slot", "bf:serve.prefill")) == 16
+    packs = inside(ev, "bf:serve.pack", "bf:serve.step")
+    assert len(packs) == len(inside(ev, "bf:serve.deliver",
+                                    "bf:serve.step")) == guard
+    assert all(1 <= e[3]["lanes"] <= e[3]["S"] for e in packs)
+    assert len(inside(ev, "bf:train.dispatch", "bf:train.train_step")) == 4
 
 
 def test_chaos_drill_kill_serving_replica(cpu_devices, tmp_path):

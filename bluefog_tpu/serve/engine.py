@@ -17,14 +17,20 @@ bucket up front; afterwards the engine snapshots all jit caches and any
 growth fires :func:`bluefog_tpu.utils.metrics.note_retrace` — the same
 sentinel a training step uses, so one gauge covers the whole fleet.
 
-The KV cache is a donated argument threaded through a ``lax.scan`` decode
-carry (:mod:`.kv_cache` owns the layout, including int8/fp8 page storage
-and shared prefix pages); steady-state decode is a single cached program
-per (bucket, steps_per_call): embed → pp-cycle of stage-local layer
-scans (``ppermute`` moves the activation, a stage-id ``where`` keeps
-exactly one stage's work) → stage-0 logits ``psum`` → greedy argmax or
-the fused temperature/top-p sampler, fused over ``decode_steps_per_call``
-tokens.
+The KV cache is a donated argument that every engine program updates **in
+place**: it rides the carry of the layer loop (and of the fused
+``decode_steps_per_call`` loop around it) and is written and read at
+``[layer, row, ...]`` — never sliced out per layer, never restacked, so a
+program's output cache is its input buffer (:mod:`.kv_cache` owns the
+layout, the in-place writes and reads, int8/fp8 page storage and shared
+prefix pages; :meth:`ServeEngine.program_memory` and the
+``bluefog_serve_cache_copy_bytes`` gauge say what the compiler built).
+Steady-state decode is a single cached program per (bucket,
+steps_per_call): embed → pp-cycle of stage-local layer loops
+(``ppermute`` moves the activation, a stage-id ``where`` keeps exactly
+one stage's work where there is more than one stage) → stage-0 logits
+``psum`` → greedy argmax or the fused temperature/top-p sampler, fused
+over ``decode_steps_per_call`` tokens.
 
 Fast paths on top of the correct-first PR 10 engine:
 
@@ -489,6 +495,7 @@ class ServeEngine:
         self._slot_keys = np.zeros((m.dp, cc.rows, 2), np.uint32)
         self._seed_count = 0
         self._warm_sizes: Optional[Tuple[int, ...]] = None
+        self._program_bytes: dict = {}
         self._engine_trace = _tracing.new_trace("engine")
 
     def _stage(self, name: str, **attrs) -> _tracing.stage:
@@ -590,9 +597,34 @@ class ServeEngine:
         ent = jnp.sum(-jnp.sum(probs * jnp.log(probs + 1e-20), axis=-1) * w)
         return jnp.concatenate([cnt, ent[None], jnp.sum(w)[None]])
 
-    def _layer_step(self, lp, x, cl, slot_ids, lens, prows, plens,
+    def _layer_scan(self, one, blocks, x, cache):
+        """Run a stage's layers with the cache as a **loop-carried**
+        buffer: ``one(lp, x, cache, layer) -> (x, cache)`` gets the whole
+        stacked cache dict plus its layer index and writes/reads it at
+        ``[layer, ...]`` (:mod:`.kv_cache`); only the block weights are
+        scanned over.  A scan that takes the cache as
+        ``xs`` and returns it as ``ys`` slices every layer out and stacks
+        the updated layers into a freshly allocated buffer — the carried
+        form is what XLA updates in place, so with the donated argument
+        the program's output cache IS its input cache."""
+        def body(carry, xs):
+            lp, layer = xs
+            return one(lp, *carry, layer), None
+        layers = jax.tree.leaves(blocks)[0].shape[0]
+        (x, cache), _ = lax.scan(body, (x, cache),
+                                 (blocks, jnp.arange(layers)))
+        return x, cache
+
+    def _layer_view(self, cache, layer):
+        """One layer's pages as a tensor of their own — what the Pallas
+        kernels take (their index maps address ``[row, head, block]``)."""
+        return {name: t[layer] for name, t in cache.items()}
+
+    def _layer_step(self, lp, x, cache, layer, slot_ids, lens, prows, plens,
                     draft=False):
-        """One decoder block on one new token per lane: ``x`` is ``[S, D]``."""
+        """One decoder block on one new token per lane: ``x`` is ``[S, D]``,
+        ``cache`` the stage's stacked cache dict, appended to and read at
+        ``layer``."""
         cfg, m = self.cfg, self.m
         Hl = cfg.heads // m.tp
         hsz = cfg.d_model // cfg.heads
@@ -602,36 +634,44 @@ class ServeEngine:
         q = apply_rope_rows(q.reshape(S, Hl, hsz), lens)
         k = apply_rope_rows(k.reshape(S, Hl, hsz), lens)
         v = v.reshape(S, Hl, hsz)
-        cl = _kv.layer_append(cl, slot_ids, lens, k, v,
-                              store=self.scfg.kv_dtype)
+        cache = _kv.layer_append(cache, layer, slot_ids, lens, k, v,
+                                 store=self.scfg.kv_dtype)
         if self.scfg.decode_kernel == "pallas":
+            cl = self._layer_view(cache, layer)
             att = _pd.flash_attend_rows(
                 q, cl["k"], cl["v"], slot_ids, lens,
                 k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
                 prefix_slots=prows, prefix_lens=plens,
                 block_k=self.scfg.decode_block_k)
         else:
-            att = _kv.attend_rows(q, cl["k"], cl["v"], slot_ids, lens,
-                                  k_scale=cl.get("k_scale"),
-                                  v_scale=cl.get("v_scale"),
-                                  prefix_slots=prows, prefix_lens=plens)
+            att = _kv.attend_rows(q, cache["k"], cache["v"], slot_ids, lens,
+                                  k_scale=cache.get("k_scale"),
+                                  v_scale=cache.get("v_scale"),
+                                  prefix_slots=prows, prefix_lens=plens,
+                                  layer=layer)
         x = x + lax.psum(att.reshape(S, Hl * hsz) @ lp["wo"], "tp")
         x, routing = self._ffn(lp, x, draft=draft)
-        return x, cl, routing
+        return x, cache, routing
 
-    def _pp_cycle(self, blocks, x, cache, stage_apply, n_stages=None):
+    def _pp_cycle(self, blocks, x, cache, one, n_stages=None):
         """Cycle ``x`` through ``n_stages`` pipeline stages (all of them by
-        default; the draft truncates); each stage's layer scan runs
-        everywhere but only the owning stage keeps its activation and
-        cache writes, so the program is identical on every device.  After
-        n hops the valid activation sits at stage ``n % pp`` (0 for the
-        full cycle) — the caller reads logits there and ``psum``
-        broadcasts them."""
+        default; the draft truncates); each stage's layer loop
+        (:meth:`_layer_scan` of ``one``, the cache carried through it and
+        updated in place) runs everywhere but only the
+        owning stage keeps its activation and cache writes, so the program
+        is identical on every device.  The keep-select over the cache is
+        what makes only the owning stage's writes stick at ``pp > 1``
+        (there it costs a pass over the cache per hop); on a ``stage`` axis
+        of one chip ``sid == s`` is the constant true and the compiler
+        folds the select away, so the carried buffer goes straight
+        through.  After n hops the valid activation sits at stage
+        ``n % pp`` (0 for the full cycle) — the caller reads logits there
+        and ``psum`` broadcasts them."""
         n = self.m.pp if n_stages is None else n_stages
         sid = lax.axis_index("stage")
         perm = [(i, (i + 1) % self.m.pp) for i in range(self.m.pp)]
         for s in range(n):
-            y, nc = stage_apply(blocks, x, cache)
+            y, nc = self._layer_scan(one, blocks, x, cache)
             keep = sid == s
             # x may be a pytree carrier (activation + stats accumulator on
             # the MoE decode path) — keep/permute leafwise
@@ -645,7 +685,8 @@ class ServeEngine:
     def _blocks_tree(self, params):
         """Per-layer scanned leaves: the dense block weights, plus the
         router/expert tables merged in on the MoE path (all leading-[Lps],
-        so one ``lax.scan`` pairs every layer's leaves)."""
+        so one ``lax.scan`` pairs every layer's leaves — the weights are
+        the scan's only ``xs``; the cache rides its carry)."""
         if not self._moe:
             return params["blocks"]
         bp = dict(params["blocks"])
@@ -674,27 +715,21 @@ class ServeEngine:
             toks, lens, cache, keys, st = carry
 
             if track:
-                def stage_apply(blocks, xc, c):
-                    def one(xc, xs):
-                        x, acc = xc
-                        lp, cl = xs
-                        x, cl, routing = self._layer_step(
-                            lp, x, cl, slot_ids, lens, prows, plens)
-                        return (x, acc + self._route_vec(routing, live)), cl
-                    return lax.scan(one, xc, (blocks, c))
+                def one(lp, xc, c, layer):
+                    x, acc = xc
+                    x, c, routing = self._layer_step(
+                        lp, x, c, layer, slot_ids, lens, prows, plens)
+                    return (x, acc + self._route_vec(routing, live)), c
                 x0 = (embed[toks], st)                        # [S, D] + [E+2]
             else:
-                def stage_apply(blocks, x, c):
-                    def one(x, xs):
-                        lp, cl = xs
-                        x, cl, _ = self._layer_step(lp, x, cl, slot_ids,
-                                                    lens, prows, plens,
-                                                    draft=draft)
-                        return x, cl
-                    return lax.scan(one, x, (blocks, c))
+                def one(lp, x, c, layer):
+                    x, c, _ = self._layer_step(lp, x, c, layer, slot_ids,
+                                               lens, prows, plens,
+                                               draft=draft)
+                    return x, c
                 x0 = embed[toks]                              # [S, D]
 
-            x, cache, sid = self._pp_cycle(bp, x0, cache, stage_apply,
+            x, cache, sid = self._pp_cycle(bp, x0, cache, one,
                                            n_stages=n_stages)
             if track:
                 x, acc = x
@@ -767,41 +802,37 @@ class ServeEngine:
         live = jnp.broadcast_to((slot_ids < self.scfg.slots)[:, None],
                                 (S, T)).reshape(S * T)
 
-        def one(xc, xs):
+        def one(lp, xc, c, layer):
             x, acc = xc
-            lp, cl = xs
             h = _ln(x)
             q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
             q = apply_rope_grid(q.reshape(S, T, Hl, hsz), pos)
             k = apply_rope_grid(k.reshape(S, T, Hl, hsz), pos)
             v = v.reshape(S, T, Hl, hsz)
-            cl = _kv.layer_append_chunk(cl, slot_ids, lens, k, v,
-                                        store=self.scfg.kv_dtype)
+            c = _kv.layer_append_chunk(c, layer, slot_ids, lens, k, v,
+                                       store=self.scfg.kv_dtype)
             if self.scfg.decode_kernel == "pallas":
                 att = _pd.flash_attend_chunk(
-                    q, cl, slot_ids, lens,
+                    q, self._layer_view(c, layer), slot_ids, lens,
                     prefix_slots=prows, prefix_lens=plens,
                     block_k=self.scfg.decode_block_k)
             else:
-                att = _kv.attend_chunk(q, cl, slot_ids, lens,
+                att = _kv.attend_chunk(q, c, slot_ids, lens,
                                        prefix_slots=prows,
-                                       prefix_lens=plens)
+                                       prefix_lens=plens, layer=layer)
             x = x + lax.psum(
                 att.reshape(S, T, Hl * hsz) @ lp["wo"], "tp")
             x, routing = self._ffn(lp, x, tile=self._moe_chunk_tile
                                    if self._moe else None)
             if self._moe:
                 acc = acc + self._route_vec(routing, live)
-            return (x, acc), cl
-
-        def stage_apply(blocks, xc, c):
-            return lax.scan(one, xc, (blocks, c))
+            return (x, acc), c
 
         st0 = jnp.zeros((cfg.num_experts + 2,) if self._moe else (),
                         jnp.float32)
         x = params["shared"]["embed"][toks]                   # [S, T, D]
         (x, st), cache, sid = self._pp_cycle(
-            self._blocks_tree(params), (x, st0), cache, stage_apply)
+            self._blocks_tree(params), (x, st0), cache, one)
         logits = lax.psum(
             jnp.where(sid == 0, _ln(x) @ params["shared"]["head"], 0.0),
             "stage")                                          # [S, T, V]
@@ -821,31 +852,28 @@ class ServeEngine:
         positions = jnp.arange(Tpad)
         x = params["shared"]["embed"][toks][None]             # [1, Tpad, D]
 
-        def stage_apply(blocks, x, c):
-            def one(x, xs):
-                lp, cl = xs
-                h = _ln(x)
-                q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
-                q = apply_rope(q.reshape(1, Tpad, Hl, hsz), positions)
-                k = apply_rope(k.reshape(1, Tpad, Hl, hsz), positions)
-                v = v.reshape(1, Tpad, Hl, hsz)
-                # the whole padded prompt lands in the slot; positions past
-                # true_len hold garbage that decode's length mask never
-                # reads before the append overwrites it.  Attention over
-                # the prompt itself is dense full-precision — quantization
-                # drift only enters where a STORED page is read back
-                cl = _kv.layer_prefill(cl, slot_id, k[0], v[0],
-                                       store=self.scfg.kv_dtype)
-                att = dense_attention(q, k, v, causal=True)
-                x = x + lax.psum(
-                    att.reshape(1, Tpad, Hl * hsz) @ lp["wo"], "tp")
-                x, _ = self._ffn(lp, x, tile=self._moe_chunk_tile
-                                 if self._moe else None)
-                return x, cl
-            return lax.scan(one, x, (blocks, c))
+        def one(lp, x, c, layer):
+            h = _ln(x)
+            q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
+            q = apply_rope(q.reshape(1, Tpad, Hl, hsz), positions)
+            k = apply_rope(k.reshape(1, Tpad, Hl, hsz), positions)
+            v = v.reshape(1, Tpad, Hl, hsz)
+            # the whole padded prompt lands in the slot; positions past
+            # true_len hold garbage that decode's length mask never
+            # reads before the append overwrites it.  Attention over
+            # the prompt itself is dense full-precision — quantization
+            # drift only enters where a STORED page is read back
+            c = _kv.layer_prefill(c, layer, slot_id, k[0], v[0],
+                                  store=self.scfg.kv_dtype)
+            att = dense_attention(q, k, v, causal=True)
+            x = x + lax.psum(
+                att.reshape(1, Tpad, Hl * hsz) @ lp["wo"], "tp")
+            x, _ = self._ffn(lp, x, tile=self._moe_chunk_tile
+                             if self._moe else None)
+            return x, c
 
         x, cache, sid = self._pp_cycle(self._blocks_tree(params), x, cache,
-                                       stage_apply)
+                                       one)
         logits = jnp.where(sid == 0, _ln(x[0]) @ params["shared"]["head"],
                            0.0)                               # [Tpad, V]
         logits = lax.psum(logits, "stage")
@@ -955,7 +983,8 @@ class ServeEngine:
             with self._stage("dispatch"):
                 nxt, logits, self.cache = self._prefill_jit(*args)
             with self._stage("collect"):
-                self._check_retrace(f"prefill Tpad={Tpad}")
+                self._check_program(f"prefill Tpad={Tpad}",
+                                    self._prefill_jit, args)
                 return (int(self._collect(nxt)[replica]),
                         self._collect(logits)[replica])
 
@@ -1013,7 +1042,8 @@ class ServeEngine:
             with self._stage("collect"):
                 if self._moe:
                     self._note_route_stats(st)
-                self._check_retrace(f"chunk S={S} T={T}")
+                self._check_program(f"chunk S={S} T={T}", self._chunk_jit,
+                                    args)
                 return self._collect(gen)
 
     def decode(self, tokens: np.ndarray, slots: np.ndarray,
@@ -1056,7 +1086,7 @@ class ServeEngine:
                 if self._moe:
                     self._note_route_stats(st)
                 self._scatter_keys(slots, self._collect(keys))
-                self._check_retrace(f"decode S={S}")
+                self._check_program(f"decode S={S}", self._decode_jit, args)
                 return self._collect(gen)
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
@@ -1103,7 +1133,7 @@ class ServeEngine:
         with self._stage("dispatch"):
             drafts, self.cache = self._draft_jit(*args)
         with self._stage("collect"):
-            self._check_retrace(f"draft S={S}")
+            self._check_program(f"draft S={S}", self._draft_jit, args)
             drafts = self._collect(drafts)              # [R, k, S]
         d = np.transpose(drafts, (0, 2, 1))             # [R, S, k]
         # verify chunk: [t0, d_1 .. d_k] per lane — the draft rows it
@@ -1236,10 +1266,44 @@ class ServeEngine:
                      for j in (self._decode_jit, self._prefill_jit,
                                self._chunk_jit, self._draft_jit))
 
-    def _check_retrace(self, detail: str) -> None:
+    def _check_program(self, program: str, fn, args) -> None:
+        """After every device call: the first time a program (``"decode
+        S=32"``, ``"prefill Tpad=64"``, ...) is seen, record what the
+        compiler built for it; once warm, any growth of the jit caches is
+        a retrace."""
+        if program not in self._program_bytes:
+            # the executable the call above compiled, found again through
+            # jit's own caches: nothing is traced or compiled a second
+            # time, and lowering reads only the arguments' shapes (the
+            # donated cache among them is already consumed)
+            ma = fn.lower(*args).compile().memory_analysis()
+            self._program_bytes[program] = {
+                "temp_bytes": int(ma.temp_size_in_bytes),
+                "alias_bytes": int(ma.alias_size_in_bytes)}
+            _metrics.gauge(
+                "bluefog_serve_cache_copy_bytes",
+                "temporaries the compiler allocated for one engine program "
+                "(per device): activations only while the KV cache is "
+                "updated in place, cache-sized once a program copies it"
+            ).set(float(ma.temp_size_in_bytes), program=program)
+            _metrics.gauge(
+                "bluefog_serve_cache_alias_bytes",
+                "output bytes of one engine program that reuse a donated "
+                "argument (per device): the whole KV cache while it is "
+                "updated in place").set(float(ma.alias_size_in_bytes),
+                                        program=program)
         if self._warm_sizes is None:
             return
         sizes = self._jit_sizes()
         if sizes > self._warm_sizes:
-            _metrics.note_retrace(detail=f"serve engine {detail}")
+            _metrics.note_retrace(detail=f"serve engine {program}")
             self._warm_sizes = sizes
+
+    def program_memory(self) -> dict:
+        """``{program: {"temp_bytes", "alias_bytes"}}`` per device, for
+        every engine program compiled so far
+        (``compiled.memory_analysis()``): whether the cache is updated in
+        place is a property of the compiled program, so this is its
+        counter — ``alias_bytes`` is the cache's size and ``temp_bytes``
+        stays under one layer's pages when it is."""
+        return {k: dict(v) for k, v in self._program_bytes.items()}

@@ -57,11 +57,13 @@ from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..ops.collectives import _amax_scale
 from ..utils import metrics as _metrics
 
-__all__ = ["KVCacheConfig", "init_cache", "append_rows", "attend_rows",
+__all__ = ["KVCacheConfig", "init_cache", "attend_rows",
            "attend_chunk", "layer_append", "layer_append_chunk",
            "layer_prefill", "quantize_rows", "dequantize_rows",
            "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -192,109 +194,179 @@ def init_cache(cfg: KVCacheConfig) -> dict:
 
 
 # ---------------------------------------------------------------------------
-# Device-side page math (one layer's slice of the cache dict)
+# Device-side page math: on one layer's slice of the cache dict, or (given
+# ``layer``) on the stacked cache dict at that layer
 # ---------------------------------------------------------------------------
 
-def append_rows(kl: jax.Array, vl: jax.Array, slots: jax.Array,
-                lengths: jax.Array, k_new: jax.Array, v_new: jax.Array):
-    """Scatter one new token's raw kv into per-request slots.
+def _pin_window(upd: jax.Array, max_len: int) -> jax.Array:
+    """Give an update window ``[1, 1, kv_heads, T(, head_dim)]`` the axis
+    order its cache tensor has in a TPU's memory, when compiling for one.
 
-    ``kl/vl``: one layer's pages ``[rows, kv_heads, max_len, head_dim]``;
-    ``slots``/``lengths``: ``[S]`` int32 (the new token lands at position
-    ``lengths[i]`` of ``slots[i]``); ``k_new/v_new``: ``[S, kv_heads,
-    head_dim]``.  Duplicate (trash-slot) indices are allowed — last write
-    wins, and nothing ever reads the trash row.
+    A TPU stores ``[..., max_len, head_dim]`` with whichever of the two
+    minor axes wastes less of the 128 lanes in the lanes: positions, for a
+    ``head_dim`` of 64 under a ``max_len`` of 1024.  A
+    ``dynamic_update_slice`` wants buffer and update in one order, and the
+    compiler, left alone, has moved the 1.7 GB buffer to the order of the
+    2 KB update (``head_dim`` minor, as the projection leaves it): a copy
+    of the whole cache at the head of the call and one back at its end,
+    in some programs and not in others.  Pinned, it transposes the
+    update.  Other backends keep everything row-major and need no pin."""
+    order = tuple(range(upd.ndim))
+    if upd.ndim == 5:
+        waste = lambda n: -(-n // 128) * 128 / n
+        if waste(upd.shape[4]) > waste(max_len):
+            order = (0, 1, 2, 4, 3)
+    lay = Layout(major_to_minor=order)
+    return lax.platform_dependent(
+        upd, tpu=lambda w: with_layout_constraint(w, lay),
+        default=lambda w: w)
+
+
+def _write_lanes(t: jax.Array, layer: jax.Array, slots: jax.Array,
+                 pos: jax.Array, upd: jax.Array) -> jax.Array:
+    """``t[layer, slots[i], :, pos[i] + j] = upd[i, :, j]`` for every lane
+    ``i`` in order (last write wins on the shared trash row) and every
+    ``j < T``: ``t`` is a stacked cache tensor ``[layers, rows, kv_heads,
+    max_len(, head_dim)]``, ``upd`` is ``[S, kv_heads, T(, head_dim)]``.
+
+    One ``dynamic_update_slice`` per lane, unrolled, NOT one scatter: the
+    TPU's scatter wants its indexed axes (layer, row, position) major and
+    its window (head, head_dim) minor, and the compiler then keeps the
+    whole loop-carried cache in that order, which also pads ``head_dim``
+    up to the 128 lanes.  A ``dynamic_update_slice`` takes the buffer in
+    the order it has (:func:`_pin_window`), so the cache stays as it is
+    from call to call.  Positions at or past ``max_len`` are dropped as
+    the scatter dropped them: a token past the end lands in the trash row
+    (the last one, which nothing reads), and a chunk that straddles the
+    end is clamped into range and keeps what its window held before it.
     """
-    kl = kl.at[slots, :, lengths].set(k_new.astype(kl.dtype))
-    vl = vl.at[slots, :, lengths].set(v_new.astype(vl.dtype))
-    return kl, vl
+    S, T, max_len = upd.shape[0], upd.shape[2], t.shape[3]
+    tail = (0,) * (t.ndim - 4)                          # head_dim, if any
+    start = jnp.minimum(pos, max_len - T)
+    over = pos - start               # > 0: the window runs past max_len
+    rows = jnp.where(over < T, slots, t.shape[1] - 1)
+    ats = [(layer, rows[i], 0, start[i]) + tail for i in range(S)]
+    new = upd.astype(t.dtype)
+    if T > 1:
+        # every window is read before any is written: live lanes hold
+        # rows of their own, and what the shared trash row held matters
+        # to no one
+        j = jnp.arange(T).reshape((1, 1, T) + (1,) * len(tail))
+        over = over.reshape((S, 1, 1) + (1,) * len(tail))
+        old = jnp.concatenate(
+            [lax.dynamic_slice(t, at, (1, 1) + new.shape[1:])[0]
+             for at in ats])
+        new = jnp.where(j >= over,
+                        jnp.take_along_axis(new, (j - over) % T, axis=2),
+                        old)
+    for i, at in enumerate(ats):
+        t = lax.dynamic_update_slice(
+            t, _pin_window(new[i][None, None], max_len), at)
+    return t
 
 
-def layer_append(cl: Dict[str, jax.Array], slots: jax.Array,
-                 lengths: jax.Array, k_new: jax.Array, v_new: jax.Array,
-                 store: str = "raw") -> Dict[str, jax.Array]:
-    """One decode token per lane into one layer's cache dict, quantizing
-    on the way in when the store calls for it."""
-    qk, sk = quantize_rows(k_new, store)
-    qv, sv = quantize_rows(v_new, store)
-    out = dict(cl)
-    out["k"], out["v"] = append_rows(cl["k"], cl["v"], slots, lengths,
-                                     qk, qv)
-    if sk is not None:
-        out["k_scale"] = cl["k_scale"].at[slots, :, lengths].set(sk)
-        out["v_scale"] = cl["v_scale"].at[slots, :, lengths].set(sv)
-    return out
+def _read_lanes(t: jax.Array, layer: jax.Array,
+                rows: jax.Array) -> jax.Array:
+    """``t[layer, rows[i]]`` for every lane: ``[S, kv_heads, max_len(,
+    head_dim)]`` out of a stacked cache tensor.  One ``dynamic_slice`` per
+    lane, unrolled, NOT one gather: the TPU's gather of rows this long
+    first cuts its whole operand — here all layers of the cache — into
+    four pieces along ``max_len`` (``mini-gather-slice``), a copy of the
+    tensor per layer; the slices are plain reads the compiler fuses."""
+    size = (1, 1) + t.shape[2:]
+    zeros = (0,) * (t.ndim - 2)
+    return jnp.concatenate(
+        [lax.dynamic_slice(t, (layer, rows[i]) + zeros, size)[0]
+         for i in range(rows.shape[0])], axis=0)
 
 
-def layer_append_chunk(cl: Dict[str, jax.Array], slots: jax.Array,
-                       lengths: jax.Array, k_new: jax.Array,
-                       v_new: jax.Array,
+def layer_append(cache: Dict[str, jax.Array], layer: jax.Array,
+                 slots: jax.Array, lengths: jax.Array, k_new: jax.Array,
+                 v_new: jax.Array, store: str = "raw"
+                 ) -> Dict[str, jax.Array]:
+    """One decode token per lane into ``layer`` of the stacked cache dict
+    (``[layers, rows, kv_heads, max_len, head_dim]`` per tensor),
+    quantizing on the way in when the store calls for it: ``k_new/v_new``
+    are ``[S, kv_heads, head_dim]`` and lane i's token lands at position
+    ``lengths[i]`` of row ``slots[i]``.  Duplicate (trash-slot) rows are
+    allowed — last write wins, and nothing ever reads the trash row."""
+    return layer_append_chunk(cache, layer, slots, lengths, k_new[:, None],
+                              v_new[:, None], store)
+
+
+def layer_append_chunk(cache: Dict[str, jax.Array], layer: jax.Array,
+                       slots: jax.Array, lengths: jax.Array,
+                       k_new: jax.Array, v_new: jax.Array,
                        store: str = "raw") -> Dict[str, jax.Array]:
-    """Scatter a T-token chunk per lane (the k-token verify / chunked
-    prefill append): ``k_new/v_new`` are ``[S, T, kv_heads, head_dim]``
-    and token t of lane i lands at row ``lengths[i] + t`` of
-    ``slots[i]``."""
-    T = k_new.shape[1]
-    rows = slots[:, None]                                       # [S, 1]
-    pos = lengths[:, None] + jnp.arange(T)[None, :]             # [S, T]
+    """Write a T-token chunk per lane (the k-token verify / chunked
+    prefill append) into ``layer`` of the stacked cache dict:
+    ``k_new/v_new`` are ``[S, T, kv_heads, head_dim]`` and token t of
+    lane i lands at position ``lengths[i] + t`` of row ``slots[i]``."""
     qk, sk = quantize_rows(k_new, store)
     qv, sv = quantize_rows(v_new, store)
-    out = dict(cl)
-    out["k"] = cl["k"].at[rows, :, pos].set(qk.astype(cl["k"].dtype))
-    out["v"] = cl["v"].at[rows, :, pos].set(qv.astype(cl["v"].dtype))
-    if sk is not None:
-        out["k_scale"] = cl["k_scale"].at[rows, :, pos].set(sk)
-        out["v_scale"] = cl["v_scale"].at[rows, :, pos].set(sv)
+    out = dict(cache)
+    for name, upd in (("k", qk), ("v", qv), ("k_scale", sk),
+                      ("v_scale", sv)):
+        if upd is not None:
+            out[name] = _write_lanes(cache[name], layer, slots, lengths,
+                                     jnp.swapaxes(upd, 1, 2))
     return out
 
 
-def layer_prefill(cl: Dict[str, jax.Array], slot_id: jax.Array,
-                  k: jax.Array, v: jax.Array,
+def layer_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
+                  slot_id: jax.Array, k: jax.Array, v: jax.Array,
                   store: str = "raw") -> Dict[str, jax.Array]:
     """Land a whole padded prompt's kv (``[Tpad, kv_heads, head_dim]``)
-    at positions ``0..Tpad-1`` of ``slot_id`` — the prefill write.
-    Positions past the true length hold garbage that the length masks
-    never read before an append overwrites them."""
-    from jax import lax
+    at positions ``0..Tpad-1`` of row ``slot_id`` of ``layer`` — the
+    prefill write, one ``dynamic_update_slice`` per tensor of the stacked
+    cache dict.  Positions past the true length hold garbage that the
+    length masks never read before an append overwrites them."""
     qk, sk = quantize_rows(k, store)
     qv, sv = quantize_rows(v, store)
-    out = dict(cl)
-    out["k"] = lax.dynamic_update_slice(
-        cl["k"], qk.transpose(1, 0, 2)[None].astype(cl["k"].dtype),
-        (slot_id, 0, 0, 0))
-    out["v"] = lax.dynamic_update_slice(
-        cl["v"], qv.transpose(1, 0, 2)[None].astype(cl["v"].dtype),
-        (slot_id, 0, 0, 0))
+    max_len = cache["k"].shape[3]
+    out = dict(cache)
+    for name, pay in (("k", qk), ("v", qv)):
+        out[name] = lax.dynamic_update_slice(
+            cache[name], _pin_window(
+                pay.transpose(1, 0, 2)[None, None].astype(cache[name].dtype),
+                max_len), (layer, slot_id, 0, 0, 0))
     if sk is not None:
-        out["k_scale"] = lax.dynamic_update_slice(
-            cl["k_scale"], sk.T[None], (slot_id, 0, 0))
-        out["v_scale"] = lax.dynamic_update_slice(
-            cl["v_scale"], sv.T[None], (slot_id, 0, 0))
+        for name, sc in (("k_scale", sk), ("v_scale", sv)):
+            out[name] = lax.dynamic_update_slice(
+                cache[name], _pin_window(sc.T[None, None], max_len),
+                (layer, slot_id, 0, 0))
     return out
 
 
 def _gather_pages(cl: Dict[str, jax.Array], slots: jax.Array,
                   prefix_slots: Optional[jax.Array],
-                  prefix_lens: Optional[jax.Array]):
+                  prefix_lens: Optional[jax.Array],
+                  layer: Optional[jax.Array] = None):
     """Gather each lane's kv rows, reading **through the page
     indirection**: key positions ``< prefix_lens[i]`` come from the
-    lane's shared prefix page, the rest from its private slot.  Returns
+    lane's shared prefix page, the rest from its private slot.  With
+    ``layer`` the rows are read straight out of the stacked cache at
+    ``[layer, row]`` (the layer is never materialized).  Returns
     f32-dequantized ``(ks, vs)`` of shape ``[S, Hkv, max_len, Dh]``."""
-    ks, vs = cl["k"][slots], cl["v"][slots]
-    ksc = cl["k_scale"][slots] if "k_scale" in cl else None
-    vsc = cl["v_scale"][slots] if "v_scale" in cl else None
+    def rows(name, r):
+        return cl[name][r] if layer is None else \
+            _read_lanes(cl[name], layer, r)
+
+    ks, vs = rows("k", slots), rows("v", slots)
+    ksc = rows("k_scale", slots) if "k_scale" in cl else None
+    vsc = rows("v_scale", slots) if "v_scale" in cl else None
     if prefix_slots is not None:
-        L = cl["k"].shape[2]
+        L = cl["k"].shape[-2]
         shared = (jnp.arange(L)[None, :]
                   < prefix_lens[:, None])                       # [S, L]
         sel = shared[:, None, :, None]
-        ks = jnp.where(sel, cl["k"][prefix_slots], ks)
-        vs = jnp.where(sel, cl["v"][prefix_slots], vs)
+        ks = jnp.where(sel, rows("k", prefix_slots), ks)
+        vs = jnp.where(sel, rows("v", prefix_slots), vs)
         if ksc is not None:
             ksc = jnp.where(shared[:, None, :],
-                            cl["k_scale"][prefix_slots], ksc)
+                            rows("k_scale", prefix_slots), ksc)
             vsc = jnp.where(shared[:, None, :],
-                            cl["v_scale"][prefix_slots], vsc)
+                            rows("v_scale", prefix_slots), vsc)
     ct = jnp.float32
     return dequantize_rows(ks, ksc, ct), dequantize_rows(vs, vsc, ct)
 
@@ -305,14 +377,16 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
                 k_scale: Optional[jax.Array] = None,
                 v_scale: Optional[jax.Array] = None,
                 prefix_slots: Optional[jax.Array] = None,
-                prefix_lens: Optional[jax.Array] = None) -> jax.Array:
+                prefix_lens: Optional[jax.Array] = None,
+                layer: Optional[jax.Array] = None) -> jax.Array:
     """Masked decode attention of one new token per request over its slot.
 
     ``q``: ``[S, heads, head_dim]`` (heads may be ``group * kv_heads`` —
     grouped-query attention: q head ``h`` attends compact kv head
     ``h // group``, via a reshape-grouped einsum that never materializes
     repeated K/V copies);
-    ``kl/vl``: one layer's pages (post-append); ``lengths``: the position
+    ``kl/vl``: one layer's pages (post-append), or with ``layer`` the
+    stacked cache read at that layer; ``lengths``: the position
     the new token was appended at, so keys ``0 .. lengths[i]`` inclusive
     are valid.  ``k_scale/v_scale`` dequantize int8/fp8 pages on the fly;
     ``prefix_slots/prefix_lens`` route key positions below the prefix
@@ -321,7 +395,7 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     masking.
     """
     S, H, Dh = q.shape
-    Hkv = kl.shape[1]
+    Hkv, L = kl.shape[-3], kl.shape[-2]
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     if scale is None:
@@ -329,11 +403,11 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     cl = {"k": kl, "v": vl}
     if k_scale is not None:
         cl["k_scale"], cl["v_scale"] = k_scale, v_scale
-    ks, vs = _gather_pages(cl, slots, prefix_slots, prefix_lens)
+    ks, vs = _gather_pages(cl, slots, prefix_slots, prefix_lens, layer)
     ct = jnp.promote_types(q.dtype, jnp.float32)
     qg = (q.astype(ct) * scale).reshape(S, Hkv, H // Hkv, Dh)
     s = jnp.einsum("skgd,skld->skgl", qg, ks.astype(ct))
-    valid = jnp.arange(kl.shape[2])[None, :] <= lengths[:, None]   # [S, L]
+    valid = jnp.arange(L)[None, :] <= lengths[:, None]             # [S, L]
     s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("skgl,skld->skgd", p, vs.astype(ct))
@@ -343,21 +417,22 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
 def attend_chunk(q: jax.Array, cl: Dict[str, jax.Array], slots: jax.Array,
                  lengths: jax.Array, scale: Optional[float] = None, *,
                  prefix_slots: Optional[jax.Array] = None,
-                 prefix_lens: Optional[jax.Array] = None) -> jax.Array:
+                 prefix_lens: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None) -> jax.Array:
     """Chunked causal attention for the k-token verify forward (and the
     chunked prefill of a prefix-hit request): ``q`` is ``[S, T, heads,
     head_dim]`` with query t of lane i sitting at position ``lengths[i] +
     t``, attending over its slot's rows ``0 .. lengths[i] + t`` inclusive
-    (post :func:`layer_append_chunk`) — prefix pages and quantized
-    storage read exactly as in :func:`attend_rows`."""
+    (post :func:`layer_append_chunk`) — prefix pages, quantized storage
+    and the stacked cache at ``layer`` read exactly as in
+    :func:`attend_rows`."""
     S, T, H, Dh = q.shape
-    Hkv = cl["k"].shape[1]
+    Hkv, L = cl["k"].shape[-3], cl["k"].shape[-2]
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     if scale is None:
         scale = Dh ** -0.5
-    ks, vs = _gather_pages(cl, slots, prefix_slots, prefix_lens)
-    L = cl["k"].shape[2]
+    ks, vs = _gather_pages(cl, slots, prefix_slots, prefix_lens, layer)
     ct = jnp.promote_types(q.dtype, jnp.float32)
     qg = (q.astype(ct) * scale).reshape(S, T, Hkv, H // Hkv, Dh)
     s = jnp.einsum("stkgd,skld->stkgl", qg, ks.astype(ct))

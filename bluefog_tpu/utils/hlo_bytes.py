@@ -17,7 +17,8 @@ import re
 _DT_BYTES = {"f64": 8, "u64": 8, "s64": 8, "c64": 8,
              "f32": 4, "u32": 4, "s32": 4,
              "bf16": 2, "f16": 2, "u16": 2, "s16": 2,
-             "u8": 1, "s8": 1, "pred": 1}
+             "u8": 1, "s8": 1, "pred": 1,
+             "f8e4m3fn": 1, "f8e5m2": 1}
 
 # ops that move bytes across chips; -done/-update variants reuse the same
 # buffer and must not be double counted
@@ -110,6 +111,68 @@ def total_wire_bytes(hlo_txt: str) -> int:
     """Sum of :func:`wire_stats` bytes across all collective kinds."""
     _, bytes_ = wire_stats(hlo_txt)
     return int(sum(bytes_.values()))
+
+
+# ---------------------------------------------------------------------------
+# Buffers a compiled program makes: the serving engine's proof that its KV
+# cache is updated in place (no copy of it, no layer loop's stacked output)
+# ---------------------------------------------------------------------------
+
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+_INSTRUCTION_RE = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+_SHAPES_RE = re.compile(r"\b\w+\[[\d,]*\]")
+# results that are another instruction's buffer, not one of their own
+_FORWARDING = frozenset((
+    "parameter", "get-tuple-element", "tuple", "bitcast", "while",
+    "conditional", "call", "optimization-barrier", "copy-done",
+    "dynamic-update-slice"))
+
+
+def _result_bytes(type_txt: str) -> int:
+    return sum(_shape_bytes(m.group(0))
+               for m in _SHAPES_RE.finditer(type_txt))
+
+
+def materialized(hlo_txt: str, min_bytes: int):
+    """``[(name, opcode, bytes), ...]`` of the instructions of a compiled
+    module whose result is a buffer of their own of at least ``min_bytes``
+    (a tuple result counts the sum of its elements).
+
+    Left out: instructions inside fused computations (a fusion's interior
+    lives in registers), results that forward an operand's buffer
+    (``bitcast``, ``get-tuple-element``, ``while``, ...) and updates in
+    place (``dynamic-update-slice``, and a fusion that holds one of its
+    own result's size).  What is left at cache size in a serving program
+    is a copy of the cache."""
+    comps, cur = {}, None
+    for line in hlo_txt.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            m = _INSTRUCTION_RE.match(line)
+            if m:
+                cur.append((m.group(1), m.group(3),
+                            _result_bytes(m.group(2)), line))
+    fused = {name for rows in comps.values() for _, op, _, line in rows
+             if op == "fusion"
+             for name in re.findall(r"calls=%?([\w.\-]+)", line)}
+    out = []
+    for comp, rows in comps.items():
+        if comp in fused:
+            continue
+        for name, op, size, line in rows:
+            if size < min_bytes or op in _FORWARDING:
+                continue
+            if op == "fusion":
+                inner = re.search(r"calls=%?([\w.\-]+)", line)
+                if inner and any(
+                        o == "dynamic-update-slice" and b == size
+                        for _, o, b, _ in comps.get(inner.group(1), ())):
+                    continue
+            out.append((name, op, size))
+    return out
 
 
 # ---------------------------------------------------------------------------

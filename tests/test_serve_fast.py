@@ -440,6 +440,206 @@ def test_prefix_cow_no_cross_contamination(fast_setup):
     assert any(r.prefix_len == 4 for r in reqs)
 
 
+# ---------------------------------------------------------------------------
+# The cache stays where it is: every engine program carries the donated
+# cache through its layer loop and updates it in place
+# ---------------------------------------------------------------------------
+
+_INPLACE = dict(batch_buckets=(1, 2), prefill_buckets=(4, 8), slots=8,
+                max_len=128)
+_INPLACE_KINDS = {
+    "raw": {},
+    "int8": dict(kv_dtype="int8"),
+    "fast": dict(spec_decode=2, spec_stages=1, prefix_pages=2,
+                 prefix_page_tokens=4),
+    "fast_int8": dict(spec_decode=2, spec_stages=1, prefix_pages=2,
+                      prefix_page_tokens=4, kv_dtype="int8"),
+}
+
+
+@pytest.fixture(scope="module")
+def inplace_engines(cpu_devices):
+    """pp = 1 engines, warmed lazily and kept for the module."""
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    built = {}
+
+    def get(kind):
+        if kind not in built:
+            built[kind] = ServeEngine(m, cfg, params, ServeConfig(
+                **_INPLACE, **_INPLACE_KINDS[kind]))
+            built[kind].warmup()
+        return built[kind]
+    return get
+
+
+@pytest.mark.parametrize("kind,family,count", [
+    ("raw", "decode", 2), ("raw", "prefill", 2),
+    ("int8", "decode", 2), ("int8", "prefill", 2),
+    ("fast", "draft", 2), ("fast", "chunk", 4),
+    ("fast_int8", "draft", 2), ("fast_int8", "chunk", 4),
+])
+def test_engine_programs_update_cache_in_place(inplace_engines, kind,
+                                               family, count):
+    """The compiled programs alias their cache output to the donated
+    input (every byte of it) and hold no buffer as large as one layer's
+    pages: the compiler's temporaries are activations, the weights of one
+    layer and the rows of the lanes.  A layer loop that scans OVER the
+    cache (xs in, ys out) fails both ways: its stacked output is a fresh
+    buffer of the whole cache."""
+    eng = inplace_engines(kind)
+    cc = eng.cache_cfg
+    layer_pages = cc.bytes() // cc.layers
+    programs = {k: v for k, v in eng.program_memory().items()
+                if k.startswith(family)}
+    assert len(programs) == count, sorted(eng.program_memory())
+    for name, mem in programs.items():
+        assert mem["alias_bytes"] == cc.bytes(), (name, mem)
+        assert mem["temp_bytes"] < layer_pages, (name, mem, layer_pages)
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip as a 1x1x1x1 carving.  Only
+    this file's worker loads the TPU compiler, and only once a test here
+    asks for it."""
+    from jax.experimental import topologies
+    try:
+        td = topologies.get_topology_desc("v5e:2x2", platform="tpu")
+    except Exception as e:          # no libtpu in this environment
+        pytest.skip(f"TPU AOT topology unavailable: {e}")
+    return compose.compose_parallelism(1, 1, 1, 1, devices=td.devices[:1])
+
+
+def _v5e_program(m, program, store, fast):
+    """Compile one engine program at the serving cell's sizes (pythia-410m:
+    24 layers, 16 heads of 64, vocab 50304; 32 slots x 1024, 32 lanes,
+    bf16) for the described chip, from shapes alone."""
+    from jax.sharding import NamedSharding
+    from bluefog_tpu.serve import kv_cache as kv
+    L, D, H, V, S, dt = 24, 1024, 16, 50304, 32, jnp.bfloat16
+    lm = compose.LMConfig(vocab=V, d_model=D, heads=H, layers=L,
+                          ffn_mult=4, seq_len=2048)
+    extra = dict(spec_decode=4, spec_stages=1, prefix_pages=2,
+                 prefix_page_tokens=64) if fast else {}
+    scfg = ServeConfig(batch_buckets=(S,), prefill_buckets=(64, 512),
+                       slots=32, max_len=1024, dtype=dt, kv_dtype=store,
+                       **extra)
+    eng = ServeEngine.__new__(ServeEngine)      # bodies only: no arrays
+    eng._moe, eng._moe_chunk_tile = False, None
+    eng.m, eng.cfg, eng.scfg = m, lm, scfg
+    eng.draft = draft_carve(m, lm, 1) if fast else None
+    sh = NamedSharding(m.mesh, m.spec)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        (1,) + tuple(shape), dtype, sharding=sh)
+    i32 = lambda *shape: sds(shape, jnp.int32)
+    params = {"blocks": {"wqkv": sds((L, D, 3 * D), dt),
+                         "wo": sds((L, D, D), dt),
+                         "w1": sds((L, D, 4 * D), dt),
+                         "w2": sds((L, 4 * D, D), dt)},
+              "shared": {"embed": sds((V, D), dt), "head": sds((D, V), dt)}}
+    cc = KVCacheConfig(layers=L, slots=32, max_len=1024, kv_heads=H,
+                       head_dim=D // H, dtype=dt, store=store,
+                       prefix_slots=scfg.prefix_pages)
+    cache = {k: sds(v.shape, v.dtype)
+             for k, v in jax.eval_shape(lambda: kv.init_cache(cc)).items()}
+    pre = (i32(S), i32(S)) if fast else (None, None)
+    lanes = (i32(S), i32(S), i32(S)) + pre
+    one = (i32(1), i32(1), i32(1), i32(1))
+    body, args = {
+        "decode": (eng._decode_body, lanes + (sds((S, 2), jnp.uint32),)),
+        "prefill64": (eng._prefill_body, (i32(64), i32(), i32())),
+        "prefill512": (eng._prefill_body, (i32(512), i32(), i32())),
+        "draft": (eng._draft_body, lanes),
+        "verify": (eng._chunk_body, (i32(S, 5),) + lanes[1:]),
+        "chunk512": (eng._chunk_body, (i32(1, 512),) + one),
+    }[program]
+    return eng._build(body).lower(params, cache, *args).compile(), cc
+
+
+_SLOW = pytest.mark.slow
+
+
+@pytest.mark.parametrize("program,store,fast", [
+    ("decode", "raw", False), ("prefill64", "raw", False),
+    ("prefill512", "raw", False),
+    pytest.param("decode", "raw", True, marks=_SLOW),
+    pytest.param("draft", "raw", True, marks=_SLOW),
+    pytest.param("verify", "raw", True, marks=_SLOW),
+    pytest.param("chunk512", "raw", True, marks=_SLOW),
+    pytest.param("decode", "int8", False, marks=_SLOW),
+    pytest.param("prefill64", "int8", False, marks=_SLOW),
+    pytest.param("verify", "int8", True, marks=_SLOW),
+])
+def test_engine_programs_in_place_on_v5e(v5e_chip, program, store, fast):
+    """What the chip's own compiler builds at the serving cell's sizes:
+    the output cache is the donated input, and no instruction outside a
+    fusion makes a buffer half as large as the K tensor (a copy of the
+    cache into another axis order, a layer loop's stacked output, a
+    gather's four-way cut of its operand).  The plain programs the cell
+    runs hold less than one layer's pages in all their temporaries.
+    The axis order the compiler picks for the carried cache differs from
+    program to program when left to it (the 64-token prefill copied the
+    cache where the 512-token one did not), so each is pinned here."""
+    from bluefog_tpu.utils.hlo_bytes import materialized
+    compiled, cc = _v5e_program(v5e_chip, program, store, fast)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == cc.bytes()
+    k_bytes = cc.bytes() // 2 if store == "raw" else \
+        cc.layers * cc.rows * cc.kv_heads * cc.max_len * cc.head_dim
+    assert materialized(compiled.as_text(), k_bytes // 2) == []
+    if store == "raw" and not fast:
+        assert mem.temp_size_in_bytes < cc.bytes() // cc.layers
+
+
+def test_cache_copy_gauge_set_at_warmup(cpu_devices):
+    """bluefog_serve_cache_copy_bytes{program} / ..._alias_bytes{program}
+    carry what ``program_memory`` holds, one series per warmed program,
+    and a steady-state call adds none."""
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    eng = ServeEngine(m, cfg, compose.init_lm_params(cfg, m, seed=3),
+                      ServeConfig(**_INPLACE))
+    eng.warmup()
+    mem = eng.program_memory()
+    assert sorted(mem) == ["decode S=1", "decode S=2", "prefill Tpad=4",
+                           "prefill Tpad=8"]
+    copy = bfm.get_metric("bluefog_serve_cache_copy_bytes")
+    alias = bfm.get_metric("bluefog_serve_cache_alias_bytes")
+    for name, row in mem.items():
+        assert copy.value(program=name) == row["temp_bytes"]
+        assert alias.value(program=name) == row["alias_bytes"] \
+            == eng.cache_cfg.bytes()
+    eng.prefill(0, 0, [5, 6, 7])
+    assert eng.program_memory() == mem
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+
+
+def test_pp2_tokens_equal_pp1(cpu_devices):
+    """The same model carved over two pipeline stages (where the stage-id
+    select keeps one stage's cache writes per hop) and over one (where
+    the carried cache goes straight through) serves the same tokens and
+    the same prefill logits, bit for bit."""
+    cfg = compose.LMConfig(**_CFG)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, _CFG["vocab"], int(n)).tolist()
+               for n in (3, 7, 5, 8)]
+    out = {}
+    for pp in (1, 2):
+        m = compose.compose_parallelism(1, pp, 1, 1,
+                                        devices=cpu_devices[:pp])
+        # init_lm_params draws [pp, tp, layers/pp, ...] from one stream,
+        # so both carvings hold the same 4 layers in the same order
+        eng = ServeEngine(m, cfg, compose.init_lm_params(cfg, m, seed=3),
+                          ServeConfig(**_SCFG))
+        eng.warmup()
+        logits = eng.prefill(0, 0, prompts[0])[1]
+        out[pp] = ([r.generated for r in _drain(eng, prompts)], logits)
+    assert out[1][0] == out[2][0]
+    np.testing.assert_array_equal(out[1][1], out[2][1])
+
+
 @pytest.fixture(scope="module")
 def flash_setup(cpu_devices):
     """Two engines differing ONLY in decode_kernel: every fast path on

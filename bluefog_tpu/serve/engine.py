@@ -25,6 +25,12 @@ program's output cache is its input buffer (:mod:`.kv_cache` owns the
 layout, the in-place writes and reads, int8/fp8 page storage and shared
 prefix pages; :meth:`ServeEngine.program_memory` and the
 ``bluefog_serve_cache_copy_bytes`` gauge say what the compiler built).
+A decode token's K and V are not written in the loop at all: each layer
+attends over the cached rows plus its own token, the tokens of all layers
+leave the loop as the scan's stacked output and land in the cache once
+per lane and tensor after it (``bluefog_serve_cache_writes_per_call``);
+only the Pallas flash-decode kernel, which reads its pages from HBM
+itself, has them written per layer.
 Steady-state decode is a single cached program per (bucket,
 steps_per_call): embed → pp-cycle of stage-local layer loops
 (``ppermute`` moves the activation, a stage-id ``where`` keeps exactly
@@ -599,32 +605,47 @@ class ServeEngine:
 
     def _layer_scan(self, one, blocks, x, cache):
         """Run a stage's layers with the cache as a **loop-carried**
-        buffer: ``one(lp, x, cache, layer) -> (x, cache)`` gets the whole
-        stacked cache dict plus its layer index and writes/reads it at
-        ``[layer, ...]`` (:mod:`.kv_cache`); only the block weights are
-        scanned over.  A scan that takes the cache as
-        ``xs`` and returns it as ``ys`` slices every layer out and stacks
-        the updated layers into a freshly allocated buffer — the carried
-        form is what XLA updates in place, so with the donated argument
-        the program's output cache IS its input cache."""
+        buffer: ``one(lp, x, cache, layer) -> (x, cache, new)`` gets the
+        whole stacked cache dict plus its layer index and writes/reads it
+        at ``[layer, ...]`` (:mod:`.kv_cache`); only the block weights
+        are scanned over.  A scan that takes the cache as ``xs`` and
+        returns it as ``ys`` slices every layer out and stacks the updated
+        layers into a freshly allocated buffer — the carried form is what
+        XLA updates in place, so with the donated argument the program's
+        output cache IS its input cache.  ``new`` is what a layer leaves
+        for after the loop (a decode token's pages, or None): returned
+        stacked ``[layers, ...]`` as the scan's ``ys``."""
         def body(carry, xs):
             lp, layer = xs
-            return one(lp, *carry, layer), None
+            x, cache, new = one(lp, *carry, layer)
+            return (x, cache), new
         layers = jax.tree.leaves(blocks)[0].shape[0]
-        (x, cache), _ = lax.scan(body, (x, cache),
-                                 (blocks, jnp.arange(layers)))
-        return x, cache
+        (x, cache), news = lax.scan(body, (x, cache),
+                                    (blocks, jnp.arange(layers)))
+        return x, cache, news
 
     def _layer_view(self, cache, layer):
         """One layer's pages as a tensor of their own — what the Pallas
         kernels take (their index maps address ``[row, head, block]``)."""
         return {name: t[layer] for name, t in cache.items()}
 
+    @property
+    def _defer_appends(self) -> bool:
+        """Whether a decode token's kv reaches the cache after the layer
+        loop (one write per lane and tensor) instead of inside it (one
+        per lane, tensor and layer).  The XLA attention stages each
+        lane's rows and takes the token beside them; the flash-decode
+        kernel streams its pages from HBM in place, so its token has to
+        be written before it runs."""
+        return self.scfg.decode_kernel != "pallas"
+
     def _layer_step(self, lp, x, cache, layer, slot_ids, lens, prows, plens,
                     draft=False):
         """One decoder block on one new token per lane: ``x`` is ``[S, D]``,
-        ``cache`` the stage's stacked cache dict, appended to and read at
-        ``layer``."""
+        ``cache`` the stage's stacked cache dict, read at ``layer``.
+        Returns ``(x, cache, new, routing)``: ``new`` is the token's
+        pages, still to be written (:meth:`_defer_appends`), or None once
+        the cache holds them."""
         cfg, m = self.cfg, self.m
         Hl = cfg.heads // m.tp
         hsz = cfg.d_model // cfg.heads
@@ -634,8 +655,13 @@ class ServeEngine:
         q = apply_rope_rows(q.reshape(S, Hl, hsz), lens)
         k = apply_rope_rows(k.reshape(S, Hl, hsz), lens)
         v = v.reshape(S, Hl, hsz)
-        cache = _kv.layer_append(cache, layer, slot_ids, lens, k, v,
-                                 store=self.scfg.kv_dtype)
+        if self._defer_appends:
+            new = _kv.token_pages(k, v, self.scfg.kv_dtype,
+                                  cache["k"].dtype)
+        else:
+            new = None
+            cache = _kv.layer_append(cache, layer, slot_ids, lens, k, v,
+                                     store=self.scfg.kv_dtype)
         if self.scfg.decode_kernel == "pallas":
             cl = self._layer_view(cache, layer)
             att = _pd.flash_attend_rows(
@@ -648,18 +674,20 @@ class ServeEngine:
                                   k_scale=cache.get("k_scale"),
                                   v_scale=cache.get("v_scale"),
                                   prefix_slots=prows, prefix_lens=plens,
-                                  layer=layer)
+                                  layer=layer, new=new)
         x = x + lax.psum(att.reshape(S, Hl * hsz) @ lp["wo"], "tp")
         x, routing = self._ffn(lp, x, draft=draft)
-        return x, cache, routing
+        return x, cache, new, routing
 
-    def _pp_cycle(self, blocks, x, cache, one, n_stages=None):
+    def _pp_cycle(self, blocks, x, cache, one, n_stages=None, land=None):
         """Cycle ``x`` through ``n_stages`` pipeline stages (all of them by
         default; the draft truncates); each stage's layer loop
         (:meth:`_layer_scan` of ``one``, the cache carried through it and
         updated in place) runs everywhere but only the
         owning stage keeps its activation and cache writes, so the program
-        is identical on every device.  The keep-select over the cache is
+        is identical on every device.  What the layers left for after the
+        loop goes into the cache there, through ``land(cache, news)``,
+        before the keep-select.  The keep-select over the cache is
         what makes only the owning stage's writes stick at ``pp > 1``
         (there it costs a pass over the cache per hop); on a ``stage`` axis
         of one chip ``sid == s`` is the constant true and the compiler
@@ -671,7 +699,9 @@ class ServeEngine:
         sid = lax.axis_index("stage")
         perm = [(i, (i + 1) % self.m.pp) for i in range(self.m.pp)]
         for s in range(n):
-            y, nc = self._layer_scan(one, blocks, x, cache)
+            y, nc, news = self._layer_scan(one, blocks, x, cache)
+            if news is not None:
+                nc = land(nc, news)
             keep = sid == s
             # x may be a pytree carrier (activation + stats accumulator on
             # the MoE decode path) — keep/permute leafwise
@@ -717,20 +747,22 @@ class ServeEngine:
             if track:
                 def one(lp, xc, c, layer):
                     x, acc = xc
-                    x, c, routing = self._layer_step(
+                    x, c, new, routing = self._layer_step(
                         lp, x, c, layer, slot_ids, lens, prows, plens)
-                    return (x, acc + self._route_vec(routing, live)), c
+                    return (x, acc + self._route_vec(routing, live)), c, new
                 x0 = (embed[toks], st)                        # [S, D] + [E+2]
             else:
                 def one(lp, x, c, layer):
-                    x, c, _ = self._layer_step(lp, x, c, layer, slot_ids,
-                                               lens, prows, plens,
-                                               draft=draft)
-                    return x, c
+                    x, c, new, _ = self._layer_step(
+                        lp, x, c, layer, slot_ids, lens, prows, plens,
+                        draft=draft)
+                    return x, c, new
                 x0 = embed[toks]                              # [S, D]
 
-            x, cache, sid = self._pp_cycle(bp, x0, cache, one,
-                                           n_stages=n_stages)
+            x, cache, sid = self._pp_cycle(
+                bp, x0, cache, one, n_stages=n_stages,
+                land=lambda c, new: _kv.append_tokens(c, slot_ids, lens,
+                                                      new))
             if track:
                 x, acc = x
                 st = lax.psum(jnp.where(sid == out_stage, acc, 0.0),
@@ -826,7 +858,7 @@ class ServeEngine:
                                    if self._moe else None)
             if self._moe:
                 acc = acc + self._route_vec(routing, live)
-            return (x, acc), c
+            return (x, acc), c, None
 
         st0 = jnp.zeros((cfg.num_experts + 2,) if self._moe else (),
                         jnp.float32)
@@ -870,7 +902,7 @@ class ServeEngine:
                 att.reshape(1, Tpad, Hl * hsz) @ lp["wo"], "tp")
             x, _ = self._ffn(lp, x, tile=self._moe_chunk_tile
                              if self._moe else None)
-            return x, c
+            return x, c, None
 
         x, cache, sid = self._pp_cycle(self._blocks_tree(params), x, cache,
                                        one)
@@ -984,7 +1016,8 @@ class ServeEngine:
                 nxt, logits, self.cache = self._prefill_jit(*args)
             with self._stage("collect"):
                 self._check_program(f"prefill Tpad={Tpad}",
-                                    self._prefill_jit, args)
+                                    self._prefill_jit, args,
+                                    self._cache_writes("prefill", 1))
                 return (int(self._collect(nxt)[replica]),
                         self._collect(logits)[replica])
 
@@ -1043,7 +1076,7 @@ class ServeEngine:
                 if self._moe:
                     self._note_route_stats(st)
                 self._check_program(f"chunk S={S} T={T}", self._chunk_jit,
-                                    args)
+                                    args, self._cache_writes("chunk", S))
                 return self._collect(gen)
 
     def decode(self, tokens: np.ndarray, slots: np.ndarray,
@@ -1065,7 +1098,8 @@ class ServeEngine:
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
-        with self._stage("decode_call", S=int(S)):
+        writes = self._cache_writes("decode", S)
+        with self._stage("decode_call", S=int(S), cache_writes=writes):
             with self._stage("stage_in"):
                 slots = np.asarray(slots, np.int32)
                 prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
@@ -1086,7 +1120,8 @@ class ServeEngine:
                 if self._moe:
                     self._note_route_stats(st)
                 self._scatter_keys(slots, self._collect(keys))
-                self._check_program(f"decode S={S}", self._decode_jit, args)
+                self._check_program(f"decode S={S}", self._decode_jit, args,
+                                    writes)
                 return self._collect(gen)
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
@@ -1133,7 +1168,8 @@ class ServeEngine:
         with self._stage("dispatch"):
             drafts, self.cache = self._draft_jit(*args)
         with self._stage("collect"):
-            self._check_program(f"draft S={S}", self._draft_jit, args)
+            self._check_program(f"draft S={S}", self._draft_jit, args,
+                                self._cache_writes("draft", S))
             drafts = self._collect(drafts)              # [R, k, S]
         d = np.transpose(drafts, (0, 2, 1))             # [R, S, k]
         # verify chunk: [t0, d_1 .. d_k] per lane — the draft rows it
@@ -1266,11 +1302,27 @@ class ServeEngine:
                      for j in (self._decode_jit, self._prefill_jit,
                                self._chunk_jit, self._draft_jit))
 
-    def _check_program(self, program: str, fn, args) -> None:
+    def _cache_writes(self, kind: str, lanes: int) -> int:
+        """``dynamic_update_slice``s into the cache that one call of a
+        ``kind`` program (``"decode"``, ``"draft"``, ``"chunk"``,
+        ``"prefill"``) makes on each device, known from shapes alone:
+        lanes x tensors, per stage hop of the cycle and per fused step,
+        once after the layer loop where a decode token's write waits for
+        it (:meth:`_defer_appends`) and once per layer everywhere else."""
+        scfg = self.scfg
+        hops = self.draft.stages if kind == "draft" else self.m.pp
+        steps = {"decode": scfg.decode_steps_per_call,
+                 "draft": scfg.spec_decode}.get(kind, 1)
+        deferred = kind in ("decode", "draft") and self._defer_appends
+        return (lanes * len(self.cache) * hops * steps
+                * (1 if deferred else self.cache_cfg.layers))
+
+    def _check_program(self, program: str, fn, args, writes: int) -> None:
         """After every device call: the first time a program (``"decode
         S=32"``, ``"prefill Tpad=64"``, ...) is seen, record what the
-        compiler built for it; once warm, any growth of the jit caches is
-        a retrace."""
+        compiler built for it and its ``writes`` into the cache a call
+        (:meth:`_cache_writes`); once warm, any growth of the jit caches
+        is a retrace."""
         if program not in self._program_bytes:
             # the executable the call above compiled, found again through
             # jit's own caches: nothing is traced or compiled a second
@@ -1279,7 +1331,8 @@ class ServeEngine:
             ma = fn.lower(*args).compile().memory_analysis()
             self._program_bytes[program] = {
                 "temp_bytes": int(ma.temp_size_in_bytes),
-                "alias_bytes": int(ma.alias_size_in_bytes)}
+                "alias_bytes": int(ma.alias_size_in_bytes),
+                "cache_writes": writes}
             _metrics.gauge(
                 "bluefog_serve_cache_copy_bytes",
                 "temporaries the compiler allocated for one engine program "
@@ -1292,6 +1345,12 @@ class ServeEngine:
                 "argument (per device): the whole KV cache while it is "
                 "updated in place").set(float(ma.alias_size_in_bytes),
                                         program=program)
+            _metrics.gauge(
+                "bluefog_serve_cache_writes_per_call",
+                "in-place writes into the KV cache one call of an engine "
+                "program makes (per device): lanes x tensors, once after "
+                "the layer loop for a decode token, once per layer "
+                "otherwise").set(float(writes), program=program)
         if self._warm_sizes is None:
             return
         sizes = self._jit_sizes()
@@ -1300,10 +1359,10 @@ class ServeEngine:
             self._warm_sizes = sizes
 
     def program_memory(self) -> dict:
-        """``{program: {"temp_bytes", "alias_bytes"}}`` per device, for
-        every engine program compiled so far
-        (``compiled.memory_analysis()``): whether the cache is updated in
-        place is a property of the compiled program, so this is its
-        counter — ``alias_bytes`` is the cache's size and ``temp_bytes``
-        stays under one layer's pages when it is."""
+        """``{program: {"temp_bytes", "alias_bytes", "cache_writes"}}`` per
+        device, for every engine program compiled so far
+        (``compiled.memory_analysis()``, :meth:`_cache_writes`): whether
+        the cache is updated in place is a property of the compiled
+        program, so this is its counter — ``alias_bytes`` is the cache's
+        size and ``temp_bytes`` stays under one layer's pages when it is."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

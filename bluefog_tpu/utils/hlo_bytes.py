@@ -12,6 +12,7 @@ The async ``-start`` forms are counted once; their ``-done``/``-update``
 variants reuse the same buffer and never match (the pattern requires the
 opening paren directly after the op name, which ``-done(`` breaks).
 """
+import math
 import re
 
 _DT_BYTES = {"f64": 8, "u64": 8, "s64": 8, "c64": 8,
@@ -134,6 +135,51 @@ def _result_bytes(type_txt: str) -> int:
                for m in _SHAPES_RE.finditer(type_txt))
 
 
+def _computations(hlo_txt: str) -> dict:
+    """``{computation: [(name, opcode, result type text, line), ...]}`` of
+    a compiled module's text."""
+    comps, cur = {}, None
+    for line in hlo_txt.splitlines():
+        m = _COMPUTATION_RE.match(line)
+        if m:
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            m = _INSTRUCTION_RE.match(line)
+            if m:
+                cur.append((m.group(1), m.group(3), m.group(2), line))
+    return comps
+
+
+def loop_writes(hlo_txt: str, elements: int) -> tuple:
+    """``(inside, outside)``: how many ``dynamic-update-slice``
+    instructions of a compiled module update a buffer of ``elements``
+    elements (a KV-cache tensor, under whatever view of it) inside a
+    ``while`` loop's body — in what it calls and fuses too — and how
+    many outside every loop.  The serving engine's proof that a decode
+    call writes its KV cache once per lane and tensor after the layer
+    loop, not once per layer in it."""
+    comps = _computations(hlo_txt)
+    callees = {c: {n for *_, line in rows for n in re.findall(
+        r"(?:calls|body|condition|to_apply)=%?([\w.\-]+)", line)}
+        for c, rows in comps.items()}
+    todo = [n for rows in comps.values() for *_, line in rows
+            for n in re.findall(r"body=%?([\w.\-]+)", line)]
+    looped = set()
+    while todo:
+        c = todo.pop()
+        if c not in looped:
+            looped.add(c)
+            todo.extend(callees.get(c, ()))
+    count = [0, 0]
+    for c, rows in comps.items():
+        for _, op, type_txt, _ in rows:
+            m = re.search(r"\[([\d,]*)\]", type_txt)
+            if op == "dynamic-update-slice" and m and elements == \
+                    math.prod(int(d) for d in m.group(1).split(",") if d):
+                count[c not in looped] += 1
+    return tuple(count)
+
+
 def materialized(hlo_txt: str, min_bytes: int):
     """``[(name, opcode, bytes), ...]`` of the instructions of a compiled
     module whose result is a buffer of their own of at least ``min_bytes``
@@ -145,16 +191,9 @@ def materialized(hlo_txt: str, min_bytes: int):
     place (``dynamic-update-slice``, and a fusion that holds one of its
     own result's size).  What is left at cache size in a serving program
     is a copy of the cache."""
-    comps, cur = {}, None
-    for line in hlo_txt.splitlines():
-        m = _COMPUTATION_RE.match(line)
-        if m:
-            cur = comps.setdefault(m.group(1), [])
-        elif cur is not None:
-            m = _INSTRUCTION_RE.match(line)
-            if m:
-                cur.append((m.group(1), m.group(3),
-                            _result_bytes(m.group(2)), line))
+    comps = {c: [(name, op, _result_bytes(type_txt), line)
+                 for name, op, type_txt, line in rows]
+             for c, rows in _computations(hlo_txt).items()}
     fused = {name for rows in comps.values() for _, op, _, line in rows
              if op == "fusion"
              for name in re.findall(r"calls=%?([\w.\-]+)", line)}

@@ -45,7 +45,9 @@ from bluefog_tpu.parallel import compose
 from bluefog_tpu.serve import (Scheduler, ServeConfig, ServeEngine,
                                SlotAllocator, WeightRefresher)
 from bluefog_tpu.serve.engine import _parse_buckets
-from bluefog_tpu.serve.kv_cache import KVCacheConfig, attend_rows, init_cache
+from bluefog_tpu.serve.kv_cache import (KVCacheConfig, append_tokens,
+                                        attend_rows, init_cache,
+                                        layer_append, token_pages)
 from bluefog_tpu.utils import chaos as bfchaos
 from bluefog_tpu.utils import flight as bfflight
 from bluefog_tpu.utils import metrics as bfm
@@ -152,6 +154,78 @@ def test_kv_cache_shapes():
     assert c["k"].shape == (2, 5, 2, 8, 4)      # slots + 1 trash row
     assert cfg.trash_slot == 4
     assert cfg.bytes() == 2 * 2 * 5 * 8 * 2 * 4 * 4
+
+
+_DEFER_LANES = {
+    # case: (slots, lengths, q heads per kv head, prefix rows, prefix lens)
+    # with 4 request slots, 1 prefix page (row 4), trash row 5, max_len 16
+    "plain": ([0, 2, 1], [3, 7, 0], 1, None, None),
+    "duplicate_trash_lanes": ([1, 5, 5, 5], [5, 0, 0, 2], 1, None, None),
+    "at_and_past_max_len": ([0, 1, 2, 3], [15, 16, 19, 4], 1, None, None),
+    "gqa": ([3, 0], [9, 2], 2, None, None),
+    "prefix_page": ([0, 2], [6, 3], 1, [4, 5], [4, 0]),
+}
+
+
+@pytest.mark.parametrize("store", ["raw", "int8", "fp8"])
+@pytest.mark.parametrize("case", sorted(_DEFER_LANES))
+def test_deferred_append_equals_per_layer_append(store, case):
+    """A decode token handed to the attention beside the pages
+    (``attend_rows(new=...)``) and written once per lane and tensor after
+    the layers (``append_tokens``) against the form it replaces in the
+    engine's XLA path, ``layer_append`` then ``attend_rows`` layer by
+    layer: the same attention output on every live lane, bit for bit, and
+    the same final cache, every row of it."""
+    import jax.numpy as jnp
+    if store == "fp8" and not hasattr(jnp, "float8_e4m3fn"):
+        pytest.skip("no fp8 dtype in this jax build")
+    slots, lens, group, prows, plens = _DEFER_LANES[case]
+    cc = KVCacheConfig(layers=3, slots=4, max_len=16, kv_heads=2,
+                       head_dim=8, store=store, prefix_slots=1)
+    rng = np.random.default_rng(sorted(_DEFER_LANES).index(case))
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    dt = init_cache(cc)["k"].dtype
+    # a cache full of earlier tokens, as their writes would have left it
+    full = token_pages(normal(cc.layers, cc.rows, cc.max_len, 2, 8),
+                       normal(cc.layers, cc.rows, cc.max_len, 2, 8),
+                       store, dt)
+    cache = {name: jnp.swapaxes(t, 2, 3) for name, t in full.items()}
+    slots, lens = jnp.asarray(slots, jnp.int32), jnp.asarray(lens, jnp.int32)
+    pre = {} if prows is None else dict(
+        prefix_slots=jnp.asarray(prows, jnp.int32),
+        prefix_lens=jnp.asarray(plens, jnp.int32))
+    S = slots.shape[0]
+    want_cache, want, got, news = cache, [], [], []
+    for layer in range(cc.layers):
+        q = normal(S, 2 * group, 8)
+        k, v = normal(S, 2, 8), normal(S, 2, 8)
+        want_cache = layer_append(want_cache, layer, slots, lens, k, v, store)
+        want.append(attend_rows(
+            q, want_cache["k"], want_cache["v"], slots, lens,
+            k_scale=want_cache.get("k_scale"),
+            v_scale=want_cache.get("v_scale"), layer=layer, **pre))
+        new = token_pages(k, v, store, dt)
+        got.append(attend_rows(
+            q, cache["k"], cache["v"], slots, lens,
+            k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+            layer=layer, new=new, **pre))
+        news.append(new)
+    got_cache = append_tokens(
+        cache, slots, lens,
+        {name: jnp.stack([n[name] for n in news]) for name in cache})
+    f32 = lambda t: np.asarray(t.astype(jnp.float32))
+    assert sorted(got_cache) == sorted(want_cache)
+    for name in cache:
+        np.testing.assert_array_equal(f32(got_cache[name]),
+                                      f32(want_cache[name]))
+        # and it is a write: the lanes' tokens are in, nothing else moved
+        assert (f32(got_cache[name]) != f32(cache[name])).any()
+    # lanes on the trash row attend over whichever of them wrote last
+    # there when the write comes first, and over their own token when it
+    # waits: no one reads what they produce
+    live = np.asarray(slots) < cc.slots
+    np.testing.assert_array_equal(f32(jnp.stack(got))[:, live],
+                                  f32(jnp.stack(want))[:, live])
 
 
 # ---------------------------------------------------------------------------
@@ -487,7 +561,11 @@ def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     assert len(steps) == guard
     assert steps[0][3] == {}
     calls = inside(ev, "bf:engine.decode_call", "bf:serve.step")
-    assert len(calls) == guard and set(calls[0][3]) == {"S"}
+    assert len(calls) == guard
+    # a decode token is written once per lane and tensor after each stage's
+    # layer loop (pp = 2 hops), whatever the number of layers
+    assert all(e[3] == {"S": e[3]["S"], "cache_writes": e[3]["S"] * 2 * 2}
+               for e in calls)
     for name in ("stage_in", "dispatch", "collect"):
         assert len(inside(ev, "bf:engine." + name,
                           "bf:engine.decode_call")) == guard, name

@@ -593,6 +593,82 @@ def test_engine_programs_in_place_on_v5e(v5e_chip, program, store, fast):
         assert mem.temp_size_in_bytes < cc.bytes() // cc.layers
 
 
+@pytest.fixture(scope="module")
+def v5e_decode(v5e_chip):
+    """The cell's decode program compiled for the described chip, once per
+    store for the module: ``(compiled text, memory analysis, cache
+    config)``."""
+    built = {}
+
+    def get(store):
+        if store not in built:
+            compiled, cc = _v5e_program(v5e_chip, "decode", store, False)
+            built[store] = (compiled.as_text(), compiled.memory_analysis(),
+                            cc)
+        return built[store]
+    return get
+
+
+def _loop_body(hlo_txt):
+    """The lines of the (one) ``while`` body of a compiled decode program
+    of one step a call: the layer loop."""
+    import re
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo_txt))
+    assert len(bodies) == 1, bodies
+    start = hlo_txt.index(f"%{bodies.pop()} (")
+    return hlo_txt[start:hlo_txt.index("\n}\n", start)].splitlines()
+
+
+@pytest.mark.parametrize("check", [
+    "no_write_in_the_loop", "one_write_per_lane_after_it", "aliased_whole",
+    "temporaries", "no_half_tensor", "rows_staged_on_chip"])
+@pytest.mark.parametrize("store", [
+    "raw", pytest.param("int8", marks=_SLOW)])
+def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
+    """The decode program at the serving cell's sizes, as the chip's own
+    compiler builds it: the layer loop's body updates no cache-shaped
+    buffer, and after it comes exactly one ``dynamic-update-slice`` per
+    lane and tensor (32 x 2; x 4 with the scales of a quantized store),
+    where the per-layer append made 24 times that in the loop.  The
+    writes did not come back under another name: the donated cache is
+    aliased whole, all temporaries together stay under one layer's pages
+    (every lane's update window laid out at once, each padded from one
+    position to the 128 of a tile, was 280 MB), nothing materialises half
+    a K tensor, and each tensor's staged rows (32 lanes x 2 MB, built by
+    one row read per lane) have their turn in the chip's on-chip memory
+    (``S(1)`` in the layout; V's are read once K's are dead), not a
+    round trip through HBM per layer."""
+    from bluefog_tpu.utils.hlo_bytes import loop_writes, materialized
+    txt, mem, cc = v5e_decode(store)
+    lanes = 32
+    scales = cc.layers * cc.rows * cc.kv_heads * cc.max_len
+    pages = scales * cc.head_dim
+    inside = outside = 0
+    for elements in (pages,) + ((scales,) if cc.quantized else ()):
+        i, o = loop_writes(txt, elements)
+        inside, outside = inside + i, outside + o
+    if check == "no_write_in_the_loop":
+        assert inside == 0
+    elif check == "one_write_per_lane_after_it":
+        assert outside == lanes * (4 if cc.quantized else 2)
+    elif check == "aliased_whole":
+        assert mem.alias_size_in_bytes == cc.bytes()
+    elif check == "temporaries":
+        assert mem.temp_size_in_bytes < cc.bytes() // cc.layers
+    elif check == "no_half_tensor":
+        k_bytes = pages * jnp.dtype(store_dtype(store, cc.dtype)).itemsize
+        assert materialized(txt, k_bytes // 2) == []
+    else:
+        staged = f"[{lanes},{cc.kv_heads},{cc.max_len},{cc.head_dim}]"
+        rows = [ln for ln in _loop_body(txt)
+                if ln.split(" = ")[-1].startswith(
+                    (f"bf16{staged}", f"s8{staged}"))]
+        assert len(rows) >= 2 * lanes, len(rows)
+        off_chip = [ln.split(" = ")[0].strip() for ln in rows
+                    if "S(1)" not in ln.split(" = ")[1].split(" ")[0]]
+        assert off_chip == []
+
+
 def test_cache_copy_gauge_set_at_warmup(cpu_devices):
     """bluefog_serve_cache_copy_bytes{program} / ..._alias_bytes{program}
     carry what ``program_memory`` holds, one series per warmed program,
@@ -607,10 +683,17 @@ def test_cache_copy_gauge_set_at_warmup(cpu_devices):
                            "prefill Tpad=8"]
     copy = bfm.get_metric("bluefog_serve_cache_copy_bytes")
     alias = bfm.get_metric("bluefog_serve_cache_alias_bytes")
+    writes = bfm.get_metric("bluefog_serve_cache_writes_per_call")
     for name, row in mem.items():
         assert copy.value(program=name) == row["temp_bytes"]
         assert alias.value(program=name) == row["alias_bytes"] \
             == eng.cache_cfg.bytes()
+        assert writes.value(program=name) == row["cache_writes"]
+    # lanes x 2 tensors once after the layer loop for a decode token; a
+    # prefill writes its one row once per layer (4) and tensor
+    assert {k: v["cache_writes"] for k, v in mem.items()} == {
+        "decode S=1": 2, "decode S=2": 4, "prefill Tpad=4": 8,
+        "prefill Tpad=8": 8}
     eng.prefill(0, 0, [5, 6, 7])
     assert eng.program_memory() == mem
     assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
@@ -638,6 +721,61 @@ def test_pp2_tokens_equal_pp1(cpu_devices):
         out[pp] = ([r.generated for r in _drain(eng, prompts)], logits)
     assert out[1][0] == out[2][0]
     np.testing.assert_array_equal(out[1][1], out[2][1])
+
+
+class _AppendPerLayer(ServeEngine):
+    """The engine with a decode token written into every layer's pages
+    inside the layer loop, before that layer's XLA attention reads them:
+    the form the write after the loop replaces (and what the Pallas path
+    still does), kept as the reference."""
+    _defer_appends = False
+
+
+_DEFER_ENGINES = {
+    # name: ((dp, pp, tp), ServeConfig overrides, cache writes of one
+    # 2-lane decode call: deferred, per layer)
+    "dp2_pp2_tp2": ((2, 2, 2), {}, (2 * 2 * 2, 2 * 2 * 4)),
+    "int8": ((1, 1, 1), dict(kv_dtype="int8"), (2 * 4, 2 * 4 * 4)),
+    "spec_prefix_pp2": ((1, 2, 1), dict(
+        spec_decode=2, spec_stages=1, prefix_pages=2,
+        prefix_page_tokens=4), (2 * 2 * 2, 2 * 2 * 4)),
+    "two_tokens_a_call": ((1, 1, 1), dict(decode_steps_per_call=2),
+                          (2 * 2 * 2, 2 * 2 * 4 * 2)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_DEFER_ENGINES))
+def test_write_after_loop_engine_equals_per_layer_engine(cpu_devices, name):
+    """Engine against engine, differing only in when a decode token
+    reaches the cache: the same tokens for every request, the same cache
+    in every row a request can read, no retrace, and ``cache_writes`` as
+    the shapes count them (lanes x tensors x stage hops x fused steps,
+    times the stage's layers when the write is in the loop)."""
+    (dp, pp, tp), extra, counted = _DEFER_ENGINES[name]
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(dp, pp, tp, 1,
+                                    devices=cpu_devices[:dp * pp * tp])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    rng = np.random.default_rng(11)
+    shared = [3, 1, 4, 1]                        # one page (page_tokens=4)
+    prompts = [rng.integers(0, _CFG["vocab"], int(n)).tolist()
+               for n in (3, 7, 5, 8, 4)] + [shared + [5, 9, 2],
+                                            shared + [6, 5, 3, 5]]
+    out = []
+    for cls in (ServeEngine, _AppendPerLayer):
+        eng = cls(m, cfg, params, ServeConfig(**{**_SCFG, **extra}))
+        eng.warmup()
+        toks = [r.generated for r in _drain(eng, prompts, max_new=7)]
+        cache = {k: np.asarray(v.astype(jnp.float32))[
+            :, :, :eng.cache_cfg.trash_slot] for k, v in eng.cache.items()}
+        out.append((toks, cache,
+                    eng.program_memory()["decode S=2"]["cache_writes"]))
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+    (toks, cache, writes), (ref_toks, ref_cache, ref_writes) = out
+    assert toks == ref_toks
+    assert (writes, ref_writes) == counted
+    for k in ref_cache:
+        np.testing.assert_array_equal(cache[k], ref_cache[k])
 
 
 @pytest.fixture(scope="module")

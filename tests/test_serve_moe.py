@@ -392,6 +392,46 @@ def test_moe_spec_decode_bit_identical(cpu_devices, moe_engine):
     assert drafted > 0
 
 
+@pytest.mark.parametrize("spec", [0, 2])
+def test_moe_write_after_loop_equals_per_layer_append(cpu_devices, spec):
+    """MoE serving (the routed carrier in the layer loop; with ``spec``
+    the expert-mean draft and its verify too) with a decode token written
+    once per lane and tensor after the layer loop, against the same
+    engine writing it into every layer inside the loop: the same tokens,
+    the same routing load, no retrace, ``cache_writes`` as counted."""
+    class AppendPerLayer(ServeEngine):
+        _defer_appends = False
+
+    cfg = _moe_cfg()
+    m = compose.compose_parallelism(2, 1, 1, 1, 2, num_experts=E,
+                                    devices=cpu_devices[:4])
+    params = init_moe_params(cfg, m, seed=5)
+    extra = dict(spec_decode=spec, spec_stages=1) if spec else {}
+    prompts = [[1, 2, 3], [9, 8, 7, 6, 5], [4, 4], [2, 7, 1, 8, 2, 8]]
+    out = []
+    for cls in (ServeEngine, AppendPerLayer):
+        eng = cls(m, cfg, params, ServeConfig(**_SCFG, **extra))
+        eng.warmup()
+        s = Scheduler(eng)
+        reqs = [s.submit(p, max_new_tokens=6) for p in prompts]
+        s.drain()
+        s.close()
+        mem = eng.program_memory()
+        out.append(([r.generated for r in reqs],
+                    [r["counts"].tolist() for r in eng.moe_load()],
+                    {k: v["cache_writes"] for k, v in mem.items()
+                     if k.startswith(("decode", "draft"))}))
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+    (toks, load, writes), (ref_toks, ref_load, ref_writes) = out
+    assert toks == ref_toks and load == ref_load
+    k = spec or 1                    # a draft call fuses k one-token steps
+    want = {"decode S=1": 2, "decode S=2": 4}
+    if spec:
+        want.update({"draft S=1": 2 * k, "draft S=2": 4 * k})
+    assert writes == want
+    assert ref_writes == {name: n * cfg.layers for name, n in want.items()}
+
+
 def test_refresher_pulls_expert_tables(cpu_devices, moe_engine):
     """The pull-only refresher moves router + expert-table leaves from a
     same-layout training carving — serve tables become bit-identical to

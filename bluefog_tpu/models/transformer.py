@@ -15,69 +15,7 @@ import jax.numpy as jnp
 
 from ..ops import local_flash_attention, ring_attention, ulysses_attention
 from ..ops.ulysses import dense_attention
-
-
-def apply_rope(x: jax.Array, positions: jax.Array,
-               base: float = 10000.0) -> jax.Array:
-    """Rotary position embedding on ``[B, T, H, D]`` with per-token global
-    ``positions`` ([T] int).  Rotation is per-token, so it commutes with any
-    sequence sharding — each device rotates its own q/k by its own global
-    positions and ring/zigzag/ulysses attention stays exact."""
-    d = x.shape[-1]
-    if d % 2:
-        raise ValueError(f"rope needs an even head_dim, got {d}: the "
-                         "rotation pairs channel i with channel i + d//2")
-    half = d // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * freqs[None]     # [T, half]
-    cos = jnp.cos(ang)[None, :, None, :]
-    sin = jnp.sin(ang)[None, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
-
-
-def apply_rope_grid(x: jax.Array, positions: jax.Array,
-                    base: float = 10000.0) -> jax.Array:
-    """Rotary position embedding on ``[S, T, H, D]`` with a PER-ROW grid of
-    ``positions`` ([S, T] int) — the k-token verify forward and chunked
-    prefill, where each batched request's T-token chunk starts at its own
-    sequence offset.  Same channel pairing and f32 internals as
-    :func:`apply_rope`, so a token roped here matches the one roped during
-    prefill or single-token decode bit-for-bit."""
-    d = x.shape[-1]
-    if d % 2:
-        raise ValueError(f"rope needs an even head_dim, got {d}: the "
-                         "rotation pairs channel i with channel i + d//2")
-    half = d // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[..., None] * freqs   # [S, T, half]
-    cos = jnp.cos(ang)[:, :, None, :]
-    sin = jnp.sin(ang)[:, :, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
-
-
-def apply_rope_rows(x: jax.Array, positions: jax.Array,
-                    base: float = 10000.0) -> jax.Array:
-    """Rotary position embedding on ``[B, H, D]`` with PER-ROW ``positions``
-    ([B] int) — the decode hot path, where each batched request sits at its
-    own sequence offset.  Same channel pairing and f32 internals as
-    :func:`apply_rope`, so a token roped here matches the one roped during
-    prefill bit-for-bit."""
-    d = x.shape[-1]
-    if d % 2:
-        raise ValueError(f"rope needs an even head_dim, got {d}: the "
-                         "rotation pairs channel i with channel i + d//2")
-    half = d // 2
-    freqs = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None] * freqs[None]     # [B, half]
-    cos = jnp.cos(ang)[:, None, :]
-    sin = jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :half].astype(jnp.float32), x[..., half:].astype(jnp.float32)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1).astype(x.dtype)
+from . import decoder
 
 
 def init_decode_cache(model: "RingTransformerLM", batch: int, max_len: int,
@@ -138,8 +76,8 @@ class RingTransformerBlock(nn.Module):
         if self.rope:
             if positions is None:
                 raise ValueError("rope needs the tokens' global positions")
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
+            q = decoder.rope(q, positions)
+            k = decoder.rope(k, positions)
         if cache is not None:
             # decode step: append this chunk's compact kv at pos_offset
             # (= positions[0]) and attend over everything written so far.

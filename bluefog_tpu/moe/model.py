@@ -52,7 +52,8 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ..parallel.compose import AXES, LMConfig, Mesh3D, _ln
+from ..models import decoder
+from ..parallel.compose import AXES, LMConfig, Mesh3D
 from ..parallel.pipeline import pipeline_apply
 from .layers import (moe_ffn_dense, moe_ffn_dense_ec, moe_ffn_dropless,
                      moe_ffn_expert_choice, moe_ffn_routed)
@@ -190,21 +191,25 @@ class MoELMConfig(LMConfig):
         return max(1, math.ceil(
             self.top_k * (self.seq_len // m.sp) / self.num_experts))
 
+    def _n_params(self, experts: int) -> int:
+        """Dense count with ``experts`` expert FFNs a block: the dense
+        block's attention leaves, the router, that many dense FFNs."""
+        per_block = (
+            decoder.block_param_count(self, decoder.ATTENTION_LEAVES)
+            + self.d_model * self.num_experts
+            + experts * decoder.block_param_count(self, decoder.FFN_LEAVES))
+        return self.layers * per_block + 2 * self.vocab * self.d_model
+
     @property
     def n_params(self) -> int:
         """Dense (un-sharded) parameter count, ALL experts included."""
-        D, F, E = self.d_model, self.ffn_mult * self.d_model, self.num_experts
-        per_block = D * 3 * D + D * D + D * E + E * (D * F + F * D)
-        return self.layers * per_block + 2 * self.vocab * D
+        return self._n_params(self.num_experts)
 
     @property
     def n_active_params(self) -> int:
         """Parameters a single token activates (top-k experts only) —
         the N in the MFU accounting."""
-        D, F, E = self.d_model, self.ffn_mult * self.d_model, self.num_experts
-        per_block = (D * 3 * D + D * D + D * E
-                     + self.top_k * (D * F + F * D))
-        return self.layers * per_block + 2 * self.vocab * D
+        return self._n_params(self.top_k)
 
     def flops_per_token(self) -> float:
         return (6.0 * self.n_active_params
@@ -245,10 +250,9 @@ def init_moe_params(cfg: MoELMConfig, m: Mesh3D, seed: int = 0,
     def w(*shape, scale=0.1):
         return (rng.normal(size=shape) * scale).astype(dtype)
 
-    blocks = {                              # [pp, tp, Lps, ...] owners
-        "wqkv": w(m.pp, TP, Lps, D, 3 * D // TP),
-        "wo":   w(m.pp, TP, Lps, D // TP, D),
-    }
+    shapes = decoder.block_param_shapes(cfg, TP)
+    blocks = {k: w(m.pp, TP, Lps, *shapes[k])   # [pp, tp, Lps, ...] owners
+              for k in decoder.ATTENTION_LEAVES}
     wr_full = w(m.pp, Lps, D, E)            # [pp, Lps, D, E]
     w1_full = w(m.pp, Lps, E, D, F)
     w2_full = w(m.pp, Lps, E, F, D)
@@ -312,34 +316,24 @@ def _make_forward(cfg: MoELMConfig, m: Mesh3D, *, remat: bool,
     cfg.validate(m)
     import optax
 
-    from ..models.transformer import apply_rope
     from ..ops.ulysses import ulysses_attention
 
-    D, H, E = cfg.d_model, cfg.heads, cfg.num_experts
-    Hl, hsz = H // m.tp, D // H
+    D, E = cfg.d_model, cfg.num_experts
     Tl, Bl = cfg.seq_len // m.sp, cfg.batch // m.ep
-    TP = m.tp
     cap, k = cfg.capacity(m), cfg.top_k
     n_ch = _CH_FIXED + E
 
-    def attn_sublayer(lp, x, positions):
-        h = _ln(x)
-        qkv = h @ lp["wqkv"]                        # [Bl, Tl, 3*D/TP]
-        q, kk, v = jnp.split(qkv, 3, axis=-1)
-        q = apply_rope(q.reshape(Bl, Tl, Hl, hsz), positions)
-        kk = apply_rope(kk.reshape(Bl, Tl, Hl, hsz), positions)
-        v = v.reshape(Bl, Tl, Hl, hsz)
-        att = ulysses_attention(q, kk, v, axis="sp", causal=True,
-                                pallas_block_q=min(512, cfg.seq_len))
-        return x + lax.psum(att.reshape(Bl, Tl, D // TP) @ lp["wo"], "tp")
+    def attend(q, kk, v):                           # [Bl, Tl, H/TP, Dh]
+        return ulysses_attention(q, kk, v, axis="sp", causal=True,
+                                 pallas_block_q=min(512, cfg.seq_len)), None
 
     ec = cfg.router_mode == "expert_choice"
     ecC = cfg.ec_capacity(m) if ec else 0
     dropless = cfg.dispatch == "dropless"
 
-    def moe_block(lp, rp, xp, x, positions):
-        x = attn_sublayer(lp, x, positions)
-        h3 = _ln(x)                                 # [Bl, Tl, D]
+    def moe_ffn(rp, xp, h3):
+        """The block's ``ffn`` hook on the normed ``h3`` ``[Bl, Tl, D]``:
+        ``(y3, this layer's carrier-channel vector)``."""
         h = h3.reshape(Bl * Tl, D)
         if ec and dense_equiv:
             y3, st = moe_ffn_dense_ec(h3, rp["wr"], xp["w1"], xp["w2"],
@@ -362,23 +356,26 @@ def _make_forward(cfg: MoELMConfig, m: Mesh3D, *, remat: bool,
                                    num_experts=E, top_k=k, capacity=cap,
                                    axis="expert")
             y3 = y.reshape(Bl, Tl, D)
-        vec = jnp.zeros((n_ch,), x.dtype)
+        vec = jnp.zeros((n_ch,), h3.dtype)
         vec = vec.at[0].set(st["aux"]).at[1].set(st["z"])
         vec = vec.at[2].set(lax.stop_gradient(st["dropped"]))
         vec = vec.at[3].set(lax.stop_gradient(st["entropy"]))
         if "coverage" in st:
             vec = vec.at[4].set(lax.stop_gradient(
-                st["coverage"].astype(x.dtype)))
+                st["coverage"].astype(h3.dtype)))
         vec = vec.at[_CH_FIXED:].set(lax.stop_gradient(
-            st["usage"].astype(x.dtype)))
-        return x + y3, vec
+            st["usage"].astype(h3.dtype)))
+        return y3, vec
 
     def stage_fn(sp_params, x):                     # x [Bl+1, Tl, D]
         data, row = x[:Bl], x[Bl:]
         positions = lax.axis_index("sp") * Tl + jnp.arange(Tl)
         def body(c, layer_params):
             lp, rp, xp = layer_params
-            return moe_block(lp, rp, xp, c, positions)
+            c, _, vec = decoder.decoder_block(
+                cfg, m.tp, lp, c, positions, attend,
+                lambda _, h3: moe_ffn(rp, xp, h3))
+            return c, vec
         data, vecs = lax.scan(body, data, sp_params)  # vecs [Lps, n_ch]
         row = row + jnp.zeros_like(row).at[0, 0, :n_ch].set(vecs.sum(0))
         return jnp.concatenate([data, row], axis=0)
@@ -392,7 +389,7 @@ def _make_forward(cfg: MoELMConfig, m: Mesh3D, *, remat: bool,
             axis="stage", remat=remat)
         data = out[:, :Bl]
         channels = out[:, Bl, 0, :n_ch].mean(0)     # mean over microbatches
-        logits = _ln(data) @ q["shared"]["head"]
+        logits = decoder.lm_logits(q["shared"], data)
         targets = jnp.roll(toks, cfg.lag, axis=-1)
         ce = optax.softmax_cross_entropy_with_integer_labels(
             logits[:, :, cfg.lag:], targets[:, :, cfg.lag:]).mean()

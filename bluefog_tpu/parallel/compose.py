@@ -52,6 +52,7 @@ from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from .. import topology as topo_util
+from ..models import decoder
 from ..schedule import CommSchedule, compile_topology
 from . import context as _ctx
 from .pipeline import pipeline_apply
@@ -364,9 +365,8 @@ class LMConfig:
     @property
     def n_params(self) -> int:
         """Dense (un-sharded) parameter count."""
-        D, F = self.d_model, self.ffn_mult * self.d_model
-        per_block = D * 3 * D + D * D + D * F + F * D
-        return self.layers * per_block + 2 * self.vocab * D
+        return (self.layers * decoder.block_param_count(self)
+                + 2 * self.vocab * self.d_model)
 
     def flops_per_token(self) -> float:
         """Training FLOPs/token: 6N weight term + attention score/value
@@ -433,13 +433,11 @@ def draft_carve(m: Mesh3D, cfg: LMConfig, stages: int) -> DraftCarve:
     cfg.validate(m)
     Lps = cfg.layers // m.pp
     draft_layers = stages * Lps
-    D, F = cfg.d_model, cfg.ffn_mult * cfg.d_model
-    per_block = D * 3 * D + D * D + D * F + F * D
-    shared = 2 * cfg.vocab * D
     return DraftCarve(
         stages=stages, pp=m.pp, layers=draft_layers,
         total_layers=cfg.layers,
-        n_params=draft_layers * per_block + shared,
+        n_params=(draft_layers * decoder.block_param_count(cfg)
+                  + 2 * cfg.vocab * cfg.d_model),
         target_params=cfg.n_params)
 
 
@@ -451,18 +449,13 @@ def init_lm_params(cfg: LMConfig, m: Mesh3D, seed: int = 0) -> Any:
     embed/head."""
     cfg.validate(m)
     rng = np.random.default_rng(seed)
-    D, F = cfg.d_model, cfg.ffn_mult * cfg.d_model
-    Lps, TP = cfg.layers // m.pp, m.tp
+    D, Lps, TP = cfg.d_model, cfg.layers // m.pp, m.tp
 
     def w(*shape, scale=0.1):
         return (rng.normal(size=shape) * scale).astype(np.float32)
 
-    blocks = {                              # [pp, tp, Lps, ...] owners
-        "wqkv": w(m.pp, TP, Lps, D, 3 * D // TP),
-        "wo":   w(m.pp, TP, Lps, D // TP, D),
-        "w1":   w(m.pp, TP, Lps, D, F // TP),
-        "w2":   w(m.pp, TP, Lps, F // TP, D),
-    }
+    blocks = {k: w(m.pp, TP, Lps, *shape)   # [pp, tp, Lps, ...] owners
+              for k, shape in decoder.block_param_shapes(cfg, TP).items()}
     shared = {"embed": w(cfg.vocab, D), "head": w(D, cfg.vocab)}
 
     # flat device i = (((r*pp + s)*tp + t)*sp + u)*ep + e
@@ -494,11 +487,6 @@ def make_lm_batch(cfg: LMConfig, m: Mesh3D, seed: int = 0,
     return jnp.asarray(per_dev)
 
 
-def _ln(z):
-    mu = z.mean(-1, keepdims=True)
-    return (z - mu) / jnp.sqrt(z.var(-1, keepdims=True) + 1e-6)
-
-
 def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
                     use_pallas: bool = False):
     """Per-device ``grad_fn(params, toks) -> (loss, grads)`` for the
@@ -517,34 +505,22 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
     cfg.validate(m)
     import optax
 
-    from ..models.transformer import apply_rope
     from ..ops.ulysses import ulysses_attention
 
-    D, H = cfg.d_model, cfg.heads
-    Hl, hsz = H // m.tp, D // H
     Tl = cfg.seq_len // m.sp
-    B, S, TP = cfg.batch, m.pp, m.tp
+    S, TP = m.pp, m.tp
 
-    def layer_fn(lp, x, positions):
-        h = _ln(x)
-        qkv = h @ lp["wqkv"]                        # [B, Tl, 3*D/TP]
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = apply_rope(q.reshape(B, Tl, Hl, hsz), positions)
-        k = apply_rope(k.reshape(B, Tl, Hl, hsz), positions)
-        v = v.reshape(B, Tl, Hl, hsz)
-        att = ulysses_attention(q, k, v, axis="sp", causal=True,
-                                use_pallas=use_pallas,
-                                pallas_block_q=min(512, cfg.seq_len))
-        x = x + lax.psum(att.reshape(B, Tl, D // TP) @ lp["wo"], "tp")
-        h = _ln(x)
-        return x + lax.psum(jax.nn.gelu(h @ lp["w1"]) @ lp["w2"], "tp")
+    def attend(q, k, v):                            # [B, Tl, H/TP, Dh]
+        return ulysses_attention(q, k, v, axis="sp", causal=True,
+                                 use_pallas=use_pallas,
+                                 pallas_block_q=min(512, cfg.seq_len)), None
 
     def stage_fn(bp, x):
         # global rope positions: each sp shard rotates by its own offset,
         # so ulysses' gathered sequence is position-consistent
         positions = lax.axis_index("sp") * Tl + jnp.arange(Tl)
-        y, _ = lax.scan(lambda c, lp: (layer_fn(lp, c, positions), None),
-                        x, bp)
+        y, _ = lax.scan(lambda c, lp: (decoder.decoder_block(
+            cfg, TP, lp, c, positions, attend)[0], None), x, bp)
         return y
 
     def grad_fn(params, toks):
@@ -554,7 +530,7 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
             x = q["shared"]["embed"][toks]          # [M, B, Tl, D]
             out = pipeline_apply(stage_fn, q["blocks"], x, axis="stage",
                                  remat=remat)
-            logits = _ln(out) @ q["shared"]["head"]
+            logits = decoder.lm_logits(q["shared"], out)
             targets = jnp.roll(toks, cfg.lag, axis=-1)
             loss = optax.softmax_cross_entropy_with_integer_labels(
                 logits[:, :, cfg.lag:], targets[:, :, cfg.lag:]).mean()

@@ -70,13 +70,13 @@ from jax import lax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from ..models.transformer import apply_rope, apply_rope_grid, apply_rope_rows
+from ..models import decoder
 from ..moe.dropless import decode_tile
 from ..moe.layers import moe_dropless_combine, router_topk
 from ..moe.model import MoELMConfig
 from ..ops import pallas_decode as _pd
 from ..ops.ulysses import dense_attention
-from ..parallel.compose import AXES, LMConfig, Mesh3D, _ln, draft_carve
+from ..parallel.compose import AXES, LMConfig, Mesh3D, draft_carve
 from ..utils import flight as _flight
 from ..utils import metrics as _metrics
 from ..utils import tracing as _tracing
@@ -554,42 +554,44 @@ class ServeEngine:
 
         return jax.vmap(one)(logits, keys)
 
-    def _ffn(self, lp, x, *, tile=None, draft=False):
-        """The post-attention FFN sublayer on ``[..., D]`` activations.
+    def _ffn(self, *, chunk=False, draft=False):
+        """The block's ``ffn`` hook (:func:`decoder.decoder_block`): normed
+        post-attention activation ``[..., D]`` in, ``(y, routing)`` out.
 
         Dense models run the reference two-matmul gelu FFN.  MoE models
         route through the dropless grouped-GEMM path (top-k router →
-        sort-based dispatch → grouped GEMM → combine); ``tile`` is the
-        grouped tile (the small decode tile on the hot path, the training
-        tile for prefill/verify shapes).  ``draft=True`` is the
-        spec-decode draft: the expert-MEAN dense FFN (one matmul pair at
-        active-param cost, no dispatch) — causally safe because the
-        verify chunk overwrites every drafted KV row and the accept rule
-        only ever emits target-argmax tokens, so draft quality affects
-        throughput, never the stream.
-
-        Returns ``(x, routing)`` — ``routing`` is ``(probs, idx)`` from
-        the router on the routed path (for hot-expert accounting), else
-        ``None``.
+        sort-based dispatch → grouped GEMM → combine) with the small
+        decode tile on the hot path, the training tile for prefill/verify
+        shapes (``chunk=True``).  ``draft=True`` is the spec-decode draft:
+        the expert-MEAN dense FFN (one matmul pair at active-param cost,
+        no dispatch) — causally safe because the verify chunk overwrites
+        every drafted KV row and the accept rule only ever emits
+        target-argmax tokens, so draft quality affects throughput, never
+        the stream.  ``routing`` is the router's ``(probs, idx)`` on the
+        routed path (for hot-expert accounting), else ``None``.
         """
-        h = _ln(x)
         if not self._moe:
-            return x + lax.psum(jax.nn.gelu(h @ lp["w1"]) @ lp["w2"],
-                                "tp"), None
+            return decoder.dense_ffn
         E = self.cfg.num_experts
-        shp = x.shape
-        hf = h.reshape(-1, shp[-1])
-        if draft:
+
+        def mean_ffn(lp, h):
+            hf = h.reshape(-1, h.shape[-1])
             w1d = lax.psum(jnp.sum(lp["w1e"], axis=0), "expert") / E
             w2d = lax.psum(jnp.sum(lp["w2e"], axis=0), "expert") / E
             y = lax.psum(jax.nn.gelu(hf @ w1d) @ w2d, "tp")
-            return x + y.reshape(shp), None
-        logits, probs, idx, gate = router_topk(hf, lp["wr"],
-                                               top_k=self.cfg.top_k)
-        y = moe_dropless_combine(
-            hf, idx, gate, lp["w1e"], lp["w2e"], num_experts=E,
-            axis="expert", tile=self._moe_tile if tile is None else tile)
-        return x + y.reshape(shp), (probs, idx)
+            return y.reshape(h.shape), None
+
+        def routed_ffn(lp, h):
+            hf = h.reshape(-1, h.shape[-1])
+            logits, probs, idx, gate = router_topk(hf, lp["wr"],
+                                                   top_k=self.cfg.top_k)
+            y = moe_dropless_combine(
+                hf, idx, gate, lp["w1e"], lp["w2e"], num_experts=E,
+                axis="expert",
+                tile=self._moe_chunk_tile if chunk else self._moe_tile)
+            return y.reshape(h.shape), (probs, idx)
+
+        return mean_ffn if draft else routed_ffn
 
     def _route_vec(self, routing, live):
         """Fold one layer's routing into the ``[E + 2]`` stats carrier:
@@ -646,37 +648,31 @@ class ServeEngine:
         Returns ``(x, cache, new, routing)``: ``new`` is the token's
         pages, still to be written (:meth:`_defer_appends`), or None once
         the cache holds them."""
-        cfg, m = self.cfg, self.m
-        Hl = cfg.heads // m.tp
-        hsz = cfg.d_model // cfg.heads
-        S = x.shape[0]
-        h = _ln(x)
-        q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
-        q = apply_rope_rows(q.reshape(S, Hl, hsz), lens)
-        k = apply_rope_rows(k.reshape(S, Hl, hsz), lens)
-        v = v.reshape(S, Hl, hsz)
-        if self._defer_appends:
-            new = _kv.token_pages(k, v, self.scfg.kv_dtype,
-                                  cache["k"].dtype)
-        else:
-            new = None
-            cache = _kv.layer_append(cache, layer, slot_ids, lens, k, v,
+        def attend(q, k, v):                            # [S, H/tp, Dh]
+            if self._defer_appends:
+                c, new = cache, _kv.token_pages(k, v, self.scfg.kv_dtype,
+                                                cache["k"].dtype)
+            else:
+                new = None
+                c = _kv.layer_append(cache, layer, slot_ids, lens, k, v,
                                      store=self.scfg.kv_dtype)
-        if self.scfg.decode_kernel == "pallas":
-            cl = self._layer_view(cache, layer)
-            att = _pd.flash_attend_rows(
-                q, cl["k"], cl["v"], slot_ids, lens,
-                k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
-                prefix_slots=prows, prefix_lens=plens,
-                block_k=self.scfg.decode_block_k)
-        else:
-            att = _kv.attend_rows(q, cache["k"], cache["v"], slot_ids, lens,
-                                  k_scale=cache.get("k_scale"),
-                                  v_scale=cache.get("v_scale"),
-                                  prefix_slots=prows, prefix_lens=plens,
-                                  layer=layer, new=new)
-        x = x + lax.psum(att.reshape(S, Hl * hsz) @ lp["wo"], "tp")
-        x, routing = self._ffn(lp, x, draft=draft)
+            if self.scfg.decode_kernel == "pallas":
+                cl = self._layer_view(c, layer)
+                att = _pd.flash_attend_rows(
+                    q, cl["k"], cl["v"], slot_ids, lens,
+                    k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
+                    prefix_slots=prows, prefix_lens=plens,
+                    block_k=self.scfg.decode_block_k)
+            else:
+                att = _kv.attend_rows(q, c["k"], c["v"], slot_ids, lens,
+                                      k_scale=c.get("k_scale"),
+                                      v_scale=c.get("v_scale"),
+                                      prefix_slots=prows, prefix_lens=plens,
+                                      layer=layer, new=new)
+            return att, (c, new)
+
+        x, (cache, new), routing = decoder.decoder_block(
+            self.cfg, self.m.tp, lp, x, lens, attend, self._ffn(draft=draft))
         return x, cache, new, routing
 
     def _pp_cycle(self, blocks, x, cache, one, n_stages=None, land=None):
@@ -733,8 +729,8 @@ class ServeEngine:
         hot-expert carrier on the routed MoE path (it rides the same
         keep/ppermute carrier as the activation, so each stage's layers
         fold in exactly once), else ``None``."""
-        embed = params["shared"]["embed"]
-        head = params["shared"]["head"]
+        shared = params["shared"]
+        embed = shared["embed"]
         bp = self._blocks_tree(params)
         draft = n_stages is not None
         out_stage = (self.m.pp if n_stages is None else n_stages) % self.m.pp
@@ -768,7 +764,8 @@ class ServeEngine:
                 st = lax.psum(jnp.where(sid == out_stage, acc, 0.0),
                               "stage")
             logits = lax.psum(
-                jnp.where(sid == out_stage, _ln(x) @ head, 0.0), "stage")
+                jnp.where(sid == out_stage, decoder.lm_logits(shared, x),
+                          0.0), "stage")
             if n_stages is None:
                 nxt, keys = self._next_token(logits, keys)
             else:
@@ -823,9 +820,6 @@ class ServeEngine:
         params, cache, toks, slot_ids, lens, prows, plens = \
             self._split_args((params, cache, toks, slot_ids, lens, prows,
                               plens))
-        cfg, m = self.cfg, self.m
-        Hl = cfg.heads // m.tp
-        hsz = cfg.d_model // cfg.heads
         S, T = toks.shape
         pos = lens[:, None] + jnp.arange(T)[None, :]          # [S, T]
         # chunk rows of live lanes all count toward the hot-expert stats
@@ -834,39 +828,36 @@ class ServeEngine:
         live = jnp.broadcast_to((slot_ids < self.scfg.slots)[:, None],
                                 (S, T)).reshape(S * T)
 
+        ffn = self._ffn(chunk=True)
+
         def one(lp, xc, c, layer):
             x, acc = xc
-            h = _ln(x)
-            q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
-            q = apply_rope_grid(q.reshape(S, T, Hl, hsz), pos)
-            k = apply_rope_grid(k.reshape(S, T, Hl, hsz), pos)
-            v = v.reshape(S, T, Hl, hsz)
-            c = _kv.layer_append_chunk(c, layer, slot_ids, lens, k, v,
-                                       store=self.scfg.kv_dtype)
-            if self.scfg.decode_kernel == "pallas":
-                att = _pd.flash_attend_chunk(
-                    q, self._layer_view(c, layer), slot_ids, lens,
-                    prefix_slots=prows, prefix_lens=plens,
-                    block_k=self.scfg.decode_block_k)
-            else:
-                att = _kv.attend_chunk(q, c, slot_ids, lens,
-                                       prefix_slots=prows,
-                                       prefix_lens=plens, layer=layer)
-            x = x + lax.psum(
-                att.reshape(S, T, Hl * hsz) @ lp["wo"], "tp")
-            x, routing = self._ffn(lp, x, tile=self._moe_chunk_tile
-                                   if self._moe else None)
+
+            def attend(q, k, v):                        # [S, T, H/tp, Dh]
+                nc = _kv.layer_append_chunk(c, layer, slot_ids, lens, k, v,
+                                            store=self.scfg.kv_dtype)
+                if self.scfg.decode_kernel == "pallas":
+                    return _pd.flash_attend_chunk(
+                        q, self._layer_view(nc, layer), slot_ids, lens,
+                        prefix_slots=prows, prefix_lens=plens,
+                        block_k=self.scfg.decode_block_k), nc
+                return _kv.attend_chunk(q, nc, slot_ids, lens,
+                                        prefix_slots=prows,
+                                        prefix_lens=plens, layer=layer), nc
+
+            x, c, routing = decoder.decoder_block(
+                self.cfg, self.m.tp, lp, x, pos, attend, ffn)
             if self._moe:
                 acc = acc + self._route_vec(routing, live)
             return (x, acc), c, None
 
-        st0 = jnp.zeros((cfg.num_experts + 2,) if self._moe else (),
+        st0 = jnp.zeros((self.cfg.num_experts + 2,) if self._moe else (),
                         jnp.float32)
         x = params["shared"]["embed"][toks]                   # [S, T, D]
         (x, st), cache, sid = self._pp_cycle(
             self._blocks_tree(params), (x, st0), cache, one)
         logits = lax.psum(
-            jnp.where(sid == 0, _ln(x) @ params["shared"]["head"], 0.0),
+            jnp.where(sid == 0, decoder.lm_logits(params["shared"], x), 0.0),
             "stage")                                          # [S, T, V]
         gen = jnp.argmax(logits, axis=-1).astype(toks.dtype)
         if self._moe:
@@ -877,36 +868,29 @@ class ServeEngine:
     def _prefill_body(self, params, cache, toks, slot_id, true_len):
         params, cache, toks, slot_id, true_len = \
             self._split_args((params, cache, toks, slot_id, true_len))
-        cfg, m = self.cfg, self.m
-        Hl = cfg.heads // m.tp
-        hsz = cfg.d_model // cfg.heads
-        Tpad = toks.shape[0]
-        positions = jnp.arange(Tpad)
+        positions = jnp.arange(toks.shape[0])
         x = params["shared"]["embed"][toks][None]             # [1, Tpad, D]
+        ffn = self._ffn(chunk=True)
 
         def one(lp, x, c, layer):
-            h = _ln(x)
-            q, k, v = jnp.split(h @ lp["wqkv"], 3, axis=-1)
-            q = apply_rope(q.reshape(1, Tpad, Hl, hsz), positions)
-            k = apply_rope(k.reshape(1, Tpad, Hl, hsz), positions)
-            v = v.reshape(1, Tpad, Hl, hsz)
-            # the whole padded prompt lands in the slot; positions past
-            # true_len hold garbage that decode's length mask never
-            # reads before the append overwrites it.  Attention over
-            # the prompt itself is dense full-precision — quantization
-            # drift only enters where a STORED page is read back
-            c = _kv.layer_prefill(c, layer, slot_id, k[0], v[0],
-                                  store=self.scfg.kv_dtype)
-            att = dense_attention(q, k, v, causal=True)
-            x = x + lax.psum(
-                att.reshape(1, Tpad, Hl * hsz) @ lp["wo"], "tp")
-            x, _ = self._ffn(lp, x, tile=self._moe_chunk_tile
-                             if self._moe else None)
+            def attend(q, k, v):                        # [1, Tpad, H/tp, Dh]
+                # the whole padded prompt lands in the slot; positions past
+                # true_len hold garbage that decode's length mask never
+                # reads before the append overwrites it.  Attention over
+                # the prompt itself is dense full-precision — quantization
+                # drift only enters where a STORED page is read back
+                nc = _kv.layer_prefill(c, layer, slot_id, k[0], v[0],
+                                       store=self.scfg.kv_dtype)
+                return dense_attention(q, k, v, causal=True), nc
+
+            x, c, _ = decoder.decoder_block(
+                self.cfg, self.m.tp, lp, x, positions, attend, ffn)
             return x, c, None
 
         x, cache, sid = self._pp_cycle(self._blocks_tree(params), x, cache,
                                        one)
-        logits = jnp.where(sid == 0, _ln(x[0]) @ params["shared"]["head"],
+        logits = jnp.where(sid == 0,
+                           decoder.lm_logits(params["shared"], x[0]),
                            0.0)                               # [Tpad, V]
         logits = lax.psum(logits, "stage")
         last = lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=0)[0]

@@ -240,14 +240,14 @@ class TestRope:
     def test_rope_scores_are_relative(self):
         """q.k after rotary rotation depends only on the position GAP:
         the same q/k pair at positions (5,3) and (105,103) score equally."""
-        from bluefog_tpu.models.transformer import apply_rope
+        from bluefog_tpu.models.decoder import rope
         rng = np.random.default_rng(0)
         q = jnp.asarray(rng.normal(size=(1, 1, 1, 8)), jnp.float32)
         k = jnp.asarray(rng.normal(size=(1, 1, 1, 8)), jnp.float32)
 
         def score(qpos, kpos):
-            qr = apply_rope(q, jnp.asarray([qpos]))
-            kr = apply_rope(k, jnp.asarray([kpos]))
+            qr = rope(q, jnp.asarray([qpos]))
+            kr = rope(k, jnp.asarray([kpos]))
             return float(jnp.sum(qr * kr))
 
         np.testing.assert_allclose(score(5, 3), score(105, 103), rtol=1e-5)
@@ -257,10 +257,10 @@ class TestRope:
     def test_rope_rejects_odd_head_dim(self):
         """The rotation pairs channel i with i + d//2; an odd head_dim has no
         valid pairing and must fail loudly, not with an opaque shape error."""
-        from bluefog_tpu.models.transformer import apply_rope
+        from bluefog_tpu.models.decoder import rope
         x = jnp.zeros((1, 2, 1, 7), jnp.float32)
         with pytest.raises(ValueError, match="even head_dim"):
-            apply_rope(x, jnp.arange(2))
+            rope(x, jnp.arange(2))
 
     def test_rope_lm_zigzag_matches_contiguous(self, cpu_devices):
         """RoPE composes with sequence sharding: per-token rotation by

@@ -247,11 +247,16 @@ def engine(cpu_devices):
     return eng
 
 
-def _ref_greedy(eng, prompt, steps):
-    """Greedy decode via plain numpy: per-tp-rank matmuls summed, dense
-    causal attention, full forward re-run per token."""
-    m, cfg = eng.m, eng.cfg
-    P = jax.tree.map(np.asarray, eng.params)
+def _np_ln(z):
+    mu = z.mean(-1, keepdims=True)
+    return (z - mu) / np.sqrt(z.var(-1, keepdims=True) + 1e-6)
+
+
+def _ref_forward(P, m, cfg, toks, ln=_np_ln):
+    """Logits ``[T, vocab]`` of the dense LM on ``toks`` via plain numpy:
+    per-tp-rank matmuls summed, dense causal attention (replica 0's rows
+    of the stacked tree ``P``; ``ln`` is the norm the model was built
+    with)."""
     Lps = cfg.layers // m.pp
     H, D = cfg.heads, cfg.d_model
     Hl, hsz = H // m.tp, D // H
@@ -267,47 +272,49 @@ def _ref_greedy(eng, prompt, steps):
         x1, x2 = x[..., :half], x[..., half:]
         return np.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
-    def ln(z):
-        mu = z.mean(-1, keepdims=True)
-        return (z - mu) / np.sqrt(z.var(-1, keepdims=True) + 1e-6)
+    toks = np.asarray(toks)
+    T = len(toks)
+    pos = np.arange(T)
+    x = P["shared"]["embed"][0][toks]
+    for l in range(cfg.layers):
+        st, li = l // Lps, l % Lps
+        h = ln(x)
+        delta = np.zeros_like(x)
+        for t in range(m.tp):
+            d = dev(st, t)
+            qkv = h @ P["blocks"]["wqkv"][d][li]
+            q, k, v = np.split(qkv, 3, -1)
+            q = rope(q.reshape(T, Hl, hsz), pos)
+            k = rope(k.reshape(T, Hl, hsz), pos)
+            v = v.reshape(T, Hl, hsz)
+            s = np.einsum("ihd,jhd->ihj", q * hsz ** -0.5, k)
+            mask = pos[:, None] >= pos[None, :]
+            s = np.where(mask[:, None, :], s, -np.inf)
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p = p / p.sum(-1, keepdims=True)
+            att = np.einsum("ihj,jhd->ihd", p, v).reshape(T, Hl * hsz)
+            delta += att @ P["blocks"]["wo"][d][li]
+        x = x + delta
+        h = ln(x)
+        delta = np.zeros_like(x)
+        for t in range(m.tp):
+            d = dev(st, t)
+            g = h @ P["blocks"]["w1"][d][li]
+            g = 0.5 * g * (1 + np.tanh(
+                np.sqrt(2 / np.pi) * (g + 0.044715 * g ** 3)))
+            delta += g @ P["blocks"]["w2"][d][li]
+        x = x + delta
+    return ln(x) @ P["shared"]["head"][0]
 
-    def forward(toks):
-        T = len(toks)
-        pos = np.arange(T)
-        x = P["shared"]["embed"][0][toks]
-        for l in range(cfg.layers):
-            st, li = l // Lps, l % Lps
-            h = ln(x)
-            delta = np.zeros_like(x)
-            for t in range(m.tp):
-                d = dev(st, t)
-                qkv = h @ P["blocks"]["wqkv"][d][li]
-                q, k, v = np.split(qkv, 3, -1)
-                q = rope(q.reshape(T, Hl, hsz), pos)
-                k = rope(k.reshape(T, Hl, hsz), pos)
-                v = v.reshape(T, Hl, hsz)
-                s = np.einsum("ihd,jhd->ihj", q * hsz ** -0.5, k)
-                mask = pos[:, None] >= pos[None, :]
-                s = np.where(mask[:, None, :], s, -np.inf)
-                p = np.exp(s - s.max(-1, keepdims=True))
-                p = p / p.sum(-1, keepdims=True)
-                att = np.einsum("ihj,jhd->ihd", p, v).reshape(T, Hl * hsz)
-                delta += att @ P["blocks"]["wo"][d][li]
-            x = x + delta
-            h = ln(x)
-            delta = np.zeros_like(x)
-            for t in range(m.tp):
-                d = dev(st, t)
-                g = h @ P["blocks"]["w1"][d][li]
-                g = 0.5 * g * (1 + np.tanh(
-                    np.sqrt(2 / np.pi) * (g + 0.044715 * g ** 3)))
-                delta += g @ P["blocks"]["w2"][d][li]
-            x = x + delta
-        return ln(x) @ P["shared"]["head"][0]
 
+def _ref_greedy(eng, prompt, steps, ln=_np_ln):
+    """Greedy decode through :func:`_ref_forward`: the full forward
+    re-run per token."""
+    P = jax.tree.map(np.asarray, eng.params)
     toks, out = list(prompt), []
     for _ in range(steps):
-        nxt = int(np.argmax(forward(np.array(toks))[-1]))
+        nxt = int(np.argmax(
+            _ref_forward(P, eng.m, eng.cfg, toks, ln)[-1]))
         out.append(nxt)
         toks.append(nxt)
     return out

@@ -24,8 +24,11 @@ What is pinned here:
 * **config surface** — ``_parse_buckets`` / ``from_env`` reject malformed
   ``BLUEFOG_SPEC_DECODE`` / ``BLUEFOG_KV_DTYPE`` / ``BLUEFOG_PREFIX_PAGES``
   specs naming the offending token and the expected grammar; the
-  greedy-only speculation rule; ``DraftCarve`` / ``apply_rope_grid``
-  units.
+  greedy-only speculation rule; ``DraftCarve`` / ``decoder.rope``
+  units;
+* **one block definition** — a swapped ``decoder.norm`` reaches every
+  program of the composed LM, and the block's parameter shapes are
+  counted in one place.
 """
 import dataclasses
 import json
@@ -39,7 +42,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bluefog_tpu.models.transformer import apply_rope_grid, apply_rope_rows
+from bluefog_tpu.models import decoder
 from bluefog_tpu.parallel import compose
 from bluefog_tpu.parallel.compose import draft_carve
 from bluefog_tpu.serve import Scheduler, ServeConfig, ServeEngine
@@ -339,17 +342,174 @@ def test_draft_carve(cpu_devices):
     assert "stages" in dc.describe()
 
 
-def test_apply_rope_grid_matches_rows():
+@pytest.mark.parametrize("rank", ["T", "S", "S,T"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_rope_position_ranks(rank, dtype):
+    """The one ``rope`` at each rank of positions the programs use — ``[T]``
+    against ``[B, T, H, Dh]`` (training, prefill), ``[S]`` against ``[S, H,
+    Dh]`` (decode), ``[S, T]`` against ``[S, T, H, Dh]`` (verify, chunked
+    prefill) — gives every token the bits the ``[S, T]`` grid gives it."""
     rng = np.random.default_rng(2)
-    x = jnp.asarray(rng.normal(size=(3, 5, 2, 8)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(3, 5, 2, 8)), dtype)
     pos = jnp.asarray(rng.integers(0, 30, (3, 5)), jnp.int32)
-    grid = apply_rope_grid(x, pos)
-    for t in range(5):                           # column t == rows at pos[:, t]
-        rows = apply_rope_rows(x[:, t], pos[:, t])
-        np.testing.assert_array_equal(np.asarray(grid[:, t]),
-                                      np.asarray(rows))
+    grid = np.asarray(decoder.rope(x, pos).astype(jnp.float32))
+    assert (grid != np.asarray(x.astype(jnp.float32))).any()
+    if rank == "T":                              # row s == [T] at pos[s]
+        got = [decoder.rope(x[s:s + 1], pos[s])[0] for s in range(3)]
+    elif rank == "S":                            # column t == [S] at pos[:, t]
+        got = jnp.stack([decoder.rope(x[:, t], pos[:, t])
+                         for t in range(5)], axis=1)
+    else:                                        # the grid, lanes flattened
+        got = decoder.rope(x.reshape(15, 2, 8), pos.reshape(15)).reshape(
+            x.shape)
+    np.testing.assert_array_equal(
+        np.asarray(jnp.asarray(got).astype(jnp.float32)), grid)
     with pytest.raises(ValueError, match="even head_dim"):
-        apply_rope_grid(x[..., :7], pos)
+        decoder.rope(x[..., :7], pos)
+
+
+def test_block_param_shapes_agree(cpu_devices):
+    """The block's parameter shapes are known in one place: what
+    ``init_lm_params`` and ``init_moe_params`` allocate, summed over the
+    owners, is what ``n_params`` says, and the draft's count is its
+    blocks' plus the shared leaves."""
+    from bluefog_tpu.moe import MoELMConfig, init_moe_params
+    cfg = compose.LMConfig(**_CFG)
+    D, F = cfg.d_model, cfg.ffn_mult * cfg.d_model
+    per_block = decoder.block_param_count(cfg)
+    assert per_block == D * 3 * D + D * D + D * F + F * D
+    assert list(decoder.block_param_shapes(cfg)) == list(
+        decoder.ATTENTION_LEAVES + decoder.FFN_LEAVES)
+    shared = 2 * cfg.vocab * D
+
+    def owned(tree, m, leaves):
+        """Elements over the (stage, tp, expert) owners of replica 0:
+        rows of one slice at sp index 0."""
+        rows = [i for i in range(m.slice_size)
+                if np.unravel_index(i, (m.pp, m.tp, m.sp, m.ep))[2] == 0]
+        return sum(int(np.prod(tree[g][k].shape[1:])) * len(rows)
+                   for g, k in leaves)
+
+    m = compose.compose_parallelism(1, 2, 2, 2, devices=cpu_devices)
+    params = compose.init_lm_params(cfg, m)
+    for k, shape in decoder.block_param_shapes(cfg, m.tp).items():
+        assert params["blocks"][k].shape == (
+            m.size, cfg.layers // m.pp) + shape
+    blocks = owned(params, m, [("blocks", k) for k in params["blocks"]])
+    assert blocks == cfg.layers * per_block
+    assert blocks + shared == cfg.n_params
+    for stages in (1, 2):
+        assert draft_carve(m, cfg, stages).n_params == (
+            stages * (cfg.layers // m.pp) * per_block + shared)
+
+    mm = compose.compose_parallelism(1, 2, 2, 1, 2, num_experts=4,
+                                     devices=cpu_devices)
+    mcfg = MoELMConfig(num_experts=4, top_k=2, **_CFG)
+    mp = init_moe_params(mcfg, mm)
+    # the router is replicated over tp and ep, the attention leaves over ep
+    attn = owned(mp, mm, [("blocks", k) for k in mp["blocks"]]) // mm.ep
+    router = owned(mp, mm, [("router", "wr")]) // (mm.tp * mm.ep)
+    experts = owned(mp, mm, [("experts", "w1"), ("experts", "w2")])
+    assert attn + router + experts + shared == mcfg.n_params
+    assert mcfg.n_params - mcfg.n_active_params == (
+        mcfg.layers * (mcfg.num_experts - mcfg.top_k)
+        * decoder.block_param_count(mcfg, decoder.FFN_LEAVES))
+
+
+def _rms(xp):
+    """A norm a model configuration might ask for in place of the default
+    (no mean subtraction, a per-channel scale), on ``xp`` = jnp for the
+    program, np for its oracle."""
+    return lambda z: (z / xp.sqrt((z * z).mean(-1, keepdims=True) + 1e-6)
+                      * (0.25 + xp.arange(z.shape[-1]) % 4))
+
+
+@pytest.mark.parametrize("program", ["train", "moe_train", "prefill",
+                                     "decode", "chunk"])
+def test_one_block_definition(cpu_devices, monkeypatch, program):
+    """A configuration's change is ONE edit: with ``decoder.norm`` swapped
+    before a program is built, each of the composed LM's five programs
+    follows its independent numpy oracle given the same norm, and leaves
+    the oracle of the norm it was built without."""
+    from test_serve import _np_ln, _ref_forward
+    from test_serve_moe import _ref_moe_forward
+    from jax.sharding import PartitionSpec as P
+    monkeypatch.setattr(decoder, "norm", _rms(jnp))
+    prompt = [5, 11, 2, 7, 19, 3]
+
+    def copy_loss(forward, rows, lag):          # the copy task's mean CE
+        def ce(toks):
+            lg = forward(toks)[lag:]
+            lg = lg - lg.max(-1, keepdims=True)
+            logp = lg - np.log(np.exp(lg).sum(-1, keepdims=True))
+            return -logp[np.arange(len(toks) - lag), toks[:-lag]]
+        return np.mean([ce(r) for r in rows])
+
+    if program == "train":
+        cfg = compose.LMConfig(micro=2, **_CFG)
+        m = compose.compose_parallelism(1, 2, 2, 1, devices=cpu_devices[:4])
+        params = compose.device_put(m, compose.init_lm_params(cfg, m, seed=3))
+        toks = compose.make_lm_batch(cfg, m, seed=1)
+        grad_fn = compose.make_lm_grad_fn(cfg, m)
+        loss = jax.jit(jax.shard_map(
+            lambda p, t: grad_fn(jax.tree.map(lambda v: v[0], p),
+                                 t[0])[0][None],
+            mesh=m.mesh, in_specs=P(compose.AXES), out_specs=P(compose.AXES),
+            check_vma=False))(params, toks)
+        got = float(np.asarray(loss)[0])
+        Pn = jax.tree.map(np.asarray, params)
+        rows = np.asarray(toks)[0].reshape(-1, cfg.seq_len)
+        oracle = lambda ln: copy_loss(
+            lambda t: _ref_forward(Pn, m, cfg, t, ln), rows, cfg.lag)
+    elif program == "moe_train":
+        from bluefog_tpu.moe import (MoELMConfig, init_moe_params,
+                                     make_moe_batch, make_moe_probe)
+        cfg = MoELMConfig(vocab=32, d_model=16, heads=4, layers=2,
+                          seq_len=16, micro=1, batch=2, num_experts=4,
+                          top_k=2, dispatch="dropless")
+        m = compose.compose_parallelism(1, 1, 1, 1, 2, num_experts=4,
+                                        devices=cpu_devices[:2])
+        params = compose.device_put(m, init_moe_params(cfg, m, seed=5))
+        batch = make_moe_batch(cfg, m, seed=1)   # [ep, micro, batch/ep, T]
+        got = make_moe_probe(cfg, m)(params, batch)["ce"]
+        Pn = jax.tree.map(np.asarray, params)
+        rows = np.asarray(batch).reshape(-1, cfg.seq_len)
+        oracle = lambda ln: copy_loss(
+            lambda t: _ref_moe_forward(Pn, m, cfg, t, ln), rows, cfg.lag)
+    else:
+        cfg = compose.LMConfig(**_CFG)
+        m = compose.compose_parallelism(1, 2, 2, 1, devices=cpu_devices[:4])
+        eng = ServeEngine(
+            m, cfg, compose.init_lm_params(cfg, m, seed=3),
+            ServeConfig(batch_buckets=(1,), prefill_buckets=(8,), slots=2,
+                        max_len=32, decode_steps_per_call=1,
+                        prefix_pages=int(program == "chunk"),
+                        prefix_page_tokens=4))
+        Pn = jax.tree.map(np.asarray, eng.params)
+        logits = lambda toks, ln: _ref_forward(Pn, m, cfg, toks, ln)
+        one = lambda v: np.full((1, 1), v, np.int32)
+        if program == "chunk":                  # every position's argmax
+            got = eng._chunk_call(np.asarray([[prompt]], np.int32), one(0),
+                                  one(0), None, None)[0, 0].tolist()
+            oracle = lambda ln: logits(prompt, ln).argmax(-1).tolist()
+        elif program == "prefill":              # the last position's logits
+            tok, got = eng.prefill(0, 0, prompt)
+            oracle = lambda ln: logits(prompt, ln)[-1]
+            assert tok == int(np.argmax(oracle(_rms(np))))
+        else:                                   # greedy tokens after it
+            toks = prompt + [eng.prefill(0, 0, prompt)[0]]
+            for _ in range(5):
+                toks.append(int(eng.decode(one(toks[-1]), one(0),
+                                           one(len(toks) - 1))[0, -1, 0]))
+            got = toks[-5:]
+            oracle = lambda ln: [int(np.argmax(logits(toks[:i], ln)[-1]))
+                                 for i in range(len(toks) - 5, len(toks))]
+    want, other = oracle(_rms(np)), oracle(_np_ln)
+    if isinstance(want, list):
+        assert got == want and got != other, (got, want, other)
+    else:
+        np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
+        assert np.abs(np.asarray(want) - np.asarray(other)).max() > 1e-2
 
 
 # ---------------------------------------------------------------------------

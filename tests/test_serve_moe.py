@@ -254,13 +254,17 @@ def moe_engine(cpu_devices):
     return eng
 
 
-def _ref_moe_greedy(eng, prompt, steps):
-    """Greedy decode via plain numpy: full forward per token, top-k
-    mixture FFN over the full expert table reassembled from the ep
-    peers' shards (replica 0; pp=tp=1)."""
-    m, cfg = eng.m, eng.cfg
-    Pt = jax.tree.map(np.asarray, eng.params)
-    H, D = cfg.heads, cfg.d_model
+def _np_ln(z):
+    mu = z.mean(-1, keepdims=True)
+    return (z - mu) / np.sqrt(z.var(-1, keepdims=True) + 1e-6)
+
+
+def _ref_moe_forward(Pt, m, cfg, toks, ln=_np_ln):
+    """Logits ``[T, vocab]`` of the MoE LM on ``toks`` via plain numpy:
+    top-k mixture FFN over the full expert table reassembled from the ep
+    peers' shards (replica 0 of the stacked tree ``Pt``; pp=tp=1; ``ln``
+    is the norm the model was built with)."""
+    H, D, E = cfg.heads, cfg.d_model, cfg.num_experts
     hsz = D // H
     k = cfg.top_k
     # replica 0's ep peers are device rows 0..ep-1 (slice-major layout)
@@ -278,10 +282,6 @@ def _ref_moe_greedy(eng, prompt, steps):
         x1, x2 = x[..., :half], x[..., half:]
         return np.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                               -1)
-
-    def ln(z):
-        mu = z.mean(-1, keepdims=True)
-        return (z - mu) / np.sqrt(z.var(-1, keepdims=True) + 1e-6)
 
     def gelu(g):
         return 0.5 * g * (1 + np.tanh(
@@ -304,30 +304,36 @@ def _ref_moe_greedy(eng, prompt, steps):
                         gelu(h[sel] @ w1[li, e]) @ w2[li, e])
         return y
 
-    def forward(toks):
-        T = len(toks)
-        pos = np.arange(T)
-        x = Pt["shared"]["embed"][0][toks]
-        for li in range(cfg.layers):
-            h = ln(x)
-            qkv = h @ Pt["blocks"]["wqkv"][0][li]
-            q, kk, v = np.split(qkv, 3, -1)
-            q = rope(q.reshape(T, H, hsz), pos)
-            kk = rope(kk.reshape(T, H, hsz), pos)
-            v = v.reshape(T, H, hsz)
-            s = np.einsum("ihd,jhd->ihj", q * hsz ** -0.5, kk)
-            mask = pos[:, None] >= pos[None, :]
-            s = np.where(mask[:, None, :], s, -np.inf)
-            p = np.exp(s - s.max(-1, keepdims=True))
-            p = p / p.sum(-1, keepdims=True)
-            att = np.einsum("ihj,jhd->ihd", p, v).reshape(T, D)
-            x = x + att @ Pt["blocks"]["wo"][0][li]
-            x = x + moe_ffn(ln(x), li)
-        return ln(x) @ Pt["shared"]["head"][0]
+    toks = np.asarray(toks)
+    T = len(toks)
+    pos = np.arange(T)
+    x = Pt["shared"]["embed"][0][toks]
+    for li in range(cfg.layers):
+        h = ln(x)
+        qkv = h @ Pt["blocks"]["wqkv"][0][li]
+        q, kk, v = np.split(qkv, 3, -1)
+        q = rope(q.reshape(T, H, hsz), pos)
+        kk = rope(kk.reshape(T, H, hsz), pos)
+        v = v.reshape(T, H, hsz)
+        s = np.einsum("ihd,jhd->ihj", q * hsz ** -0.5, kk)
+        mask = pos[:, None] >= pos[None, :]
+        s = np.where(mask[:, None, :], s, -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p = p / p.sum(-1, keepdims=True)
+        att = np.einsum("ihj,jhd->ihd", p, v).reshape(T, D)
+        x = x + att @ Pt["blocks"]["wo"][0][li]
+        x = x + moe_ffn(ln(x), li)
+    return ln(x) @ Pt["shared"]["head"][0]
 
+
+def _ref_moe_greedy(eng, prompt, steps):
+    """Greedy decode through :func:`_ref_moe_forward`: the full forward
+    re-run per token."""
+    Pt = jax.tree.map(np.asarray, eng.params)
     toks, out = list(prompt), []
     for _ in range(steps):
-        nxt = int(np.argmax(forward(np.array(toks))[-1]))
+        nxt = int(np.argmax(
+            _ref_moe_forward(Pt, eng.m, eng.cfg, toks)[-1]))
         out.append(nxt)
         toks.append(nxt)
     return out

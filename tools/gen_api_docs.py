@@ -47,6 +47,8 @@ MODULES = [
     ("bluefog_tpu.data", "Sharded input pipeline"),
     ("bluefog_tpu.fusion", "Tensor fusion (per-dtype bucketing)"),
     ("bluefog_tpu.models", "Model zoo"),
+    ("bluefog_tpu.models.decoder",
+     "The composed LM's decoder block (norm, rope, block, shapes)"),
     ("bluefog_tpu.run.launcher", "bfrun-tpu launcher"),
     ("bluefog_tpu.run.interactive", "Interactive multi-host mode"),
     ("bluefog_tpu.utils.utility", "Broadcast utilities (restart flow)"),

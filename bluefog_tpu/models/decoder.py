@@ -53,8 +53,21 @@ def rope(x: jax.Array, positions: jax.Array,
 
 def dense_ffn(lp: Dict[str, jax.Array], h: jax.Array):
     """The default ``ffn`` hook: the two-matmul gelu FFN, column- then
-    row-parallel over ``tp``, on the normed activation ``h``."""
-    return lax.psum(jax.nn.gelu(h @ lp["w1"]) @ lp["w2"], "tp"), None
+    row-parallel over ``tp``, on the normed activation ``h``.
+
+    The backward pass keeps the f32 pre-activation ``z = h @ w1`` alone
+    and recomputes the gelu from it: under the layers' ``lax.scan`` every
+    value AD keeps is stacked over the layers, and the plain expression
+    keeps four intermediates of the tanh gelu and the second matmul's
+    operand besides (at 24 layers of [2048, 4096]: 3.6 GB written in the
+    forward loop and read back in the backward loop, PERF.md §6, PR 31).
+    Same forward arithmetic, same AD rule; a program that is never
+    differentiated lowers to the plain expression.  ``prevent_cse`` is
+    off as ``jax.checkpoint`` advises under ``scan``: its barriers keep
+    the recompute out of the backward matmuls' fusions."""
+    down = jax.checkpoint(lambda z, w2: jax.nn.gelu(z) @ w2,
+                          prevent_cse=False)
+    return lax.psum(down(h @ lp["w1"], lp["w2"]), "tp"), None
 
 
 def decoder_block(cfg: Any, tp: int, lp: Dict[str, jax.Array], x: jax.Array,

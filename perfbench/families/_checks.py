@@ -21,9 +21,10 @@ def row0(tree):
 
 def loss_agrees(got, want, tol, **more):
     """|program - reference| <= tol * max(1, |reference|), as a report."""
-    ok = np.isfinite(got) and abs(got - want) <= tol * max(1.0, abs(want))
+    gap = abs(got - want) / max(1.0, abs(want))
+    ok = np.isfinite(got) and gap <= tol
     return {"ok": bool(ok), "program_loss": got, "reference_loss": want,
-            "tolerance": tol, **more}
+            "tolerance": tol, "compared": {"loss_gap": [gap, tol]}, **more}
 
 
 def mixing_check(comm, mesh, spec, topology, seed, tol=1e-5):
@@ -44,7 +45,8 @@ def mixing_check(comm, mesh, spec, topology, seed, tol=1e-5):
                                   check_vma=False))(x)
     want = topo_util.to_weight_matrix(topology).T @ x_np
     err = float(np.max(np.abs(np.asarray(mixed) - want)))
-    return {"ok": bool(err <= tol), "max_abs_err": err, "tolerance": tol}
+    return {"ok": bool(err <= tol), "max_abs_err": err, "tolerance": tol,
+            "compared": {"mixing_max_abs_err": [err, tol]}}
 
 
 def hlo_facts(step, args, n_chips, expect_permutes):
@@ -53,10 +55,13 @@ def hlo_facts(step, args, n_chips, expect_permutes):
     ``collective-permute``s and no ``all-reduce``."""
     text = step.lower(*args).compile().as_text()
     counts, bytes_ = wire.wire_stats(text)
-    ok = True
+    ok, compared = True, {}
     if n_chips > 1:
-        ok = (counts.get("collective-permute", 0) == expect_permutes
-              and counts.get("all-reduce", 0) == 0)
-    return {"ok": ok, "collective_counts": counts,
+        compared = {
+            "permutes_off_schedule": [abs(counts.get("collective-permute", 0)
+                                          - expect_permutes), 0],
+            "cross_chip_all_reduces": [counts.get("all-reduce", 0), 0]}
+        ok = all(v <= limit for v, limit in compared.values())
+    return {"ok": ok, "collective_counts": counts, "compared": compared,
             "expect_permutes": expect_permutes,
             "wire_bytes_per_call": int(sum(bytes_.values()))}

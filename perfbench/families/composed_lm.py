@@ -246,17 +246,27 @@ class Serve:
             gaps = [float(want[len(prompt) - 1 + j].max()
                           - want[len(prompt) - 1 + j, int(t)])
                     for j, t in enumerate(req.generated)]
-            good = (req.state == "done"
-                    and len(req.generated) == output_tokens
-                    and prefill_err <= SERVE_LOGIT_TOL * scale
+            whole = req.state == "done" \
+                and len(req.generated) == output_tokens
+            good = (whole and prefill_err <= SERVE_LOGIT_TOL * scale
                     and max(gaps) <= SERVE_LOGIT_TOL * scale)
             ok = ok and good
             rows.append({"prompt_tokens": len(prompt),
                          "prefill_logit_max_abs_err": prefill_err,
                          "decode_logit_gap_max": max(gaps),
-                         "reference_logit_max_abs": scale, "ok": bool(good)})
+                         "reference_logit_max_abs": scale,
+                         "off_length": int(not whole), "ok": bool(good)})
+
+        def worst(key):
+            return max(r[key] / r["reference_logit_max_abs"] for r in rows)
         return {"ok": bool(ok), "tolerance": SERVE_LOGIT_TOL,
-                "requests": rows}
+                "requests": rows, "compared": {
+                    "prefill_logit_err_share": [
+                        worst("prefill_logit_max_abs_err"), SERVE_LOGIT_TOL],
+                    "decode_logit_gap_share": [
+                        worst("decode_logit_gap_max"), SERVE_LOGIT_TOL],
+                    "requests_off_length": [sum(
+                        r["off_length"] for r in rows), 0]}}
 
 
 def build_serve(cfg, traffic, devices, seed):

@@ -27,14 +27,35 @@ class CompileWatch:
             self.compiles += 1
 
 
+def memory_peak(stats):
+    """Bytes one device holds, from its ``memory_stats()`` read when the
+    window closes: live arrays (``bytes_in_use``: weights, optimizer state,
+    cache, inputs) and the region the runtime keeps reserved for its loaded
+    programs' temporaries (``bytes_reserved``: as large as the largest
+    program's, kept between calls).  What a deployment holds while it
+    runs, and nothing that set-up held for a moment and freed:
+    ``peak_bytes_in_use`` is left out, because in LM training it is two
+    copies of the state during init (12.96 GB where 11.50 are held).  What
+    the keys mean was found out on the chip with
+    perfbench/tools/memory_probe.py (PERF.md section 7).  A platform that
+    reports no key (the CPU) reads 0."""
+    return (int(stats.get("bytes_in_use", 0))
+            + int(stats.get("bytes_reserved", 0)))
+
+
+def fullest(devices):
+    """``memory_stats()`` of the device that holds most (``{}`` where the
+    platform reports none)."""
+    return max((d.memory_stats() or {} for d in devices), key=memory_peak)
+
+
 def device_info(devices):
-    """The ``device`` object of the last line.  ``memory_peak_bytes`` is the
-    allocator's peak reservation on the fullest chip: on this runtime
-    ``peak_bytes_in_use`` omits a compiled program's temporaries."""
-    peak = 0
-    for d in devices:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_reserved",
-                                       stats.get("peak_bytes_in_use", 0))))
+    """The ``device`` object of the last line.  ``memory_peak_bytes`` is
+    resident and temporary bytes together on the fullest chip;
+    ``memory_reserved_peak_bytes`` the old reading under its own name
+    (``peak_bytes_reserved``: the largest program's temporaries alone)."""
+    stats = fullest(devices)
     return {"platform": devices[0].platform, "kind": devices[0].device_kind,
-            "count": len(devices), "memory_peak_bytes": peak}
+            "count": len(devices), "memory_peak_bytes": memory_peak(stats),
+            "memory_reserved_peak_bytes":
+                int(stats.get("peak_bytes_reserved", 0))}

@@ -1,13 +1,15 @@
 """Arithmetic from readings to a run's numbers.  Pure Python, no JAX.
 
-A run's rate is the MEDIAN of many block (or slice) readings, so one
+A training run's rate is the MEDIAN of many block readings, so one
 stalled block moves the whole-window rate (total over elapsed) and leaves
-the metric alone.  Every run keeps its series and prints this summary, so
-a run that stands apart from its set can be told apart afterwards: one
-stall (a few readings low, median untouched) or slow throughout (every
-reading shifted).
+the metric alone; a serving run's rate is the whole window's, with the
+median of its slices beside it as a per-layer statistic.  Every run keeps
+its series and prints this summary, so a run that stands apart from its set
+can be told apart afterwards: one stall (a few readings low, median
+untouched) or slow throughout (every reading shifted).
 """
 import math
+import statistics
 
 
 def percentile(values, q):
@@ -46,12 +48,21 @@ def summary(readings, whole_window=None):
 
 
 def spread(values):
-    """Distance between the quartiles over the median (the driver's
-    measure of a set's spread) and range over the median."""
-    med = median(values)
-    return {"iqr_over_median": (percentile(values, 75)
-                                - percentile(values, 25)) / med,
-            "range_over_median": (max(values) - min(values)) / med}
+    """How widely one set of runs reads, as shares of its median: ``iqr``,
+    the distance between the quartiles as ``statistics.quantiles(n=4)``
+    gives them (the benchmark contract's measure); ``iqr5`` the same with
+    the run farthest from the median left out (the driver's reading of
+    whether a bound is too tight); ``range`` and ``range5`` likewise."""
+    med = statistics.median(values)
+    kept = sorted(values, key=lambda v: abs(v - med))[:-1] \
+        if len(values) > 2 else list(values)
+
+    def iqr(xs):
+        q = statistics.quantiles(xs, n=4)
+        return (q[2] - q[0]) / med
+    return {"median": med, "iqr": iqr(values), "iqr5": iqr(kept),
+            "range5": (max(kept) - min(kept)) / med,
+            "range": (max(values) - min(values)) / med}
 
 
 def slice_rates(event_times, event_counts, t_open, t_close, width=1.0):

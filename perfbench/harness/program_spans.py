@@ -209,7 +209,7 @@ class Analysis:
         self.offset_ns = clock_offset(trace_doc, [
             (e[1], e[1] + e[2]) for e in events
             if e[0].startswith(PREFIX + "engine.") and e[0].endswith("_call")])
-        busy = _busy(trace_doc, lo, hi, sum(self.offset_ns or (0, 0)) // 2)
+        busy = _busy(trace_doc, lo, hi, self.shift_ns)
         if busy is None:
             return
         gaps, pos = [], lo
@@ -238,6 +238,12 @@ class Analysis:
                         named += ov
                 gj += 1
         self.idle_named_s = named / 1e9
+
+    @property
+    def shift_ns(self):
+        """What is added to a device stamp to put it on the host's clock:
+        the middle of what the offset can be, 0 where it is unknown."""
+        return sum(self.offset_ns or (0, 0)) // 2
 
     # -- readings (None where the trace holds nothing to read) -------------
 
@@ -324,11 +330,13 @@ class Analysis:
 
 
 def of(run):
-    """The analysis of this run's traced tail, made once per run (kept on
-    the run record) and printed before the run's last line."""
+    """The analysis of this run's traced tail: the one the runner made
+    when it reduced the trace (runners/_common.py), else made here once
+    from the run's trace directory and printed before the last line."""
     if "program_spans" not in run:
-        trace_dir = os.path.join(manifest.ROOT, "perfbench_out", "trace",
-                                 run["workload"])
+        trace_dir = os.path.join(
+            run.get("out_dir", os.path.join(manifest.ROOT, "perfbench_out")),
+            "trace", run["workload"])
         try:
             ana = Analysis(load(trace.find_xplane(trace_dir)))
         except FileNotFoundError:

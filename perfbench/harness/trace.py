@@ -149,16 +149,19 @@ def idle_share(reduced):
     return 1.0 - reduced["busy_s"] / reduced["window_s"]
 
 
-def reduce(trace, top=10):
+def reduce(trace, top=10, device_shift_ns=0):
     """The plain structure -> the numbers the per-layer metrics read.
 
     Returns None when no operation ran on a device.  Times in seconds.
     ``window_s`` is the benchmark's ``pb:window`` span where the trace
     holds one that contains device work, else first to last device event.
-    The profiler puts device and host events on one clock to within about a
-    millisecond (the recorded fixture shows a device event 1.04 ms before
-    the host call that launched it), so gaps far under a millisecond are
-    named less surely than long ones.
+    The profiler stamps device events ahead of host events (1.04 ms in the
+    recorded fixture, 1.3-1.7 ms in the cells' traces).  Busy time and the
+    window are taken as stamped; an idle gap is NAMED by the host span that
+    covers it after the gap is moved by ``device_shift_ns`` onto the host's
+    clock (harness/program_spans.py's ``clock_offset`` estimates it per
+    trace; 0 where the trace cannot say, which is safe while spans last
+    far longer than the offset).
     """
     devices = []
     for plane in trace["planes"]:
@@ -203,11 +206,12 @@ def reduce(trace, top=10):
     named = [s for s in spans if s[0] != WINDOW_SPAN]
     by_name, singles = {}, []
     for g0, g1 in worst["gaps"]:
+        h0, h1 = g0 + device_shift_ns, g1 + device_shift_ns
         best, best_ov = "none", 0
         for n, s, e in named:
-            if s >= g1:
+            if s >= h1:
                 break
-            ov = min(e, g1) - max(s, g0)
+            ov = min(e, h1) - max(s, h0)
             if ov > best_ov:
                 best, best_ov = n[len(SPAN_PREFIX):], ov
         by_name[best] = by_name.get(best, 0) + (g1 - g0)
@@ -222,6 +226,7 @@ def reduce(trace, top=10):
         "n_devices": n_dev,
         "window_s": window / 1e9,
         "window_from": window_from,
+        "device_shift_s": device_shift_ns / 1e9,
         "busy_s": sum(d["busy_ns"] for d in per_device) / n_dev / 1e9,
         "comm_exposed_s_worst": max(d["comm_exposed_ns"]
                                     for d in per_device) / 1e9,

@@ -7,8 +7,10 @@ the traffic's ``kind`` and the adapter of the configuration's ``family`` (all
 found by name, see harness/manifest.py), runs it on this machine's chips and
 prints, as the last line of stdout, one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics`` (the cell's end-to-end metrics with
-``--trace 0``, its per-layer metrics with ``--trace 1``), ``device`` and,
-traced, ``breakdown``.  Exits non-zero and prints no result line without a
+``--trace 0``, its per-layer metrics with ``--trace 1``), ``device``,
+traced ``breakdown``, and last ``checks``: each number ``correct`` rests
+on beside its limit (also the last lines of standard error).  Exits
+non-zero and prints no result line without a
 TPU, with fewer chips than the cell asks for, or without the program.
 
 ``--rehearse`` is for the tests: the files' ``tiny`` sizes on CPU devices.
@@ -39,6 +41,9 @@ def main(argv=None):
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--rehearse", action="store_true",
                     help="tests only: tiny sizes on CPU, no metric values")
+    ap.add_argument("--out-dir", default=os.path.join(ROOT, "perfbench_out"),
+                    help="where series and the trace go (tests hand each "
+                         "run a directory of its own)")
     args = ap.parse_args(argv)
     try:
         man = manifest.load()
@@ -53,7 +58,7 @@ def main(argv=None):
         "rehearsal": args.rehearse, "cell": cell["cell"],
         "config": manifest.sized(cell["config"], args.rehearse),
         "traffic": manifest.sized(cell["traffic"], args.rehearse),
-        "out_dir": os.path.join(ROOT, "perfbench_out"),
+        "out_dir": os.path.abspath(args.out_dir),
         "t_process_start": T_PROCESS_START,
     }
     from perfbench.runners import _common
@@ -65,7 +70,9 @@ def main(argv=None):
         return 3
     run = {**record, "workload": args.workload, "setup_s": ctx["setup_s"],
            "spans": ctx["spans"], "config": ctx["config"],
-           "traffic": ctx["traffic"]}
+           "traffic": ctx["traffic"], "out_dir": ctx["out_dir"]}
+    if "program_spans" in ctx:
+        run["program_spans"] = ctx["program_spans"]
     group = "per_layer" if args.trace else "end_to_end"
     metrics = manifest.read_metrics(man, args.workload, group, run)
     device = dict(record["device"])
@@ -87,6 +94,13 @@ def main(argv=None):
         device.pop("busy_s", None)
         device.pop("window_s", None)
         line.pop("breakdown", None)
+    # every number `correct` rests on beside its limit: the last lines of
+    # standard error, and the last key of the result line
+    line["checks"] = record["checks"]
+    for name, c in record["checks"].items():
+        print(f"perfbench: check {name} = {c['value']} (limit {c['limit']})",
+              file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(line), flush=True)
     return 0
 

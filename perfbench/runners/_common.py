@@ -9,7 +9,7 @@ import time
 import numpy as np
 
 from perfbench.harness import device as hw
-from perfbench.harness import estimators, manifest, trace
+from perfbench.harness import estimators, program_spans, trace
 
 
 class Refused(Exception):
@@ -62,12 +62,51 @@ def trace_tail(ctx, body):
     finally:
         jax.profiler.stop_trace()
     t0 = time.perf_counter()
-    reduced = trace.reduce(trace.load_xplane(trace.find_xplane(trace_dir)))
+    xplane = trace.find_xplane(trace_dir)
+    # the program's own spans first: they say how far the device's events
+    # are stamped ahead of the host's, which the naming of idle gaps needs
+    ana = ctx["program_spans"] = program_spans.Analysis(
+        program_spans.load(xplane))
+    for text in ana.report() if ana.spans else ():
+        say(text)
+    reduced = trace.reduce(trace.load_xplane(xplane),
+                           device_shift_ns=ana.shift_ns)
     say(f"trace reduced in {time.perf_counter() - t0:.1f} s: "
         + json.dumps({k: reduced[k] for k in
-                      ("n_devices", "window_s", "window_from", "busy_s")}
-                     if reduced else None))
+                      ("n_devices", "window_s", "window_from", "busy_s",
+                       "device_shift_s")} if reduced else None))
     return reduced
+
+
+def compared(*reports, **own):
+    """Every number a run's ``correct`` rests on beside its limit, as
+    {name: {"value", "limit"}}: what the family's checks compared
+    (``compared`` in their reports, nested ones too) and the runner's own
+    counts.  run.py prints them as a run's last lines on standard error
+    and as the last key of its result line."""
+    out = {}
+
+    def take(report):
+        for name, (value, limit) in report.get("compared", {}).items():
+            # a number that is not finite has no place in a JSON line
+            out[name] = {"value": float(value) if np.isfinite(value) else None,
+                         "limit": float(limit)}
+        for v in report.values():
+            if isinstance(v, dict):
+                take(v)
+    for r in reports:
+        take(r)
+    take({"compared": own})
+    return out
+
+
+def device_report(devices):
+    """The ``device`` object of the last line (harness/device.py), with the
+    fullest device's whole ``memory_stats()`` printed beside it: what the
+    reading was made from stays on record."""
+    say("memory_stats of the fullest device: "
+        + json.dumps(hw.fullest(devices)))
+    return hw.device_info(devices)
 
 
 def say(msg):
@@ -95,4 +134,4 @@ def keep_series(ctx, series, extra=None):
     os.makedirs(os.path.dirname(path), exist_ok=True)
     with open(path, "w") as f:
         json.dump(doc, f)
-    say(f"series kept in {os.path.relpath(path, manifest.ROOT)}")
+    say(f"series kept in {path}")

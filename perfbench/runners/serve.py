@@ -8,14 +8,15 @@ at that moment, not from the program's own stamps.  Closed loop: the window
 opens once every client has had a request retired (the ramp is set-up).
 Open loop: requests are submitted when due, timed from when they were due,
 and the window opens after ``ramp_seconds``.  Completed tokens per second
-is the median over the window's slices of ``slice_seconds`` (1 by default).
+is every token delivered in the window over the window's length; the median
+over its slices of ``slice_seconds`` (1 by default) stands beside it as a
+per-layer statistic.
 """
 import gc
 import time
 
 import numpy as np
 
-from perfbench.harness import device as hw
 from perfbench.harness import estimators, manifest
 from perfbench.harness.spans import Spans
 from perfbench.harness.traffic import Requests
@@ -28,6 +29,24 @@ class _Live:
     def __init__(self, req, client, due, asked):
         self.req, self.client, self.due, self.asked = req, client, due, asked
         self.seen, self.times = 0, []
+
+
+def longest_steps(step_ends, records, t_open, t_close, k=5):
+    """The window's ``k`` longest scheduler steps, each with the time the
+    benchmark's own spans took inside it: a stall then says which call it
+    sat in (or ``other``: the scheduler's own code and this loop)."""
+    ends = [t for t in step_ends if t_open < t <= t_close]
+    steps = sorted(zip(ends, ends[1:]), key=lambda ab: ab[0] - ab[1])[:k]
+    out = []
+    for a, b in steps:
+        inside = {}
+        for name, t0, t1 in records:
+            if t0 >= a and t1 <= b:
+                inside[name] = inside.get(name, 0.0) + t1 - t0
+        inside["other"] = b - a - sum(inside.values())
+        out.append({"at_s": round(a - t_open, 3), "seconds": round(b - a, 6),
+                    "spans": {n: round(v, 6) for n, v in inside.items()}})
+    return out
 
 
 def run(ctx):
@@ -117,7 +136,8 @@ def run(ctx):
     t_close = time.perf_counter()
     compiles_in_window = ctx["watch"].compiles - compiles_before
     retraces = prog.retraces() - retraces_before
-    dev = hw.device_info(devices)
+    dev = _common.device_report(devices)
+    stalls = longest_steps(deliveries_t, spans.records, t_open, t_close)
 
     reduced = None
     if ctx["trace"]:
@@ -152,6 +172,7 @@ def run(ctx):
         [rng.integers(0, prog.vocab, n).tolist()
          for n in check["prompt_tokens"]], check["output_tokens"])
     _common.say(f"reference check: {ref}")
+    _common.say(f"longest steps of the window: {stalls}")
     _common.say(f"window {t_close - t_open:.2f} s: {len(in_window)} requests "
                 f"retired ({len(short)} short or failed), "
                 f"{len(sched.failed)} failed in the scheduler, {len(ttft)} "
@@ -161,10 +182,16 @@ def run(ctx):
         "serve_tok_per_s": (rates, tokens_in_window / (t_close - t_open)),
         "ttft_s": (ttft, None),
         "token_gap_s": (gaps, None),
-    }, extra={"reference_check": ref,
+    }, extra={"reference_check": ref, "longest_steps": stalls,
               "slice_seconds": traffic.get("slice_seconds", 1.0),
               "deliveries": [[t - t_open, n] for t, n in
-                             zip(deliveries_t, deliveries_n) if t >= t_open]})
+                             zip(deliveries_t, deliveries_n) if t >= t_open],
+              # when each request was due and when the client saw each of
+              # its tokens, from the window's opening: any estimator, over
+              # any part of the window, can be priced with no further run
+              "requests": [[round(lv.due - t_open, 6), lv in live.values(),
+                            [round(t - t_open, 6) for t in lv.times]]
+                           for lv in everyone if lv.times]})
     return {
         "correct": bool(ref["ok"] and not short and not sched.failed
                         and compiles_in_window == 0 and retraces == 0
@@ -173,9 +200,14 @@ def run(ctx):
         "failed": len(short) + len(sched.failed),
         "device": dev,
         "trace": reduced,
+        "checks": _common.compared(
+            ref, requests_short_or_failed=(len(short) + len(sched.failed), 0),
+            compiles_in_window=(compiles_in_window, 0),
+            retraces=(retraces, 0)),
         "readings": {"serve_tok_per_s": rates, "ttft_s": ttft,
                      "token_gap_s": gaps},
         "facts": {"setup_cache_misses": setup_misses,
+                  "tokens_in_window": tokens_in_window,
                   "lanes_per_decode_call": [n for t, n in lanes
                                             if t_open < t <= t_close],
                   "window": (t_open, t_close),

@@ -14,7 +14,6 @@ import time
 
 import numpy as np
 
-from perfbench.harness import device as hw
 from perfbench.harness import estimators, manifest
 from perfbench.harness.spans import Spans
 from perfbench.runners import _common
@@ -55,7 +54,7 @@ def run(ctx):
         losses.append(loss)
     window_s = time.perf_counter() - t_open
     compiles_in_window = ctx["watch"].compiles - compiles_before
-    dev = hw.device_info(devices)          # before the reference's buffers
+    dev = _common.device_report(devices)   # before the reference's buffers
 
     reduced = None
     if ctx["trace"]:
@@ -95,6 +94,9 @@ def run(ctx):
         "failed": int((~finite).sum() * steps_per_block),
         "device": dev,
         "trace": reduced,
+        "checks": _common.compared(
+            ref, structure, nonfinite_blocks=(int((~finite).sum()), 0),
+            compiles_in_window=(compiles_in_window, 0)),
         "readings": {"train_items_per_s_per_chip": rates},
         "facts": {"setup_cache_misses": setup_misses,
                   "items_per_step": prog.items_per_call // prog.steps_per_call,

@@ -7,7 +7,11 @@ printed here is a measurement.
     JAX_PLATFORMS=cpu python3 perfbench/tools/rehearse_aot.py --workload <cell> [--set key=value ...]
 
 ``--set`` overrides a traffic size for the compile (e.g. micro=8), to size a
-batch before a cell's file is written.
+batch before a cell's file is written.  After a cell's programs comes one
+line for the cell: what its fullest device "would hold" (the largest, over
+its programs, of arguments + temporaries + the output bytes that reuse no
+argument) beside the driver's floors for a new cell, so that a cell's bytes
+can be reckoned before any chip call.
 """
 import argparse
 import json
@@ -26,6 +30,21 @@ from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from perfbench.harness import manifest, wire  # noqa: E402
 
+GIB = 1 << 30
+# the driver refuses a new cell whose fullest device holds less than a
+# quarter of a v5e's 16 GiB, or an eighth where the device is busy for at
+# least three quarters of the traced window
+FLOOR_BYTES, FLOOR_BUSY_BYTES = 4 * GIB, 2 * GIB
+
+
+def would_hold(ma):
+    """Bytes one device holds while this program runs, from its
+    ``memory_analysis()``: arguments (weights, state, cache, inputs),
+    temporaries, and the outputs that are not written into a donated
+    argument."""
+    return (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + max(0, ma.output_size_in_bytes - ma.alias_size_in_bytes))
+
 
 def report(name, compiled, n_dev):
     ma = compiled.memory_analysis()
@@ -35,10 +54,7 @@ def report(name, compiled, n_dev):
            "output_gb": ma.output_size_in_bytes / 1e9,
            "alias_gb": ma.alias_size_in_bytes / 1e9,
            "temp_gb": ma.temp_size_in_bytes / 1e9,
-           "peak_estimate_gb": (ma.argument_size_in_bytes
-                                + ma.output_size_in_bytes
-                                - ma.alias_size_in_bytes
-                                + ma.temp_size_in_bytes) / 1e9,
+           "would_hold_gb": would_hold(ma) / 1e9,
            "collectives": counts, "wire_bytes": bytes_}
     print(json.dumps(row), flush=True)
     return row
@@ -164,8 +180,15 @@ def main():
         traffic[k] = json.loads(v)
     build = BUILDERS[(cfg["family"], traffic["kind"])]
     chips = cell["cell"]["chips"]
-    for name, compiled, n in build(cfg, traffic, list(topo.devices)[:chips]):
-        report(f"{args.workload}:{name}", compiled, n)
+    rows = [report(f"{args.workload}:{name}", compiled, n) for name, compiled,
+            n in build(cfg, traffic, list(topo.devices)[:chips])]
+    held = max(r["would_hold_gb"] for r in rows) * 1e9
+    print(json.dumps({
+        "cell": args.workload, "would_hold_gb": held / 1e9,
+        "would_hold_gib": held / GIB, "floor_gib": FLOOR_BYTES / GIB,
+        "floor_gib_if_busy_75pc": FLOOR_BUSY_BYTES / GIB,
+        "passes_floor": held >= FLOOR_BYTES,
+        "passes_floor_if_busy_75pc": held >= FLOOR_BUSY_BYTES}), flush=True)
 
 
 if __name__ == "__main__":

@@ -42,8 +42,8 @@ def test_cell_refuses_to_measure_without_a_tpu(cell):
 
 @pytest.mark.parametrize("cell,trace", [(c, 0) for c in CELLS] + [
     ("pythia-410m.serve-closed32", 1), ("pythia-410m.train-seq2048", 1)])
-def test_cell_rehearses_end_to_end_on_cpu(cell, trace):
-    p = run_cell(cell, "--rehearse", trace=trace)
+def test_cell_rehearses_end_to_end_on_cpu(cell, trace, tmp_path):
+    p = run_cell(cell, "--rehearse", "--out-dir", str(tmp_path), trace=trace)
     assert p.returncode == 0, p.stderr[-3000:]
     lines = p.stdout.strip().splitlines()
     line = json.loads(lines[-1])
@@ -53,6 +53,13 @@ def test_cell_rehearses_end_to_end_on_cpu(cell, trace):
     assert line["device"]["platform"] == "cpu"
     assert line["device"]["count"] == CELLS[cell]["chips"]
     assert "busy_s" not in line["device"] and "breakdown" not in line
+    # each number `correct` rests on beside its limit: the line's last key
+    # and the last lines of standard error
+    assert list(line)[-1] == "checks" and len(line["checks"]) >= 3
+    assert all(c["value"] <= c["limit"] for c in line["checks"].values())
+    said = p.stderr.strip().splitlines()[-len(line["checks"]):]
+    assert [l.split()[2] for l in said] == list(line["checks"])
+    assert all(l.startswith("perfbench: check ") for l in said)
     group = "per_layer" if trace else "end_to_end"
     names = {m["name"] for m in manifest.metrics_for(MAN, cell, group)}
     assert set(line["would_report"]) <= names
@@ -62,8 +69,7 @@ def test_cell_rehearses_end_to_end_on_cpu(cell, trace):
     # never under a device metric's name, and the series is kept in a file
     said = [l for l in lines[:-1] if l.startswith("[perfbench] series ")]
     assert said and all("cpu_rehearsal." in l for l in said[:-1])
-    kept = os.path.join(ROOT, "perfbench_out", "series",
-                        f"{cell}.seed5.trace{trace}.json")
+    kept = tmp_path / "series" / f"{cell}.seed5.trace{trace}.json"
     with open(kept) as f:
         doc = json.load(f)
     assert doc["rehearsal"] is True
@@ -75,3 +81,72 @@ def test_unknown_workload_is_refused():
     p = run_cell("resnet50.no-such-traffic")
     assert p.returncode != 0 and "no workload named" in p.stderr
     assert p.stdout.strip() == ""
+
+
+def test_compared_gathers_every_number_beside_its_limit():
+    from perfbench.runners import _common
+    ref = {"ok": True, "compared": {"loss_gap": [2e-6, 1e-3]}}
+    structure = {"ok": True, "compared": {"cross_chip_all_reduces": [0, 0]},
+                 "mixing": {"ok": True,
+                            "compared": {"mixing_max_abs_err": [2e-7, 1e-5]}},
+                 "collective_counts": {"collective-permute": 2}}
+    got = _common.compared(ref, structure, compiles_in_window=(0, 0))
+    assert got == {
+        "loss_gap": {"value": 2e-6, "limit": 1e-3},
+        "cross_chip_all_reduces": {"value": 0.0, "limit": 0.0},
+        "mixing_max_abs_err": {"value": 2e-7, "limit": 1e-5},
+        "compiles_in_window": {"value": 0.0, "limit": 0.0}}
+    assert _common.compared({"ok": True}) == {}
+
+
+@pytest.mark.parametrize("got,want,ok", [(2.5, 2.5004, True),
+                                         (2.5, 2.51, False),
+                                         (float("nan"), 2.5, False)])
+def test_a_loss_is_compared_as_a_share_of_the_references(got, want, ok):
+    from perfbench.families import _checks
+    rep = _checks.loss_agrees(got, want, 1e-3)
+    assert rep["ok"] is ok
+    gap, limit = rep["compared"]["loss_gap"]
+    assert limit == 1e-3 and (gap <= limit) is ok
+    from perfbench.runners import _common
+    value = _common.compared(rep)["loss_gap"]["value"]
+    assert value is None if got != got else value == pytest.approx(gap)
+
+
+BROKEN_DECODE = """
+import sys
+sys.path.insert(0, {root!r})
+from bluefog_tpu.serve import ServeEngine
+sound = ServeEngine.decode
+
+
+def altered(self, *args, **kwargs):
+    # every decoded token is another one than the program chose
+    return (sound(self, *args, **kwargs) + 1) % self.cfg.vocab
+
+
+ServeEngine.decode = altered
+from perfbench import run
+sys.exit(run.main(sys.argv[1:]))
+"""
+
+
+def test_a_token_altered_where_it_is_produced_is_not_correct(tmp_path):
+    """The rest of a run driven with the timed path broken underneath: the
+    serving cell's decode call returns wrong tokens, the run still ends
+    with a result line, and ``correct`` is false on the decoded tokens'
+    reference logits (the other numbers stay inside their limits)."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    p = subprocess.run(
+        [sys.executable, "-c", BROKEN_DECODE.format(root=ROOT), "--workload",
+         "pythia-410m.serve-closed32", "--seed", "11", "--seconds", "1",
+         "--trace", "0", "--rehearse", "--out-dir", str(tmp_path)],
+        cwd=ROOT, env=env, capture_output=True, text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is False
+    over = {name for name, c in line["checks"].items()
+            if c["value"] > c["limit"]}
+    assert over == {"decode_logit_gap_share"}
+    assert "check decode_logit_gap_share" in p.stderr
+

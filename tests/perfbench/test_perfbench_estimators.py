@@ -50,8 +50,8 @@ def test_summary_and_spread():
         5, 10.0, 11.0, 12.0, 13.0, 14.0)
     assert s["whole_window"] == 11.5
     sp = estimators.spread(xs)
-    assert sp["iqr_over_median"] == pytest.approx(2 / 12)
-    assert sp["range_over_median"] == pytest.approx(4 / 12)
+    assert sp["iqr"] == pytest.approx(3 / 12)      # quartiles 10.5 and 13.5
+    assert sp["range"] == pytest.approx(4 / 12)
 
 
 def test_slice_rates_do_not_jump_by_a_delivery():
@@ -139,3 +139,180 @@ def test_wire_stats_counts_what_crosses_chips():
     counts, bytes_ = wire.wire_stats(HLO)
     assert counts == {"collective-permute": 2, "all-reduce": 1}
     assert bytes_ == {"collective-permute": 4096 + 2048, "all-reduce": 256}
+
+
+def clustered(shares, at=(0.0164, 0.0243, 0.0322), n=10000):
+    """Token gaps in three clusters: a decode call alone, with one prefill
+    call, with two (the serving cell's, PERF.md section 6, PR 32)."""
+    out = []
+    for share, value in zip(shares, at):
+        out += [value] * int(round(n * share))
+    return out
+
+
+KEPT = {"p90": lambda g: estimators.percentile(g, 90),
+        "p50": lambda g: estimators.percentile(g, 50)}
+
+
+@pytest.mark.parametrize("name", list(KEPT))
+@pytest.mark.parametrize("moved", [(0.60, 0.34, 0.06), (0.60, 0.36, 0.04)])
+def test_a_point_of_share_between_the_last_clusters_leaves_the_kept_gap(
+        name, moved):
+    """The reason on record for ``token_gap_p90_s``: the 90th percentile
+    lies inside the one-prefill cluster, five points from its end."""
+    base = KEPT[name](clustered((0.60, 0.35, 0.05)))
+    assert abs(KEPT[name](clustered(moved)) / base - 1) < 0.02
+
+
+def test_the_same_point_moves_p95_by_a_third_and_the_slow_tenth_by_less():
+    base = clustered((0.60, 0.35, 0.05))
+    more = clustered((0.60, 0.34, 0.06))       # one point more two-prefill
+    p95 = [estimators.percentile(g, 95) for g in (base, more)]
+    assert p95[0] == pytest.approx(0.0243 + 0.05 * 0.0079)     # on the edge
+    assert p95[1] == 0.0322 and p95[1] / p95[0] - 1 > 0.15
+    # the mean of the slowest tenth moves too, with the tail's weight: the
+    # candidate that was priced and not kept
+    tail_mean = _tool("price_estimators").tail_mean
+    slow = [tail_mean(g, 0.10) for g in (base, more)]
+    assert slow[0] == pytest.approx(0.5 * 0.0322 + 0.5 * 0.0243)
+    assert 0.02 < slow[1] / slow[0] - 1 < 0.04
+
+
+def test_tail_mean():
+    tail_mean = _tool("price_estimators").tail_mean
+    xs = [float(i) for i in range(1, 101)]
+    assert tail_mean(xs, 0.10) == pytest.approx(95.5)
+    assert tail_mean(xs, 1.0) == pytest.approx(50.5)
+    assert tail_mean([3.0, 1.0], 0.01) == 3.0     # at least one
+    with pytest.raises(ValueError):
+        tail_mean([], 0.1)
+
+
+def _tool(name):
+    import importlib.util
+    spec = importlib.util.spec_from_file_location(
+        "perfbench_tools_" + name,
+        os.path.join(ROOT, "perfbench", "tools", name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_a_sets_spread_is_the_contracts_and_the_drivers():
+    import statistics
+    values = [100.0, 101.0, 99.5, 100.5, 100.2, 104.0]
+    sp = estimators.spread(values)
+    q = statistics.quantiles(values, n=4)
+    assert sp["median"] == statistics.median(values)
+    assert sp["iqr"] == pytest.approx((q[2] - q[0]) / sp["median"])
+    # the run farthest from the median (104) left out
+    assert sp["range5"] == pytest.approx((101.0 - 99.5) / sp["median"])
+    assert sp["range"] == pytest.approx(4.5 / sp["median"])
+    assert sp["iqr5"] < sp["iqr"]
+
+
+def test_clusters_say_where_a_percentile_sits():
+    price = _tool("price_estimators")
+    got = price.clusters(clustered((0.67, 0.28, 0.05)), prefill_s=0.0079)
+    assert got["decode_call_s"] == pytest.approx(0.0164)
+    assert got["share_decode_only"] == pytest.approx(0.67)
+    assert got["share_one_prefill"] == pytest.approx(0.28)
+    assert got["share_two_or_more"] == pytest.approx(0.05)
+    assert got["one_prefill_ends_at_percentile"] == pytest.approx(95.0)
+
+
+def test_candidates_of_a_kept_series():
+    price = _tool("price_estimators")
+    gaps = clustered((0.67, 0.28, 0.05), n=1000)
+    doc = {"seconds": 10.0, "series": {
+        "token_gap_s": {"readings": gaps},
+        "ttft_s": {"readings": [0.025, 0.026, 0.027]},
+        "serve_tok_per_s": {"summary": {"whole_window": 1600.0}}},
+        "deliveries": [[0.02 * i, 32] for i in range(1, 501)]}
+    got = price.candidates(doc, 0.0079)
+    assert got["token_gap_p90_s"] == 0.0243 and got["ttft_p50_s"] == 0.026
+    assert got["token_gap_p99_s"] == 0.0322
+    for width in ("1", "2", "3"):
+        assert got[f"tok_per_s_slice{width}s"] == pytest.approx(1600.0)
+
+
+def _served(seconds, step=0.02, lanes=4, tokens=10, stall=None):
+    """A kept series as runners/serve.py writes it: ``lanes`` clients, each
+    request ``tokens`` steps long, a step every ``step`` s; ``stall`` =
+    (at, for) holds every lane for a while."""
+    t, deliveries, requests = 0.0, [], []
+    open_ = [[0.0, []] for _ in range(lanes)]
+    while t < seconds:
+        t += step
+        if stall and stall[0] <= t < stall[0] + step:
+            t += stall[1]
+        deliveries.append([t, lanes])
+        for lane in open_:
+            lane[1].append(t)
+            if len(lane[1]) == tokens:
+                requests.append([lane[0], False, lane[1]])
+                lane[:] = [t, []]
+    requests += [[due, True, times] for due, times in open_ if times]
+    return {"seconds": seconds, "deliveries": deliveries,
+            "requests": requests, "series": {}}
+
+
+@pytest.mark.parametrize("seconds", [3.0, 4.0, 5.1])
+def test_a_cut_prices_the_first_seconds_of_a_longer_window(seconds):
+    price = _tool("price_estimators")
+    got = price.candidates(price.cut(_served(5.1), seconds), 0.0079)
+    assert got["tok_per_s_whole_window"] == pytest.approx(200.0, rel=0.01)
+    assert got["token_gap_p90_s"] == pytest.approx(0.02)
+    assert got["ttft_p50_s"] == pytest.approx(0.02)
+
+
+def test_the_whole_windows_rate_shows_a_stall_that_the_slices_median_hides():
+    """Why ``serve_tok_per_s`` is all the tokens over all the time (PR 32's
+    review): a stall of 0.6 s in 6 s is a tenth of the rate gone, and the
+    median of one-second slices does not move."""
+    price = _tool("price_estimators")
+    calm, stalled = (price.candidates(price.cut(_served(6.0, stall=st), 6.0),
+                                      0.0079) for st in (None, (2.5, 0.6)))
+    assert stalled["tok_per_s_slice1s"] == pytest.approx(
+        calm["tok_per_s_slice1s"], rel=0.015)
+    assert stalled["tok_per_s_whole_window"] < 0.92 * calm[
+        "tok_per_s_whole_window"]
+
+
+def _reader(name):
+    from perfbench.harness import manifest
+    return manifest.load_module("metrics", name).read
+
+
+def test_the_serving_rate_is_every_token_over_the_whole_window():
+    """One slice in five stalled: the end-to-end rate is tokens over time,
+    the slices' median (the per-layer statistic) stays where it was."""
+    run = {"facts": {"tokens_in_window": 9000, "window": (100.0, 110.0)},
+           "readings": {"serve_tok_per_s": [1000.0, 1000.0, 500.0, 1000.0,
+                                            1000.0],
+                        "token_gap_s": [0.016] * 95 + [0.024] * 4 + [0.033]}}
+    assert _reader("serve_tok_per_s")(run) == 900.0
+    assert _reader("scheduler.tok_per_s_slice_p50")(run) == 1000.0
+    assert _reader("token_gap_p90_s")(run) == 0.016
+    assert 0.024 < _reader("token_gap_p99_s")(run) < 0.033
+    empty = {"facts": {}, "readings": {}}
+    for name in ("serve_tok_per_s", "scheduler.tok_per_s_slice_p50",
+                 "token_gap_p99_s"):
+        assert _reader(name)(empty) is None
+
+
+def test_the_longest_steps_say_which_call_a_stall_sat_in():
+    from perfbench.runners import serve
+    ends = [10.0 + 0.02 * i for i in range(50)]
+    ends[30:] = [t + 0.13 for t in ends[30:]]       # one stall of 130 ms
+    records = [("decode_call", b - 0.016, b - 0.001)
+               for b in ends[1:]]
+    records.append(("prefill_call", ends[29] + 0.001, ends[29] + 0.132))
+    got = serve.longest_steps(ends, records, ends[0] - 0.001, ends[-1], k=2)
+    assert got[0]["seconds"] == pytest.approx(0.15)
+    assert got[0]["at_s"] == pytest.approx(0.581, abs=0.002)
+    assert got[0]["spans"]["prefill_call"] == pytest.approx(0.131)
+    assert got[0]["spans"]["decode_call"] == pytest.approx(0.015)
+    assert got[0]["spans"]["other"] == pytest.approx(0.004, abs=1e-5)
+    assert got[1]["seconds"] == pytest.approx(0.02)
+    assert "prefill_call" not in got[1]["spans"]

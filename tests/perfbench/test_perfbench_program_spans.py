@@ -200,10 +200,10 @@ def test_a_program_without_stage_spans_gives_nothing_and_does_not_raise():
 @pytest.mark.parametrize("cell,names", [
     ("pythia-410m.serve-closed32", SERVE_METRICS),
     ("pythia-410m.train-seq2048", TRAIN_METRICS)])
-def test_traced_rehearsal_lists_every_new_metric(cell, names):
+def test_traced_rehearsal_lists_every_new_metric(cell, names, tmp_path):
     assert names <= {m["name"] for m in
                      manifest.metrics_for(MAN, cell, "per_layer")}
-    p = run_cell(cell, "--rehearse", trace=1)
+    p = run_cell(cell, "--rehearse", "--out-dir", str(tmp_path), trace=1)
     assert p.returncode == 0, p.stderr[-3000:]
     lines = p.stdout.strip().splitlines()
     line = json.loads(lines[-1])

@@ -138,3 +138,80 @@ def test_short_name():
     assert trace.short_name(trace.short_name(long)) == trace.short_name(long)
     assert trace.COMM_RE.search(trace.short_name(
         "%collective-permute-done.2 = f32[4]{0} collective-permute-done(%s)"))
+
+
+MS = 1_000_000
+
+
+def serving_steps(stamped_early_ns):
+    """Two scheduler steps as the serving cell's trace has them: a prefill
+    call of 7.9 ms, then a decode call of 16 ms.  The device runs from
+    1 ms after each call starts; its events are STAMPED early."""
+    host, dev, t = [["pb:window", 0, 60 * MS]], [], 1 * MS
+    for _ in range(2):
+        host.append(["pb:prefill_call", t, int(7.9 * MS)])
+        dev.append(["fusion.1", t + 1 * MS - stamped_early_ns, 2 * MS])
+        t += int(7.9 * MS)
+        host.append(["pb:decode_call", t, 16 * MS])
+        dev.append(["fusion.2", t + 1 * MS - stamped_early_ns, 13 * MS])
+        t += 16 * MS
+    return {"planes": [
+        {"name": "/device:TPU:0",
+         "lines": [{"name": "XLA Ops", "events": dev}]},
+        {"name": "/host:CPU", "lines": [{"name": "main", "events": host}]}]}
+
+
+@pytest.mark.parametrize("early_ms", [0.0, 1.3, 1.5, 1.7])
+def test_idle_gaps_are_named_on_the_hosts_clock(early_ms):
+    """On the host's clock the device idles 4.9 ms of a prefill call after
+    its program (collect, seed_slot) and 1 ms at the head of each call; the
+    decode call's idle time is its head and its 2 ms tail."""
+    early = int(early_ms * MS)
+    r = trace.reduce(serving_steps(early), device_shift_ns=early)
+    gaps = dict(g for g in r["breakdown"]["idle_gaps"]
+                if not g[0].startswith("longest:"))
+    # prefill: 4.9 ms after the program + the decode call's 1 ms head (one
+    # gap, named by the span that covers most of it), twice; and what the
+    # stamped window holds before the first program (2 ms less the offset)
+    assert gaps["prefill_call"] == pytest.approx(
+        2 * 5.9e-3 + 2e-3 - early / 1e9)
+    # decode: its 2 ms tail with the next prefill call's 1 ms head, and
+    # the end of the window
+    assert gaps["decode_call"] == pytest.approx(
+        3e-3 + (60 - 46.8) * 1e-3 + early / 1e9)
+    assert set(gaps) == {"prefill_call", "decode_call"}
+    assert r["device_shift_s"] == pytest.approx(early / 1e9)
+    # busy time and the window are taken as stamped
+    assert r["busy_s"] == pytest.approx(30e-3)
+
+
+def test_idle_gaps_named_as_stamped_go_to_the_neighbouring_span():
+    """What the shift repairs: stamped 1.5 ms early and named unshifted,
+    the 1 ms the device waits at the head of the first prefill call falls
+    before every span."""
+    early = int(1.5 * MS)
+    shifted = dict(trace.reduce(serving_steps(early), device_shift_ns=early)[
+        "breakdown"]["idle_gaps"][:3])
+    stamped = dict(trace.reduce(serving_steps(early))[
+        "breakdown"]["idle_gaps"][:3])
+    assert "none" in stamped and "none" not in shifted
+    assert stamped["prefill_call"] < shifted["prefill_call"]
+
+
+def test_the_recorded_trace_names_its_gaps_after_the_shift(recorded):
+    """The fixture's device events are stamped 1.04 ms before the host call
+    that launched them (first program 44.998 ms, first ``pb:step_call``
+    46.037 ms).  Shifted by that, each program starts inside its
+    ``step_call`` and the pause after it still owns the long gaps."""
+    shift = 46_036_677 - 44_998_420
+    r = trace.reduce(recorded, device_shift_ns=shift)
+    gaps = r["breakdown"]["idle_gaps"]
+    assert gaps[0][0] == "host_pause"
+    assert gaps[0][1] >= 0.9 * recorded["recorded"]["calls"] * 0.002
+    assert [n for n, _ in gaps if n.startswith("longest:")][:3] == [
+        "longest:host_pause"] * 3
+    # between two operations of one program the host is in `step_call`
+    assert dict(gaps)["step_call"] == pytest.approx(7e-9)
+    same = trace.reduce(recorded)
+    assert (r["busy_s"], r["window_s"]) == (same["busy_s"], same["window_s"])
+    assert "step_call" not in dict(same["breakdown"]["idle_gaps"])

@@ -38,10 +38,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..models.decoder import gated_ffn
 from ..parallel.expert import moe_apply_dropless, moe_combine, moe_dispatch
 from .dropless import grouped_ffn
 
-__all__ = ["router_topk", "router_expert_choice", "moe_ffn_routed",
+__all__ = ["router_topk", "router_sigmoid_grouped", "held_expert_ffn",
+           "held_expert_ffn_grouped", "held_moe_ffn",
+           "router_expert_choice", "moe_ffn_routed",
            "moe_ffn_dropless", "moe_dropless_combine",
            "moe_ffn_expert_choice", "moe_ffn_dense", "moe_ffn_dense_ec"]
 
@@ -62,6 +65,144 @@ def router_topk(x: jax.Array, wr: jax.Array, *, top_k: int):
     if top_k > 1:
         gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
     return logits, probs, idx, gate
+
+
+def router_sigmoid_grouped(x: jax.Array, wr: jax.Array, *, top_k: int,
+                           n_group: int, topk_group: int,
+                           route_scale: float):
+    """Sigmoid router with group-limited selection:
+    ``(scores [T, E], idx [T, k], weight [T, k])``.
+
+    Scores are ``sigmoid(x wr)`` in float32 at full matmul precision
+    whatever ``x``'s dtype: the selection sits on near-ties that a
+    rounded score flips.  Experts lie in ``n_group`` equal groups; a
+    group's score is the sum of its two highest scores, the
+    ``topk_group`` best groups stay, and among their experts the
+    ``top_k`` highest scores are taken.  The kept scores are normalised
+    to sum to one and multiplied by ``route_scale``.  No correction bias.
+    """
+    with jax.named_scope("moe.route"):
+        T, E = x.shape[0], wr.shape[1]
+        s = jax.nn.sigmoid(jnp.matmul(
+            x.astype(jnp.float32), wr.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+        group = jnp.sum(lax.top_k(s.reshape(T, n_group, E // n_group), 2)[0],
+                        axis=-1)                               # [T, G]
+        kept = jnp.sum(jax.nn.one_hot(lax.top_k(group, topk_group)[1],
+                                      n_group, dtype=jnp.float32), axis=1)
+        masked = jnp.where(jnp.repeat(kept, E // n_group, axis=1) > 0,
+                           s, -1.0)
+        top, idx = lax.top_k(masked, top_k)
+        return s, idx, route_scale * top / jnp.sum(top, -1, keepdims=True)
+
+
+def _held(idx: jax.Array, held_start: int, held_experts: int):
+    """``idx`` ``[T, k]`` over all the router's experts as flat indices
+    into this chip's experts, and which pairs fell on one of them."""
+    local = idx.reshape(-1) - held_start
+    return local, (local >= 0) & (local < held_experts)
+
+
+def held_expert_ffn(x: jax.Array, idx: jax.Array, weight: jax.Array,
+                    wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
+                    held_start: int):
+    """What THIS chip's experts add for tokens ``x`` ``[T, D]`` routed as
+    ``idx`` / ``weight`` ``[T, k]`` over all the router's experts: the
+    chip holds the ``wg.shape[0]`` experts from ``held_start`` (gated
+    SiLU FFNs, ``wg``/``wu`` ``[Eh, D, F]``, ``wd`` ``[Eh, F, D]``) and
+    computes ``sum_{e selected and held} weight_e * expert_e(x)``.  What
+    the absent experts would add is left out: on one chip of an
+    expert-parallel deployment this is the layer without its exchange.
+
+    The form of a decode step's lanes: every token through every held
+    expert, masked by its weight, each expert's weights read once (the
+    matmuls wait for their weights either way, and the grouped kernel's
+    row tiles of 32 took 2.5 times the weights' time over groups of
+    five, PERF.md §6, PR 33).  Returns ``(y [T, D], pairs)``: ``pairs``
+    counts the pairs that fell on held experts (int32 scalar), the rows
+    of the ``T * Eh`` computed that are real work."""
+    with jax.named_scope("moe.experts"):
+        T, k, Eh = x.shape[0], idx.shape[1], wg.shape[0]
+        local, held = _held(idx, held_start, Eh)
+        gate = jnp.sum(jnp.where(
+            local[:, None] == jnp.arange(Eh), weight.reshape(T * k, 1),
+            0).reshape(T, k, Eh), axis=1)                      # [T, Eh]
+        act = jax.nn.silu(jnp.einsum("td,edf->etf", x, wg)) \
+            * jnp.einsum("td,edf->etf", x, wu)
+        y = jnp.einsum("etf,efd,te->td", act, wd, gate.astype(x.dtype),
+                       preferred_element_type=jnp.float32)
+        return y.astype(x.dtype), jnp.sum(held.astype(jnp.int32))
+
+
+def held_expert_ffn_grouped(x: jax.Array, idx: jax.Array, weight: jax.Array,
+                            wg: jax.Array, wu: jax.Array, wd: jax.Array,
+                            layer: jax.Array, *, held_start: int):
+    """:func:`held_expert_ffn`'s sum for a prompt's tokens, dropless: the
+    token-expert pairs are sorted by expert into one buffer of ``T * k``
+    rows, held pairs first in contiguous groups, and the three matmuls
+    run as XLA's grouped (ragged) dot over the held groups alone; rows
+    behind them are never computed.  The weights are the stacks of ALL
+    expert layers (``[layers, Eh, ., .]``) and ``layer``'s experts are
+    the only groups with rows: a layer loop that sliced its experts out
+    of the stack would copy them every time (the grouped dot is a kernel
+    call, no slice fuses into it).  Returns ``(y [T, D], pairs)``."""
+    with jax.named_scope("moe.experts"):
+        T, D = x.shape
+        k, Eh = idx.shape[1], wg.shape[1]
+        local, held = _held(idx, held_start, Eh)
+        key = jnp.where(held, local, Eh)           # absent pairs sort last
+        order = jnp.argsort(key)                   # stable in jax
+        sizes = jnp.sum(jax.nn.one_hot(key, Eh + 1, dtype=jnp.int32),
+                        axis=0)[:Eh]
+        rows = x[order // k]                                   # [T*k, D]
+        pairs = jnp.sum(sizes)
+        sizes = lax.dynamic_update_slice(
+            jnp.zeros((wg.shape[0] * Eh,), jnp.int32), sizes, (layer * Eh,))
+        wg, wu, wd = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wu, wd))
+        act = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) \
+            * lax.ragged_dot(rows, wu, sizes)
+        out = lax.ragged_dot(act, wd, sizes)
+        w = jnp.where(held, weight.reshape(T * k), 0.0)[order]
+        # rows past the held groups hold whatever the kernel left there
+        out = jnp.where((jnp.arange(T * k) < pairs)[:, None],
+                        out * w.astype(out.dtype)[:, None], 0)
+        back = jnp.zeros((T * k,), jnp.int32).at[order].set(
+            jnp.arange(T * k, dtype=jnp.int32))
+        y = jnp.sum(out[back].reshape(T, k, D), axis=1, dtype=jnp.float32)
+        return y.astype(x.dtype), pairs
+
+
+def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
+                 live: jax.Array | None = None,
+                 layer: jax.Array | None = None):
+    """One served expert layer's FFN on the normed tokens ``h`` ``[T, D]``
+    as the chip that holds ``cfg.held_experts`` experts from
+    ``cfg.held_start`` computes it: the full-width router
+    (:func:`router_sigmoid_grouped`), the held experts' part (leaves
+    ``weg``/``weu``/``wed``), and the shared expert
+    (``wsg``/``wsu``/``wsd``) for every token.  Tokens not ``live``
+    (trash lanes, a prompt's padding) are routed to no expert: their
+    ``idx`` reads -1.  Without ``layer`` the leaves are the layer's own
+    and every token goes through every held expert
+    (:func:`held_expert_ffn`); with it ``weg``/``weu``/``wed`` are the
+    stacks of all expert layers and the pairs go through the grouped
+    kernel (:func:`held_expert_ffn_grouped`).  Returns
+    ``(y, idx [T, k], weight [T, k])``."""
+    _, idx, weight = router_sigmoid_grouped(
+        h, lp["wr"], top_k=cfg.top_k, n_group=cfg.n_group,
+        topk_group=cfg.topk_group, route_scale=cfg.route_scale)
+    if live is not None:
+        idx = jnp.where(live[:, None], idx, -1)
+    experts = (h, idx, weight.astype(h.dtype), lp["weg"], lp["weu"],
+               lp["wed"])
+    if layer is None:
+        y, _ = held_expert_ffn(*experts, held_start=cfg.held_start)
+    else:
+        y, _ = held_expert_ffn_grouped(*experts, layer,
+                                       held_start=cfg.held_start)
+    with jax.named_scope("moe.shared"):
+        return (y + gated_ffn(h, lp["wsg"], lp["wsu"], lp["wsd"]), idx,
+                weight)
 
 
 def _router_stats(logits, probs, idx, keep, *, num_experts: int,

@@ -56,6 +56,16 @@ Fast paths on top of the correct-first PR 10 engine:
   wire codec's per-(position, head) amax recipe, dequantized inside the
   attend kernels; prefill's own dense attention stays full-precision —
   drift only enters where a stored page is read back.
+
+A **latent model** (:class:`~bluefog_tpu.models.decoder.LatentConfig`:
+latent attention, a leading dense layer, expert layers of which this chip
+holds a subset under the full-width router) is served by two programs of
+its own over a latent cache (one compressed vector per token and layer,
+:class:`.kv_cache.LatentCacheConfig`): prefill in the unabsorbed form,
+decode in the absorbed form with the same deferred one-write-per-lane
+landing; the leading layer runs apart from the scan over the expert
+layers, one cache stacked over all.  Every fast path above and any
+carving with pp, tp or ep above 1 is refused for it by name.
 """
 from __future__ import annotations
 
@@ -72,7 +82,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..models import decoder
 from ..moe.dropless import decode_tile
-from ..moe.layers import moe_dropless_combine, router_topk
+from ..moe.layers import held_moe_ffn, moe_dropless_combine, router_topk
 from ..moe.model import MoELMConfig
 from ..ops import pallas_decode as _pd
 from ..ops.ulysses import dense_attention
@@ -409,16 +419,23 @@ class ServeEngine:
     training snapshot via :func:`bluefog_tpu.checkpoint.load_for_serving`).
     The engine never mutates it — :meth:`update_params` rebinds the whole
     tree, which is how the refresher swaps weights mid-traffic without a
-    retrace (same shapes, same program).
+    retrace (same shapes, same program).  With a
+    :class:`~bluefog_tpu.models.decoder.LatentConfig` as ``cfg`` the tree
+    is :func:`~bluefog_tpu.models.decoder.latent_param_shapes`'s
+    (``first`` / ``blocks`` / ``shared``) and the latent programs run.
     """
 
-    def __init__(self, m: Mesh3D, cfg: LMConfig, params: Any,
+    def __init__(self, m: Mesh3D,
+                 cfg: "LMConfig | decoder.LatentConfig", params: Any,
                  scfg: Optional[ServeConfig] = None):
         if m.sp != 1:
             raise ValueError(
                 "serving decodes one token at a time; an sp > 1 carving has "
                 "no sequence to shard — fold sp into tp for inference")
         self._moe = isinstance(cfg, MoELMConfig)
+        self._latent = isinstance(cfg, decoder.LatentConfig)
+        # a call of these returns the routing carrier beside its tokens
+        self._routed = self._moe or self._latent
         if self._moe and cfg.router_mode == "expert_choice":
             raise ValueError(
                 "moe_serving_requires_topk_router: expert-choice routing "
@@ -432,6 +449,8 @@ class ServeEngine:
         if scfg.max_len < scfg.prefill_buckets[-1] + scfg.decode_window:
             raise ValueError("max_len leaves no room to decode past the "
                              "longest prompt bucket")
+        if self._latent:
+            self._refuse_latent(m, scfg)
         if scfg.moe_experts and not self._moe:
             raise ValueError(
                 f"ServeConfig declares an MoE (moe_experts="
@@ -465,7 +484,10 @@ class ServeEngine:
         # a mid-traffic weight swap presents bit-identical shardings to the
         # jit cache and cannot retrace the warmed buckets
         self.update_params(params)
-        self.cache_cfg = _kv.KVCacheConfig(
+        self.cache_cfg = _kv.LatentCacheConfig(
+            layers=cfg.layers, slots=scfg.slots, max_len=scfg.max_len,
+            kv_rank=cfg.kv_rank, rope_dim=cfg.rope_dim, dtype=scfg.dtype) \
+            if self._latent else _kv.KVCacheConfig(
             layers=cfg.layers // m.pp, slots=scfg.slots,
             max_len=scfg.max_len, kv_heads=cfg.heads // m.tp,
             head_dim=cfg.d_model // cfg.heads, dtype=scfg.dtype,
@@ -475,11 +497,14 @@ class ServeEngine:
         # P(AXES) spec normalizes differently (size-1 axes dropped) and
         # would retrace every bucket once on its second visit
         cc = self.cache_cfg
-        per_dev = (1, cc.layers, cc.rows, cc.kv_heads, cc.max_len,
-                   cc.head_dim)
-        pay_dt = _kv.store_dtype(cc.store, cc.dtype)
 
         def _zeros():
+            if self._latent:
+                return {name: jnp.zeros((1,) + shape, cc.dtype)
+                        for name, shape in cc.shapes().items()}
+            per_dev = (1, cc.layers, cc.rows, cc.kv_heads, cc.max_len,
+                       cc.head_dim)
+            pay_dt = _kv.store_dtype(cc.store, cc.dtype)
             cache = {"k": jnp.zeros(per_dev, pay_dt),
                      "v": jnp.zeros(per_dev, pay_dt)}
             if cc.quantized:
@@ -489,8 +514,16 @@ class ServeEngine:
 
         self.cache = jax.jit(jax.shard_map(
             _zeros, mesh=m.mesh, in_specs=(), out_specs=P(AXES)))()
-        self._decode_jit = self._build(self._decode_body)
-        self._prefill_jit = self._build(self._prefill_body)
+        self._decode_jit = self._build(
+            self._latent_decode_body if self._latent else self._decode_body)
+        self._prefill_jit = self._build(
+            self._latent_prefill_body if self._latent
+            else self._prefill_body)
+        if self._latent:
+            _metrics.gauge(
+                "bluefog_serve_cache_bytes_per_token",
+                "device bytes one cached token costs over all layers"
+            ).set(float(cc.bytes_per_token()))
         self._chunk_jit = self._build(self._chunk_body) \
             if (scfg.spec_decode or scfg.prefix_pages) else None
         self._draft_jit = self._build(self._draft_body) \
@@ -503,6 +536,29 @@ class ServeEngine:
         self._warm_sizes: Optional[Tuple[int, ...]] = None
         self._program_bytes: dict = {}
         self._engine_trace = _tracing.new_trace("engine")
+
+    @staticmethod
+    def _refuse_latent(m: Mesh3D, scfg: ServeConfig) -> None:
+        """What the latent programs do not do yet, refused by name before
+        anything is built (a later PR each)."""
+        if (m.pp, m.tp, m.ep) != (1, 1, 1):
+            raise ValueError(
+                f"latent_serving_carving: pp={m.pp} tp={m.tp} ep={m.ep} — "
+                "the latent programs run one chip's share of each layer; "
+                "the experts it holds are named in the LatentConfig, "
+                "replicas (dp) are the only carving")
+        for bad, name, why in (
+                (scfg.decode_kernel != "xla", "latent_serving_decode_kernel",
+                 "the flash-decode kernel streams per-head K and V pages; "
+                 "the latent cache holds one vector per token"),
+                (scfg.kv_dtype != "raw", "latent_serving_kv_dtype",
+                 "the latent cache has no quantized store"),
+                (scfg.spec_decode, "latent_serving_spec_decode",
+                 "there is no truncated-stage draft of a latent model"),
+                (scfg.prefix_pages, "latent_serving_prefix_pages",
+                 "the latent cache has no shared prefix rows")):
+            if bad:
+                raise ValueError(f"{name}: {why}")
 
     def _stage(self, name: str, **attrs) -> _tracing.stage:
         """``bf:engine.<name>`` in the profiler's trace (and the ring when
@@ -898,6 +954,174 @@ class ServeEngine:
         return jax.tree.map(lambda t: t[None], (nxt, last, cache))
 
     # ------------------------------------------------------------------
+    # the latent model's programs (one chip's share of each layer)
+    # ------------------------------------------------------------------
+
+    def _latent_ffn(self, live, experts=None):
+        """An expert layer's ``ffn`` hook: full-width sigmoid router with
+        group-limited selection, the held experts' part, the shared expert
+        for every token.  ``live`` ``[tokens]`` marks the tokens that
+        count: the rest (trash lanes, a prompt's padding) are routed to no
+        expert and stay out of the carrier.  With ``experts``, the held
+        experts' weights of ALL layers stacked as the tree holds them, the
+        pairs go through the grouped kernel and ``lp["layer"]`` says which
+        layer's groups are meant; without, ``lp`` holds the layer's own
+        and every token goes through every held expert
+        (:func:`~bluefog_tpu.moe.layers.held_moe_ffn`).  ``faux`` is the
+        layer's ``[E + 4]`` carrier (:meth:`_note_route_stats`)."""
+        cfg = self.cfg
+        held = jnp.arange(cfg.num_experts) - cfg.held_start
+        held = ((held >= 0) & (held < cfg.held_experts)).astype(jnp.float32)
+
+        def ffn(lp, h):
+            if experts is None:
+                y, idx, weight = held_moe_ffn(cfg, lp, h, live)
+            else:
+                y, idx, weight = held_moe_ffn(cfg, {**lp, **experts}, h,
+                                              live, layer=lp["layer"])
+            cnt = jnp.sum(jax.nn.one_hot(idx, cfg.num_experts,
+                                         dtype=jnp.float32), axis=(0, 1))
+            p = weight / cfg.route_scale
+            ent = jnp.sum(-jnp.sum(p * jnp.log(p + 1e-20), -1) * live)
+            return y, jnp.concatenate([cnt, jnp.stack([
+                ent, jnp.sum(live.astype(jnp.float32)),
+                jnp.sum(cnt * held), jnp.sum((cnt > 0) * held)])])
+
+        return ffn
+
+    def _latent_layers(self, params, x, cache, positions, attend_with, live,
+                       grouped):
+        """The leading dense layer, then the scan over the expert layers,
+        over ONE cache stacked over all of them.  ``attend_with(lp, cache,
+        layer)`` builds a layer's ``attend`` hook; its ``aux`` is
+        ``(cache, new)``: the cache as the layer leaves it and what it
+        hands on for after the loop.  ``grouped`` is the held experts'
+        form: a prompt's pairs through the grouped kernel, or (decode)
+        every lane through every held expert (:meth:`_latent_ffn`).
+        Returns ``(x, cache, news [layers, ...] or None, carrier)``."""
+        cfg = self.cfg
+        names = ("weg", "weu", "wed") if grouped else ()
+        ffn = self._latent_ffn(
+            live, {k: params["blocks"][k] for k in names} or None)
+        blocks = {k: v for k, v in params["blocks"].items()
+                  if k not in names}
+        blocks["layer"] = jnp.arange(cfg.layers - 1)
+        x, (cache, new0), _ = decoder.latent_block(
+            cfg, params["first"], x, positions,
+            attend_with(params["first"], cache, 0), decoder.dense_gated_ffn)
+
+        def body(carry, lp):
+            x, cache, acc = carry
+            x, (cache, new), vec = decoder.latent_block(
+                cfg, lp, x, positions,
+                attend_with(lp, cache, lp["layer"] + 1), ffn)
+            return (x, cache, acc + vec), new
+
+        (x, cache, acc), news = lax.scan(
+            body, (x, cache, jnp.zeros((cfg.num_experts + 4,), jnp.float32)),
+            blocks)
+        if new0 is not None:
+            news = jnp.concatenate([new0[None], news])
+        return x, cache, news, acc
+
+    def _latent_decode_body(self, params, cache, toks, slot_ids, lens, prows,
+                            plens, keys):
+        """Fused decode in the absorbed form: every layer attends over its
+        lanes' cached vectors plus the token's own, and the tokens of all
+        layers land in the cache once per lane after the loop."""
+        params, cache, toks, slot_ids, lens, keys = self._split_args(
+            (params, cache, toks, slot_ids, lens, keys))
+        cfg, shared = self.cfg, params["shared"]
+        live = slot_ids < self.scfg.slots
+
+        def step(carry, _):
+            toks, lens, cache, keys, st = carry
+
+            # the layers only read the cache: it stays outside their loop's
+            # carry, and the loop hands on the tokens' vectors alone
+            def attend_with(lp, _, layer):
+                def attend(q_nope, q_rope, latent):         # [S, H, .]
+                    with jax.named_scope("mla.attend"):
+                        u = _kv.latent_attend_rows(
+                            decoder.mla_absorb_q(cfg, lp, q_nope), q_rope,
+                            cache, layer, slot_ids, lens, latent,
+                            cfg.softmax_scale)
+                        return decoder.mla_unabsorb_out(cfg, lp, u), \
+                            (None, latent)
+                return attend
+
+            x, _, news, acc = self._latent_layers(
+                params, shared["embed"][toks], None, lens, attend_with, live,
+                grouped=False)
+            cache = _kv.latent_append_tokens(cache, slot_ids, lens, news)
+            nxt, keys = self._next_token(
+                decoder.latent_logits(cfg, shared, x), keys)
+            nxt = nxt.astype(toks.dtype)
+            return (nxt, lens + 1, cache, keys, st + acc), nxt
+
+        st0 = jnp.zeros((cfg.num_experts + 4,), jnp.float32)
+        (_, _, cache, keys, st), gen = lax.scan(
+            step, (toks, lens, cache, keys, st0), None,
+            length=self.scfg.decode_steps_per_call)
+        return jax.tree.map(lambda t: t[None], (gen, keys, st, cache))
+
+    def _latent_prefill_body(self, params, cache, toks, slot_id, true_len):
+        """One padded prompt in the unabsorbed form; every layer's vectors
+        land in the slot as the layer runs.  Padding is routed to no
+        expert, and only the last real position is read out."""
+        params, cache, toks, slot_id, true_len = \
+            self._split_args((params, cache, toks, slot_id, true_len))
+        cfg, shared = self.cfg, params["shared"]
+        positions = jnp.arange(toks.shape[0])
+
+        def attend_with(lp, cache, layer):
+            def attend(q_nope, q_rope, latent):             # [Tpad, H, .]
+                nc = _kv.latent_prefill(cache, layer, slot_id, latent)
+                return decoder.mla_unabsorbed(cfg, lp, q_nope, q_rope,
+                                              latent), (nc, None)
+            return attend
+
+        x, cache, _, _ = self._latent_layers(
+            params, shared["embed"][toks], cache, positions, attend_with,
+            positions < true_len, grouped=True)
+        last = decoder.latent_logits(
+            cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
+        ).astype(jnp.float32)
+        nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        return jax.tree.map(lambda t: t[None], (nxt, last, cache))
+
+    def _count_held_work(self, lanes: int, lens, slots) -> None:
+        """After a latent decode call: the routing carrier's held-expert
+        counts into the fleet's counters, and a ``bf:engine.held_work``
+        mark in the trace carrying them for this call beside the lanes'
+        live cache ``positions`` (the benchmark's expert-layer and roofline
+        metrics read its attributes)."""
+        cfg, scfg = self.cfg, self.scfg
+        E = cfg.num_experts
+        pairs = int(self._route_stats[:, E + 2].sum())
+        hit = int(self._route_stats[:, E + 3].sum())
+        rows = (self.m.dp * lanes * cfg.held_experts * (cfg.layers - 1)
+                * scfg.decode_steps_per_call)
+        _metrics.counter(
+            "bluefog_serve_moe_held_pairs_total",
+            "token-expert pairs of live decode lanes that fell on experts "
+            "this chip holds").inc(pairs)
+        _metrics.counter(
+            "bluefog_serve_moe_rows_total",
+            "rows the held experts' matmuls of decode calls compute (every "
+            "lane through every held expert; x expert layers x fused "
+            "steps)").inc(rows)
+        _metrics.counter(
+            "bluefog_serve_moe_decode_calls_total",
+            "decode calls of a held-experts model").inc()
+        live = slots < scfg.slots
+        with self._stage("held_work", pairs=pairs, rows=rows,
+                         experts_hit=hit,
+                         positions=int(np.asarray(lens)[live].sum()
+                                       + live.sum())):
+            pass
+
+    # ------------------------------------------------------------------
     # host-side surface (per-REPLICA shapes; the engine broadcasts each
     # replica's row across its slice devices)
     # ------------------------------------------------------------------
@@ -1096,13 +1320,15 @@ class ServeEngine:
                         self._expand(plens) if plens is not None else None,
                         self._expand(keys))
             with self._stage("dispatch"):
-                if self._moe:
+                if self._routed:
                     gen, keys, st, self.cache = self._decode_jit(*args)
                 else:
                     gen, keys, self.cache = self._decode_jit(*args)
             with self._stage("collect"):
-                if self._moe:
+                if self._routed:
                     self._note_route_stats(st)
+                if self._latent:
+                    self._count_held_work(S, lens, slots)
                 self._scatter_keys(slots, self._collect(keys))
                 self._check_program(f"decode S={S}", self._decode_jit, args,
                                     writes)
@@ -1192,7 +1418,10 @@ class ServeEngine:
     def _note_route_stats(self, st: jax.Array) -> None:
         """Fold one MoE call's ``[R, E + 2]`` hot-expert carrier into the
         last-call snapshot (per-expert top-1 counts over live lanes and
-        layers, summed router entropy, live token-layer count)."""
+        layers, summed router entropy, live token-layer count).  A latent
+        model's carrier counts every selection, not the first alone, and
+        has two more entries: the pairs that fell on held experts, and the
+        (layer, held expert) groups that got a token."""
         self._route_stats = self._collect(st).astype(np.float64)
 
     def moe_load(self) -> Optional[list]:
@@ -1204,7 +1433,7 @@ class ServeEngine:
         count).  ``None`` for dense engines or before the first call with
         a live lane — the expert-load-aware scheduler and serve_bench's
         hot-expert histogram read this."""
-        if not self._moe or self._route_stats is None:
+        if not self._routed or self._route_stats is None:
             return None
         E = self.cfg.num_experts
         out = []

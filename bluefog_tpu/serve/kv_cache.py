@@ -85,7 +85,9 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..ops.collectives import _amax_scale
 from ..utils import metrics as _metrics
 
-__all__ = ["KVCacheConfig", "init_cache", "attend_rows",
+__all__ = ["KVCacheConfig", "LatentCacheConfig", "latent_prefill",
+           "latent_append_tokens", "latent_attend_rows", "init_cache",
+           "attend_rows",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
            "layer_append_chunk", "layer_prefill", "quantize_rows",
            "dequantize_rows", "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -595,6 +597,134 @@ def attend_chunk(q: jax.Array, cl: Dict[str, jax.Array], slots: jax.Array,
     p = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("stkgl,skld->stkgd", p, vs.astype(ct))
     return out.reshape(S, T, H, Dh).astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The latent cache: one compressed vector per token and layer
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class LatentCacheConfig:
+    """Shapes of the latent cache ``{"ckv": [layers, slots + 1, max_len,
+    kv_rank], "kr": [layers, slots + 1, max_len, rope_dim]}``: per token
+    and layer ONE compressed vector and ONE rotary key
+    (:func:`..models.decoder.mla_project`) shared by every head, where
+    :class:`KVCacheConfig` keeps K and V per head.  Two tensors, because
+    a TPU lays ``[max_len, kv_rank + rope_dim]`` out with the positions in
+    its lanes when the sum is no multiple of 128 and then copies the whole
+    cache into the order the attention reads; apart, ``ckv`` keeps a
+    token's values together (a token's write touches a few tiles: PR 29
+    measured that a write costs the tiles it touches) and ``kr`` takes the
+    order :func:`_positions_minor` says.  The last row is the trash slot;
+    there are no prefix pages and no quantized store."""
+    layers: int
+    slots: int
+    max_len: int
+    kv_rank: int
+    rope_dim: int
+    dtype: Any = jnp.float32
+    prefix_slots = 0                # what the engine's host code asks for
+
+    @property
+    def rows(self) -> int:
+        return self.slots + 1
+
+    @property
+    def trash_slot(self) -> int:
+        return self.slots
+
+    def shapes(self) -> Dict[str, Tuple[int, ...]]:
+        lead = (self.layers, self.rows, self.max_len)
+        return {"ckv": lead + (self.kv_rank,), "kr": lead + (self.rope_dim,)}
+
+    def bytes(self) -> int:
+        return self.rows * self.max_len * self.bytes_per_token()
+
+    def bytes_per_token(self) -> int:
+        """Device bytes one cached token costs over all layers."""
+        return (self.layers * (self.kv_rank + self.rope_dim)
+                * jnp.dtype(self.dtype).itemsize)
+
+
+def _pin_latent(w: jax.Array, max_len: int) -> jax.Array:
+    """A window ``[layers, 1, T, dim]`` of a latent cache tensor in the
+    axis order the tensor has in a TPU's memory (:func:`_pin_window`'s
+    reason)."""
+    order = (0, 1, 3, 2) if _positions_minor(w.shape[3], max_len) \
+        else (0, 1, 2, 3)
+    return _pin(w, order)
+
+
+def _split_latent(cache: Dict[str, jax.Array], latent: jax.Array):
+    """``latent [..., kv_rank + rope_dim]`` as the cache's two parts."""
+    C = cache["ckv"].shape[-1]
+    return {"ckv": latent[..., :C], "kr": latent[..., C:]}
+
+
+def latent_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
+                   slot_id: jax.Array, latent: jax.Array
+                   ) -> Dict[str, jax.Array]:
+    """Land a padded prompt's vectors ``[Tpad, kv_rank + rope_dim]`` at
+    positions ``0..Tpad-1`` of row ``slot_id`` of ``layer``: one
+    ``dynamic_update_slice`` per tensor."""
+    max_len = cache["ckv"].shape[2]
+    return {name: lax.dynamic_update_slice(
+        cache[name], _pin_latent(part[None, None].astype(cache[name].dtype),
+                                 max_len), (layer, slot_id, 0, 0))
+        for name, part in _split_latent(cache, latent).items()}
+
+
+def latent_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
+                         lengths: jax.Array, new: jax.Array
+                         ) -> Dict[str, jax.Array]:
+    """One decode token per lane into EVERY layer at once, after the
+    layer loop: ``new`` is ``[layers, S, kv_rank + rope_dim]`` (the
+    layers' stacked vectors) and ``t[:, slots[i], lengths[i]] = new[:,
+    i]``, one ``dynamic_update_slice`` per lane and tensor, each after the
+    one before (:func:`_write_in_turn`).  A position at or past
+    ``max_len`` goes to the trash row."""
+    S, max_len = new.shape[1], cache["ckv"].shape[2]
+    rows = jnp.where(lengths < max_len, slots, cache["ckv"].shape[1] - 1)
+    at = jnp.minimum(lengths, max_len - 1)
+    starts = [(0, rows[i], at[i], 0) for i in range(S)]
+    return {name: _write_in_turn(
+        cache[name], part.astype(cache[name].dtype)[:, :, None], starts,
+        lambda w: _pin_latent(w, max_len))
+        for name, part in _split_latent(cache, new).items()}
+
+
+def latent_attend_rows(q_abs: jax.Array, q_rope: jax.Array,
+                       cache: Dict[str, jax.Array], layer: jax.Array,
+                       slots: jax.Array, lengths: jax.Array,
+                       new: jax.Array, scale: float) -> jax.Array:
+    """Absorbed decode attention of one new token per lane over its row
+    of the latent cache: ``q_abs`` ``[S, H, kv_rank]`` is the query moved
+    into the compressed space, ``q_rope`` ``[S, H, rope]`` its rotary
+    part, ``new`` ``[S, kv_rank + rope]`` the token's own vector (not yet
+    written: position ``lengths[i]`` reads it).  ``score = (q_abs . ckv +
+    q_rope . k_rope) * scale`` in float32 over positions ``0 ..
+    lengths[i]``; returns the attended compressed vectors ``[S, H,
+    kv_rank]``.  Each lane's row is one ``dynamic_slice`` at ``[layer,
+    slot]`` per tensor (:func:`_read_lanes`'s reason: a gather makes the
+    compiler cut the whole cache)."""
+    S, L = slots.shape[0], cache["ckv"].shape[2]
+    here = (jnp.arange(L)[None, :] == lengths[:, None])[..., None]
+    rows = {}
+    for name, part in _split_latent(cache, new).items():
+        t = cache[name]
+        r = jnp.concatenate([_pin_latent(lax.dynamic_slice(
+            t, (layer, slots[i], 0, 0), (1, 1) + t.shape[2:]), L)[0]
+            for i in range(S)])                               # [S, L, dim]
+        rows[name] = jnp.where(here, part.astype(t.dtype)[:, None], r)
+    dt = cache["ckv"].dtype
+    s = (jnp.einsum("shc,slc->shl", q_abs.astype(dt), rows["ckv"],
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("shr,slr->shl", q_rope.astype(dt), rows["kr"],
+                      preferred_element_type=jnp.float32)) * scale
+    valid = jnp.arange(L)[None, :] <= lengths[:, None]
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
+    return jnp.einsum("shl,slc->shc", p.astype(dt), rows["ckv"],
+                      preferred_element_type=jnp.float32).astype(q_abs.dtype)
 
 
 # ---------------------------------------------------------------------------

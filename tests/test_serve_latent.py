@@ -1,0 +1,340 @@
+"""Serving a latent-attention expert model as one chip's share: the two
+attention forms, the latent cache, the group-limited sigmoid router, the
+held experts' grouped GEMM, the counters, and what the engine refuses for
+this family.  Small sizes, seeded random weights, float32 on the CPU; the
+comparison with the plain reference is in tests/perfbench/."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from bluefog_tpu.models import decoder
+from bluefog_tpu.moe import layers as moe_layers
+from bluefog_tpu.parallel import compose
+from bluefog_tpu.serve import Scheduler, ServeConfig, ServeEngine
+from bluefog_tpu.serve import kv_cache as kv
+from bluefog_tpu.utils import metrics
+
+CFG = decoder.LatentConfig(
+    vocab=128, d_model=48, heads=4, layers=3, q_rank=24, kv_rank=16,
+    nope_dim=8, rope_dim=8, v_dim=8, dense_ffn=96, expert_ffn=32,
+    num_experts=16, held_experts=4, held_start=4, top_k=4, n_group=2,
+    topk_group=1, route_scale=2.5, rope_factor=32.0, rope_orig_len=64,
+    rope_mscale_all_dim=1.0)
+
+
+def make_params(cfg, seed=0, n=1, dtype=jnp.float32):
+    key, out = jax.random.key(seed), {}
+    for group, leaves in decoder.latent_param_shapes(cfg).items():
+        out[group] = {}
+        for name, shape in leaves.items():
+            key, k = jax.random.split(key)
+            z = jax.random.normal(k, shape, jnp.float32)
+            z = 1.0 + 0.1 * z if name.startswith("g") else 0.2 * z
+            out[group][name] = jnp.broadcast_to(
+                z.astype(jnp.float32 if name == "wr" else dtype)[None],
+                (n,) + shape)
+    return out
+
+
+def make_engine(cpu_devices, cfg=CFG, seed=0, **scfg):
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    kw = dict(batch_buckets=(4,), prefill_buckets=(8, 16), slots=4,
+              max_len=32)
+    kw.update(scfg)
+    return ServeEngine(m, cfg, make_params(cfg, seed), ServeConfig(**kw))
+
+
+def full_forward(cfg, params, toks):
+    """Logits [T, V] of one whole sequence through the block in its
+    unabsorbed form, no cache: what prefill + decode must reproduce."""
+    p = jax.tree.map(lambda a: a[0], params)
+    pos = jnp.arange(len(toks))
+    live = jnp.ones(len(toks), bool)
+
+    def attend_of(lp):
+        return lambda qn, qr, lat: (
+            decoder.mla_unabsorbed(cfg, lp, qn, qr, lat), None)
+    x = p["shared"]["embed"][jnp.asarray(toks)]
+    x, _, _ = decoder.latent_block(cfg, p["first"], x, pos,
+                                   attend_of(p["first"]),
+                                   decoder.dense_gated_ffn)
+    for i in range(cfg.layers - 1):
+        lp = jax.tree.map(lambda a: a[i], p["blocks"])
+        x, _, _ = decoder.latent_block(
+            cfg, lp, x, pos, attend_of(lp),
+            lambda lp, h: (moe_layers.held_moe_ffn(cfg, lp, h, live)[0],
+                           None))
+    return np.asarray(decoder.latent_logits(cfg, p["shared"], x))
+
+
+def test_param_count_matches_the_shapes():
+    shapes = decoder.latent_param_shapes(CFG)
+    assert decoder.latent_param_count(CFG) == sum(
+        int(np.prod(s)) for g in shapes.values() for s in g.values())
+    assert shapes["blocks"]["weg"] == (2, 4, 48, 32)
+    assert shapes["blocks"]["wr"] == (2, 48, 16)       # the router's full width
+    assert "wr" not in shapes["first"] and "wg" in shapes["first"]
+
+
+def test_yarn_ladder_blends_between_its_two_turn_counts():
+    f = np.asarray(decoder.yarn_freqs(CFG))
+    plain = 10000.0 ** (-np.arange(4) * 2 / 8)
+    assert f[0] == pytest.approx(plain[0])              # fast pair: kept
+    assert f[-1] == pytest.approx(plain[-1] / 32)       # slow pair: / factor
+    assert np.all(f <= plain * (1 + 1e-6)) and np.all(f >= plain / 32 * (1 - 1e-6))
+    off = dataclasses.replace(CFG, rope_factor=1.0)
+    np.testing.assert_allclose(decoder.yarn_freqs(off), plain, rtol=1e-6)
+    assert off.softmax_scale == pytest.approx(16 ** -0.5)
+    m = 0.1 * np.log(32.0) + 1
+    assert CFG.softmax_scale == pytest.approx(16 ** -0.5 * m * m)
+
+
+def test_rms_norm_applies_its_scale():
+    x = jax.random.normal(jax.random.key(0), (5, 48))
+    g = 1.0 + 0.1 * jax.random.normal(jax.random.key(1), (48,))
+    want = x / np.sqrt(np.mean(np.square(x), -1, keepdims=True) + 1e-6) * g
+    np.testing.assert_allclose(decoder.rms_norm(x, g), want, rtol=1e-5)
+    assert not np.allclose(decoder.rms_norm(x, jnp.ones(48)), want, rtol=1e-3)
+
+
+def test_absorbed_attention_equals_unabsorbed():
+    """Decode's form (scores against the cached vector itself) and
+    prefill's (keys and values rebuilt per head) are one function."""
+    p = jax.tree.map(lambda a: a[0], make_params(CFG)["first"])
+    T = 11
+    h = jax.random.normal(jax.random.key(3), (T, CFG.d_model))
+    qn, qr, lat = decoder.mla_project(CFG, p, h, jnp.arange(T))
+    want = decoder.mla_unabsorbed(CFG, p, qn, qr, lat)       # [T, H * v]
+    # the last token as a decode step over a cache that holds the others
+    cache = {"ckv": jnp.zeros((2, 3, 16, CFG.kv_rank)),
+             "kr": jnp.zeros((2, 3, 16, CFG.rope_dim))}
+    cache = kv.latent_prefill(cache, 1, 2, jnp.pad(lat[:T - 1],
+                                                   ((0, 16 - T + 1), (0, 0))))
+    u = kv.latent_attend_rows(
+        decoder.mla_absorb_q(CFG, p, qn[-1:]), qr[-1:], cache, 1,
+        jnp.array([2]), jnp.array([T - 1]), lat[-1:], CFG.softmax_scale)
+    got = decoder.mla_unabsorb_out(CFG, p, u)
+    np.testing.assert_allclose(got[0], want[-1], rtol=2e-5, atol=2e-6)
+
+
+def test_latent_cache_lands_tokens_once_per_lane_after_the_layers():
+    cc = kv.LatentCacheConfig(layers=3, slots=4, max_len=8, kv_rank=4,
+                              rope_dim=2)
+    assert (cc.rows, cc.trash_slot) == (5, 4)
+    assert cc.bytes_per_token() == 3 * 6 * 4
+    assert cc.bytes() == 5 * 8 * cc.bytes_per_token()
+    assert cc.shapes() == {"ckv": (3, 5, 8, 4), "kr": (3, 5, 8, 2)}
+    cache = {k: jnp.zeros(s) for k, s in cc.shapes().items()}
+    new = jnp.arange(3 * 3 * 6, dtype=jnp.float32).reshape(3, 3, 6) + 1
+    out = kv.latent_append_tokens(cache, jnp.array([2, 0, 4]),
+                                  jnp.array([5, 0, 9]), new)
+    out = jnp.concatenate([out["ckv"], out["kr"]], -1)
+    np.testing.assert_array_equal(out[:, 2, 5], new[:, 0])
+    np.testing.assert_array_equal(out[:, 0, 0], new[:, 1])
+    # a position past the end goes to the trash row; nothing else is touched
+    assert float(jnp.abs(out[:, :4]).sum()) == float(
+        jnp.abs(new[:, :2]).sum())
+
+
+def numpy_route(s, k, n_group, topk_group):
+    T, E = s.shape
+    g = s.reshape(T, n_group, E // n_group)
+    score = np.sort(g, -1)[..., -2:].sum(-1)
+    kept = np.argsort(-score, -1)[:, :topk_group]
+    mask = np.zeros((T, n_group), bool)
+    np.put_along_axis(mask, kept, True, 1)
+    masked = np.where(np.repeat(mask, E // n_group, 1), s, -1.0)
+    idx = np.argsort(-masked, -1)[:, :k]
+    return idx, np.take_along_axis(s, idx, 1)
+
+
+@pytest.mark.parametrize("n_group,topk_group,k", [(2, 1, 4), (4, 2, 3),
+                                                  (8, 4, 2), (1, 1, 5)])
+def test_router_against_numpy_topk_with_groups(n_group, topk_group, k):
+    x = jax.random.normal(jax.random.key(5), (40, 48))
+    wr = 0.3 * jax.random.normal(jax.random.key(6), (48, 16))
+    s, idx, w = moe_layers.router_sigmoid_grouped(
+        x, wr, top_k=k, n_group=n_group, topk_group=topk_group,
+        route_scale=2.5)
+    want_s = 1 / (1 + np.exp(-(np.asarray(x, np.float64) @ np.asarray(wr))))
+    np.testing.assert_allclose(s, want_s, rtol=1e-5)
+    want_idx, top = numpy_route(np.asarray(s), k, n_group, topk_group)
+    assert np.array_equal(np.sort(idx, -1), np.sort(want_idx, -1))
+    np.testing.assert_allclose(np.sort(w, -1), np.sort(
+        2.5 * top / top.sum(-1, keepdims=True), -1), rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(w).sum(-1), 2.5, rtol=1e-5)
+
+
+def test_router_keeps_float32_scores_for_bfloat16_tokens():
+    x = jax.random.normal(jax.random.key(5), (8, 48)).astype(jnp.bfloat16)
+    wr = 0.3 * jax.random.normal(jax.random.key(6), (48, 16))
+    s, _, w = moe_layers.router_sigmoid_grouped(
+        x, wr, top_k=4, n_group=2, topk_group=1, route_scale=2.5)
+    assert s.dtype == jnp.float32 and w.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("dense", [False, True])
+@pytest.mark.parametrize("held_start", [0, 4, 12])
+def test_held_experts_compute_their_pairs_and_nothing_else(held_start, dense):
+    """Both forms: every token through every held expert, and the pairs
+    through the grouped kernel over a stack of layers of which the second
+    is meant."""
+    T, D, F, Eh, k = 24, 48, 32, 4, 4
+    ks = jax.random.split(jax.random.key(held_start), 6)
+    x = jax.random.normal(ks[0], (T, D))
+    wr = 0.3 * jax.random.normal(ks[1], (D, 16))
+    stack = [0.2 * jax.random.normal(kk, (2, Eh) + shape) for kk, shape in
+             zip(ks[2:5], ((D, F), (D, F), (F, D)))]
+    wg, wu, wd = (w[1] for w in stack)
+    _, idx, w = moe_layers.router_sigmoid_grouped(
+        x, wr, top_k=k, n_group=2, topk_group=1, route_scale=2.5)
+    if dense:
+        y, pairs = jax.jit(lambda *a: moe_layers.held_expert_ffn(
+            *a, held_start=held_start))(x, idx, w, wg, wu, wd)
+    else:
+        y, pairs = jax.jit(lambda *a: moe_layers.held_expert_ffn_grouped(
+            *a, held_start=held_start))(x, idx, w, *stack, jnp.int32(1))
+    want, n = np.zeros((T, D)), 0
+    for t in range(T):
+        for j in range(k):
+            e = int(idx[t, j]) - held_start
+            if 0 <= e < Eh:
+                n += 1
+                a = np.asarray(x[t]) @ np.asarray(wg[e])
+                want[t] += float(w[t, j]) * (
+                    (a / (1 + np.exp(-a))) * (np.asarray(x[t]) @ np.asarray(
+                        wu[e]))) @ np.asarray(wd[e])
+    assert int(pairs) == n
+    np.testing.assert_allclose(y, want, rtol=2e-4, atol=2e-5)
+
+
+def test_tokens_that_are_not_live_reach_no_expert():
+    p = jax.tree.map(lambda a: a[0, 0], make_params(CFG)["blocks"])
+    h = jax.random.normal(jax.random.key(9), (6, CFG.d_model))
+    live = jnp.array([True, False, True, True, False, True])
+    y, idx, _ = moe_layers.held_moe_ffn(CFG, p, h, live)
+    shared = decoder.gated_ffn(h, p["wsg"], p["wsu"], p["wsd"])
+    assert np.all(np.asarray(idx)[~np.asarray(live)] == -1)
+    np.testing.assert_allclose(y[1], shared[1], rtol=1e-5, atol=1e-6)
+
+
+def test_prefill_then_decode_through_the_scheduler_match_the_full_forward(
+        cpu_devices):
+    eng = make_engine(cpu_devices)
+    eng.warmup()
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, CFG.vocab, n).tolist() for n in (5, 12, 9)]
+    sched = Scheduler(eng)
+    reqs = [sched.submit(p, max_new_tokens=6) for p in prompts]
+    sched.drain()
+    sched.close()
+    for slot, (prompt, req) in enumerate(zip(prompts, reqs)):
+        assert req.state == "done" and len(req.generated) == 6
+        want = full_forward(CFG, eng.params, prompt + list(req.generated))
+        _, got = eng.prefill(0, slot, prompt)
+        np.testing.assert_allclose(got, want[len(prompt) - 1], rtol=1e-3,
+                                   atol=2e-5)
+        for j, t in enumerate(req.generated):
+            row = want[len(prompt) - 1 + j]
+            assert row.max() - row[int(t)] <= 1e-4 * np.abs(row).max()
+    assert metrics.counter(
+        "bluefog_retrace_after_warmup_total").total() == 0
+
+
+def test_decode_counts_held_work_and_cache_writes(cpu_devices):
+    metrics.reset_metrics()
+    eng = make_engine(cpu_devices)
+    toks = np.array([[3, 5, 0, 0]], np.int32)
+    for slot, p in enumerate(([1, 2, 3], [4, 5, 6, 7, 8])):
+        eng.prefill(0, slot, p)
+    slots = np.array([[0, 1, 4, 4]], np.int32)          # two live, two trash
+    lens = np.array([[3, 5, 0, 0]], np.int32)
+    eng.decode(toks, slots, lens)
+    load = eng.moe_load()[0]
+    # every selection of the two live lanes in both expert layers
+    assert load["tokens"] == 4 and load["counts"].sum() == 4 * CFG.top_k
+    pairs = metrics.counter("bluefog_serve_moe_held_pairs_total").total()
+    held = slice(CFG.held_start, CFG.held_start + CFG.held_experts)
+    assert pairs == load["counts"][held].sum()
+    # four lanes: every lane through every held expert
+    assert metrics.counter("bluefog_serve_moe_rows_total").total() == \
+        4 * CFG.held_experts * (CFG.layers - 1)
+    assert metrics.counter(
+        "bluefog_serve_moe_decode_calls_total").total() == 1
+    assert metrics.gauge("bluefog_serve_cache_bytes_per_token").value() == \
+        CFG.layers * CFG.latent_dim * 4
+    mem = eng.program_memory()
+    # one write a lane and tensor for all layers; a prompt one a layer
+    assert mem["decode S=4"]["cache_writes"] == 4 * 2
+    assert mem["prefill Tpad=8"]["cache_writes"] == CFG.layers * 2
+    assert {k: v.shape for k, v in eng.cache.items()} == {
+        "ckv": (1, CFG.layers, 5, 32, CFG.kv_rank),
+        "kr": (1, CFG.layers, 5, 32, CFG.rope_dim)}
+
+
+def test_decode_steps_per_call_fuses_the_same_tokens(cpu_devices):
+    one = make_engine(cpu_devices)
+    two = make_engine(cpu_devices, decode_steps_per_call=2)
+    prompt = [7, 1, 9, 4]
+    outs = []
+    for eng, calls in ((one, 2), (two, 1)):
+        first, _ = eng.prefill(0, 0, prompt)
+        toks = np.array([[first, 0, 0, 0]], np.int32)
+        slots = np.array([[0, 4, 4, 4]], np.int32)
+        lens, got = np.array([[4, 0, 0, 0]], np.int32), []
+        for _ in range(calls):
+            gen = eng.decode(toks, slots, lens)
+            got += [int(t) for t in gen[0, :, 0]]
+            toks[0, 0], lens = got[-1], lens + gen.shape[1] * (slots < 4)
+        outs.append(got)
+    assert outs[0] == outs[1]
+
+
+@pytest.mark.parametrize("name,carve,scfg", [
+    ("latent_serving_carving", (1, 2, 1, 1), {}),
+    ("latent_serving_carving", (1, 1, 2, 1), {}),
+    ("latent_serving_carving", (1, 1, 1, 2), {}),
+    ("latent_serving_decode_kernel", (1, 1, 1, 1),
+     {"decode_kernel": "pallas"}),
+    ("latent_serving_kv_dtype", (1, 1, 1, 1), {"kv_dtype": "int8"}),
+    ("latent_serving_spec_decode", (1, 1, 1, 1), {"spec_decode": 2}),
+    ("latent_serving_prefix_pages", (1, 1, 1, 1),
+     {"prefix_pages": 2, "prefix_page_tokens": 8}),
+])
+def test_what_the_latent_programs_do_not_do_is_refused_by_name(
+        cpu_devices, name, carve, scfg):
+    dp, pp, tp, ep = carve
+    m = compose.compose_parallelism(dp, pp, tp, 1, ep, num_experts=16,
+                                    devices=cpu_devices[:dp * pp * tp * ep])
+    kw = dict(batch_buckets=(4,), prefill_buckets=(8, 16), slots=4,
+              max_len=32)
+    kw.update(scfg)
+    with pytest.raises(ValueError, match=name):
+        ServeEngine(m, CFG, make_params(CFG, n=m.size), ServeConfig(**kw))
+
+
+@pytest.mark.parametrize("bad,match", [
+    (dict(n_group=3), "latent_router_groups"),
+    (dict(top_k=9), "latent_router_groups"),
+    (dict(held_start=14), "latent_held_experts"),
+    (dict(layers=1), "leading dense layer"),
+])
+def test_latent_config_refuses_what_it_cannot_mean(cpu_devices, bad, match):
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    with pytest.raises(ValueError, match=match):
+        dataclasses.replace(CFG, **bad).validate(m)
+
+
+def test_unabsorbed_attention_in_chunks_of_heads_is_the_same(monkeypatch):
+    """Past SCORE_BYTES the heads go through a chunk at a time."""
+    p = jax.tree.map(lambda a: a[0], make_params(CFG)["first"])
+    h = jax.random.normal(jax.random.key(4), (12, CFG.d_model))
+    qn, qr, lat = decoder.mla_project(CFG, p, h, jnp.arange(12))
+    whole = decoder.mla_unabsorbed(CFG, p, qn, qr, lat)
+    for limit in (2 * 12 * 12 * 4, 1):               # two heads; one head
+        monkeypatch.setattr(decoder, "SCORE_BYTES", limit)
+        np.testing.assert_allclose(decoder.mla_unabsorbed(CFG, p, qn, qr, lat),
+                                   whole, rtol=1e-5, atol=1e-6)

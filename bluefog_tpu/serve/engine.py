@@ -411,6 +411,21 @@ class ServeConfig:
                          f"{self.prefill_buckets[-1]}")
 
 
+class _DeviceRow:
+    """Row ``index`` of a program's ``[n_devices, ...]`` output, left on the
+    device: it crosses to the host when something converts it
+    (``np.asarray``), which ``on_read`` is told."""
+
+    def __init__(self, out: jax.Array, index: int, on_read):
+        self._out, self._index, self._on_read = out, index, on_read
+        self.shape, self.dtype = out.shape[1:], out.dtype
+
+    def __array__(self, dtype=None, copy=None):
+        self._on_read()
+        row = np.asarray(self._out)[self._index]
+        return row if dtype is None else row.astype(dtype)
+
+
 class ServeEngine:
     """SPMD prefill/decode over one carving; host-side shapes per replica.
 
@@ -492,10 +507,11 @@ class ServeEngine:
             max_len=scfg.max_len, kv_heads=cfg.heads // m.tp,
             head_dim=cfg.d_model // cfg.heads, dtype=scfg.dtype,
             store=scfg.kv_dtype, prefix_slots=scfg.prefix_pages)
-        # materialize the zero cache THROUGH a shard_map so its sharding is
-        # byte-identical to what the jitted bodies emit — a device_put'd
-        # P(AXES) spec normalizes differently (size-1 axes dropped) and
-        # would retrace every bucket once on its second visit
+        # the zero state and every program's outputs carry the SAME stated
+        # sharding (``out_shardings``): left to infer it, jit names an
+        # output on a one-chip mesh ``P()`` or ``P(AXES)`` by its rank, and
+        # a state array that changed spelling between calls would add a jit
+        # cache entry to every bucket on its second visit
         cc = self.cache_cfg
 
         def _zeros():
@@ -512,8 +528,16 @@ class ServeEngine:
                 cache["v_scale"] = jnp.zeros(per_dev[:-1], jnp.float32)
             return cache
 
-        self.cache = jax.jit(jax.shard_map(
-            _zeros, mesh=m.mesh, in_specs=(), out_specs=P(AXES)))()
+        # the fused sampler's raw PRNG keys, one per physical row, live on
+        # the device beside the cache: an argument and a donated output of
+        # every program.  An admission's key is made INSIDE its prefill
+        # from (seed, replica, slot, admission count), so a fixed seed
+        # replays a fixed run; decode gathers its lanes' keys and scatters
+        # the advanced ones back (idle lanes meet in the trash row)
+        self.cache, self._keys = jax.jit(jax.shard_map(
+            lambda: (_zeros(), jnp.zeros((1, cc.rows, 2), jnp.uint32)),
+            mesh=m.mesh, in_specs=(), out_specs=P(AXES)),
+            out_shardings=self._sharding)()
         self._decode_jit = self._build(
             self._latent_decode_body if self._latent else self._decode_body)
         self._prefill_jit = self._build(
@@ -528,11 +552,7 @@ class ServeEngine:
             if (scfg.spec_decode or scfg.prefix_pages) else None
         self._draft_jit = self._build(self._draft_body) \
             if scfg.spec_decode else None
-        # per-(replica, physical row) raw PRNG keys for the fused sampler;
-        # re-seeded deterministically at each prefill from (seed, replica,
-        # slot, admission count), so a fixed seed replays a fixed run
-        self._slot_keys = np.zeros((m.dp, cc.rows, 2), np.uint32)
-        self._seed_count = 0
+        self._seed_count = 0        # admissions so far: folded into keys
         self._warm_sizes: Optional[Tuple[int, ...]] = None
         self._program_bytes: dict = {}
         self._engine_trace = _tracing.new_trace("engine")
@@ -563,9 +583,10 @@ class ServeEngine:
     def _stage(self, name: str, **attrs) -> _tracing.stage:
         """``bf:engine.<name>`` in the profiler's trace (and the ring when
         armed).  Every device call is staged the same way: ``stage_in``
-        (host arrays onto the mesh), ``dispatch`` (the jitted call, until
-        it returns to Python), ``collect`` (the wait for the device, the
-        transfer back and the host bookkeeping after it)."""
+        (the call's one host array onto the mesh), ``dispatch`` (the
+        jitted call, until it returns to Python), ``collect`` (the wait
+        for the device, the read back of what the host reads and the
+        bookkeeping after it)."""
         return _tracing.stage(self._engine_trace, name, cat="engine",
                               **attrs)
 
@@ -574,11 +595,47 @@ class ServeEngine:
     # ------------------------------------------------------------------
 
     def _build(self, body):
+        """Every program is ``body(params, cache, keys, staged)``: the
+        cache and the sampler-key table are its state (donated, its last
+        two outputs), ``staged`` the one int32 array the host sends a call
+        (:meth:`_pack`)."""
         return jax.jit(
             jax.shard_map(body, mesh=self.m.mesh,
                           in_specs=P(AXES), out_specs=P(AXES),
                           check_vma=False),
-            donate_argnums=(1,))
+            donate_argnums=(1, 2),
+            out_shardings=NamedSharding(self.m.mesh, P(AXES)))
+
+    @staticmethod
+    def _unpack(staged, n: int):
+        """Inside a program, what :meth:`_pack` put together: the tokens
+        ``[..., T]`` and the ``n`` integers after them, ``[...]`` each."""
+        return (staged[..., :-n],) + tuple(staged[..., i - n]
+                                           for i in range(n))
+
+    def _unpack_lanes(self, lanes, n: int = 4):
+        """A lane program's ``staged``: tokens ``[S, T]``, then slot,
+        position, prefix row and prefix length per lane (the last two None
+        for the program of an engine without prefix pages) and whatever
+        else the program was sent."""
+        toks, slot_ids, lens, prows, plens, *rest = self._unpack(lanes, n)
+        if not self._use_prefix:
+            prows = plens = None
+        return (toks, slot_ids, lens, prows, plens, *rest)
+
+    def _seed_rows(self, keys, rows, key_id, count):
+        """An admission's sampler key into its row of the table, per lane:
+        ``fold_in(fold_in(PRNGKey(seed), key_id), count)`` with ``key_id``
+        the slot's ``replica * rows + slot`` and ``count`` the engine's
+        admission count.  A count of 0 is no admission (another replica's
+        copy of the program, a sealed prefix, a verify chunk): that key
+        lands in the trash row."""
+        base = jax.random.PRNGKey(self.scfg.seed)
+        new = jax.vmap(lambda i, c: jax.random.key_data(
+            jax.random.fold_in(jax.random.fold_in(base, i), c)))(key_id,
+                                                                  count)
+        trash = keys.shape[0] - 1
+        return keys.at[jnp.where(count > 0, rows, trash)].set(new)
 
     @property
     def _use_prefix(self) -> bool:
@@ -838,44 +895,45 @@ class ServeEngine:
     def _split_args(self, args):
         return jax.tree.map(lambda t: t[0], args)
 
-    def _decode_body(self, params, cache, toks, slot_ids, lens, prows,
-                     plens, keys):
-        params, cache, toks, slot_ids, lens, prows, plens, keys = \
-            self._split_args((params, cache, toks, slot_ids, lens, prows,
-                              plens, keys))
-        gen, keys, cache, st = self._decode_scan(
-            params, cache, toks, slot_ids, lens, prows, plens, keys,
-            steps=self.scfg.decode_steps_per_call)
-        out = (gen, keys, st, cache) if self._moe else (gen, keys, cache)
+    def _decode_body(self, params, cache, keys, lanes):
+        params, cache, keys, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        toks, slot_ids, lens, prows, plens = self._unpack_lanes(lanes)
+        gen, lane_keys, cache, st = self._decode_scan(
+            params, cache, toks[:, 0], slot_ids, lens, prows, plens,
+            keys[slot_ids], steps=self.scfg.decode_steps_per_call)
+        keys = keys.at[slot_ids].set(lane_keys)
+        out = (gen, st, keys, cache) if self._moe else (gen, keys, cache)
         return jax.tree.map(lambda t: t[None], out)
 
-    def _draft_body(self, params, cache, toks, slot_ids, lens, prows,
-                    plens):
+    def _draft_body(self, params, cache, keys, lanes):
         """k greedy draft tokens on the truncated stage cycle.  The draft
         IS the target's own first ``spec_stages`` stages, so its
         early-layer cache appends equal what the verify pass will write
         over them — shared rows stay consistent by construction."""
-        params, cache, toks, slot_ids, lens, prows, plens = \
-            self._split_args((params, cache, toks, slot_ids, lens, prows,
-                              plens))
-        keys = jnp.zeros(toks.shape + (2,), jnp.uint32)   # greedy: unused
+        params, cache, keys, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        toks, slot_ids, lens, prows, plens = self._unpack_lanes(lanes)
         gen, _, cache, _ = self._decode_scan(
-            params, cache, toks, slot_ids, lens, prows, plens, keys,
+            params, cache, toks[:, 0], slot_ids, lens, prows, plens,
+            jnp.zeros(slot_ids.shape + (2,), jnp.uint32),  # greedy: unused
             steps=self.scfg.spec_decode, n_stages=self.draft.stages)
-        return jax.tree.map(lambda t: t[None], (gen, cache))
+        return jax.tree.map(lambda t: t[None], (gen, keys, cache))
 
-    def _chunk_body(self, params, cache, toks, slot_ids, lens, prows,
-                    plens):
+    def _chunk_body(self, params, cache, keys, lanes):
         """The k-token verify forward / chunked prefill: ``toks`` is
         ``[S, T]`` with token t of lane i at position ``lens[i] + t``.
         Appends all T kv rows then attends causally over the slot (and
         through the prefix indirection); emits the argmax at EVERY
         position ``[S, T]`` — for the verify these are the target tokens
         g_1..g_T, for a chunked prefill position ``true_len - 1`` is the
-        request's first generated token."""
-        params, cache, toks, slot_ids, lens, prows, plens = \
-            self._split_args((params, cache, toks, slot_ids, lens, prows,
-                              plens))
+        request's first generated token, and the lane carries what its
+        slot's sampler key is made from (:meth:`_seed_rows`)."""
+        params, cache, keys, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        toks, slot_ids, lens, prows, plens, key_id, count = \
+            self._unpack_lanes(lanes, 6)
+        keys = self._seed_rows(keys, slot_ids, key_id, count)
         S, T = toks.shape
         pos = lens[:, None] + jnp.arange(T)[None, :]          # [S, T]
         # chunk rows of live lanes all count toward the hot-expert stats
@@ -918,12 +976,22 @@ class ServeEngine:
         gen = jnp.argmax(logits, axis=-1).astype(toks.dtype)
         if self._moe:
             st = lax.psum(jnp.where(sid == 0, st, 0.0), "stage")
-            return jax.tree.map(lambda t: t[None], (gen, st, cache))
-        return jax.tree.map(lambda t: t[None], (gen, cache))
+            return jax.tree.map(lambda t: t[None], (gen, st, keys, cache))
+        return jax.tree.map(lambda t: t[None], (gen, keys, cache))
 
-    def _prefill_body(self, params, cache, toks, slot_id, true_len):
-        params, cache, toks, slot_id, true_len = \
-            self._split_args((params, cache, toks, slot_id, true_len))
+    def _unpack_prompt(self, keys, staged):
+        """A prefill program's ``staged``: the padded prompt ``[Tpad]``,
+        its row and true length, and what the row's sampler key is made
+        from, which goes into the table here (:meth:`_seed_rows`)."""
+        toks, slot_id, true_len, key_id, count = self._unpack(staged, 4)
+        keys = self._seed_rows(keys, slot_id[None], key_id[None],
+                               count[None])
+        return keys, toks, slot_id, true_len
+
+    def _prefill_body(self, params, cache, keys, staged):
+        params, cache, keys, staged = self._split_args(
+            (params, cache, keys, staged))
+        keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
         positions = jnp.arange(toks.shape[0])
         x = params["shared"]["embed"][toks][None]             # [1, Tpad, D]
         ffn = self._ffn(chunk=True)
@@ -951,7 +1019,7 @@ class ServeEngine:
         logits = lax.psum(logits, "stage")
         last = lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=0)[0]
         nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
-        return jax.tree.map(lambda t: t[None], (nxt, last, cache))
+        return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     # ------------------------------------------------------------------
     # the latent model's programs (one chip's share of each layer)
@@ -1024,13 +1092,14 @@ class ServeEngine:
             news = jnp.concatenate([new0[None], news])
         return x, cache, news, acc
 
-    def _latent_decode_body(self, params, cache, toks, slot_ids, lens, prows,
-                            plens, keys):
+    def _latent_decode_body(self, params, cache, keys, lanes):
         """Fused decode in the absorbed form: every layer attends over its
         lanes' cached vectors plus the token's own, and the tokens of all
         layers land in the cache once per lane after the loop."""
-        params, cache, toks, slot_ids, lens, keys = self._split_args(
-            (params, cache, toks, slot_ids, lens, keys))
+        params, cache, table, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
+        toks, keys = toks[:, 0], table[slot_ids]
         cfg, shared = self.cfg, params["shared"]
         live = slot_ids < self.scfg.slots
 
@@ -1063,14 +1132,16 @@ class ServeEngine:
         (_, _, cache, keys, st), gen = lax.scan(
             step, (toks, lens, cache, keys, st0), None,
             length=self.scfg.decode_steps_per_call)
-        return jax.tree.map(lambda t: t[None], (gen, keys, st, cache))
+        table = table.at[slot_ids].set(keys)
+        return jax.tree.map(lambda t: t[None], (gen, st, table, cache))
 
-    def _latent_prefill_body(self, params, cache, toks, slot_id, true_len):
+    def _latent_prefill_body(self, params, cache, keys, staged):
         """One padded prompt in the unabsorbed form; every layer's vectors
         land in the slot as the layer runs.  Padding is routed to no
         expert, and only the last real position is read out."""
-        params, cache, toks, slot_id, true_len = \
-            self._split_args((params, cache, toks, slot_id, true_len))
+        params, cache, keys, staged = self._split_args(
+            (params, cache, keys, staged))
+        keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
         cfg, shared = self.cfg, params["shared"]
         positions = jnp.arange(toks.shape[0])
 
@@ -1088,7 +1159,7 @@ class ServeEngine:
             cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
         ).astype(jnp.float32)
         nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
-        return jax.tree.map(lambda t: t[None], (nxt, last, cache))
+        return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     def _count_held_work(self, lanes: int, lens, slots) -> None:
         """After a latent decode call: the routing carrier's held-expert
@@ -1126,67 +1197,84 @@ class ServeEngine:
     # replica's row across its slice devices)
     # ------------------------------------------------------------------
 
-    def _expand(self, arr: np.ndarray) -> jax.Array:
-        """``[replicas, ...]`` host array -> ``[n_devices, ...]`` on mesh."""
-        arr = np.asarray(arr)
+    def _count_crossing(self, program: str, direction: str,
+                        arrays: int = 1) -> None:
+        _metrics.counter(
+            "bluefog_serve_host_arrays_total",
+            "arrays an engine call moved between the host and the mesh, by "
+            "program (decode, prefill, chunk, draft) and direction (in: "
+            "staged for the call; out: read back)").inc(
+                arrays, program=program, direction=direction)
+
+    @staticmethod
+    def _pack(tokens, *fields) -> np.ndarray:
+        """Everything the host sends one call, as ONE int32 array: per
+        replica (and lane) the tokens ``[..., T]``, then one integer of
+        each of ``fields`` ``[...]`` (:meth:`_unpack` inside the program)."""
+        return np.concatenate(
+            [np.asarray(tokens, np.int32)]
+            + [np.asarray(f, np.int32)[..., None] for f in fields], axis=-1)
+
+    def _expand(self, program: str, arr: np.ndarray) -> jax.Array:
+        """``[replicas, ...]`` host array -> ``[n_devices, ...]`` on the
+        mesh, in one transfer."""
         if arr.shape[0] != self.m.dp:
             raise ValueError(f"leading axis {arr.shape[0]} != replica count "
                              f"{self.m.dp}")
-        return jax.device_put(
-            jnp.asarray(np.repeat(arr, self.m.slice_size, axis=0)),
-            self._sharding)
+        self._count_crossing(program, "in")
+        if self.m.slice_size > 1:
+            arr = np.repeat(arr, self.m.slice_size, axis=0)
+        return jax.device_put(arr, self._sharding)
 
-    def _collect(self, out: jax.Array) -> np.ndarray:
-        """``[n_devices, ...]`` -> ``[replicas, ...]`` (slice rows agree)."""
-        return np.asarray(out)[::self.m.slice_size]
+    def _collect(self, program: str, *outs: jax.Array) -> list:
+        """``[n_devices, ...]`` each -> ``[replicas, ...]`` host arrays
+        (slice rows agree), read back together."""
+        self._count_crossing(program, "out", len(outs))
+        return [a[::self.m.slice_size] for a in jax.device_get(outs)]
 
-    def _seed_slot(self, replica: int, slot: int) -> None:
-        """Deterministic per-admission PRNG key for the fused sampler."""
+    def _args(self, staged: jax.Array) -> tuple:
+        """A program's arguments (:meth:`_build`)."""
+        return self.params, self.cache, self._keys, staged
+
+    def _admission(self, replica: int, slot: int, shape=()):
+        """What an admitted slot's sampler key is made from inside the
+        program (:meth:`_seed_rows`), ``[replicas, *shape]`` each: the
+        slot's id and the admission count, which advances here; 0 for every
+        other replica."""
         self._seed_count += 1
-        # four eager device calls and a read back, once per admission
-        with self._stage("seed_slot"):
-            key = jax.random.fold_in(
-                jax.random.fold_in(jax.random.PRNGKey(self.scfg.seed),
-                                   replica * self.cache_cfg.rows + slot),
-                self._seed_count)
-            self._slot_keys[replica, slot] = np.asarray(
-                jax.random.key_data(key), np.uint32)
+        key_id = np.zeros((self.m.dp,) + shape, np.int32)
+        count = np.zeros_like(key_id)
+        key_id[replica] = replica * self.cache_cfg.rows + slot
+        count[replica] = self._seed_count
+        return key_id, count
 
     def _trash_vec(self, S: int) -> np.ndarray:
         return np.full((self.m.dp, S), self.cache_cfg.trash_slot, np.int32)
 
-    def _prefix_args(self, prefix_rows, prefix_lens, S: int):
-        """Normalize optional per-lane prefix attachments to arrays (trash
-        row at length 0 = no indirection for that lane)."""
-        if not self._use_prefix:
-            if prefix_rows is not None:
-                raise ValueError("prefix attachments need prefix_pages > 0")
-            return None, None
+    def _stage_lanes(self, program: str, tokens, slots, lens, prefix_rows,
+                     prefix_lens, *more) -> tuple:
+        """The arguments of a lane program's call: ``tokens`` ``[replicas,
+        S, T]`` and per lane its slot, position, prefix attachment (trash
+        row at length 0 = no indirection for that lane) and ``more``."""
         if prefix_rows is None:
-            return self._trash_vec(S), np.zeros((self.m.dp, S), np.int32)
-        return (np.asarray(prefix_rows, np.int32),
-                np.asarray(prefix_lens, np.int32))
-
-    def _gather_keys(self, slots: np.ndarray) -> np.ndarray:
-        return np.take_along_axis(
-            self._slot_keys, np.asarray(slots, np.int64)[..., None], axis=1)
-
-    def _scatter_keys(self, slots: np.ndarray, keys: np.ndarray) -> None:
-        np.put_along_axis(self._slot_keys,
-                          np.asarray(slots, np.int64)[..., None],
-                          keys, axis=1)
+            S = tokens.shape[1]
+            prefix_rows = self._trash_vec(S)
+            prefix_lens = np.zeros((self.m.dp, S), np.int32)
+        elif not self._use_prefix:
+            raise ValueError("prefix attachments need prefix_pages > 0")
+        return self._args(self._expand(program, self._pack(
+            tokens, slots, lens, prefix_rows, prefix_lens, *more)))
 
     def prefill(self, replica: int, slot: int,
-                tokens: Sequence[int]) -> Tuple[int, np.ndarray]:
+                tokens: Sequence[int]) -> Tuple[int, "_DeviceRow"]:
         """Prefill one request into ``slot`` of ``replica``; other replicas
         run the same program against their trash slot.  Returns the first
-        greedy token and the last-position logits ``[vocab]``."""
+        greedy token and the last-position logits ``[vocab]``, which stay
+        on the device until something converts them (``np.asarray``)."""
         if not 0 <= slot < self.scfg.slots:
             raise ValueError(f"slot {slot} out of range "
                              f"[0, {self.scfg.slots})")
-        nxt, logits = self._prefill_into(replica, slot, tokens)
-        self._seed_slot(replica, slot)
-        return nxt, logits
+        return self._prefill_into(replica, slot, tokens, admit=True)
 
     def seal_prefix(self, replica: int, row: int,
                     tokens: Sequence[int]) -> None:
@@ -1201,10 +1289,10 @@ class ServeEngine:
         if len(tokens) % self.scfg.prefix_page_tokens:
             raise ValueError(f"prefix of {len(tokens)} tokens is not whole "
                              f"pages of {self.scfg.prefix_page_tokens}")
-        self._prefill_into(replica, row, tokens)
+        self._prefill_into(replica, row, tokens, admit=False)
 
-    def _prefill_into(self, replica: int, row: int,
-                      tokens: Sequence[int]) -> Tuple[int, np.ndarray]:
+    def _prefill_into(self, replica: int, row: int, tokens: Sequence[int],
+                      admit: bool) -> Tuple[int, "_DeviceRow"]:
         if not tokens:
             raise ValueError("empty prompt")
         Tpad = self.scfg.prefill_bucket_for(len(tokens))
@@ -1215,19 +1303,25 @@ class ServeEngine:
         slot_id[replica] = row
         true_len = np.ones((R,), np.int32)
         true_len[replica] = len(tokens)
+        # a sealed prefix is no admission: no key, the count stays
+        key_id, count = self._admission(replica, row) if admit \
+            else (np.zeros((R,), np.int32),) * 2
         with self._stage("prefill_call", Tpad=Tpad, tokens=len(tokens),
                          replica=replica):
             with self._stage("stage_in"):
-                args = (self.params, self.cache, self._expand(toks),
-                        self._expand(slot_id), self._expand(true_len))
+                args = self._args(self._expand("prefill", self._pack(
+                    toks, slot_id, true_len, key_id, count)))
             with self._stage("dispatch"):
-                nxt, logits, self.cache = self._prefill_jit(*args)
+                nxt, logits, self._keys, self.cache = \
+                    self._prefill_jit(*args)
             with self._stage("collect"):
                 self._check_program(f"prefill Tpad={Tpad}",
                                     self._prefill_jit, args,
                                     self._cache_writes("prefill", 1))
-                return (int(self._collect(nxt)[replica]),
-                        self._collect(logits)[replica])
+                nxt, = self._collect("prefill", nxt)
+                return int(nxt[replica]), _DeviceRow(
+                    logits, replica * self.m.slice_size,
+                    lambda: self._count_crossing("prefill", "out"))
 
     def chunk_prefill(self, replica: int, slot: int, tokens: Sequence[int],
                       start: int, prefix_row: int) -> int:
@@ -1258,34 +1352,31 @@ class ServeEngine:
         lens[replica, 0] = start
         prows = self._trash_vec(1)
         prows[replica, 0] = prefix_row
-        plens = np.zeros((R, 1), np.int32)
-        plens[replica, 0] = start
-        gen = self._chunk_call(toks, slots, lens, prows, plens)
-        self._seed_slot(replica, slot)
+        # the lane starts where its prefix ends: position == prefix length
+        gen = self._chunk_call(toks, slots, lens, prows, lens,
+                               self._admission(replica, slot, (1,)))
         return int(gen[replica, 0, len(tokens) - 1])
 
-    def _chunk_call(self, toks, slots, lens, prows, plens) -> np.ndarray:
-        S, T = int(toks.shape[1]), int(toks.shape[2])
+    def _chunk_call(self, toks, slots, lens, prows, plens,
+                    admission=None) -> np.ndarray:
+        """One call of the chunk program; ``admission`` is the one lane's
+        :meth:`_admission` where the chunk is a request's prefill."""
+        toks = np.asarray(toks, np.int32)
+        S, T = toks.shape[1:]
+        zeros = np.zeros((self.m.dp, S), np.int32)
         with self._stage("chunk_call", S=S, T=T):
             with self._stage("stage_in"):
-                prows, plens = self._prefix_args(prows, plens, S)
-                args = (self.params, self.cache,
-                        self._expand(np.asarray(toks, np.int32)),
-                        self._expand(np.asarray(slots, np.int32)),
-                        self._expand(np.asarray(lens, np.int32)),
-                        self._expand(prows) if prows is not None else None,
-                        self._expand(plens) if plens is not None else None)
+                args = self._stage_lanes("chunk", toks, slots, lens, prows,
+                                         plens, *(admission or (zeros,) * 2))
             with self._stage("dispatch"):
-                if self._moe:
-                    gen, st, self.cache = self._chunk_jit(*args)
-                else:
-                    gen, self.cache = self._chunk_jit(*args)
+                *out, self._keys, self.cache = self._chunk_jit(*args)
             with self._stage("collect"):
-                if self._moe:
-                    self._note_route_stats(st)
                 self._check_program(f"chunk S={S} T={T}", self._chunk_jit,
                                     args, self._cache_writes("chunk", S))
-                return self._collect(gen)
+                gen, *st = self._collect("chunk", *out)
+                if st:
+                    self._note_route_stats(st[0])
+                return gen
 
     def decode(self, tokens: np.ndarray, slots: np.ndarray,
                lens: np.ndarray, prefix_rows: Optional[np.ndarray] = None,
@@ -1302,37 +1393,27 @@ class ServeEngine:
         ``temperature > 0`` — each lane's PRNG stream was seeded at its
         prefill).
         """
-        S = np.asarray(tokens).shape[1]
+        tokens = np.asarray(tokens, np.int32)
+        S = tokens.shape[1]
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
         writes = self._cache_writes("decode", S)
         with self._stage("decode_call", S=int(S), cache_writes=writes):
             with self._stage("stage_in"):
-                slots = np.asarray(slots, np.int32)
-                prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
-                keys = self._gather_keys(slots)
-                args = (self.params, self.cache,
-                        self._expand(np.asarray(tokens, np.int32)),
-                        self._expand(slots),
-                        self._expand(np.asarray(lens, np.int32)),
-                        self._expand(prows) if prows is not None else None,
-                        self._expand(plens) if plens is not None else None,
-                        self._expand(keys))
+                args = self._stage_lanes("decode", tokens[..., None], slots,
+                                         lens, prefix_rows, prefix_lens)
             with self._stage("dispatch"):
-                if self._routed:
-                    gen, keys, st, self.cache = self._decode_jit(*args)
-                else:
-                    gen, keys, self.cache = self._decode_jit(*args)
+                *out, self._keys, self.cache = self._decode_jit(*args)
             with self._stage("collect"):
-                if self._routed:
-                    self._note_route_stats(st)
-                if self._latent:
-                    self._count_held_work(S, lens, slots)
-                self._scatter_keys(slots, self._collect(keys))
                 self._check_program(f"decode S={S}", self._decode_jit, args,
                                     writes)
-                return self._collect(gen)
+                gen, *st = self._collect("decode", *out)
+                if st:
+                    self._note_route_stats(st[0])
+                if self._latent:
+                    self._count_held_work(S, lens, np.asarray(slots))
+                return gen
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
                     lens: np.ndarray,
@@ -1370,23 +1451,21 @@ class ServeEngine:
     def _spec_round(self, tokens, slots, lens, prefix_rows, prefix_lens):
         k, S = self.scfg.spec_decode, tokens.shape[1]
         with self._stage("stage_in"):
-            prows, plens = self._prefix_args(prefix_rows, prefix_lens, S)
-            args = (self.params, self.cache, self._expand(tokens),
-                    self._expand(slots), self._expand(lens),
-                    self._expand(prows) if prows is not None else None,
-                    self._expand(plens) if plens is not None else None)
+            args = self._stage_lanes("draft", tokens[..., None], slots, lens,
+                                     prefix_rows, prefix_lens)
         with self._stage("dispatch"):
-            drafts, self.cache = self._draft_jit(*args)
+            drafts, self._keys, self.cache = self._draft_jit(*args)
         with self._stage("collect"):
             self._check_program(f"draft S={S}", self._draft_jit, args,
                                 self._cache_writes("draft", S))
-            drafts = self._collect(drafts)              # [R, k, S]
+            drafts, = self._collect("draft", drafts)    # [R, k, S]
         d = np.transpose(drafts, (0, 2, 1))             # [R, S, k]
         # verify chunk: [t0, d_1 .. d_k] per lane — the draft rows it
         # appended are overwritten with the (identical) target values and
         # the later-stage layers get theirs written for the first time
         chunk = np.concatenate([tokens[:, :, None], d], axis=2)
-        gen = self._chunk_call(chunk, slots, lens, prows, plens)  # [R,S,k+1]
+        gen = self._chunk_call(chunk, slots, lens, prefix_rows,
+                               prefix_lens)                 # [R, S, k+1]
         # accept: longest prefix where draft_i == target g_i, then the
         # bonus g_{j+1}; rejected rows above the new frontier are garbage
         # that the next round's appends overwrite before any read
@@ -1415,14 +1494,14 @@ class ServeEngine:
         """(token, slot, len) triple a padding lane should carry."""
         return 0, self.cache_cfg.trash_slot, 0
 
-    def _note_route_stats(self, st: jax.Array) -> None:
+    def _note_route_stats(self, st: np.ndarray) -> None:
         """Fold one MoE call's ``[R, E + 2]`` hot-expert carrier into the
         last-call snapshot (per-expert top-1 counts over live lanes and
         layers, summed router entropy, live token-layer count).  A latent
         model's carrier counts every selection, not the first alone, and
         has two more entries: the pairs that fell on held experts, and the
         (layer, held expert) groups that got a token."""
-        self._route_stats = self._collect(st).astype(np.float64)
+        self._route_stats = st.astype(np.float64)
 
     def moe_load(self) -> Optional[list]:
         """Per-replica routing load from the most recent MoE engine call
@@ -1462,14 +1541,9 @@ class ServeEngine:
                              f"bucket {self.scfg.batch_buckets}")
         tok, slot, ln = self.idle_lane()
         full = lambda v: np.full((self.m.dp, S), v, np.int32)
-        prows, plens = self._prefix_args(None, None, S)
-        args = (self.params, self.cache,
-                self._expand(full(tok)), self._expand(full(slot)),
-                self._expand(full(ln)),
-                self._expand(prows) if prows is not None else None,
-                self._expand(plens) if plens is not None else None,
-                self._expand(self._gather_keys(full(slot))))
-        return self._decode_jit.lower(*args).as_text()
+        return self._decode_jit.lower(*self._stage_lanes(
+            "decode", full(tok)[..., None], full(slot), full(ln), None,
+            None)).as_text()
 
     def update_params(self, params: Any) -> None:
         """Swap in a fresh ``[n, ...]``-stacked tree (shapes must match —
@@ -1493,11 +1567,9 @@ class ServeEngine:
                 self.spec_decode(full(tok), full(slot), full(ln))
         if self._use_prefix:
             for Tpad in scfg.prefill_buckets:
-                toks = np.zeros((R, 1, Tpad), np.int32)
-                self._chunk_call(toks, self._trash_vec(1),
-                                 np.zeros((R, 1), np.int32),
+                self._chunk_call(np.zeros((R, 1, Tpad), np.int32),
                                  self._trash_vec(1),
-                                 np.zeros((R, 1), np.int32))
+                                 np.zeros((R, 1), np.int32), None, None)
         self._warm_sizes = self._jit_sizes()
         _flight.record("serve", name="warmup",
                        batch_buckets=list(scfg.batch_buckets),
@@ -1576,6 +1648,7 @@ class ServeEngine:
         device, for every engine program compiled so far
         (``compiled.memory_analysis()``, :meth:`_cache_writes`): whether
         the cache is updated in place is a property of the compiled
-        program, so this is its counter — ``alias_bytes`` is the cache's
-        size and ``temp_bytes`` stays under one layer's pages when it is."""
+        program, so this is its counter — ``alias_bytes`` is the size of
+        the cache and the key table, and ``temp_bytes`` stays under one
+        layer's pages when it is."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

@@ -32,7 +32,7 @@ entry-time attributes as the event's stats; nothing has to be armed for
 that.  When the ring is armed it also records the same interval here,
 under the plain ``name``.  The vocabulary (``bf:serve.step`` ⊃
 ``admit``/``prefill``/``pack``/``deliver``; ``bf:engine.<call>`` ⊃
-``stage_in``/``dispatch``/``collect``, and ``seed_slot``;
+``stage_in``/``dispatch``/``collect``;
 ``bf:train.train_step`` ⊃ ``dispatch``) is tabled in
 ``docs/OBSERVABILITY.md``.  Spans whose
 endpoints lie in the past (``queue``, ``request``, the per-rider

@@ -117,11 +117,10 @@ def test_serving_programs_unchanged(cpu_devices, monkeypatch, program):
         i32 = lambda *s: jnp.zeros((n,) + s, jnp.int32)
         if program == "prefill":
             lowered = eng._build(eng._prefill_body).lower(
-                eng.params, eng.cache, i32(8), i32(), i32())
+                *eng._args(i32(8 + 4)))
         else:
             lowered = eng._build(eng._decode_body).lower(
-                eng.params, eng.cache, i32(2), i32(2), i32(2), None, None,
-                jnp.zeros((n, 2, 2), jnp.uint32))
+                *eng._args(i32(2, 1 + 4)))
         return (_opcodes(lowered.as_text(dialect="hlo")),
                 _opcodes(lowered.compile().as_text()))
 

@@ -455,6 +455,62 @@ print(json.dumps({"mha": max_diff(None), "gqa": max_diff(2)}))
 """
 
 
+def test_engine_call_crosses_the_host_boundary_once_each_way(engine,
+                                                            tmp_path):
+    """Warm, a scheduler step stages ONE array for its decode call and
+    reads ONE back, and an admission's prefill the same: the lanes'
+    sampler keys stay on the device, the admitted slot's key is made
+    inside the prefill program (no eager ``jax.random`` program, no
+    ``seed_slot`` stage), and the prefill's logits cross only when
+    somebody converts them."""
+    eng = engine
+    moved = bfm.counter("bluefog_serve_host_arrays_total")
+
+    def crossings():
+        return {(p, d): moved.value(program=p, direction=d)
+                for p in ("decode", "prefill") for d in ("in", "out")}
+    sched = Scheduler(eng)
+    rng = np.random.default_rng(5)
+    reqs = [sched.submit(rng.integers(0, _CFG["vocab"], n).tolist(),
+                         max_new_tokens=4) for n in (3, 8, 5, 2, 6)]
+    before, steps = crossings(), 0
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while not sched.done:
+            steps += 1
+            assert steps < 100, "scheduler failed to drain"
+            sched.step()
+    finally:
+        jax.profiler.stop_trace()
+    sched.close()
+    assert all(len(r.generated) == 4 for r in reqs)
+    after = crossings()
+    assert {k: after[k] - before[k] for k in after} == {
+        ("decode", "in"): steps, ("decode", "out"): steps,
+        ("prefill", "in"): len(reqs), ("prefill", "out"): len(reqs)}
+
+    # the logits a prefill returns are a value on the device
+    first, logits = eng.prefill(1, 0, reqs[0].prompt)
+    assert first == reqs[0].generated[0] and logits.shape == (_CFG["vocab"],)
+    held = crossings()
+    assert held[("prefill", "in")] == after[("prefill", "in")] + 1
+    assert held[("prefill", "out")] == after[("prefill", "out")] + 1
+    row = np.asarray(logits, np.float32)
+    assert int(row.argmax()) == first
+    assert crossings()[("prefill", "out")] == held[("prefill", "out")] + 1
+
+    ev = bf_events(tmp_path, ("bf:", "PjitFunction("))
+    in_step = [e for e in ev if e[0] == "bf:serve.step"]
+    assert len(in_step) == steps
+    assert [e for e in ev if e[0] == "bf:engine.seed_slot"] == []
+    # every jitted call the runtime saw between a step's two ends
+    programs = {e[0] for e in ev if e[0].startswith("PjitFunction(")
+                and any(s[1] <= e[1] and e[2] <= s[2] for s in in_step)}
+    assert programs == {"PjitFunction(_decode_body)",
+                        "PjitFunction(_prefill_body)"}
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+
+
 def test_float64_decode_oracle():
     """The cached decode path is logit-identical (float64, ~1e-12) to the
     full forward, for both MHA and grouped-query attention — the numeric
@@ -584,7 +640,8 @@ def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     inner = inside(ev, "bf:engine.prefill_call", "bf:serve.prefill")
     assert len(inner) == 16
     assert all(e[3]["tokens"] <= e[3]["Tpad"] for e in inner)
-    assert len(inside(ev, "bf:engine.seed_slot", "bf:serve.prefill")) == 16
+    # the slot's sampler key is made inside the prefill program
+    assert [e for e in ev if e[0] == "bf:engine.seed_slot"] == []
     packs = inside(ev, "bf:serve.pack", "bf:serve.step")
     assert len(packs) == len(inside(ev, "bf:serve.deliver",
                                     "bf:serve.step")) == guard

@@ -617,6 +617,12 @@ _INPLACE_KINDS = {
 }
 
 
+def _state_bytes(cc):
+    """What a program's donated state holds on one device: the cache and
+    the sampler-key table (two uint32 a row)."""
+    return cc.bytes() + cc.rows * 2 * 4
+
+
 @pytest.fixture(scope="module")
 def inplace_engines(cpu_devices):
     """pp = 1 engines, warmed lazily and kept for the module."""
@@ -642,8 +648,8 @@ def inplace_engines(cpu_devices):
 ])
 def test_engine_programs_update_cache_in_place(inplace_engines, kind,
                                                family, count):
-    """The compiled programs alias their cache output to the donated
-    input (every byte of it) and hold no buffer as large as one layer's
+    """The compiled programs alias their cache and key-table outputs to the
+    donated inputs (every byte of them) and hold no buffer as large as one layer's
     pages: the compiler's temporaries are activations, the weights of one
     layer and the rows of the lanes.  A layer loop that scans OVER the
     cache (xs in, ys out) fails both ways: its stacked output is a fresh
@@ -655,7 +661,7 @@ def test_engine_programs_update_cache_in_place(inplace_engines, kind,
                 if k.startswith(family)}
     assert len(programs) == count, sorted(eng.program_memory())
     for name, mem in programs.items():
-        assert mem["alias_bytes"] == cc.bytes(), (name, mem)
+        assert mem["alias_bytes"] == _state_bytes(cc), (name, mem)
         assert mem["temp_bytes"] < layer_pages, (name, mem, layer_pages)
 
 
@@ -704,21 +710,23 @@ def _v5e_program(m, program, store, fast):
                        prefix_slots=scfg.prefix_pages)
     cache = {k: sds(v.shape, v.dtype)
              for k, v in jax.eval_shape(lambda: kv.init_cache(cc)).items()}
-    pre = (i32(S), i32(S)) if fast else (None, None)
-    lanes = (i32(S), i32(S), i32(S)) + pre
-    one = (i32(1), i32(1), i32(1), i32(1))
-    body, args = {
-        "decode": (eng._decode_body, lanes + (sds((S, 2), jnp.uint32),)),
-        "prefill64": (eng._prefill_body, (i32(64), i32(), i32())),
-        "prefill512": (eng._prefill_body, (i32(512), i32(), i32())),
-        "draft": (eng._draft_body, lanes),
-        "verify": (eng._chunk_body, (i32(S, 5),) + lanes[1:]),
-        "chunk512": (eng._chunk_body, (i32(1, 512),) + one),
+    keys = sds((cc.rows, 2), jnp.uint32)
+    # the one staged array of a call: tokens, then 4 integers a lane or a
+    # prompt (6 a chunk's lane)
+    body, staged = {
+        "decode": (eng._decode_body, i32(S, 1 + 4)),
+        "prefill64": (eng._prefill_body, i32(64 + 4)),
+        "prefill512": (eng._prefill_body, i32(512 + 4)),
+        "draft": (eng._draft_body, i32(S, 1 + 4)),
+        "verify": (eng._chunk_body, i32(S, 5 + 6)),
+        "chunk512": (eng._chunk_body, i32(1, 512 + 6)),
     }[program]
-    return eng._build(body).lower(params, cache, *args).compile(), cc
+    return eng._build(body).lower(params, cache, keys, staged).compile(), cc
 
 
 _SLOW = pytest.mark.slow
+# on the chip the key table's 66 words are padded to whole tiles
+_V5E_KEY_TABLE = 4096
 
 
 @pytest.mark.parametrize("program,store,fast", [
@@ -745,7 +753,8 @@ def test_engine_programs_in_place_on_v5e(v5e_chip, program, store, fast):
     from bluefog_tpu.utils.hlo_bytes import materialized
     compiled, cc = _v5e_program(v5e_chip, program, store, fast)
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == cc.bytes()
+    assert _state_bytes(cc) <= mem.alias_size_in_bytes \
+        <= cc.bytes() + _V5E_KEY_TABLE
     k_bytes = cc.bytes() // 2 if store == "raw" else \
         cc.layers * cc.rows * cc.kv_heads * cc.max_len * cc.head_dim
     assert materialized(compiled.as_text(), k_bytes // 2) == []
@@ -812,7 +821,8 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     elif check == "one_write_per_lane_after_it":
         assert outside == lanes * (4 if cc.quantized else 2)
     elif check == "aliased_whole":
-        assert mem.alias_size_in_bytes == cc.bytes()
+        assert _state_bytes(cc) <= mem.alias_size_in_bytes \
+            <= cc.bytes() + _V5E_KEY_TABLE
     elif check == "temporaries":
         assert mem.temp_size_in_bytes < cc.bytes() // cc.layers
     elif check == "no_half_tensor":
@@ -847,7 +857,7 @@ def test_cache_copy_gauge_set_at_warmup(cpu_devices):
     for name, row in mem.items():
         assert copy.value(program=name) == row["temp_bytes"]
         assert alias.value(program=name) == row["alias_bytes"] \
-            == eng.cache_cfg.bytes()
+            == _state_bytes(eng.cache_cfg)
         assert writes.value(program=name) == row["cache_writes"]
     # lanes x 2 tensors once after the layer loop for a decode token; a
     # prefill writes its one row once per layer (4) and tensor
@@ -1011,4 +1021,114 @@ def test_sampling_determinism(cpu_devices):
     assert first == second
     assert all(0 <= t < _CFG["vocab"] for t in first)
     # greedy config rejects a sampled-only code path ever engaging
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+
+
+# ---------------------------------------------------------------------------
+# The sampled stream, replayed on the host from the key schedule alone
+# ---------------------------------------------------------------------------
+
+_STREAM = dict(batch_buckets=(2,), prefill_buckets=(8, 16), slots=2,
+               max_len=32)
+
+
+def _replayed(ref, scfg, rows, replica, slot, count, prompt, n):
+    """The ``1 + n`` tokens of a request that was the engine's ``count``-th
+    admission, into ``slot`` of ``replica``: its first token greedy, then
+    each one drawn on the host from the key the schedule gives the slot
+    (``fold_in(fold_in(PRNGKey(seed), replica * rows + slot), count)``,
+    one ``split`` a sampled token), over the logits a greedy engine of the
+    same weights gives for the sequence so far."""
+    key = jax.random.fold_in(jax.random.fold_in(
+        jax.random.PRNGKey(scfg.seed), replica * rows + slot), count)
+    out = []
+    for i in range(1 + n):
+        lg = jnp.asarray(np.asarray(
+            ref.prefill(replica, 0, prompt + out)[1], np.float32))
+        if i == 0:
+            out.append(int(jnp.argmax(lg)))
+            continue
+        use, key = jax.random.split(key)
+        lg = lg / scfg.temperature
+        if scfg.top_p < 1.0:
+            srt = jnp.sort(lg)[::-1]
+            probs = jax.nn.softmax(srt)
+            kept = srt[(jnp.cumsum(probs) - probs) < scfg.top_p]
+            lg = jnp.where(lg >= kept.min(), lg, -jnp.inf)
+        out.append(int(jax.random.categorical(use, lg)))
+    return out
+
+
+@pytest.mark.parametrize("case", ["slot_reused", "prefix_hit"])
+@pytest.mark.parametrize("dp", [1, 2])
+@pytest.mark.parametrize("top_p", [1.0, 0.8])
+def test_sampled_stream_is_the_key_schedule_replayed(cpu_devices, top_p, dp,
+                                                     case):
+    """temperature 0.8: every request's tokens are what its slot's key
+    gives, whoever holds the keys between calls.  The key is made from
+    the seed, the slot's id over all replicas and the admission's number
+    (the warm-up's prefills count; a sealed prefix does not), so a slot's
+    second request draws another stream than its first, and a prefix hit
+    admitted through ``chunk_prefill`` draws the stream a whole prefill
+    would have."""
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(dp, 1, 1, 1, devices=cpu_devices[:dp])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    prefix = dict(prefix_pages=2, prefix_page_tokens=4) \
+        if case == "prefix_hit" else {}
+    scfg = ServeConfig(temperature=0.8, top_p=top_p, seed=11, **prefix,
+                       **_STREAM)
+    eng = ServeEngine(m, cfg, params, scfg)
+    eng.warmup()
+    ref = ServeEngine(m, cfg, params, ServeConfig(**_STREAM))
+    cc, last = eng.cache_cfg, dp - 1
+    admitted = len(scfg.prefill_buckets)          # the warm-up's prefills
+    reqs = []
+
+    def admit(replica, slot, prompt, lane, row=None):
+        nonlocal admitted
+        admitted += 1
+        if row is None:
+            first, _ = eng.prefill(replica, slot, prompt)
+        else:
+            first = eng.chunk_prefill(replica, slot, prompt[4:], 4, row)
+        reqs.append(dict(replica=replica, slot=slot, lane=lane, row=row,
+                         count=admitted, prompt=prompt, out=[first]))
+        return reqs[-1]
+
+    def decode(*live):
+        toks = np.zeros((dp, 2), np.int32)
+        slots = np.full((dp, 2), cc.trash_slot, np.int32)
+        lens, prows, plens = toks.copy(), slots.copy(), toks.copy()
+        for q in live:
+            at = q["replica"], q["lane"]
+            toks[at], slots[at] = q["out"][-1], q["slot"]
+            lens[at] = len(q["prompt"]) + len(q["out"]) - 1
+            if q["row"] is not None:
+                prows[at], plens[at] = q["row"], 4
+        gen = eng.decode(toks, slots, lens,
+                         *((prows, plens) if prefix else ()))
+        for q in live:
+            q["out"].append(int(gen[q["replica"], 0, q["lane"]]))
+
+    if case == "slot_reused":
+        a = admit(0, 0, [5, 6, 7], lane=0)
+        b = admit(last, 1, [9, 2, 4, 8, 1], lane=1)
+        for _ in range(4):
+            decode(a, b)
+        c = admit(0, 0, [5, 6, 7], lane=0)       # a's slot, a's prompt
+        for _ in range(4):
+            decode(c, b)
+        assert c["out"] != a["out"]
+    else:
+        shared = [3, 1, 4, 1]                    # one page
+        eng.seal_prefix(last, cc.slots, shared)
+        d = admit(last, 1, shared + [5, 9, 2], lane=0, row=cc.slots)
+        e = admit(0, 0, [7, 7, 1], lane=1)
+        for _ in range(5):
+            decode(d, e)
+    for q in reqs:
+        assert q["out"] == _replayed(
+            ref, scfg, cc.rows, q["replica"], q["slot"], q["count"],
+            q["prompt"], len(q["out"]) - 1), q
     assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0

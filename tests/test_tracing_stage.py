@@ -27,9 +27,10 @@ def _clean():
     bfflight.reset()
 
 
-def bf_events(trace_dir):
-    """[(name, start_ns, end_ns, stats)] of the trace's bf: events, in
-    time order, outermost first."""
+def bf_events(trace_dir, prefix="bf:"):
+    """[(name, start_ns, end_ns, stats)] of the trace's bf: events (or
+    those of another ``prefix``, or of a tuple of them), in time order,
+    outermost first."""
     path = sorted(glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
                             recursive=True))[-1]
     out = []
@@ -37,7 +38,7 @@ def bf_events(trace_dir):
         for line in plane.lines:
             out += [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns,
                      dict(ev.stats)) for ev in line.events
-                    if ev.name.startswith("bf:")]
+                    if ev.name.startswith(prefix)]
     return sorted(out, key=lambda e: (e[1], -e[2]))
 
 

@@ -17,6 +17,13 @@ a rotary part beside a part that is never turned, YaRN frequencies) in an
 unabsorbed form for whole sequences and an absorbed form over a cache of
 one compressed vector per token, and a gated SiLU FFN.  The serving
 engine's latent programs call it the same way.
+
+Third, the **hybrid block** (:class:`HybridConfig`, :func:`hybrid_block`):
+window and full attention layers in one model by a static layer plan,
+separate ``wq``/``wk``/``wv`` with fewer keys than queries, RMSNorm over
+each head of q and k, rotary on the window layers only, each sublayer's
+OUTPUT normed before it is added; a window layer's whole-sequence
+attention is a band of blocks (:func:`window_attention`).
 Imports jax only: the callers import this module, never the reverse.
 """
 import dataclasses
@@ -166,6 +173,10 @@ class LatentConfig:
     eps: float = 1e-6
 
     @property
+    def expert_layers(self) -> int:
+        return self.layers - 1
+
+    @property
     def latent_dim(self) -> int:
         """Values cached per token and layer: compressed kv + rotary key."""
         return self.kv_rank + self.rope_dim
@@ -281,6 +292,13 @@ def _wkvb(cfg: LatentConfig, lp: Dict[str, jax.Array]):
 SCORE_BYTES = 64 << 20
 
 
+def _chunks(n: int, row_bytes: int) -> int:
+    """The largest divisor ``c`` of ``n`` with ``c * row_bytes`` inside
+    :data:`SCORE_BYTES` (1 where not even one row is)."""
+    return max(c for c in range(1, n + 1)
+               if n % c == 0 and (c == 1 or c * row_bytes <= SCORE_BYTES))
+
+
 def mla_unabsorbed(cfg: LatentConfig, lp: Dict[str, jax.Array],
                    q_nope: jax.Array, q_rope: jax.Array,
                    latent: jax.Array) -> jax.Array:
@@ -305,8 +323,7 @@ def mla_unabsorbed(cfg: LatentConfig, lp: Dict[str, jax.Array],
                 jnp.where(causal[None], s * cfg.softmax_scale, -jnp.inf), -1)
             return jnp.einsum("hts,shd->thd", p.astype(v.dtype), v)
 
-        chunk = max(c for c in range(1, H + 1)
-                    if H % c == 0 and (c == 1 or c * T * T * 4 <= SCORE_BYTES))
+        chunk = _chunks(H, T * T * 4)
         wk, wv = _wkvb(cfg, lp)
         if chunk == H:
             return heads((q_nope, q_rope, wk, wv)).reshape(T, -1)
@@ -383,4 +400,198 @@ def latent_param_shapes(cfg: LatentConfig) -> Dict[str, Dict[str, tuple]]:
 def latent_param_count(cfg: LatentConfig) -> int:
     """Parameters this chip holds (:func:`latent_param_shapes`)."""
     return sum(math.prod(s) for group in latent_param_shapes(cfg).values()
+               for s in group.values())
+
+
+# ---------------------------------------------------------------------------
+# The hybrid block: window and full attention layers in one model, grouped
+# query heads with QK-norm, the norm on each sublayer's OUTPUT
+# ---------------------------------------------------------------------------
+
+LAYER_KINDS = ("window", "full")
+FFN_KINDS = ("dense", "experts")
+
+
+@dataclasses.dataclass(frozen=True)
+class HybridConfig:
+    """Sizes of a decoder whose layers attend in two ways, as ONE chip of
+    an expert-parallel deployment holds it.  ``plan`` is the layer plan,
+    one ``(kind, ffn)`` per layer: a ``"window"`` layer sees the last
+    ``window`` positions (the query's own included) and turns q and k by
+    rotary, a ``"full"`` layer sees every earlier position and turns
+    nothing; ``"dense"`` is one gated FFN of width ``dense_ffn``,
+    ``"experts"`` the sigmoid-routed layer of which this chip holds
+    ``held_experts`` from ``held_start`` beside the shared expert (the
+    held-experts contract of :class:`LatentConfig`).  ``heads`` query
+    heads share ``kv_heads`` keys and values, ``heads // kv_heads`` each."""
+    vocab: int
+    d_model: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    window: int
+    plan: Tuple[Tuple[str, str], ...]
+    dense_ffn: int
+    expert_ffn: int
+    num_experts: int                # the router's outputs
+    held_experts: int
+    top_k: int
+    route_scale: float
+    held_start: int = 0
+    n_group: int = 1                # 1 group of which 1 stays: plain top-k
+    topk_group: int = 1
+    rope_base: float = 1e6
+    eps: float = 1e-5
+
+    @property
+    def layers(self) -> int:
+        return len(self.plan)
+
+    @property
+    def expert_layers(self) -> int:
+        return sum(ffn == "experts" for _, ffn in self.plan)
+
+    def layers_of(self, kind: str) -> int:
+        return sum(k == kind for k, _ in self.plan)
+
+    def index_in_kind(self, layer: int) -> int:
+        """Which of its kind's layers ``layer`` is: its place in that
+        kind's part of the cache."""
+        kind = self.plan[layer][0]
+        return sum(k == kind for k, _ in self.plan[:layer])
+
+    def validate(self, m: Any) -> None:
+        for name in ("vocab", "d_model", "heads", "kv_heads", "head_dim",
+                     "window", "dense_ffn", "expert_ffn", "num_experts",
+                     "held_experts", "top_k", "n_group", "topk_group"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"HybridConfig.{name} must be >= 1")
+        if not self.plan or any(
+                len(p) != 2 or p[0] not in LAYER_KINDS or p[1] not in FFN_KINDS
+                for p in self.plan):
+            raise ValueError(
+                f"hybrid_layer_plan: every layer is (kind in {LAYER_KINDS}, "
+                f"ffn in {FFN_KINDS}), got {self.plan!r}")
+        if self.heads % self.kv_heads:
+            raise ValueError(
+                f"hybrid_grouped_heads: {self.heads} query heads do not "
+                f"share {self.kv_heads} key-value heads evenly")
+        if self.head_dim % 2:
+            raise ValueError("head_dim must be even")
+        if self.num_experts % self.n_group or \
+                not self.topk_group <= self.n_group or \
+                self.top_k > self.topk_group * (self.num_experts
+                                                // self.n_group):
+            raise ValueError(
+                f"hybrid_router_groups: {self.top_k} of {self.num_experts} "
+                f"experts in {self.n_group} groups of which "
+                f"{self.topk_group} are kept")
+        if not 0 <= self.held_start <= self.num_experts - self.held_experts:
+            raise ValueError(
+                f"hybrid_held_experts: experts {self.held_start}.."
+                f"{self.held_start + self.held_experts - 1} are not among "
+                f"the router's {self.num_experts}")
+
+
+def window_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     window: int) -> jax.Array:
+    """Causal attention of one whole sequence under a band, with grouped
+    heads: ``q`` ``[T, H, Dh]``, ``k``/``v`` ``[T, Hkv, Dh]``, q head ``h``
+    on kv head ``h // (H // Hkv)`` (K and V are never repeated in memory);
+    a query at ``t`` sees the keys ``t - window + 1 .. t``.  The sequence
+    is cut into blocks of ``window`` positions and a block of queries
+    meets its own block of keys and the one before it, nothing else: ``2
+    * window`` scores a query instead of ``T``.  Returns ``[T, H, Dh]``."""
+    T, H, Dh = q.shape
+    Hkv, W = k.shape[1], window
+    G, pad = H // Hkv, (-T) % W
+    if pad:                     # padding lies behind every real query
+        q, k, v = (jnp.pad(a, ((0, pad), (0, 0), (0, 0))) for a in (q, k, v))
+    nb = (T + pad) // W
+    qb = q.reshape(nb, W, Hkv, G, Dh)
+
+    def with_previous(a):                      # [nb, 2W, Hkv, Dh]
+        a = a.reshape(nb, W, Hkv, Dh)
+        return jnp.concatenate(
+            [jnp.concatenate([jnp.zeros_like(a[:1]), a[:-1]]), a], axis=1)
+    kk, vv = with_previous(k), with_previous(v)
+    # key b of block n sits at (n - 1) W + b, query a at n W + a
+    behind = W + jnp.arange(W)[:, None] - jnp.arange(2 * W)[None, :]
+    keep = (behind >= 0) & (behind < W)
+    keep = keep[None] & ((jnp.arange(nb) > 0)[:, None, None]
+                         | (jnp.arange(2 * W) >= W)[None, None, :])
+
+    def blocks(args):
+        qq, kc, vc, kp = args        # [c, W, Hkv, G, Dh], [c, 2W, Hkv, Dh]
+        s = jnp.einsum("nqkgd,nskd->nkgqs", qq, kc,
+                       preferred_element_type=jnp.float32) * Dh ** -0.5
+        p = jax.nn.softmax(jnp.where(kp[:, None, None], s, -jnp.inf), -1)
+        return jnp.einsum("nkgqs,nskd->nqkgd", p.astype(vc.dtype), vc,
+                          preferred_element_type=jnp.float32).astype(q.dtype)
+
+    c = _chunks(nb, H * W * 2 * W * 4)
+    if c == nb:
+        out = blocks((qb, kk, vv, keep))
+    else:
+        split = lambda a: a.reshape((nb // c, c) + a.shape[1:])
+        out = lax.map(blocks, tuple(map(split, (qb, kk, vv, keep))))
+    return out.reshape(nb * W, H, Dh)[:T]
+
+
+def hybrid_block(cfg: HybridConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                 positions: jax.Array, kind: str, attend: Callable,
+                 ffn: Callable) -> Tuple[jax.Array, Any, Any]:
+    """One block of a ``kind`` layer on ``x`` ``[..., D]``: each sublayer
+    reads the residual stream itself and its OUTPUT is normed before it
+    is added, ``x += RMS(Attn(x); g1)`` then ``x += RMS(FFN(x); g2)``.
+    Attention: separate ``wq`` / ``wk`` / ``wv`` (``heads`` queries,
+    ``kv_heads`` keys and values), RMSNorm over each head of q and k with
+    one scale vector for all heads (``gq``, ``gk``), then, on a
+    ``"window"`` layer only, rotary over the whole head.  ``attend(q, k,
+    v) -> (att, aux)`` takes ``[..., heads, head_dim]`` and ``[...,
+    kv_heads, head_dim]`` x 2 and returns the attention output in q's
+    shape plus what the caller's cache hooks made; ``ffn(lp, x) -> (y,
+    faux)`` as in :func:`decoder_block`."""
+    lead = x.shape[:-1]
+    with jax.named_scope(f"attn.{kind}"):
+        q = (x @ lp["wq"]).reshape(lead + (cfg.heads, cfg.head_dim))
+        k = (x @ lp["wk"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
+        v = (x @ lp["wv"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
+        q = rms_norm(q, lp["gq"], cfg.eps)
+        k = rms_norm(k, lp["gk"], cfg.eps)
+        if kind == "window":
+            q = rope(q, positions, cfg.rope_base)
+            k = rope(k, positions, cfg.rope_base)
+        att, aux = attend(q, k, v)
+        x = x + rms_norm(att.reshape(lead + (-1,)) @ lp["wo"], lp["g1"],
+                         cfg.eps)
+    y, faux = ffn(lp, x)
+    return x + rms_norm(y, lp["g2"], cfg.eps), aux, faux
+
+
+def hybrid_param_shapes(cfg: HybridConfig) -> Dict[str, Any]:
+    """The hybrid model's parameter tree as shapes: ``layers``, one dict
+    of leaves per layer of the plan (nothing stacked: a layer's leaves
+    are whole arrays and no program slices a stack), and ``shared``.
+    Names that start with ``g`` are RMSNorm scales; ``wr`` is the router,
+    kept in float32."""
+    D, Hd = cfg.d_model, cfg.head_dim
+    Fe, Eh = cfg.expert_ffn, cfg.held_experts
+    attn = {"wq": (D, cfg.heads * Hd), "wk": (D, cfg.kv_heads * Hd),
+            "wv": (D, cfg.kv_heads * Hd), "gq": (Hd,), "gk": (Hd,),
+            "wo": (cfg.heads * Hd, D), "g1": (D,), "g2": (D,)}
+    ffn = {"dense": {"wg": (D, cfg.dense_ffn), "wu": (D, cfg.dense_ffn),
+                     "wd": (cfg.dense_ffn, D)},
+           "experts": {"wr": (D, cfg.num_experts), "wsg": (D, Fe),
+                       "wsu": (D, Fe), "wsd": (Fe, D), "weg": (Eh, D, Fe),
+                       "weu": (Eh, D, Fe), "wed": (Eh, Fe, D)}}
+    return {"layers": tuple(dict(attn, **ffn[f]) for _, f in cfg.plan),
+            "shared": {"embed": (cfg.vocab, D), "head": (D, cfg.vocab),
+                       "gf": (D,)}}
+
+
+def hybrid_param_count(cfg: HybridConfig) -> int:
+    """Parameters this chip holds (:func:`hybrid_param_shapes`)."""
+    shapes = hybrid_param_shapes(cfg)
+    return sum(math.prod(s) for group in shapes["layers"] + (shapes["shared"],)
                for s in group.values())

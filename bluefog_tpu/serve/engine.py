@@ -66,6 +66,14 @@ decode in the absorbed form with the same deferred one-write-per-lane
 landing; the leading layer runs apart from the scan over the expert
 layers, one cache stacked over all.  Every fast path above and any
 carving with pp, tp or ep above 1 is refused for it by name.
+
+A **hybrid model** (:class:`~bluefog_tpu.models.decoder.HybridConfig`:
+window and full attention layers by a static plan, grouped-query heads
+with QK-norm, the same held-experts layers) has two programs of its own
+too, over a cache of two kinds (:class:`.kv_cache.HybridCacheConfig`: a
+slot's rows in the full layers, its rings in the window layers): the plan
+is unrolled, a prompt's attention is blocked, decode reads the cache in
+place, and the same fast paths and carvings are refused by name.
 """
 from __future__ import annotations
 
@@ -84,6 +92,7 @@ from ..models import decoder
 from ..moe.dropless import decode_tile
 from ..moe.layers import held_moe_ffn, moe_dropless_combine, router_topk
 from ..moe.model import MoELMConfig
+from ..ops import pallas_attention as _pa
 from ..ops import pallas_decode as _pd
 from ..ops.ulysses import dense_attention
 from ..parallel.compose import AXES, LMConfig, Mesh3D, draft_carve
@@ -441,7 +450,8 @@ class ServeEngine:
     """
 
     def __init__(self, m: Mesh3D,
-                 cfg: "LMConfig | decoder.LatentConfig", params: Any,
+                 cfg: "LMConfig | decoder.LatentConfig | decoder.HybridConfig",
+                 params: Any,
                  scfg: Optional[ServeConfig] = None):
         if m.sp != 1:
             raise ValueError(
@@ -449,8 +459,12 @@ class ServeEngine:
                 "no sequence to shard — fold sp into tp for inference")
         self._moe = isinstance(cfg, MoELMConfig)
         self._latent = isinstance(cfg, decoder.LatentConfig)
+        self._hybrid = isinstance(cfg, decoder.HybridConfig)
+        # one chip's share of an expert-parallel deployment: held experts
+        # under the full-width router, programs of the family's own
+        self._share = self._latent or self._hybrid
         # a call of these returns the routing carrier beside its tokens
-        self._routed = self._moe or self._latent
+        self._routed = self._moe or self._share
         if self._moe and cfg.router_mode == "expert_choice":
             raise ValueError(
                 "moe_serving_requires_topk_router: expert-choice routing "
@@ -464,8 +478,9 @@ class ServeEngine:
         if scfg.max_len < scfg.prefill_buckets[-1] + scfg.decode_window:
             raise ValueError("max_len leaves no room to decode past the "
                              "longest prompt bucket")
-        if self._latent:
-            self._refuse_latent(m, scfg)
+        if self._share:
+            self._refuse_share("latent" if self._latent else "hybrid", m,
+                               scfg)
         if scfg.moe_experts and not self._moe:
             raise ValueError(
                 f"ServeConfig declares an MoE (moe_experts="
@@ -491,6 +506,7 @@ class ServeEngine:
                 m.ep * scfg.batch_buckets[-1] * cfg.top_k, e_local)
             self._moe_chunk_tile = cfg.group_tile   # prefill/verify shapes
         self._route_stats: Optional[np.ndarray] = None
+        self._decode_logits = None      # (slots, device array): hybrid
         self.m, self.cfg, self.scfg = m, cfg, scfg
         self.draft = draft_carve(m, cfg, scfg.spec_stages) \
             if scfg.spec_decode else None
@@ -502,7 +518,12 @@ class ServeEngine:
         self.cache_cfg = _kv.LatentCacheConfig(
             layers=cfg.layers, slots=scfg.slots, max_len=scfg.max_len,
             kv_rank=cfg.kv_rank, rope_dim=cfg.rope_dim, dtype=scfg.dtype) \
-            if self._latent else _kv.KVCacheConfig(
+            if self._latent else _kv.HybridCacheConfig(
+            full_layers=cfg.layers_of("full"),
+            window_layers=cfg.layers_of("window"), slots=scfg.slots,
+            max_len=scfg.max_len, window=cfg.window, kv_heads=cfg.kv_heads,
+            head_dim=cfg.head_dim, dtype=scfg.dtype) \
+            if self._hybrid else _kv.KVCacheConfig(
             layers=cfg.layers // m.pp, slots=scfg.slots,
             max_len=scfg.max_len, kv_heads=cfg.heads // m.tp,
             head_dim=cfg.d_model // cfg.heads, dtype=scfg.dtype,
@@ -515,7 +536,7 @@ class ServeEngine:
         cc = self.cache_cfg
 
         def _zeros():
-            if self._latent:
+            if self._share:
                 return {name: jnp.zeros((1,) + shape, cc.dtype)
                         for name, shape in cc.shapes().items()}
             per_dev = (1, cc.layers, cc.rows, cc.kv_heads, cc.max_len,
@@ -539,15 +560,24 @@ class ServeEngine:
             mesh=m.mesh, in_specs=(), out_specs=P(AXES)),
             out_shardings=self._sharding)()
         self._decode_jit = self._build(
-            self._latent_decode_body if self._latent else self._decode_body)
+            self._latent_decode_body if self._latent else
+            self._hybrid_decode_body if self._hybrid else self._decode_body)
         self._prefill_jit = self._build(
-            self._latent_prefill_body if self._latent
+            self._latent_prefill_body if self._latent else
+            self._hybrid_prefill_body if self._hybrid
             else self._prefill_body)
-        if self._latent:
+        if self._share:
             _metrics.gauge(
                 "bluefog_serve_cache_bytes_per_token",
                 "device bytes one cached token costs over all layers"
             ).set(float(cc.bytes_per_token()))
+        if self._hybrid:
+            for kind, size in cc.bytes_per_slot().items():
+                _metrics.gauge(
+                    "bluefog_serve_cache_bytes_per_slot",
+                    "device bytes of the cache a slot owns, by kind of "
+                    "layer (full: every position; window: a ring)"
+                ).set(float(size), kind=kind)
         self._chunk_jit = self._build(self._chunk_body) \
             if (scfg.spec_decode or scfg.prefix_pages) else None
         self._draft_jit = self._build(self._draft_body) \
@@ -557,28 +587,44 @@ class ServeEngine:
         self._program_bytes: dict = {}
         self._engine_trace = _tracing.new_trace("engine")
 
-    @staticmethod
-    def _refuse_latent(m: Mesh3D, scfg: ServeConfig) -> None:
-        """What the latent programs do not do yet, refused by name before
-        anything is built (a later PR each)."""
+    # what a held-experts family's programs do not do yet, by family: the
+    # reason each ServeConfig fast path is refused (a later PR each)
+    _SHARE_REFUSALS = {
+        "latent": dict(
+            decode_kernel="the flash-decode kernel streams per-head K and V "
+                          "pages; the latent cache holds one vector per token",
+            kv_dtype="the latent cache has no quantized store",
+            spec_decode="there is no truncated-stage draft of a latent "
+                        "model",
+            prefix_pages="the latent cache has no shared prefix rows"),
+        "hybrid": dict(
+            decode_kernel="the flash-decode kernel reads a slot's row up to "
+                          "its length; it has no ring",
+            kv_dtype="the two-kind cache has no quantized store",
+            spec_decode="the model's own drafter layer is not served, and "
+                        "there is no truncated-stage draft of one chip's "
+                        "share",
+            prefix_pages="a ring holds a prompt's END: it cannot lend rows "
+                         "to a shared prefix"),
+    }
+
+    @classmethod
+    def _refuse_share(cls, family: str, m: Mesh3D, scfg: ServeConfig) -> None:
+        """Refuse by name (``<family>_serving_<what>``), before anything is
+        built, what the programs of a held-experts ``family`` do not do."""
+        why = cls._SHARE_REFUSALS[family]
         if (m.pp, m.tp, m.ep) != (1, 1, 1):
             raise ValueError(
-                f"latent_serving_carving: pp={m.pp} tp={m.tp} ep={m.ep} — "
-                "the latent programs run one chip's share of each layer; "
-                "the experts it holds are named in the LatentConfig, "
+                f"{family}_serving_carving: pp={m.pp} tp={m.tp} ep={m.ep} — "
+                f"the {family} programs run one chip's share of each layer; "
+                "the experts it holds are named in the model's config, "
                 "replicas (dp) are the only carving")
-        for bad, name, why in (
-                (scfg.decode_kernel != "xla", "latent_serving_decode_kernel",
-                 "the flash-decode kernel streams per-head K and V pages; "
-                 "the latent cache holds one vector per token"),
-                (scfg.kv_dtype != "raw", "latent_serving_kv_dtype",
-                 "the latent cache has no quantized store"),
-                (scfg.spec_decode, "latent_serving_spec_decode",
-                 "there is no truncated-stage draft of a latent model"),
-                (scfg.prefix_pages, "latent_serving_prefix_pages",
-                 "the latent cache has no shared prefix rows")):
+        for bad, what in ((scfg.decode_kernel != "xla", "decode_kernel"),
+                          (scfg.kv_dtype != "raw", "kv_dtype"),
+                          (scfg.spec_decode, "spec_decode"),
+                          (scfg.prefix_pages, "prefix_pages")):
             if bad:
-                raise ValueError(f"{name}: {why}")
+                raise ValueError(f"{family}_serving_{what}: {why[what]}")
 
     def _stage(self, name: str, **attrs) -> _tracing.stage:
         """``bf:engine.<name>`` in the profiler's trace (and the ring when
@@ -1161,6 +1207,161 @@ class ServeEngine:
         nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
+    # ------------------------------------------------------------------
+    # the hybrid model's programs (window and full layers, one chip's share)
+    # ------------------------------------------------------------------
+
+    # a prompt's expert layers run over this many tokens at a time: the
+    # grouped kernel's buffers hold top_k rows a token, 0.8 GB at 8,192
+    _PROMPT_FFN_CHUNK = 2048
+
+    def _hybrid_ffn(self, ffn_kind, live, grouped):
+        """A hybrid layer's ``ffn`` hook by the plan's ``ffn_kind``: the
+        dense gated FFN, or the held-experts layer with its carrier
+        (:meth:`_latent_ffn`).  ``grouped`` (a prompt): the pairs through
+        the grouped kernel, :attr:`_PROMPT_FFN_CHUNK` tokens at a time,
+        and no carrier (nothing reads a prompt's)."""
+        if ffn_kind == "dense":
+            return lambda lp, h: (decoder.dense_gated_ffn(lp, h)[0], 0.0)
+        if not grouped:
+            return self._latent_ffn(live)
+        cfg = self.cfg
+
+        def ffn(lp, h):
+            stack = {**lp, **{k: lp[k][None] for k in ("weg", "weu", "wed")}}
+            part = lambda hl: held_moe_ffn(cfg, stack, hl[0], hl[1],
+                                           layer=jnp.int32(0))[0]
+            T, C = h.shape[0], self._PROMPT_FFN_CHUNK
+            if T <= C or T % C:
+                return part((h, live)), 0.0
+            y = lax.map(part, (h.reshape(T // C, C, -1),
+                               live.reshape(T // C, C)))
+            return y.reshape(h.shape), 0.0
+        return ffn
+
+    def _hybrid_layers(self, params, x, cache, positions, attend_with, live,
+                       grouped):
+        """The plan's layers, unrolled (they differ in kind and
+        feed-forward on no common period), one cache tree carried
+        through: ``attend_with(kind, index, cache)`` builds the ``attend``
+        hook of the ``index``-th layer of its kind, whose ``aux`` is
+        ``(cache, new)`` as in :meth:`_latent_layers`.  Returns ``(x,
+        cache, news, carrier)``, ``news`` each layer's ``new`` in plan
+        order."""
+        cfg = self.cfg
+        news, acc = [], jnp.zeros((cfg.num_experts + 4,), jnp.float32)
+        for i, (kind, ffn_kind) in enumerate(cfg.plan):
+            x, (cache, new), vec = decoder.hybrid_block(
+                cfg, params["layers"][i], x, positions, kind,
+                attend_with(kind, cfg.index_in_kind(i), cache),
+                self._hybrid_ffn(ffn_kind, live, grouped))
+            news.append(new)
+            acc = acc + vec
+        return x, cache, news, acc
+
+    def _hybrid_decode_body(self, params, cache, keys, lanes):
+        """Fused decode over the two-kind cache: a full layer attends over
+        its lanes' rows, a window layer over their rings, each plus the
+        token's own K and V; the tokens of all layers land once per lane
+        and tensor after the loop (a ring's at ``length mod window``).
+        Beside the tokens it hands out every fused step's logits (left on
+        the device: :meth:`decode_logits`) and, behind the routing
+        carrier, the cache positions its attention met, by kind."""
+        params, cache, table, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
+        toks, keys = toks[:, 0], table[slot_ids]
+        cfg, shared = self.cfg, params["shared"]
+        live = slot_ids < self.scfg.slots
+
+        def step(carry, _):
+            toks, lens, cache, keys, st = carry
+            met = dict.fromkeys(_kv.KIND_TENSORS, 0)
+
+            # the layers only read the cache: it stays out of their carry
+            def attend_with(kind, index, _):
+                kn, vn = _kv.KIND_TENSORS[kind]
+
+                def attend(q, k, v):            # [S, H, Dh], [S, Hkv, Dh]
+                    new = _kv.token_pages(k, v, "raw", cache[kn].dtype)
+                    out, read = _kv.attend_slots(
+                        q, cache[kn][index], cache[vn][index], slot_ids,
+                        lens, new, ring=kind == "window")
+                    met[kind] += read
+                    return out, (None, new)
+                return attend
+
+            x, _, news, acc = self._hybrid_layers(
+                params, shared["embed"][toks], None, lens, attend_with, live,
+                grouped=False)
+            stacked = {}
+            for kind, names in _kv.KIND_TENSORS.items():
+                mine = [n for n, (k, _) in zip(news, cfg.plan) if k == kind]
+                for name, part in zip(names, ("k", "v")):
+                    stacked[name] = jnp.stack([n[part] for n in mine])
+            cache = _kv.hybrid_append_tokens(cache, slot_ids, lens, stacked)
+            logits = decoder.latent_logits(cfg, shared, x)
+            nxt, keys = self._next_token(logits, keys)
+            nxt = nxt.astype(toks.dtype)
+            acc = jnp.concatenate([acc, jnp.array(
+                [met[kind] for kind in _kv.KIND_TENSORS], jnp.float32)])
+            return (nxt, lens + 1, cache, keys, st + acc), (
+                nxt, logits.astype(jnp.float32))
+
+        st0 = jnp.zeros((cfg.num_experts + 4 + len(_kv.KIND_TENSORS),),
+                        jnp.float32)
+        (_, _, cache, keys, st), (gen, logits) = lax.scan(
+            step, (toks, lens, cache, keys, st0), None,
+            length=self.scfg.decode_steps_per_call)
+        table = table.at[slot_ids].set(keys)
+        return jax.tree.map(lambda t: t[None],
+                            (gen, st, logits, table, cache))
+
+    @staticmethod
+    def _flash_causal(q, k, v):
+        """Causal attention of one whole prompt on a full layer, ``q``
+        ``[T, H, Dh]`` on compact ``k``/``v`` ``[T, Hkv, Dh]``: the flash
+        forward kernel (K and V of a head whole in VMEM, queries in
+        blocks, scores never in HBM).  At 8,192 positions of 64 heads on a
+        v5e it takes 21.5 ms where XLA's blocked form took 58.7; under a
+        window of 128 the kernel still meets every key (21.4 ms) and the
+        band of :func:`decoder.window_attention` takes 2.9 (PERF.md §6,
+        PR 35), so window layers do not come here."""
+        o, l, _ = _pa.attention_block_partial(
+            q[None], k[None], v[None], jnp.int32(0), jnp.int32(0),
+            causal=True, scale=q.shape[-1] ** -0.5)
+        return (o / l[..., None])[0].astype(q.dtype)
+
+    def _hybrid_prefill_body(self, params, cache, keys, staged):
+        """One padded prompt, its attention blocked (the flash forward
+        kernel on a full layer, the band on a window layer); a full
+        layer's K and V land in the slot's row, a window layer's last
+        ``window`` real positions in its ring.  Padding is routed to no
+        expert, and only the last real position is read out."""
+        params, cache, keys, staged = self._split_args(
+            (params, cache, keys, staged))
+        keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
+        cfg, shared = self.cfg, params["shared"]
+        positions = jnp.arange(toks.shape[0])
+
+        def attend_with(kind, index, cache):
+            def attend(q, k, v):            # [Tpad, H, Dh], [Tpad, Hkv, Dh]
+                nc = _kv.hybrid_prefill(cache, kind, index, slot_id, k, v,
+                                        true_len)
+                att = decoder.window_attention(q, k, v, cfg.window) \
+                    if kind == "window" else self._flash_causal(q, k, v)
+                return att, (nc, None)
+            return attend
+
+        x, cache, _, _ = self._hybrid_layers(
+            params, shared["embed"][toks], cache, positions, attend_with,
+            positions < true_len, grouped=True)
+        last = decoder.latent_logits(
+            cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
+        ).astype(jnp.float32)
+        nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
+
     def _count_held_work(self, lanes: int, lens, slots) -> None:
         """After a latent decode call: the routing carrier's held-expert
         counts into the fleet's counters, and a ``bf:engine.held_work``
@@ -1171,7 +1372,7 @@ class ServeEngine:
         E = cfg.num_experts
         pairs = int(self._route_stats[:, E + 2].sum())
         hit = int(self._route_stats[:, E + 3].sum())
-        rows = (self.m.dp * lanes * cfg.held_experts * (cfg.layers - 1)
+        rows = (self.m.dp * lanes * cfg.held_experts * cfg.expert_layers
                 * scfg.decode_steps_per_call)
         _metrics.counter(
             "bluefog_serve_moe_held_pairs_total",
@@ -1186,10 +1387,26 @@ class ServeEngine:
             "bluefog_serve_moe_decode_calls_total",
             "decode calls of a held-experts model").inc()
         live = slots < scfg.slots
-        with self._stage("held_work", pairs=pairs, rows=rows,
-                         experts_hit=hit,
-                         positions=int(np.asarray(lens)[live].sum()
-                                       + live.sum())):
+        seen = np.asarray(lens)[live] + 1       # a lane's live positions
+        attrs = dict(pairs=pairs, rows=rows, experts_hit=hit,
+                     positions=int(seen.sum()))
+        if self._hybrid:
+            # what a window layer may see of the lanes' positions, and
+            # what the program's attention met of either kind (the
+            # carrier's last entries, summed over that kind's layers and
+            # the fused steps: here per layer and step)
+            attrs["positions_window"] = int(
+                np.minimum(seen, cfg.window).sum())
+            for i, kind in enumerate(_kv.KIND_TENSORS):
+                met = int(self._route_stats[:, E + 4 + i].sum())
+                attrs[f"positions_read_{kind}"] = met // (
+                    cfg.layers_of(kind) * scfg.decode_steps_per_call)
+                _metrics.counter(
+                    "bluefog_serve_cache_positions_read_total",
+                    "cache positions the decode programs' attention met, "
+                    "by kind of layer (summed over that kind's layers and "
+                    "the fused steps)").inc(met, kind=kind)
+        with self._stage("held_work", **attrs):
             pass
 
     # ------------------------------------------------------------------
@@ -1408,12 +1625,32 @@ class ServeEngine:
             with self._stage("collect"):
                 self._check_program(f"decode S={S}", self._decode_jit, args,
                                     writes)
+                if self._hybrid:
+                    self._decode_logits = (np.array(slots, np.int32),
+                                           out.pop())
                 gen, *st = self._collect("decode", *out)
                 if st:
                     self._note_route_stats(st[0])
-                if self._latent:
+                if self._share:
                     self._count_held_work(S, lens, np.asarray(slots))
                 return gen
+
+    def decode_logits(self, replica: int
+                      ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
+        """What the last :meth:`decode` call of the hybrid family chose
+        its tokens from: ``replica``'s lanes' slots ``[S]`` and their
+        logits ``[decode_steps_per_call, S, vocab]`` (float32), which stay
+        on the device until something converts them, as a prefill's do.
+        ``Scheduler`` never reads them; the benchmark's comparison with
+        the reference does, so that what decode READS of the cache is
+        held to it number by number.  ``None`` before the first call and
+        for the other families, whose programs hand out tokens alone."""
+        if self._decode_logits is None:
+            return None
+        slots, logits = self._decode_logits
+        return slots[replica], _DeviceRow(
+            logits, replica * self.m.slice_size,
+            lambda: self._count_crossing("decode", "out"))
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
                     lens: np.ndarray,
@@ -1500,7 +1737,9 @@ class ServeEngine:
         layers, summed router entropy, live token-layer count).  A latent
         model's carrier counts every selection, not the first alone, and
         has two more entries: the pairs that fell on held experts, and the
-        (layer, held expert) groups that got a token."""
+        (layer, held expert) groups that got a token; a hybrid model's has
+        two more behind those: the cache positions its attention met on
+        full and on window layers."""
         self._route_stats = st.astype(np.float64)
 
     def moe_load(self) -> Optional[list]:
@@ -1599,6 +1838,9 @@ class ServeEngine:
         steps = {"decode": scfg.decode_steps_per_call,
                  "draft": scfg.spec_decode}.get(kind, 1)
         deferred = kind in ("decode", "draft") and self._defer_appends
+        if self._hybrid and not deferred:
+            # a layer writes the K and V of its own kind
+            return lanes * 2 * self.cfg.layers
         return (lanes * len(self.cache) * hops * steps
                 * (1 if deferred else self.cache_cfg.layers))
 

@@ -60,6 +60,15 @@ on-chip memory; each lane's window is laid out only after the write
 before it, or all of them (6 MiB each, one position padded to the 128 of
 a tile) are held at once.
 
+**Two kinds of layer in one model** (:class:`HybridCacheConfig`): full
+layers keep every position of a slot, window layers a ring of ``window``
+positions (position ``p`` at ``p mod window``); a slot owns its row of
+both, a prompt lands whole in the one and by its last ``window`` positions
+in the other (:func:`hybrid_prefill`), a decode token once per lane and
+tensor after the layers (:func:`hybrid_append_tokens`), and decode reads
+the pages in place (:func:`attend_slots`).  :class:`LatentCacheConfig`
+is the cache of one compressed vector per token.
+
 The pure functions here (:func:`layer_append`, :func:`attend_rows`,
 :func:`attend_chunk`, ...) are the single-device math the engine's
 shard_map body calls per layer; they are also unit-tested directly (GQA
@@ -85,7 +94,9 @@ from jax.experimental.layout import Layout, with_layout_constraint
 from ..ops.collectives import _amax_scale
 from ..utils import metrics as _metrics
 
-__all__ = ["KVCacheConfig", "LatentCacheConfig", "latent_prefill",
+__all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
+           "hybrid_prefill", "hybrid_append_tokens", "attend_slots",
+           "latent_prefill",
            "latent_append_tokens", "latent_attend_rows", "init_cache",
            "attend_rows",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
@@ -725,6 +736,176 @@ def latent_attend_rows(q_abs: jax.Array, q_rope: jax.Array,
     p = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
     return jnp.einsum("shl,slc->shc", p.astype(dt), rows["ckv"],
                       preferred_element_type=jnp.float32).astype(q_abs.dtype)
+
+
+# ---------------------------------------------------------------------------
+# The two-kind cache: full layers keep every position, window layers a ring
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class HybridCacheConfig:
+    """Shapes of a cache with two kinds of layer, K and V per compact kv
+    head as in :class:`KVCacheConfig`::
+
+        k,  v:  [full_layers,   slots + 1, kv_heads, max_len, head_dim]
+        kw, vw: [window_layers, slots + 1, kv_heads, window,  head_dim]
+
+    A full layer keeps every position of a slot.  A window layer keeps a
+    **ring** of the last ``window`` positions, position ``p`` at index
+    ``p mod window``: what a query at ``t`` may see there (``t - window +
+    1 .. t``) is exactly what the ring holds once the token at ``t`` has
+    replaced the one at ``t - window``.  A slot owns its row of both
+    kinds; the last row is the trash slot.  No prefix pages (a ring
+    cannot lend rows to a shared prefix: it holds a prompt's END) and no
+    quantized store."""
+    full_layers: int
+    window_layers: int
+    slots: int
+    max_len: int
+    window: int
+    kv_heads: int
+    head_dim: int
+    dtype: Any = jnp.float32
+    prefix_slots = 0                # what the engine's host code asks for
+
+    @property
+    def rows(self) -> int:
+        return self.slots + 1
+
+    @property
+    def trash_slot(self) -> int:
+        return self.slots
+
+    def shapes(self) -> Dict[str, Tuple[int, ...]]:
+        full = (self.full_layers, self.rows, self.kv_heads, self.max_len,
+                self.head_dim)
+        ring = (self.window_layers, self.rows, self.kv_heads, self.window,
+                self.head_dim)
+        return {"k": full, "v": full, "kw": ring, "vw": ring}
+
+    def _position_bytes(self) -> int:
+        """K and V of one position in one layer."""
+        return (2 * self.kv_heads * self.head_dim
+                * jnp.dtype(self.dtype).itemsize)
+
+    def bytes_per_token(self) -> int:
+        """Device bytes one more cached token costs: the full layers'
+        alone, a ring's size does not grow with the sequence."""
+        return self.full_layers * self._position_bytes()
+
+    def bytes_per_slot(self) -> Dict[str, int]:
+        """Device bytes a slot owns, by kind of layer."""
+        return {"full": self.full_layers * self.max_len
+                * self._position_bytes(),
+                "window": self.window_layers * self.window
+                * self._position_bytes()}
+
+    def bytes(self) -> int:
+        return self.rows * sum(self.bytes_per_slot().values())
+
+
+# the K and V tensor of each kind of layer
+KIND_TENSORS = {"full": ("k", "v"), "window": ("kw", "vw")}
+
+
+def hybrid_prefill(cache: Dict[str, jax.Array], kind: str, layer: int,
+                   slot_id: jax.Array, k: jax.Array, v: jax.Array,
+                   true_len: jax.Array) -> Dict[str, jax.Array]:
+    """Land a padded prompt's kv (``[Tpad, kv_heads, head_dim]``) in row
+    ``slot_id`` of the ``layer``-th layer of its ``kind``.  A full layer
+    takes positions ``0..Tpad-1`` as :func:`layer_prefill` writes them.  A
+    window layer takes the prompt's LAST ``window`` real positions,
+    position ``p`` at ring index ``p mod window`` (of a prompt shorter
+    than the window every position, at its own index; what lies behind
+    ``true_len`` is garbage the length mask never reads)."""
+    kn, vn = KIND_TENSORS[kind]
+    out = dict(cache)
+    if kind == "window":
+        W = cache[kn].shape[3]
+        first = jnp.maximum(true_len - W, 0)
+        at = first + (jnp.arange(W) - first) % W     # the p = j (mod W) kept
+        k, v = k[at], v[at]
+    max_len = cache[kn].shape[3]
+    for name, pay in ((kn, k), (vn, v)):
+        out[name] = lax.dynamic_update_slice(
+            cache[name], _pin_window(
+                pay.transpose(1, 0, 2)[None, None].astype(cache[name].dtype),
+                max_len), (layer, slot_id, 0, 0, 0))
+    return out
+
+
+def hybrid_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
+                         lengths: jax.Array, new: Dict[str, jax.Array]
+                         ) -> Dict[str, jax.Array]:
+    """One decode token per lane into EVERY layer of both kinds at once,
+    after the layer loop (:func:`append_tokens`): ``new`` holds per cache
+    tensor its layers' pages stacked ``[layers, S, kv_heads, head_dim]``;
+    a full layer's land at ``lengths[i]``, a ring's at ``lengths[i] mod
+    window``.  One ``dynamic_update_slice`` per lane and tensor."""
+    W = cache["kw"].shape[3]
+    ring = KIND_TENSORS["window"]
+    return {name: _write_tokens(
+        t, slots, lengths % W if name in ring else lengths, new[name])
+        for name, t in cache.items()}
+
+
+def attend_slots(q: jax.Array, kt: jax.Array, vt: jax.Array,
+                 slots: jax.Array, lengths: jax.Array,
+                 new: Dict[str, jax.Array], *,
+                 ring: bool = False) -> Tuple[jax.Array, int]:
+    """Decode attention of one new token per lane over one layer's pages
+    ``kt``/``vt`` ``[rows, kv_heads, L, head_dim]`` (a static slice of the
+    cache), q head ``h`` on compact kv head ``h // group`` in one
+    grouped-head einsum.  Returns the lanes' result and the cache
+    POSITIONS the einsum met (rows x ``L``: what the program reads of this
+    layer, whatever the lanes' lengths).
+
+    The pages are read IN PLACE: the lanes' queries are laid out by row
+    (``q`` ``[S, heads, head_dim]`` to row ``slots[i]``), every row meets
+    its own pages, and the lanes' rows of the result are read back.
+    Staging each lane's row first (:func:`attend_rows`) reads it, writes
+    it and reads it again: 0.86 GB a tensor and layer, 4.04 GB of
+    temporaries a program against 0.11, at 48 lanes of 8,704 positions of
+    8 heads of 128.  So only where the lanes are under a third of the
+    rows, where three passes over theirs cost less than one over all, are
+    the lanes' rows staged and met alone.
+
+    The token itself is not in the pages yet (``new``: its
+    :func:`token_pages`, ``[S, kv_heads, head_dim]``) and is attended
+    beside them: positions ``0 .. lengths[i] - 1`` of the pages are
+    valid, or in a ``ring`` of ``L`` positions (position ``p`` at index
+    ``p mod L``) the entries written so far but the one the token will
+    replace, ``lengths[i] mod L``; their order inside the ring does not
+    matter to a softmax, the keys were turned before they were stored.
+    The matmuls take the pages in their own dtype and accumulate in
+    float32; softmax in float32."""
+    S, H, Dh = q.shape
+    Hkv, L = kt.shape[1:3]
+    if H % Hkv:
+        raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
+    scale = Dh ** -0.5
+    if 3 * S < kt.shape[0]:
+        kt, vt, slots = kt[slots], vt[slots], jnp.arange(S)
+    R = kt.shape[0]
+    by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
+    qr = by_row(q.astype(kt.dtype)).reshape(R, Hkv, H // Hkv, Dh)
+    kn, vn, at = by_row(new["k"]), by_row(new["v"]), by_row(lengths)
+    j = jnp.arange(L)[None, :]
+    valid = j < at[:, None]
+    if ring:
+        valid = valid & (j != (at % L)[:, None])
+    s = jnp.einsum("rkgd,rkld->rkgl", qr, kt,
+                   preferred_element_type=jnp.float32) * scale
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    sn = jnp.einsum("rkgd,rkd->rkg", qr, kn,
+                    preferred_element_type=jnp.float32) * scale
+    m = jnp.maximum(jnp.max(s, -1), sn)
+    p, pn = jnp.exp(s - m[..., None]), jnp.exp(sn - m)
+    out = jnp.einsum("rkgl,rkld->rkgd", p.astype(vt.dtype), vt,
+                     preferred_element_type=jnp.float32) \
+        + pn[..., None] * vn[:, :, None, :].astype(jnp.float32)
+    out = out / (jnp.sum(p, -1) + pn)[..., None]
+    return out.reshape(R, H, Dh)[slots].astype(q.dtype), R * L
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,12 @@ attends over the cached rows plus its own token, the tokens of all layers
 leave the loop as the scan's stacked output and land in the cache once
 per lane and tensor after it (``bluefog_serve_cache_writes_per_call``);
 only the Pallas flash-decode kernel, which reads its pages from HBM
-itself, has them written per layer.
+itself, has them written per layer.  That attention meets the layer's
+pages where they lie, every row its own query, in all three families
+(``program_memory()[...]["read"] == "in_place"``,
+``bluefog_serve_cache_positions_read_total{kind}``); shared prefix pages,
+a quantized store and a bucket under a third of the rows stage each
+lane's row first, as the cache's own properties say.
 Steady-state decode is a single cached program per (bucket,
 steps_per_call): embed → pp-cycle of stage-local layer loops
 (``ppermute`` moves the activation, a stage-id ``where`` keeps exactly
@@ -794,11 +799,25 @@ class ServeEngine:
     def _defer_appends(self) -> bool:
         """Whether a decode token's kv reaches the cache after the layer
         loop (one write per lane and tensor) instead of inside it (one
-        per lane, tensor and layer).  The XLA attention stages each
-        lane's rows and takes the token beside them; the flash-decode
-        kernel streams its pages from HBM in place, so its token has to
-        be written before it runs."""
+        per lane, tensor and layer).  The XLA attention takes the token
+        beside the pages it reads (in place, or staged:
+        :attr:`_read_in_place`); the flash-decode kernel streams its
+        pages from HBM itself, so its token has to be written before it
+        runs."""
         return self.scfg.decode_kernel != "pallas"
+
+    @property
+    def _read_in_place(self) -> bool:
+        """Whether a decode step's attention meets a layer's pages where
+        they lie, every row its own query (:func:`.kv_cache.attend_layer`,
+        ``latent_attend_slots``, ``attend_slots``), instead of staging
+        each lane's row first.  A property of the cache alone: shared
+        prefix pages have several lanes attend one row, a quantized
+        store's scales are not folded into the by-row products, and a
+        token already written (the flash-decode kernel's order) is not
+        beside the pages; those keep :func:`.kv_cache.attend_rows`."""
+        return (self._defer_appends and not self._use_prefix
+                and self.scfg.kv_dtype == "raw")
 
     def _layer_step(self, lp, x, cache, layer, slot_ids, lens, prows, plens,
                     draft=False):
@@ -822,6 +841,9 @@ class ServeEngine:
                     k_scale=cl.get("k_scale"), v_scale=cl.get("v_scale"),
                     prefix_slots=prows, prefix_lens=plens,
                     block_k=self.scfg.decode_block_k)
+            elif self._read_in_place:
+                att, _ = _kv.attend_layer(q, c["k"], c["v"], layer, slot_ids,
+                                          lens, new)
             else:
                 att = _kv.attend_rows(q, c["k"], c["v"], slot_ids, lens,
                                       k_scale=c.get("k_scale"),
@@ -1157,10 +1179,11 @@ class ServeEngine:
             def attend_with(lp, _, layer):
                 def attend(q_nope, q_rope, latent):         # [S, H, .]
                     with jax.named_scope("mla.attend"):
-                        u = _kv.latent_attend_rows(
+                        u, _ = _kv.latent_attend_slots(
                             decoder.mla_absorb_q(cfg, lp, q_nope), q_rope,
                             cache, layer, slot_ids, lens, latent,
-                            cfg.softmax_scale)
+                            cfg.softmax_scale,
+                            stage=None if self._read_in_place else True)
                         return decoder.mla_unabsorb_out(cfg, lp, u), \
                             (None, latent)
                 return attend
@@ -1401,13 +1424,34 @@ class ServeEngine:
                 met = int(self._route_stats[:, E + 4 + i].sum())
                 attrs[f"positions_read_{kind}"] = met // (
                     cfg.layers_of(kind) * scfg.decode_steps_per_call)
-                _metrics.counter(
-                    "bluefog_serve_cache_positions_read_total",
-                    "cache positions the decode programs' attention met, "
-                    "by kind of layer (summed over that kind's layers and "
-                    "the fused steps)").inc(met, kind=kind)
+                self._count_positions(met, kind)
         with self._stage("held_work", **attrs):
             pass
+
+    def _read_form(self, lanes: int) -> str:
+        """How a decode program of ``lanes`` lanes meets the cache:
+        ``"in_place"`` or ``"staged"`` (:attr:`_read_in_place`, and a
+        bucket under a third of the rows stages its lanes' rows)."""
+        return "in_place" if self._read_in_place and _kv.read_in_place(
+            lanes, self.cache_cfg.rows) else "staged"
+
+    def _count_positions(self, met: int, kind: str) -> None:
+        _metrics.counter(
+            "bluefog_serve_cache_positions_read_total",
+            "cache positions the decode programs' attention met, by kind "
+            "of layer (full, window, latent; summed over that kind's "
+            "layers and the fused steps)").inc(met, kind=kind)
+
+    def _count_decode_read(self, lanes: int) -> None:
+        """After a dense or latent decode call: what its attention met of
+        the cache, from shapes alone (every row of every layer whole where
+        the read is in place, the lanes' rows where it is staged; a hybrid
+        program sums its two kinds behind its carrier)."""
+        cc = self.cache_cfg
+        rows = cc.rows if self._read_form(lanes) == "in_place" else lanes
+        self._count_positions(
+            self.m.dp * self.cfg.layers * self.scfg.decode_steps_per_call
+            * rows * cc.max_len, "latent" if self._latent else "full")
 
     # ------------------------------------------------------------------
     # host-side surface (per-REPLICA shapes; the engine broadcasts each
@@ -1624,7 +1668,7 @@ class ServeEngine:
                 *out, self._keys, self.cache = self._decode_jit(*args)
             with self._stage("collect"):
                 self._check_program(f"decode S={S}", self._decode_jit, args,
-                                    writes)
+                                    writes, self._read_form(S))
                 if self._hybrid:
                     self._decode_logits = (np.array(slots, np.int32),
                                            out.pop())
@@ -1633,6 +1677,8 @@ class ServeEngine:
                     self._note_route_stats(st[0])
                 if self._share:
                     self._count_held_work(S, lens, np.asarray(slots))
+                if not self._hybrid:
+                    self._count_decode_read(S)
                 return gen
 
     def decode_logits(self, replica: int
@@ -1694,7 +1740,8 @@ class ServeEngine:
             drafts, self._keys, self.cache = self._draft_jit(*args)
         with self._stage("collect"):
             self._check_program(f"draft S={S}", self._draft_jit, args,
-                                self._cache_writes("draft", S))
+                                self._cache_writes("draft", S),
+                                self._read_form(S))
             drafts, = self._collect("draft", drafts)    # [R, k, S]
         d = np.transpose(drafts, (0, 2, 1))             # [R, S, k]
         # verify chunk: [t0, d_1 .. d_k] per lane — the draft rows it
@@ -1844,12 +1891,14 @@ class ServeEngine:
         return (lanes * len(self.cache) * hops * steps
                 * (1 if deferred else self.cache_cfg.layers))
 
-    def _check_program(self, program: str, fn, args, writes: int) -> None:
+    def _check_program(self, program: str, fn, args, writes: int,
+                       read: Optional[str] = None) -> None:
         """After every device call: the first time a program (``"decode
         S=32"``, ``"prefill Tpad=64"``, ...) is seen, record what the
-        compiler built for it and its ``writes`` into the cache a call
-        (:meth:`_cache_writes`); once warm, any growth of the jit caches
-        is a retrace."""
+        compiler built for it, its ``writes`` into the cache a call
+        (:meth:`_cache_writes`) and, of a program that attends over the
+        cache one token a lane, how it ``read``s it (:meth:`_read_form`);
+        once warm, any growth of the jit caches is a retrace."""
         if program not in self._program_bytes:
             # the executable the call above compiled, found again through
             # jit's own caches: nothing is traced or compiled a second
@@ -1860,6 +1909,8 @@ class ServeEngine:
                 "temp_bytes": int(ma.temp_size_in_bytes),
                 "alias_bytes": int(ma.alias_size_in_bytes),
                 "cache_writes": writes}
+            if read is not None:
+                self._program_bytes[program]["read"] = read
             _metrics.gauge(
                 "bluefog_serve_cache_copy_bytes",
                 "temporaries the compiler allocated for one engine program "
@@ -1892,5 +1943,7 @@ class ServeEngine:
         the cache is updated in place is a property of the compiled
         program, so this is its counter — ``alias_bytes`` is the size of
         the cache and the key table, and ``temp_bytes`` stays under one
-        layer's pages when it is."""
+        layer's pages when it is.  A decode or draft program also says
+        how its attention ``read``s the cache: ``"in_place"`` (no staging
+        buffer among its temporaries) or ``"staged"``."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

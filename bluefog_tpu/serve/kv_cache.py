@@ -41,24 +41,39 @@ keeps the payload in ``dtype`` (f32 or bf16) with no scales.
 
 **A decode token reaches the cache once, after the layers.**  On the
 engine's XLA path each layer hands its new token's pages
-(:func:`token_pages`) to :func:`attend_rows` beside the cache (``new=``:
-position ``lengths[i]`` reads the token, dequantized as its page would
-be), the layer loop stacks them as its output, and
-:func:`append_tokens` writes ``t[:, slot, :, length]`` with one
-``dynamic_update_slice`` per lane and tensor.  :func:`layer_append`, the
-write per lane, tensor AND layer, stays for the flash-decode kernel,
-which streams its pages from HBM and so needs the token there before it
-runs; chunks (:func:`layer_append_chunk`) and prompts
-(:func:`layer_prefill`) are written per layer as before.  Three things
-hold the TPU's compiler to this (each found by compiling the serving
-cell's decode program for a described v5e, ``tests/test_serve_fast.py``):
-every window written and every row read is pinned to the cache's own
-axis order (:func:`_pin_window`), or the compiler copies both tensors
-whole into another; V's rows are read only after the softmax, or both
-tensors' staged rows are alive at once and one of them leaves the chip's
-on-chip memory; each lane's window is laid out only after the write
-before it, or all of them (6 MiB each, one position padded to the 128 of
-a tile) are held at once.
+(:func:`token_pages`) to the attention beside the cache, the layer loop
+stacks them as its output, and :func:`append_tokens` writes ``t[:, slot,
+:, length]`` with one ``dynamic_update_slice`` per lane and tensor.
+:func:`layer_append`, the write per lane, tensor AND layer, stays for the
+flash-decode kernel, which streams its pages from HBM and so needs the
+token there before it runs; chunks (:func:`layer_append_chunk`) and
+prompts (:func:`layer_prefill`) are written per layer as before.
+
+**Decode attention meets the pages where they lie, in every family**
+(:func:`_attend_by_row` under :func:`attend_layer`,
+:func:`latent_attend_slots` and :func:`attend_slots`): the lanes' queries
+are laid out by row, every row meets its own pages in one grouped einsum
+that takes the layer's slice of the stacked tensor as its operand
+(:func:`_layer_pages`), the token is attended beside the pages, and the
+lanes' rows of the result are read back: one pass over K and one over V a
+layer.  The staged form (:func:`attend_rows` through
+:func:`_gather_pages`: each lane's row read into a buffer of its own,
+2 MB a lane and tensor, and read again by the attention) stays where
+"one query a row" does not hold or is not priced: shared prefix pages
+(several lanes attend one row), a quantized store (the scales are not
+folded into the by-row products), the k-token forms
+(:func:`attend_chunk`), a token already written (the flash-decode
+kernel's order) and a decode bucket under a third of the rows
+(:func:`read_in_place`).  Three things hold the TPU's compiler to the
+writes and to either read (each found by compiling the serving cell's
+decode program for a described v5e, ``tests/test_serve_fast.py``): every
+window written, every row staged and the layer's slice read in place is
+pinned to the cache's own axis order (:func:`_pin_window`), or the
+compiler copies both tensors whole into another; where rows are staged
+V's are read only after the softmax, or both tensors' staged rows are
+alive at once and one of them leaves the chip's on-chip memory; each
+lane's window is laid out only after the write before it, or all of them
+(6 MiB each, one position padded to the 128 of a tile) are held at once.
 
 **Two kinds of layer in one model** (:class:`HybridCacheConfig`): full
 layers keep every position of a slot, window layers a ring of ``window``
@@ -67,10 +82,12 @@ both, a prompt lands whole in the one and by its last ``window`` positions
 in the other (:func:`hybrid_prefill`), a decode token once per lane and
 tensor after the layers (:func:`hybrid_append_tokens`), and decode reads
 the pages in place (:func:`attend_slots`).  :class:`LatentCacheConfig`
-is the cache of one compressed vector per token.
+is the cache of one compressed vector per token, which every head of a
+layer shares (:func:`latent_attend_slots`).
 
-The pure functions here (:func:`layer_append`, :func:`attend_rows`,
-:func:`attend_chunk`, ...) are the single-device math the engine's
+The pure functions here (:func:`layer_append`, :func:`attend_layer`,
+:func:`attend_rows`, :func:`attend_chunk`, ...) are the single-device
+math the engine's
 shard_map body calls per layer; they are also unit-tested directly (GQA
 grouping, slot-reuse equivalence after evict, quantization drift bounds,
 the deferred write against the per-layer one).
@@ -97,8 +114,8 @@ from ..utils import metrics as _metrics
 __all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
            "hybrid_prefill", "hybrid_append_tokens", "attend_slots",
            "latent_prefill",
-           "latent_append_tokens", "latent_attend_rows", "init_cache",
-           "attend_rows",
+           "latent_append_tokens", "latent_attend_slots", "init_cache",
+           "attend_rows", "attend_layer", "read_in_place",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
            "layer_append_chunk", "layer_prefill", "quantize_rows",
            "dequantize_rows", "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -423,8 +440,9 @@ def layer_append(cache: Dict[str, jax.Array], layer: jax.Array,
     One write per lane, tensor AND layer: the flash-decode kernel reads
     its pages from HBM itself, so its token has to be there before it
     runs.  The XLA attention takes the token beside the pages
-    (:func:`attend_rows`'s ``new``) and the engine lands all layers'
-    tokens at once after the layer loop (:func:`append_tokens`)."""
+    (:func:`attend_layer`, or :func:`attend_rows`'s ``new``) and the
+    engine lands all layers' tokens at once after the layer loop
+    (:func:`append_tokens`)."""
     return layer_append_chunk(cache, layer, slots, lengths, k_new[:, None],
                               v_new[:, None], store)
 
@@ -524,6 +542,91 @@ def _gather_pages(cl: Dict[str, jax.Array], name: str, slots: jax.Array,
     return out
 
 
+def read_in_place(lanes: int, rows: int) -> bool:
+    """Whether a decode step of ``lanes`` lanes meets a layer's ``rows``
+    rows where they lie: one pass over all of them costs less than the
+    three passes (read, write, read again) over the lanes' own, staged,
+    unless the lanes are under a third of the rows."""
+    return 3 * lanes >= rows
+
+
+def _layer_pages(t: jax.Array, layer: jax.Array, pin) -> jax.Array:
+    """``t[layer]`` of a stacked cache tensor as an operand the compiler
+    reads where it lies: the layer's slice ``[1, rows, ...]``, ``pin``-ned
+    to the cache's own axis order and fused into the einsum that takes it,
+    the way a scanned layer's weights reach their matmul.  Unpinned, in a
+    loop that only reads the cache, the TPU's compiler copies the whole
+    tensor into the order a matmul would like (at the serving cell's
+    sizes two copies, ``head_dim`` padded from 64 to 128: 6.65 GB of
+    temporaries)."""
+    window = lax.dynamic_slice(t, (layer,) + (0,) * (t.ndim - 1),
+                               (1,) + t.shape[1:])
+    return pin(window)[0]
+
+
+def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
+                   vt: jax.Array, slots: jax.Array, lengths: jax.Array,
+                   kns: Sequence[jax.Array], vn: jax.Array,
+                   scale: Optional[float], *, ring: bool = False,
+                   probs: Any = None, stage: Optional[bool] = None
+                   ) -> Tuple[jax.Array, int]:
+    """The decode read every family shares: one new token per lane
+    attends over one layer's pages IN PLACE.  The lanes' queries are laid
+    out by row (lane ``i`` to row ``slots[i]``), every row meets its own
+    pages in one grouped einsum, and the lanes' rows of the result are
+    read back; the trash row and the rows of no lane compute what nobody
+    reads.
+
+    The score is a sum of parts (one for K and V per head, two for the
+    latent cache): ``qs[j]`` ``[S, heads, d_j]`` on ``kts[j]`` ``[rows,
+    kv_heads, L, d_j]``, q head ``h`` on kv head ``h // group``, times
+    ``scale`` (None: folded into the queries); ``vt`` ``[rows, kv_heads,
+    L, dv]`` holds the values.  The token itself is not in the pages yet
+    (``kns[j]`` ``[S, kv_heads, d_j]``, ``vn``) and is attended beside
+    them, no select over whole rows: positions ``0 .. lengths[i] - 1`` of
+    the pages are valid, or in a ``ring`` of ``L`` positions (position
+    ``p`` at index ``p mod L``) the entries written so far but the one
+    the token will replace.  Every operand keeps the dtype it comes in,
+    products accumulate in float32, the softmax over pages and token is
+    float32, and the probabilities enter the value product as ``probs``
+    (None: float32 as they are).
+
+    ``stage`` (None: decided from the shapes, :func:`read_in_place`)
+    gathers the lanes' rows first and meets them alone.  Returns the
+    lanes' result ``[S, heads, dv]`` in float32 and the cache positions
+    the einsums met."""
+    S, H = qs[0].shape[:2]
+    R, Hkv, L, Dv = vt.shape
+    if H % Hkv:
+        raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
+    if (not read_in_place(S, R)) if stage is None else stage:
+        kts, vt = [kt[slots] for kt in kts], vt[slots]
+        slots, R = jnp.arange(S), S
+    by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
+    qs = [by_row(q).reshape(R, Hkv, H // Hkv, -1) for q in qs]
+    kns, vn, at = [by_row(kn) for kn in kns], by_row(vn), by_row(lengths)
+    j = jnp.arange(L)[None, :]
+    valid = j < at[:, None]
+    if ring:
+        valid = valid & (j != (at % L)[:, None])
+    f32 = dict(preferred_element_type=jnp.float32)
+    total = lambda parts: sum(parts[1:], parts[0])
+    s = total([jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
+               for q, kt in zip(qs, kts)])
+    sn = total([jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
+                for q, kn in zip(qs, kns)])
+    if scale is not None:
+        s, sn = s * scale, sn * scale
+    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    m = jnp.maximum(jnp.max(s, -1), sn)
+    p, pn = jnp.exp(s - m[..., None]), jnp.exp(sn - m)
+    out = jnp.einsum("rkgl,rkld->rkgd",
+                     p if probs is None else p.astype(probs), vt, **f32) \
+        + pn[..., None] * vn[:, :, None, :].astype(jnp.float32)
+    out = out / (jnp.sum(p, -1) + pn)[..., None]
+    return out.reshape(R, H, Dv)[slots], R * L
+
+
 def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
                 slots: jax.Array, lengths: jax.Array,
                 scale: Optional[float] = None, *,
@@ -577,6 +680,36 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     vs = _gather_pages(cl, "v", *pages)
     out = jnp.einsum("skgl,skld->skgd", p, vs.astype(ct))
     return out.reshape(S, H, Dh).astype(q.dtype)
+
+
+def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
+                 layer: jax.Array, slots: jax.Array, lengths: jax.Array,
+                 new: Dict[str, jax.Array], scale: Optional[float] = None
+                 ) -> Tuple[jax.Array, int]:
+    """:func:`attend_rows` of raw pages without prefix rows, read IN
+    PLACE: one new token per lane (``q`` ``[S, heads, head_dim]``, its
+    ``new`` :func:`token_pages` not yet written) over ``layer`` of the
+    stacked cache tensors ``kl``/``vl`` ``[layers, rows, kv_heads,
+    max_len, head_dim]``; ``layer`` may be a scanned index.  The same
+    arithmetic as the staged form (the scale folded into float32 queries,
+    pages in their own dtype, exact products, float32 softmax and float32
+    probabilities into the value product) in another order of summation.
+    A bucket under a third of the rows (:func:`read_in_place`) takes the
+    staged form itself.  Returns the lanes' result and the cache
+    positions met."""
+    S, R, L = q.shape[0], kl.shape[1], kl.shape[3]
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    if not read_in_place(S, R):
+        return attend_rows(q, kl, vl, slots, lengths, scale, layer=layer,
+                           new=new), S * L
+    ct = jnp.promote_types(q.dtype, jnp.float32)
+    pin = lambda w: _pin_window(w, L)
+    out, met = _attend_by_row(
+        (q.astype(ct) * scale,), (_layer_pages(kl, layer, pin),),
+        _layer_pages(vl, layer, pin), slots, lengths, (new["k"],),
+        new["v"], None, stage=False)
+    return out.astype(q.dtype), met
 
 
 def attend_chunk(q: jax.Array, cl: Dict[str, jax.Array], slots: jax.Array,
@@ -704,38 +837,38 @@ def latent_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
         for name, part in _split_latent(cache, new).items()}
 
 
-def latent_attend_rows(q_abs: jax.Array, q_rope: jax.Array,
-                       cache: Dict[str, jax.Array], layer: jax.Array,
-                       slots: jax.Array, lengths: jax.Array,
-                       new: jax.Array, scale: float) -> jax.Array:
+def latent_attend_slots(q_abs: jax.Array, q_rope: jax.Array,
+                        cache: Dict[str, jax.Array], layer: jax.Array,
+                        slots: jax.Array, lengths: jax.Array,
+                        new: jax.Array, scale: float, *,
+                        stage: Optional[bool] = None
+                        ) -> Tuple[jax.Array, int]:
     """Absorbed decode attention of one new token per lane over its row
     of the latent cache: ``q_abs`` ``[S, H, kv_rank]`` is the query moved
     into the compressed space, ``q_rope`` ``[S, H, rope]`` its rotary
     part, ``new`` ``[S, kv_rank + rope]`` the token's own vector (not yet
-    written: position ``lengths[i]`` reads it).  ``score = (q_abs . ckv +
-    q_rope . k_rope) * scale`` in float32 over positions ``0 ..
-    lengths[i]``; returns the attended compressed vectors ``[S, H,
-    kv_rank]``.  Each lane's row is one ``dynamic_slice`` at ``[layer,
-    slot]`` per tensor (:func:`_read_lanes`'s reason: a gather makes the
-    compiler cut the whole cache)."""
-    S, L = slots.shape[0], cache["ckv"].shape[2]
-    here = (jnp.arange(L)[None, :] == lengths[:, None])[..., None]
-    rows = {}
-    for name, part in _split_latent(cache, new).items():
-        t = cache[name]
-        r = jnp.concatenate([_pin_latent(lax.dynamic_slice(
-            t, (layer, slots[i], 0, 0), (1, 1) + t.shape[2:]), L)[0]
-            for i in range(S)])                               # [S, L, dim]
-        rows[name] = jnp.where(here, part.astype(t.dtype)[:, None], r)
+    written, attended beside the pages).  ``score = (q_abs . ckv + q_rope
+    . k_rope) * scale`` in float32 over positions ``0 .. lengths[i] - 1``
+    and the token; returns the attended compressed vectors ``[S, H,
+    kv_rank]`` and the cache positions met.  The layer's pages (``layer``
+    of the stacked cache, a scanned index or a static one) are read IN
+    PLACE, every head on the one vector a position has
+    (:func:`_attend_by_row` with one shared kv head): the compressed
+    vectors come from HBM twice a layer, for the scores and for the
+    weighted sum, where staging each lane's row (``[128, 2560, 512]``:
+    336 MB a layer at 128 lanes of 2,560) wrote them once more and read
+    them twice."""
+    L = cache["ckv"].shape[2]
     dt = cache["ckv"].dtype
-    s = (jnp.einsum("shc,slc->shl", q_abs.astype(dt), rows["ckv"],
-                    preferred_element_type=jnp.float32)
-         + jnp.einsum("shr,slr->shl", q_rope.astype(dt), rows["kr"],
-                      preferred_element_type=jnp.float32)) * scale
-    valid = jnp.arange(L)[None, :] <= lengths[:, None]
-    p = jax.nn.softmax(jnp.where(valid[:, None], s, -jnp.inf), axis=-1)
-    return jnp.einsum("shl,slc->shc", p.astype(dt), rows["ckv"],
-                      preferred_element_type=jnp.float32).astype(q_abs.dtype)
+    pages = {name: _layer_pages(t, layer, lambda w: _pin_latent(w, L))
+             [:, None] for name, t in cache.items()}        # [R, 1, L, dim]
+    tok = {name: part.astype(dt)[:, None]                   # [S, 1, dim]
+           for name, part in _split_latent(cache, new).items()}
+    out, met = _attend_by_row(
+        (q_abs.astype(dt), q_rope.astype(dt)), (pages["ckv"], pages["kr"]),
+        pages["ckv"], slots, lengths, (tok["ckv"], tok["kr"]), tok["ckv"],
+        scale, probs=dt, stage=stage)
+    return out.astype(q_abs.dtype), met
 
 
 # ---------------------------------------------------------------------------
@@ -860,52 +993,24 @@ def attend_slots(q: jax.Array, kt: jax.Array, vt: jax.Array,
     POSITIONS the einsum met (rows x ``L``: what the program reads of this
     layer, whatever the lanes' lengths).
 
-    The pages are read IN PLACE: the lanes' queries are laid out by row
-    (``q`` ``[S, heads, head_dim]`` to row ``slots[i]``), every row meets
-    its own pages, and the lanes' rows of the result are read back.
-    Staging each lane's row first (:func:`attend_rows`) reads it, writes
-    it and reads it again: 0.86 GB a tensor and layer, 4.04 GB of
-    temporaries a program against 0.11, at 48 lanes of 8,704 positions of
-    8 heads of 128.  So only where the lanes are under a third of the
-    rows, where three passes over theirs cost less than one over all, are
-    the lanes' rows staged and met alone.
+    The pages are read IN PLACE (:func:`_attend_by_row`).  Staging each
+    lane's row first (:func:`attend_rows`) reads it, writes it and reads
+    it again: 0.86 GB a tensor and layer, 4.04 GB of temporaries a program
+    against 0.11, at 48 lanes of 8,704 positions of 8 heads of 128.  So
+    only where the lanes are under a third of the rows, where three passes
+    over theirs cost less than one over all, are the lanes' rows staged
+    and met alone.
 
     The token itself is not in the pages yet (``new``: its
     :func:`token_pages`, ``[S, kv_heads, head_dim]``) and is attended
-    beside them: positions ``0 .. lengths[i] - 1`` of the pages are
-    valid, or in a ``ring`` of ``L`` positions (position ``p`` at index
-    ``p mod L``) the entries written so far but the one the token will
-    replace, ``lengths[i] mod L``; their order inside the ring does not
-    matter to a softmax, the keys were turned before they were stored.
-    The matmuls take the pages in their own dtype and accumulate in
-    float32; softmax in float32."""
-    S, H, Dh = q.shape
-    Hkv, L = kt.shape[1:3]
-    if H % Hkv:
-        raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
-    scale = Dh ** -0.5
-    if 3 * S < kt.shape[0]:
-        kt, vt, slots = kt[slots], vt[slots], jnp.arange(S)
-    R = kt.shape[0]
-    by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
-    qr = by_row(q.astype(kt.dtype)).reshape(R, Hkv, H // Hkv, Dh)
-    kn, vn, at = by_row(new["k"]), by_row(new["v"]), by_row(lengths)
-    j = jnp.arange(L)[None, :]
-    valid = j < at[:, None]
-    if ring:
-        valid = valid & (j != (at % L)[:, None])
-    s = jnp.einsum("rkgd,rkld->rkgl", qr, kt,
-                   preferred_element_type=jnp.float32) * scale
-    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
-    sn = jnp.einsum("rkgd,rkd->rkg", qr, kn,
-                    preferred_element_type=jnp.float32) * scale
-    m = jnp.maximum(jnp.max(s, -1), sn)
-    p, pn = jnp.exp(s - m[..., None]), jnp.exp(sn - m)
-    out = jnp.einsum("rkgl,rkld->rkgd", p.astype(vt.dtype), vt,
-                     preferred_element_type=jnp.float32) \
-        + pn[..., None] * vn[:, :, None, :].astype(jnp.float32)
-    out = out / (jnp.sum(p, -1) + pn)[..., None]
-    return out.reshape(R, H, Dh)[slots].astype(q.dtype), R * L
+    beside them; with ``ring`` the pages are a ring of ``L`` positions,
+    whose order does not matter to a softmax: the keys were turned before
+    they were stored.  The matmuls take the pages in their own dtype and
+    accumulate in float32; softmax in float32."""
+    out, met = _attend_by_row(
+        (q.astype(kt.dtype),), (kt,), vt, slots, lengths, (new["k"],),
+        new["v"], q.shape[-1] ** -0.5, ring=ring, probs=vt.dtype)
+    return out.astype(q.dtype), met
 
 
 # ---------------------------------------------------------------------------

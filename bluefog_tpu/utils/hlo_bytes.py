@@ -130,6 +130,16 @@ _FORWARDING = frozenset((
     "dynamic-update-slice"))
 
 
+def _dims(shape_txt: str) -> tuple:
+    """``bf16[1,33,16]`` -> ``(33, 16)``: the dimensions, leading ones
+    dropped."""
+    d = [int(x) for x in shape_txt[shape_txt.index("[") + 1:-1].split(",")
+         if x]
+    while d[:1] == [1]:
+        d.pop(0)
+    return tuple(d)
+
+
 def _result_bytes(type_txt: str) -> int:
     return sum(_shape_bytes(m.group(0))
                for m in _SHAPES_RE.finditer(type_txt))
@@ -180,10 +190,13 @@ def loop_writes(hlo_txt: str, elements: int) -> tuple:
     return tuple(count)
 
 
-def materialized(hlo_txt: str, min_bytes: int):
+def materialized(hlo_txt: str, min_bytes: int, dims: tuple = None):
     """``[(name, opcode, bytes), ...]`` of the instructions of a compiled
     module whose result is a buffer of their own of at least ``min_bytes``
-    (a tuple result counts the sum of its elements).
+    (a tuple result counts the sum of its elements); with ``dims`` only
+    those whose result holds an array of exactly these dimensions, leading
+    ones apart and whatever its dtype (a staged copy of the rows ``[lanes,
+    heads, max_len, head_dim]``, a layer of the cache).
 
     Left out: instructions inside fused computations (a fusion's interior
     lives in registers), results that forward an operand's buffer
@@ -191,24 +204,28 @@ def materialized(hlo_txt: str, min_bytes: int):
     place (``dynamic-update-slice``, and a fusion that holds one of its
     own result's size).  What is left at cache size in a serving program
     is a copy of the cache."""
-    comps = {c: [(name, op, _result_bytes(type_txt), line)
+    comps = {c: [(name, op, _result_bytes(type_txt), line, type_txt)
                  for name, op, type_txt, line in rows]
              for c, rows in _computations(hlo_txt).items()}
-    fused = {name for rows in comps.values() for _, op, _, line in rows
+    fused = {name for rows in comps.values() for _, op, _, line, _ in rows
              if op == "fusion"
              for name in re.findall(r"calls=%?([\w.\-]+)", line)}
     out = []
     for comp, rows in comps.items():
         if comp in fused:
             continue
-        for name, op, size, line in rows:
+        for name, op, size, line, type_txt in rows:
             if size < min_bytes or op in _FORWARDING:
+                continue
+            if dims is not None and tuple(dims) not in (
+                    _dims(m.group(0))
+                    for m in _SHAPES_RE.finditer(type_txt)):
                 continue
             if op == "fusion":
                 inner = re.search(r"calls=%?([\w.\-]+)", line)
                 if inner and any(
                         o == "dynamic-update-slice" and b == size
-                        for _, o, b, _ in comps.get(inner.group(1), ())):
+                        for _, o, b, *_ in comps.get(inner.group(1), ())):
                     continue
             out.append((name, op, size))
     return out

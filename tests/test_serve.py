@@ -51,6 +51,7 @@ from bluefog_tpu.serve.kv_cache import (KVCacheConfig, append_tokens,
 from bluefog_tpu.utils import chaos as bfchaos
 from bluefog_tpu.utils import flight as bfflight
 from bluefog_tpu.utils import metrics as bfm
+from attend_oracle import token_beside_pages_oracle
 from test_tracing_stage import bf_events, inside
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
@@ -233,6 +234,82 @@ def test_deferred_append_equals_per_layer_append(store, case):
 # ---------------------------------------------------------------------------
 
 _CFG = dict(vocab=32, d_model=32, heads=4, layers=4, seq_len=32)
+
+
+_IN_PLACE_SHAPES = {
+    # case: (kv heads, q heads per kv head, head_dim, max_len, whether a
+    # TPU keeps the positions, not head_dim, in its lanes)
+    "one_head_a_kv_head": (2, 1, 128, 24, False),
+    "grouped": (2, 3, 8, 16, True),
+    # the cell's page: head_dim 64 under max_len 1024
+    "positions_minor": (2, 1, 64, 1024, True),
+}
+
+
+@pytest.mark.parametrize("against", ["oracle", "staged", "scanned_layer"])
+@pytest.mark.parametrize("case", sorted(_IN_PLACE_SHAPES))
+def test_attend_layer_reads_in_place(case, against):
+    """``attend_layer`` (the dense family's decode read: queries laid out
+    by row, the stacked cache at a layer index, the token beside the
+    pages) against the float64 dense oracle, against the staged form it
+    replaces (``attend_rows(new=...)``), and with the layer a scanned
+    index under ``jit`` as the engine's layer loop hands it over.  Lanes
+    in arbitrary slot order with the trash row (the last) among them
+    twice, a length of 0, a lane at ``max_len - 1``."""
+    import jax
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    Hkv, G, Dh, L, minor = _IN_PLACE_SHAPES[case]
+    assert kv._positions_minor(Dh, L) == minor
+    layers, rows = 3, 6
+    rng = np.random.default_rng(sorted(_IN_PLACE_SHAPES).index(case))
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    kl, vl = (normal(layers, rows, Hkv, L, Dh) for _ in range(2))
+    slots = jnp.array([3, 5, 0, 5, 1], jnp.int32)
+    lens = jnp.array([L - 1, 0, 0, 2, 7], jnp.int32)
+    S, live = slots.shape[0], np.asarray(slots) < rows - 1
+    q = normal(S, Hkv * G, Dh)
+    new = token_pages(normal(S, Hkv, Dh), normal(S, Hkv, Dh), "raw",
+                      jnp.float32)
+    got, met = kv.attend_layer(q, kl, vl, 1, slots, lens, new)
+    assert met == rows * L                  # every row whole, in place
+    if against == "oracle":
+        want = token_beside_pages_oracle(
+            [q], [kl[1]], vl[1], slots, lens, [new["k"]], new["v"],
+            Dh ** -0.5)
+    elif against == "staged":
+        want = attend_rows(q, kl, vl, slots, lens, layer=1, new=new)
+    else:
+        want = got
+
+        @jax.jit
+        def scanned(q, kl, vl, slots, lens, new):
+            def body(_, layer):
+                return None, kv.attend_layer(q, kl, vl, layer, slots, lens,
+                                             new)[0]
+            return jax.lax.scan(body, None, jnp.arange(layers))[1]
+        got = scanned(q, kl, vl, slots, lens, new)[1]
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-6)
+
+
+def test_attend_layer_stages_a_bucket_under_a_third_of_the_rows():
+    """Two lanes of seven rows: three passes over theirs cost less than
+    one over all, so the lanes' rows are staged (``attend_rows``), as
+    ``read_in_place`` says from the shapes alone."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    assert kv.read_in_place(2, 6) and not kv.read_in_place(2, 7)
+    rng = np.random.default_rng(5)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    kl, vl = normal(2, 7, 2, 16, 8), normal(2, 7, 2, 16, 8)
+    slots, lens = jnp.array([4, 1]), jnp.array([9, 0])
+    q = normal(2, 4, 8)
+    new = token_pages(normal(2, 2, 8), normal(2, 2, 8), "raw", jnp.float32)
+    got, met = kv.attend_layer(q, kl, vl, 1, slots, lens, new)
+    assert met == 2 * 16
+    np.testing.assert_array_equal(
+        got, attend_rows(q, kl, vl, slots, lens, layer=1, new=new))
 
 
 @pytest.fixture(scope="module")

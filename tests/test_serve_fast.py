@@ -788,11 +788,24 @@ def _loop_body(hlo_txt):
     return hlo_txt[start:hlo_txt.index("\n}\n", start)].splitlines()
 
 
-@pytest.mark.parametrize("check", [
-    "no_write_in_the_loop", "one_write_per_lane_after_it", "aliased_whole",
-    "temporaries", "no_half_tensor", "rows_staged_on_chip"])
-@pytest.mark.parametrize("store", [
-    "raw", pytest.param("int8", marks=_SLOW)])
+def _results_of_shape(hlo_lines, dims):
+    """Those of ``hlo_lines`` (a compiled module's instructions, inside
+    fusions too) whose result is an array of exactly ``dims``, whatever
+    its dtype."""
+    import re
+    shape = re.compile(r"^\(?\w+\[%s\]" % ",".join(map(str, dims)))
+    return [ln for ln in hlo_lines
+            if " = " in ln and shape.match(ln.split(" = ", 1)[1])]
+
+
+_DECODE_CHECKS = ["no_write_in_the_loop", "one_write_per_lane_after_it",
+                  "aliased_whole", "temporaries", "no_half_tensor"]
+
+
+@pytest.mark.parametrize("store,check", [
+    ("raw", c) for c in _DECODE_CHECKS + ["no_rows_staged"]] + [
+    pytest.param("int8", c, marks=_SLOW)
+    for c in _DECODE_CHECKS + ["rows_staged_on_chip"]])
 def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     """The decode program at the serving cell's sizes, as the chip's own
     compiler builds it: the layer loop's body updates no cache-shaped
@@ -803,10 +816,15 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     aliased whole, all temporaries together stay under one layer's pages
     (every lane's update window laid out at once, each padded from one
     position to the 128 of a tile, was 280 MB), nothing materialises half
-    a K tensor, and each tensor's staged rows (32 lanes x 2 MB, built by
-    one row read per lane) have their turn in the chip's on-chip memory
-    (``S(1)`` in the layout; V's are read once K's are dead), not a
-    round trip through HBM per layer."""
+    a K tensor.  The raw store's attention reads the pages where they
+    lie: no instruction, inside a fusion or out, makes the lanes' staged
+    rows ``[32, 16, 1024, 64]``, none outside a fusion a layer's pages
+    ``[33, 16, 1024, 64]``, and the loop's body hands the stacked tensor
+    itself to the two fusions that attend over it (the scores over K,
+    the weighted sum over V: one pass each).  The quantized store still stages: each tensor's rows
+    (32 lanes x 2 MB, built by one row read per lane) have their turn in
+    the chip's on-chip memory (``S(1)`` in the layout; V's are read once
+    K's are dead), not a round trip through HBM per layer."""
     from bluefog_tpu.utils.hlo_bytes import loop_writes, materialized
     txt, mem, cc = v5e_decode(store)
     lanes = 32
@@ -816,6 +834,7 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     for elements in (pages,) + ((scales,) if cc.quantized else ()):
         i, o = loop_writes(txt, elements)
         inside, outside = inside + i, outside + o
+    row = (cc.kv_heads, cc.max_len, cc.head_dim)
     if check == "no_write_in_the_loop":
         assert inside == 0
     elif check == "one_write_per_lane_after_it":
@@ -828,15 +847,93 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     elif check == "no_half_tensor":
         k_bytes = pages * jnp.dtype(store_dtype(store, cc.dtype)).itemsize
         assert materialized(txt, k_bytes // 2) == []
+    elif check == "no_rows_staged":
+        assert _results_of_shape(txt.splitlines(), (lanes,) + row) == []
+        assert materialized(txt, 1, (cc.rows,) + row) == []
+        assert materialized(txt, 1, (cc.layers, cc.rows) + row) == []
+        body = _loop_body(txt)
+        stacked = [ln.split(" = ")[0].strip() for ln in
+                   _results_of_shape(body, (cc.layers, cc.rows) + row)]
+        readers = [ln for ln in body if " fusion(" in ln and any(
+            f"{name}," in ln or f"{name})" in ln for name in stacked)]
+        assert len(stacked) == 2 and len(readers) == 2, (stacked, readers)
     else:
-        staged = f"[{lanes},{cc.kv_heads},{cc.max_len},{cc.head_dim}]"
-        rows = [ln for ln in _loop_body(txt)
-                if ln.split(" = ")[-1].startswith(
-                    (f"bf16{staged}", f"s8{staged}"))]
+        rows = _results_of_shape(_loop_body(txt), (lanes,) + row)
         assert len(rows) >= 2 * lanes, len(rows)
         off_chip = [ln.split(" = ")[0].strip() for ln in rows
                     if "S(1)" not in ln.split(" = ")[1].split(" ")[0]]
         assert off_chip == []
+
+
+def _v5e_latent_decode(m):
+    """The latent decode program at the ``a.x-k1`` cell's sizes (6 layers,
+    129 rows x 2,560, ranks 512 + 64, 128 lanes of 64 heads, bf16) for
+    the described chip, from shapes alone."""
+    from jax.sharding import NamedSharding
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.serve import kv_cache as kv
+    S, dt = 128, jnp.bfloat16
+    cfg = decoder.LatentConfig(
+        vocab=20480, d_model=7168, heads=64, layers=6, q_rank=1536,
+        kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128, dense_ffn=18432,
+        expert_ffn=2048, num_experts=192, held_experts=12, top_k=8,
+        n_group=8, topk_group=4, route_scale=2.5, rope_factor=32.0,
+        rope_mscale_all_dim=1.0)
+    eng = ServeEngine.__new__(ServeEngine)      # bodies only: no arrays
+    eng._moe, eng._latent, eng._hybrid, eng._share = False, True, False, True
+    eng.m, eng.cfg = m, cfg
+    eng.scfg = ServeConfig(batch_buckets=(S,), prefill_buckets=(256, 2048),
+                           slots=128, max_len=2560, dtype=dt)
+    sh = NamedSharding(m.mesh, m.spec)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        (1,) + tuple(shape), dtype, sharding=sh)
+    params = {g: {k: sds(shape, jnp.float32 if k == "wr" else dt)
+                  for k, shape in grp.items()}
+              for g, grp in decoder.latent_param_shapes(cfg).items()}
+    cc = kv.LatentCacheConfig(layers=cfg.layers, slots=128, max_len=2560,
+                              kv_rank=cfg.kv_rank, rope_dim=cfg.rope_dim,
+                              dtype=dt)
+    cache = {k: sds(shape, dt) for k, shape in cc.shapes().items()}
+    return eng._build(eng._latent_decode_body).lower(
+        params, cache, sds((cc.rows, 2), jnp.uint32),
+        sds((S, 1 + 4), jnp.int32)).compile(), cc
+
+
+@pytest.fixture(scope="module")
+def v5e_latent_decode(v5e_chip):
+    compiled, cc = _v5e_latent_decode(v5e_chip)
+    return compiled.as_text(), compiled.memory_analysis(), cc
+
+
+@pytest.mark.parametrize("check", [
+    "no_rows_staged", "no_layer_copied", "aliased_whole"])
+def test_latent_decode_reads_in_place_on_v5e(v5e_latent_decode, check):
+    """The latent decode program at the ``a.x-k1`` cell's sizes, as the
+    chip's own compiler builds it: no instruction, inside a fusion or
+    out, makes the lanes' staged rows (``[128, 2560, 512]`` compressed
+    vectors, 336 MB a layer written and read again, and ``[128, 2560,
+    64]`` rotary keys), none outside a fusion a layer of either tensor
+    or a copy of the whole (the layer's slice reaches the matmuls inside
+    their fusions), and the donated cache is the output."""
+    from bluefog_tpu.utils.hlo_bytes import materialized
+    txt, mem, cc = v5e_latent_decode
+    lanes = 128
+    if check == "no_rows_staged":
+        for dim in (cc.kv_rank, cc.rope_dim):
+            assert _results_of_shape(
+                txt.splitlines(), (lanes, cc.max_len, dim)) == []
+    elif check == "no_layer_copied":
+        for dim in (cc.kv_rank, cc.rope_dim):
+            for lead in ((cc.rows,), (cc.layers, cc.rows)):
+                assert materialized(
+                    txt, 1, lead + (cc.max_len, dim)) == []
+        # what is left: float32 scores and probabilities [129, 64, 2560]
+        # and the layers' weights on their way in, under a quarter of
+        # the cache (the staged form's temporaries were 0.96 GB)
+        assert mem.temp_size_in_bytes < cc.bytes() // 4
+    else:
+        assert cc.bytes() <= mem.alias_size_in_bytes \
+            <= cc.bytes() + _V5E_KEY_TABLE
 
 
 def test_cache_copy_gauge_set_at_warmup(cpu_devices):
@@ -932,7 +1029,10 @@ def test_write_after_loop_engine_equals_per_layer_engine(cpu_devices, name):
                for n in (3, 7, 5, 8, 4)] + [shared + [5, 9, 2],
                                             shared + [6, 5, 3, 5]]
     out = []
-    for cls in (ServeEngine, _AppendPerLayer):
+    # both through ONE read form, the staged one: a token already written
+    # is not beside the pages, so the per-layer engine stages, and floats
+    # compared to the bit must have been summed in one order
+    for cls in (_StagedRead, _AppendPerLayer):
         eng = cls(m, cfg, params, ServeConfig(**{**_SCFG, **extra}))
         eng.warmup()
         toks = [r.generated for r in _drain(eng, prompts, max_new=7)]
@@ -946,6 +1046,93 @@ def test_write_after_loop_engine_equals_per_layer_engine(cpu_devices, name):
     assert (writes, ref_writes) == counted
     for k in ref_cache:
         np.testing.assert_array_equal(cache[k], ref_cache[k])
+
+
+class _StagedRead(ServeEngine):
+    """The engine whose decode attention stages each lane's row before it
+    attends over it (``attend_rows``): the form the in-place read
+    replaces on a raw cache without prefix pages, kept as the
+    reference."""
+    _read_in_place = False
+
+
+_READ_ENGINES = {
+    # name: ((dp, pp, tp), ServeConfig overrides)
+    "one_chip": ((1, 1, 1), {}),
+    "dp2_pp2_tp2": ((2, 2, 2), {}),
+    "two_tokens_a_call": ((1, 1, 1), dict(decode_steps_per_call=2)),
+    "spec_draft": ((1, 2, 1), dict(spec_decode=2, spec_stages=1)),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_READ_ENGINES))
+def test_in_place_read_engine_serves_the_staged_engines_tokens(cpu_devices,
+                                                               name):
+    """Engine against engine, differing only in how decode attention
+    meets the cache: the same greedy tokens for every request (the sums
+    run in another order, so floats are held at the oracle tests'
+    tolerance there and to the token here), ``program_memory`` names the
+    form per decode program (a one-lane bucket of five rows is under a
+    third of them and stages by its shapes), and the positions counter
+    advances by layers x rows x max_len a fused step where the read is in
+    place, by the lanes' rows where it is staged."""
+    (dp, pp, tp), extra = _READ_ENGINES[name]
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(dp, pp, tp, 1,
+                                    devices=cpu_devices[:dp * pp * tp])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    rng = np.random.default_rng(17)
+    prompts = [rng.integers(0, _CFG["vocab"], int(n)).tolist()
+               for n in (3, 7, 5, 8, 4, 6)]
+    toks, per_call = {}, {}
+    for cls in (ServeEngine, _StagedRead):
+        eng = cls(m, cfg, params, ServeConfig(**{**_SCFG, **extra}))
+        eng.warmup()
+        form = "in_place" if cls is ServeEngine else "staged"
+        mem = eng.program_memory()
+        assert mem["decode S=2"]["read"] == form
+        assert mem["decode S=1"]["read"] == "staged"
+        assert "read" not in mem["prefill Tpad=4"]
+        if eng.scfg.spec_decode:
+            assert mem["draft S=2"]["read"] == form
+        toks[form] = [r.generated for r in _drain(eng, prompts, max_new=7)]
+        read = bfm.counter("bluefog_serve_cache_positions_read_total")
+        before = read.value(kind="full")
+        tok, slot, ln = eng.idle_lane()
+        full = lambda v: np.full((dp, 2), v, np.int32)
+        eng.decode(full(tok), full(slot), full(ln))
+        per_call[form] = read.value(kind="full") - before
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+    assert toks["in_place"] == toks["staged"]
+    cc, steps = eng.cache_cfg, eng.scfg.decode_steps_per_call
+    assert per_call == {
+        "in_place": dp * cfg.layers * steps * cc.rows * cc.max_len,
+        "staged": dp * cfg.layers * steps * 2 * cc.max_len}
+
+
+@pytest.mark.parametrize("kind,form", [
+    ("raw", "in_place"), ("prefix_pages", "staged"), ("int8", "staged"),
+    ("pallas", "staged")])
+def test_read_form_is_a_function_of_the_cache_alone(cpu_devices, kind, form):
+    """What keeps the staged read is what the engine can see of its
+    cache, no knob: shared prefix pages (several lanes attend one row),
+    a quantized store (scales beside the pages), the flash-decode
+    kernel's order (the token written before it is read)."""
+    extra = {"raw": {}, "int8": dict(kv_dtype="int8"),
+             "prefix_pages": dict(prefix_pages=1, prefix_page_tokens=4),
+             "pallas": dict(decode_kernel="pallas", decode_block_k=8)}[kind]
+    cfg = compose.LMConfig(**_CFG)
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    eng = ServeEngine(m, cfg, compose.init_lm_params(cfg, m, seed=3),
+                      ServeConfig(**{**_SCFG, "batch_buckets": (2, 4),
+                                     **extra}))
+    tok, slot, ln = eng.idle_lane()
+    for S in (2, 4):
+        full = lambda v: np.full((1, S), v, np.int32)
+        eng.decode(full(tok), full(slot), full(ln))
+    mem = eng.program_memory()
+    # two lanes of 5 rows (6 with the prefix page) are a third or more
+    assert [mem[f"decode S={S}"]["read"] for S in (2, 4)] == [form] * 2
 
 
 @pytest.fixture(scope="module")

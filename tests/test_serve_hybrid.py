@@ -214,6 +214,40 @@ def test_ring_cache_equals_a_full_cache_under_a_band_mask(T):
                                atol=2e-6)
 
 
+@pytest.mark.parametrize("rows", [6, 16])
+@pytest.mark.parametrize("kind", ["window", "full"])
+def test_lanes_in_any_slot_order_meet_their_own_row(kind, rows):
+    """``attend_slots`` over several lanes at once, laid out by row: slots
+    in arbitrary order with the trash row (the last) among them twice, a
+    length of 0, a lane at the last position, a ring that has wrapped;
+    every live lane against the dense band (or causal) oracle over its
+    own whole sequence.  With 16 rows the five lanes are under a third
+    of them and their rows are staged."""
+    L = W if kind == "window" else 16
+    cc = kv.HybridCacheConfig(full_layers=1, window_layers=1, slots=rows - 1,
+                              max_len=16, window=W, kv_heads=2, head_dim=8)
+    cache = {n: jnp.zeros(s) for n, s in cc.shapes().items()}
+    slots, lens = [3, rows - 1, 0, rows - 1, 1], [15, 0, 0, 2, 7]
+    seqs = [qkv(n + 1, seed=10 + i) for i, n in enumerate(lens)]
+    for slot, n, (_, k, v) in zip(slots, lens, seqs):
+        if n:
+            pad = ((0, 16 - n), (0, 0), (0, 0))
+            cache = kv.hybrid_prefill(
+                cache, kind, 0, jnp.int32(slot), jnp.pad(k[:n], pad),
+                jnp.pad(v[:n], pad), jnp.int32(n))
+    kn, vn = kv.KIND_TENSORS[kind]
+    new = {"k": jnp.stack([k[n] for n, (_, k, _) in zip(lens, seqs)]),
+           "v": jnp.stack([v[n] for n, (_, _, v) in zip(lens, seqs)])}
+    got, met = kv.attend_slots(
+        jnp.stack([q[n] for n, (q, _, _) in zip(lens, seqs)]),
+        cache[kn][0], cache[vn][0], jnp.array(slots), jnp.array(lens), new,
+        ring=kind == "window")
+    assert met == (rows if rows == 6 else len(slots)) * L
+    for i in (0, 2, 4):                     # the live lanes
+        want = dense_attention(*seqs[i], W if kind == "window" else 0)[-1]
+        np.testing.assert_allclose(got[i], want, atol=2e-6)
+
+
 def test_grouped_heads_equal_repeated_keys_and_values():
     """Four query heads on two compact kv heads give what four heads on K
     and V repeated in memory give, in the cache's in-place decode form and
@@ -332,6 +366,7 @@ def test_cache_shapes_writes_and_gauges(cpu_devices):
     eng.warmup()
     mem = eng.program_memory()
     assert mem["decode S=4"]["cache_writes"] == 16
+    assert mem["decode S=4"]["read"] == "in_place"
     assert mem["prefill Tpad=8"]["alias_bytes"] >= cc.bytes()
     metrics.mark_steady_state(False)
 

@@ -113,11 +113,60 @@ def test_absorbed_attention_equals_unabsorbed():
              "kr": jnp.zeros((2, 3, 16, CFG.rope_dim))}
     cache = kv.latent_prefill(cache, 1, 2, jnp.pad(lat[:T - 1],
                                                    ((0, 16 - T + 1), (0, 0))))
-    u = kv.latent_attend_rows(
+    u, _ = kv.latent_attend_slots(
         decoder.mla_absorb_q(CFG, p, qn[-1:]), qr[-1:], cache, 1,
         jnp.array([2]), jnp.array([T - 1]), lat[-1:], CFG.softmax_scale)
     got = decoder.mla_unabsorb_out(CFG, p, u)
     np.testing.assert_allclose(got[0], want[-1], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("against", ["oracle", "staged"])
+@pytest.mark.parametrize("layer", ["leading_layer", "scanned_layer"])
+def test_latent_attend_slots_reads_in_place(layer, against):
+    """The latent decode read (two score parts on one vector a position
+    that every head shares, the compressed vectors again as the values,
+    the token beside the pages) against the float64 oracle and against
+    its own staged form, at the leading layer's static index 0 and at a
+    scanned index under ``jit``.  Lanes in arbitrary slot order with the
+    trash row among them twice, a length of 0, a lane at ``max_len -
+    1``."""
+    from attend_oracle import token_beside_pages_oracle
+    layers, rows, L, C, R, H = 3, 6, 16, 12, 4, 5
+    rng = np.random.default_rng(layers + (against == "staged"))
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    cache = {"ckv": normal(layers, rows, L, C), "kr": normal(layers, rows, L, R)}
+    slots = jnp.array([3, 5, 0, 5, 1], jnp.int32)
+    lens = jnp.array([L - 1, 0, 0, 2, 7], jnp.int32)
+    S, live = slots.shape[0], np.asarray(slots) < rows - 1
+    q_abs, q_rope, new = normal(S, H, C), normal(S, H, R), normal(S, C + R)
+    scale = 0.31
+    if layer == "leading_layer":
+        at = 0
+        got, met = kv.latent_attend_slots(q_abs, q_rope, cache, 0, slots,
+                                          lens, new, scale)
+        assert met == rows * L              # every row whole, in place
+    else:
+        at = 2
+
+        @jax.jit
+        def scanned(q_abs, q_rope, cache, slots, lens, new):
+            def body(_, i):
+                return None, kv.latent_attend_slots(
+                    q_abs, q_rope, cache, i, slots, lens, new, scale)[0]
+            return jax.lax.scan(body, None, jnp.arange(layers))[1]
+        got = scanned(q_abs, q_rope, cache, slots, lens, new)[at]
+    if against == "oracle":
+        pages = {n: t[at][:, None] for n, t in cache.items()}
+        tok = {"ckv": new[:, None, :C], "kr": new[:, None, C:]}
+        want = token_beside_pages_oracle(
+            [q_abs, q_rope], [pages["ckv"], pages["kr"]], pages["ckv"],
+            slots, lens, [tok["ckv"], tok["kr"]], tok["ckv"], scale)
+    else:
+        want, met = kv.latent_attend_slots(q_abs, q_rope, cache, at, slots,
+                                           lens, new, scale, stage=True)
+        assert met == S * L                 # the lanes' rows alone
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-5, atol=2e-6)
 
 
 def test_latent_cache_lands_tokens_once_per_lane_after_the_layers():
@@ -273,6 +322,46 @@ def test_decode_counts_held_work_and_cache_writes(cpu_devices):
     assert {k: v.shape for k, v in eng.cache.items()} == {
         "ckv": (1, CFG.layers, 5, 32, CFG.kv_rank),
         "kr": (1, CFG.layers, 5, 32, CFG.rope_dim)}
+
+
+class _StagedRead(ServeEngine):
+    """The latent engine whose decode attention gathers each lane's row of
+    compressed vectors before it attends over it: the form the in-place
+    read replaces, kept as the reference."""
+    _read_in_place = False
+
+
+@pytest.mark.parametrize("steps", [1, 2])
+def test_in_place_read_engine_serves_the_staged_engines_tokens(cpu_devices,
+                                                               steps):
+    """Engine against engine, differing only in how decode attention
+    meets the latent cache: the same greedy tokens, ``program_memory``
+    names the form, and the positions counter advances by layers x rows
+    x max_len a fused step in place, by the lanes' rows staged."""
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, CFG.vocab, n).tolist() for n in (5, 12, 9, 3)]
+    toks, per_call = {}, {}
+    for cls in (ServeEngine, _StagedRead):
+        m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+        eng = cls(m, CFG, make_params(CFG), ServeConfig(
+            batch_buckets=(4,), prefill_buckets=(8, 16), slots=4,
+            max_len=32, decode_steps_per_call=steps))
+        eng.warmup()
+        form = "in_place" if cls is ServeEngine else "staged"
+        assert eng.program_memory()["decode S=4"]["read"] == form
+        sched = Scheduler(eng)
+        reqs = [sched.submit(p, max_new_tokens=6) for p in prompts]
+        sched.drain()
+        sched.close()
+        toks[form] = [r.generated for r in reqs]
+        read = metrics.counter("bluefog_serve_cache_positions_read_total")
+        before = read.value(kind="latent")
+        eng.decode(np.zeros((1, 4), np.int32), np.full((1, 4), 4, np.int32),
+                   np.zeros((1, 4), np.int32))
+        per_call[form] = read.value(kind="latent") - before
+    assert toks["in_place"] == toks["staged"]
+    assert per_call == {"in_place": CFG.layers * steps * 5 * 32,
+                        "staged": CFG.layers * steps * 4 * 32}
 
 
 def test_decode_steps_per_call_fuses_the_same_tokens(cpu_devices):

@@ -24,6 +24,11 @@ separate ``wq``/``wk``/``wv`` with fewer keys than queries, RMSNorm over
 each head of q and k, rotary on the window layers only, each sublayer's
 OUTPUT normed before it is added; a window layer's whole-sequence
 attention is a band of blocks (:func:`window_attention`).
+Each block opens the device scopes of its parts (``attn.project`` or
+``mla.project``, ``attn.window`` / ``attn.full``, ``ffn``; the read-outs
+``readout``): plain ``jax.named_scope``s, metadata that changes no
+instruction and lets ``utils.tracing.device_scopes`` say which compiled
+instruction belongs to which part.
 Imports jax only: the callers import this module, never the reverse.
 """
 import dataclasses
@@ -104,19 +109,23 @@ def decoder_block(cfg: Any, tp: int, lp: Dict[str, jax.Array], x: jax.Array,
     vector, None).  Returns ``(x, aux, faux)``."""
     lead = x.shape[:-1]
     heads = lead + (cfg.heads // tp, cfg.d_model // cfg.heads)
-    q, k, v = jnp.split(norm(x) @ lp["wqkv"], 3, axis=-1)
-    q = rope(q.reshape(heads), positions)
-    k = rope(k.reshape(heads), positions)
+    with jax.named_scope("attn.project"):
+        q, k, v = jnp.split(norm(x) @ lp["wqkv"], 3, axis=-1)
+        q = rope(q.reshape(heads), positions)
+        k = rope(k.reshape(heads), positions)
     att, aux = attend(q, k, v.reshape(heads))
-    x = x + lax.psum(att.reshape(lead + (-1,)) @ lp["wo"], "tp")
-    y, faux = ffn(lp, norm(x))
-    return x + y, aux, faux
+    with jax.named_scope("attn.project"):
+        x = x + lax.psum(att.reshape(lead + (-1,)) @ lp["wo"], "tp")
+    with jax.named_scope("ffn"):
+        y, faux = ffn(lp, norm(x))
+        return x + y, aux, faux
 
 
 def lm_logits(shared: Dict[str, jax.Array], x: jax.Array) -> jax.Array:
     """Final norm and read-out through the shared head (the stage select
     and its ``psum`` stay with the caller: they differ)."""
-    return norm(x) @ shared["head"]
+    with jax.named_scope("readout"):
+        return norm(x) @ shared["head"]
 
 
 def block_param_shapes(cfg: Any, tp: int = 1) -> Dict[str, Tuple[int, int]]:
@@ -361,17 +370,21 @@ def latent_block(cfg: LatentConfig, lp: Dict[str, jax.Array], x: jax.Array,
     block's own projections (:func:`mla_project`) and meets the sequence
     (:func:`mla_unabsorbed`) or the cache (the engine's absorbed form);
     ``ffn(lp, h) -> (y, faux)`` as in :func:`decoder_block`."""
-    h = rms_norm(x, lp["g1"], cfg.eps)
+    with jax.named_scope("mla.project"):
+        h = rms_norm(x, lp["g1"], cfg.eps)
     att, aux = attend(*mla_project(cfg, lp, h, positions))
-    x = x + att @ lp["wo"]
-    y, faux = ffn(lp, rms_norm(x, lp["g2"], cfg.eps))
-    return x + y, aux, faux
+    with jax.named_scope("mla.project"):
+        x = x + att @ lp["wo"]
+    with jax.named_scope("ffn"):
+        y, faux = ffn(lp, rms_norm(x, lp["g2"], cfg.eps))
+        return x + y, aux, faux
 
 
 def latent_logits(cfg: LatentConfig, shared: Dict[str, jax.Array],
                   x: jax.Array) -> jax.Array:
     """Final RMSNorm and read-out over this chip's vocabulary slice."""
-    return rms_norm(x, shared["gf"], cfg.eps) @ shared["head"]
+    with jax.named_scope("readout"):
+        return rms_norm(x, shared["gf"], cfg.eps) @ shared["head"]
 
 
 def latent_param_shapes(cfg: LatentConfig) -> Dict[str, Dict[str, tuple]]:
@@ -554,19 +567,22 @@ def hybrid_block(cfg: HybridConfig, lp: Dict[str, jax.Array], x: jax.Array,
     faux)`` as in :func:`decoder_block`."""
     lead = x.shape[:-1]
     with jax.named_scope(f"attn.{kind}"):
-        q = (x @ lp["wq"]).reshape(lead + (cfg.heads, cfg.head_dim))
-        k = (x @ lp["wk"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
-        v = (x @ lp["wv"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
-        q = rms_norm(q, lp["gq"], cfg.eps)
-        k = rms_norm(k, lp["gk"], cfg.eps)
-        if kind == "window":
-            q = rope(q, positions, cfg.rope_base)
-            k = rope(k, positions, cfg.rope_base)
+        with jax.named_scope("attn.project"):
+            q = (x @ lp["wq"]).reshape(lead + (cfg.heads, cfg.head_dim))
+            k = (x @ lp["wk"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
+            v = (x @ lp["wv"]).reshape(lead + (cfg.kv_heads, cfg.head_dim))
+            q = rms_norm(q, lp["gq"], cfg.eps)
+            k = rms_norm(k, lp["gk"], cfg.eps)
+            if kind == "window":
+                q = rope(q, positions, cfg.rope_base)
+                k = rope(k, positions, cfg.rope_base)
         att, aux = attend(q, k, v)
-        x = x + rms_norm(att.reshape(lead + (-1,)) @ lp["wo"], lp["g1"],
-                         cfg.eps)
-    y, faux = ffn(lp, x)
-    return x + rms_norm(y, lp["g2"], cfg.eps), aux, faux
+        with jax.named_scope("attn.project"):
+            x = x + rms_norm(att.reshape(lead + (-1,)) @ lp["wo"], lp["g1"],
+                             cfg.eps)
+    with jax.named_scope("ffn"):
+        y, faux = ffn(lp, x)
+        return x + rms_norm(y, lp["g2"], cfg.eps), aux, faux
 
 
 def hybrid_param_shapes(cfg: HybridConfig) -> Dict[str, Any]:

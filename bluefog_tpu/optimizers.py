@@ -1833,6 +1833,9 @@ class _InstrumentedStep:
         self._calls = 0
         self._jit_cache_baseline: Optional[int] = None
         self._trace = _tracing.new_trace("train")
+        # the first call alone goes through :meth:`_first_call`, which
+        # registers the program and takes itself out of the way
+        self._call = self._first_call
 
     def __getattr__(self, name):
         fn = self.__dict__.get("_fn")
@@ -1855,6 +1858,25 @@ class _InstrumentedStep:
                             fused_k=self._steps_per_call) as st:
             st.attrs["overlap"] = self._overlap        # ring only
             return self._call(*args, **kwargs)
+
+    def _first_call(self, *args, **kwargs):
+        """The first call, then never again: keep the call's abstract
+        shapes (the arrays themselves may be donated) and hand
+        ``tracing.register_program`` a way to find the compiled step again
+        when someone asks which of its instructions is which named part
+        (``tracing.device_scopes``): lowering from the shapes goes through
+        jit's own caches, and nothing is lowered or compiled before
+        that."""
+        shapes = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(
+                a.shape, a.dtype,
+                sharding=a.sharding if a.committed else None)
+            if isinstance(a, jax.Array) else a, (args, kwargs))
+        _tracing.register_program(
+            "train_step", lambda fn=self._fn, shapes=shapes:
+            fn.lower(*shapes[0], **shapes[1]).compile())
+        del self._call
+        return self._call(*args, **kwargs)
 
     def _call(self, *args, **kwargs):
         import time as _time

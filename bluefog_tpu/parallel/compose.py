@@ -511,9 +511,10 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
     S, TP = m.pp, m.tp
 
     def attend(q, k, v):                            # [B, Tl, H/TP, Dh]
-        return ulysses_attention(q, k, v, axis="sp", causal=True,
-                                 use_pallas=use_pallas,
-                                 pallas_block_q=min(512, cfg.seq_len)), None
+        with jax.named_scope("attn"):
+            return ulysses_attention(
+                q, k, v, axis="sp", causal=True, use_pallas=use_pallas,
+                pallas_block_q=min(512, cfg.seq_len)), None
 
     def stage_fn(bp, x):
         # global rope positions: each sp shard rotates by its own offset,
@@ -527,14 +528,16 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
         sid = lax.axis_index("stage")
 
         def loss_fn(q):
-            x = q["shared"]["embed"][toks]          # [M, B, Tl, D]
+            with jax.named_scope("readout"):
+                x = q["shared"]["embed"][toks]      # [M, B, Tl, D]
             out = pipeline_apply(stage_fn, q["blocks"], x, axis="stage",
                                  remat=remat)
-            logits = decoder.lm_logits(q["shared"], out)
-            targets = jnp.roll(toks, cfg.lag, axis=-1)
-            loss = optax.softmax_cross_entropy_with_integer_labels(
-                logits[:, :, cfg.lag:], targets[:, :, cfg.lag:]).mean()
-            return jnp.where(sid == S - 1, loss, 0.0) / TP
+            with jax.named_scope("readout"):
+                logits = decoder.lm_logits(q["shared"], out)
+                targets = jnp.roll(toks, cfg.lag, axis=-1)
+                loss = optax.softmax_cross_entropy_with_integer_labels(
+                    logits[:, :, cfg.lag:], targets[:, :, cfg.lag:]).mean()
+                return jnp.where(sid == S - 1, loss, 0.0) / TP
 
         loss, g = jax.value_and_grad(loss_fn)(params)
         loss = lax.psum(loss, ("stage", "tp"))

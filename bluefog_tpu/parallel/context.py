@@ -260,6 +260,8 @@ def init(
     _chaos.maybe_install_from_env()
     from ..utils import flight as _flight
     _flight.maybe_enable_from_env()
+    from ..utils import tracing as _tracing
+    _tracing.maybe_enable_from_env()
     from ..utils import fleetview as _fleetview
     _fleetview.maybe_arm_from_env(n)
     _flight.record("lifecycle", name="init", devices=n)

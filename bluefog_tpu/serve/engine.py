@@ -674,6 +674,7 @@ class ServeEngine:
             prows = plens = None
         return (toks, slot_ids, lens, prows, plens, *rest)
 
+    @jax.named_scope("readout")
     def _seed_rows(self, keys, rows, key_id, count):
         """An admission's sampler key into its row of the table, per lane:
         ``fold_in(fold_in(PRNGKey(seed), key_id), count)`` with ``key_id``
@@ -692,6 +693,7 @@ class ServeEngine:
     def _use_prefix(self) -> bool:
         return self.scfg.prefix_pages > 0
 
+    @jax.named_scope("readout")
     def _next_token(self, logits, keys):
         """Greedy argmax, or the fused temperature/top-p sampler.
 
@@ -927,14 +929,16 @@ class ServeEngine:
                     x, c, new, routing = self._layer_step(
                         lp, x, c, layer, slot_ids, lens, prows, plens)
                     return (x, acc + self._route_vec(routing, live)), c, new
-                x0 = (embed[toks], st)                        # [S, D] + [E+2]
+                with jax.named_scope("readout"):
+                    x0 = (embed[toks], st)                    # [S, D] + [E+2]
             else:
                 def one(lp, x, c, layer):
                     x, c, new, _ = self._layer_step(
                         lp, x, c, layer, slot_ids, lens, prows, plens,
                         draft=draft)
                     return x, c, new
-                x0 = embed[toks]                              # [S, D]
+                with jax.named_scope("readout"):
+                    x0 = embed[toks]                          # [S, D]
 
             x, cache, sid = self._pp_cycle(
                 bp, x0, cache, one, n_stages=n_stages,
@@ -944,14 +948,15 @@ class ServeEngine:
                 x, acc = x
                 st = lax.psum(jnp.where(sid == out_stage, acc, 0.0),
                               "stage")
-            logits = lax.psum(
-                jnp.where(sid == out_stage, decoder.lm_logits(shared, x),
-                          0.0), "stage")
-            if n_stages is None:
-                nxt, keys = self._next_token(logits, keys)
-            else:
-                nxt = jnp.argmax(logits, axis=-1)     # draft: greedy only
-            nxt = nxt.astype(toks.dtype)
+            with jax.named_scope("readout"):
+                logits = lax.psum(
+                    jnp.where(sid == out_stage, decoder.lm_logits(shared, x),
+                              0.0), "stage")
+                if n_stages is None:
+                    nxt, keys = self._next_token(logits, keys)
+                else:
+                    nxt = jnp.argmax(logits, axis=-1)  # draft: greedy only
+                nxt = nxt.astype(toks.dtype)
             return (nxt, lens + 1, cache, keys, st), nxt
 
         st0 = jnp.zeros((self.cfg.num_experts + 2,), jnp.float32) \
@@ -970,7 +975,8 @@ class ServeEngine:
         gen, lane_keys, cache, st = self._decode_scan(
             params, cache, toks[:, 0], slot_ids, lens, prows, plens,
             keys[slot_ids], steps=self.scfg.decode_steps_per_call)
-        keys = keys.at[slot_ids].set(lane_keys)
+        with jax.named_scope("readout"):
+            keys = keys.at[slot_ids].set(lane_keys)
         out = (gen, st, keys, cache) if self._moe else (gen, keys, cache)
         return jax.tree.map(lambda t: t[None], out)
 
@@ -1035,13 +1041,15 @@ class ServeEngine:
 
         st0 = jnp.zeros((self.cfg.num_experts + 2,) if self._moe else (),
                         jnp.float32)
-        x = params["shared"]["embed"][toks]                   # [S, T, D]
+        with jax.named_scope("readout"):
+            x = params["shared"]["embed"][toks]               # [S, T, D]
         (x, st), cache, sid = self._pp_cycle(
             self._blocks_tree(params), (x, st0), cache, one)
-        logits = lax.psum(
-            jnp.where(sid == 0, decoder.lm_logits(params["shared"], x), 0.0),
-            "stage")                                          # [S, T, V]
-        gen = jnp.argmax(logits, axis=-1).astype(toks.dtype)
+        with jax.named_scope("readout"):
+            logits = lax.psum(
+                jnp.where(sid == 0, decoder.lm_logits(params["shared"], x),
+                          0.0), "stage")                      # [S, T, V]
+            gen = jnp.argmax(logits, axis=-1).astype(toks.dtype)
         if self._moe:
             st = lax.psum(jnp.where(sid == 0, st, 0.0), "stage")
             return jax.tree.map(lambda t: t[None], (gen, st, keys, cache))
@@ -1061,7 +1069,8 @@ class ServeEngine:
             (params, cache, keys, staged))
         keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
         positions = jnp.arange(toks.shape[0])
-        x = params["shared"]["embed"][toks][None]             # [1, Tpad, D]
+        with jax.named_scope("readout"):
+            x = params["shared"]["embed"][toks][None]         # [1, Tpad, D]
         ffn = self._ffn(chunk=True)
 
         def one(lp, x, c, layer):
@@ -1073,7 +1082,8 @@ class ServeEngine:
                 # drift only enters where a STORED page is read back
                 nc = _kv.layer_prefill(c, layer, slot_id, k[0], v[0],
                                        store=self.scfg.kv_dtype)
-                return dense_attention(q, k, v, causal=True), nc
+                with jax.named_scope("attn"):
+                    return dense_attention(q, k, v, causal=True), nc
 
             x, c, _ = decoder.decoder_block(
                 self.cfg, self.m.tp, lp, x, positions, attend, ffn)
@@ -1081,12 +1091,14 @@ class ServeEngine:
 
         x, cache, sid = self._pp_cycle(self._blocks_tree(params), x, cache,
                                        one)
-        logits = jnp.where(sid == 0,
-                           decoder.lm_logits(params["shared"], x[0]),
-                           0.0)                               # [Tpad, V]
-        logits = lax.psum(logits, "stage")
-        last = lax.dynamic_slice_in_dim(logits, true_len - 1, 1, axis=0)[0]
-        nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        with jax.named_scope("readout"):
+            logits = jnp.where(sid == 0,
+                               decoder.lm_logits(params["shared"], x[0]),
+                               0.0)                           # [Tpad, V]
+            logits = lax.psum(logits, "stage")
+            last = lax.dynamic_slice_in_dim(logits, true_len - 1, 1,
+                                            axis=0)[0]
+            nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     # ------------------------------------------------------------------
@@ -1167,7 +1179,8 @@ class ServeEngine:
         params, cache, table, lanes = self._split_args(
             (params, cache, keys, lanes))
         toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
-        toks, keys = toks[:, 0], table[slot_ids]
+        with jax.named_scope("readout"):
+            toks, keys = toks[:, 0], table[slot_ids]
         cfg, shared = self.cfg, params["shared"]
         live = slot_ids < self.scfg.slots
 
@@ -1188,8 +1201,10 @@ class ServeEngine:
                             (None, latent)
                 return attend
 
+            with jax.named_scope("readout"):
+                x = shared["embed"][toks]
             x, _, news, acc = self._latent_layers(
-                params, shared["embed"][toks], None, lens, attend_with, live,
+                params, x, None, lens, attend_with, live,
                 grouped=False)
             cache = _kv.latent_append_tokens(cache, slot_ids, lens, news)
             nxt, keys = self._next_token(
@@ -1201,7 +1216,8 @@ class ServeEngine:
         (_, _, cache, keys, st), gen = lax.scan(
             step, (toks, lens, cache, keys, st0), None,
             length=self.scfg.decode_steps_per_call)
-        table = table.at[slot_ids].set(keys)
+        with jax.named_scope("readout"):
+            table = table.at[slot_ids].set(keys)
         return jax.tree.map(lambda t: t[None], (gen, st, table, cache))
 
     def _latent_prefill_body(self, params, cache, keys, staged):
@@ -1221,13 +1237,16 @@ class ServeEngine:
                                               latent), (nc, None)
             return attend
 
+        with jax.named_scope("readout"):
+            x = shared["embed"][toks]
         x, cache, _, _ = self._latent_layers(
-            params, shared["embed"][toks], cache, positions, attend_with,
+            params, x, cache, positions, attend_with,
             positions < true_len, grouped=True)
-        last = decoder.latent_logits(
-            cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
-        ).astype(jnp.float32)
-        nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        with jax.named_scope("readout"):
+            last = decoder.latent_logits(
+                cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
+            ).astype(jnp.float32)
+            nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     # ------------------------------------------------------------------
@@ -1293,7 +1312,8 @@ class ServeEngine:
         params, cache, table, lanes = self._split_args(
             (params, cache, keys, lanes))
         toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
-        toks, keys = toks[:, 0], table[slot_ids]
+        with jax.named_scope("readout"):
+            toks, keys = toks[:, 0], table[slot_ids]
         cfg, shared = self.cfg, params["shared"]
         live = slot_ids < self.scfg.slots
 
@@ -1314,8 +1334,10 @@ class ServeEngine:
                     return out, (None, new)
                 return attend
 
+            with jax.named_scope("readout"):
+                x = shared["embed"][toks]
             x, _, news, acc = self._hybrid_layers(
-                params, shared["embed"][toks], None, lens, attend_with, live,
+                params, x, None, lens, attend_with, live,
                 grouped=False)
             stacked = {}
             for kind, names in _kv.KIND_TENSORS.items():
@@ -1336,7 +1358,8 @@ class ServeEngine:
         (_, _, cache, keys, st), (gen, logits) = lax.scan(
             step, (toks, lens, cache, keys, st0), None,
             length=self.scfg.decode_steps_per_call)
-        table = table.at[slot_ids].set(keys)
+        with jax.named_scope("readout"):
+            table = table.at[slot_ids].set(keys)
         return jax.tree.map(lambda t: t[None],
                             (gen, st, logits, table, cache))
 
@@ -1376,19 +1399,23 @@ class ServeEngine:
                 return att, (nc, None)
             return attend
 
+        with jax.named_scope("readout"):
+            x = shared["embed"][toks]
         x, cache, _, _ = self._hybrid_layers(
-            params, shared["embed"][toks], cache, positions, attend_with,
+            params, x, cache, positions, attend_with,
             positions < true_len, grouped=True)
-        last = decoder.latent_logits(
-            cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
-        ).astype(jnp.float32)
-        nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        with jax.named_scope("readout"):
+            last = decoder.latent_logits(
+                cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
+            ).astype(jnp.float32)
+            nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     def _count_held_work(self, lanes: int, lens, slots) -> None:
         """After a latent decode call: the routing carrier's held-expert
         counts into the fleet's counters, and a ``bf:engine.held_work``
-        mark in the trace carrying them for this call beside the lanes'
+        mark in the trace (directly under ``decode_call``, once ``collect``
+        has closed) carrying them for this call beside the lanes'
         live cache ``positions`` (the benchmark's expert-layer and roofline
         metrics read its attributes)."""
         cfg, scfg = self.cfg, self.scfg
@@ -1675,11 +1702,13 @@ class ServeEngine:
                 gen, *st = self._collect("decode", *out)
                 if st:
                     self._note_route_stats(st[0])
-                if self._share:
-                    self._count_held_work(S, lens, np.asarray(slots))
                 if not self._hybrid:
                     self._count_decode_read(S)
-                return gen
+            # a mark never goes inside a leaf stage: ``collect`` stays a
+            # span with none beneath it in every family
+            if self._share:
+                self._count_held_work(S, lens, np.asarray(slots))
+            return gen
 
     def decode_logits(self, replica: int
                       ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
@@ -1904,7 +1933,11 @@ class ServeEngine:
             # jit's own caches: nothing is traced or compiled a second
             # time, and lowering reads only the arguments' shapes (the
             # donated cache among them is already consumed)
-            ma = fn.lower(*args).compile().memory_analysis()
+            compiled = fn.lower(*args).compile()
+            # kept for whoever asks which instruction is which named part
+            # of the program (tracing.device_scopes); nothing is parsed here
+            _tracing.register_program(program, compiled)
+            ma = compiled.memory_analysis()
             self._program_bytes[program] = {
                 "temp_bytes": int(ma.temp_size_in_bytes),
                 "alias_bytes": int(ma.alias_size_in_bytes),

@@ -85,6 +85,12 @@ the pages in place (:func:`attend_slots`).  :class:`LatentCacheConfig`
 is the cache of one compressed vector per token, which every head of a
 layer shares (:func:`latent_attend_slots`).
 
+Every read of the cache here runs under the device scope ``cache.read``
+and every landing in it under ``cache.write`` (``jax.named_scope``;
+:func:`bluefog_tpu.utils.tracing.device_scopes`), so a device trace says
+what a program spends on either whatever the compiler numbers its
+instructions.
+
 The pure functions here (:func:`layer_append`, :func:`attend_layer`,
 :func:`attend_rows`, :func:`attend_chunk`, ...) are the single-device
 math the engine's
@@ -447,6 +453,7 @@ def layer_append(cache: Dict[str, jax.Array], layer: jax.Array,
                               v_new[:, None], store)
 
 
+@jax.named_scope("cache.write")
 def append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
                   lengths: jax.Array, new: Dict[str, jax.Array]
                   ) -> Dict[str, jax.Array]:
@@ -463,6 +470,7 @@ def append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
             for name, t in cache.items()}
 
 
+@jax.named_scope("cache.write")
 def layer_append_chunk(cache: Dict[str, jax.Array], layer: jax.Array,
                        slots: jax.Array, lengths: jax.Array,
                        k_new: jax.Array, v_new: jax.Array,
@@ -477,6 +485,7 @@ def layer_append_chunk(cache: Dict[str, jax.Array], layer: jax.Array,
             for name, t in cache.items()}
 
 
+@jax.named_scope("cache.write")
 def layer_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
                   slot_id: jax.Array, k: jax.Array, v: jax.Array,
                   store: str = "raw") -> Dict[str, jax.Array]:
@@ -564,6 +573,7 @@ def _layer_pages(t: jax.Array, layer: jax.Array, pin) -> jax.Array:
     return pin(window)[0]
 
 
+@jax.named_scope("cache.read")
 def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
                    vt: jax.Array, slots: jax.Array, lengths: jax.Array,
                    kns: Sequence[jax.Array], vn: jax.Array,
@@ -627,6 +637,7 @@ def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
     return out.reshape(R, H, Dv)[slots], R * L
 
 
+@jax.named_scope("cache.read")
 def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
                 slots: jax.Array, lengths: jax.Array,
                 scale: Optional[float] = None, *,
@@ -682,6 +693,7 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     return out.reshape(S, H, Dh).astype(q.dtype)
 
 
+@jax.named_scope("cache.read")
 def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
                  layer: jax.Array, slots: jax.Array, lengths: jax.Array,
                  new: Dict[str, jax.Array], scale: Optional[float] = None
@@ -712,6 +724,7 @@ def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
     return out.astype(q.dtype), met
 
 
+@jax.named_scope("cache.read")
 def attend_chunk(q: jax.Array, cl: Dict[str, jax.Array], slots: jax.Array,
                  lengths: jax.Array, scale: Optional[float] = None, *,
                  prefix_slots: Optional[jax.Array] = None,
@@ -805,6 +818,7 @@ def _split_latent(cache: Dict[str, jax.Array], latent: jax.Array):
     return {"ckv": latent[..., :C], "kr": latent[..., C:]}
 
 
+@jax.named_scope("cache.write")
 def latent_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
                    slot_id: jax.Array, latent: jax.Array
                    ) -> Dict[str, jax.Array]:
@@ -818,6 +832,7 @@ def latent_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
         for name, part in _split_latent(cache, latent).items()}
 
 
+@jax.named_scope("cache.write")
 def latent_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
                          lengths: jax.Array, new: jax.Array
                          ) -> Dict[str, jax.Array]:
@@ -837,6 +852,7 @@ def latent_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
         for name, part in _split_latent(cache, new).items()}
 
 
+@jax.named_scope("cache.read")
 def latent_attend_slots(q_abs: jax.Array, q_rope: jax.Array,
                         cache: Dict[str, jax.Array], layer: jax.Array,
                         slots: jax.Array, lengths: jax.Array,
@@ -941,6 +957,7 @@ class HybridCacheConfig:
 KIND_TENSORS = {"full": ("k", "v"), "window": ("kw", "vw")}
 
 
+@jax.named_scope("cache.write")
 def hybrid_prefill(cache: Dict[str, jax.Array], kind: str, layer: int,
                    slot_id: jax.Array, k: jax.Array, v: jax.Array,
                    true_len: jax.Array) -> Dict[str, jax.Array]:
@@ -967,6 +984,7 @@ def hybrid_prefill(cache: Dict[str, jax.Array], kind: str, layer: int,
     return out
 
 
+@jax.named_scope("cache.write")
 def hybrid_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
                          lengths: jax.Array, new: Dict[str, jax.Array]
                          ) -> Dict[str, jax.Array]:

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import logging
 import os
+import re
 from typing import Optional
 
 logger = logging.getLogger("bluefog_tpu")
@@ -74,6 +75,48 @@ DEFAULT_COMPILATION_CACHE_DIR = os.path.join(
         os.path.abspath(__file__)))), ".jax_cache")
 
 
+# a function's head, or a device scope opened under it
+_SCOPE_SITE_RE = re.compile(
+    r"^\s*def (\w+)|named_(?:scope|span)\(\s*f?\"([^\"]+)\"", re.M)
+
+
+def scope_sites_key() -> str:
+    """A digest of WHERE the package opens device scopes: every
+    ``jax.named_scope("...")`` / ``named_span("...")`` in its sources as
+    (file, enclosing function, name), whatever line it stands on.
+
+    JAX keys its persistent compile cache on a program with its debug
+    information stripped, and a scope is debug information: a build that
+    moved or added scopes and nothing else finds the older build's
+    executable under its own key, with the older ``op_name``s, and
+    ``tracing.device_scopes()`` then truthfully describes THAT executable.
+    With this digest in the key (:func:`enable_compilation_cache`) such a
+    build compiles afresh once; a build that only moved lines does not
+    (keeping all metadata in the key, ``jax_compilation_cache_include_
+    metadata_in_key``, would make it)."""
+    import hashlib
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sites = []
+    for folder, _, files in sorted(os.walk(root)):
+        for name in sorted(files):
+            if not name.endswith(".py"):
+                continue
+            path = os.path.join(folder, name)
+            with open(path, encoding="utf-8") as f:
+                text = f.read()
+            if "named_s" not in text:
+                continue
+            func = ""
+            for m in _SCOPE_SITE_RE.finditer(text):
+                if m.group(1):
+                    func = m.group(1)
+                else:
+                    sites.append((os.path.relpath(path, root), func,
+                                  m.group(2)))
+    return "bluefog-device-scopes-" + hashlib.sha256(
+        repr(sites).encode()).hexdigest()[:16]
+
+
 def enable_compilation_cache() -> str:
     """Point JAX's persistent compilation cache at a directory that
     survives the process; returns the directory in use.
@@ -83,7 +126,9 @@ def enable_compilation_cache() -> str:
     Otherwise the cache goes to ``<checkout>/.jax_cache``.
     ``JAX_ENABLE_COMPILATION_CACHE=0`` turns it off either way.  Called by
     ``bf.init`` when the devices are TPUs; CPU runs keep the cache off so
-    the sandbox does not grow the tree the chip tool copies.
+    the sandbox does not grow the tree the chip tool copies.  The cache's
+    key also takes in where the package opens device scopes
+    (:func:`scope_sites_key`).
     """
     import jax
 
@@ -94,6 +139,18 @@ def enable_compilation_cache() -> str:
     # are dozens of them; lower it unless the user configured one
     if jax.config.jax_persistent_cache_min_compile_time_secs == 1.0:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    # where the package opens device scopes is part of the key: a scope is
+    # debug information, which the key leaves out (scope_sites_key)
+    key = scope_sites_key()
+    try:
+        from jax._src import cache_key
+    except ImportError:
+        cache_key = None
+    if hasattr(cache_key, "custom_hook"):       # what this jax's key calls
+        cache_key.custom_hook = lambda: key
+    else:
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          True)
     return jax.config.jax_compilation_cache_dir
 
 

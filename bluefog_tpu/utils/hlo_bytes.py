@@ -232,6 +232,57 @@ def materialized(hlo_txt: str, min_bytes: int, dims: tuple = None):
 
 
 # ---------------------------------------------------------------------------
+# Which named part of the program an instruction belongs to: the
+# ``jax.named_scope``s a program was traced under come through compilation
+# as each instruction's ``metadata={op_name="jit(f)/GRADIENT/jvp(ffn)/dot"}``
+# ---------------------------------------------------------------------------
+
+_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+_OP_NAME_RE = re.compile(r'\bop_name="([^"]*)"')
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
+def op_names(hlo_txt: str) -> tuple:
+    """``(module name, {computation: {instruction: (opcode, op_name, fused,
+    operands)}})`` of a compiled module's text, for every computation the
+    device runs instruction by instruction (a fused computation's
+    interior is left out: a device trace shows the fusion alone).
+    ``op_name`` is the instruction's own metadata (a fusion's is its
+    root's; ``""`` where the compiler made the instruction itself: a copy,
+    a tuple, a loop), ``fused`` the distinct ``op_name``s of the
+    instructions a fusion holds, so that a fusion the compiler put
+    together across named parts of the program can be told from one that
+    lies inside a single part (``()`` for anything but a fusion), and
+    ``operands`` the instructions of the same computation it reads."""
+    module = _MODULE_RE.search(hlo_txt)
+    comps = _computations(hlo_txt)
+    own, roots = {}, {}
+    for c, rows in comps.items():
+        own[c] = {name: (m.group(1) if (m := _OP_NAME_RE.search(line))
+                         else "") for name, _, _, line in rows}
+        roots[c] = next((own[c][name] for name, _, _, line in rows
+                         if line.lstrip().startswith("ROOT ")), "")
+    inner = {name: m.group(1) for rows in comps.values()
+             for name, op, _, line in rows
+             if op == "fusion" and (m := _CALLS_RE.search(line))}
+    fused = set(inner.values())
+    out = {}
+    for c, rows in comps.items():
+        if c in fused:
+            continue
+        out[c] = {}
+        for name, op, _, line in rows:
+            held = own.get(inner.get(name), {})
+            reads = tuple(dict.fromkeys(
+                n for n in _NAME_RE.findall(line.split(" = ", 1)[1])
+                if n in own[c] and n != name))
+            out[c][name] = (op, own[c][name] or roots.get(inner.get(name), ""),
+                            tuple(sorted(set(held.values()) - {""})), reads)
+    return (module.group(1) if module else ""), out
+
+
+# ---------------------------------------------------------------------------
 # ICI-vs-DCN attribution from PRE-optimization StableHLO (jax `.lower()`
 # text).  Pre-opt is the honest layer for codec pins: the CPU backend
 # constant-folds bf16/fp8 casts in *compiled* HLO, but the traced program

@@ -38,11 +38,23 @@ under the plain ``name``.  The vocabulary (``bf:serve.step`` ⊃
 endpoints lie in the past (``queue``, ``request``, the per-rider
 ``decode``) cannot be annotations and stay :func:`add_span`, ring only.
 
+Device scopes: the DEVICE's time is named from inside the programs.  A
+``jax.named_scope`` of :data:`DEVICE_SCOPES` (``ffn``, ``cache.read``,
+``GRADIENT``, ...; tabled in ``docs/OBSERVABILITY.md``) reaches every
+compiled instruction's ``op_name``; whoever compiles a program hands it to
+:func:`register_program` (a kept reference, nothing else), and
+:func:`device_scopes` parses, when asked, which instruction belongs to
+which scope.  A device trace names instructions and nothing more, so a
+reader of one (``perfbench/harness/scopes.py``) sums device time by scope
+through that table, under names that survive a recompile's renumbering.
+
 Arming: ``BLUEFOG_TRACE=<dir>`` (or :func:`configure`) arms recording
 (the per-request ring; stage spans reach a profiler's trace without it)
 and directs :func:`flush` to ``<dir>/trace_rank<r>.trace.jsonl`` — one
 self-describing JSONL bundle per rank (a ``meta`` line, then one line
-per span), written atomically and flushed again at exit.  Producers:
+per span), written atomically and flushed again at exit — with the
+device-scope tables of the programs compiled so far beside it
+(``<dir>/device_scopes_rank<r>.json``).  Producers:
 
 * the serve scheduler threads request spans (``cat="serve"``) and tags
   each :class:`~bluefog_tpu.serve.scheduler.Request` with its trace id;
@@ -56,17 +68,20 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import re
 import sys
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Tuple, Union
 
+from . import hlo_bytes
 from .config import logger
 
 __all__ = [
     "SCHEMA", "ENV_TRACE", "enabled", "configure", "maybe_enable_from_env",
     "new_trace", "add_span", "mark", "span", "stage", "spans", "dropped",
-    "flush", "bundle_path", "capacity", "reset",
+    "flush", "bundle_path", "capacity", "reset", "DEVICE_SCOPES",
+    "register_program", "device_scopes", "scope_of", "scopes_path",
 ]
 
 SCHEMA = "bluefog-trace-1"
@@ -230,6 +245,130 @@ class stage:
 
 
 # ---------------------------------------------------------------------------
+# Device scopes: which instruction of a compiled program is which named part
+# ---------------------------------------------------------------------------
+
+# the one vocabulary (docs/OBSERVABILITY.md tables where each is opened
+# and which metric reads it)
+DEVICE_SCOPES = frozenset((
+    "GRADIENT", "ADAPT", "COMMUNICATE", "STATE_SYNC",      # the train step
+    "attn", "attn.project", "attn.window", "attn.full",
+    "mla.project", "mla.attend", "cache.read", "cache.write",
+    "ffn", "moe.route", "moe.experts", "moe.shared", "readout"))
+
+_NOT_A_SCOPE_RE = re.compile(r"\bp?jit\([^()]*\)")     # a function's name
+_PATH_NAME_RE = re.compile(r"[^/()]+")
+_programs: Dict[str, Any] = {}
+_tables: Dict[str, dict] = {}
+
+
+def register_program(key: str,
+                     compiled: Union[Any, Callable[[], Any]]) -> None:
+    """Keep the compiled program ``key`` (``"decode S=32"``, ``"prefill
+    Tpad=256"``, ``"train_step"``) for :func:`device_scopes`: the object
+    ``jit(f).lower(...).compile()`` returns, or a function that makes it
+    when asked.  A kept reference and nothing else: no text is made and
+    nothing is parsed until someone asks.  A key registered again (another
+    engine in the process) replaces the earlier program."""
+    _programs[key] = compiled
+    _tables.pop(key, None)
+
+
+def scope_of(op_name: str) -> Tuple[str, str]:
+    """``(scope, direction)`` of an instruction's ``op_name``: the
+    INNERMOST name of :data:`DEVICE_SCOPES` on its path (``""`` if none)
+    and ``"bwd"`` under a ``transpose(``, ``"fwd"`` under a ``jvp(``
+    alone, ``""`` outside differentiation.  A rematerialised forward is
+    traced under the transpose and counts as ``bwd``: it runs there."""
+    found = [t for t in _PATH_NAME_RE.findall(
+        _NOT_A_SCOPE_RE.sub("", op_name)) if t in DEVICE_SCOPES]
+    return (found[-1] if found else "",
+            "bwd" if "transpose(" in op_name
+            else "fwd" if "jvp(" in op_name else "")
+
+
+def _scope_table(hlo_txt: str) -> dict:
+    """One program's table (:func:`device_scopes`) from its compiled
+    text."""
+    module, comps = hlo_bytes.op_names(hlo_txt)
+    ops, mixed, inherited = {}, {}, {}
+    for rows in comps.values():
+        users: Dict[str, list] = {}
+        for name, (opcode, op_name, fused, reads) in rows.items():
+            ops[name] = scope_of(op_name)
+            held = {scope_of(f) for f in fused}
+            inside = {scope for scope, _ in held} - {""}
+            if len(inside) > 1:
+                mixed[name] = tuple(sorted(inside))
+            elif inside and not ops[name][0]:
+                # a root outside every scope (the step's re-stacking of
+                # its outputs behind the optimizer update) over fused
+                # instructions that all lie in one
+                directions = {d for scope, d in held if scope}
+                ops[name] = (inside.pop(), directions.pop()
+                             if len(directions) == 1 else "")
+                inherited[name] = "fused"
+            for r in reads:
+                users.setdefault(r, []).append(name)
+
+        def around(name, links, seen):
+            """The scoped instructions ``name`` reaches along ``links``,
+            through what only forwards a buffer."""
+            for n in links(name):
+                if n in seen:
+                    continue
+                seen.add(n)
+                if ops[n][0]:
+                    yield ops[n]
+                elif rows[n][0] in hlo_bytes._FORWARDING:
+                    yield from around(n, links, seen)
+
+        # what the compiler made itself, or traced outside every scope
+        # (a scan's slice of its layer's weights, a kernel the compiler
+        # expands an op into): the part its operands come from, else the
+        # part that reads it, where they agree; operands first, in program
+        # order, so a chain of such instructions resolves in one pass
+        for how, links in (("operands", lambda n: rows[n][3]),
+                           ("users", lambda n: users.get(n, ()))):
+            for name, (opcode, *_) in rows.items():
+                if ops[name][0] or opcode in hlo_bytes._FORWARDING:
+                    continue
+                found = set(around(name, links, {name}))
+                if len({scope for scope, _ in found}) == 1:
+                    scope, direction = found.pop()
+                    ops[name] = (scope, "" if found else direction)
+                    inherited[name] = how
+    return {"module": module, "ops": ops, "mixed": mixed,
+            "inherited": inherited}
+
+
+def device_scopes() -> Dict[str, dict]:
+    """Per registered program ``{"module": the HLO module's name, "ops":
+    {instruction: (scope, direction)}, "mixed": {fusion: (scopes, ...)},
+    "inherited": {instruction: "fused" | "operands" | "users"}}``.  ``ops`` holds
+    every instruction a device trace can show (fused interiors apart); a
+    fusion goes by its root.  ``mixed`` lists the fusions whose fused
+    instructions lie in more than one scope, with those scopes: their
+    time is their root's in ``ops``, and a reader can say how much time
+    that is.  ``inherited`` lists the instructions that carry no scope of
+    their own and were given one: a fusion the one scope its fused
+    instructions lie in, any other the one all its scoped operands (else
+    all its users) lie in.  Parsed from ``compiled.as_text()`` on the first
+    call that finds the program, kept after."""
+    for key, compiled in list(_programs.items()):
+        if key not in _tables:
+            if not hasattr(compiled, "as_text"):
+                compiled = _programs[key] = compiled()
+            _tables[key] = _scope_table(compiled.as_text())
+    return {key: _tables[key] for key in _programs}
+
+
+def scopes_path(out_dir: Optional[str] = None) -> str:
+    base = out_dir if out_dir is not None else (_dir or ".")
+    return os.path.join(base, f"device_scopes_rank{_rank()}.json")
+
+
+# ---------------------------------------------------------------------------
 # Introspection + bundles
 # ---------------------------------------------------------------------------
 
@@ -282,6 +421,15 @@ def flush(path: Optional[str] = None) -> str:
         for ev in snap:
             f.write(json.dumps(ev) + "\n")
     os.replace(tmp, path)
+    if _programs:
+        # beside the bundle: with a jax.profiler trace of the same
+        # process, device time splits by scope offline
+        beside = scopes_path(os.path.dirname(path) or ".")
+        tmp = f"{beside}.tmp.{os.getpid()}"
+        with open(tmp, "w") as f:
+            json.dump({"schema": SCHEMA, "rank": meta["rank"],
+                       "programs": device_scopes()}, f)
+        os.replace(tmp, beside)
     return path
 
 
@@ -289,13 +437,15 @@ def _final_flush() -> None:
     if _armed:
         try:
             flush()
-        except OSError:                                   # pragma: no cover
+        except Exception:                                 # pragma: no cover
             logger.warning("trace flush at exit failed", exc_info=True)
 
 
 def reset() -> None:
-    """Test isolation: disarm and drop every buffered span."""
+    """Test isolation: disarm, drop every buffered span and program."""
     global _armed, _dir, _buf, _seq, _last_seq, _trace_seq
+    _programs.clear()
+    _tables.clear()
     _armed = False
     _dir = None
     _buf = deque(maxlen=DEFAULT_CAPACITY)

@@ -1,0 +1,10 @@
+"""Device time of the held experts' matmuls per thousand real prompt
+tokens: op self time of the prefill programs under ``moe.experts`` alone
+(the grouped kernel and the sort and gather around it), over the
+``tokens`` of the traced ``bf:engine.prefill_call`` spans / 1,000."""
+from perfbench.harness import scopes
+
+
+def read(run):
+    ana = scopes.on_chip(run)
+    return ana and ana.per_ktok("prefill ", ("moe.experts",))

@@ -69,6 +69,35 @@ def test_compile_cache_is_placed_from_outside(tmp_path, restore_cache_config):
         _ROOT, ".jax_cache")
 
 
+def test_compile_cache_key_sees_where_scopes_are_opened(
+        tmp_path, monkeypatch, restore_cache_config):
+    """A scope is debug information, which JAX's cache key strips: the
+    digest of the package's scope sites goes into the key through its
+    hook, moves when a scope is added or moved to another function, and
+    stays when lines shift."""
+    from jax._src import cache_key
+    monkeypatch.setattr(cache_key, "custom_hook", cache_key.custom_hook)
+    key = bfcfg.scope_sites_key()
+    assert key.startswith("bluefog-device-scopes-")
+    assert bfcfg.scope_sites_key() == key
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    bfcfg.enable_compilation_cache()
+    assert cache_key.custom_hook() == key
+    # the digest reads (file, function, name), not line numbers
+    pkg = tmp_path / "pkg" / "utils"
+    pkg.mkdir(parents=True)
+    monkeypatch.setattr(bfcfg, "__file__", str(pkg / "config.py"))
+    src = 'def f(x):\n    with jax.named_scope("ffn"):\n        return x\n'
+    (tmp_path / "pkg" / "a.py").write_text(src)
+    first = bfcfg.scope_sites_key()
+    (tmp_path / "pkg" / "a.py").write_text("# a moved line\n\n" + src)
+    assert bfcfg.scope_sites_key() == first != key
+    (tmp_path / "pkg" / "a.py").write_text(src.replace("ffn", "readout"))
+    assert bfcfg.scope_sites_key() != first
+    (tmp_path / "pkg" / "a.py").write_text(src.replace("def f", "def g"))
+    assert bfcfg.scope_sites_key() != first
+
+
 def test_compile_cache_env_var_reaches_the_config(tmp_path):
     p = subprocess.run(
         [sys.executable, "-c",

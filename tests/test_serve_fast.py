@@ -936,6 +936,36 @@ def test_latent_decode_reads_in_place_on_v5e(v5e_latent_decode, check):
             <= cc.bytes() + _V5E_KEY_TABLE
 
 
+@pytest.mark.parametrize("family", ["dense", "latent"])
+def test_scope_table_names_the_decode_programs_the_chip_compiles(
+        family, request):
+    """The two decode programs at their cells' sizes as the chip's own
+    compiler builds them (compiled once for the tests above): at least
+    0.9 of their fusions, dots, custom calls and in-place writes, by
+    count, fall under a scope of the vocabulary, every scope of the
+    family's decode vocabulary is there, and the 64 (dense) / 256
+    (latent) token writes after the layer loop are ``cache.write``."""
+    from bluefog_tpu.utils import hlo_bytes, tracing
+    txt = request.getfixturevalue(
+        "v5e_decode")("raw")[0] if family == "dense" else \
+        request.getfixturevalue("v5e_latent_decode")[0]
+    tab = tracing._scope_table(txt)
+    _, comps = hlo_bytes.op_names(txt)
+    heavy = [n for ops in comps.values() for n, r in ops.items()
+             if r[0] in ("fusion", "dot", "custom-call",
+                         "dynamic-update-slice", "convolution")]
+    named = [n for n in heavy if tab["ops"][n][0]]
+    assert len(named) >= 0.9 * len(heavy), (len(named), len(heavy))
+    want = {"dense": {"attn.project", "cache.read", "cache.write", "ffn",
+                      "readout"},
+            "latent": {"mla.project", "mla.attend", "cache.read",
+                       "cache.write", "ffn", "moe.route", "moe.experts",
+                       "moe.shared", "readout"}}[family]
+    assert want <= {scope for scope, _ in tab["ops"].values()}
+    writes = [n for n in heavy if tab["ops"][n] == ("cache.write", "")]
+    assert len(writes) >= {"dense": 64, "latent": 256}[family]
+
+
 def test_cache_copy_gauge_set_at_warmup(cpu_devices):
     """bluefog_serve_cache_copy_bytes{program} / ..._alias_bytes{program}
     carry what ``program_memory`` holds, one series per warmed program,

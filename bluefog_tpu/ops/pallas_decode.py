@@ -26,7 +26,9 @@ flash-decoding recipe:
   score rows / probability columns, so quantized pages never round-trip
   through HBM at f32.
 
-The kv-head-major page layout (``[rows, kv_heads, max_len, head_dim]``)
+The kv-head-major page layout (``[rows, kv_heads, max_len, head_dim]``:
+what the engine hands the kernel whatever order the cache stores, a copy
+of a layer where the store is token rows, ``kv_cache.logical_pages``)
 makes every K/V block a natively-tiled ``[block_k, head_dim]`` VMEM tile
 — the same scalar-prefetch BlockSpec trick :mod:`.pallas_moe` proved
 through Mosaic for v5e.  Off-TPU the kernel runs in interpreter mode;

@@ -22,8 +22,10 @@ place**: it rides the carry of the layer loop (and of the fused
 ``decode_steps_per_call`` loop around it) and is written and read at
 ``[layer, row, ...]`` — never sliced out per layer, never restacked, so a
 program's output cache is its input buffer (:mod:`.kv_cache` owns the
-layout, the in-place writes and reads, int8/fp8 page storage and shared
-prefix pages; :meth:`ServeEngine.program_memory` and the
+layout, which follows the shapes: heads of 64 lie side by side in a
+token's row, ``program_memory()[...]["pages"]``; the in-place writes and
+reads, int8/fp8 page storage and shared prefix pages;
+:meth:`ServeEngine.program_memory` and the
 ``bluefog_serve_cache_copy_bytes`` gauge say what the compiler built).
 A decode token's K and V are not written in the loop at all: each layer
 attends over the cached rows plus its own token, the tokens of all layers
@@ -544,15 +546,7 @@ class ServeEngine:
             if self._share:
                 return {name: jnp.zeros((1,) + shape, cc.dtype)
                         for name, shape in cc.shapes().items()}
-            per_dev = (1, cc.layers, cc.rows, cc.kv_heads, cc.max_len,
-                       cc.head_dim)
-            pay_dt = _kv.store_dtype(cc.store, cc.dtype)
-            cache = {"k": jnp.zeros(per_dev, pay_dt),
-                     "v": jnp.zeros(per_dev, pay_dt)}
-            if cc.quantized:
-                cache["k_scale"] = jnp.zeros(per_dev[:-1], jnp.float32)
-                cache["v_scale"] = jnp.zeros(per_dev[:-1], jnp.float32)
-            return cache
+            return {name: t[None] for name, t in _kv.init_cache(cc).items()}
 
         # the fused sampler's raw PRNG keys, one per physical row, live on
         # the device beside the cache: an argument and a donated output of
@@ -794,8 +788,11 @@ class ServeEngine:
 
     def _layer_view(self, cache, layer):
         """One layer's pages as a tensor of their own — what the Pallas
-        kernels take (their index maps address ``[row, head, block]``)."""
-        return {name: t[layer] for name, t in cache.items()}
+        kernels take (their index maps address ``[row, head, block]``: the
+        logical order, whatever order the pages are stored in)."""
+        return {name: _kv.logical_pages(t[layer], self.cache_cfg.head_dim)
+                if name in ("k", "v") else t[layer]
+                for name, t in cache.items()}
 
     @property
     def _defer_appends(self) -> bool:
@@ -1941,7 +1938,8 @@ class ServeEngine:
             self._program_bytes[program] = {
                 "temp_bytes": int(ma.temp_size_in_bytes),
                 "alias_bytes": int(ma.alias_size_in_bytes),
-                "cache_writes": writes}
+                "cache_writes": writes,
+                "pages": self.cache_cfg.page_orders()}
             if read is not None:
                 self._program_bytes[program]["read"] = read
             _metrics.gauge(
@@ -1970,13 +1968,16 @@ class ServeEngine:
             self._warm_sizes = sizes
 
     def program_memory(self) -> dict:
-        """``{program: {"temp_bytes", "alias_bytes", "cache_writes"}}`` per
-        device, for every engine program compiled so far
+        """``{program: {"temp_bytes", "alias_bytes", "cache_writes",
+        "pages"}}`` per device, for every engine program compiled so far
         (``compiled.memory_analysis()``, :meth:`_cache_writes`): whether
         the cache is updated in place is a property of the compiled
         program, so this is its counter — ``alias_bytes`` is the size of
         the cache and the key table, and ``temp_bytes`` stays under one
-        layer's pages when it is.  A decode or draft program also says
-        how its attention ``read``s the cache: ``"in_place"`` (no staging
-        buffer among its temporaries) or ``"staged"``."""
+        layer's pages when it is; ``pages`` names the order each cache
+        tensor's pages lie in (:func:`.kv_cache.page_order`:
+        ``"token_rows"``, ``"head_dim_minor"`` or ``"positions_minor"``),
+        which is what a token's write costs.  A decode or draft program
+        also says how its attention ``read``s the cache: ``"in_place"``
+        (no staging buffer among its temporaries) or ``"staged"``."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

@@ -4,6 +4,10 @@ Layout (per device, i.e. per (replica, stage, tp) coordinate of the
 compose carving)::
 
     k, v: [layers, slots + prefix_slots + 1, kv_heads, max_len, head_dim]
+    k, v: [layers, slots + prefix_slots + 1, max_len, kv_heads * head_dim]
+
+(the second where a ``head_dim`` under 128 fills the 128 lanes only
+beside the other heads: **token rows**, :func:`page_order`)
 
 * ``layers``   — the decoder blocks THIS pipeline stage owns;
 * ``slots``    — request slots: one resident sequence each, allocated at
@@ -25,11 +29,24 @@ compose carving)::
   :class:`bluefog_tpu.models.transformer.RingTransformerBlock` — q heads
   attend their ``h // group`` kv head).
 
-The layout is **kv-head major** (``kv_heads`` BEFORE ``max_len``): one
-(row, head)'s key positions are contiguous, so the flash-decode kernel
-(:mod:`bluefog_tpu.ops.pallas_decode`) streams ``[block_k, head_dim]``
-K/V blocks straight from HBM as natively-tiled VMEM tiles — no Mosaic
-relayout, no strided DMA.  The XLA paths below index the same layout.
+**The order a row's pages lie in follows the shapes**
+(:func:`page_order`; :meth:`KVCacheConfig.shapes`), because a decode
+token's write costs the tiles it touches: kept by head (``kv_heads``
+BEFORE ``max_len``: one (row, head)'s key positions are contiguous, and
+the flash-decode kernel, :mod:`bluefog_tpu.ops.pallas_decode`, streams
+``[block_k, head_dim]`` K/V blocks straight from HBM) where ``head_dim``
+fills the 128 lanes, or where nothing can be put beside it (the compiler
+then holds the positions in the lanes and a token is one COLUMN of
+tiles); as **token rows** ``[max_len, kv_heads * head_dim]`` where
+``head_dim`` is under 128 and the heads side by side fill the lanes (16
+heads of 64): the same bytes, and a token is one ROW, 8 tiles a layer
+where the column was 64.  The functions below tell the two apart by a
+payload tensor's rank.  Writes land in either order; the in-place decode
+read contracts token rows as they lie (:func:`_attend_by_row`); every
+other reader (the staged reads, prefix pages, quantized stores, the
+k-token forms, the engine's view for the flash-decode kernel) meets
+them through ONE logical view ``[..., kv_heads, max_len, head_dim]``
+(:func:`logical_pages`), a copy whose speed nothing measures.
 
 **Quantized storage** (``store="int8"`` / ``"fp8"``): pages hold the
 quantized payload plus per-(position, head) f32 amax scales in sibling
@@ -43,7 +60,8 @@ keeps the payload in ``dtype`` (f32 or bf16) with no scales.
 engine's XLA path each layer hands its new token's pages
 (:func:`token_pages`) to the attention beside the cache, the layer loop
 stacks them as its output, and :func:`append_tokens` writes ``t[:, slot,
-:, length]`` with one ``dynamic_update_slice`` per lane and tensor.
+:, length]`` (a token row: ``t[:, slot, length]``) with one
+``dynamic_update_slice`` per lane and tensor.
 :func:`layer_append`, the write per lane, tensor AND layer, stays for the
 flash-decode kernel, which streams its pages from HBM and so needs the
 token there before it runs; chunks (:func:`layer_append_chunk`) and
@@ -73,7 +91,8 @@ compiler copies both tensors whole into another; where rows are staged
 V's are read only after the softmax, or both tensors' staged rows are
 alive at once and one of them leaves the chip's on-chip memory; each
 lane's window is laid out only after the write before it, or all of them
-(6 MiB each, one position padded to the 128 of a tile) are held at once.
+(one position padded to a tile's worth: 6 MiB each where positions are
+minor, 0.79 MB of a token row) are held at once.
 
 **Two kinds of layer in one model** (:class:`HybridCacheConfig`): full
 layers keep every position of a slot, window layers a ring of ``window``
@@ -107,10 +126,12 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import heapq
+import math
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
@@ -121,7 +142,8 @@ __all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
            "hybrid_prefill", "hybrid_append_tokens", "attend_slots",
            "latent_prefill",
            "latent_append_tokens", "latent_attend_slots", "init_cache",
-           "attend_rows", "attend_layer", "read_in_place",
+           "attend_rows", "attend_layer", "read_in_place", "page_order",
+           "logical_pages",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
            "layer_append_chunk", "layer_prefill", "quantize_rows",
            "dequantize_rows", "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -221,6 +243,30 @@ class KVCacheConfig:
     def quantized(self) -> bool:
         return self.store != "raw"
 
+    @property
+    def page_order(self) -> str:
+        """How a layer's pages of a row lie (:func:`page_order`)."""
+        return page_order(self.kv_heads, self.head_dim, self.max_len)
+
+    def shapes(self) -> Dict[str, Tuple[int, ...]]:
+        """The stored shape of every cache tensor: the payload by its
+        :attr:`page_order`, the scales of a quantized store ``[layers,
+        rows, kv_heads, max_len]`` in every order."""
+        lead = (self.layers, self.rows)
+        by_head = lead + (self.kv_heads, self.max_len)
+        pay = lead + (self.max_len, self.kv_heads * self.head_dim) \
+            if self.page_order == "token_rows" else by_head + (self.head_dim,)
+        out = {"k": pay, "v": pay}
+        if self.quantized:
+            out["k_scale"] = out["v_scale"] = by_head
+        return out
+
+    def page_orders(self) -> Dict[str, str]:
+        """:func:`page_order` of every cache tensor by name; a scale has
+        no ``head_dim``, its positions are minor."""
+        return {name: self.page_order if name in ("k", "v")
+                else "positions_minor" for name in self.shapes()}
+
     def bytes(self) -> int:
         """Device bytes of one cache (payload pages + riding scales)."""
         per = self.layers * self.rows * self.max_len * self.kv_heads
@@ -241,14 +287,11 @@ class KVCacheConfig:
 
 def init_cache(cfg: KVCacheConfig) -> dict:
     """Zeroed cache dict: ``{"k", "v"}`` payload pages (plus
-    ``{"k_scale", "v_scale"}`` when quantized)."""
-    shape = (cfg.layers, cfg.rows, cfg.kv_heads, cfg.max_len, cfg.head_dim)
+    ``{"k_scale", "v_scale"}`` when quantized), each in its stored shape
+    (:meth:`KVCacheConfig.shapes`)."""
     dt = store_dtype(cfg.store, cfg.dtype)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if cfg.quantized:
-        cache["k_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-        cache["v_scale"] = jnp.zeros(shape[:-1], jnp.float32)
-    return cache
+    return {name: jnp.zeros(shape, dt if name in ("k", "v") else jnp.float32)
+            for name, shape in cfg.shapes().items()}
 
 
 # ---------------------------------------------------------------------------
@@ -265,6 +308,57 @@ def _positions_minor(head_dim: int, max_len: int) -> bool:
     return waste(head_dim) > waste(max_len)
 
 
+def page_order(kv_heads: int, head_dim: int, max_len: int) -> str:
+    """How a layer's pages of a row lie in a dense cache tensor, from the
+    shapes alone; what a decode token's write costs on a TPU is the tiles
+    it touches, each read and written back, so the order is the one in
+    which a token touches fewest without a lane padded:
+
+    * ``"head_dim_minor"``: ``[kv_heads, max_len, head_dim]`` with a
+      ``head_dim`` that fills the 128 lanes.  A token is one row of each
+      head's tiles.
+    * ``"token_rows"``: ``[max_len, kv_heads * head_dim]``, positions down
+      the sublanes and a token's heads and ``head_dim`` side by side in
+      the lanes, where ``head_dim`` alone is under 128 but the product
+      fills them (16 heads of 64): a token is one ROW, 8 tiles of 1,024
+      lanes, and the same bytes as any other order.
+    * ``"positions_minor"``: ``[kv_heads, max_len, head_dim]`` held with
+      the positions in the lanes (:func:`_positions_minor`), where neither
+      holds (one kv head of 64 on a tp rank): a token is one COLUMN, one
+      element in every tile of ``head_dim`` sublanes, which is what 16
+      heads of 64 cost before they were token rows (1,536 tiles of 4 KB a
+      token's window over 24 layers, 60 us of a v5e, 64 windows a call)."""
+    if head_dim % 128 == 0:
+        return "head_dim_minor"
+    if head_dim < 128 and (kv_heads * head_dim) % 128 == 0:
+        return "token_rows"
+    return "positions_minor" if _positions_minor(head_dim, max_len) \
+        else "head_dim_minor"
+
+
+def _token_rows(t: jax.Array, stacked: bool = True) -> bool:
+    """Whether a PAYLOAD tensor of a dense cache (``k``/``v``: stacked
+    over the layers, or one layer of it) holds token rows ``[..., rows,
+    max_len, kv_heads * head_dim]``, one axis fewer than pages kept by
+    head ``[..., rows, kv_heads, max_len, head_dim]``."""
+    return t.ndim == 3 + stacked
+
+
+def logical_pages(t: jax.Array, head_dim: int,
+                  stacked: bool = False) -> jax.Array:
+    """A payload tensor (or any leading slice of one) in the ONE order
+    every reader but the in-place decode read takes: ``[..., kv_heads,
+    max_len, head_dim]``.  Pages kept by head are that already; token rows
+    are split and turned, a copy where it is not fused away (the staged
+    reads, the flash-decode kernel's view: paths whose speed nothing
+    measures)."""
+    if not _token_rows(t, stacked):
+        return t
+    return jnp.swapaxes(
+        t.reshape(t.shape[:-1] + (t.shape[-1] // head_dim, head_dim)),
+        -3, -2)
+
+
 def _pin(w: jax.Array, major_to_minor: Tuple[int, ...]) -> jax.Array:
     """``w`` held to an axis order in memory, when compiling for a TPU.
     Other backends keep everything row-major and need no pin."""
@@ -275,9 +369,12 @@ def _pin(w: jax.Array, major_to_minor: Tuple[int, ...]) -> jax.Array:
 
 
 def _pin_window(upd: jax.Array, max_len: int) -> jax.Array:
-    """Give a window of a cache tensor ``[layers, 1, kv_heads, T(,
-    head_dim)]``, one about to be written or one just read, the axis
-    order the tensor has in a TPU's memory (:func:`_positions_minor`).
+    """Give a window of a cache tensor (``[layers, 1, kv_heads, T,
+    head_dim]`` of pages kept by head, ``[layers, 1, T, kv_heads *
+    head_dim]`` of token rows, ``[layers, 1, kv_heads, T]`` of scales),
+    one about to be written or one just read, the axis order the tensor
+    has in a TPU's memory: its own, but for pages kept by head with the
+    positions minor (:func:`_positions_minor`).
 
     A ``dynamic_update_slice`` wants buffer and update in one order, and
     the compiler, left alone, has moved the 1.7 GB buffer to the order of
@@ -291,12 +388,23 @@ def _pin_window(upd: jax.Array, max_len: int) -> jax.Array:
     return _pin(upd, order)
 
 
+def _at(t: jax.Array, layer, row, ax: int, pos) -> tuple:
+    """The start of a window of ``t`` at ``[layer, row]`` whose position
+    axis ``ax`` starts at ``pos``, every other axis at 0."""
+    at = [layer, row] + [0] * (t.ndim - 2)
+    at[ax] = pos
+    return tuple(at)
+
+
 def _write_lanes(t: jax.Array, layer: jax.Array, slots: jax.Array,
-                 pos: jax.Array, upd: jax.Array) -> jax.Array:
+                 pos: jax.Array, upd: jax.Array, ax: int = 3) -> jax.Array:
     """``t[layer, slots[i], :, pos[i] + j] = upd[i, :, j]`` for every lane
     ``i`` in order (last write wins on the shared trash row) and every
-    ``j < T``: ``t`` is a stacked cache tensor ``[layers, rows, kv_heads,
-    max_len(, head_dim)]``, ``upd`` is ``[S, kv_heads, T(, head_dim)]``.
+    ``j < T``: ``t`` is a stacked cache tensor whose axis ``ax`` holds the
+    positions (``[layers, rows, kv_heads, max_len(, head_dim)]``, or token
+    rows ``[layers, rows, max_len, kv_heads * head_dim]`` with ``ax`` 2),
+    ``upd`` is a lane's window of it per lane, ``T`` positions long
+    (``[S, kv_heads, T(, head_dim)]``, ``[S, T, kv_heads * head_dim]``).
 
     One ``dynamic_update_slice`` per lane, unrolled, NOT one scatter: the
     TPU's scatter wants its indexed axes (layer, row, position) major and
@@ -309,24 +417,25 @@ def _write_lanes(t: jax.Array, layer: jax.Array, slots: jax.Array,
     (the last one, which nothing reads), and a chunk that straddles the
     end is clamped into range and keeps what its window held before it.
     """
-    S, T, max_len = upd.shape[0], upd.shape[2], t.shape[3]
-    tail = (0,) * (t.ndim - 4)                          # head_dim, if any
+    S, T, max_len = upd.shape[0], upd.shape[ax - 1], t.shape[ax]
     start = jnp.minimum(pos, max_len - T)
     over = pos - start               # > 0: the window runs past max_len
     rows = jnp.where(over < T, slots, t.shape[1] - 1)
-    ats = [(layer, rows[i], 0, start[i]) + tail for i in range(S)]
+    ats = [_at(t, layer, rows[i], ax, start[i]) for i in range(S)]
     new = upd.astype(t.dtype)
     if T > 1:
         # every window is read before any is written: live lanes hold
         # rows of their own, and what the shared trash row held matters
         # to no one
-        j = jnp.arange(T).reshape((1, 1, T) + (1,) * len(tail))
-        over = over.reshape((S, 1, 1) + (1,) * len(tail))
+        along = [1] * upd.ndim
+        along[ax - 1] = T
+        j = jnp.arange(T).reshape(along)
+        over = over.reshape((S,) + (1,) * (upd.ndim - 1))
         old = jnp.concatenate(
             [lax.dynamic_slice(t, at, (1, 1) + new.shape[1:])[0]
              for at in ats])
         new = jnp.where(j >= over,
-                        jnp.take_along_axis(new, (j - over) % T, axis=2),
+                        jnp.take_along_axis(new, (j - over) % T, axis=ax - 1),
                         old)
     for i, at in enumerate(ats):
         t = lax.dynamic_update_slice(
@@ -339,12 +448,17 @@ def _write_in_turn(buf: jax.Array, new: jax.Array,
     """``buf`` with lane i's window ``new[:, i:i + 1]`` written at
     ``starts[i]``, in order, each ``pin``-ned to ``buf``'s axis order.
     On a TPU a window is laid out only once the write before it is done:
-    in the cache's tiling a single position pads to the 128 of a tile
-    (6 MiB for 49 KB at 24 layers x 16 heads x 64), and unordered the
-    compiler lays out every lane's window first and holds them all.  So
-    the window crosses a barrier with the write before it in the order
-    it was computed in, and is given the buffer's only behind it.  (The
-    CPU's compiler answers the same barrier with a copy of ``buf``.)"""
+    a single position pads to a tile's worth of them (128 where positions
+    are minor, 6 MiB for 49 KB at 24 layers x 16 heads x 64; 16 sublanes
+    of a token row, 0.79 MB), and unordered the compiler lays out every
+    lane's window first and holds them all.  So the window crosses a
+    barrier with the write before it in the order it was computed in, and
+    is given the buffer's only behind it.  (The CPU's compiler answers
+    the same barrier with a copy of ``buf``.  Token rows no longer need
+    the chain: at 24 layers x 32 lanes the decode program compiled for a
+    v5e holds 2.9 MB of temporaries with it and without; it stays, as
+    the one path that positions-minor pages, the rings and the latent
+    cache share, whose programs are as they were.)"""
     for i, at in enumerate(starts):
         w = _pin(new[:, i:i + 1], tuple(range(new.ndim)))
         buf, w = lax.platform_dependent(
@@ -355,53 +469,38 @@ def _write_in_turn(buf: jax.Array, new: jax.Array,
 
 
 def _write_tokens(t: jax.Array, slots: jax.Array, pos: jax.Array,
-                  upd: jax.Array) -> jax.Array:
+                  upd: jax.Array, ax: int = 3) -> jax.Array:
     """``t[:, slots[i], :, pos[i]] = upd[:, i]`` for every lane ``i`` in
     order: :func:`_write_lanes` for one token per lane and ALL layers at
     once.  ``upd`` is ``[layers, S, kv_heads(, head_dim)]``; the window
-    of one lane is ``[layers, 1, kv_heads, 1(, head_dim)]``, pinned like
-    every other.  Last write wins on the shared trash row, and a position
-    at or past ``max_len`` goes there too.
+    of one lane is ``[layers, 1, kv_heads, 1(, head_dim)]``, or of token
+    rows (``ax`` 2: the positions' axis of ``t``) ``[layers, 1, 1,
+    kv_heads * head_dim]``, pinned like every other.  Last write wins on
+    the shared trash row, and a position at or past ``max_len`` goes
+    there too.
 
     What a write costs on a TPU is neither its launch nor its bytes but
-    the tiles it touches, each read and written back: where positions
-    are minor a token has one element in every tile of its column
-    (1,536 tiles of 4 KB for 49 KB at 24 layers x 16 heads x 64).  There
-    heads and ``head_dim`` are neighbours in memory, so the pages are
-    written as ``[layers, rows, max_len, kv_heads * head_dim]`` (the same
-    bytes, no copy): one run of tiles per layer instead of one per layer
-    and head, 60 µs a window instead of 77 at those sizes on a v5e."""
-    S, max_len = upd.shape[1], t.shape[3]
+    the tiles it touches, each read and written back (39 ns a tile of
+    4 KB on a v5e): a token row touches 8 a layer at 16 heads of 64,
+    where the same token in a cache with the positions minor had one
+    element in every tile of its column, 64 a layer
+    (:func:`page_order`)."""
+    S, max_len = upd.shape[1], t.shape[ax]
     rows = jnp.where(pos < max_len, slots, t.shape[1] - 1)
     at = jnp.minimum(pos, max_len - 1)
-
-    def per_head(t, new):
-        tail = (0,) * (t.ndim - 4)                      # head_dim, if any
-        return _write_in_turn(
-            t, jnp.expand_dims(new, 3),
-            [(0, rows[i], 0, at[i]) + tail for i in range(S)],
-            lambda w: _pin_window(w, max_len))
-
-    def heads_merged(t, new):
-        Ls, R, H, P, D = t.shape
-        pin = lambda w: _pin(w, (0, 1, 3, 2))
-        flat = pin(t.transpose(0, 1, 3, 2, 4).reshape(Ls, R, P, H * D))
-        flat = _write_in_turn(
-            flat, new.reshape(Ls, S, 1, H * D),
-            [(0, rows[i], at[i], 0) for i in range(S)], pin)
-        return flat.reshape(Ls, R, P, H, D).transpose(0, 1, 3, 2, 4)
-
     new = upd.astype(t.dtype)
-    if t.ndim == 5 and _positions_minor(t.shape[4], max_len):
-        return lax.platform_dependent(t, new, tpu=heads_merged,
-                                      default=per_head)
-    return per_head(t, new)
+    new = new.reshape(new.shape[:2] + (1, -1)) if ax == 2 \
+        else jnp.expand_dims(new, 3)
+    return _write_in_turn(
+        t, new, [_at(t, 0, rows[i], ax, at[i]) for i in range(S)],
+        lambda w: _pin_window(w, max_len))
 
 
 def _read_lanes(t: jax.Array, layer: jax.Array,
                 rows: jax.Array) -> jax.Array:
     """``t[layer, rows[i]]`` for every lane: ``[S, kv_heads, max_len(,
-    head_dim)]`` out of a stacked cache tensor.  One ``dynamic_slice`` per
+    head_dim)]`` (token rows: ``[S, max_len, kv_heads * head_dim]``) out
+    of a stacked cache tensor.  One ``dynamic_slice`` per
     lane, unrolled, NOT one gather: the TPU's gather of rows this long
     first cuts its whole operand — here all layers of the cache — into
     four pieces along ``max_len`` (``mini-gather-slice``), a copy of the
@@ -453,6 +552,13 @@ def layer_append(cache: Dict[str, jax.Array], layer: jax.Array,
                               v_new[:, None], store)
 
 
+def _positions_axis(name: str, t: jax.Array) -> int:
+    """The axis of the stacked cache tensor ``t`` called ``name`` that
+    holds the positions: 2 of token rows, 3 of everything kept by head
+    (pages and scales)."""
+    return 2 if name in ("k", "v") and _token_rows(t) else 3
+
+
 @jax.named_scope("cache.write")
 def append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
                   lengths: jax.Array, new: Dict[str, jax.Array]
@@ -461,12 +567,15 @@ def append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
     dict at once: ``new`` holds, per cache tensor, the
     :func:`token_pages` of all layers stacked (``[layers, S, kv_heads(,
     head_dim)]``, a layer scan's ``ys``), and ``t[:, slots[i], :,
-    lengths[i]] = new[:, i]``.  The cache ends up as ``layers`` calls of
-    :func:`layer_append` leave it, with one ``dynamic_update_slice`` per
-    lane and tensor instead of one per lane, tensor and layer (1,536 of
-    2 KB in a 24-layer decode call of 32 lanes, 4.19 µs each on a v5e:
-    6.45 ms of a 16.85 ms program; the 64 that replace them take 3.84)."""
-    return {name: _write_tokens(t, slots, lengths, new[name])
+    lengths[i]] = new[:, i]`` (of token rows ``t[:, slots[i],
+    lengths[i]]``, a token's heads side by side).  The cache ends up as
+    ``layers`` calls of :func:`layer_append` leave it, with one
+    ``dynamic_update_slice`` per lane and tensor instead of one per lane,
+    tensor and layer (1,536 of 2 KB in a 24-layer decode call of 32
+    lanes, 4.19 µs each on a v5e: 6.45 ms of a 16.85 ms program; the 64
+    that replaced them took 3.84 while the positions were minor)."""
+    return {name: _write_tokens(t, slots, lengths, new[name],
+                                _positions_axis(name, t))
             for name, t in cache.items()}
 
 
@@ -480,9 +589,14 @@ def layer_append_chunk(cache: Dict[str, jax.Array], layer: jax.Array,
     ``k_new/v_new`` are ``[S, T, kv_heads, head_dim]`` and token t of
     lane i lands at position ``lengths[i] + t`` of row ``slots[i]``."""
     new = token_pages(k_new, v_new, store, cache["k"].dtype)
-    return {name: _write_lanes(t, layer, slots, lengths,
-                               jnp.swapaxes(new[name], 1, 2))
-            for name, t in cache.items()}
+    S, T = k_new.shape[:2]
+
+    def land(name, t):
+        ax = _positions_axis(name, t)
+        upd = new[name].reshape(S, T, -1) if ax == 2 \
+            else jnp.swapaxes(new[name], 1, 2)
+        return _write_lanes(t, layer, slots, lengths, upd, ax)
+    return {name: land(name, t) for name, t in cache.items()}
 
 
 @jax.named_scope("cache.write")
@@ -496,13 +610,17 @@ def layer_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
     length masks never read before an append overwrites them."""
     qk, sk = quantize_rows(k, store)
     qv, sv = quantize_rows(v, store)
-    max_len = cache["k"].shape[3]
+    rows = _token_rows(cache["k"])
+    max_len = cache["k"].shape[2 if rows else 3]
     out = dict(cache)
     for name, pay in (("k", qk), ("v", qv)):
+        # token rows take the block as the projection leaves it; pages
+        # kept by head take it turned
+        pay = pay.reshape(pay.shape[0], -1) if rows else pay.transpose(1, 0, 2)
         out[name] = lax.dynamic_update_slice(
             cache[name], _pin_window(
-                pay.transpose(1, 0, 2)[None, None].astype(cache[name].dtype),
-                max_len), (layer, slot_id, 0, 0, 0))
+                pay[None, None].astype(cache[name].dtype), max_len),
+            _at(cache[name], layer, slot_id, 2, 0))
     if sk is not None:
         for name, sc in (("k_scale", sk), ("v_scale", sv)):
             out[name] = lax.dynamic_update_slice(
@@ -511,8 +629,8 @@ def layer_prefill(cache: Dict[str, jax.Array], layer: jax.Array,
     return out
 
 
-def _gather_pages(cl: Dict[str, jax.Array], name: str, slots: jax.Array,
-                  prefix_slots: Optional[jax.Array],
+def _gather_pages(cl: Dict[str, jax.Array], name: str, head_dim: int,
+                  slots: jax.Array, prefix_slots: Optional[jax.Array],
                   prefix_lens: Optional[jax.Array],
                   layer: Optional[jax.Array] = None,
                   new: Optional[Dict[str, jax.Array]] = None,
@@ -525,10 +643,12 @@ def _gather_pages(cl: Dict[str, jax.Array], name: str, slots: jax.Array,
     materialized).  With ``new`` (one token's :func:`token_pages` per
     lane, not yet written) position ``lengths[i]`` reads the token,
     dequantized as its page would be.  Returns the f32-dequantized rows
-    ``[S, Hkv, max_len, Dh]``."""
+    in the logical order ``[S, Hkv, max_len, Dh]`` whatever order the
+    pages are stored in (:func:`logical_pages`)."""
     def rows(tensor, r):
-        return cl[tensor][r] if layer is None else \
+        got = cl[tensor][r] if layer is None else \
             _read_lanes(cl[tensor], layer, r)
+        return logical_pages(got, head_dim) if tensor == name else got
 
     sname = name + "_scale"
     pay = rows(name, slots)
@@ -549,6 +669,14 @@ def _gather_pages(cl: Dict[str, jax.Array], name: str, slots: jax.Array,
         out = jnp.where(here, dequantize_rows(
             new[name], new.get(sname), jnp.float32)[:, :, None], out)
     return out
+
+
+def _heads_and_positions(t: jax.Array, head_dim: int,
+                         stacked: bool) -> Tuple[int, int]:
+    """``(kv_heads, max_len)`` of a payload tensor in either order."""
+    if _token_rows(t, stacked):
+        return t.shape[-1] // head_dim, t.shape[-2]
+    return t.shape[-3], t.shape[-2]
 
 
 def read_in_place(lanes: int, rows: int) -> bool:
@@ -601,38 +729,92 @@ def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
     float32, and the probabilities enter the value product as ``probs``
     (None: float32 as they are).
 
+    Pages of token rows (one part, ``kts[0]`` and ``vt`` ``[rows, L,
+    kv_heads * d]``: :func:`page_order`) are contracted AS THEY LIE, the
+    lanes never split into heads: a row's queries are spread
+    block-diagonally over them (q head ``h`` in the lanes of kv head ``h
+    // group``, zeros elsewhere) for the scores, every head's
+    probabilities weigh the whole row of values and the head keeps the
+    lanes that are its own.  The zeros add an exact 0.0 to a float32 sum:
+    the by-head result in another order of summation, at ``kv_heads``
+    times the multiply-adds, which a matrix unit has to spare beside the
+    bytes.  Products are exact whatever the dtypes: an explicit precision
+    where an operand is float32, but for float32 probabilities on
+    bfloat16 pages, which go in as their three bfloat16 pieces stacked
+    into one matmul (the same float32 sum in ONE pass over each tile of
+    pages, where the explicit precision makes three: 16 query rows a tile
+    leave the matrix unit no pass to spare).
+
     ``stage`` (None: decided from the shapes, :func:`read_in_place`)
     gathers the lanes' rows first and meets them alone.  Returns the
     lanes' result ``[S, heads, dv]`` in float32 and the cache positions
     the einsums met."""
     S, H = qs[0].shape[:2]
-    R, Hkv, L, Dv = vt.shape
+    (Hkv, Dv), (R, L) = vn.shape[1:], (vt.shape[0], vt.shape[-2])
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     if (not read_in_place(S, R)) if stage is None else stage:
         kts, vt = [kt[slots] for kt in kts], vt[slots]
         slots, R = jnp.arange(S), S
     by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
-    qs = [by_row(q).reshape(R, Hkv, H // Hkv, -1) for q in qs]
+    qs = [by_row(q) for q in qs]
     kns, vn, at = [by_row(kn) for kn in kns], by_row(vn), by_row(lengths)
     j = jnp.arange(L)[None, :]
     valid = j < at[:, None]
     if ring:
         valid = valid & (j != (at % L)[:, None])
     f32 = dict(preferred_element_type=jnp.float32)
-    total = lambda parts: sum(parts[1:], parts[0])
-    s = total([jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
-               for q, kt in zip(qs, kts)])
-    sn = total([jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
-                for q, kn in zip(qs, kns)])
+    if vt.ndim == 3:                                        # token rows
+        (q,), G = qs, H // Hkv
+        exact = dict(f32, precision=lax.Precision.HIGHEST)
+        # which lanes are a head's own: a constant of the program (numpy),
+        # not iotas compared again in every layer
+        own = (np.arange(Hkv * Dv)[None, :] // Dv
+               == np.arange(H)[:, None] // G)               # [H, lanes]
+        q = jnp.where(own, jnp.tile(q, (1, 1, Hkv)), 0)
+        s = jnp.einsum("rhc,rlc->rhl", q, kts[0], **exact)
+        sn = jnp.einsum("rhc,rc->rh", q, kns[0].reshape(R, -1), **exact)
+        vn = jnp.repeat(vn, G, axis=1)                      # [R, H, dv]
+
+        def weigh(p):
+            # float32 probabilities on the pages in their own dtype, no
+            # pass of either rounded.  On bfloat16 pages the float32
+            # probabilities go in as their three bfloat16 pieces (hi + mid
+            # + lo is the float32 exactly), stacked as 3 H rows of ONE
+            # matmul and summed in float32: what an explicit precision
+            # computes in three passes over the pages' tiles (2.50 ms a
+            # pass over V at the serving cell's sizes on a v5e where the
+            # bytes take 2.34), in one (2.38)
+            pages = (((2,), (1,)), ((0,), (0,)))
+            if p.dtype == jnp.float32 and vt.dtype == jnp.bfloat16:
+                hi = p.astype(vt.dtype)
+                rest = p - hi.astype(p.dtype)
+                mid = rest.astype(vt.dtype)
+                lo = (rest - mid.astype(p.dtype)).astype(vt.dtype)
+                wide = lax.dot_general(
+                    jnp.concatenate([hi, mid, lo], 1), vt, pages, **f32)
+                wide = wide[:, :H] + wide[:, H:2 * H] + wide[:, 2 * H:]
+            else:
+                wide = lax.dot_general(p, vt, pages, **exact)
+            return jnp.sum(jnp.where(own, wide, 0.0)
+                           .reshape(R, H, Hkv, Dv), 2)
+    else:
+        qs = [q.reshape(R, Hkv, H // Hkv, -1) for q in qs]
+        total = lambda parts: sum(parts[1:], parts[0])
+        s = total([jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
+                   for q, kt in zip(qs, kts)])
+        sn = total([jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
+                    for q, kn in zip(qs, kns)])
+        vn = vn[:, :, None, :]                              # [R, Hkv, 1, dv]
+        weigh = lambda p: jnp.einsum("rkgl,rkld->rkgd", p, vt, **f32)
     if scale is not None:
         s, sn = s * scale, sn * scale
-    s = jnp.where(valid[:, None, None, :], s, -jnp.inf)
+    s = jnp.where(jnp.expand_dims(valid, tuple(range(1, s.ndim - 1))), s,
+                  -jnp.inf)
     m = jnp.maximum(jnp.max(s, -1), sn)
     p, pn = jnp.exp(s - m[..., None]), jnp.exp(sn - m)
-    out = jnp.einsum("rkgl,rkld->rkgd",
-                     p if probs is None else p.astype(probs), vt, **f32) \
-        + pn[..., None] * vn[:, :, None, :].astype(jnp.float32)
+    out = weigh(p if probs is None else p.astype(probs)) \
+        + pn[..., None] * vn.astype(jnp.float32)
     out = out / (jnp.sum(p, -1) + pn)[..., None]
     return out.reshape(R, H, Dv)[slots], R * L
 
@@ -654,9 +836,11 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     ``h // group``, via a reshape-grouped einsum that never materializes
     repeated K/V copies);
     ``kl/vl``: one layer's pages, or with ``layer`` the stacked cache
-    read at that layer; ``lengths``: the new token's position, so keys
-    ``0 .. lengths[i]`` inclusive are valid.  The token is either in the
-    pages already (post-append) or handed over as ``new``, its
+    read at that layer, in either stored order (by head, or token rows:
+    every reader here meets them as :func:`logical_pages`); ``lengths``:
+    the new token's position, so keys ``0 .. lengths[i]`` inclusive are
+    valid.  The token is either in the pages already (post-append) or
+    handed over as ``new``, its
     :func:`token_pages` (``[S, kv_heads(, head_dim)]`` per tensor): the
     attention then sees at ``lengths[i]`` exactly what reading the
     written page would give, and the write can wait
@@ -667,7 +851,7 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     ``-inf`` masking.
     """
     S, H, Dh = q.shape
-    Hkv, L = kl.shape[-3], kl.shape[-2]
+    Hkv, L = _heads_and_positions(kl, Dh, layer is not None)
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     if scale is None:
@@ -675,7 +859,7 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     cl = {"k": kl, "v": vl}
     if k_scale is not None:
         cl["k_scale"], cl["v_scale"] = k_scale, v_scale
-    pages = (slots, prefix_slots, prefix_lens, layer, new, lengths)
+    pages = (Dh, slots, prefix_slots, prefix_lens, layer, new, lengths)
     ct = jnp.promote_types(q.dtype, jnp.float32)
     qg = (q.astype(ct) * scale).reshape(S, Hkv, H // Hkv, Dh)
     ks = _gather_pages(cl, "k", *pages)
@@ -701,21 +885,28 @@ def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
     """:func:`attend_rows` of raw pages without prefix rows, read IN
     PLACE: one new token per lane (``q`` ``[S, heads, head_dim]``, its
     ``new`` :func:`token_pages` not yet written) over ``layer`` of the
-    stacked cache tensors ``kl``/``vl`` ``[layers, rows, kv_heads,
-    max_len, head_dim]``; ``layer`` may be a scanned index.  The same
-    arithmetic as the staged form (the scale folded into float32 queries,
+    stacked cache tensors ``kl``/``vl`` (``[layers, rows, kv_heads,
+    max_len, head_dim]``, or token rows ``[layers, rows, max_len, kv_heads
+    * head_dim]``); ``layer`` may be a scanned index.  The same
+    arithmetic as the staged form (the scale folded into the queries,
     pages in their own dtype, exact products, float32 softmax and float32
     probabilities into the value product) in another order of summation.
-    A bucket under a third of the rows (:func:`read_in_place`) takes the
-    staged form itself.  Returns the lanes' result and the cache
-    positions met."""
-    S, R, L = q.shape[0], kl.shape[1], kl.shape[3]
+    The queries are float32, but over token rows they keep their own
+    dtype where the scale is a power of two (0.125 for a ``head_dim`` of
+    64): the product with it is exact there, and a matrix unit then takes
+    queries and pages in one dtype.  A bucket under a third of the rows
+    (:func:`read_in_place`) takes the staged form itself.  Returns the
+    lanes' result and the cache positions met."""
+    rows = _token_rows(kl)
+    S, R, L = q.shape[0], kl.shape[1], kl.shape[2 if rows else 3]
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if not read_in_place(S, R):
         return attend_rows(q, kl, vl, slots, lengths, scale, layer=layer,
                            new=new), S * L
     ct = jnp.promote_types(q.dtype, jnp.float32)
+    if rows and math.frexp(scale)[0] == 0.5:
+        ct = q.dtype
     pin = lambda w: _pin_window(w, L)
     out, met = _attend_by_row(
         (q.astype(ct) * scale,), (_layer_pages(kl, layer, pin),),
@@ -738,12 +929,12 @@ def attend_chunk(q: jax.Array, cl: Dict[str, jax.Array], slots: jax.Array,
     and the stacked cache at ``layer`` read exactly as in
     :func:`attend_rows`."""
     S, T, H, Dh = q.shape
-    Hkv, L = cl["k"].shape[-3], cl["k"].shape[-2]
+    Hkv, L = _heads_and_positions(cl["k"], Dh, layer is not None)
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     if scale is None:
         scale = Dh ** -0.5
-    ks, vs = (_gather_pages(cl, name, slots, prefix_slots, prefix_lens,
+    ks, vs = (_gather_pages(cl, name, Dh, slots, prefix_slots, prefix_lens,
                             layer) for name in ("k", "v"))
     ct = jnp.promote_types(q.dtype, jnp.float32)
     qg = (q.astype(ct) * scale).reshape(S, T, Hkv, H // Hkv, Dh)
@@ -793,6 +984,13 @@ class LatentCacheConfig:
     def shapes(self) -> Dict[str, Tuple[int, ...]]:
         lead = (self.layers, self.rows, self.max_len)
         return {"ckv": lead + (self.kv_rank,), "kr": lead + (self.rope_dim,)}
+
+    def page_orders(self) -> Dict[str, str]:
+        """How each tensor's ``[max_len, dim]`` pages lie, in
+        :func:`page_order`'s words (one vector a token: there are no
+        heads to put side by side)."""
+        return {name: page_order(1, shape[-1], self.max_len)
+                for name, shape in self.shapes().items()}
 
     def bytes(self) -> int:
         return self.rows * self.max_len * self.bytes_per_token()
@@ -931,6 +1129,12 @@ class HybridCacheConfig:
         ring = (self.window_layers, self.rows, self.kv_heads, self.window,
                 self.head_dim)
         return {"k": full, "v": full, "kw": ring, "vw": ring}
+
+    def page_orders(self) -> Dict[str, str]:
+        """How each tensor's pages lie; both kinds are kept by head, so
+        never as token rows (:func:`page_order` of one head)."""
+        return {name: page_order(1, self.head_dim, shape[3])
+                for name, shape in self.shapes().items()}
 
     def _position_bytes(self) -> int:
         """K and V of one position in one layer."""

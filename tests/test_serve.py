@@ -293,6 +293,274 @@ def test_attend_layer_reads_in_place(case, against):
                                rtol=2e-5, atol=2e-6)
 
 
+_PAGE_ORDERS = {
+    # case: (kv heads, head_dim, max_len, the order its pages lie in)
+    # the dense serving cell: 16 heads of 64 side by side fill 1,024 lanes
+    "cell_16_heads_of_64": (16, 64, 1024, "token_rows"),
+    # the hybrid cell's full layers and its rings: head_dim fills the lanes
+    "full_layers_of_128": (8, 128, 8704, "head_dim_minor"),
+    "rings_of_128": (8, 128, 128, "head_dim_minor"),
+    # one kv head of 64 on a tp rank: nothing to put beside it
+    "one_head_of_64": (1, 64, 1024, "positions_minor"),
+    # the padding-driven cases ``_positions_minor`` decided alone
+    "padding_8_under_16": (2, 8, 16, "positions_minor"),
+    "padding_128_over_24": (2, 128, 24, "head_dim_minor"),
+    "three_heads_of_64": (3, 64, 1024, "positions_minor"),
+    "two_heads_of_64": (2, 64, 16, "token_rows"),
+    "head_dim_256": (4, 256, 512, "head_dim_minor"),
+}
+
+
+@pytest.mark.parametrize("store", ["raw", "int8"])
+@pytest.mark.parametrize("case", sorted(_PAGE_ORDERS))
+def test_page_order_is_a_function_of_the_shapes(case, store):
+    """The order a dense cache's pages lie in, from ``kv_heads``,
+    ``head_dim`` and ``max_len`` alone: the stored shape follows it, the
+    scales of a quantized store stay ``[layers, rows, kv_heads,
+    max_len]``, ``bytes()`` is what the stored arrays hold in every
+    order, and ``page_orders()`` (``program_memory()``'s word for every
+    tensor) says so by name."""
+    from bluefog_tpu.serve import kv_cache as kv
+    Hkv, Dh, L, order = _PAGE_ORDERS[case]
+    assert kv.page_order(Hkv, Dh, L) == order
+    if order != "token_rows" and Dh % 128:
+        assert kv._positions_minor(Dh, L) == (order == "positions_minor")
+    cc = KVCacheConfig(layers=2, slots=2, max_len=L, kv_heads=Hkv,
+                       head_dim=Dh, store=store, prefix_slots=1)
+    assert cc.page_order == order
+    pay = (2, 4, L, Hkv * Dh) if order == "token_rows" \
+        else (2, 4, Hkv, L, Dh)
+    want = {"k": pay, "v": pay}
+    if store == "int8":
+        want["k_scale"] = want["v_scale"] = (2, 4, Hkv, L)
+    assert cc.shapes() == want
+    got = jax.eval_shape(lambda: init_cache(cc))
+    assert {k: v.shape for k, v in got.items()} == want
+    assert cc.bytes() == sum(int(np.prod(v.shape)) * v.dtype.itemsize
+                             for v in got.values())
+    assert cc.page_orders() == {
+        name: order if name in ("k", "v") else "positions_minor"
+        for name in want}
+
+
+def test_page_orders_of_the_other_two_caches():
+    """``page_orders()`` at the two other serving cells' shapes: the
+    latent cache's compressed vectors fill the lanes and its 64 rotary
+    dimensions lie with the positions minor; both kinds of the hybrid
+    cache are kept by head with ``head_dim`` 128 minor."""
+    from bluefog_tpu.serve import kv_cache as kv
+    lat = kv.LatentCacheConfig(layers=6, slots=128, max_len=2560,
+                               kv_rank=512, rope_dim=64)
+    assert lat.page_orders() == {"ckv": "head_dim_minor",
+                                 "kr": "positions_minor"}
+    hyb = kv.HybridCacheConfig(full_layers=2, window_layers=6, slots=48,
+                               max_len=8704, window=128, kv_heads=8,
+                               head_dim=128)
+    assert hyb.page_orders() == dict.fromkeys(
+        ("k", "v", "kw", "vw"), "head_dim_minor")
+
+
+def _token_row_caches(store, rng, layers=3, slots=4, L=16, Hkv=2, Dh=64):
+    """One cache full of earlier tokens in both orders: token rows as
+    ``init_cache`` lays them out at these shapes, and the same pages kept
+    by head (the order every function here also takes, by the tensors'
+    rank): ``(config, token rows, by head)``."""
+    import jax.numpy as jnp
+    cc = KVCacheConfig(layers=layers, slots=slots, max_len=L, kv_heads=Hkv,
+                       head_dim=Dh, store=store, prefix_slots=1)
+    assert cc.page_order == "token_rows"
+    dt = init_cache(cc)["k"].dtype
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    full = token_pages(normal(layers, cc.rows, L, Hkv, Dh),
+                       normal(layers, cc.rows, L, Hkv, Dh), store, dt)
+    by_head = {n: jnp.swapaxes(t, 2, 3) for n, t in full.items()}
+    rows = {n: t.reshape(layers, cc.rows, L, Hkv * Dh) if n in ("k", "v")
+            else by_head[n] for n, t in full.items()}
+    assert {n: t.shape for n, t in rows.items()} == cc.shapes()
+    return cc, rows, by_head
+
+
+def _assert_same_pages(rows, by_head, head_dim):
+    """A token-row cache holds, seen through the logical view, exactly
+    what the cache kept by head holds."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    assert sorted(rows) == sorted(by_head)
+    for n, t in rows.items():
+        seen = kv.logical_pages(t, head_dim, True) if n in ("k", "v") else t
+        np.testing.assert_array_equal(
+            np.asarray(seen.astype(jnp.float32)),
+            np.asarray(by_head[n].astype(jnp.float32)))
+
+
+_ROW_WRITES = {
+    # case: (slots, positions) of five lanes over 4 request slots, one
+    # prefix row (4) and the trash row (5) of 16 positions
+    "plain": ([3, 0, 2, 1, 4], [15, 0, 7, 2, 9]),
+    "trash_row_twice": ([3, 5, 0, 5, 1], [15, 0, 3, 2, 7]),
+    "at_and_past_max_len": ([0, 1, 2, 3, 5], [16, 19, 15, 4, 16]),
+    "chunk_straddles_max_len": ([0, 1, 2, 3, 5], [13, 14, 12, 0, 15]),
+}
+
+
+@pytest.mark.parametrize("store", ["raw", "int8"])
+@pytest.mark.parametrize("write", ["append_tokens", "layer_prefill",
+                                   "layer_append_chunk"])
+@pytest.mark.parametrize("case", sorted(_ROW_WRITES))
+def test_writes_land_the_same_in_token_rows(case, write, store):
+    """Every landing in a dense cache, on token rows against the same
+    pages kept by head: a token per lane after the layers
+    (``append_tokens``), a prompt's block as the projection leaves it
+    (``layer_prefill``), a chunk of four positions per lane
+    (``layer_append_chunk``: straddling ``max_len``, on the trash row, at
+    ``max_len``) leave the same pages under the logical view, scales
+    included, and do write."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve.kv_cache import layer_append_chunk, layer_prefill
+    rng = np.random.default_rng(sorted(_ROW_WRITES).index(case))
+    cc, rows, by_head = _token_row_caches(store, rng)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    slots, pos = (jnp.asarray(x, jnp.int32) for x in _ROW_WRITES[case])
+    S, Hkv, Dh = slots.shape[0], cc.kv_heads, cc.head_dim
+    if write == "append_tokens":
+        new = token_pages(normal(cc.layers, S, Hkv, Dh),
+                          normal(cc.layers, S, Hkv, Dh), store,
+                          rows["k"].dtype)
+        land = lambda c: append_tokens(c, slots, pos, new)
+    elif write == "layer_prefill":
+        k, v = normal(8, Hkv, Dh), normal(8, Hkv, Dh)
+        land = lambda c: layer_prefill(c, 1, slots[0], k, v, store)
+    else:
+        k, v = normal(S, 4, Hkv, Dh), normal(S, 4, Hkv, Dh)
+        land = lambda c: layer_append_chunk(c, 2, slots, pos, k, v, store)
+    got, want = land(rows), land(by_head)
+    _assert_same_pages(got, want, Dh)
+    assert any((np.asarray(got[n].astype(jnp.float32))
+                != np.asarray(rows[n].astype(jnp.float32))).any()
+               for n in got)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("against", ["oracle", "by_head", "staged",
+                                     "scanned_layer"])
+@pytest.mark.parametrize("group", [1, 4])
+def test_attend_layer_meets_token_rows_as_they_lie(group, against, dtype):
+    """``attend_layer`` over token rows (the queries spread
+    block-diagonally over a row's lanes, every head's probabilities on
+    the whole row of values, the head's own lanes kept) against the
+    float64 oracle on the logical view, against the by-head read of the
+    same pages, against the staged form through the logical view, and
+    with the layer a scanned index under ``jit``; one q head a kv head and
+    four (``H`` = 4 x ``Hkv``).  In bfloat16 the scale of 0.125 folds
+    into bfloat16 queries exactly, so the by-head read (float32 queries)
+    sums the same exact products: equal to float32 rounding either
+    way."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    dt = jnp.dtype(dtype)
+    rng = np.random.default_rng(group)
+    cc, rows, by_head = _token_row_caches("raw", rng)
+    rows, by_head = ({n: t.astype(dt) for n, t in c.items()}
+                     for c in (rows, by_head))
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), dt)
+    slots = jnp.array([3, 5, 0, 5, 1], jnp.int32)
+    lens = jnp.array([cc.max_len - 1, 0, 0, 2, 7], jnp.int32)
+    S, Hkv, Dh = slots.shape[0], cc.kv_heads, cc.head_dim
+    live = np.asarray(slots) < cc.trash_slot
+    q = normal(S, Hkv * group, Dh)
+    new = token_pages(normal(S, Hkv, Dh), normal(S, Hkv, Dh), "raw", dt)
+    got, met = kv.attend_layer(q, rows["k"], rows["v"], 1, slots, lens, new)
+    assert met == cc.rows * cc.max_len and got.dtype == dt
+    if against == "oracle":
+        want = token_beside_pages_oracle(
+            [q.astype(jnp.float32)], [by_head["k"][1].astype(jnp.float32)],
+            by_head["v"][1].astype(jnp.float32), slots, lens,
+            [new["k"].astype(jnp.float32)], new["v"].astype(jnp.float32),
+            Dh ** -0.5)
+    elif against == "by_head":
+        want, _ = kv.attend_layer(q, by_head["k"], by_head["v"], 1, slots,
+                                  lens, new)
+    elif against == "staged":
+        want = attend_rows(q, rows["k"], rows["v"], slots, lens, layer=1,
+                           new=new)
+    else:
+        want = got
+
+        @jax.jit
+        def scanned(q, kl, vl, slots, lens, new):
+            def body(_, layer):
+                return None, kv.attend_layer(q, kl, vl, layer, slots, lens,
+                                             new)[0]
+            return jax.lax.scan(body, None, jnp.arange(cc.layers))[1]
+        got = scanned(q, rows["k"], rows["v"], slots, lens, new)[1]
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=1e-2, atol=1e-2)
+    f32 = lambda t: np.asarray(jnp.asarray(t).astype(jnp.float32))
+    np.testing.assert_allclose(f32(got)[live], f32(want)[live], **tol)
+
+
+@pytest.mark.parametrize("group", [1, 4])
+def test_float32_probabilities_weigh_bfloat16_token_rows(group):
+    """The value product over bfloat16 token rows takes the float32
+    probabilities whole (as their three bfloat16 pieces, rows of one
+    matmul): the result is the one the same pages give as float32, where
+    the probabilities meet them at full precision, to float32 rounding;
+    probabilities rounded to bfloat16 on the way in would stand 4e-3
+    off."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    rng = np.random.default_rng(7 + group)
+    cc, rows, _ = _token_row_caches("raw", rng, L=64)
+    bf = {n: t.astype(jnp.bfloat16) for n, t in rows.items()}
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.bfloat16)
+    slots = jnp.array([3, 5, 0, 5, 1], jnp.int32)
+    lens = jnp.array([cc.max_len - 1, 0, 40, 2, 17], jnp.int32)
+    S, Hkv, Dh = slots.shape[0], cc.kv_heads, cc.head_dim
+    live = np.asarray(slots) < cc.trash_slot
+    q = normal(S, Hkv * group, Dh)
+    kn, vn = normal(S, Hkv, Dh), normal(S, Hkv, Dh)
+    run = lambda dt: np.asarray(kv._attend_by_row(
+        (q * 0.125,), (bf["k"][1].astype(dt),), bf["v"][1].astype(dt),
+        slots, lens, (kn,), vn, None)[0])
+    got, want = run(jnp.bfloat16), run(jnp.float32)
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("store", ["raw", "int8"])
+@pytest.mark.parametrize("read", ["attend_rows", "attend_rows_prefix",
+                                  "attend_chunk_prefix"])
+def test_staged_reads_see_token_rows_through_the_logical_view(read, store):
+    """The readers that stage (``attend_rows``, ``attend_chunk``: prefix
+    rows, a quantized store and its scales, a chunk of queries) give, on
+    token rows, bit for bit what they give on the same pages kept by
+    head: they meet them through one logical view."""
+    import jax.numpy as jnp
+    from bluefog_tpu.serve.kv_cache import attend_chunk
+    rng = np.random.default_rng(5)
+    cc, rows, by_head = _token_row_caches(store, rng)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    slots = jnp.array([3, 5, 0, 5, 1], jnp.int32)
+    lens = jnp.array([12, 0, 9, 2, 7], jnp.int32)
+    live = np.asarray(slots) < cc.trash_slot
+    pre = dict(prefix_slots=jnp.array([4, 5, 4, 5, 5], jnp.int32),
+               prefix_lens=jnp.array([4, 0, 8, 0, 0], jnp.int32)) \
+        if read.endswith("prefix") else {}
+    S, Hkv, Dh = slots.shape[0], cc.kv_heads, cc.head_dim
+    if read.startswith("attend_rows"):
+        q = normal(S, 2 * Hkv, Dh)
+        new = token_pages(normal(S, Hkv, Dh), normal(S, Hkv, Dh), store,
+                          rows["k"].dtype)
+        run = lambda c: attend_rows(
+            q, c["k"], c["v"], slots, lens, k_scale=c.get("k_scale"),
+            v_scale=c.get("v_scale"), layer=1, new=new, **pre)
+    else:
+        q = normal(S, 4, 2 * Hkv, Dh)
+        run = lambda c: attend_chunk(q, c, slots, lens, layer=2, **pre)
+    np.testing.assert_array_equal(np.asarray(run(rows))[live],
+                                  np.asarray(run(by_head))[live])
+
+
 def test_attend_layer_stages_a_bucket_under_a_third_of_the_rows():
     """Two lanes of seven rows: three passes over theirs cost less than
     one over all, so the lanes' rows are staged (``attend_rows``), as

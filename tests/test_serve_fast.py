@@ -799,7 +799,8 @@ def _results_of_shape(hlo_lines, dims):
 
 
 _DECODE_CHECKS = ["no_write_in_the_loop", "one_write_per_lane_after_it",
-                  "aliased_whole", "temporaries", "no_half_tensor"]
+                  "aliased_whole", "temporaries", "no_half_tensor",
+                  "a_window_is_a_token_row"]
 
 
 @pytest.mark.parametrize("store,check", [
@@ -817,14 +818,22 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     (every lane's update window laid out at once, each padded from one
     position to the 128 of a tile, was 280 MB), nothing materialises half
     a K tensor.  The raw store's attention reads the pages where they
-    lie: no instruction, inside a fusion or out, makes the lanes' staged
-    rows ``[32, 16, 1024, 64]``, none outside a fusion a layer's pages
-    ``[33, 16, 1024, 64]``, and the loop's body hands the stacked tensor
+    lie, token rows ``[max_len, kv_heads * head_dim]`` = ``[1024, 1024]``
+    (``kv_cache.page_order``): no instruction, inside a fusion or out,
+    makes the lanes' staged rows ``[32, 1024, 1024]`` or the logical view
+    of them ``[32, 16, 1024, 64]``, none outside a fusion a layer's pages
+    ``[33, 1024, 1024]``, and the loop's body hands the stacked tensor
     itself to the two fusions that attend over it (the scores over K,
-    the weighted sum over V: one pass each).  The quantized store still stages: each tensor's rows
-    (32 lanes x 2 MB, built by one row read per lane) have their turn in
-    the chip's on-chip memory (``S(1)`` in the layout; V's are read once
-    K's are dead), not a round trip through HBM per layer."""
+    the weighted sum over V: one pass each).  Each of the 64 windows
+    written is a token's row of every layer, ``[24, 1, 1, 1024]`` with
+    the 1,024 minor as in the cache: 8 tiles a layer, where the same
+    token in a cache with the positions minor touched 64, the property
+    the write's cost rests on.  The quantized store still stages: each
+    tensor's rows (32 lanes x 1 MB, built by one row read per lane) have
+    their turn in the chip's on-chip memory (``S(1)`` in the layout; V's
+    are read once K's are dead), not a round trip through HBM per
+    layer."""
+    import re
     from bluefog_tpu.utils.hlo_bytes import loop_writes, materialized
     txt, mem, cc = v5e_decode(store)
     lanes = 32
@@ -834,7 +843,8 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     for elements in (pages,) + ((scales,) if cc.quantized else ()):
         i, o = loop_writes(txt, elements)
         inside, outside = inside + i, outside + o
-    row = (cc.kv_heads, cc.max_len, cc.head_dim)
+    row = cc.shapes()["k"][2:]                  # a row as it is stored
+    assert cc.page_order == "token_rows" and row == (1024, 1024)
     if check == "no_write_in_the_loop":
         assert inside == 0
     elif check == "one_write_per_lane_after_it":
@@ -847,8 +857,27 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     elif check == "no_half_tensor":
         k_bytes = pages * jnp.dtype(store_dtype(store, cc.dtype)).itemsize
         assert materialized(txt, k_bytes // 2) == []
+    elif check == "a_window_is_a_token_row":
+        # the update operand of every write of a whole K or V tensor
+        # (under whatever view: the last of each carries a leading 1)
+        defs = {ln.split(" = ")[0].replace("ROOT", "").strip():
+                ln.split(" = ", 1)[1] for ln in txt.splitlines()
+                if " = " in ln}
+        whole = re.compile(r"\w+\[(?:1,)?%d,%d,1024,1024\]\S* "
+                           r"dynamic-update-slice\([^,]+, ([^,]+),"
+                           % (cc.layers, cc.rows))
+        windows = [defs[m.group(1)] for m in map(whole.match, defs.values())
+                   if m]
+        assert len(windows) == 2 * lanes
+        for w in windows:           # bf16[24,1,1,1024]{3,2,1,0:T(...)...}
+            dims, minor = re.match(r"\w+\[([\d,]+)\]\{(\d)", w).groups()
+            dims = dims.split(",")
+            assert dims[-4:] == [str(cc.layers), "1", "1", "1024"] and \
+                int(minor) == len(dims) - 1, w[:80]
     elif check == "no_rows_staged":
-        assert _results_of_shape(txt.splitlines(), (lanes,) + row) == []
+        logical = (cc.kv_heads, cc.max_len, cc.head_dim)
+        for staged in ((lanes,) + row, (lanes,) + logical):
+            assert _results_of_shape(txt.splitlines(), staged) == []
         assert materialized(txt, 1, (cc.rows,) + row) == []
         assert materialized(txt, 1, (cc.layers, cc.rows) + row) == []
         body = _loop_body(txt)
@@ -858,7 +887,11 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
             f"{name}," in ln or f"{name})" in ln for name in stacked)]
         assert len(stacked) == 2 and len(readers) == 2, (stacked, readers)
     else:
-        rows = _results_of_shape(_loop_body(txt), (lanes,) + row)
+        # the lanes' rows as they are staged: token rows, their lanes
+        # split into heads on the way to the logical view
+        rows = _results_of_shape(
+            _loop_body(txt),
+            (lanes, cc.max_len, cc.kv_heads, cc.head_dim))
         assert len(rows) >= 2 * lanes, len(rows)
         off_chip = [ln.split(" = ")[0].strip() for ln in rows
                     if "S(1)" not in ln.split(" = ")[1].split(" ")[0]]
@@ -1138,6 +1171,81 @@ def test_in_place_read_engine_serves_the_staged_engines_tokens(cpu_devices,
     assert per_call == {
         "in_place": dp * cfg.layers * steps * cc.rows * cc.max_len,
         "staged": dp * cfg.layers * steps * 2 * cc.max_len}
+
+
+_ROW_ENGINES = {
+    # name: ((dp, pp, tp), heads of 64, ServeConfig overrides, how the
+    # decode program reads the cache).  In place the sums run in another
+    # order, and a staged read's logical view is a turned operand of its
+    # matmuls under jit: floats to a tolerance, tokens to the token
+    "one_chip": ((1, 1, 1), 2, {}, "in_place"),
+    "gossip2_tp2": ((2, 1, 2), 4, {}, "in_place"),
+    "two_tokens_a_call": ((1, 1, 1), 2, dict(decode_steps_per_call=2),
+                          "in_place"),
+    "int8": ((1, 1, 1), 2, dict(kv_dtype="int8"), "staged"),
+    "spec_prefix_pp2": ((1, 2, 1), 2, dict(
+        spec_decode=2, spec_stages=1, prefix_pages=2,
+        prefix_page_tokens=4), "staged"),
+    "flash_kernel": ((1, 1, 1), 2, dict(decode_kernel="pallas",
+                                        decode_block_k=8), "staged"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_ROW_ENGINES))
+def test_token_row_engine_serves_the_by_head_engines_tokens(
+        cpu_devices, monkeypatch, name):
+    """Engine against engine, differing only in the order the dense
+    cache's pages lie in: heads of 64 whose product fills the lanes are
+    kept as token rows (``kv_cache.page_order``), and the same model over
+    a cache held by head (the order forced for the reference) serves the
+    same greedy tokens for every request, through every path that meets
+    the cache: the in-place read, the staged read of a quantized store,
+    prefix pages with copy-on-write suffixes, the draft and verify
+    chunks, the flash-decode kernel's view.  ``program_memory()`` names
+    the order of every tensor, and the two caches hold the same pages
+    under the logical view."""
+    from bluefog_tpu.serve import kv_cache as kv
+    (dp, pp, tp), heads, extra, form = _ROW_ENGINES[name]
+    cfg = compose.LMConfig(vocab=32, d_model=64 * heads, heads=heads,
+                           layers=2, seq_len=32)
+    m = compose.compose_parallelism(dp, pp, tp, 1,
+                                    devices=cpu_devices[:dp * pp * tp])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    rng = np.random.default_rng(23)
+    shared = [3, 1, 4, 1]                        # one page (page_tokens=4)
+    prompts = [rng.integers(0, 32, int(n)).tolist()
+               for n in (3, 7, 5, 8, 4)] + [shared + [5, 9, 2],
+                                            shared + [6, 5, 3, 5]]
+    by_head = lambda kv_heads, head_dim, max_len: "positions_minor"
+    out = {}
+    for order in ("token_rows", "positions_minor"):
+        if order != "token_rows":
+            monkeypatch.setattr(kv, "page_order", by_head)
+        eng = ServeEngine(m, cfg, params, ServeConfig(**{**_SCFG, **extra}))
+        cc = eng.cache_cfg
+        assert cc.page_order == order
+        assert eng.cache["k"].shape[1:] == cc.shapes()["k"] == (
+            (cc.layers, cc.rows, cc.max_len, cc.kv_heads * 64)
+            if order == "token_rows" else
+            (cc.layers, cc.rows, cc.kv_heads, cc.max_len, 64))
+        eng.warmup()
+        mem = eng.program_memory()
+        assert all(row["pages"] == cc.page_orders() for row in mem.values())
+        assert mem["decode S=2"]["pages"]["k"] == order
+        assert mem["decode S=2"]["read"] == form
+        toks = [r.generated for r in _drain(eng, prompts, max_new=7)]
+        # per device: what every reader but the in-place one sees
+        pages = {k: np.stack([np.asarray(
+            (kv.logical_pages(t, 64, True) if k in ("k", "v") else t
+             ).astype(jnp.float32))[:, :cc.trash_slot] for t in v])
+            for k, v in eng.cache.items()}
+        out[order] = (toks, pages)
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+    (toks, pages), (ref_toks, ref_pages) = out.values()
+    assert toks == ref_toks
+    for k in ref_pages:
+        np.testing.assert_allclose(pages[k], ref_pages[k], rtol=1e-3,
+                                   atol=1e-5)
 
 
 @pytest.mark.parametrize("kind,form", [

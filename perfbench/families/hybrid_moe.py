@@ -131,6 +131,14 @@ def layers_of(cfg, kind):
     return sum(k == kind for k, _ in _plan(cfg))
 
 
+held_experts = latent_moe.held_experts
+
+
+def expert_layers(cfg):
+    """Layers with routed experts, of those held here."""
+    return sum(f == "sparse" for _, f in _plan(cfg))
+
+
 def decode_floor_bytes(cfg, calls, experts_hit, positions, positions_window,
                        itemsize=2):
     """The bytes ``calls`` decode calls cannot avoid: every weight byte of
@@ -177,6 +185,27 @@ def prefill_flops(cfg, tokens):
                 + layers_of(cfg, "sliding_attention") * band)
     return (2 * per_token * n + 2 * 2 * H * Dh * keys_met
             + 2 * D * cfg["vocab_size"])
+
+
+def aot_programs(cfg, traffic, devices):
+    """The cell's decode and prefill programs compiled for ``devices``
+    from shapes alone (perfbench/tools/rehearse_aot.py)."""
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.serve import kv_cache as kv
+    lm, scfg = hybrid_config(cfg), serve_config(traffic)
+    shapes = decoder.hybrid_param_shapes(lm)
+    group = lambda leaf, leaves: {name: leaf(name, shape)
+                                  for name, shape in leaves.items()}
+    return latent_moe.share_programs(
+        lm, scfg, devices,
+        lambda leaf: {"layers": tuple(group(leaf, leaves)
+                                      for leaves in shapes["layers"]),
+                      "shared": group(leaf, shapes["shared"])},
+        kv.HybridCacheConfig(
+            full_layers=lm.layers_of("full"),
+            window_layers=lm.layers_of("window"), slots=scfg.slots,
+            max_len=scfg.max_len, window=lm.window, kv_heads=lm.kv_heads,
+            head_dim=lm.head_dim, dtype=scfg.dtype))
 
 
 def _init_params(hcfg, m, seed, dtype, std):

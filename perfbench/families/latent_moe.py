@@ -80,6 +80,17 @@ def latent_config(cfg):
         eps=cfg["rms_norm_eps"])
 
 
+def held_experts(cfg):
+    """Routed experts this chip holds in each expert layer."""
+    lo, hi = cfg["deployment"]["held_experts"]
+    return hi - lo
+
+
+def expert_layers(cfg):
+    """Layers with routed experts: all but the leading dense one."""
+    return cfg["num_hidden_layers"] - cfg["first_k_dense_replace"]
+
+
 def weight_bytes(cfg, itemsize=2):
     """Bytes of the six layers' and the head's weights a decode call reads
     whatever it routes: everything but the embedding table (a call reads
@@ -116,6 +127,67 @@ def decode_floor_bytes(cfg, calls, experts_hit, live_positions, itemsize=2):
     return (calls * weight_bytes(cfg, itemsize)
             + experts_hit * expert_bytes(cfg, itemsize)
             + live_positions * cfg["num_hidden_layers"] * latent)
+
+
+def share_programs(lm, scfg, devices, params_of, cache_cfg):
+    """The programs a held-experts engine compiles for ``lm`` (decode at
+    every batch bucket, prefill at every prompt bucket) for ``devices[0]``,
+    from shapes alone: ``ServeEngine``'s own jitted bodies
+    without an engine (which would place parameters and a cache on real
+    devices; ``devices`` may be a described chip's).  ``params_of(leaf)``
+    builds the family's parameter tree from ``leaf(name, shape)``.
+    Returns ``[(name, compiled, 1)]`` for perfbench/tools/rehearse_aot.py."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.parallel import compose
+    from bluefog_tpu.serve import ServeEngine
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=devices[:1])
+    eng = ServeEngine.__new__(ServeEngine)
+    eng._moe, eng._share = False, True
+    eng._latent = isinstance(lm, decoder.LatentConfig)
+    eng._hybrid = not eng._latent
+    eng.m, eng.cfg, eng.scfg = m, lm, scfg
+    sh = NamedSharding(m.mesh, m.spec)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct((1,) + tuple(shape), dtype, sharding=sh)
+    # the router's weight is float32, every other leaf the served type
+    params = params_of(lambda name, shape: sds(
+        shape, jnp.float32 if name == "wr" else scfg.dtype))
+    state = lambda: ({k: sds(shape, scfg.dtype)
+                      for k, shape in cache_cfg.shapes().items()},
+                     sds((cache_cfg.rows, 2), jnp.uint32))
+    decode, prefill = (
+        (eng._latent_decode_body, eng._latent_prefill_body) if eng._latent
+        else (eng._hybrid_decode_body, eng._hybrid_prefill_body))
+    decode, prefill = eng._build(decode), eng._build(prefill)
+    # the one staged array of a call: a token and 4 integers a lane, or a
+    # prompt's tokens and 4 integers
+    return [(f"decode_S{S}", decode.lower(
+        params, *state(), sds((S, 1 + 4), jnp.int32)).compile(), 1)
+        for S in scfg.batch_buckets] + [(f"prefill_T{T}", prefill.lower(
+            params, *state(), sds((T + 4,), jnp.int32)).compile(), 1)
+        for T in scfg.prefill_buckets]
+
+
+def aot_programs(cfg, traffic, devices):
+    """The cell's decode and prefill programs compiled for ``devices``
+    from shapes alone (perfbench/tools/rehearse_aot.py)."""
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.serve import kv_cache as kv
+    lm, scfg = latent_config(cfg), serve_config(traffic)
+    shapes = decoder.latent_param_shapes(lm)
+    return share_programs(
+        lm, scfg, devices,
+        lambda leaf: {group: {name: leaf(name, shape)
+                              for name, shape in leaves.items()}
+                      for group, leaves in shapes.items()},
+        kv.LatentCacheConfig(layers=lm.layers, slots=scfg.slots,
+                             max_len=scfg.max_len, kv_rank=lm.kv_rank,
+                             rope_dim=lm.rope_dim, dtype=scfg.dtype))
 
 
 def _init_params(lcfg, m, seed, dtype, std):

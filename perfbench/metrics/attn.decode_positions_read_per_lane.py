@@ -22,8 +22,6 @@ def read(run):
             or not hasattr(family, "layers_of"):
         return None
     cfg = run["config"]
-    sparse = sum(f == "sparse" for f in
-                 cfg["mlp_layer_types"][:cfg["num_hidden_layers"]])
-    lanes = rows / (cfg["num_experts"] * sparse)
+    lanes = rows / (family.held_experts(cfg) * family.expert_layers(cfg))
     return (full * family.layers_of(cfg, "full_attention")
             + ring * family.layers_of(cfg, "sliding_attention")) / lanes

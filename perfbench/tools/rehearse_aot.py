@@ -93,14 +93,14 @@ def lm_train(cfg, traffic, devices):
 
 def lm_serve(cfg, traffic, devices):
     from bluefog_tpu.parallel import compose
-    from bluefog_tpu.serve import ServeEngine
+    from bluefog_tpu.serve import KVCacheConfig, ServeEngine
+    from bluefog_tpu.serve import kv_cache as kv
     fam = manifest.load_module("families", "composed_lm")
     scfg = fam.serve_config(traffic)
     dtype = scfg.dtype
     m = compose.compose_parallelism(1, 1, 1, 1, devices=devices[:1])
     lm = fam._lm_config(cfg)
     sh = NamedSharding(m.mesh, m.spec)
-    D, L = cfg["hidden_size"], cfg["num_hidden_layers"]
     params = jax.tree.map(lambda s: sds((1,) + s, dtype, sh),
                           fam.param_shapes(cfg),
                           is_leaf=lambda s: isinstance(s, tuple))
@@ -108,23 +108,27 @@ def lm_serve(cfg, traffic, devices):
     # places parameters and a cache on real devices)
     eng_obj = ServeEngine.__new__(ServeEngine)
     eng_obj._moe, eng_obj.m, eng_obj.cfg, eng_obj.scfg = False, m, lm, scfg
-    eng_obj._moe_chunk_tile = None
-    hd = D // cfg["num_attention_heads"]
-    cache = {k: sds((1, L, scfg.slots + 1 + scfg.prefix_pages,
-                     cfg["num_attention_heads"], scfg.max_len, hd), dtype, sh)
-             for k in ("k", "v")}
+    eng_obj._moe_chunk_tile, eng_obj.draft = None, None
+    cc = KVCacheConfig(
+        layers=lm.layers, slots=scfg.slots, max_len=scfg.max_len,
+        kv_heads=lm.heads, head_dim=lm.d_model // lm.heads, dtype=dtype,
+        store=scfg.kv_dtype, prefix_slots=scfg.prefix_pages)
+    # every program is body(params, cache, keys, staged): the cache in the
+    # order its shapes give (kv_cache.page_order), the sampler keys a row
+    # each, and the one staged array of a call
+    state = lambda: (
+        {k: sds((1,) + v.shape, v.dtype, sh)
+         for k, v in jax.eval_shape(lambda: kv.init_cache(cc)).items()},
+        sds((1, cc.rows, 2), jnp.uint32, sh))
     i32 = lambda *s: sds((1,) + s, jnp.int32, sh)
-    out = []
-    S = scfg.batch_buckets[-1]
     dec = eng_obj._build(eng_obj._decode_body)
-    out.append((f"decode_S{S}", dec.lower(
-        params, cache, i32(S), i32(S), i32(S), None, None,
-        sds((1, S, 2), jnp.uint32, sh)).compile(), 1))
     pre = eng_obj._build(eng_obj._prefill_body)
-    T = scfg.prefill_buckets[-1]
-    out.append((f"prefill_T{T}", pre.lower(
-        params, cache, i32(T), i32(), i32()).compile(), 1))
-    return out
+    return [(f"decode_S{S}",
+             dec.lower(params, *state(), i32(S, 1 + 4)).compile(), 1)
+            for S in scfg.batch_buckets] + [
+        (f"prefill_T{T}",
+         pre.lower(params, *state(), i32(T + 4)).compile(), 1)
+        for T in scfg.prefill_buckets]
 
 
 def resnet_train(cfg, traffic, devices):
@@ -165,6 +169,29 @@ BUILDERS = {("composed_lm", "train"): lm_train,
             ("resnet", "train"): resnet_train}
 
 
+def programs(man, workload, devices, rehearsal=False, overrides=()):
+    """``[(name, compiled, n devices)]`` of the cell's programs compiled
+    for ``devices`` (a described chip's, or CPU devices with the files'
+    ``tiny`` sizes: ``rehearsal``), by this file's builder of the cell's
+    (family, kind) or, for a family this file does not know, by the
+    family adapter's own ``aot_programs(cfg, traffic, devices)``."""
+    cell = manifest.resolve_cell(man, workload)
+    cfg = manifest.sized(cell["config"], rehearsal)
+    traffic = manifest.sized(cell["traffic"], rehearsal)
+    for kv in overrides:
+        k, v = kv.split("=", 1)
+        traffic[k] = json.loads(v)
+    build = BUILDERS.get((cfg["family"], traffic["kind"]))
+    if build is None:
+        family = manifest.load_module("families", cfg["family"])
+        if not hasattr(family, "aot_programs"):
+            raise manifest.ManifestError(
+                f"perfbench/families/{cfg['family']}.py has no "
+                "aot_programs(cfg, traffic, devices)")
+        build = family.aot_programs
+    return build(cfg, traffic, list(devices)[:cell["cell"]["chips"]])
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -172,16 +199,10 @@ def main():
     args = ap.parse_args()
     from jax.experimental import topologies
     topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    cell = manifest.resolve_cell(manifest.load(), args.workload)
-    cfg = manifest.sized(cell["config"], False)
-    traffic = manifest.sized(cell["traffic"], False)
-    for kv in args.set:
-        k, v = kv.split("=", 1)
-        traffic[k] = json.loads(v)
-    build = BUILDERS[(cfg["family"], traffic["kind"])]
-    chips = cell["cell"]["chips"]
-    rows = [report(f"{args.workload}:{name}", compiled, n) for name, compiled,
-            n in build(cfg, traffic, list(topo.devices)[:chips])]
+    rows = [report(f"{args.workload}:{name}", compiled, n)
+            for name, compiled, n in programs(
+                manifest.load(), args.workload, topo.devices,
+                overrides=args.set)]
     held = max(r["would_hold_gb"] for r in rows) * 1e9
     print(json.dumps({
         "cell": args.workload, "would_hold_gb": held / 1e9,

@@ -9,11 +9,13 @@ import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+for _p in (ROOT, HERE):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
+import _shape  # noqa: E402
 from perfbench.harness import manifest  # noqa: E402
 
 MAN = manifest.load()
@@ -40,8 +42,20 @@ def test_cell_refuses_to_measure_without_a_tpu(cell):
     assert not any(l.startswith("{") for l in p.stdout.splitlines())
 
 
+def test_a_traced_rehearsal_is_made_for_every_family_and_kind():
+    traced = list(_shape.first_cells(MAN).values())
+    assert {"resnet50.train-b256", "pythia-410m.train-seq2048",
+            "pythia-410m.serve-closed32", "a.x-k1.serve-closed128-p2048",
+            "k-exaone.serve-closed48-p8192"} <= set(traced)
+    # a cell of a family and kind that has one already adds none
+    assert "pythia-410m.gossip4-seq2048" not in traced
+    more = {**MAN, "workloads": MAN["workloads"] + [dict(
+        MAN["workloads"][-1], name="k-exaone.another")]}
+    assert list(_shape.first_cells(more).values()) == traced
+
+
 @pytest.mark.parametrize("cell,trace", [(c, 0) for c in CELLS] + [
-    ("pythia-410m.serve-closed32", 1), ("pythia-410m.train-seq2048", 1)])
+    (c, 1) for c in _shape.first_cells(MAN).values()])
 def test_cell_rehearses_end_to_end_on_cpu(cell, trace, tmp_path):
     p = run_cell(cell, "--rehearse", "--out-dir", str(tmp_path), trace=trace)
     assert p.returncode == 0, p.stderr[-3000:]
@@ -62,7 +76,7 @@ def test_cell_rehearses_end_to_end_on_cpu(cell, trace, tmp_path):
     assert all(l.startswith("perfbench: check ") for l in said)
     group = "per_layer" if trace else "end_to_end"
     names = {m["name"] for m in manifest.metrics_for(MAN, cell, group)}
-    assert set(line["would_report"]) <= names
+    assert line["would_report"] and set(line["would_report"]) <= names
     if not trace:
         assert set(line["would_report"]) == names
     # the distribution of the readings is printed before the last line,

@@ -9,11 +9,13 @@ import types
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+for _p in (ROOT, HERE):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
+import _shape  # noqa: E402
 from perfbench.harness import device, manifest  # noqa: E402
 
 GIB = 1 << 30
@@ -140,6 +142,67 @@ def test_peak_hbm_reserved_reads_the_reserved_field(cell, gb):
                  "device": device.device_info([Dev(None)])}) is None
 
 
+def the_tool():
+    sys.modules.pop("rehearse_aot", None)
+    tools = os.path.join(ROOT, "perfbench", "tools")
+    sys.path.insert(0, tools)
+    try:
+        import rehearse_aot
+    finally:
+        sys.path.remove(tools)
+    return rehearse_aot
+
+
+@pytest.mark.parametrize("family,kind", list(_shape.first_cells(
+    manifest.load())))
+def test_the_rehearsal_tool_builds_every_family_and_kind(family, kind):
+    """perfbench/tools/rehearse_aot.py on CPU devices at the files' tiny
+    sizes: every (family, kind) the manifest has lowers and compiles, by
+    the tool's own builder or by the family adapter's ``aot_programs``,
+    and each program's row says what its device would hold.  (At the real
+    sizes for a described v5e the same call is the tool's ``main``.)"""
+    import jax
+    tool, man = the_tool(), manifest.load()
+    cell = _shape.first_cells(man)[(family, kind)]
+    chips = manifest.resolve_cell(man, cell)["cell"]["chips"]
+    built = tool.programs(man, cell, jax.devices("cpu"), rehearsal=True)
+    assert built and all(n == chips for _, _, n in built)
+    if kind == "serve":     # a program a bucket, as the engine warms up
+        eng = manifest.sized(manifest.resolve_cell(man, cell)["traffic"],
+                             True)["engine"]
+        assert [name for name, _, _ in built] == [
+            f"decode_S{S}" for S in eng["batch_buckets"]] + [
+            f"prefill_T{T}" for T in eng["prefill_buckets"]]
+    for name, compiled, n in built:
+        ma = compiled.memory_analysis()
+        assert tool.would_hold(ma) >= ma.argument_size_in_bytes > 0
+        if kind == "serve":      # the donated cache comes back in place
+            assert ma.alias_size_in_bytes > 0
+
+
+def test_a_family_the_tool_does_not_know_brings_its_own_builder(monkeypatch):
+    """No builder of the tool's and no ``aot_programs`` of the family's:
+    refused by name; with the hook, the family's word is taken as it is."""
+    import jax
+    tool, man = the_tool(), manifest.load()
+    cell = "a.x-k1.serve-closed128-p2048"
+    assert ("latent_moe", "serve") not in tool.BUILDERS
+    real = manifest.load_module
+
+    def a_family_with(**hooks):
+        return lambda kind_dir, name, *a: types.SimpleNamespace(**hooks) \
+            if kind_dir == "families" else real(kind_dir, name, *a)
+    monkeypatch.setattr(manifest, "load_module", a_family_with())
+    with pytest.raises(manifest.ManifestError, match="aot_programs"):
+        tool.programs(man, cell, jax.devices("cpu"), rehearsal=True)
+    seen = []
+    monkeypatch.setattr(manifest, "load_module", a_family_with(
+        aot_programs=lambda cfg, traffic, devices: seen.append(
+            (cfg["family"], traffic["kind"], len(devices))) or []))
+    assert tool.programs(man, cell, jax.devices("cpu"), rehearsal=True) == []
+    assert seen == [("latent_moe", "serve", 1)]
+
+
 @pytest.mark.parametrize("ma,want", [
     # the decode program: the donated cache comes back in place
     (dict(argument=4131916288, output=3321890816, alias=3321888768,
@@ -149,13 +212,7 @@ def test_peak_hbm_reserved_reads_the_reserved_field(cell, gb):
     (dict(argument=100, output=40, alias=64, temp=7), 107),
 ])
 def test_would_hold_counts_arguments_temporaries_and_fresh_outputs(ma, want):
-    sys.modules.pop("rehearse_aot", None)
-    tools = os.path.join(ROOT, "perfbench", "tools")
-    sys.path.insert(0, tools)
-    try:
-        import rehearse_aot
-    finally:
-        sys.path.remove(tools)
+    rehearse_aot = the_tool()
     stats = types.SimpleNamespace(**{k + "_size_in_bytes": v
                                      for k, v in ma.items()})
     assert rehearse_aot.would_hold(stats) == want

@@ -9,14 +9,16 @@ import sys
 import numpy as np
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+for _p in (ROOT, HERE):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+import _shape  # noqa: E402
 from perfbench.harness import manifest, program_spans  # noqa: E402
 from perfbench.reference import latent_moe as reference  # noqa: E402
 
@@ -281,6 +283,35 @@ def test_the_expert_layer_metrics_read_the_held_work_marks():
         assert read(name) is None
 
 
+@pytest.mark.parametrize("cell, pairs, lanes, on_the_chip", [
+    (CELL, 316, 128, 5.26), ("k-exaone.serve-closed48-p8192", 168, 48, 3.0)])
+def test_tokens_per_held_expert_takes_the_familys_word(cell, pairs, lanes,
+                                                       on_the_chip):
+    """Held experts and expert layers come from the family adapter
+    (``held_experts``, ``expert_layers``), not from one family's key names:
+    both held-experts cells read what they read when the reader divided
+    by ``n_routed_experts * (num_hidden_layers - 1)`` (12 x 5 and 8 x 7;
+    ledger, PR 39: 5.26 and 3.00), and a family without the hook reads
+    nothing."""
+    resolved = manifest.resolve_cell(manifest.load(), cell)
+    cfg = manifest.sized(resolved["config"], False)
+    assert "n_routed_experts" not in cfg or cfg["family"] == "latent_moe"
+    family = manifest.load_module("families", cfg["family"])
+    held, layers = family.held_experts(cfg), family.expert_layers(cfg)
+    assert (held, layers) == {CELL: (12, 5)}.get(cell, (8, 7))
+    mark = {"pairs": pairs, "rows": lanes * held * layers, "experts_hit": 1,
+            "positions": 1}
+    run = {"config": cfg, "workload": cell,
+           "device": {"platform": "cpu", "kind": "cpu"},
+           "program_spans": marks([mark, mark, mark])}
+    read = manifest.load_module("metrics", "moe.tokens_per_held_expert").read
+    assert read(run) == pytest.approx(pairs / (held * layers))
+    # the pairs chosen are what the chip's traced tails read per call
+    assert read(run) == pytest.approx(on_the_chip, rel=0.01)
+    run["config"] = dict(cfg, family="composed_lm")
+    assert read(run) is None
+
+
 @pytest.mark.parametrize("name, want", [
     ("engine.decode_call_s_p50.tok_per_s", 0.035),
     ("token_gap_p80_s", 0.136)])
@@ -301,45 +332,49 @@ def test_the_decode_call_and_the_gap_inside_a_cluster_have_readers(name, want):
     assert read({"facts": {}, "spans": rec, "readings": {}}) is None
 
 
-def test_the_manifest_is_pr_32s_with_one_cell_appended():
-    """What test_perfbench_manifest.py's pin guarded, for the list as it
-    stands: the accepted entries first and in their order, the new ones
-    behind them."""
-    man = manifest.load()
-    assert [w["name"] for w in man["workloads"]] == [
-        "resnet50.train-b256", "pythia-410m.train-seq2048",
-        "pythia-410m.gossip4-seq2048", "pythia-410m.serve-closed32", CELL]
-    assert [c["name"] for c in man["configs"]] == [
-        "resnet50", "pythia-410m", "a.x-k1"]
-    assert [m["name"] for m in man["end_to_end"]] == [
-        "train_items_per_s_per_chip", "serve_tok_per_s", "ttft_p50_s",
-        "token_gap_p90_s", "setup_s"]
-    assert [m["name"] for m in man["per_layer"]][-5:] == list(NEW_METRICS)
-    assert sum(w["chips"] == 4 for w in man["workloads"]) == 1
-    assert man["paths"] == ["perfbench", "tests/perfbench"]
-    assert man["run_seconds"] == 30
-    assert os.path.getsize(manifest.MANIFEST) < 64 * 1024
-    a = [c for c in man["configs"] if c["name"] == "a.x-k1"][0]
-    assert a["reduced"] == ["num_hidden_layers", "n_routed_experts",
-                            "vocab_size"]
-    assert all(len(w["why"]) <= 200 for w in man["workloads"])
+# what the cell reports besides the metrics this file's PR wrote for it
+REPORTS = ("serve_tok_per_s", "ttft_p50_s", "setup_s")
 
 
-def test_the_new_metrics_list_the_new_cell_alone():
+def manifest_rule(man, root=ROOT):
+    """The cell and the five metrics PR 33 wrote, however much has been
+    appended since: the cell IN their lists, each moving the rate (the
+    cell reports no gap percentile end to end, so ``_shape.moves_inside``
+    keeps it out of every list that moves one), and the configuration
+    with the three keys it cut."""
+    bad = _shape.written_for(man, CELL, config="a.x-k1", chips=1,
+                             traffic="serve-closed128-p2048",
+                             metrics=REPORTS + NEW_METRICS)
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    bad += [f"{n} moves {by_name[n]['moves']}" for n in NEW_METRICS
+            if n in by_name and by_name[n]["moves"] != "serve_tok_per_s"]
+    entry = [c for c in man["configs"] if c["name"] == "a.x-k1"]
+    if [c["reduced"] for c in entry] != [["num_hidden_layers",
+                                          "n_routed_experts", "vocab_size"]]:
+        bad.append(f"a.x-k1's entry is {entry}")
+    return bad
+
+
+def test_the_cell_and_its_metrics_stand_as_their_pr_wrote_them():
     man = manifest.load()
-    assert manifest.check(man) == []
-    for name in NEW_METRICS:
-        entry = [m for m in man["per_layer"] if m["name"] == name]
-        assert len(entry) == 1 and entry[0]["workloads"] == [CELL]
-        assert entry[0]["moves"] == "serve_tok_per_s"
-    # every serving metric the benchmark had lists the new cell too, but
-    # the gap's 90th percentile and what moves it: on this traffic it sits
-    # on the edge of the one-large-prefill cluster in every order (PERF.md)
-    serving = [m for m in man["per_layer"] + man["end_to_end"]
-               if "pythia-410m.serve-closed32" in m.get("workloads", [])]
-    without = {m["name"] for m in serving if CELL not in m["workloads"]}
-    assert without == {"token_gap_p90_s", "engine.decode_call_s_p50",
-                       "engine.decode_collect_s_p50", "token_gap_p99_s"}
-    assert all(m["name"] == "token_gap_p90_s"
-               or m["moves"] == "token_gap_p90_s"
-               for m in serving if m["name"] in without)
+    assert manifest_rule(man) == []
+    # the rule sees a cell taken out of a list, and not a cell put in
+    by_name = {m["name"]: m for m in man["per_layer"]}
+    by_name["moe.pad_share"]["workloads"].append("a.further-cell")
+    assert manifest_rule(man) == []
+    by_name["moe.pad_share"]["workloads"].remove(CELL)
+    assert manifest_rule(man) == [f"moe.pad_share does not list {CELL}"]
+
+
+def test_the_cell_reports_no_gap_percentile_and_nothing_that_moves_one():
+    """On this traffic the gap's 90th percentile sits on the edge of the
+    one-large-prefill cluster in every order (PERF.md): the cell is in no
+    list of ``token_gap_p90_s`` or of a metric that moves it, which is
+    ``_shape.moves_inside`` once the first holds."""
+    man = manifest.load()
+    assert CELL not in _shape.cells_of(man, "token_gap_p90_s")
+    assert _shape.moves_inside(man) == []
+    moving = [m["name"] for m in man["per_layer"]
+              if m["moves"] == "token_gap_p90_s"]
+    assert len(moving) >= 3
+    assert not any(CELL in _shape.cells_of(man, n) for n in moving)

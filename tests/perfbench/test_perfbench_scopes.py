@@ -11,19 +11,20 @@ import sys
 
 import pytest
 
-ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
-if ROOT not in sys.path:
-    sys.path.insert(0, ROOT)
+HERE = os.path.dirname(os.path.abspath(__file__))
+ROOT = os.path.dirname(os.path.dirname(HERE))
+for _p in (ROOT, HERE):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
 
+import _shape  # noqa: E402
 from perfbench.harness import manifest, program_spans, scopes  # noqa: E402
 
 FIXTURE = os.path.join(ROOT, "perfbench", "fixtures", "scopes_small.json.gz")
 MAN = manifest.load()
-TRAIN = ["resnet50.train-b256", "pythia-410m.train-seq2048",
-         "pythia-410m.gossip4-seq2048"]
-SERVE = ["pythia-410m.serve-closed32", "a.x-k1.serve-closed128-p2048",
-         "k-exaone.serve-closed48-p8192"]
+# which cells list each metric, by what a cell IS and not by its name:
+# those that train, those that serve, those that serve held experts
+TRAIN, SERVE, EXPERTS = "train", "serve", "experts"
 NEW = {
     "train_step.forward_device_s_per_step": TRAIN,
     "train_step.backward_device_s_per_step": TRAIN,
@@ -36,9 +37,41 @@ NEW = {
     "engine.decode_readout_device_s_per_call": SERVE,
     "engine.prefill_attend_device_s_per_ktok": SERVE,
     "engine.prefill_ffn_device_s_per_ktok": SERVE,
-    "moe.prefill_experts_device_s_per_ktok": SERVE[1:],
+    "moe.prefill_experts_device_s_per_ktok": EXPERTS,
     "device.serve_scoped_share": SERVE,
 }
+
+
+def cells_that(man, what, root=ROOT):
+    """The cells of ``man`` that train (they report
+    ``train_items_per_s_per_chip``), that serve (``serve_tok_per_s``), or
+    that serve a family which holds experts (its adapter says how many:
+    ``held_experts``)."""
+    if what == TRAIN:
+        return _shape.cells_of(man, "train_items_per_s_per_chip")
+    serve = _shape.cells_of(man, "serve_tok_per_s")
+    if what == SERVE:
+        return serve
+    return [c for c in _shape.holding(man, "held_experts", root)
+            if c in serve]
+
+
+def listing(man, name, root=ROOT):
+    """Complaints about which cells list the scope metric ``name``: those
+    its kind names, all of them and no other."""
+    want = cells_that(man, NEW[name], root)
+    got = _shape.cells_of(man, name)
+    return [] if got == want else [f"{name} lists {got}, not {want}"]
+
+
+def manifest_rule(man, root=ROOT):
+    """PR 38's thirteen metrics: each listed for the cells of its kind,
+    and in the manifest in the order their PR gave them."""
+    bad = [c for name in NEW for c in listing(man, name, root)]
+    names = [m["name"] for m in man["per_layer"]]
+    if [n for n in names if n in NEW] != list(NEW):
+        bad.append("the scope metrics stand in another order")
+    return bad
 
 TABLES = {
     "decode S=4": {"module": "jit__decode_body", "ops": {
@@ -200,13 +233,11 @@ def test_new_metrics_are_listed_for_their_cells_and_read_none_off_the_tpu(
         name):
     entry, = [m for m in MAN["per_layer"] if m["name"] == name]
     assert entry["source"] == "device_trace" and "bound" not in entry
-    for w in MAN["workloads"]:
-        listed = name in {m["name"] for m in manifest.metrics_for(
-            MAN, w["name"], "per_layer")}
-        assert listed == (w["name"] in NEW[name])
+    assert listing(MAN, name) == [] and cells_that(MAN, NEW[name])
     read = manifest.load_module("metrics", name).read
     run = {"device": {"platform": "cpu"}, "facts": {"traced_steps": 18},
-           "workload": NEW[name][0], "out_dir": "/nonexistent"}
+           "workload": cells_that(MAN, NEW[name])[0],
+           "out_dir": "/nonexistent"}
     assert read(run) is None
     # on the chip, with a program that has no registry or a trace that met
     # no table: nothing, and no error
@@ -215,8 +246,15 @@ def test_new_metrics_are_listed_for_their_cells_and_read_none_off_the_tpu(
     assert read(run) is None
 
 
-def test_new_metrics_come_last_in_the_manifests_order():
-    assert [m["name"] for m in MAN["per_layer"]][-len(NEW):] == list(NEW)
+def test_new_metrics_keep_their_order_in_the_manifest():
+    """In the order their PR gave them, wherever a later PR's metrics
+    stand; and the rule sees two of them changing places."""
+    assert manifest_rule(MAN) == []
+    per_layer = list(MAN["per_layer"])
+    a, b = (i for i, m in enumerate(per_layer) if m["name"] in list(NEW)[:2])
+    per_layer[a], per_layer[b] = per_layer[b], per_layer[a]
+    assert manifest_rule({**MAN, "per_layer": per_layer}) == [
+        "the scope metrics stand in another order"]
 
 
 def test_a_traced_cpu_rehearsal_reports_none_of_them(tmp_path):

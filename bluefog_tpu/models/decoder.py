@@ -149,12 +149,19 @@ def block_param_count(cfg: Any, leaves: Optional[Sequence[str]] = None) -> int:
 
 @dataclasses.dataclass(frozen=True)
 class LatentConfig:
-    """Sizes of a latent-attention decoder with a leading dense layer and
-    expert layers behind it, as ONE chip of an expert-parallel deployment
-    holds it: the router keeps all ``num_experts`` outputs, this chip
-    computes the experts ``held_start .. held_start + held_experts - 1``
-    and the shared expert, and what the absent experts would add is left
-    out (:func:`bluefog_tpu.moe.layers.held_expert_ffn`)."""
+    """Sizes of a latent-attention decoder with ``dense_layers`` leading
+    dense layers and expert layers behind them, as ONE chip of an
+    expert-parallel deployment holds it: the router keeps all
+    ``num_experts`` outputs, this chip computes the experts ``held_start
+    .. held_start + held_experts - 1`` and the shared expert, and what the
+    absent experts would add is left out
+    (:func:`bluefog_tpu.moe.layers.held_expert_ffn`).  ``route_bias``: the
+    router selects by ``score + e_bias`` and weighs by the raw scores.
+    ``streams`` (1: the plain residual): the residual is that many
+    streams, which every sublayer reads through a learned combination and
+    writes back under a Sinkhorn-normalised remix (:func:`hc_coefficients`,
+    ``sinkhorn_iters`` rounds on ``exp`` of logits clipped to
+    ``+-res_clamp``, ``hc_eps`` in every normalisation)."""
     vocab: int
     d_model: int
     heads: int
@@ -180,10 +187,16 @@ class LatentConfig:
     rope_beta_slow: float = 1.0
     rope_mscale_all_dim: float = 0.0
     eps: float = 1e-6
+    dense_layers: int = 1           # leading layers with one gated FFN
+    route_bias: bool = False        # select by score + e_bias
+    streams: int = 1                # residual streams; 1: the plain one
+    sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    res_clamp: float = 30.0
 
     @property
     def expert_layers(self) -> int:
-        return self.layers - 1
+        return self.layers - self.dense_layers
 
     @property
     def latent_dim(self) -> int:
@@ -197,9 +210,17 @@ class LatentConfig:
                      "n_group", "topk_group"):
             if getattr(self, name) < 1:
                 raise ValueError(f"LatentConfig.{name} must be >= 1")
-        if self.layers < 2:
-            raise ValueError("LatentConfig.layers counts the leading dense "
-                             "layer and at least one expert layer")
+        if not 1 <= self.dense_layers < self.layers:
+            raise ValueError(
+                f"latent_dense_layers: {self.dense_layers} leading dense "
+                f"layers of {self.layers}: layers counts at least one dense "
+                "layer and at least one expert layer behind them")
+        if self.streams < 1 or self.sinkhorn_iters < 1 \
+                or self.hc_eps <= 0 or self.res_clamp <= 0:
+            raise ValueError(
+                f"latent_streams: {self.streams} streams remixed in "
+                f"{self.sinkhorn_iters} Sinkhorn rounds (eps {self.hc_eps}, "
+                f"clamp {self.res_clamp}): every one must be positive")
         if self.rope_dim % 2:
             raise ValueError("rope_dim must be even")
         if self.num_experts % self.n_group or \
@@ -361,6 +382,75 @@ def mla_unabsorb_out(cfg: LatentConfig, lp: Dict[str, jax.Array],
     return o.reshape(o.shape[:-2] + (-1,))
 
 
+def hc_coefficients(cfg: LatentConfig, phi: jax.Array, alpha: jax.Array,
+                    bias: jax.Array, xs: jax.Array):
+    """What one sublayer's maps make of the residual streams ``xs`` ``[n,
+    ..., D]``: ``(pre [n, ...], post [n, ...], res [n, n, ...])``, float32
+    with the tokens minor.  With ``c = (vec(xs) / rms(vec(xs))) phi`` per
+    token (``phi`` ``[n, D, n * n + 2 n]``, the norm over all ``n * D``
+    values with no learned scale, the product at full matmul precision):
+    ``pre = sigmoid(alpha[0] c[:n] + b[:n])`` weighs the streams into the
+    sublayer's input, ``post = 2 sigmoid(alpha[1] c[n:2n] + b[n:2n])``
+    spreads its output over them, and ``res``, the remix of the streams
+    themselves, is ``exp(clip(alpha[2] c[2n:] + b[2n:], +-res_clamp))``
+    as an ``n x n`` matrix (row-major) after ``sinkhorn_iters`` rounds of
+    column-then-row normalisation with ``hc_eps`` in each denominator:
+    doubly stochastic up to the last round's columns."""
+    with jax.named_scope("hc.coef"):
+        n = xs.shape[0]
+        xf = xs.astype(jnp.float32)
+        scale = lax.rsqrt(jnp.mean(xf * xf, axis=(0, -1)) + cfg.eps)
+        c = jnp.einsum("n...d,ndc->c...", xf, phi.astype(jnp.float32),
+                       precision=lax.Precision.HIGHEST) * scale
+        alpha = alpha.astype(jnp.float32)
+        b = bias.astype(jnp.float32).reshape((-1,) + (1,) * scale.ndim)
+        pre = jax.nn.sigmoid(alpha[0] * c[:n] + b[:n])
+        post = 2.0 * jax.nn.sigmoid(alpha[1] * c[n:2 * n] + b[n:2 * n])
+        res = jnp.exp(jnp.clip(alpha[2] * c[2 * n:] + b[2 * n:],
+                               -cfg.res_clamp, cfg.res_clamp))
+        res = res.reshape((n, n) + scale.shape)
+        for _ in range(cfg.sinkhorn_iters):
+            res = res / (jnp.sum(res, 0, keepdims=True) + cfg.hc_eps)
+            res = res / (jnp.sum(res, 1, keepdims=True) + cfg.hc_eps)
+        return pre, post, res
+
+
+def hc_mix(w: jax.Array, xs: jax.Array, post: Optional[jax.Array] = None,
+           y: Optional[jax.Array] = None) -> jax.Array:
+    """``out_k = sum_j w[k, j] xs_j (+ post[k] y)`` over the streams ``xs``
+    ``[n, ..., D]`` with per-token weights ``w`` ``[m, n, ...]``: a
+    sublayer's input (``w = pre[None]``) and the streams it leaves (``w =
+    res`` with its output ``y`` spread by ``post``).  Sums in float32, the
+    result in the streams' dtype, stream axis major.  The result is made
+    here and nowhere else (an optimization barrier): left to the compiler,
+    a sublayer's input is recomputed from all ``n`` streams inside every
+    matmul that reads it."""
+    with jax.named_scope("hc.mix"):
+        out = jnp.sum(w[..., None] * xs.astype(jnp.float32)[None], axis=1)
+        if y is not None:
+            out = out + post[..., None] * y.astype(jnp.float32)[None]
+        return lax.optimization_barrier(out.astype(xs.dtype))
+
+
+def hc_fan_out(cfg: LatentConfig, x: jax.Array) -> jax.Array:
+    """The residual a streamed model's layers carry, from the embedded
+    tokens ``x`` ``[..., D]``: every stream a copy, ``[n, ..., D]`` (``x``
+    itself where the config has one stream)."""
+    if cfg.streams == 1:
+        return x
+    with jax.named_scope("hc.mix"):
+        return jnp.broadcast_to(x[None], (cfg.streams,) + x.shape)
+
+
+def hc_collapse(cfg: LatentConfig, xs: jax.Array) -> jax.Array:
+    """What the read-out sees of :func:`hc_fan_out`'s residual: the sum of
+    the streams."""
+    if cfg.streams == 1:
+        return xs
+    with jax.named_scope("hc.mix"):
+        return jnp.sum(xs, axis=0, dtype=jnp.float32).astype(xs.dtype)
+
+
 def latent_block(cfg: LatentConfig, lp: Dict[str, jax.Array], x: jax.Array,
                  positions: jax.Array, attend: Callable,
                  ffn: Callable) -> Tuple[jax.Array, Any, Any]:
@@ -369,15 +459,35 @@ def latent_block(cfg: LatentConfig, lp: Dict[str, jax.Array], x: jax.Array,
     ``attend(q_nope, q_rope, latent) -> (att [..., H * v], aux)`` gets the
     block's own projections (:func:`mla_project`) and meets the sequence
     (:func:`mla_unabsorbed`) or the cache (the engine's absorbed form);
-    ``ffn(lp, h) -> (y, faux)`` as in :func:`decoder_block`."""
-    with jax.named_scope("mla.project"):
-        h = rms_norm(x, lp["g1"], cfg.eps)
-    att, aux = attend(*mla_project(cfg, lp, h, positions))
-    with jax.named_scope("mla.project"):
-        x = x + att @ lp["wo"]
-    with jax.named_scope("ffn"):
-        y, faux = ffn(lp, rms_norm(x, lp["g2"], cfg.eps))
-        return x + y, aux, faux
+    ``ffn(lp, h) -> (y, faux)`` as in :func:`decoder_block`.  Where the
+    config has several residual streams ``x`` is all of them ``[n, ...,
+    D]`` (:func:`hc_fan_out`): each half reads their learned combination
+    and leaves them remixed with its output spread over them, where the
+    plain residual is ``x + y`` (:func:`hc_coefficients`; leaves
+    ``h1p``/``h1a``/``h1b`` for attention, ``h2*`` for the FFN)."""
+    def half(x, k, scope, sublayer):
+        if cfg.streams == 1:
+            y, aux = sublayer(x)
+            with scope:             # the plain residual, in its half's scope
+                return x + y, aux
+        pre, post, res = hc_coefficients(
+            cfg, lp[f"h{k}p"], lp[f"h{k}a"], lp[f"h{k}b"], x)
+        y, aux = sublayer(hc_mix(pre[None], x)[0])
+        return hc_mix(res, x, post, y), aux
+
+    def attention(h):
+        with jax.named_scope("mla.project"):
+            h = rms_norm(h, lp["g1"], cfg.eps)
+        att, aux = attend(*mla_project(cfg, lp, h, positions))
+        with jax.named_scope("mla.project"):
+            return att @ lp["wo"], aux
+
+    def feed_forward(h):
+        with jax.named_scope("ffn"):
+            return ffn(lp, rms_norm(h, lp["g2"], cfg.eps))
+    x, aux = half(x, 1, jax.named_scope("mla.project"), attention)
+    x, faux = half(x, 2, jax.named_scope("ffn"), feed_forward)
+    return x, aux, faux
 
 
 def latent_logits(cfg: LatentConfig, shared: Dict[str, jax.Array],
@@ -389,25 +499,40 @@ def latent_logits(cfg: LatentConfig, shared: Dict[str, jax.Array],
 
 def latent_param_shapes(cfg: LatentConfig) -> Dict[str, Dict[str, tuple]]:
     """The latent model's parameter tree as shapes: ``first`` (the leading
-    dense layer), ``blocks`` (the expert layers, stacked), ``shared``.
-    Names that start with ``g`` are RMSNorm scales; ``wr`` is the router,
-    kept in float32."""
+    dense layer), ``dense`` (the further leading dense layers, stacked;
+    only where ``dense_layers > 1``), ``blocks`` (the expert layers,
+    stacked), ``shared``.  Names that start with ``g`` are RMSNorm scales;
+    ``wr`` is the router, kept in float32, as are its selection bias
+    ``eb`` (``route_bias``) and the stream maps of each half (``streams``:
+    ``h1p``/``h2p`` ``[n, D, n * n + 2 n]``, the three gains ``h1a``/``h2a``
+    and the biases ``h1b``/``h2b``)."""
     D, H = cfg.d_model, cfg.heads
-    Fe, Eh, Lx = cfg.expert_ffn, cfg.held_experts, cfg.layers - 1
+    Fe, Eh, Lx = cfg.expert_ffn, cfg.held_experts, cfg.expert_layers
     attn = {"g1": (D,), "wqa": (D, cfg.q_rank), "gq": (cfg.q_rank,),
             "wqb": (cfg.q_rank, H * (cfg.nope_dim + cfg.rope_dim)),
             "wkva": (D, cfg.latent_dim), "gkv": (cfg.kv_rank,),
             "wkvb": (cfg.kv_rank, H * (cfg.nope_dim + cfg.v_dim)),
             "wo": (H * cfg.v_dim, D), "g2": (D,)}
+    if cfg.streams > 1:
+        n = cfg.streams
+        for half in "12":
+            attn.update({f"h{half}p": (n, D, n * n + 2 * n),
+                         f"h{half}a": (3,), f"h{half}b": (n * n + 2 * n,)})
     first = dict(attn, wg=(D, cfg.dense_ffn), wu=(D, cfg.dense_ffn),
                  wd=(cfg.dense_ffn, D))
     expert = dict(attn, wr=(D, cfg.num_experts), wsg=(D, Fe), wsu=(D, Fe),
                   wsd=(Fe, D), weg=(Eh, D, Fe), weu=(Eh, D, Fe),
                   wed=(Eh, Fe, D))
-    return {"first": first,
-            "blocks": {k: (Lx,) + v for k, v in expert.items()},
-            "shared": {"embed": (cfg.vocab, D), "head": (D, cfg.vocab),
-                       "gf": (D,)}}
+    if cfg.route_bias:
+        expert["eb"] = (cfg.num_experts,)
+    out = {"first": first}
+    if cfg.dense_layers > 1:
+        out["dense"] = {k: (cfg.dense_layers - 1,) + v
+                        for k, v in first.items()}
+    out["blocks"] = {k: (Lx,) + v for k, v in expert.items()}
+    out["shared"] = {"embed": (cfg.vocab, D), "head": (D, cfg.vocab),
+                     "gf": (D,)}
+    return out
 
 
 def latent_param_count(cfg: LatentConfig) -> int:

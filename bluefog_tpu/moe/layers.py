@@ -69,7 +69,8 @@ def router_topk(x: jax.Array, wr: jax.Array, *, top_k: int):
 
 def router_sigmoid_grouped(x: jax.Array, wr: jax.Array, *, top_k: int,
                            n_group: int, topk_group: int,
-                           route_scale: float):
+                           route_scale: float,
+                           bias: jax.Array | None = None):
     """Sigmoid router with group-limited selection:
     ``(scores [T, E], idx [T, k], weight [T, k])``.
 
@@ -79,20 +80,26 @@ def router_sigmoid_grouped(x: jax.Array, wr: jax.Array, *, top_k: int,
     group's score is the sum of its two highest scores, the
     ``topk_group`` best groups stay, and among their experts the
     ``top_k`` highest scores are taken.  The kept scores are normalised
-    to sum to one and multiplied by ``route_scale``.  No correction bias.
+    to sum to one and multiplied by ``route_scale``.  With a correction
+    ``bias`` ``[E]`` groups and experts are SELECTED by ``score + bias``
+    and weighed by the raw scores: the bias moves selections alone.
     """
     with jax.named_scope("moe.route"):
         T, E = x.shape[0], wr.shape[1]
         s = jax.nn.sigmoid(jnp.matmul(
             x.astype(jnp.float32), wr.astype(jnp.float32),
             precision=lax.Precision.HIGHEST))
-        group = jnp.sum(lax.top_k(s.reshape(T, n_group, E // n_group), 2)[0],
+        by = s if bias is None else s + bias.astype(jnp.float32)
+        group = jnp.sum(lax.top_k(by.reshape(T, n_group, E // n_group), 2)[0],
                         axis=-1)                               # [T, G]
         kept = jnp.sum(jax.nn.one_hot(lax.top_k(group, topk_group)[1],
                                       n_group, dtype=jnp.float32), axis=1)
+        # raw scores lie in (0, 1); a biased one may lie anywhere
         masked = jnp.where(jnp.repeat(kept, E // n_group, axis=1) > 0,
-                           s, -1.0)
+                           by, -1.0 if bias is None else -jnp.inf)
         top, idx = lax.top_k(masked, top_k)
+        if bias is not None:
+            top = jnp.take_along_axis(s, idx, axis=-1)
         return s, idx, route_scale * top / jnp.sum(top, -1, keepdims=True)
 
 
@@ -178,7 +185,8 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
     """One served expert layer's FFN on the normed tokens ``h`` ``[T, D]``
     as the chip that holds ``cfg.held_experts`` experts from
     ``cfg.held_start`` computes it: the full-width router
-    (:func:`router_sigmoid_grouped`), the held experts' part (leaves
+    (:func:`router_sigmoid_grouped`, under the layer's correction bias
+    ``eb`` where it has one), the held experts' part (leaves
     ``weg``/``weu``/``wed``), and the shared expert
     (``wsg``/``wsu``/``wsd``) for every token.  Tokens not ``live``
     (trash lanes, a prompt's padding) are routed to no expert: their
@@ -190,7 +198,8 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
     ``(y, idx [T, k], weight [T, k])``."""
     _, idx, weight = router_sigmoid_grouped(
         h, lp["wr"], top_k=cfg.top_k, n_group=cfg.n_group,
-        topk_group=cfg.topk_group, route_scale=cfg.route_scale)
+        topk_group=cfg.topk_group, route_scale=cfg.route_scale,
+        bias=lp.get("eb"))
     if live is not None:
         idx = jnp.where(live[:, None], idx, -1)
     experts = (h, idx, weight.astype(h.dtype), lp["weg"], lp["weu"],

@@ -565,6 +565,11 @@ class ServeEngine:
             self._latent_prefill_body if self._latent else
             self._hybrid_prefill_body if self._hybrid
             else self._prefill_body)
+        _metrics.gauge(
+            "bluefog_serve_residual_streams",
+            "residual streams the layer loops of this engine's programs "
+            "carry (1: the plain residual)").set(
+                float(getattr(cfg, "streams", 1)))
         if self._share:
             _metrics.gauge(
                 "bluefog_serve_cache_bytes_per_token",
@@ -1136,37 +1141,47 @@ class ServeEngine:
 
     def _latent_layers(self, params, x, cache, positions, attend_with, live,
                        grouped):
-        """The leading dense layer, then the scan over the expert layers,
-        over ONE cache stacked over all of them.  ``attend_with(lp, cache,
-        layer)`` builds a layer's ``attend`` hook; its ``aux`` is
-        ``(cache, new)``: the cache as the layer leaves it and what it
-        hands on for after the loop.  ``grouped`` is the held experts'
-        form: a prompt's pairs through the grouped kernel, or (decode)
-        every lane through every held expert (:meth:`_latent_ffn`).
-        Returns ``(x, cache, news [layers, ...] or None, carrier)``."""
+        """The leading dense layers, then the scan over the expert layers,
+        over ONE cache stacked over all of them.  ``x`` is the residual as
+        the layers carry it (:func:`~bluefog_tpu.models.decoder.hc_fan_out`:
+        the streams, stream axis major, where the model has several).
+        ``attend_with(lp, cache, layer)`` builds a layer's ``attend`` hook;
+        its ``aux`` is ``(cache, new)``: the cache as the layer leaves it
+        and what it hands on for after the loop.  ``grouped`` is the held
+        experts' form: a prompt's pairs through the grouped kernel, or
+        (decode) every lane through every held expert
+        (:meth:`_latent_ffn`).  Returns ``(x, cache, news [layers, ...] or
+        None, carrier)``."""
         cfg = self.cfg
         names = ("weg", "weu", "wed") if grouped else ()
         ffn = self._latent_ffn(
             live, {k: params["blocks"][k] for k in names} or None)
         blocks = {k: v for k, v in params["blocks"].items()
                   if k not in names}
-        blocks["layer"] = jnp.arange(cfg.layers - 1)
+        blocks["layer"] = jnp.arange(cfg.expert_layers)
         x, (cache, new0), _ = decoder.latent_block(
             cfg, params["first"], x, positions,
             attend_with(params["first"], cache, 0), decoder.dense_gated_ffn)
+        news0 = [new0]                  # the leading layers' tokens
+        for i in range(1, cfg.dense_layers):
+            lp = jax.tree.map(lambda a: a[i - 1], params["dense"])
+            x, (cache, new), _ = decoder.latent_block(
+                cfg, lp, x, positions, attend_with(lp, cache, i),
+                decoder.dense_gated_ffn)
+            news0.append(new)
 
         def body(carry, lp):
             x, cache, acc = carry
             x, (cache, new), vec = decoder.latent_block(
                 cfg, lp, x, positions,
-                attend_with(lp, cache, lp["layer"] + 1), ffn)
+                attend_with(lp, cache, lp["layer"] + cfg.dense_layers), ffn)
             return (x, cache, acc + vec), new
 
         (x, cache, acc), news = lax.scan(
             body, (x, cache, jnp.zeros((cfg.num_experts + 4,), jnp.float32)),
             blocks)
         if new0 is not None:
-            news = jnp.concatenate([new0[None], news])
+            news = jnp.concatenate([new[None] for new in news0] + [news])
         return x, cache, news, acc
 
     def _latent_decode_body(self, params, cache, keys, lanes):
@@ -1201,11 +1216,11 @@ class ServeEngine:
             with jax.named_scope("readout"):
                 x = shared["embed"][toks]
             x, _, news, acc = self._latent_layers(
-                params, x, None, lens, attend_with, live,
-                grouped=False)
+                params, decoder.hc_fan_out(cfg, x), None, lens, attend_with,
+                live, grouped=False)
             cache = _kv.latent_append_tokens(cache, slot_ids, lens, news)
-            nxt, keys = self._next_token(
-                decoder.latent_logits(cfg, shared, x), keys)
+            nxt, keys = self._next_token(decoder.latent_logits(
+                cfg, shared, decoder.hc_collapse(cfg, x)), keys)
             nxt = nxt.astype(toks.dtype)
             return (nxt, lens + 1, cache, keys, st + acc), nxt
 
@@ -1237,12 +1252,14 @@ class ServeEngine:
         with jax.named_scope("readout"):
             x = shared["embed"][toks]
         x, cache, _, _ = self._latent_layers(
-            params, x, cache, positions, attend_with,
+            params, decoder.hc_fan_out(cfg, x), cache, positions, attend_with,
             positions < true_len, grouped=True)
         with jax.named_scope("readout"):
-            last = decoder.latent_logits(
-                cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
-            ).astype(jnp.float32)
+            x = lax.dynamic_slice_in_dim(x, true_len - 1, 1, axis=-2)
+        x = decoder.hc_collapse(cfg, x)
+        with jax.named_scope("readout"):
+            last = decoder.latent_logits(cfg, shared, x[0]).astype(
+                jnp.float32)
             nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 

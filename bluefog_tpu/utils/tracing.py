@@ -254,7 +254,8 @@ DEVICE_SCOPES = frozenset((
     "GRADIENT", "ADAPT", "COMMUNICATE", "STATE_SYNC",      # the train step
     "attn", "attn.project", "attn.window", "attn.full",
     "mla.project", "mla.attend", "cache.read", "cache.write",
-    "ffn", "moe.route", "moe.experts", "moe.shared", "readout"))
+    "ffn", "moe.route", "moe.experts", "moe.shared", "readout",
+    "hc.coef", "hc.mix"))                   # the residual streams' maps
 
 _NOT_A_SCOPE_RE = re.compile(r"\bp?jit\([^()]*\)")     # a function's name
 _PATH_NAME_RE = re.compile(r"[^/()]+")

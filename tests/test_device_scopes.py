@@ -58,6 +58,12 @@ def small_compiled():
     ("jit(_decode_body)/while/body/closed_call/attn.window/attn.project/"
      "dot_general", ("attn.project", "")),
     ("jit(body)/attn.full/mla.attend/cache.read/exp", ("cache.read", "")),
+    # the residual streams' maps: their own scopes, inside a layer loop and
+    # inside another scope alike
+    ("jit(_latent_prefill_body)/while/body/closed_call/hc.coef/div",
+     ("hc.coef", "")),
+    ("jit(_latent_decode_body)/while/body/ffn/hc.mix/reduce_sum",
+     ("hc.mix", "")),
     # a jitted function called ``ffn`` is not the scope ``ffn``
     ("jit(f)/jit(ffn)/dot_general", ("", "")),
     ("jit(f)/pjit(readout)/jvp(mul)", ("", "fwd")),
@@ -218,6 +224,10 @@ VOCABULARY = {
                {"attn.project", "attn.window", "attn.full", "cache.write",
                 "ffn", "moe.route", "moe.experts", "moe.shared", "readout"}),
 }
+# four residual streams round the latent block: the latent programs'
+# vocabulary with the stream maps' two scopes
+VOCABULARY["latent_streams"] = tuple(
+    scopes | {"hc.coef", "hc.mix"} for scopes in VOCABULARY["latent"])
 
 
 def make_engine(family, cpu_devices):
@@ -230,6 +240,9 @@ def make_engine(family, cpu_devices):
                         max_len=16))
     import test_serve_hybrid
     import test_serve_latent
+    if family == "latent_streams":
+        return test_serve_latent.make_engine(cpu_devices,
+                                             test_serve_latent.STREAMED)
     mod = {"latent": test_serve_latent, "hybrid": test_serve_hybrid}[family]
     return mod.make_engine(cpu_devices)
 

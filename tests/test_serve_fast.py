@@ -424,13 +424,53 @@ def _rms(xp):
                       * (0.25 + xp.arange(z.shape[-1]) % 4))
 
 
+def _streamed_block_is_one_definition(cpu_devices, monkeypatch):
+    """The streamed latent model's three paths (prefill, decode, the
+    whole-sequence forward the checks use) all go through
+    ``decoder.latent_block`` with the streams, stream axis major, and
+    through ``decoder.hc_coefficients`` twice a layer: an edit to either
+    reaches every one of them."""
+    import test_serve_latent as tl
+    cfg, blocks, maps = tl.STREAMED, [], []
+    real_block, real_maps = decoder.latent_block, decoder.hc_coefficients
+
+    def block(cfg_, lp, x, *rest):
+        blocks.append(x.shape)
+        return real_block(cfg_, lp, x, *rest)
+
+    def coefficients(cfg_, phi, alpha, bias, xs):
+        maps.append(xs.shape)
+        return real_maps(cfg_, phi, alpha, bias, xs)
+    monkeypatch.setattr(decoder, "latent_block", block)
+    monkeypatch.setattr(decoder, "hc_coefficients", coefficients)
+    eng = tl.make_engine(cpu_devices, cfg, prefill_buckets=(8,))
+    # the scan traces its body once: the two dense layers, one expert layer
+    traced = 1 + (cfg.dense_layers - 1) + 1
+    eng.prefill(0, 0, [1, 2, 3])
+    assert blocks == [(4, 8, cfg.d_model)] * traced
+    assert maps == [(4, 8, cfg.d_model)] * 2 * traced
+    del blocks[:], maps[:]
+    trash = eng.cache_cfg.trash_slot
+    eng.decode(np.array([[5, 0, 0, 0]]), np.array([[0] + [trash] * 3]),
+               np.array([[3, 0, 0, 0]]))
+    assert blocks == [(4, 4, cfg.d_model)] * traced
+    assert maps == [(4, 4, cfg.d_model)] * 2 * traced
+    del blocks[:], maps[:]
+    tl.full_forward(cfg, eng.params, [1, 2, 3, 4, 5])
+    assert blocks == [(4, 5, cfg.d_model)] * cfg.layers
+    assert maps == [(4, 5, cfg.d_model)] * 2 * cfg.layers
+
+
 @pytest.mark.parametrize("program", ["train", "moe_train", "prefill",
-                                     "decode", "chunk"])
+                                     "decode", "chunk", "latent_streams"])
 def test_one_block_definition(cpu_devices, monkeypatch, program):
     """A configuration's change is ONE edit: with ``decoder.norm`` swapped
     before a program is built, each of the composed LM's five programs
     follows its independent numpy oracle given the same norm, and leaves
-    the oracle of the norm it was built without."""
+    the oracle of the norm it was built without.  The streamed latent
+    block's programs are held to one definition by who they call."""
+    if program == "latent_streams":
+        return _streamed_block_is_one_definition(cpu_devices, monkeypatch)
     from test_serve import _np_ln, _ref_forward
     from test_serve_moe import _ref_moe_forward
     from jax.sharding import PartitionSpec as P

@@ -25,16 +25,30 @@ CFG = decoder.LatentConfig(
     rope_mscale_all_dim=1.0)
 
 
+# the same model with everything the streamed latent programs add: four
+# residual streams, two leading dense layers, a router bias, every expert
+# of a layer held
+STREAMED = dataclasses.replace(
+    CFG, layers=4, dense_layers=2, route_bias=True, streams=4, n_group=1,
+    topk_group=1, held_experts=16, held_start=0)
+FLOAT32 = ("wr", "eb", "h1p", "h1a", "h1b", "h2p", "h2a", "h2b")
+
+
 def make_params(cfg, seed=0, n=1, dtype=jnp.float32):
+    """Seeded leaves at scales that let a fault show: matrices 0.2 normal,
+    norm scales and the stream maps' gains 1 + 0.1 normal, the maps'
+    biases normal, the router's bias 0.1 normal."""
     key, out = jax.random.key(seed), {}
     for group, leaves in decoder.latent_param_shapes(cfg).items():
         out[group] = {}
         for name, shape in leaves.items():
             key, k = jax.random.split(key)
             z = jax.random.normal(k, shape, jnp.float32)
-            z = 1.0 + 0.1 * z if name.startswith("g") else 0.2 * z
+            z = 1.0 + 0.1 * z if name[0] == "g" or name in ("h1a", "h2a") \
+                else z if name in ("h1b", "h2b") \
+                else 0.1 * z if name == "eb" else 0.2 * z
             out[group][name] = jnp.broadcast_to(
-                z.astype(jnp.float32 if name == "wr" else dtype)[None],
+                z.astype(jnp.float32 if name in FLOAT32 else dtype)[None],
                 (n,) + shape)
     return out
 
@@ -57,17 +71,20 @@ def full_forward(cfg, params, toks):
     def attend_of(lp):
         return lambda qn, qr, lat: (
             decoder.mla_unabsorbed(cfg, lp, qn, qr, lat), None)
-    x = p["shared"]["embed"][jnp.asarray(toks)]
-    x, _, _ = decoder.latent_block(cfg, p["first"], x, pos,
-                                   attend_of(p["first"]),
-                                   decoder.dense_gated_ffn)
-    for i in range(cfg.layers - 1):
+    x = decoder.hc_fan_out(cfg, p["shared"]["embed"][jnp.asarray(toks)])
+    dense = [p["first"]] + [jax.tree.map(lambda a: a[i], p["dense"])
+                            for i in range(cfg.dense_layers - 1)]
+    for lp in dense:
+        x, _, _ = decoder.latent_block(cfg, lp, x, pos, attend_of(lp),
+                                       decoder.dense_gated_ffn)
+    for i in range(cfg.expert_layers):
         lp = jax.tree.map(lambda a: a[i], p["blocks"])
         x, _, _ = decoder.latent_block(
             cfg, lp, x, pos, attend_of(lp),
             lambda lp, h: (moe_layers.held_moe_ffn(cfg, lp, h, live)[0],
                            None))
-    return np.asarray(decoder.latent_logits(cfg, p["shared"], x))
+    return np.asarray(decoder.latent_logits(
+        cfg, p["shared"], decoder.hc_collapse(cfg, x)))
 
 
 def test_param_count_matches_the_shapes():
@@ -270,9 +287,11 @@ def test_tokens_that_are_not_live_reach_no_expert():
     np.testing.assert_allclose(y[1], shared[1], rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("CFG", [CFG, STREAMED], ids=["one_stream",
+                                                        "four_streams"])
 def test_prefill_then_decode_through_the_scheduler_match_the_full_forward(
-        cpu_devices):
-    eng = make_engine(cpu_devices)
+        cpu_devices, CFG):
+    eng = make_engine(cpu_devices, CFG)
     eng.warmup()
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, CFG.vocab, n).tolist() for n in (5, 12, 9)]
@@ -410,6 +429,11 @@ def test_what_the_latent_programs_do_not_do_is_refused_by_name(
     (dict(top_k=9), "latent_router_groups"),
     (dict(held_start=14), "latent_held_experts"),
     (dict(layers=1), "leading dense layer"),
+    (dict(dense_layers=3), "latent_dense_layers"),
+    (dict(dense_layers=0), "latent_dense_layers"),
+    (dict(streams=0), "latent_streams"),
+    (dict(streams=4, sinkhorn_iters=0), "latent_streams"),
+    (dict(streams=4, hc_eps=0.0), "latent_streams"),
 ])
 def test_latent_config_refuses_what_it_cannot_mean(cpu_devices, bad, match):
     m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
@@ -427,3 +451,166 @@ def test_unabsorbed_attention_in_chunks_of_heads_is_the_same(monkeypatch):
         monkeypatch.setattr(decoder, "SCORE_BYTES", limit)
         np.testing.assert_allclose(decoder.mla_unabsorbed(CFG, p, qn, qr, lat),
                                    whole, rtol=1e-5, atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# residual streams, several leading dense layers, a router bias
+# ---------------------------------------------------------------------------
+
+def test_streamed_param_shapes_count_the_maps_the_dense_layers_and_the_bias():
+    shapes = decoder.latent_param_shapes(STREAMED)
+    assert list(shapes) == ["first", "dense", "blocks", "shared"]
+    n, D = 4, STREAMED.d_model
+    for half in "12":
+        assert shapes["first"][f"h{half}p"] == (n, D, n * n + 2 * n)
+        assert shapes["dense"][f"h{half}a"] == (1, 3)
+        assert shapes["blocks"][f"h{half}b"] == (2, n * n + 2 * n)
+    assert shapes["dense"]["wg"] == (1, D, STREAMED.dense_ffn)
+    assert shapes["blocks"]["eb"] == (2, 16) and "eb" not in shapes["first"]
+    assert STREAMED.expert_layers == 2 and STREAMED.streams == 4
+    maps = 2 * (n * D * 24 + 3 + 24)
+    plain = dataclasses.replace(STREAMED, streams=1, route_bias=False)
+    assert decoder.latent_param_count(STREAMED) == \
+        decoder.latent_param_count(plain) + 4 * maps + 2 * 16
+    # one stream, one dense layer, no bias: the tree a.x-k1's programs take
+    assert list(decoder.latent_param_shapes(CFG)) == ["first", "blocks",
+                                                      "shared"]
+    assert CFG.streams == 1
+
+
+def stream_maps(seed=0, n=4, D=48, alpha=(1.0, 1.0, 1.0)):
+    ks = jax.random.split(jax.random.key(seed), 3)
+    return (0.2 * jax.random.normal(ks[0], (n, D, n * n + 2 * n)),
+            jnp.asarray(alpha, jnp.float32),
+            jax.random.normal(ks[1], (n * n + 2 * n,)),
+            jax.random.normal(ks[2], (n, 9, D)))
+
+
+def test_the_remix_is_doubly_stochastic_and_the_maps_are_the_equations():
+    phi, alpha, b, xs = stream_maps()
+    pre, post, res = decoder.hc_coefficients(STREAMED, phi, alpha, b, xs)
+    assert pre.shape == post.shape == (4, 9) and res.shape == (4, 4, 9)
+    assert pre.dtype == post.dtype == res.dtype == jnp.float32
+    # twenty rounds end on the rows: those sum to one; the columns do at
+    # the median token, and within a few percent where a token's logits
+    # spread widest (20,000 draws at these scales: 4e-5 at the median,
+    # 0.044 at the worst)
+    np.testing.assert_allclose(np.asarray(res).sum(1), 1.0, atol=1e-5)
+    cols = np.abs(np.asarray(res).sum(0) - 1.0).max(0)          # [tokens]
+    assert np.median(cols) < 1e-3 and cols.max() < 0.1, cols
+    assert np.all(np.asarray(res) > 0)
+    # the equations in numpy, token by token
+    X = np.asarray(xs, np.float64)
+    for t in range(9):
+        v = X[:, t].reshape(-1)
+        c = (v / np.sqrt(np.mean(v * v) + STREAMED.eps)) @ np.asarray(
+            phi, np.float64).reshape(-1, 24)
+        sig = lambda z: 1 / (1 + np.exp(-z))
+        np.testing.assert_allclose(pre[:, t], sig(c[:4] + b[:4]), rtol=1e-4)
+        np.testing.assert_allclose(post[:, t], 2 * sig(c[4:8] + b[4:8]),
+                                   rtol=1e-4)
+        m = np.exp(np.clip(c[8:] + np.asarray(b[8:]), -30, 30)).reshape(4, 4)
+        for _ in range(20):
+            m = m / (m.sum(0, keepdims=True) + 1e-6)
+            m = m / (m.sum(1, keepdims=True) + 1e-6)
+        np.testing.assert_allclose(res[:, :, t], m, rtol=1e-3, atol=1e-6)
+    # one round is another matrix, and the gains are not decoration
+    one = decoder.hc_coefficients(
+        dataclasses.replace(STREAMED, sinkhorn_iters=1), phi, alpha, b, xs)[2]
+    assert float(jnp.max(jnp.abs(one - res))) > 1e-2
+    flat = decoder.hc_coefficients(STREAMED, phi, jnp.zeros(3), b, xs)
+    assert float(jnp.max(jnp.abs(flat[0] - pre))) > 1e-2
+
+
+def test_the_remix_stays_finite_at_the_clip(scale=1e4):
+    """Inputs of 1e4 change nothing (the flattened streams are normed);
+    gains of 1e4 drive every logit to the clip at +-30, where the rounds
+    still end on rows that sum to one."""
+    phi, _, b, xs = stream_maps(1)
+    calm = decoder.hc_coefficients(STREAMED, phi, jnp.ones(3), b, xs)
+    loud = decoder.hc_coefficients(STREAMED, phi, jnp.ones(3), b, scale * xs)
+    for a, c in zip(loud, calm):
+        np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-6)
+    pre, post, res = decoder.hc_coefficients(
+        STREAMED, phi, jnp.full((3,), 1e4), b, xs)
+    for a in (pre, post, res):
+        assert np.all(np.isfinite(np.asarray(a)))
+    np.testing.assert_allclose(np.asarray(res).sum(1), 1.0, atol=1e-5)
+
+
+def test_the_mix_reads_a_combination_and_writes_the_remix():
+    phi, alpha, b, xs = stream_maps(2)
+    pre, post, res = decoder.hc_coefficients(STREAMED, phi, alpha, b, xs)
+    y = jax.random.normal(jax.random.key(7), (9, 48))
+    h = decoder.hc_mix(pre[None], xs)[0]
+    out = decoder.hc_mix(res, xs, post, y)
+    assert h.shape == (9, 48) and out.shape == xs.shape
+    np.testing.assert_allclose(
+        h, np.einsum("it,itd->td", pre, xs), rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(
+        out, np.einsum("ijt,jtd->itd", res, xs)
+        + np.asarray(post)[:, :, None] * np.asarray(y)[None],
+        rtol=1e-5, atol=1e-6)
+    # the streams stay in their own dtype
+    assert decoder.hc_mix(res, xs.astype(jnp.bfloat16), post,
+                          y).dtype == jnp.bfloat16
+    fan = decoder.hc_fan_out(STREAMED, y)
+    assert fan.shape == (4, 9, 48)
+    np.testing.assert_allclose(decoder.hc_collapse(STREAMED, fan), 4 * y,
+                               rtol=1e-6)
+    assert decoder.hc_fan_out(CFG, y) is y and decoder.hc_collapse(CFG, y) is y
+
+
+def test_the_router_bias_moves_selections_and_never_weights():
+    x = jax.random.normal(jax.random.key(5), (200, 48))
+    wr = 0.3 * jax.random.normal(jax.random.key(6), (48, 16))
+    bias = 0.1 * jax.random.normal(jax.random.key(7), (16,))
+    kw = dict(top_k=4, n_group=1, topk_group=1, route_scale=2.0)
+    s0, idx0, w0 = moe_layers.router_sigmoid_grouped(x, wr, **kw)
+    s, idx, w = moe_layers.router_sigmoid_grouped(x, wr, bias=bias, **kw)
+    np.testing.assert_array_equal(s, s0)            # the scores are raw
+    by = np.asarray(s) + np.asarray(bias)
+    assert np.array_equal(np.sort(idx, -1),
+                          np.sort(np.argsort(-by, -1)[:, :4], -1))
+    same = np.all(np.sort(idx, -1) == np.sort(idx0, -1), -1)
+    assert 0.1 < 1 - same.mean() < 0.9              # a visible share moves
+    # weights: the RAW scores of the taken, normalised, times the scale
+    raw = np.take_along_axis(np.asarray(s), np.asarray(idx), 1)
+    np.testing.assert_allclose(w, 2.0 * raw / raw.sum(-1, keepdims=True),
+                               rtol=1e-6)
+    # where the bias moved nothing the weights are the unbiased router's
+    order, order0 = np.argsort(idx, -1), np.argsort(idx0, -1)
+    np.testing.assert_allclose(
+        np.take_along_axis(np.asarray(w), order, 1)[same],
+        np.take_along_axis(np.asarray(w0), order0, 1)[same], rtol=1e-6)
+    # a zero bias is no bias
+    _, idxz, wz = moe_layers.router_sigmoid_grouped(
+        x, wr, bias=jnp.zeros(16), **kw)
+    np.testing.assert_array_equal(idxz, idx0)
+    np.testing.assert_allclose(wz, w0, rtol=1e-6)
+
+
+def test_the_streamed_engine_reports_its_streams(cpu_devices):
+    metrics.reset_metrics()
+    eng = make_engine(cpu_devices, STREAMED)
+    assert metrics.gauge("bluefog_serve_residual_streams").value() == 4
+    eng.prefill(0, 0, [1, 2, 3])
+    mem = eng.program_memory()
+    # every layer's vectors land: two dense layers, two expert layers
+    assert mem["prefill Tpad=8"]["cache_writes"] == 4 * 2
+    assert eng.cache["ckv"].shape == (1, 4, 5, 32, STREAMED.kv_rank)
+    plain = make_engine(cpu_devices)
+    assert metrics.gauge("bluefog_serve_residual_streams").value() == 1
+
+
+def test_a_streamed_model_is_refused_what_the_latent_programs_refuse(
+        cpu_devices):
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    kw = dict(batch_buckets=(4,), prefill_buckets=(8,), slots=4, max_len=32)
+    for knob, name in ((dict(spec_decode=2), "latent_serving_spec_decode"),
+                       (dict(kv_dtype="int8"), "latent_serving_kv_dtype"),
+                       (dict(prefix_pages=1, prefix_page_tokens=4),
+                        "latent_serving_prefix_pages")):
+        with pytest.raises(ValueError, match=name):
+            ServeEngine(m, STREAMED, make_params(STREAMED),
+                        ServeConfig(**kw, **knob))

@@ -86,7 +86,7 @@ from __future__ import annotations
 
 import dataclasses
 import os
-from typing import Any, Optional, Sequence, Tuple
+from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -442,6 +442,16 @@ class _DeviceRow:
         return row if dtype is None else row.astype(dtype)
 
 
+class _DecodeCall(NamedTuple):
+    """A decode call dispatched and not yet read back: its bucket, its
+    lanes' slots and positions ``[replicas, S]`` and the program's outputs
+    before the state (tokens ``[n_devices, steps, S]`` first)."""
+    S: int
+    slots: np.ndarray
+    lens: np.ndarray
+    out: list
+
+
 class ServeEngine:
     """SPMD prefill/decode over one carving; host-side shapes per replica.
 
@@ -586,6 +596,15 @@ class ServeEngine:
             if (scfg.spec_decode or scfg.prefix_pages) else None
         self._draft_jit = self._build(self._draft_body) \
             if scfg.spec_decode else None
+        # a decode call staged before the one ahead of it has been read
+        # back takes its lanes' tokens from that call's output, on the
+        # device (:meth:`decode`): the decode programs themselves are the
+        # ones that take every token from the host
+        self._feed_jit = jax.jit(
+            jax.shard_map(self._feed_body, mesh=m.mesh, in_specs=P(AXES),
+                          out_specs=P(AXES), check_vma=False),
+            out_shardings=self._sharding)
+        self._flying: Optional[_DecodeCall] = None     # dispatched, unread
         self._seed_count = 0        # admissions so far: folded into keys
         self._warm_sizes: Optional[Tuple[int, ...]] = None
         self._program_bytes: dict = {}
@@ -969,6 +988,19 @@ class ServeEngine:
 
     def _split_args(self, args):
         return jax.tree.map(lambda t: t[0], args)
+
+    @staticmethod
+    def _feed_body(lanes, gen):
+        """A decode call's ``staged`` out of what the host sent for a call
+        that runs ahead: ``lanes`` ``[1, S, 1 + 4 + 1]`` is the staged
+        array with one more integer a lane, the lane of the call in flight
+        that chooses this lane's token (negative: the host's token
+        stands), and ``gen`` ``[1, steps, S']`` that call's tokens, which
+        the host has not read yet."""
+        src = lanes[0, :, -1]
+        toks = jnp.where(src >= 0, gen[0, -1, jnp.maximum(src, 0)],
+                         lanes[0, :, 0])
+        return jnp.concatenate([toks[:, None], lanes[0, :, 1:-1]], -1)[None]
 
     def _decode_body(self, params, cache, keys, lanes):
         params, cache, keys, lanes = self._split_args(
@@ -1682,7 +1714,8 @@ class ServeEngine:
 
     def decode(self, tokens: np.ndarray, slots: np.ndarray,
                lens: np.ndarray, prefix_rows: Optional[np.ndarray] = None,
-               prefix_lens: Optional[np.ndarray] = None) -> np.ndarray:
+               prefix_lens: Optional[np.ndarray] = None, *,
+               ahead: bool = False) -> np.ndarray:
         """One fused decode call for every replica at one batch bucket.
 
         ``tokens``/``slots``/``lens``: ``[replicas, S]`` with ``S`` in
@@ -1694,40 +1727,134 @@ class ServeEngine:
         ``[replicas, decode_steps_per_call, S]`` (greedy, or sampled when
         ``temperature > 0`` — each lane's PRNG stream was seeded at its
         prefill).
+
+        ``ahead=True`` (the scheduler's) runs ONE CALL AHEAD of the host:
+        this call is staged and dispatched, then the call dispatched one
+        ``decode`` earlier is collected and ITS tokens are returned (they
+        belong to that call's lanes; ``[replicas, 0, S]``, no step, where
+        nothing was in flight), and this one stays in flight until the
+        next ``decode`` or :meth:`decode_drain`.  A NEGATIVE token then
+        stands for the slot's pending token, the last one the call in
+        flight chooses for it, which the host has not read: it goes from
+        that call's output into this call's staged array on the device
+        (:meth:`_feed_body`, one small program between the two decode
+        programs, which stay what they are).  The stages keep their names
+        and order under one ``decode_call``; ``collect`` waits for the
+        program ahead of the one just dispatched, so the device always has
+        its next program queued.  What belongs to a call (the routing
+        carrier behind :meth:`moe_load`, the ``held_work`` mark, the
+        position counters, :meth:`decode_logits`) is published when THAT
+        call is collected.  The call's ``ahead`` attribute and
+        ``bluefog_serve_decode_calls_total{ahead}`` say whether a call was
+        in flight when it was dispatched.
         """
         tokens = np.asarray(tokens, np.int32)
+        slots = np.array(slots, np.int32)
         S = tokens.shape[1]
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
+        if self._flying is not None and not ahead:
+            raise RuntimeError(
+                "a decode call is in flight: its tokens would be lost — "
+                "collect it first (decode_drain)")
+        fed = self._fed_by(tokens, slots)
         writes = self._cache_writes("decode", S)
-        with self._stage("decode_call", S=int(S), cache_writes=writes):
+        behind = int(self._flying is not None)
+        self._count_decode_call(behind)
+        with self._stage("decode_call", S=int(S), cache_writes=writes,
+                         ahead=behind):
             with self._stage("stage_in"):
-                args = self._stage_lanes("decode", tokens[..., None], slots,
-                                         lens, prefix_rows, prefix_lens)
+                args = self._stage_lanes(
+                    "decode", tokens[..., None], slots, lens, prefix_rows,
+                    prefix_lens, *(() if fed is None else (fed,)))
             with self._stage("dispatch"):
+                if fed is not None:
+                    args = args[:-1] + (
+                        self._feed_jit(args[-1], self._flying.out[0]),)
                 *out, self._keys, self.cache = self._decode_jit(*args)
-            with self._stage("collect"):
-                self._check_program(f"decode S={S}", self._decode_jit, args,
-                                    writes, self._read_form(S))
-                if self._hybrid:
-                    self._decode_logits = (np.array(slots, np.int32),
-                                           out.pop())
-                gen, *st = self._collect("decode", *out)
-                if st:
-                    self._note_route_stats(st[0])
-                if not self._hybrid:
-                    self._count_decode_read(S)
-            # a mark never goes inside a leaf stage: ``collect`` stays a
-            # span with none beneath it in every family
-            if self._share:
-                self._count_held_work(S, lens, np.asarray(slots))
-            return gen
+            call = _DecodeCall(S, slots, np.array(lens, np.int32), out)
+            if self._hybrid and self._decode_logits is None:
+                # nothing has been collected yet: the call in flight's
+                self._decode_logits = (slots, out[-1])
+            due, self._flying = (self._flying, call) if ahead \
+                else (call, None)
+            gen = self._collect_decode(due, (
+                f"decode S={S}", self._decode_jit, args, writes,
+                self._read_form(S)))
+            return np.empty((self.m.dp, 0, S), np.int32) if gen is None \
+                else gen
+
+    def _fed_by(self, tokens: np.ndarray, slots: np.ndarray
+                ) -> Optional[np.ndarray]:
+        """Per lane of a call about to be staged, the lane of the call in
+        flight whose slot it rides (``[replicas, S]``, -1 where the host's
+        token stands); None where every token is the host's."""
+        wanted = tokens < 0
+        if not wanted.any():
+            return None
+        if self._flying is None:
+            raise ValueError("a negative token stands for what the decode "
+                             "call in flight chooses, and none is in flight")
+        ahead = self._flying.slots                          # [replicas, S']
+        lane_of = np.full((self.m.dp, self.cache_cfg.rows), -1, np.int32)
+        np.put_along_axis(lane_of, ahead, np.arange(
+            ahead.shape[1], dtype=np.int32)[None], axis=1)
+        lane_of[:, self.cache_cfg.trash_slot] = -1
+        fed = np.where(wanted, np.take_along_axis(lane_of, slots, axis=1), -1)
+        if (fed < 0)[wanted].any():
+            raise ValueError("a negative token on a slot that the decode "
+                             "call in flight does not carry")
+        return fed
+
+    def decode_drain(self) -> Optional[np.ndarray]:
+        """Collect the decode call in flight (``decode(..., ahead=True)``)
+        with nothing dispatched behind it: its tokens, or None where none
+        is in flight.  One ``bf:engine.decode_drain`` span with a
+        ``collect`` beneath."""
+        due, self._flying = self._flying, None
+        if due is None:
+            return None
+        with self._stage("decode_drain", S=due.S):
+            return self._collect_decode(due)
+
+    def _count_decode_call(self, ahead: int) -> None:
+        _metrics.counter(
+            "bluefog_serve_decode_calls_total",
+            "decode calls (a speculative round is one) by whether another "
+            "was in flight, dispatched and not yet read back, when the "
+            "call was dispatched (ahead: 1 or 0)").inc(ahead=str(ahead))
+
+    def _collect_decode(self, due: Optional[_DecodeCall], check=None
+                        ) -> Optional[np.ndarray]:
+        """The ``collect`` stage of a decode call, ``due`` the call whose
+        tokens are read (None: nothing was in flight) and ``check`` the program just dispatched
+        (:meth:`_check_program`), and what is published with a call."""
+        with self._stage("collect"):
+            if check is not None:
+                self._check_program(*check)
+            if due is None:
+                return None
+            S, slots, lens, out = due
+            if self._hybrid:
+                self._decode_logits = (slots, out.pop())
+            gen, *st = self._collect("decode", *out)
+            if st:
+                self._note_route_stats(st[0])
+            if not self._hybrid:
+                self._count_decode_read(S)
+        # a mark never goes inside a leaf stage: ``collect`` stays a
+        # span with none beneath it in every family
+        if self._share:
+            self._count_held_work(S, lens, slots)
+        return gen
 
     def decode_logits(self, replica: int
                       ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
-        """What the last :meth:`decode` call of the hybrid family chose
-        its tokens from: ``replica``'s lanes' slots ``[S]`` and their
+        """What the last :meth:`decode` call COLLECTED of the hybrid family
+        (the call whose tokens were last returned; before any has been,
+        the call in flight, and converting them waits for it) chose its
+        tokens from: ``replica``'s lanes' slots ``[S]`` and their
         logits ``[decode_steps_per_call, S, vocab]`` (float32), which stay
         on the device until something converts them, as a prefill's do.
         ``Scheduler`` never reads them; the benchmark's comparison with
@@ -1768,6 +1895,7 @@ class ServeEngine:
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "
                              f"bucket {self.scfg.batch_buckets}")
+        self._count_decode_call(0)
         with self._stage("spec_round", S=int(S), k=k) as round_:
             emitted, counts, drafted, accepted = self._spec_round(
                 tokens, slots, lens, prefix_rows, prefix_lens)
@@ -1894,6 +2022,14 @@ class ServeEngine:
             self.decode(full(tok), full(slot), full(ln))
             if scfg.spec_decode:
                 self.spec_decode(full(tok), full(slot), full(ln))
+        # a call that runs ahead is fed by the one in flight: every pair
+        # of buckets (:meth:`_feed_body`)
+        n, steps = R * self.m.slice_size, scfg.decode_steps_per_call
+        zeros = lambda *shape: jax.device_put(
+            np.zeros((n,) + shape, np.int32), self._sharding)
+        for S in scfg.batch_buckets:
+            for ahead in scfg.batch_buckets:
+                self._feed_jit(zeros(S, 1 + 4 + 1), zeros(steps, ahead))
         if self._use_prefix:
             for Tpad in scfg.prefill_buckets:
                 self._chunk_call(np.zeros((R, 1, Tpad), np.int32),
@@ -1914,7 +2050,8 @@ class ServeEngine:
     def _jit_sizes(self) -> Tuple[int, ...]:
         return tuple(j._cache_size() if j is not None else 0
                      for j in (self._decode_jit, self._prefill_jit,
-                               self._chunk_jit, self._draft_jit))
+                               self._chunk_jit, self._draft_jit,
+                               self._feed_jit))
 
     def _cache_writes(self, kind: str, lanes: int) -> int:
         """``dynamic_update_slice``s into the cache that one call of a

@@ -15,10 +15,13 @@ armed, and the per-request token state.  Each :meth:`Scheduler.step` does
    declared batch bucket that fits the busiest replica, idle lanes padded
    with the trash slot.  With ``spec_decode=k`` armed this is one
    speculative round (draft + verify) and each lane advances by its own
-   accepted count; otherwise it is ``decode_steps_per_call`` plain steps.
-3. **retire** — requests that hit ``max_new_tokens`` (or the KV-cache
-   length ceiling) free their slot, release their prefix page reference,
-   and close their latency clocks.
+   accepted count; otherwise it is ``decode_steps_per_call`` plain steps,
+   dispatched ONE CALL AHEAD of the host: this step's call goes out before
+   the call of the step before has been read back (:meth:`Scheduler.step`).
+3. **deliver** — the tokens of the call just read go to their requests;
+   those that hit ``max_new_tokens`` (or the KV-cache length ceiling) free
+   their slot, release their prefix page reference, and close their
+   latency clocks.
 
 Because admission only changes *which slot/page ids* ride in the bucketed
 arrays — never a shape — steady-state traffic re-runs the warmed programs
@@ -77,11 +80,13 @@ class Request:
     requeued: int = 0                # replica-failure evictions survived
     requeued_at: Optional[float] = None   # last eviction time (queue spans)
     trace_id: str = ""               # request-scoped trace (utils.tracing)
+    flying: int = 0                  # tokens dispatched and not yet read
 
     @property
     def next_pos(self) -> int:
-        """KV position the pending (last generated) token will occupy."""
-        return len(self.prompt) + len(self.generated) - 1
+        """KV position the pending token will occupy: the last one
+        generated, or chosen on the device by the call in flight."""
+        return len(self.prompt) + len(self.generated) + self.flying - 1
 
     @property
     def ttft(self) -> Optional[float]:
@@ -115,6 +120,7 @@ class Scheduler:
         self.failed: List[Request] = []
         self.requeued_total = 0
         self._decode_calls = 0
+        self._flying = None              # (lanes, t0) of the call in flight
         self._moe_load = None            # last ServeEngine.moe_load() snapshot
         self._slo = None                 # diagnostics.SLOEngine, if attached
         self._sched_trace = _tracing.new_trace("sched")
@@ -152,7 +158,8 @@ class Scheduler:
 
     @property
     def done(self) -> bool:
-        return not self._queue and self.in_flight == 0
+        return (not self._queue and self.in_flight == 0
+                and self._flying is None)
 
     def live_replicas(self) -> List[int]:
         return [r for r in range(self.replicas) if r not in self._dead]
@@ -189,6 +196,10 @@ class Scheduler:
         if park:
             self._parked.add(replica)
         lost = list(self._active[replica].values())
+        if self._flying is not None:
+            # what the call in flight chose for these lanes is dropped
+            # with their KV: the slots may have new owners when it is read
+            self._flying[0][replica] = []
         for req in lost:
             self._alloc[replica].free(req.slot)
             if req.prefix_row >= 0 and self._prefix[replica] is not None:
@@ -197,6 +208,7 @@ class Scheduler:
             req.replica = req.slot = req.prefix_row = -1
             req.prefix_len = 0
             req.generated.clear()          # KV died with the replica
+            req.flying = 0
             req.first_token_at = None
             req.requeued += 1
             req.requeued_at = time.monotonic()
@@ -266,8 +278,23 @@ class Scheduler:
         return _tracing.stage(self._sched_trace, name, cat="serve", **attrs)
 
     def step(self) -> List[Request]:
-        """One admit → decode → retire cycle; returns requests retired
-        this cycle."""
+        """One admit → decode → deliver cycle; returns requests retired
+        this cycle.
+
+        The decode runs ONE CALL AHEAD of the host: a request retires by
+        length alone, so which lanes ride the next call and where they
+        stand is known before the call in flight has been read, and the
+        token such a lane feeds on goes from that call's output into the
+        next call's input on the device.  So this step's call is packed
+        and dispatched FIRST, then the call dispatched a step earlier is
+        read back and its tokens delivered: a token reaches its request
+        one call late, and the host's part of a step runs while the device
+        is busy.  A prefill stays synchronous: it queues behind the call
+        in flight, and its first token is appended before this step's call
+        is packed.  With ``spec_decode`` a round's accepted counts are
+        data, so nothing runs ahead: the round is read back and delivered
+        in its own step.
+        """
         with self._stage("step"):
             with self._stage("admit"):
                 self._admit()
@@ -285,7 +312,8 @@ class Scheduler:
         self._slo = engine
 
     def drain(self, max_steps: int = 10_000) -> None:
-        """Run until every submitted request reaches a terminal state."""
+        """Run until every submitted request reaches a terminal state
+        (and no decode call is in flight)."""
         for _ in range(max_steps):
             if self.done:
                 return
@@ -396,11 +424,23 @@ class Scheduler:
             self._last_ids[target] = (self._last_ids[target] + [req.id])[-8:]
             self._maybe_retire(req)
 
+    def _rides(self, req: Request) -> bool:
+        """Whether ``req`` takes a lane of the next fused call: it appends
+        at next_pos .. next_pos + window - 1, all of which must fit under
+        the per-slot capacity (the window is a speculative round's k + 1
+        when spec decode is armed), and tokens in flight count as
+        generated."""
+        return (len(req.generated) + req.flying < req.max_new_tokens
+                and req.next_pos + self.engine.scfg.decode_window
+                <= self.engine.scfg.max_len)
+
     def _decode_once(self) -> List[Request]:
-        lanes = [sorted(self._active[r]) for r in range(self.replicas)]
+        lanes = [[(slot, req) for slot, req in sorted(self._active[r].items())
+                  if self._rides(req)] for r in range(self.replicas)]
         busiest = max((len(l) for l in lanes), default=0)
         if busiest == 0:
-            return []
+            # every live lane's last token is in flight, or none is live
+            return self._settle()
         scfg = self.engine.scfg
         S = scfg.batch_bucket_for(busiest)
         R = self.replicas
@@ -412,9 +452,10 @@ class Scheduler:
             prows = np.full((R, S), idle_slot, np.int32)
             plens = np.zeros((R, S), np.int32)
             for r in range(R):
-                for i, slot in enumerate(lanes[r]):
-                    req = self._active[r][slot]
-                    toks[r, i] = req.generated[-1]
+                for i, (slot, req) in enumerate(lanes[r]):
+                    # the token the call in flight chooses for the slot
+                    # stays on the device: the host has not read it yet
+                    toks[r, i] = -1 if req.flying else req.generated[-1]
                     slots[r, i] = slot
                     lens[r, i] = req.next_pos
                     if req.prefix_row >= 0:
@@ -422,21 +463,52 @@ class Scheduler:
                         plens[r, i] = req.prefix_len
             pargs = (prows, plens) if self._prefix[0] is not None else (
                 None, None)
-        t0 = time.monotonic()
         if scfg.spec_decode:
+            # a round's accepted counts are data: where its lanes stand
+            # next is unknown until it is read, so nothing runs ahead
+            t0 = time.monotonic()
             emitted, counts = self.engine.spec_decode(toks, slots, lens,
                                                       *pargs)
+            dt = time.monotonic() - t0
             gen_tokens = lambda r, i: \
                 [int(t) for t in emitted[r, i, :counts[r, i]]]
-            steps = int(counts.max())
-        else:
-            gen = self.engine.decode(toks, slots, lens, *pargs)
-            steps = gen.shape[1]                          # [R, steps, S]
-            gen_tokens = lambda r, i: [int(t) for t in gen[r, :, i]]
-            counts = None
-        dt = time.monotonic() - t0
+            with self._stage("deliver"):
+                return self._deliver(lanes, gen_tokens, counts,
+                                     int(counts.max()), t0, dt)
+        gen = self.engine.decode(toks, slots, lens, *pargs, ahead=True)
+        return self._deliver_decode(gen, lanes)
+
+    def _settle(self) -> List[Request]:
+        """Read back and deliver the call in flight, if any, with nothing
+        dispatched behind it."""
+        if self._flying is None:
+            return []
+        return self._deliver_decode(self.engine.decode_drain(), None)
+
+    def _deliver_decode(self, gen, lanes) -> List[Request]:
+        """Deliver the call that was in flight, if any, whose tokens
+        ``gen`` ``[R, steps, S]`` the engine just handed back, and note
+        ``lanes`` (None: nothing was dispatched) as the call in flight
+        now.  A call's clock runs from when the one ahead of it was read
+        to when it is: its device time and whatever the host put in
+        between."""
+        now = time.monotonic()
+        due, self._flying = self._flying, (
+            None if lanes is None else (lanes, now))
+        steps = self.engine.scfg.decode_steps_per_call
+        for mine in lanes or ():
+            for _, req in mine:
+                req.flying += steps
+        if due is None:
+            return []
+        lanes, t0 = due
+        for mine in lanes:
+            for _, req in mine:
+                req.flying -= steps
+        gen_tokens = lambda r, i: [int(t) for t in gen[r, :, i]]
         with self._stage("deliver"):
-            return self._deliver(lanes, gen_tokens, counts, steps, t0, dt)
+            return self._deliver(lanes, gen_tokens, None, gen.shape[1], t0,
+                                 now - t0)
 
     def _deliver(self, lanes, gen_tokens, counts, steps, t0, dt
                  ) -> List[Request]:
@@ -449,8 +521,7 @@ class Scheduler:
         n_tokens = 0
         retired: List[Request] = []
         for r in range(self.replicas):
-            for i, slot in enumerate(lanes[r]):
-                req = self._active[r][slot]
+            for i, (_, req) in enumerate(lanes[r]):
                 room = req.max_new_tokens - len(req.generated)
                 new = gen_tokens(r, i)[:room]
                 req.generated.extend(new)
@@ -525,12 +596,7 @@ class Scheduler:
         return int((float(frac.max()) - 1.0 / len(frac)) * 8)
 
     def _maybe_retire(self, req: Request) -> bool:
-        # the next fused call appends at next_pos .. next_pos + window - 1,
-        # all of which must fit under the per-slot capacity (the window is
-        # a speculative round's k + 1 when spec decode is armed)
-        window = self.engine.scfg.decode_window
-        if (len(req.generated) < req.max_new_tokens
-                and req.next_pos + window <= self.engine.scfg.max_len):
+        if req.flying or self._rides(req):
             return False
         req.state = "done"
         req.finished_at = time.monotonic()
@@ -602,6 +668,9 @@ class Scheduler:
         return block
 
     def close(self) -> None:
+        """Stop scheduling: the call in flight, if any, is read back and
+        delivered, so the engine is left with nothing in flight."""
+        self._settle()
         _flight.unregister_block("serve")
 
 

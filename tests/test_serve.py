@@ -802,14 +802,15 @@ print(json.dumps({"mha": max_diff(None), "gqa": max_diff(2)}))
 
 def test_engine_call_crosses_the_host_boundary_once_each_way(engine,
                                                             tmp_path):
-    """Warm, a scheduler step stages ONE array for its decode call and
-    reads ONE back, and an admission's prefill the same: the lanes'
-    sampler keys stay on the device, the admitted slot's key is made
-    inside the prefill program (no eager ``jax.random`` program, no
-    ``seed_slot`` stage), and the prefill's logits cross only when
-    somebody converts them."""
+    """Warm, a scheduler's decode call stages ONE array and ONE is read
+    back for it (a step later: the call runs ahead), and an admission's
+    prefill the same: the lanes' sampler keys and pending tokens stay on
+    the device, the admitted slot's key is made inside the prefill program
+    (no eager ``jax.random`` program, no ``seed_slot`` stage), and the
+    prefill's logits cross only when somebody converts them."""
     eng = engine
     moved = bfm.counter("bluefog_serve_host_arrays_total")
+    dispatched = bfm.counter("bluefog_serve_decode_calls_total")
 
     def crossings():
         return {(p, d): moved.value(program=p, direction=d)
@@ -818,7 +819,7 @@ def test_engine_call_crosses_the_host_boundary_once_each_way(engine,
     rng = np.random.default_rng(5)
     reqs = [sched.submit(rng.integers(0, _CFG["vocab"], n).tolist(),
                          max_new_tokens=4) for n in (3, 8, 5, 2, 6)]
-    before, steps = crossings(), 0
+    before, steps, calls = crossings(), 0, dispatched.total()
     jax.profiler.start_trace(str(tmp_path))
     try:
         while not sched.done:
@@ -830,8 +831,10 @@ def test_engine_call_crosses_the_host_boundary_once_each_way(engine,
     sched.close()
     assert all(len(r.generated) == 4 for r in reqs)
     after = crossings()
+    calls = dispatched.total() - calls
+    assert 0 < calls < steps        # the last step only reads a call back
     assert {k: after[k] - before[k] for k in after} == {
-        ("decode", "in"): steps, ("decode", "out"): steps,
+        ("decode", "in"): calls, ("decode", "out"): calls,
         ("prefill", "in"): len(reqs), ("prefill", "out"): len(reqs)}
 
     # the logits a prefill returns are a value on the device
@@ -852,7 +855,8 @@ def test_engine_call_crosses_the_host_boundary_once_each_way(engine,
     programs = {e[0] for e in ev if e[0].startswith("PjitFunction(")
                 and any(s[1] <= e[1] and e[2] <= s[2] for s in in_step)}
     assert programs == {"PjitFunction(_decode_body)",
-                        "PjitFunction(_prefill_body)"}
+                        "PjitFunction(_prefill_body)",
+                        "PjitFunction(_feed_body)"}
     assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
 
 
@@ -963,20 +967,27 @@ def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     sched.close()
 
     # the stage spans, unarmed, in the profiler's trace: step > decode_call
-    # > stage_in, dispatch, collect; step > admit > prefill > prefill_call
+    # > stage_in, dispatch, collect; step > admit > prefill > prefill_call.
+    # The decode runs one call ahead: a step in which every live lane's
+    # last token is in flight only reads (decode_drain), and the call
+    # after it, like the first, finds nothing in flight
     ev = bf_events(tmp_path)
     steps = [e for e in ev if e[0] == "bf:serve.step"]
     assert len(steps) == guard
     assert steps[0][3] == {}
     calls = inside(ev, "bf:engine.decode_call", "bf:serve.step")
-    assert len(calls) == guard
+    drains = inside(ev, "bf:engine.decode_drain", "bf:serve.step")
+    assert drains and len(calls) + len(drains) == guard
     # a decode token is written once per lane and tensor after each stage's
     # layer loop (pp = 2 hops), whatever the number of layers
-    assert all(e[3] == {"S": e[3]["S"], "cache_writes": e[3]["S"] * 2 * 2}
-               for e in calls)
+    assert all(e[3] == {"S": e[3]["S"], "cache_writes": e[3]["S"] * 2 * 2,
+                        "ahead": e[3]["ahead"]} for e in calls)
+    assert [e[3]["ahead"] for e in calls].count(0) == len(drains)
     for name in ("stage_in", "dispatch", "collect"):
         assert len(inside(ev, "bf:engine." + name,
-                          "bf:engine.decode_call")) == guard, name
+                          "bf:engine.decode_call")) == len(calls), name
+    assert len(inside(ev, "bf:engine.collect",
+                      "bf:engine.decode_drain")) == len(drains)
     prefills = inside(ev, "bf:serve.prefill", "bf:serve.admit")
     assert sorted(e[3]["prompt_len"] for e in prefills) == sorted(
         len(r.prompt) for r in reqs)
@@ -989,7 +1000,7 @@ def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     assert [e for e in ev if e[0] == "bf:engine.seed_slot"] == []
     packs = inside(ev, "bf:serve.pack", "bf:serve.step")
     assert len(packs) == len(inside(ev, "bf:serve.deliver",
-                                    "bf:serve.step")) == guard
+                                    "bf:serve.step")) == len(calls)
     assert all(1 <= e[3]["lanes"] <= e[3]["S"] for e in packs)
     assert len(inside(ev, "bf:train.dispatch", "bf:train.train_step")) == 4
 

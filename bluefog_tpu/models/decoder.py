@@ -24,13 +24,21 @@ separate ``wq``/``wk``/``wv`` with fewer keys than queries, RMSNorm over
 each head of q and k, rotary on the window layers only, each sublayer's
 OUTPUT normed before it is added; a window layer's whole-sequence
 attention is a band of blocks (:func:`window_attention`).
+Fourth, the **single-mixer block** (:class:`SsmConfig`,
+:func:`mixer_block`): every layer is ONE mixer behind one RMSNorm, by a
+static plan a Mamba-2 state-space mixer (:func:`mamba_project`,
+:func:`mamba_conv`, :func:`mamba_scan_chunked` for a prompt and
+:func:`mamba_step` for a decode token, :func:`mamba_gate_out`), grouped-query
+attention that turns nothing (:func:`gqa_project`), or ``relu^2`` experts
+in a latent (:func:`bluefog_tpu.moe.layers.held_moe_ffn`).
 Each block opens the device scopes of its parts (``attn.project`` or
-``mla.project``, ``attn.window`` / ``attn.full``, ``ffn``; the read-outs
-``readout``): plain ``jax.named_scope``s, metadata that changes no
+``mla.project``, ``attn.window`` / ``attn.full``, ``ffn``, ``ssm.project``
+/ ``ssm.conv`` / ``ssm.scan``; the read-outs ``readout``): plain ``jax.named_scope``s, metadata that changes no
 instruction and lets ``utils.tracing.device_scopes`` say which compiled
 instruction belongs to which part.
 Imports jax only: the callers import this module, never the reverse.
 """
+import contextlib
 import dataclasses
 import math
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
@@ -734,5 +742,371 @@ def hybrid_param_shapes(cfg: HybridConfig) -> Dict[str, Any]:
 def hybrid_param_count(cfg: HybridConfig) -> int:
     """Parameters this chip holds (:func:`hybrid_param_shapes`)."""
     shapes = hybrid_param_shapes(cfg)
+    return sum(math.prod(s) for group in shapes["layers"] + (shapes["shared"],)
+               for s in group.values())
+
+
+# ---------------------------------------------------------------------------
+# The single-mixer block: every layer ONE mixer behind one RMSNorm, by a
+# static plan a Mamba-2 mixer, attention that turns nothing, or relu^2
+# experts in a latent
+# ---------------------------------------------------------------------------
+
+MIXER_KINDS = ("ssm", "full", "experts")
+# leaves kept in float32 whatever the served dtype: the router and its
+# selection bias (near-ties), and what the scan's exponents are made of
+FLOAT32_LEAVES = frozenset(("wr", "eb", "dt_bias", "A_log", "Dskip"))
+
+
+@dataclasses.dataclass(frozen=True)
+class SsmConfig:
+    """Sizes of a decoder whose every layer is one mixer, ``x += mixer(
+    RMS(x))``, as ONE chip of an expert-parallel deployment holds it.
+    ``plan`` names each layer's mixer: ``"ssm"`` a Mamba-2 mixer
+    (``ssm_heads`` heads of ``ssm_head_dim`` channels, ``B`` and ``C`` in
+    ``ssm_groups`` groups of ``ssm_state``, a causal depthwise convolution
+    of ``conv_kernel`` taps, prompts scanned in chunks of ``chunk``: per
+    sequence a recurrent state ``[ssm_heads, ssm_head_dim, ssm_state]`` and
+    the convolution's last ``conv_kernel - 1`` inputs, no positions),
+    ``"full"`` causal attention of ``heads`` queries on ``kv_heads`` keys
+    and values with no position signal (the state-space layers carry the
+    order), ``"experts"`` the sigmoid-routed layer whose ``relu^2`` experts
+    of width ``expert_ffn`` read and write a ``latent``-wide projection of
+    the hidden state, beside a shared expert of width ``shared_ffn`` on the
+    hidden state itself (the held-experts contract of
+    :class:`LatentConfig`)."""
+    vocab: int
+    d_model: int
+    plan: Tuple[str, ...]
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_groups: int
+    ssm_state: int
+    heads: int
+    kv_heads: int
+    head_dim: int
+    latent: int
+    expert_ffn: int
+    shared_ffn: int
+    num_experts: int                # the router's outputs
+    held_experts: int
+    top_k: int
+    route_scale: float
+    held_start: int = 0
+    route_bias: bool = True         # select by score + e_bias
+    conv_kernel: int = 4
+    chunk: int = 128
+    eps: float = 1e-5               # every layer's RMSNorm and the final one
+    ssm_eps: float = 1e-5           # the gated norm inside a Mamba mixer
+    # no fields: this router has no group step (held_moe_ffn reads them)
+    n_group = 1
+    topk_group = 1
+
+    @property
+    def layers(self) -> int:
+        return len(self.plan)
+
+    @property
+    def expert_layers(self) -> int:
+        return self.layers_of("experts")
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C side by side."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    def layers_of(self, kind: str) -> int:
+        return sum(k == kind for k in self.plan)
+
+    def index_in_kind(self, layer: int) -> int:
+        """Which of its kind's layers ``layer`` is: its place in that
+        kind's part of the cache."""
+        return sum(k == self.plan[layer] for k in self.plan[:layer])
+
+    def validate(self, m: Any) -> None:
+        for name in ("vocab", "d_model", "ssm_heads", "ssm_head_dim",
+                     "ssm_groups", "ssm_state", "heads", "kv_heads",
+                     "head_dim", "latent", "expert_ffn", "shared_ffn",
+                     "num_experts", "held_experts", "top_k", "chunk"):
+            if getattr(self, name) < 1:
+                raise ValueError(f"SsmConfig.{name} must be >= 1")
+        if not self.plan or any(k not in MIXER_KINDS for k in self.plan):
+            raise ValueError(
+                f"ssm_layer_plan: every layer is one mixer of {MIXER_KINDS}, "
+                f"got {self.plan!r}")
+        if self.ssm_heads % self.ssm_groups:
+            raise ValueError(
+                f"ssm_head_groups: {self.ssm_heads} state-space heads do not "
+                f"share {self.ssm_groups} groups of B and C evenly")
+        if self.conv_kernel < 2:
+            raise ValueError(
+                f"ssm_conv_kernel: a convolution of {self.conv_kernel} tap "
+                "keeps no input between steps")
+        if self.heads % self.kv_heads:
+            raise ValueError(
+                f"ssm_grouped_heads: {self.heads} query heads do not share "
+                f"{self.kv_heads} key-value heads evenly")
+        if self.top_k > self.num_experts:
+            raise ValueError(
+                f"ssm_router_top_k: {self.top_k} experts a token of the "
+                f"router's {self.num_experts}")
+        if not 0 <= self.held_start <= self.num_experts - self.held_experts:
+            raise ValueError(
+                f"ssm_held_experts: experts {self.held_start}.."
+                f"{self.held_start + self.held_experts - 1} are not among "
+                f"the router's {self.num_experts}")
+
+
+def relu2_ffn(h: jax.Array, w1: jax.Array, w2: jax.Array) -> jax.Array:
+    """``relu(h w1)^2 w2``: no gate."""
+    return jnp.square(jax.nn.relu(h @ w1)) @ w2
+
+
+def mamba_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """The Mamba mixer's one input projection of the normed activation
+    ``h`` ``[..., D]``, split: ``(z [..., d_inner]`` the gate, ``xBC [...,
+    conv_dim]`` what the convolution runs over, ``dt [..., ssm_heads]`` the
+    raw step sizes)."""
+    with jax.named_scope("ssm.project"):
+        u = h @ lp["w_in"]
+        d, c = cfg.d_inner, cfg.conv_dim
+        return u[..., :d], u[..., d:d + c], u[..., d + c:]
+
+
+def _silu_conv(cfg: SsmConfig, lp: Dict[str, jax.Array], taps):
+    """``silu(b_conv + sum_j w_conv[:, j] taps[j])`` in float32; ``taps``
+    oldest first, the current input last."""
+    w = lp["w_conv"].astype(jnp.float32)
+    acc = lp["b_conv"].astype(jnp.float32)
+    for j, tap in enumerate(taps):
+        acc = acc + w[:, j] * tap.astype(jnp.float32)
+    return jax.nn.silu(acc)
+
+
+def mamba_conv(cfg: SsmConfig, lp: Dict[str, jax.Array], xbc: jax.Array,
+               prev: Optional[jax.Array] = None,
+               true_len: Optional[jax.Array] = None):
+    """The causal depthwise convolution over time (``conv_kernel`` taps,
+    ``w_conv`` ``[conv_dim, taps]`` with the current input under the LAST
+    tap, bias ``b_conv``) and the SiLU behind it.  A prompt (``prev``
+    None): ``xbc`` ``[T, conv_dim]`` with zeros before it; returns the
+    convolved sequence and the ``taps - 1`` raw inputs before ``true_len``
+    (zeros where the prompt is shorter), what a decode step needs of it.
+    A decode step: ``xbc`` ``[S, conv_dim]`` behind each lane's kept inputs
+    ``prev`` ``[S, taps - 1, conv_dim]``; returns the lanes' outputs and
+    their kept inputs moved on by one.  Sums in float32, the result in
+    ``xbc``'s dtype; the kept inputs are raw, in ``xbc``'s dtype."""
+    with jax.named_scope("ssm.conv"):
+        K = cfg.conv_kernel
+        if prev is not None:
+            window = jnp.concatenate([prev.astype(xbc.dtype), xbc[:, None]],
+                                     axis=1)                # [S, K, C]
+            out = _silu_conv(cfg, lp, [window[:, j] for j in range(K)])
+            return out.astype(xbc.dtype), window[:, 1:]
+        T = xbc.shape[0]
+        padded = jnp.concatenate(
+            [jnp.zeros((K - 1,) + xbc.shape[1:], xbc.dtype), xbc])
+        out = _silu_conv(cfg, lp, [padded[j:j + T] for j in range(K)])
+        # padded[i + K - 1] is xbc[i]: the K - 1 inputs before true_len
+        kept = lax.dynamic_slice_in_dim(padded, true_len, K - 1)
+        return out.astype(xbc.dtype), kept
+
+
+def mamba_split(cfg: SsmConfig, xbc: jax.Array):
+    """The convolved channels as ``(x [..., heads, head_dim], B [...,
+    groups, state], C [..., groups, state])``."""
+    lead, d = xbc.shape[:-1], cfg.d_inner
+    gn = cfg.ssm_groups * cfg.ssm_state
+    shape = lead + (cfg.ssm_groups, cfg.ssm_state)
+    return (xbc[..., :d].reshape(lead + (cfg.ssm_heads, cfg.ssm_head_dim)),
+            xbc[..., d:d + gn].reshape(shape),
+            xbc[..., d + gn:].reshape(shape))
+
+
+def mamba_discretize(lp: Dict[str, jax.Array], x: jax.Array, dt: jax.Array,
+                     live: Optional[jax.Array] = None):
+    """Per head the step ``delta = softplus(dt + dt_bias)`` (no clamp), its
+    log decay ``delta * A`` with ``A = -exp(A_log)`` (so ``<= 0``) and the
+    input it lets in, ``delta * x``: ``(log_a [..., H], dx [..., H, P])``,
+    float32.  Where ``live`` is false the step is 0: the state passes such
+    a position unchanged (a prompt's padding)."""
+    f32 = jnp.float32
+    delta = jax.nn.softplus(dt.astype(f32) + lp["dt_bias"].astype(f32))
+    if live is not None:
+        delta = jnp.where(live[..., None], delta, 0.0)
+    return (delta * -jnp.exp(lp["A_log"].astype(f32)),
+            delta[..., None] * x.astype(f32))
+
+
+def _per_head(cfg: SsmConfig, t: jax.Array, axis: int) -> jax.Array:
+    """``t`` with its ``heads`` axis split ``[groups, heads per group]``."""
+    return t.reshape(t.shape[:axis] + (cfg.ssm_groups, -1)
+                     + t.shape[axis + 1:])
+
+
+def mamba_scan_chunked(cfg: SsmConfig, lp: Dict[str, jax.Array],
+                       x: jax.Array, B: jax.Array, C: jax.Array,
+                       dt: jax.Array, true_len: jax.Array):
+    """The state-space recurrence ``S_t = a_t S_{t-1} + delta_t x_t (x)
+    B_t``, ``y_t = S_t C_t + Dskip x_t`` (``S_{-1} = 0``) over one prompt
+    ``x`` ``[T, heads, head_dim]``, ``B``/``C`` ``[T, groups, state]``,
+    ``dt`` ``[T, heads]``, in chunks of ``cfg.chunk`` positions: within a
+    chunk the masked product ``(C B^T o decay) (delta x)``, between chunks
+    the carried state.  Every exponent is a difference of cumulative
+    ``delta A`` that is ``<= 0``; everything in float32, the four matrix
+    products at the backend's default matmul precision (a TPU rounds their
+    operands to bfloat16 and accumulates in float32: the state then lies
+    0.002 of its norm from the token-by-token recurrence).  Positions from
+    ``true_len`` on take a step of 0, so the returned state ``[heads,
+    head_dim, state]`` is the one after the last REAL token.  Returns
+    ``(y [T, heads, head_dim]`` in ``x``'s dtype, state float32)."""
+    with jax.named_scope("ssm.scan"):
+        T, H, P = x.shape
+        G, N, Q = cfg.ssm_groups, cfg.ssm_state, cfg.chunk
+        pad = (-T) % Q
+        if pad:                         # behind every real position
+            x, B, C, dt = (jnp.pad(a, ((0, pad),) + ((0, 0),) * (a.ndim - 1))
+                           for a in (x, B, C, dt))
+        nc = (T + pad) // Q
+        live = jnp.arange(T + pad) < jnp.minimum(true_len, T)
+        log_a, dx = mamba_discretize(lp, x, dt, live)
+        dx = _per_head(cfg, dx, 1).reshape(nc, Q, G, H // G, P)
+        cum = jnp.cumsum(_per_head(cfg, log_a, 1).reshape(
+            nc, Q, G, H // G), axis=1)                      # inclusive
+        Bc = B.astype(jnp.float32).reshape(nc, Q, G, N)
+        Cc = C.astype(jnp.float32).reshape(nc, Q, G, N)
+        # within a chunk: s <= t
+        seen = jnp.arange(Q)[:, None] >= jnp.arange(Q)[None, :]
+        seen = seen[None, :, :, None, None]
+        decay = jnp.exp(jnp.where(seen, cum[:, :, None] - cum[:, None], 0.0))
+        scores = jnp.einsum("ctgn,csgn->ctsg", Cc, Bc)
+        y = jnp.einsum("ctsgr,csgrp->ctgrp",
+                       jnp.where(seen, decay, 0.0) * scores[..., None], dx)
+        # what each chunk adds to the state by its end, and the state each
+        # chunk starts from
+        to_end = jnp.exp(cum[:, -1:] - cum)                 # [nc, Q, G, R]
+        local = jnp.einsum("csgr,csgrp,csgn->cgrpn", to_end, dx, Bc)
+
+        def carry(S, chunk):
+            total, add = chunk
+            return jnp.exp(total)[..., None, None] * S + add, S
+        S, starts = lax.scan(
+            carry, jnp.zeros((G, H // G, P, N), jnp.float32),
+            (cum[:, -1], local))
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            "ctgn,cgrpn->ctgrp", Cc, starts)
+        y = y.reshape(T + pad, H, P)[:T] \
+            + lp["Dskip"].astype(jnp.float32)[:, None] * x[:T].astype(
+                jnp.float32)
+        return y.astype(x.dtype), S.reshape(H, P, N)
+
+
+def mamba_step(cfg: SsmConfig, state: jax.Array, log_a: jax.Array,
+               dx: jax.Array, B: jax.Array, C: jax.Array):
+    """One step of the recurrence for a batch of states ``[R, heads,
+    head_dim, state]`` (float32): ``S <- exp(log_a) S + dx (x) B``, ``y = S
+    C`` with ``log_a`` ``[R, heads]`` and ``dx`` ``[R, heads, head_dim]``
+    from :func:`mamba_discretize` and ``B``/``C`` ``[R, groups, state]``.
+    A batch entry with ``log_a = 0`` and ``dx = 0`` keeps its state as it
+    is.  Returns ``(y [R, heads, head_dim] float32, the new states)``; the
+    skip ``Dskip x`` is the caller's to add."""
+    of_head = lambda t: jnp.repeat(t.astype(jnp.float32),
+                                   cfg.ssm_heads // cfg.ssm_groups, axis=1)
+    new = jnp.exp(log_a)[..., None, None] * state \
+        + dx[..., None] * of_head(B)[:, :, None, :]
+    return jnp.sum(new * of_head(C)[:, :, None, :], axis=-1), new
+
+
+def mamba_gate_out(cfg: SsmConfig, lp: Dict[str, jax.Array], y: jax.Array,
+                   z: jax.Array) -> jax.Array:
+    """What leaves the Mamba mixer: ``y`` ``[..., heads, head_dim]`` gated
+    by ``silu(z)`` FIRST, then RMS-normed per group of ``d_inner /
+    ssm_groups`` channels under the scale ``g_y``, then ``w_out``."""
+    with jax.named_scope("ssm.project"):
+        lead = z.shape[:-1]
+        v = y.reshape(lead + (-1,)).astype(jnp.float32) \
+            * jax.nn.silu(z.astype(jnp.float32))
+        v = v.reshape(lead + (cfg.ssm_groups, -1))
+        v = v * lax.rsqrt(jnp.mean(v * v, -1, keepdims=True) + cfg.ssm_eps)
+        v = v.reshape(lead + (-1,)) * lp["g_y"].astype(jnp.float32)
+        return v.astype(z.dtype) @ lp["w_out"]
+
+
+def gqa_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """The attention mixer's projections of the normed activation ``h``:
+    ``(q [..., heads, head_dim], k, v [..., kv_heads, head_dim])``; nothing
+    is normed and nothing is turned."""
+    with jax.named_scope("attn.project"):
+        lead = h.shape[:-1]
+        return ((h @ lp["wq"]).reshape(lead + (cfg.heads, cfg.head_dim)),
+                (h @ lp["wk"]).reshape(lead + (cfg.kv_heads, cfg.head_dim)),
+                (h @ lp["wv"]).reshape(lead + (cfg.kv_heads, cfg.head_dim)))
+
+
+# per kind of layer: the device scope round the whole layer (its mixer's
+# own scopes lie inside; a Mamba mixer's parts name themselves), and the
+# one the layer's norm and residual run under
+_MIXER_SCOPES = {"ssm": (None, "ssm.project"),
+                 "full": ("attn.full", "attn.project"),
+                 "experts": ("ffn", None)}
+
+
+def _scoped(name: Optional[str]):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def mixer_block(cfg: SsmConfig, lp: Dict[str, jax.Array], x: jax.Array,
+                kind: str, mix: Callable) -> Tuple[jax.Array, Any]:
+    """One layer on ``x`` ``[..., D]``: ``x + mix(RMS(x; g))``.  ``mix(h)
+    -> (y, aux)`` is the layer's one mixer on the normed activation, built
+    by the caller from this file's parts (a Mamba mixer over a prompt or a
+    state, attention over a sequence or a cache, the expert layer); the
+    layer runs under the ``kind``'s own device scopes."""
+    whole, own = _MIXER_SCOPES[kind]
+    with _scoped(whole):
+        with _scoped(own):
+            h = rms_norm(x, lp["g"], cfg.eps)
+        y, aux = mix(h)
+        with _scoped(own):
+            return x + y, aux
+
+
+def ssm_param_shapes(cfg: SsmConfig) -> Dict[str, Any]:
+    """The single-mixer model's parameter tree as shapes: ``layers``, one
+    dict of leaves per layer of the plan (nothing stacked), and ``shared``.
+    ``g`` is each layer's RMSNorm scale; :data:`FLOAT32_LEAVES` are kept in
+    float32."""
+    D, H = cfg.d_model, cfg.ssm_heads
+    F, Eh, La = cfg.expert_ffn, cfg.held_experts, cfg.latent
+    kinds = {
+        "ssm": {"g": (D,),
+                "w_in": (D, 2 * cfg.d_inner
+                         + 2 * cfg.ssm_groups * cfg.ssm_state + H),
+                "w_conv": (cfg.conv_dim, cfg.conv_kernel),
+                "b_conv": (cfg.conv_dim,), "dt_bias": (H,), "A_log": (H,),
+                "Dskip": (H,), "g_y": (cfg.d_inner,),
+                "w_out": (cfg.d_inner, D)},
+        "full": {"g": (D,), "wq": (D, cfg.heads * cfg.head_dim),
+                 "wk": (D, cfg.kv_heads * cfg.head_dim),
+                 "wv": (D, cfg.kv_heads * cfg.head_dim),
+                 "wo": (cfg.heads * cfg.head_dim, D)},
+        "experts": {"g": (D,), "wr": (D, cfg.num_experts),
+                    "wdn": (D, La), "wup": (La, D),
+                    "we1": (Eh, La, F), "we2": (Eh, F, La),
+                    "ws1": (D, cfg.shared_ffn), "ws2": (cfg.shared_ffn, D)}}
+    if cfg.route_bias:
+        kinds["experts"]["eb"] = (cfg.num_experts,)
+    return {"layers": tuple(dict(kinds[k]) for k in cfg.plan),
+            "shared": {"embed": (cfg.vocab, D), "head": (D, cfg.vocab),
+                       "gf": (D,)}}
+
+
+def ssm_param_count(cfg: SsmConfig) -> int:
+    """Parameters this chip holds (:func:`ssm_param_shapes`)."""
+    shapes = ssm_param_shapes(cfg)
     return sum(math.prod(s) for group in shapes["layers"] + (shapes["shared"],)
                for s in group.values())

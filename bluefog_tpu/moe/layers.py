@@ -38,12 +38,12 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.decoder import gated_ffn
+from ..models.decoder import gated_ffn, relu2_ffn
 from ..parallel.expert import moe_apply_dropless, moe_combine, moe_dispatch
 from .dropless import grouped_ffn
 
 __all__ = ["router_topk", "router_sigmoid_grouped", "held_expert_ffn",
-           "held_expert_ffn_grouped", "held_moe_ffn",
+           "held_expert_ffn_grouped", "held_moe_ffn", "EXPERT_FORMS",
            "router_expert_choice", "moe_ffn_routed",
            "moe_ffn_dropless", "moe_dropless_combine",
            "moe_ffn_expert_choice", "moe_ffn_dense", "moe_ffn_dense_ec"]
@@ -103,6 +103,22 @@ def router_sigmoid_grouped(x: jax.Array, wr: jax.Array, *, top_k: int,
         return s, idx, route_scale * top / jnp.sum(top, -1, keepdims=True)
 
 
+# what one expert computes: ``(silu(x wg) * (x wu)) wd``, or ``relu(x wg)^2
+# wd`` with no gate (``wu`` is then None)
+EXPERT_FORMS = ("gated_silu", "relu2")
+
+
+def _expert_act(form: str, up: jax.Array, gate_of) -> jax.Array:
+    """An expert's activation from its first product ``up``: times the
+    gate's product (``gate_of()``, made only where the form has a gate)."""
+    if form not in EXPERT_FORMS:
+        raise ValueError(f"an expert's form is one of {EXPERT_FORMS}, got "
+                         f"{form!r}")
+    if form == "gated_silu":
+        return jax.nn.silu(up) * gate_of()
+    return jnp.square(jax.nn.relu(up))
+
+
 def _held(idx: jax.Array, held_start: int, held_experts: int):
     """``idx`` ``[T, k]`` over all the router's experts as flat indices
     into this chip's experts, and which pairs fell on one of them."""
@@ -111,12 +127,13 @@ def _held(idx: jax.Array, held_start: int, held_experts: int):
 
 
 def held_expert_ffn(x: jax.Array, idx: jax.Array, weight: jax.Array,
-                    wg: jax.Array, wu: jax.Array, wd: jax.Array, *,
-                    held_start: int):
+                    wg: jax.Array, wu: jax.Array | None, wd: jax.Array, *,
+                    held_start: int, form: str = "gated_silu"):
     """What THIS chip's experts add for tokens ``x`` ``[T, D]`` routed as
     ``idx`` / ``weight`` ``[T, k]`` over all the router's experts: the
     chip holds the ``wg.shape[0]`` experts from ``held_start`` (gated
-    SiLU FFNs, ``wg``/``wu`` ``[Eh, D, F]``, ``wd`` ``[Eh, F, D]``) and
+    SiLU FFNs, ``wg``/``wu`` ``[Eh, D, F]``, ``wd`` ``[Eh, F, D]``; or, in
+    the ``"relu2"`` ``form``, ``relu(x wg)^2 wd`` with no ``wu``) and
     computes ``sum_{e selected and held} weight_e * expert_e(x)``.  What
     the absent experts would add is left out: on one chip of an
     expert-parallel deployment this is the layer without its exchange.
@@ -134,16 +151,17 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weight: jax.Array,
         gate = jnp.sum(jnp.where(
             local[:, None] == jnp.arange(Eh), weight.reshape(T * k, 1),
             0).reshape(T, k, Eh), axis=1)                      # [T, Eh]
-        act = jax.nn.silu(jnp.einsum("td,edf->etf", x, wg)) \
-            * jnp.einsum("td,edf->etf", x, wu)
+        act = _expert_act(form, jnp.einsum("td,edf->etf", x, wg),
+                          lambda: jnp.einsum("td,edf->etf", x, wu))
         y = jnp.einsum("etf,efd,te->td", act, wd, gate.astype(x.dtype),
                        preferred_element_type=jnp.float32)
         return y.astype(x.dtype), jnp.sum(held.astype(jnp.int32))
 
 
 def held_expert_ffn_grouped(x: jax.Array, idx: jax.Array, weight: jax.Array,
-                            wg: jax.Array, wu: jax.Array, wd: jax.Array,
-                            layer: jax.Array, *, held_start: int):
+                            wg: jax.Array, wu: jax.Array | None,
+                            wd: jax.Array, layer: jax.Array, *,
+                            held_start: int, form: str = "gated_silu"):
     """:func:`held_expert_ffn`'s sum for a prompt's tokens, dropless: the
     token-expert pairs are sorted by expert into one buffer of ``T * k``
     rows, held pairs first in contiguous groups, and the three matmuls
@@ -165,9 +183,10 @@ def held_expert_ffn_grouped(x: jax.Array, idx: jax.Array, weight: jax.Array,
         pairs = jnp.sum(sizes)
         sizes = lax.dynamic_update_slice(
             jnp.zeros((wg.shape[0] * Eh,), jnp.int32), sizes, (layer * Eh,))
-        wg, wu, wd = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wu, wd))
-        act = jax.nn.silu(lax.ragged_dot(rows, wg, sizes)) \
-            * lax.ragged_dot(rows, wu, sizes)
+        wg, wu, wd = (w if w is None else w.reshape((-1,) + w.shape[2:])
+                      for w in (wg, wu, wd))
+        act = _expert_act(form, lax.ragged_dot(rows, wg, sizes),
+                          lambda: lax.ragged_dot(rows, wu, sizes))
         out = lax.ragged_dot(act, wd, sizes)
         w = jnp.where(held, weight.reshape(T * k), 0.0)[order]
         # rows past the held groups hold whatever the kernel left there
@@ -181,7 +200,8 @@ def held_expert_ffn_grouped(x: jax.Array, idx: jax.Array, weight: jax.Array,
 
 def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
                  live: jax.Array | None = None,
-                 layer: jax.Array | None = None):
+                 layer: jax.Array | None = None, *,
+                 form: str = "gated_silu"):
     """One served expert layer's FFN on the normed tokens ``h`` ``[T, D]``
     as the chip that holds ``cfg.held_experts`` experts from
     ``cfg.held_start`` computes it: the full-width router
@@ -194,7 +214,13 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
     and every token goes through every held expert
     (:func:`held_expert_ffn`); with it ``weg``/``weu``/``wed`` are the
     stacks of all expert layers and the pairs go through the grouped
-    kernel (:func:`held_expert_ffn_grouped`).  Returns
+    kernel (:func:`held_expert_ffn_grouped`).  In the ``"relu2"`` ``form``
+    every expert is ``relu(x w1)^2 w2`` with no gate (leaves
+    ``we1``/``we2``, the shared expert ``ws1``/``ws2``), and where the
+    layer has latent projections (``wdn`` ``[D, latent]``, ``wup``
+    ``[latent, D]``) the ROUTED experts read ``h wdn`` and their weighted
+    sum goes through ``wup``, with nothing between a projection and the
+    experts; the router and the shared expert stay on ``h``.  Returns
     ``(y, idx [T, k], weight [T, k])``."""
     _, idx, weight = router_sigmoid_grouped(
         h, lp["wr"], top_k=cfg.top_k, n_group=cfg.n_group,
@@ -202,16 +228,27 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
         bias=lp.get("eb"))
     if live is not None:
         idx = jnp.where(live[:, None], idx, -1)
-    experts = (h, idx, weight.astype(h.dtype), lp["weg"], lp["weu"],
-               lp["wed"])
+    x = h
+    if "wdn" in lp:
+        with jax.named_scope("moe.latent"):
+            x = h @ lp["wdn"]
+    gated = form == "gated_silu"
+    experts = (x, idx, weight.astype(h.dtype)) + (
+        (lp["weg"], lp["weu"], lp["wed"]) if gated
+        else (lp["we1"], None, lp["we2"]))
     if layer is None:
-        y, _ = held_expert_ffn(*experts, held_start=cfg.held_start)
+        y, _ = held_expert_ffn(*experts, held_start=cfg.held_start,
+                               form=form)
     else:
         y, _ = held_expert_ffn_grouped(*experts, layer,
-                                       held_start=cfg.held_start)
+                                       held_start=cfg.held_start, form=form)
+    if "wup" in lp:
+        with jax.named_scope("moe.latent"):
+            y = y @ lp["wup"]
     with jax.named_scope("moe.shared"):
-        return (y + gated_ffn(h, lp["wsg"], lp["wsu"], lp["wsd"]), idx,
-                weight)
+        shared = gated_ffn(h, lp["wsg"], lp["wsu"], lp["wsd"]) if gated \
+            else relu2_ffn(h, lp["ws1"], lp["ws2"])
+        return y + shared, idx, weight
 
 
 def _router_stats(logits, probs, idx, keep, *, num_experts: int,

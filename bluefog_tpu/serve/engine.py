@@ -81,6 +81,17 @@ too, over a cache of two kinds (:class:`.kv_cache.HybridCacheConfig`: a
 slot's rows in the full layers, its rings in the window layers): the plan
 is unrolled, a prompt's attention is blocked, decode reads the cache in
 place, and the same fast paths and carvings are refused by name.
+
+A **state-space model** (:class:`~bluefog_tpu.models.decoder.SsmConfig`:
+every layer ONE mixer, by a static plan a Mamba-2 mixer, attention that
+turns nothing, or ``relu^2`` experts in a latent under the same full-width
+router) is the fourth pair of programs, over a cache with a third kind of
+tensor (:class:`.kv_cache.SsmCacheConfig`): a slot owns, beside its rows in
+the attention layers, a fixed-size recurrent state and the convolution's
+kept inputs in every state-space layer.  A prompt is scanned in chunks and
+overwrites the slot's state whole; a decode step updates every row's state
+where it lies.  The scheduler needs nothing new: a state lives on the
+device between calls, so a call staged one ahead finds it there.
 """
 from __future__ import annotations
 
@@ -466,8 +477,13 @@ class ServeEngine:
     (``first`` / ``blocks`` / ``shared``) and the latent programs run.
     """
 
+    # a state-space model's programs (set per engine; the class's word is
+    # what an engine built before the family, or around it, goes by)
+    _ssm = False
+
     def __init__(self, m: Mesh3D,
-                 cfg: "LMConfig | decoder.LatentConfig | decoder.HybridConfig",
+                 cfg: "LMConfig | decoder.LatentConfig | decoder.HybridConfig"
+                      " | decoder.SsmConfig",
                  params: Any,
                  scfg: Optional[ServeConfig] = None):
         if m.sp != 1:
@@ -477,9 +493,10 @@ class ServeEngine:
         self._moe = isinstance(cfg, MoELMConfig)
         self._latent = isinstance(cfg, decoder.LatentConfig)
         self._hybrid = isinstance(cfg, decoder.HybridConfig)
+        self._ssm = isinstance(cfg, decoder.SsmConfig)
         # one chip's share of an expert-parallel deployment: held experts
         # under the full-width router, programs of the family's own
-        self._share = self._latent or self._hybrid
+        self._share = self._latent or self._hybrid or self._ssm
         # a call of these returns the routing carrier beside its tokens
         self._routed = self._moe or self._share
         if self._moe and cfg.router_mode == "expert_choice":
@@ -496,8 +513,9 @@ class ServeEngine:
             raise ValueError("max_len leaves no room to decode past the "
                              "longest prompt bucket")
         if self._share:
-            self._refuse_share("latent" if self._latent else "hybrid", m,
-                               scfg)
+            self._refuse_share(
+                "latent" if self._latent else
+                "hybrid" if self._hybrid else "ssm", m, scfg)
         if scfg.moe_experts and not self._moe:
             raise ValueError(
                 f"ServeConfig declares an MoE (moe_experts="
@@ -523,7 +541,9 @@ class ServeEngine:
                 m.ep * scfg.batch_buckets[-1] * cfg.top_k, e_local)
             self._moe_chunk_tile = cfg.group_tile   # prefill/verify shapes
         self._route_stats: Optional[np.ndarray] = None
-        self._decode_logits = None      # (slots, device array): hybrid
+        self._decode_logits = None      # (slots, device array): hybrid, ssm
+        self._decode_chosen = None      # (slots, device array): ssm
+        self._prefill_chosen = None     # device array: ssm
         self.m, self.cfg, self.scfg = m, cfg, scfg
         self.draft = draft_carve(m, cfg, scfg.spec_stages) \
             if scfg.spec_decode else None
@@ -540,7 +560,15 @@ class ServeEngine:
             window_layers=cfg.layers_of("window"), slots=scfg.slots,
             max_len=scfg.max_len, window=cfg.window, kv_heads=cfg.kv_heads,
             head_dim=cfg.head_dim, dtype=scfg.dtype) \
-            if self._hybrid else _kv.KVCacheConfig(
+            if self._hybrid else _kv.SsmCacheConfig(
+            full_layers=cfg.layers_of("full"),
+            ssm_layers=cfg.layers_of("ssm"), slots=scfg.slots,
+            max_len=scfg.max_len, kv_heads=cfg.kv_heads,
+            head_dim=cfg.head_dim, ssm_heads=cfg.ssm_heads,
+            ssm_head_dim=cfg.ssm_head_dim, ssm_state=cfg.ssm_state,
+            conv_taps=cfg.conv_kernel - 1, conv_dim=cfg.conv_dim,
+            dtype=scfg.dtype) \
+            if self._ssm else _kv.KVCacheConfig(
             layers=cfg.layers // m.pp, slots=scfg.slots,
             max_len=scfg.max_len, kv_heads=cfg.heads // m.tp,
             head_dim=cfg.d_model // cfg.heads, dtype=scfg.dtype,
@@ -554,7 +582,10 @@ class ServeEngine:
 
         def _zeros():
             if self._share:
-                return {name: jnp.zeros((1,) + shape, cc.dtype)
+                # a state-space layer's state is float32 whatever is served
+                dtypes = cc.dtypes() if self._ssm else {}
+                return {name: jnp.zeros((1,) + shape,
+                                        dtypes.get(name, cc.dtype))
                         for name, shape in cc.shapes().items()}
             return {name: t[None] for name, t in _kv.init_cache(cc).items()}
 
@@ -570,11 +601,12 @@ class ServeEngine:
             out_shardings=self._sharding)()
         self._decode_jit = self._build(
             self._latent_decode_body if self._latent else
-            self._hybrid_decode_body if self._hybrid else self._decode_body)
+            self._hybrid_decode_body if self._hybrid else
+            self._ssm_decode_body if self._ssm else self._decode_body)
         self._prefill_jit = self._build(
             self._latent_prefill_body if self._latent else
-            self._hybrid_prefill_body if self._hybrid
-            else self._prefill_body)
+            self._hybrid_prefill_body if self._hybrid else
+            self._ssm_prefill_body if self._ssm else self._prefill_body)
         _metrics.gauge(
             "bluefog_serve_residual_streams",
             "residual streams the layer loops of this engine's programs "
@@ -585,12 +617,13 @@ class ServeEngine:
                 "bluefog_serve_cache_bytes_per_token",
                 "device bytes one cached token costs over all layers"
             ).set(float(cc.bytes_per_token()))
-        if self._hybrid:
+        if self._hybrid or self._ssm:
             for kind, size in cc.bytes_per_slot().items():
                 _metrics.gauge(
                     "bluefog_serve_cache_bytes_per_slot",
                     "device bytes of the cache a slot owns, by kind of "
-                    "layer (full: every position; window: a ring)"
+                    "layer (full: every position; window: a ring; ssm: a "
+                    "recurrent state and its convolution's kept inputs)"
                 ).set(float(size), kind=kind)
         self._chunk_jit = self._build(self._chunk_body) \
             if (scfg.spec_decode or scfg.prefix_pages) else None
@@ -629,6 +662,15 @@ class ServeEngine:
                         "share",
             prefix_pages="a ring holds a prompt's END: it cannot lend rows "
                          "to a shared prefix"),
+        "ssm": dict(
+            decode_kernel="the flash-decode kernel reads rows of positions; "
+                          "a state-space layer's state has none",
+            kv_dtype="the state cache has no quantized store: a recurrent "
+                     "state is fed back every step",
+            spec_decode="the model's own drafter layer is not served, and a "
+                        "rejected draft would have to roll a state back",
+            prefix_pages="a prefix page holds positions; a recurrent state "
+                         "has none to share"),
     }
 
     @classmethod
@@ -1152,8 +1194,7 @@ class ServeEngine:
         (:func:`~bluefog_tpu.moe.layers.held_moe_ffn`).  ``faux`` is the
         layer's ``[E + 4]`` carrier (:meth:`_note_route_stats`)."""
         cfg = self.cfg
-        held = jnp.arange(cfg.num_experts) - cfg.held_start
-        held = ((held >= 0) & (held < cfg.held_experts)).astype(jnp.float32)
+        held = self._held_mask()
 
         def ffn(lp, h):
             if experts is None:
@@ -1161,15 +1202,28 @@ class ServeEngine:
             else:
                 y, idx, weight = held_moe_ffn(cfg, {**lp, **experts}, h,
                                               live, layer=lp["layer"])
-            cnt = jnp.sum(jax.nn.one_hot(idx, cfg.num_experts,
-                                         dtype=jnp.float32), axis=(0, 1))
-            p = weight / cfg.route_scale
-            ent = jnp.sum(-jnp.sum(p * jnp.log(p + 1e-20), -1) * live)
-            return y, jnp.concatenate([cnt, jnp.stack([
-                ent, jnp.sum(live.astype(jnp.float32)),
-                jnp.sum(cnt * held), jnp.sum((cnt > 0) * held)])])
+            return y, self._carrier(idx, weight, live, held)
 
         return ffn
+
+    def _held_mask(self):
+        """``[E]`` float32: 1 at the router outputs this chip holds."""
+        cfg = self.cfg
+        held = jnp.arange(cfg.num_experts) - cfg.held_start
+        return ((held >= 0) & (held < cfg.held_experts)).astype(jnp.float32)
+
+    def _carrier(self, idx, weight, live, held):
+        """One expert layer's ``[E + 4]`` carrier
+        (:meth:`_note_route_stats`) from its routing ``idx`` / ``weight``
+        ``[tokens, top_k]`` (-1 where a token is not ``live``)."""
+        cfg = self.cfg
+        cnt = jnp.sum(jax.nn.one_hot(idx, cfg.num_experts,
+                                     dtype=jnp.float32), axis=(0, 1))
+        p = weight / cfg.route_scale
+        ent = jnp.sum(-jnp.sum(p * jnp.log(p + 1e-20), -1) * live)
+        return jnp.concatenate([cnt, jnp.stack([
+            ent, jnp.sum(live.astype(jnp.float32)),
+            jnp.sum(cnt * held), jnp.sum((cnt > 0) * held)])])
 
     def _latent_layers(self, params, x, cache, positions, attend_with, live,
                        grouped):
@@ -1457,6 +1511,202 @@ class ServeEngine:
             nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
+    # ------------------------------------------------------------------
+    # the state-space model's programs (one mixer a layer, one chip's share)
+    # ------------------------------------------------------------------
+
+    def _ssm_ffn(self, live, grouped):
+        """An expert layer's mixer: the full-width router under its
+        selection bias, the held ``relu^2`` experts in the latent, the
+        shared expert (:func:`~bluefog_tpu.moe.layers.held_moe_ffn`).
+        ``grouped``: the pairs through the grouped kernel (a prompt), else
+        every token through every held expert (a decode step).  ``aux`` is ``(carrier [E + 4], chosen [tokens,
+        top_k])``."""
+        cfg = self.cfg
+        held = self._held_mask()
+
+        def ffn(lp, h):
+            if grouped:
+                lp = {**lp, "we1": lp["we1"][None], "we2": lp["we2"][None]}
+            y, idx, weight = held_moe_ffn(
+                cfg, lp, h, live, jnp.int32(0) if grouped else None,
+                form="relu2")
+            return y, (self._carrier(idx, weight, live, held), idx)
+        return ffn
+
+    @staticmethod
+    def _experts_mixer(ffn, lp, cache):
+        """An expert layer's ``mix`` hook (:meth:`_ssm_layers`): it leaves
+        the cache as it is and hands on its carrier and selections."""
+        def mix(h):
+            y, (vec, idx) = ffn(lp, h)
+            return y, (cache, None, vec, idx)
+        return mix
+
+    def _ssm_layers(self, params, x, cache, mixer_of):
+        """The plan's layers, unrolled, one cache tree carried through:
+        ``mixer_of(kind, index, lp, cache)`` builds the ``mix`` hook of the
+        ``index``-th layer of its kind (:func:`decoder.mixer_block`), whose
+        ``aux`` is ``(cache, new, carrier, chosen)``: the cache as the
+        layer leaves it, what an attention layer hands on for after the
+        loop, and an expert layer's carrier and selections.  Returns ``(x,
+        cache, news, carrier, chosen [expert layers, tokens, top_k])``."""
+        cfg = self.cfg
+        news, chosen = [], []
+        acc = jnp.zeros((cfg.num_experts + 4,), jnp.float32)
+        for i, kind in enumerate(cfg.plan):
+            lp = params["layers"][i]
+            x, (cache, new, vec, idx) = decoder.mixer_block(
+                cfg, lp, x, kind,
+                mixer_of(kind, cfg.index_in_kind(i), lp, cache))
+            if kind == "full":
+                news.append(new)
+            elif kind == "experts":
+                acc = acc + vec
+                chosen.append(idx)
+        return x, cache, news, acc, jnp.stack(chosen)
+
+    def _ssm_decode_body(self, params, cache, keys, lanes):
+        """Fused decode over the state cache: a state-space layer moves
+        every row's state on by its lane's token where the state lies (its
+        convolution's kept inputs too), an attention layer attends over its
+        lanes' rows plus the token's own K and V, which land once per lane
+        and tensor after the loop.  Beside the tokens it hands out every
+        fused step's logits and the experts each expert layer chose (left
+        on the device: :meth:`decode_logits`, :meth:`decode_chosen`) and,
+        behind the routing carrier, the cache positions its attention
+        met."""
+        whole = cache["ssm"]
+        params, cache, table, lanes = self._split_args(
+            (params, cache, keys, lanes))
+        # the states keep the argument's own leading axis: read through a
+        # squeeze, the first layer's states come from the argument and
+        # land in a copy of all of them (3.4 GB of temporaries)
+        cache["ssm"] = whole
+        toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
+        with jax.named_scope("readout"):
+            toks, keys = toks[:, 0], table[slot_ids]
+        cfg, shared = self.cfg, params["shared"]
+        live = slot_ids < self.scfg.slots
+        ffn = self._ssm_ffn(live, grouped=False)
+
+        def step(carry, _):
+            toks, lens, cache, keys, st = carry
+            met = [0]
+
+            def mixer_of(kind, index, lp, cache):
+                if kind == "experts":
+                    return self._experts_mixer(ffn, lp, cache)
+                if kind == "full":
+                    def attention(h):
+                        q, k, v = decoder.gqa_project(cfg, lp, h)
+                        new = _kv.token_pages(k, v, "raw", cache["k"].dtype)
+                        out, read = _kv.attend_slots(
+                            q, cache["k"][index], cache["v"][index],
+                            slot_ids, lens, new)
+                        met[0] += read
+                        with jax.named_scope("attn.project"):
+                            y = out.reshape(h.shape[0], -1) @ lp["wo"]
+                        return y, (cache, new, None, None)
+                    return attention
+
+                def mamba(h):
+                    z, xbc, dt = decoder.mamba_project(cfg, lp, h)
+                    xbc, nc = _kv.ssm_conv_step(
+                        cache, index, slot_ids, xbc,
+                        lambda xbc, prev: decoder.mamba_conv(cfg, lp, xbc,
+                                                             prev))
+                    x, B, C = decoder.mamba_split(cfg, xbc)
+                    with jax.named_scope("ssm.scan"):
+                        log_a, dx = decoder.mamba_discretize(lp, x, dt)
+                    y, nc = _kv.ssm_state_step(
+                        nc, index, slot_ids,
+                        lambda *a: decoder.mamba_step(cfg, *a), log_a, dx, B,
+                        C)
+                    with jax.named_scope("ssm.scan"):
+                        y = y + lp["Dskip"].astype(jnp.float32)[:, None] \
+                            * x.astype(jnp.float32)
+                    return decoder.mamba_gate_out(cfg, lp, y, z), (
+                        nc, None, None, None)
+                return mamba
+
+            with jax.named_scope("readout"):
+                x = shared["embed"][toks]
+            x, cache, news, acc, chosen = self._ssm_layers(
+                params, x, cache, mixer_of)
+            cache = _kv.ssm_append_tokens(cache, slot_ids, lens, {
+                part: jnp.stack([n[part] for n in news])
+                for part in ("k", "v")})
+            logits = decoder.latent_logits(cfg, shared, x)
+            nxt, keys = self._next_token(logits, keys)
+            nxt = nxt.astype(toks.dtype)
+            acc = jnp.concatenate([acc, jnp.array(met, jnp.float32)])
+            return (nxt, lens + 1, cache, keys, st + acc), (
+                nxt, chosen, logits.astype(jnp.float32))
+
+        st0 = jnp.zeros((cfg.num_experts + 5,), jnp.float32)
+        (_, _, cache, keys, st), (gen, chosen, logits) = lax.scan(
+            step, (toks, lens, cache, keys, st0), None,
+            length=self.scfg.decode_steps_per_call)
+        with jax.named_scope("readout"):
+            table = table.at[slot_ids].set(keys)
+        whole = cache.pop("ssm")
+        out = jax.tree.map(lambda t: t[None],
+                           (gen, st, chosen, logits, table, cache))
+        out[-1]["ssm"] = whole
+        return out
+
+    def _ssm_prefill_body(self, params, cache, keys, staged):
+        """One padded prompt: a state-space layer scans it in chunks and
+        leaves in the slot the state after its last REAL token and the
+        convolution inputs before ``true_len``, both overwritten whole; an
+        attention layer's K and V land in the slot's row under the flash
+        forward kernel.  Padding is routed to no expert, and only the last
+        real position is read out; beside its logits the program hands
+        out the experts each expert layer chose (:meth:`prefill_chosen`)."""
+        params, cache, keys, staged = self._split_args(
+            (params, cache, keys, staged))
+        keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
+        cfg, shared = self.cfg, params["shared"]
+        ffn = self._ssm_ffn(jnp.arange(toks.shape[0]) < true_len,
+                            grouped=True)
+
+        def mixer_of(kind, index, lp, cache):
+            if kind == "experts":
+                return self._experts_mixer(ffn, lp, cache)
+            if kind == "full":
+                def attention(h):
+                    q, k, v = decoder.gqa_project(cfg, lp, h)
+                    nc = _kv.hybrid_prefill(cache, "full", index, slot_id,
+                                            k, v, true_len)
+                    att = self._flash_causal(q, k, v)
+                    with jax.named_scope("attn.project"):
+                        y = att.reshape(h.shape[0], -1) @ lp["wo"]
+                    return y, (nc, None, None, None)
+                return attention
+
+            def mamba(h):
+                z, xbc, dt = decoder.mamba_project(cfg, lp, h)
+                xbc, kept = decoder.mamba_conv(cfg, lp, xbc,
+                                               true_len=true_len)
+                y, state = decoder.mamba_scan_chunked(
+                    cfg, lp, *decoder.mamba_split(cfg, xbc), dt, true_len)
+                nc = _kv.ssm_prefill(cache, index, slot_id, state, kept)
+                return decoder.mamba_gate_out(cfg, lp, y, z), (
+                    nc, None, None, None)
+            return mamba
+
+        with jax.named_scope("readout"):
+            x = shared["embed"][toks]
+        x, cache, _, _, chosen = self._ssm_layers(params, x, cache, mixer_of)
+        with jax.named_scope("readout"):
+            last = decoder.latent_logits(
+                cfg, shared, lax.dynamic_slice_in_dim(x, true_len - 1, 1)[0]
+            ).astype(jnp.float32)
+            nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
+        return jax.tree.map(lambda t: t[None],
+                            (nxt, last, chosen, keys, cache))
+
     def _count_held_work(self, lanes: int, lens, slots) -> None:
         """After a latent decode call: the routing carrier's held-expert
         counts into the fleet's counters, and a ``bf:engine.held_work``
@@ -1486,6 +1736,21 @@ class ServeEngine:
         seen = np.asarray(lens)[live] + 1       # a lane's live positions
         attrs = dict(pairs=pairs, rows=rows, experts_hit=hit,
                      positions=int(seen.sum()))
+        if self._ssm:
+            # the live lanes' states, which every state-space layer reads
+            # and writes whole in every fused step; and the positions the
+            # attention layers' contraction met, as the hybrid family's
+            attrs["state_lanes"] = int(live.sum())
+            _metrics.counter(
+                "bluefog_serve_state_updates_total",
+                "recurrent states decode calls read and wrote (live lanes "
+                "x state-space layers x fused steps)").inc(
+                    attrs["state_lanes"] * cfg.layers_of("ssm")
+                    * scfg.decode_steps_per_call)
+            met = int(self._route_stats[:, E + 4].sum())
+            attrs["positions_read_full"] = met // (
+                cfg.layers_of("full") * scfg.decode_steps_per_call)
+            self._count_positions(met, "full")
         if self._hybrid:
             # what a window layer may see of the lanes' positions, and
             # what the program's attention met of either kind (the
@@ -1646,8 +1911,10 @@ class ServeEngine:
                 args = self._args(self._expand("prefill", self._pack(
                     toks, slot_id, true_len, key_id, count)))
             with self._stage("dispatch"):
-                nxt, logits, self._keys, self.cache = \
+                nxt, logits, *chosen, self._keys, self.cache = \
                     self._prefill_jit(*args)
+                if chosen:
+                    self._prefill_chosen, = chosen
             with self._stage("collect"):
                 self._check_program(f"prefill Tpad={Tpad}",
                                     self._prefill_jit, args,
@@ -1774,9 +2041,11 @@ class ServeEngine:
                         self._feed_jit(args[-1], self._flying.out[0]),)
                 *out, self._keys, self.cache = self._decode_jit(*args)
             call = _DecodeCall(S, slots, np.array(lens, np.int32), out)
-            if self._hybrid and self._decode_logits is None:
+            if self._hands_logits and self._decode_logits is None:
                 # nothing has been collected yet: the call in flight's
                 self._decode_logits = (slots, out[-1])
+                if self._ssm:
+                    self._decode_chosen = (slots, out[-2])
             due, self._flying = (self._flying, call) if ahead \
                 else (call, None)
             gen = self._collect_decode(due, (
@@ -1836,12 +2105,14 @@ class ServeEngine:
             if due is None:
                 return None
             S, slots, lens, out = due
-            if self._hybrid:
+            if self._hands_logits:
                 self._decode_logits = (slots, out.pop())
+            if self._ssm:
+                self._decode_chosen = (slots, out.pop())
             gen, *st = self._collect("decode", *out)
             if st:
                 self._note_route_stats(st[0])
-            if not self._hybrid:
+            if not self._hands_logits:
                 self._count_decode_read(S)
         # a mark never goes inside a leaf stage: ``collect`` stays a
         # span with none beneath it in every family
@@ -1849,10 +2120,16 @@ class ServeEngine:
             self._count_held_work(S, lens, slots)
         return gen
 
+    @property
+    def _hands_logits(self) -> bool:
+        """Whether the decode program hands out every fused step's logits
+        beside its tokens (:meth:`decode_logits`)."""
+        return self._hybrid or self._ssm
+
     def decode_logits(self, replica: int
                       ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
-        """What the last :meth:`decode` call COLLECTED of the hybrid family
-        (the call whose tokens were last returned; before any has been,
+        """What the last :meth:`decode` call COLLECTED of the hybrid or the
+        state-space family (the call whose tokens were last returned; before any has been,
         the call in flight, and converting them waits for it) chose its
         tokens from: ``replica``'s lanes' slots ``[S]`` and their
         logits ``[decode_steps_per_call, S, vocab]`` (float32), which stay
@@ -1861,12 +2138,36 @@ class ServeEngine:
         the reference does, so that what decode READS of the cache is
         held to it number by number.  ``None`` before the first call and
         for the other families, whose programs hand out tokens alone."""
-        if self._decode_logits is None:
+        return self._handed_out(self._decode_logits, replica)
+
+    def _handed_out(self, kept, replica: int):
+        if kept is None:
             return None
-        slots, logits = self._decode_logits
+        slots, rows = kept
         return slots[replica], _DeviceRow(
-            logits, replica * self.m.slice_size,
+            rows, replica * self.m.slice_size,
             lambda: self._count_crossing("decode", "out"))
+
+    def decode_chosen(self, replica: int
+                      ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
+        """Beside :meth:`decode_logits`, of the state-space family: the
+        experts every expert layer chose for every lane in every fused step
+        of that call, ``[decode_steps_per_call, expert layers, S, top_k]``
+        int32 over the router's outputs (-1: a dead lane).  Left on the
+        device like the logits; only a comparison with a reference reads
+        them, to evaluate the reference under the program's own selections
+        where a rounding tie put the two apart.  ``None`` before the first
+        call and for the other families."""
+        return self._handed_out(self._decode_chosen, replica)
+
+    def prefill_chosen(self, replica: int) -> Optional["_DeviceRow"]:
+        """:meth:`decode_chosen` for the last :meth:`prefill`: ``[expert
+        layers, Tpad, top_k]`` (-1 at the prompt's padding)."""
+        if self._prefill_chosen is None:
+            return None
+        return _DeviceRow(self._prefill_chosen,
+                          replica * self.m.slice_size,
+                          lambda: self._count_crossing("prefill", "out"))
 
     def spec_decode(self, tokens: np.ndarray, slots: np.ndarray,
                     lens: np.ndarray,
@@ -1957,7 +2258,8 @@ class ServeEngine:
         has two more entries: the pairs that fell on held experts, and the
         (layer, held expert) groups that got a token; a hybrid model's has
         two more behind those: the cache positions its attention met on
-        full and on window layers."""
+        full and on window layers; a state-space model's one: the
+        positions its attention layers met."""
         self._route_stats = st.astype(np.float64)
 
     def moe_load(self) -> Optional[list]:
@@ -2065,6 +2367,13 @@ class ServeEngine:
         steps = {"decode": scfg.decode_steps_per_call,
                  "draft": scfg.spec_decode}.get(kind, 1)
         deferred = kind in ("decode", "draft") and self._defer_appends
+        if self._ssm:
+            # K and V of the attention layers a lane (a token's once after
+            # the loop), a state and its convolution inputs a state-space
+            # layer: per lane from a prompt, whole by a decode step
+            full, ssm = (self.cfg.layers_of(k) for k in ("full", "ssm"))
+            return lanes * 2 * (full + ssm) if not deferred \
+                else steps * 2 * (lanes + ssm)
         if self._hybrid and not deferred:
             # a layer writes the K and V of its own kind
             return lanes * 2 * self.cfg.layers
@@ -2096,6 +2405,10 @@ class ServeEngine:
                 "pages": self.cache_cfg.page_orders()}
             if read is not None:
                 self._program_bytes[program]["read"] = read
+            if self._ssm:
+                cc = self.cache_cfg
+                self._program_bytes[program]["state_bytes"] = \
+                    cc.rows * cc.bytes_per_slot()["ssm"]
             _metrics.gauge(
                 "bluefog_serve_cache_copy_bytes",
                 "temporaries the compiler allocated for one engine program "
@@ -2130,8 +2443,10 @@ class ServeEngine:
         the cache and the key table, and ``temp_bytes`` stays under one
         layer's pages when it is; ``pages`` names the order each cache
         tensor's pages lie in (:func:`.kv_cache.page_order`:
-        ``"token_rows"``, ``"head_dim_minor"`` or ``"positions_minor"``),
-        which is what a token's write costs.  A decode or draft program
+        ``"token_rows"``, ``"head_dim_minor"`` or ``"positions_minor"``;
+        ``"state"``: no positions), which is what a token's write costs; a
+        state-space model's programs add ``state_bytes``, the recurrent
+        states and convolution inputs among the aliased bytes.  A decode or draft program
         also says how its attention ``read``s the cache: ``"in_place"``
         (no staging buffer among its temporaries) or ``"staged"``."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

@@ -104,6 +104,19 @@ the pages in place (:func:`attend_slots`).  :class:`LatentCacheConfig`
 is the cache of one compressed vector per token, which every head of a
 layer shares (:func:`latent_attend_slots`).
 
+**A third kind of tensor: a state with no positions**
+(:class:`SsmCacheConfig`).  A state-space layer keeps per slot a recurrent
+state ``[heads, head_dim, state]`` in float32 and the last inputs of its
+convolution: no length, overwritten whole by a prompt
+(:func:`ssm_prefill`), read AND written whole by every decode step
+(:func:`ssm_conv_step`, :func:`ssm_state_step`: every row of a layer's
+states in one pass, where they lie, a row no lane names passing
+unchanged), beside the rows of the model's attention layers in one tree
+under one :class:`SlotAllocator`.  Prefix pages do not apply to it (a
+state has no positions to share); a slot that is admitted again is
+overwritten whole, so nothing carries over.  Its reads and writes run
+under the device scopes ``ssm.scan`` and ``ssm.conv``, not ``cache.*``.
+
 Every read of the cache here runs under the device scope ``cache.read``
 and every landing in it under ``cache.write`` (``jax.named_scope``;
 :func:`bluefog_tpu.utils.tracing.device_scopes`), so a device trace says
@@ -139,6 +152,8 @@ from ..ops.collectives import _amax_scale
 from ..utils import metrics as _metrics
 
 __all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
+           "SsmCacheConfig", "ssm_prefill", "ssm_conv_step", "ssm_state_step",
+           "ssm_append_tokens",
            "hybrid_prefill", "hybrid_append_tokens", "attend_slots",
            "latent_prefill",
            "latent_append_tokens", "latent_attend_slots", "init_cache",
@@ -1233,6 +1248,174 @@ def attend_slots(q: jax.Array, kt: jax.Array, vt: jax.Array,
         (q.astype(kt.dtype),), (kt,), vt, slots, lengths, (new["k"],),
         new["v"], q.shape[-1] ** -0.5, ring=ring, probs=vt.dtype)
     return out.astype(q.dtype), met
+
+
+# ---------------------------------------------------------------------------
+# The state cache: rows of positions for the attention layers, beside a
+# fixed-size recurrent state and the convolution's kept inputs per slot
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class SsmCacheConfig:
+    """Shapes of the cache of a model with state-space layers beside
+    attention layers::
+
+        k, v: [full_layers, slots + 1, kv_heads, max_len, head_dim]
+        ssm:  [ssm_layers,  slots + 1, ssm_heads, ssm_head_dim, ssm_state]
+        conv: [ssm_layers,  slots + 1, conv_taps, conv_dim]
+
+    ``k``/``v`` are :class:`HybridCacheConfig`'s full layers.  ``ssm`` is
+    each slot's recurrent state, float32 whatever the served dtype (a
+    rounding of it is fed back every step); ``conv`` the ``conv_taps`` raw
+    inputs of the layer's convolution before the next token, in ``dtype``.
+    Neither has a length.  The last row is the trash slot; no prefix pages
+    and no quantized store."""
+    full_layers: int
+    ssm_layers: int
+    slots: int
+    max_len: int
+    kv_heads: int
+    head_dim: int
+    ssm_heads: int
+    ssm_head_dim: int
+    ssm_state: int
+    conv_taps: int
+    conv_dim: int
+    dtype: Any = jnp.float32
+    prefix_slots = 0                # what the engine's host code asks for
+
+    @property
+    def rows(self) -> int:
+        return self.slots + 1
+
+    @property
+    def trash_slot(self) -> int:
+        return self.slots
+
+    def shapes(self) -> Dict[str, Tuple[int, ...]]:
+        full = (self.full_layers, self.rows, self.kv_heads, self.max_len,
+                self.head_dim)
+        return {"k": full, "v": full,
+                "ssm": (self.ssm_layers, self.rows, self.ssm_heads,
+                        self.ssm_head_dim, self.ssm_state),
+                "conv": (self.ssm_layers, self.rows, self.conv_taps,
+                         self.conv_dim)}
+
+    def dtypes(self) -> Dict[str, Any]:
+        return {"k": self.dtype, "v": self.dtype, "ssm": jnp.float32,
+                "conv": self.dtype}
+
+    def page_orders(self) -> Dict[str, str]:
+        """How each tensor's pages lie: the attention layers' by head; a
+        state has no positions (``"state"``)."""
+        return {"k": page_order(1, self.head_dim, self.max_len),
+                "v": page_order(1, self.head_dim, self.max_len),
+                "ssm": "state", "conv": "state"}
+
+    def bytes_per_token(self) -> int:
+        """Device bytes one more cached token costs: the attention
+        layers' alone, a state does not grow with the sequence."""
+        return (self.full_layers * 2 * self.kv_heads * self.head_dim
+                * jnp.dtype(self.dtype).itemsize)
+
+    def bytes_per_slot(self) -> Dict[str, int]:
+        """Device bytes a slot owns, by kind of layer."""
+        return {"full": self.max_len * self.bytes_per_token(),
+                "ssm": self.ssm_layers * (
+                    self.ssm_heads * self.ssm_head_dim * self.ssm_state * 4
+                    + self.conv_taps * self.conv_dim
+                    * jnp.dtype(self.dtype).itemsize)}
+
+    def bytes(self) -> int:
+        return self.rows * sum(self.bytes_per_slot().values())
+
+
+def ssm_prefill(cache: Dict[str, jax.Array], layer: int, slot_id: jax.Array,
+                state: jax.Array, conv: jax.Array) -> Dict[str, jax.Array]:
+    """Overwrite row ``slot_id`` of the ``layer``-th state-space layer
+    whole with a prompt's ``state`` ``[heads, head_dim, state]`` and kept
+    convolution inputs ``conv`` ``[taps, conv_dim]``: whatever the slot's
+    last request left there is gone."""
+    out = dict(cache)
+    with jax.named_scope("ssm.scan"):
+        out["ssm"] = lax.dynamic_update_slice(
+            cache["ssm"], state[None, None].astype(cache["ssm"].dtype),
+            (layer, slot_id, 0, 0, 0))
+    with jax.named_scope("ssm.conv"):
+        out["conv"] = lax.dynamic_update_slice(
+            cache["conv"], conv[None, None].astype(cache["conv"].dtype),
+            (layer, slot_id, 0, 0))
+    return out
+
+
+def _by_row(rows: int, slots: jax.Array, value: jax.Array,
+            fill: float) -> jax.Array:
+    """The lanes' ``value`` ``[S, ...]`` laid out by row ``[rows, ...]``,
+    ``fill`` where no lane names the row (dead lanes meet in the trash
+    row; which of them lands there does not matter)."""
+    return jnp.full((rows,) + value.shape[1:], fill,
+                    value.dtype).at[slots].set(value)
+
+
+@jax.named_scope("ssm.conv")
+def ssm_conv_step(cache: Dict[str, jax.Array], layer: int, slots: jax.Array,
+                  xbc: jax.Array, step
+                  ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One decode token per lane through the ``layer``-th state-space
+    layer's convolution, its kept inputs updated where they lie: the
+    lanes' raw inputs ``xbc`` ``[S, conv_dim]`` are laid out by row,
+    ``step(xbc, prev [rows, taps, conv_dim]) -> (out, kept)`` runs over
+    every row, the rows a lane names take their new kept inputs (the rest
+    keep theirs) and the lanes' rows of ``out`` are read back.  (A gather
+    of the lanes' kept inputs and a scatter behind it took 0.5 ms a layer
+    at 160 lanes of 61 KB, twenty times what the bytes cost: PERF.md
+    section 6, PR 43.)"""
+    t = cache["conv"]
+    rows = t.shape[1]
+    named = _by_row(rows, slots, jnp.ones(slots.shape, bool), False)
+    out, kept = step(_by_row(rows, slots, xbc, 0.0), t[layer])
+    kept = jnp.where(named[:, None, None], kept.astype(t.dtype), t[layer])
+    return out[slots], {**cache, "conv": lax.dynamic_update_slice(
+        t, kept[None], (layer, 0, 0, 0))}
+
+
+@jax.named_scope("ssm.scan")
+def ssm_state_step(cache: Dict[str, jax.Array], layer: int, slots: jax.Array,
+                   step, log_a: jax.Array, dx: jax.Array, B: jax.Array,
+                   C: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One decode token per lane through the ``layer``-th state-space
+    layer's recurrence, the states updated WHERE THEY LIE: the lanes'
+    inputs (``log_a`` ``[S, heads]``, ``dx`` ``[S, heads, head_dim]``,
+    ``B``/``C`` ``[S, groups, state]``) are laid out by row, every row's
+    state takes ``step(states, log_a, dx, B, C) -> (y, states)`` in one
+    pass over the layer's slice of the donated tensor (a row no lane names
+    gets ``log_a = 0`` and ``dx = 0`` and passes unchanged), and the
+    lanes' rows of ``y`` are read back.  A gather of the lanes' states
+    and a scatter behind it would move each state four times where this
+    moves it twice; like the in-place attention read it is the form for a
+    bucket that covers most of the rows (:func:`read_in_place`)."""
+    t = cache["ssm"]
+    at = (0,) * (t.ndim - 5) + (layer,)     # a leading axis of one or none
+    rows = t.shape[-4]
+    y, new = step(t[at], _by_row(rows, slots, log_a, 0.0),
+                  _by_row(rows, slots, dx, 0.0), _by_row(rows, slots, B, 0.0),
+                  _by_row(rows, slots, C, 0.0))
+    return y[slots], {**cache, "ssm": lax.dynamic_update_slice(
+        t, new.reshape((1,) * len(at) + new.shape).astype(t.dtype),
+        at + (0,) * 4)}
+
+
+@jax.named_scope("cache.write")
+def ssm_append_tokens(cache: Dict[str, jax.Array], slots: jax.Array,
+                      lengths: jax.Array, new: Dict[str, jax.Array]
+                      ) -> Dict[str, jax.Array]:
+    """One decode token per lane into every attention layer at once, after
+    the layer loop (:func:`hybrid_append_tokens` for the full layers
+    alone): ``new["k"]``/``new["v"]`` ``[full_layers, S, kv_heads,
+    head_dim]`` land at ``lengths[i]``.  The states were updated in the
+    loop."""
+    return {**cache, **{name: _write_tokens(cache[name], slots, lengths,
+                                            new[name]) for name in ("k", "v")}}
 
 
 # ---------------------------------------------------------------------------

@@ -255,7 +255,11 @@ DEVICE_SCOPES = frozenset((
     "attn", "attn.project", "attn.window", "attn.full",
     "mla.project", "mla.attend", "cache.read", "cache.write",
     "ffn", "moe.route", "moe.experts", "moe.shared", "readout",
-    "hc.coef", "hc.mix"))                   # the residual streams' maps
+    "hc.coef", "hc.mix",                    # the residual streams' maps
+    # a state-space mixer: its two projections with the gate and grouped
+    # norm, its convolution, its scan or single step with the state's read
+    # and write; and an expert layer's two latent projections
+    "ssm.project", "ssm.conv", "ssm.scan", "moe.latent"))
 
 _NOT_A_SCOPE_RE = re.compile(r"\bp?jit\([^()]*\)")     # a function's name
 _PATH_NAME_RE = re.compile(r"[^/()]+")

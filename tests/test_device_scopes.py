@@ -224,6 +224,13 @@ VOCABULARY = {
                {"attn.project", "attn.window", "attn.full", "cache.write",
                 "ffn", "moe.route", "moe.experts", "moe.shared", "readout"}),
 }
+# one mixer a layer: a Mamba mixer's three scopes, the attention layer's,
+# the expert layer's with its latent projections; the state is read and
+# written under ssm.scan and ssm.conv, never under cache.*
+VOCABULARY["ssm"] = tuple(
+    {"ssm.project", "ssm.conv", "ssm.scan", "attn.project", "cache.write",
+     "ffn", "moe.route", "moe.latent", "moe.experts", "moe.shared",
+     "readout"} | more for more in ({"cache.read"}, {"attn.full"}))
 # four residual streams round the latent block: the latent programs'
 # vocabulary with the stream maps' two scopes
 VOCABULARY["latent_streams"] = tuple(
@@ -240,6 +247,9 @@ def make_engine(family, cpu_devices):
                         max_len=16))
     import test_serve_hybrid
     import test_serve_latent
+    import test_serve_ssm
+    if family == "ssm":
+        return test_serve_ssm.make_engine(cpu_devices)
     if family == "latent_streams":
         return test_serve_latent.make_engine(cpu_devices,
                                              test_serve_latent.STREAMED)
@@ -339,7 +349,7 @@ def test_lm_train_step_splits_by_phase_direction_and_block_scope(
     assert step._cache_size() == size
 
 
-@pytest.mark.parametrize("family", ["latent", "hybrid"])
+@pytest.mark.parametrize("family", ["latent", "hybrid", "ssm"])
 def test_held_work_mark_sits_under_the_decode_call_not_in_collect(
         family, cpu_devices, monkeypatch):
     """``collect`` closes before the mark opens, and the mark closes before

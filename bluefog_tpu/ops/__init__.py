@@ -8,6 +8,8 @@ axis, compiled by XLA into ICI collectives.  The outer blocking API in
 from .collectives import (
     my_rank,
     neighbor_allreduce,
+    neighbor_exchange,
+    neighbor_combine,
     neighbor_allgather,
     ragged_neighbor_allgather,
     allreduce,
@@ -23,6 +25,8 @@ from .ulysses import ulysses_attention, local_flash_attention
 __all__ = [
     "my_rank",
     "neighbor_allreduce",
+    "neighbor_exchange",
+    "neighbor_combine",
     "neighbor_allgather",
     "ragged_neighbor_allgather",
     "allreduce",

@@ -18,7 +18,7 @@ per device.
 """
 from __future__ import annotations
 
-from typing import Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -294,6 +294,47 @@ def _concurrent_ppermutes(wire: Optional[str], sends, axis: Axis,
     return recvs
 
 
+def neighbor_exchange(
+    x: jax.Array,
+    sched: CommSchedule,
+    *,
+    axis: Axis = "rank",
+    wire: Optional[str] = None,
+    concurrent: Optional[bool] = None,
+) -> List[Tuple[jax.Array, jax.Array]]:
+    """The permute rounds of :func:`neighbor_allreduce` without its combine:
+    per round, what this device received and the weight it enters the
+    combine with (:func:`neighbor_combine`).  ``wire`` and ``concurrent``
+    as there."""
+    if concurrent is None:
+        concurrent = _default_concurrent()
+    idx = lax.axis_index(axis)
+    sends = _round_sends(x, sched, idx)
+    if concurrent and sched.num_rounds > 1:
+        recvs = _concurrent_ppermutes(wire, sends, axis, sched.rounds)
+    else:
+        recvs = [_wire_ppermute(wire, send, axis, perm)
+                 for send, perm in zip(sends, sched.rounds)]
+    return [(recv, _table(sched.recv_weight[r], idx, x.dtype))
+            for r, recv in enumerate(recvs)]
+
+
+def neighbor_combine(
+    x: jax.Array,
+    sched: CommSchedule,
+    received: Sequence[Tuple[jax.Array, jax.Array]],
+    *,
+    axis: Axis = "rank",
+) -> jax.Array:
+    """``self_weight * x + sum_r w_r * recv_r`` in round order, over the
+    ``(recv_r, w_r)`` of :func:`neighbor_exchange` or equal slices of
+    ``x`` and of every ``recv_r``."""
+    acc = x * _table(sched.self_weight, lax.axis_index(axis), x.dtype)
+    for recv, w in received:
+        acc = acc + recv * w
+    return acc
+
+
 def neighbor_allreduce(
     x: jax.Array,
     sched: CommSchedule,
@@ -318,32 +359,21 @@ def neighbor_allreduce(
     consensus tolerates stale neighbor values.
 
     ``concurrent=True`` emits the edge-colored rounds as one concurrent
-    permute group instead of a sequential permute/combine chain — every
+    permute group instead of a sequential permute chain — every
     round's input is ``x`` (rounds are edge-disjoint by construction,
     :func:`bluefog_tpu.schedule.rounds_edge_disjoint`), so the chain depth
     was never semantically required.  The weighted combine happens after
-    the whole group, in round order, so results match the sequential path
-    exactly up to float summation.  ``None`` (default) resolves to the
-    context's ``round_parallel`` knob, then ``BLUEFOG_ROUND_PARALLEL``,
-    then False.
+    the exchange, in round order, either way.  ``None`` (default) resolves
+    to the context's ``round_parallel`` knob, then
+    ``BLUEFOG_ROUND_PARALLEL``, then False.
+
+    The exchange (:func:`neighbor_exchange`) and the combine
+    (:func:`neighbor_combine`) are callable apart: the fused communicator
+    exchanges one buffer and combines leaf by leaf.
     """
-    if concurrent is None:
-        concurrent = _default_concurrent()
-    idx = lax.axis_index(axis)
-    acc = x * _table(sched.self_weight, idx, x.dtype)
-    if concurrent and sched.num_rounds > 1:
-        sends = _round_sends(x, sched, idx)
-        recvs = _concurrent_ppermutes(wire, sends, axis, sched.rounds)
-        for r, recv in enumerate(recvs):
-            acc = acc + recv * _table(sched.recv_weight[r], idx, x.dtype)
-        return acc
-    for r in range(sched.num_rounds):
-        send = x
-        if sched.uses_dst_weighting:
-            send = x * _table(sched.send_scale[r], idx, x.dtype)
-        recv = _wire_ppermute(wire, send, axis, sched.rounds[r])
-        acc = acc + recv * _table(sched.recv_weight[r], idx, x.dtype)
-    return acc
+    received = neighbor_exchange(x, sched, axis=axis, wire=wire,
+                                 concurrent=concurrent)
+    return neighbor_combine(x, sched, received, axis=axis)
 
 
 def neighbor_allgather(

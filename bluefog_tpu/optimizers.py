@@ -77,11 +77,14 @@ def neighbor_communicator(
     Dynamic topologies compile to a ``lax.switch`` over the period's branches
     (the reference instead re-negotiates per-iteration send/recv lists,
     ``optimizers.py`` + ``examples/pytorch_benchmark.py:182-208``).
-    ``fuse`` gossips one flat buffer per dtype instead of one permute chain
-    per leaf (reference fusion buffers, SURVEY.md §2.4).  ``wire`` compresses
-    the gossiped bytes on the wire (``"bf16"``/``"int8"``/``"fp8"``, see
+    ``fuse`` gossips one buffer per dtype instead of one permute chain per
+    leaf (reference fusion buffers, SURVEY.md §2.4): the buffer is in the
+    leaves' own tile order (:func:`bluefog_tpu.fusion.tile_tree`), it is
+    permuted once a round, and each leaf's span of it combines with the
+    same span of what arrived.  ``wire`` compresses the gossiped bytes on
+    the wire (``"bf16"``/``"int8"``/``"fp8"``, see
     :func:`bluefog_tpu.ops.neighbor_allreduce`); with ``fuse`` the int8/fp8
-    riding scale is per flat buffer, amortizing the side channel across the
+    riding scale is per buffer, amortizing the side channel across the
     whole model.  ``concurrent`` forwards to
     :func:`bluefog_tpu.ops.neighbor_allreduce` (round-parallel emission of
     the edge-colored permute rounds; None = context/env default).
@@ -92,24 +95,50 @@ def neighbor_communicator(
         fuse = False     # degenerate topology (e.g. 1 chip): the op is
                          # elementwise, fusion's concat/split is pure cost
 
+    def wire_of(dtype):
+        # non-real-float leaves (int counters, complex) always travel
+        # uncompressed — quantizing them is meaningless or lossy
+        return wire if jnp.issubdtype(dtype, jnp.floating) else None
+
+    def by_step(fn, step, x):
+        """``fn(sched, x)`` under the schedule of this step."""
+        if schedule is not None:
+            return fn(schedule, x)
+        return lax.switch(step % len(schedules),
+                          [partial(fn, s) for s in schedules], x)
+
+    def leaf(sched, x):
+        return ops.neighbor_allreduce(x, sched, axis=axis,
+                                      wire=wire_of(x.dtype),
+                                      concurrent=concurrent)
+
+    def mix(sched, buf, spans):
+        """One dtype's leaves mixed in tile order: the buffer is exchanged
+        once, and each leaf's span of it combines with the same span of
+        what arrived."""
+        received = ops.neighbor_exchange(buf, sched, axis=axis,
+                                         wire=wire_of(buf.dtype),
+                                         concurrent=concurrent)
+        return [ops.neighbor_combine(
+            buf[a:b], sched, [(recv[a:b], w) for recv, w in received],
+            axis=axis) for a, b in spans]
+
     def comm(params, step):
-        def leaf(x):
-            # non-real-float leaves (int counters, complex) always travel
-            # uncompressed — quantizing them is meaningless or lossy
-            w = wire if jnp.issubdtype(x.dtype, jnp.floating) else None
-            if schedule is not None:
-                return ops.neighbor_allreduce(x, schedule, axis=axis, wire=w,
-                                              concurrent=concurrent)
-            branches = [
-                partial(ops.neighbor_allreduce, sched=s, axis=axis, wire=w,
-                        concurrent=concurrent)
-                for s in schedules
-            ]
-            return lax.switch(step % len(schedules), branches, x)
         with named_span("COMMUNICATE"):
-            if fuse:
-                return fusion.fused_leaf_op(leaf)(params)
-            return jax.tree.map(leaf, params)
+            if not fuse:
+                return jax.tree.map(partial(by_step, leaf, step), params)
+            tiled = fusion.tile_tree(params)
+            # The combine reads the packed buffer (the permutes hold it
+            # anyway), not the leaf it was packed from: behind the barrier
+            # XLA cannot forward the span to the leaf, so a step that
+            # donates its state may write the new parameters over the old
+            # before the permutes are done.  Without it the mixed leaves
+            # and the new parameters each want the other's input buffer,
+            # and the step pays a copy of every mixed leaf.
+            return tiled.untile([
+                by_step(partial(mix, spans=spans), step,
+                        lax.optimization_barrier(buf))
+                for buf, spans in zip(tiled.buffers, tiled.spans)])
 
     return comm
 

@@ -35,7 +35,9 @@ only the Pallas flash-decode kernel, which reads its pages from HBM
 itself, has them written per layer.  That attention meets the layer's
 pages where they lie, every row its own query, in all three families
 (``program_memory()[...]["read"] == "in_place"``,
-``bluefog_serve_cache_positions_read_total{kind}``); shared prefix pages,
+``bluefog_serve_cache_positions_read_total{kind}``), the dense family's
+only as far as the longest live lane of the call reaches, a bound the
+program chooses itself (:func:`.kv_cache.attend_layer`); shared prefix pages,
 a quantized store and a bucket under a third of the rows stage each
 lane's row first, as the cache's own properties say.
 Steady-state decode is a single cached program per (bucket,
@@ -455,12 +457,14 @@ class _DeviceRow:
 
 class _DecodeCall(NamedTuple):
     """A decode call dispatched and not yet read back: its bucket, its
-    lanes' slots and positions ``[replicas, S]`` and the program's outputs
-    before the state (tokens ``[n_devices, steps, S]`` first)."""
+    lanes' slots and positions ``[replicas, S]``, the program's outputs
+    before the state (tokens ``[n_devices, steps, S]`` first) and the cache
+    positions its attention meets (None: the program counts them itself)."""
     S: int
     slots: np.ndarray
     lens: np.ndarray
     out: list
+    read: Optional[int] = None
 
 
 class ServeEngine:
@@ -1780,16 +1784,26 @@ class ServeEngine:
             "of layer (full, window, latent; summed over that kind's "
             "layers and the fused steps)").inc(met, kind=kind)
 
-    def _count_decode_read(self, lanes: int) -> None:
-        """After a dense or latent decode call: what its attention met of
-        the cache, from shapes alone (every row of every layer whole where
-        the read is in place, the lanes' rows where it is staged; a hybrid
-        program sums its two kinds behind its carrier)."""
-        cc = self.cache_cfg
-        rows = cc.rows if self._read_form(lanes) == "in_place" else lanes
-        self._count_positions(
-            self.m.dp * self.cfg.layers * self.scfg.decode_steps_per_call
-            * rows * cc.max_len, "latent" if self._latent else "full")
+    def _decode_positions(self, lanes: int, slots: np.ndarray,
+                          lens: np.ndarray) -> Tuple[int, int]:
+        """What a dense or latent decode call's attention meets of the
+        cache, and what meeting every position of the same rows would be,
+        from what the host staged (nothing is read back; a hybrid program
+        sums its two kinds behind its carrier): the lanes' rows whole where
+        the read is staged, every row of every layer where it is in place,
+        and of a dense cache each fused step's rows only as far as the
+        program's own rule takes them (:func:`.kv_cache.live_bound` of
+        the lanes' positions as they advance, a bound per replica)."""
+        cc, steps = self.cache_cfg, self.scfg.decode_steps_per_call
+        in_place = self._read_form(lanes) == "in_place"
+        rows = cc.rows if in_place else lanes
+        reserved = self.m.dp * self.cfg.layers * steps * rows * cc.max_len
+        if self._latent or not in_place:
+            return reserved, reserved
+        bounds = _kv.live_bound(
+            lens[:, None, :] + np.arange(steps)[None, :, None],
+            (slots != cc.trash_slot)[:, None, :], cc.max_len)
+        return int(self.cfg.layers * rows * bounds.sum()), reserved
 
     # ------------------------------------------------------------------
     # host-side surface (per-REPLICA shapes; the engine broadcasts each
@@ -2029,8 +2043,14 @@ class ServeEngine:
         writes = self._cache_writes("decode", S)
         behind = int(self._flying is not None)
         self._count_decode_call(behind)
-        with self._stage("decode_call", S=int(S), cache_writes=writes,
-                         ahead=behind):
+        lens = np.array(lens, np.int32)
+        attrs = dict(S=int(S), cache_writes=writes, ahead=behind)
+        read = None
+        if not self._hands_logits:
+            read, reserved = self._decode_positions(S, slots, lens)
+            if not self._share:
+                attrs.update(positions_read=read, positions_reserved=reserved)
+        with self._stage("decode_call", **attrs):
             with self._stage("stage_in"):
                 args = self._stage_lanes(
                     "decode", tokens[..., None], slots, lens, prefix_rows,
@@ -2040,7 +2060,7 @@ class ServeEngine:
                     args = args[:-1] + (
                         self._feed_jit(args[-1], self._flying.out[0]),)
                 *out, self._keys, self.cache = self._decode_jit(*args)
-            call = _DecodeCall(S, slots, np.array(lens, np.int32), out)
+            call = _DecodeCall(S, slots, lens, out, read)
             if self._hands_logits and self._decode_logits is None:
                 # nothing has been collected yet: the call in flight's
                 self._decode_logits = (slots, out[-1])
@@ -2104,7 +2124,7 @@ class ServeEngine:
                 self._check_program(*check)
             if due is None:
                 return None
-            S, slots, lens, out = due
+            S, slots, lens, out, read = due
             if self._hands_logits:
                 self._decode_logits = (slots, out.pop())
             if self._ssm:
@@ -2112,8 +2132,9 @@ class ServeEngine:
             gen, *st = self._collect("decode", *out)
             if st:
                 self._note_route_stats(st[0])
-            if not self._hands_logits:
-                self._count_decode_read(S)
+            if read is not None:
+                self._count_positions(
+                    read, "latent" if self._latent else "full")
         # a mark never goes inside a leaf stage: ``collect`` stays a
         # span with none beneath it in every family
         if self._share:
@@ -2405,6 +2426,9 @@ class ServeEngine:
                 "pages": self.cache_cfg.page_orders()}
             if read is not None:
                 self._program_bytes[program]["read"] = read
+            if read == "in_place" and not self._share:
+                self._program_bytes[program]["read_step"] = \
+                    _kv.read_bounds(self.cache_cfg.max_len)[0]
             if self._ssm:
                 cc = self.cache_cfg
                 self._program_bytes[program]["state_bytes"] = \
@@ -2448,5 +2472,8 @@ class ServeEngine:
         state-space model's programs add ``state_bytes``, the recurrent
         states and convolution inputs among the aliased bytes.  A decode or draft program
         also says how its attention ``read``s the cache: ``"in_place"``
-        (no staging buffer among its temporaries) or ``"staged"``."""
+        (no staging buffer among its temporaries) or ``"staged"``; a dense
+        program that reads in place adds ``read_step``, the positions its
+        read's bound advances by (:func:`.kv_cache.read_bounds`; ``max_len``
+        where every row is read whole)."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

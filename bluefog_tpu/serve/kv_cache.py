@@ -74,7 +74,19 @@ are laid out by row, every row meets its own pages in one grouped einsum
 that takes the layer's slice of the stacked tensor as its operand
 (:func:`_layer_pages`), the token is attended beside the pages, and the
 lanes' rows of the result are read back: one pass over K and one over V a
-layer.  The staged form (:func:`attend_rows` through
+layer.  **The dense family's read stops at the batch's longest live
+position** (:func:`attend_layer`, :func:`_attend_dense`): the passes take
+positions ``[0, bound)`` of every row, ``bound`` the least multiple of a
+step that covers the longest lane that holds a request
+(:func:`live_bound`; the step from ``max_len`` alone, an eighth of a row
+in whole 128s, so at most eight bounds: :func:`read_bounds`), chosen ON
+THE DEVICE from the call's lengths by a ``lax.switch`` in every layer
+whose branches are the two passes over a shorter slice of the layer's
+pages and nothing else: one decode program a bucket as before, no option,
+and what lies past the bound is what the mask gave a weight of exactly
+0.0.  A bound per LANE would need a kernel that takes the lengths; the
+latent and the hybrid family read every row whole.  The staged form
+(:func:`attend_rows` through
 :func:`_gather_pages`: each lane's row read into a buffer of its own,
 2 MB a lane and tensor, and read again by the attention) stays where
 "one query a row" does not hold or is not priced: shared prefix pages
@@ -157,8 +169,8 @@ __all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
            "hybrid_prefill", "hybrid_append_tokens", "attend_slots",
            "latent_prefill",
            "latent_append_tokens", "latent_attend_slots", "init_cache",
-           "attend_rows", "attend_layer", "read_in_place", "page_order",
-           "logical_pages",
+           "attend_rows", "attend_layer", "read_in_place", "read_bounds",
+           "live_bound", "page_order", "logical_pages",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
            "layer_append_chunk", "layer_prefill", "quantize_rows",
            "dequantize_rows", "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -469,11 +481,13 @@ def _write_in_turn(buf: jax.Array, new: jax.Array,
     lane's window first and holds them all.  So the window crosses a
     barrier with the write before it in the order it was computed in, and
     is given the buffer's only behind it.  (The CPU's compiler answers
-    the same barrier with a copy of ``buf``.  Token rows no longer need
-    the chain: at 24 layers x 32 lanes the decode program compiled for a
-    v5e holds 2.9 MB of temporaries with it and without; it stays, as
-    the one path that positions-minor pages, the rings and the latent
-    cache share, whose programs are as they were.)"""
+    the same barrier with a copy of ``buf``.  Token rows need no turn
+    and take none, :func:`_write_tokens`: at 24 layers x 32 lanes the
+    decode program compiled for a v5e holds 2.9 MB of temporaries with it
+    and without, a call's writes take 0.279 ms either way, and its three
+    platform switches a lane and tensor were most of what tracing the
+    dense decode program cost at set-up.  Positions-minor pages, the rings
+    and the latent cache share this path, their programs as they were.)"""
     for i, at in enumerate(starts):
         w = _pin(new[:, i:i + 1], tuple(range(new.ndim)))
         buf, w = lax.platform_dependent(
@@ -506,9 +520,14 @@ def _write_tokens(t: jax.Array, slots: jax.Array, pos: jax.Array,
     new = upd.astype(t.dtype)
     new = new.reshape(new.shape[:2] + (1, -1)) if ax == 2 \
         else jnp.expand_dims(new, 3)
-    return _write_in_turn(
-        t, new, [_at(t, 0, rows[i], ax, at[i]) for i in range(S)],
-        lambda w: _pin_window(w, max_len))
+    starts = [_at(t, 0, rows[i], ax, at[i]) for i in range(S)]
+    pin = lambda w: _pin_window(w, max_len)
+    if ax != 2:
+        return _write_in_turn(t, new, starts, pin)
+    # a token row's window waits for no turn (:func:`_write_in_turn`)
+    for i, start in enumerate(starts):
+        t = lax.dynamic_update_slice(t, pin(new[:, i:i + 1]), start)
+    return t
 
 
 def _read_lanes(t: jax.Array, layer: jax.Array,
@@ -702,6 +721,30 @@ def read_in_place(lanes: int, rows: int) -> bool:
     return 3 * lanes >= rows
 
 
+def read_bounds(max_len: int) -> Tuple[int, ...]:
+    """The position counts a dense in-place decode read may stop at, from
+    ``max_len`` alone: multiples of a step of ``max_len / 8`` rounded up to
+    whole 128s (a positions-minor page's lanes stay whole; at least 128),
+    the last of them the whole row; so at most 8, and one (the whole row,
+    no choice to make) where the row is no longer than a step."""
+    step = -(-max_len // (8 * 128)) * 128
+    return tuple(range(step, max_len, step)) + (max_len,)
+
+
+def live_bound(lengths, live, max_len: int):
+    """The least of :func:`read_bounds` that covers the longest LIVE
+    lane's cached positions ``0 .. lengths[i] - 1``, whatever stale length
+    a lane that holds no request carries (``live`` false: its slot is the
+    trash row); the first where no lane is live.  Of the last axis, so a
+    batch of calls gives a bound each.  The same integer arithmetic on
+    numpy arrays (the host counting what a call reads) and on traced ones
+    (the program choosing it)."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    step = read_bounds(max_len)[0]
+    longest = xp.max(xp.where(live, lengths, 0), -1)
+    return xp.minimum(xp.maximum(-(-longest // step), 1) * step, max_len)
+
+
 def _layer_pages(t: jax.Array, layer: jax.Array, pin) -> jax.Array:
     """``t[layer]`` of a stacked cache tensor as an operand the compiler
     reads where it lies: the layer's slice ``[1, rows, ...]``, ``pin``-ned
@@ -742,23 +785,9 @@ def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
     the token will replace.  Every operand keeps the dtype it comes in,
     products accumulate in float32, the softmax over pages and token is
     float32, and the probabilities enter the value product as ``probs``
-    (None: float32 as they are).
-
-    Pages of token rows (one part, ``kts[0]`` and ``vt`` ``[rows, L,
-    kv_heads * d]``: :func:`page_order`) are contracted AS THEY LIE, the
-    lanes never split into heads: a row's queries are spread
-    block-diagonally over them (q head ``h`` in the lanes of kv head ``h
-    // group``, zeros elsewhere) for the scores, every head's
-    probabilities weigh the whole row of values and the head keeps the
-    lanes that are its own.  The zeros add an exact 0.0 to a float32 sum:
-    the by-head result in another order of summation, at ``kv_heads``
-    times the multiply-adds, which a matrix unit has to spare beside the
-    bytes.  Products are exact whatever the dtypes: an explicit precision
-    where an operand is float32, but for float32 probabilities on
-    bfloat16 pages, which go in as their three bfloat16 pieces stacked
-    into one matmul (the same float32 sum in ONE pass over each tile of
-    pages, where the explicit precision makes three: 16 query rows a tile
-    leave the matrix unit no pass to spare).
+    (None: float32 as they are).  (The dense family's read, which also
+    meets token rows and stops at a bound, is :func:`_attend_dense`: the
+    same sums.)
 
     ``stage`` (None: decided from the shapes, :func:`read_in_place`)
     gathers the lanes' rows first and meets them alone.  Returns the
@@ -779,49 +808,14 @@ def _attend_by_row(qs: Sequence[jax.Array], kts: Sequence[jax.Array],
     if ring:
         valid = valid & (j != (at % L)[:, None])
     f32 = dict(preferred_element_type=jnp.float32)
-    if vt.ndim == 3:                                        # token rows
-        (q,), G = qs, H // Hkv
-        exact = dict(f32, precision=lax.Precision.HIGHEST)
-        # which lanes are a head's own: a constant of the program (numpy),
-        # not iotas compared again in every layer
-        own = (np.arange(Hkv * Dv)[None, :] // Dv
-               == np.arange(H)[:, None] // G)               # [H, lanes]
-        q = jnp.where(own, jnp.tile(q, (1, 1, Hkv)), 0)
-        s = jnp.einsum("rhc,rlc->rhl", q, kts[0], **exact)
-        sn = jnp.einsum("rhc,rc->rh", q, kns[0].reshape(R, -1), **exact)
-        vn = jnp.repeat(vn, G, axis=1)                      # [R, H, dv]
-
-        def weigh(p):
-            # float32 probabilities on the pages in their own dtype, no
-            # pass of either rounded.  On bfloat16 pages the float32
-            # probabilities go in as their three bfloat16 pieces (hi + mid
-            # + lo is the float32 exactly), stacked as 3 H rows of ONE
-            # matmul and summed in float32: what an explicit precision
-            # computes in three passes over the pages' tiles (2.50 ms a
-            # pass over V at the serving cell's sizes on a v5e where the
-            # bytes take 2.34), in one (2.38)
-            pages = (((2,), (1,)), ((0,), (0,)))
-            if p.dtype == jnp.float32 and vt.dtype == jnp.bfloat16:
-                hi = p.astype(vt.dtype)
-                rest = p - hi.astype(p.dtype)
-                mid = rest.astype(vt.dtype)
-                lo = (rest - mid.astype(p.dtype)).astype(vt.dtype)
-                wide = lax.dot_general(
-                    jnp.concatenate([hi, mid, lo], 1), vt, pages, **f32)
-                wide = wide[:, :H] + wide[:, H:2 * H] + wide[:, 2 * H:]
-            else:
-                wide = lax.dot_general(p, vt, pages, **exact)
-            return jnp.sum(jnp.where(own, wide, 0.0)
-                           .reshape(R, H, Hkv, Dv), 2)
-    else:
-        qs = [q.reshape(R, Hkv, H // Hkv, -1) for q in qs]
-        total = lambda parts: sum(parts[1:], parts[0])
-        s = total([jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
-                   for q, kt in zip(qs, kts)])
-        sn = total([jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
-                    for q, kn in zip(qs, kns)])
-        vn = vn[:, :, None, :]                              # [R, Hkv, 1, dv]
-        weigh = lambda p: jnp.einsum("rkgl,rkld->rkgd", p, vt, **f32)
+    qs = [q.reshape(R, Hkv, H // Hkv, -1) for q in qs]
+    total = lambda parts: sum(parts[1:], parts[0])
+    s = total([jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
+               for q, kt in zip(qs, kts)])
+    sn = total([jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
+                for q, kn in zip(qs, kns)])
+    vn = vn[:, :, None, :]                                  # [R, Hkv, 1, dv]
+    weigh = lambda p: jnp.einsum("rkgl,rkld->rkgd", p, vt, **f32)
     if scale is not None:
         s, sn = s * scale, sn * scale
     s = jnp.where(jnp.expand_dims(valid, tuple(range(1, s.ndim - 1))), s,
@@ -892,11 +886,131 @@ def attend_rows(q: jax.Array, kl: jax.Array, vl: jax.Array,
     return out.reshape(S, H, Dh).astype(q.dtype)
 
 
+def _attend_dense(q: jax.Array, kl: jax.Array, vl: jax.Array,
+                  layer: jax.Array, slots: jax.Array, lengths: jax.Array,
+                  kn: jax.Array, vn: jax.Array) -> Tuple[jax.Array, Any]:
+    """The dense family's in-place decode read, :func:`_attend_by_row`'s
+    sums in three steps so that the middle one can stop at a bound: the
+    scaled queries ``q`` ``[S, heads, head_dim]`` of one new token a lane
+    over ``layer`` of the stacked tensors ``kl``/``vl``, the token (``kn``,
+    ``vn`` ``[S, kv_heads, head_dim]``) attended beside the pages.
+
+    *What no bound changes* is done once: the lanes' queries, tokens and
+    lengths laid out by row, the token's own score.  *The two passes over
+    the pages* (scores and mask, the softmax's maximum with the token's
+    score in it, the exponentials, the value product and their sum) take
+    positions ``[0, bound)`` of every row of the layer, sliced where the
+    stacked tensor lies as :func:`_layer_pages` slices a layer (the axis
+    before the last in all three page orders).  Where a
+    row holds more than one step (:func:`read_bounds`) the bound is
+    :func:`live_bound` of the call's lanes, a lane live unless its slot is
+    the trash row (the last), chosen on the device by a ``lax.switch``
+    over the bounds, the cache tensors its operands as they lie; a branch
+    holds the passes and nothing else, because every branch is traced,
+    lowered and loaded at set-up (whole reads in the branches cost the
+    serving cell 2.4 s of it).  The positions left out are those whose
+    score the mask sets to ``-inf``: the same float32 sum without its
+    exact zeros.  *The token's share and the division* follow once.
+
+    Pages of token rows (``[layers, rows, L, kv_heads * d]``:
+    :func:`page_order`) are contracted AS THEY LIE, the lanes never split
+    into heads: a row's queries are spread block-diagonally over them (q
+    head ``h`` in the lanes of kv head ``h // group``, zeros elsewhere)
+    for the scores, every head's probabilities weigh the whole row of
+    values and the head keeps the lanes that are its own.  The zeros add
+    an exact 0.0 to a float32 sum: the by-head result in another order of
+    summation, at ``kv_heads`` times the multiply-adds, which a matrix
+    unit has to spare beside the bytes.  Products are exact whatever the
+    dtypes: an explicit precision where an operand is float32, but for
+    float32 probabilities on bfloat16 pages, which go in as their three
+    bfloat16 pieces stacked into one matmul (the same float32 sum in ONE
+    pass over each tile of pages, where the explicit precision makes
+    three: 16 query rows a tile leave the matrix unit no pass to spare).
+
+    Returns the lanes' result ``[S, heads, head_dim]`` in float32 and the
+    cache positions met (rows x bound: traced where the bound is)."""
+    rows = _token_rows(kl)
+    (S, H, Dh), Hkv = q.shape, kn.shape[1]
+    R, L = kl.shape[1], kl.shape[-2]
+    if H % Hkv:
+        raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
+    G = H // Hkv
+    by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
+    q, kn, vn, at = by_row(q), by_row(kn), by_row(vn), by_row(lengths)
+    valid = jnp.arange(L)[None, :] < at[:, None]                  # [R, L]
+    f32 = dict(preferred_element_type=jnp.float32)
+    exact = dict(f32, precision=lax.Precision.HIGHEST)
+    if rows:
+        # which lanes are a head's own: a constant of the program (numpy),
+        # not iotas compared again in every layer
+        own = (np.arange(Hkv * Dh)[None, :] // Dh
+               == np.arange(H)[:, None] // G)               # [H, lanes]
+        q = jnp.where(own, jnp.tile(q, (1, 1, Hkv)), 0)
+        sn = jnp.einsum("rhc,rc->rh", q, kn.reshape(R, -1), **exact)
+        vn = jnp.repeat(vn, G, axis=1)                      # [R, H, d]
+    else:
+        q = q.reshape(R, Hkv, G, Dh)
+        sn = jnp.einsum("rkgd,rkd->rkg", q, kn, **f32)
+        vn = vn[:, :, None, :]                              # [R, Hkv, 1, d]
+    # the stacked tensors pinned to their own axis order once, not each
+    # branch's slice of them (:func:`_layer_pages`): the compiled program
+    # is the same, and a pin is two traces a platform in every branch
+    kl, vl = _pin_window(kl, L), _pin_window(vl, L)
+
+    def weigh(p, vt):
+        if not rows:
+            return jnp.einsum("rkgl,rkld->rkgd", p, vt, **f32)
+        # float32 probabilities on the pages in their own dtype, no pass of
+        # either rounded.  On bfloat16 pages the float32 probabilities go
+        # in as their three bfloat16 pieces (hi + mid + lo is the float32
+        # exactly), stacked as 3 H rows of ONE matmul and summed in
+        # float32: what an explicit precision computes in three passes
+        # over the pages' tiles (2.50 ms a pass over V at the serving
+        # cell's sizes on a v5e where the bytes take 2.34), in one (2.38)
+        pages = (((2,), (1,)), ((0,), (0,)))
+        if vt.dtype != jnp.bfloat16:
+            return lax.dot_general(p, vt, pages, **exact)
+        hi = p.astype(vt.dtype)
+        rest = p - hi.astype(p.dtype)
+        mid = rest.astype(vt.dtype)
+        lo = (rest - mid.astype(p.dtype)).astype(vt.dtype)
+        wide = lax.dot_general(jnp.concatenate([hi, mid, lo], 1), vt, pages,
+                               **f32)
+        return wide[:, :H] + wide[:, H:2 * H] + wide[:, 2 * H:]
+
+    def passes(bound):
+        kt, vt = (lax.dynamic_slice(
+            t, (layer,) + (0,) * (t.ndim - 1),
+            (1,) + t.shape[1:-2] + (bound, t.shape[-1]))[0] for t in (kl, vl))
+        s = lax.dot_general(q, kt, (((2,), (2,)), ((0,), (0,))), **exact) \
+            if rows else jnp.einsum("rkgd,rkld->rkgl", q, kt, **f32)
+        s = jnp.where(jnp.expand_dims(valid[:, :bound],
+                                      tuple(range(1, s.ndim - 1))),
+                      s, -jnp.inf)
+        m = jnp.maximum(jnp.max(s, -1), sn)
+        p = jnp.exp(s - m[..., None])
+        return m, weigh(p, vt), jnp.sum(p, -1)
+    bounds = read_bounds(L)
+    if len(bounds) == 1:
+        bound = L
+        m, out, total = passes(L)
+    else:
+        bound = live_bound(lengths, slots != R - 1, L)
+        m, out, total = lax.switch((bound - 1) // bounds[0],
+                                   [lambda b=b: passes(b) for b in bounds])
+    if rows:        # a head keeps the lanes that are its own
+        out = jnp.sum(jnp.where(own, out, 0.0).reshape(R, H, Hkv, Dh), 2)
+    pn = jnp.exp(sn - m)
+    out = (out + pn[..., None] * vn.astype(jnp.float32)) \
+        / (total + pn)[..., None]
+    return out.reshape(R, H, Dh)[slots], R * bound
+
+
 @jax.named_scope("cache.read")
 def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
                  layer: jax.Array, slots: jax.Array, lengths: jax.Array,
                  new: Dict[str, jax.Array], scale: Optional[float] = None
-                 ) -> Tuple[jax.Array, int]:
+                 ) -> Tuple[jax.Array, Any]:
     """:func:`attend_rows` of raw pages without prefix rows, read IN
     PLACE: one new token per lane (``q`` ``[S, heads, head_dim]``, its
     ``new`` :func:`token_pages` not yet written) over ``layer`` of the
@@ -905,15 +1019,16 @@ def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
     * head_dim]``); ``layer`` may be a scanned index.  The same
     arithmetic as the staged form (the scale folded into the queries,
     pages in their own dtype, exact products, float32 softmax and float32
-    probabilities into the value product) in another order of summation.
-    The queries are float32, but over token rows they keep their own
-    dtype where the scale is a power of two (0.125 for a ``head_dim`` of
-    64): the product with it is exact there, and a matrix unit then takes
-    queries and pages in one dtype.  A bucket under a third of the rows
-    (:func:`read_in_place`) takes the staged form itself.  Returns the
-    lanes' result and the cache positions met."""
+    probabilities into the value product) in another order of summation,
+    over the positions the longest live lane reaches and no further
+    (:func:`_attend_dense`).  The queries are float32, but over token rows
+    they keep their own dtype where the scale is a power of two (0.125 for
+    a ``head_dim`` of 64): the product with it is exact there, and a
+    matrix unit then takes queries and pages in one dtype.  A bucket under
+    a third of the rows (:func:`read_in_place`) takes the staged form
+    itself.  Returns the lanes' result and the cache positions met."""
     rows = _token_rows(kl)
-    S, R, L = q.shape[0], kl.shape[1], kl.shape[2 if rows else 3]
+    S, R, L = q.shape[0], kl.shape[1], kl.shape[-2]
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if not read_in_place(S, R):
@@ -922,11 +1037,8 @@ def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
     ct = jnp.promote_types(q.dtype, jnp.float32)
     if rows and math.frexp(scale)[0] == 0.5:
         ct = q.dtype
-    pin = lambda w: _pin_window(w, L)
-    out, met = _attend_by_row(
-        (q.astype(ct) * scale,), (_layer_pages(kl, layer, pin),),
-        _layer_pages(vl, layer, pin), slots, lengths, (new["k"],),
-        new["v"], None, stage=False)
+    out, met = _attend_dense(q.astype(ct) * scale, kl, vl, layer, slots,
+                             lengths, new["k"], new["v"])
     return out.astype(q.dtype), met
 
 

@@ -293,6 +293,66 @@ def test_attend_layer_reads_in_place(case, against):
                                rtol=2e-5, atol=2e-6)
 
 
+_BOUNDED_READS = {
+    # order: (kv heads, head_dim): three steps of 128 in a row of 384
+    "token_rows": (2, 64),
+    "head_dim_minor": (2, 128),
+    "positions_minor": (1, 64),
+}
+
+
+@pytest.mark.parametrize("longest", [127, 128, 129, 256, 383])
+@pytest.mark.parametrize("order", sorted(_BOUNDED_READS))
+def test_attend_layer_stops_at_the_longest_live_lane(order, longest,
+                                                     monkeypatch):
+    """The dense in-place read takes positions ``[0, bound)`` of every
+    row, ``bound`` the least of ``read_bounds(max_len)`` that covers the
+    longest LIVE lane: in all three page orders, with that lane one under,
+    on and one over a step's edge (and at the next edge, and at the row's
+    end), beside a lane on the trash row that carries a stale length of
+    the whole row, in a cache of more rows than lanes.  Equal to the read
+    of the whole rows (the parent's program: one bound) to float32
+    round-off, with every position past the bound NaN in the pages the
+    bounded read is handed: none of them is read.  ``live_bound`` chooses
+    the same bound from numpy arrays (the host's count) and traced ones
+    (the program's), and the positions met are rows x bound."""
+    import jax
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    (Hkv, Dh), L, layers, rows = _BOUNDED_READS[order], 384, 2, 6
+    assert kv.page_order(Hkv, Dh, L) == order
+    assert kv.read_bounds(L) == (128, 256, 384)
+    rng = np.random.default_rng(longest)
+    normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
+    shape = (layers, rows, L, Hkv * Dh) if order == "token_rows" \
+        else (layers, rows, Hkv, L, Dh)
+    kl, vl = normal(*shape), normal(*shape)
+    slots = jnp.array([3, 5, 0, 1], jnp.int32)          # 5: the trash row
+    lens = jnp.array([longest, L - 1, 0, 40], jnp.int32)
+    live = np.asarray(slots) != rows - 1
+    bound = -(-longest // 128) * 128
+    assert int(kv.live_bound(np.asarray(lens), live, L)) == bound
+    assert int(jax.jit(lambda n, a: kv.live_bound(n, a, L))(
+        lens, jnp.asarray(live))) == bound
+    # a call with no live lane reads the first step, whatever is stale
+    assert int(kv.live_bound(np.asarray(lens), np.zeros(4, bool), L)) == 128
+    q = normal(4, Hkv * 2, Dh)
+    new = token_pages(normal(4, Hkv, Dh), normal(4, Hkv, Dh), "raw",
+                      jnp.float32)
+    past = (jnp.arange(L) >= bound).reshape((L, 1) if order == "token_rows"
+                                            else (1, L, 1))
+    got, met = jax.jit(kv.attend_layer)(
+        q, jnp.where(past, jnp.nan, kl), jnp.where(past, jnp.nan, vl), 1,
+        slots, lens, new)
+    assert int(met) == rows * bound
+    monkeypatch.setattr(kv, "read_bounds", lambda max_len: (max_len,))
+    want, whole = kv.attend_layer(q, kl, vl, 1, slots, lens, new)
+    assert whole == rows * L
+    assert np.isfinite(np.asarray(got)[live]).all()
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               rtol=2e-6, atol=2e-7)
+
+
 _PAGE_ORDERS = {
     # case: (kv heads, head_dim, max_len, the order its pages lie in)
     # the dense serving cell: 16 heads of 64 side by side fill 1,024 lanes
@@ -519,9 +579,9 @@ def test_float32_probabilities_weigh_bfloat16_token_rows(group):
     live = np.asarray(slots) < cc.trash_slot
     q = normal(S, Hkv * group, Dh)
     kn, vn = normal(S, Hkv, Dh), normal(S, Hkv, Dh)
-    run = lambda dt: np.asarray(kv._attend_by_row(
-        (q * 0.125,), (bf["k"][1].astype(dt),), bf["v"][1].astype(dt),
-        slots, lens, (kn,), vn, None)[0])
+    run = lambda dt: np.asarray(kv._attend_dense(
+        q * 0.125, bf["k"].astype(dt), bf["v"].astype(dt), 1, slots, lens,
+        kn, vn)[0])
     got, want = run(jnp.bfloat16), run(jnp.float32)
     assert got.dtype == np.float32
     np.testing.assert_allclose(got[live], want[live], rtol=2e-5, atol=2e-6)
@@ -979,9 +1039,13 @@ def test_e2e_serving_while_training_advances(cpu_devices, tmp_path):
     drains = inside(ev, "bf:engine.decode_drain", "bf:serve.step")
     assert drains and len(calls) + len(drains) == guard
     # a decode token is written once per lane and tensor after each stage's
-    # layer loop (pp = 2 hops), whatever the number of layers
+    # layer loop (pp = 2 hops), whatever the number of layers; what the
+    # dense program's attention meets of the cache rides the same span
     assert all(e[3] == {"S": e[3]["S"], "cache_writes": e[3]["S"] * 2 * 2,
-                        "ahead": e[3]["ahead"]} for e in calls)
+                        "ahead": e[3]["ahead"],
+                        "positions_read": e[3]["positions_reserved"],
+                        "positions_reserved": e[3]["positions_reserved"]}
+               and e[3]["positions_reserved"] > 0 for e in calls)
     assert [e[3]["ahead"] for e in calls].count(0) == len(drains)
     for name in ("stage_in", "dispatch", "collect"):
         assert len(inside(ev, "bf:engine." + name,

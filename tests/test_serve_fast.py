@@ -50,7 +50,7 @@ from bluefog_tpu.serve.engine import _parse_buckets
 from bluefog_tpu.serve.kv_cache import (KVCacheConfig, PrefixCache,
                                         SlotAllocator, attend_rows,
                                         dequantize_rows, quantize_rows,
-                                        store_dtype)
+                                        read_bounds, store_dtype)
 from bluefog_tpu.utils import flight as bfflight
 from bluefog_tpu.utils import metrics as bfm
 
@@ -824,7 +824,12 @@ def _loop_body(hlo_txt):
     import re
     bodies = set(re.findall(r"body=%?([\w.\-]+)", hlo_txt))
     assert len(bodies) == 1, bodies
-    start = hlo_txt.index(f"%{bodies.pop()} (")
+    return _computation(hlo_txt, bodies.pop())
+
+
+def _computation(hlo_txt, name):
+    """The lines of one named computation of a compiled module."""
+    start = hlo_txt.index(f"%{name} (")
     return hlo_txt[start:hlo_txt.index("\n}\n", start)].splitlines()
 
 
@@ -863,8 +868,10 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     makes the lanes' staged rows ``[32, 1024, 1024]`` or the logical view
     of them ``[32, 16, 1024, 64]``, none outside a fusion a layer's pages
     ``[33, 1024, 1024]``, and the loop's body hands the stacked tensor
-    itself to the two fusions that attend over it (the scores over K,
-    the weighted sum over V: one pass each).  Each of the 64 windows
+    itself to ONE conditional whose eight branches (a bound each,
+    ``kv_cache.read_bounds``) hand it to the two fusions that attend over
+    it as far as the bound (the scores over K, the weighted sum over V:
+    one pass each).  Each of the 64 windows
     written is a token's row of every layer, ``[24, 1, 1, 1024]`` with
     the 1,024 minor as in the cache: 8 tiles a layer, where the same
     token in a cache with the positions minor touched 64, the property
@@ -920,12 +927,30 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
             assert _results_of_shape(txt.splitlines(), staged) == []
         assert materialized(txt, 1, (cc.rows,) + row) == []
         assert materialized(txt, 1, (cc.layers, cc.rows) + row) == []
+        # the loop's body hands both stacked tensors to its one
+        # conditional, and each of its branches, a bound each, hands them
+        # to the two fusions that attend over them: the scores over K
+        # come out [rows, heads, bound]
+        def stacked_in(lines):
+            return [ln.split(" = ")[0].strip() for ln in
+                    _results_of_shape(lines, (cc.layers, cc.rows) + row)]
         body = _loop_body(txt)
-        stacked = [ln.split(" = ")[0].strip() for ln in
-                   _results_of_shape(body, (cc.layers, cc.rows) + row)]
-        readers = [ln for ln in body if " fusion(" in ln and any(
-            f"{name}," in ln or f"{name})" in ln for name in stacked)]
-        assert len(stacked) == 2 and len(readers) == 2, (stacked, readers)
+        conds = [ln for ln in body if " conditional(" in ln]
+        assert len(stacked_in(body)) == 2 and len(conds) == 1
+        branches = re.search(r"branch_computations=\{([^}]*)\}",
+                             conds[0]).group(1).replace("%", "").split(", ")
+        bounds = read_bounds(cc.max_len)
+        assert bounds == tuple(range(128, 1025, 128))
+        assert len(branches) == len(bounds)
+        for name, bound in zip(branches, bounds):
+            lines = _computation(txt, name)
+            stacked = stacked_in(lines)
+            readers = [ln for ln in lines if " fusion(" in ln and any(
+                f"{t}," in ln or f"{t})" in ln for t in stacked)]
+            assert len(stacked) == 2 and len(readers) == 2, (
+                name, stacked, readers)
+            assert any(f"f32[{cc.rows},16,{bound}]" in ln
+                       for ln in readers), (name, bound)
     else:
         # the lanes' rows as they are staged: token rows, their lanes
         # split into heads on the way to the logical view
@@ -1286,6 +1311,101 @@ def test_token_row_engine_serves_the_by_head_engines_tokens(
     for k in ref_pages:
         np.testing.assert_allclose(pages[k], ref_pages[k], rtol=1e-3,
                                    atol=1e-5)
+
+
+_BOUNDED_ENGINES = {
+    # name: (heads, d_model, fused steps a call): heads of 64 side by side
+    # are token rows, four heads of 8 are kept by head, positions minor
+    "token_rows": (2, 128, 1),
+    "token_rows_two_tokens_a_call": (2, 128, 2),
+    "by_head": (4, 32, 1),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_BOUNDED_ENGINES))
+def test_dense_decode_read_stops_at_the_longest_live_lane(
+        cpu_devices, monkeypatch, name):
+    """A dense engine whose rows hold two steps of 128 positions decodes
+    across the step's edge (a prompt of 120 tokens beside short ones, 13
+    new tokens each; with two tokens a call the edge falls between a
+    call's two fused steps) and serves the greedy tokens of the engine
+    that reads every row whole (the parent's program: one bound).  The
+    bound is chosen inside the ONE decode program of the bucket: its
+    lowered text holds one conditional in its one layer loop, so the
+    slice of a layer's K and of its V pages at each of
+    ``read_bounds(max_len)`` stands in it once, a branch a bound (``case``
+    ops cannot be counted: every pinned layout is one, over the
+    platforms); ``program_memory`` names the step; and the positions counter advances, call by call, by layers
+    x rows x the bound the host reckons from the lengths it staged (each
+    fused step its own), which is under layers x rows x ``max_len`` a
+    step while the lanes are short, and is what the ``decode_call`` spans
+    carry as ``positions_read`` beside ``positions_reserved``."""
+    import re
+    from bluefog_tpu.serve import kv_cache as kv
+    heads, d_model, steps = _BOUNDED_ENGINES[name]
+    cfg = compose.LMConfig(vocab=32, d_model=d_model, heads=heads, layers=2,
+                           seq_len=32)
+    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    params = compose.init_lm_params(cfg, m, seed=3)
+    scfg = ServeConfig(batch_buckets=(2,), prefill_buckets=(8, 128), slots=2,
+                       max_len=256, decode_steps_per_call=steps)
+    rng = np.random.default_rng(29)
+    prompts = [rng.integers(0, 32, int(n)).tolist() for n in (120, 5, 7)]
+    bounds = kv.read_bounds(scfg.max_len)
+    assert bounds == (128, 256)
+    read = bfm.counter("bluefog_serve_cache_positions_read_total")
+    toks = {}
+    for form in ("bounded", "whole"):
+        if form == "whole":
+            monkeypatch.setattr(kv, "read_bounds", lambda max_len: (max_len,))
+        eng = ServeEngine(m, cfg, params, scfg)
+        eng.warmup()
+        if form == "bounded":
+            text, cc = eng.decode_lowered_text(), eng.cache_cfg
+            lanes = cc.shapes()["k"][-1]
+            for b in bounds:
+                pages = f"1x{cc.rows}x{b}x{lanes}" if name != "by_head" \
+                    else f"1x{cc.rows}x{cc.kv_heads}x{b}x{lanes}"
+                assert len(re.findall(
+                    r"dynamic_slice[^\n]*-> tensor<%sx" % pages, text)) == 2
+            mem = eng.program_memory()["decode S=2"]
+            assert (mem["read"], mem["read_step"]) == ("in_place", 128)
+            want, spans = [], []
+            decode, stage = eng.decode, eng._stage
+
+            def reckoned(tokens, slots, lens, *a, **k):
+                # by hand, replica 0 (the only one): a bound a fused step
+                live = np.asarray(slots)[0] != cc.trash_slot
+                longest = [int((np.asarray(lens)[0][live] + i).max(initial=0))
+                           for i in range(steps)]
+                want.append(cfg.layers * cc.rows * sum(
+                    min(max(-(-n // 128), 1) * 128, 256) for n in longest))
+                return decode(tokens, slots, lens, *a, **k)
+
+            def staged(stage_name, **attrs):
+                if stage_name == "decode_call":
+                    spans.append(attrs)
+                return stage(stage_name, **attrs)
+            monkeypatch.setattr(eng, "decode", reckoned)
+            monkeypatch.setattr(eng, "_stage", staged)
+            before = read.value(kind="full")
+        toks[form] = [r.generated for r in _drain(eng, prompts, max_new=13)]
+        if form == "bounded":
+            counted = read.value(kind="full") - before
+    assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
+    assert toks["bounded"] == toks["whole"]
+    assert all(len(t) == 13 for t in toks["bounded"])
+    # a call's count is published when ITS tokens are collected (a call
+    # later while the scheduler runs one ahead): the sums agree
+    first, whole = (cfg.layers * cc.rows * steps * b for b in bounds)
+    assert counted == sum(want) < whole * len(want)
+    assert [a["positions_read"] for a in spans] == want
+    assert {a["positions_reserved"] for a in spans} == {whole}
+    # the long lane reads one step until its 129th position is cached,
+    # then both; the short one that follows it one again; and with two
+    # tokens a call one call straddles the edge
+    assert want[0] == want[-1] == first and whole in want
+    assert (steps == 1) == (set(want) == {first, whole})
 
 
 @pytest.mark.parametrize("kind,form", [

@@ -4,9 +4,8 @@ The cost model never guesses bytes from shapes: each compile group (one
 per ``(algorithm, topology, wire, weights)`` — the knobs that change what
 crosses the wire) is lowered through ``shard_map`` on the *current*
 backend and the bytes are counted from the compiled program by
-:func:`bluefog_tpu.utils.hlo_bytes.wire_stats` — the same counter
-``tools/strategy_bench.py`` publishes, so a plan's prediction and the
-bench table can never disagree.  Scoring is pure arithmetic on those
+:func:`bluefog_tpu.utils.hlo_bytes.wire_stats`.  Scoring is pure
+arithmetic on those
 bytes: no wall clock, no RNG, so the same inputs always produce the same
 plan (pinned by tests).
 """
@@ -164,8 +163,8 @@ def carving_wire_bytes(carve: CarvingCandidate, cfg, *,
     ``cfg`` is a :class:`~bluefog_tpu.moe.MoELMConfig`, the dense one
     otherwise — lowers it, and splits the pre-optimization StableHLO's
     collective bytes by slice with
-    :func:`~bluefog_tpu.utils.hlo_bytes.stablehlo_wire_stats`, exactly
-    the counter ``tools/lm_bench.py`` publishes.  The model contract
+    :func:`~bluefog_tpu.utils.hlo_bytes.stablehlo_wire_stats`.  The
+    model contract
     (``cfg.validate``) and the carving contract both raise here; the
     carving tuner converts that into an audited rejection.  The process
     context's active carving is restored on exit."""

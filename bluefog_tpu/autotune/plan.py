@@ -6,7 +6,7 @@ trail of everything considered or rejected — plus constructors that turn
 it back into a configured :class:`~bluefog_tpu.optimizers
 .DecentralizedOptimizer` and context state.  ``plan_id`` is a content
 hash of the chosen configuration, so two identical decisions are
-identical artifacts and ``bench.py --plan`` replay is exact.
+identical artifacts and a replay (``plan.apply()``) is exact.
 """
 from __future__ import annotations
 

@@ -10,8 +10,8 @@ Ulysses over ``sp``, gossip-DP over ``rank``, experts over ``expert``.
 
 Gossip remains the ONLY DCN-crossing axis: every expert all_to_all is
 intra-slice by construction (slice-major device sort keeps gossip-DP
-outermost), which tools/lm_bench.py ``--moe`` proves from the
-pre-optimization StableHLO.
+outermost), which tests/test_moe.py proves from the pre-optimization
+StableHLO.
 
 Two dispatch modes share the wiring: the classic static-``capacity``
 padded path (Switch), and the **dropless** fast path

@@ -211,21 +211,6 @@ class MoELMConfig(LMConfig):
         the N in the MFU accounting."""
         return self._n_params(self.top_k)
 
-    def flops_per_token(self) -> float:
-        return (6.0 * self.n_active_params
-                + 6.0 * self.layers * self.d_model * self.seq_len)
-
-    def dense_twin(self) -> LMConfig:
-        """The dense LM with the same *active* FFN parameters per token:
-        ``ffn_mult = top_k * ffn_mult`` and every skeleton field copied.
-        This is the fair serving baseline — tokens/s MoE vs dense at
-        equal active params (Switch-Transformer accounting), not vs the
-        E×-wider dense model nobody would deploy."""
-        fields = {f.name: getattr(self, f.name)
-                  for f in dataclasses.fields(LMConfig)}
-        fields["ffn_mult"] = self.top_k * self.ffn_mult
-        return LMConfig(**fields)
-
 
 def init_moe_params(cfg: MoELMConfig, m: Mesh3D, seed: int = 0,
                     dtype: Any = np.float32,
@@ -444,9 +429,8 @@ def make_moe_probe(cfg: MoELMConfig, m: Mesh3D, *,
 
     Runs the same composed forward OUTSIDE the train step (donation and
     the retrace sentinel stay untouched) and returns the routing health
-    scalars lm_bench ``--moe`` grades: load-balance aux, router z, dropped
-    token fraction, mean token entropy, per-expert dispatch fractions and
-    their usage entropy (nats; ``log(E)`` is perfectly balanced), plus the
+    scalars: load-balance aux, router z, dropped token fraction, mean
+    token entropy, per-expert dispatch fractions and their usage entropy (nats; ``log(E)`` is perfectly balanced), plus the
     plain CE for cross-checking.  All values are global — aggregated over
     stage/tp/expert/sp exactly like the loss.
     """

@@ -1828,10 +1828,10 @@ def init_distributed(strategy: DecentralizedOptimizer, dist_params):
     return state
 
 
-# Argument positions make_train_step donates (params, opt-state).  bench and
-# the AOT tests read this instead of hard-coding the tuple, so a future
-# signature change cannot silently desynchronize the reported `donated` flag
-# from what the executable actually aliases.
+# Argument positions make_train_step donates (params, opt-state).  The AOT
+# tests read this instead of hard-coding the tuple, so a future signature
+# change cannot silently desynchronize the reported `donated` flag from what
+# the executable actually aliases.
 TRAIN_STEP_DONATE_ARGNUMS = (0, 1)
 STATEFUL_TRAIN_STEP_DONATE_ARGNUMS = (0, 1, 2)
 
@@ -2045,9 +2045,8 @@ def make_train_step(
     ``reuse_batch=True`` (requires ``steps_per_call > 1``) feeds the SAME
     batch to every step of the fused loop instead of slicing a steps axis:
     batch leaves stay ``[n, ...]``, so a k-step call costs no k-fold batch
-    replication in HBM or on the host->device path.  This is the synthetic
-    -benchmark shape (bench.py) and the right mode whenever the data loader
-    is not the object under test.
+    replication in HBM or on the host->device path.  This is the right
+    mode whenever the data loader is not the object under test.
 
     ``donate=False`` disables buffer donation for callers that must keep
     reading the pre-step params/state after the call; by default both are

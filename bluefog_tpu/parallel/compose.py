@@ -30,7 +30,8 @@ retrace sentinel all survive composition — the returned step is the same
 :class:`~bluefog_tpu.optimizers._InstrumentedStep` a 1-D run gets.
 
 The module also ships the reference composed LM (:class:`LMConfig`,
-:func:`init_lm_params`, :func:`make_lm_grad_fn`) used by tools/lm_bench.py,
+:func:`init_lm_params`, :func:`make_lm_grad_fn`) used by the benchmark's
+composed-LM cells (perfbench/families/composed_lm.py), chip_smoke.py,
 examples/llm_3d.py, and the compose test oracles.  Its gradient recipe is
 the one tests/test_compose.py pins: NO loss-side collective inside AD —
 the loss is masked to the last stage and seeded once (``1/TP``), the
@@ -78,8 +79,8 @@ class Mesh3D:
     wire.  The ``expert`` axis (``ep``, innermost, 1 by default) shards
     routed-MoE experts: its all_to_alls stay intra-slice by construction —
     see :mod:`bluefog_tpu.moe`.  ``num_experts``/``capacity_factor`` are
-    carried as carving metadata so tools (lm_bench, autotune, flight
-    bundles) grade the MoE shape alongside the mesh shape.
+    carried as carving metadata so tools (autotune, flight bundles) see
+    the MoE shape alongside the mesh shape.
     """
     mesh: Mesh
     dp: int
@@ -129,7 +130,7 @@ class Mesh3D:
             topo_util.to_weight_matrix(self.topology))
 
     def describe(self) -> dict:
-        """JSON-ready summary for bench artifacts / flight bundles."""
+        """JSON-ready summary for plans and flight bundles."""
         return {
             "dp": self.dp, "pp": self.pp, "tp": self.tp, "sp": self.sp,
             "ep": self.ep, "num_experts": self.num_experts,
@@ -170,7 +171,7 @@ def compose_parallelism(
         ``num_experts // ep`` experts, so ``num_experts % ep == 0``);
         optional metadata otherwise.
       capacity_factor: expert capacity factor metadata, surfaced by
-        ``describe()`` and the bench artifacts (the model config holds the
+        ``describe()`` (the model config holds the
         operative value — see ``moe.MoELMConfig``).
       devices: explicit device list; defaults to the context's devices
         (``bf.init`` order) or ``jax.devices()``.  On multislice hardware
@@ -323,8 +324,8 @@ def device_put(m: Mesh3D, tree: Any) -> Any:
 
 # ---------------------------------------------------------------------------
 # The reference composed LM: decoder blocks with TP inside, pipelined over
-# stages, Ulysses over sp, gossip-DP over replicas.  Shared by lm_bench,
-# examples/llm_3d.py, and the compose oracles.
+# stages, Ulysses over sp, gossip-DP over replicas.  Shared by the
+# benchmark's composed-LM cells, examples/llm_3d.py, and the compose oracles.
 # ---------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -368,12 +369,6 @@ class LMConfig:
         return (self.layers * decoder.block_param_count(self)
                 + 2 * self.vocab * self.d_model)
 
-    def flops_per_token(self) -> float:
-        """Training FLOPs/token: 6N weight term + attention score/value
-        matmuls (same accounting as tools/roofline.py)."""
-        return (6.0 * self.n_params
-                + 6.0 * self.layers * self.d_model * self.seq_len)
-
 
 @dataclasses.dataclass(frozen=True)
 class DraftCarve:
@@ -387,8 +382,7 @@ class DraftCarve:
     what makes its early-layer KV writes bit-identical to the target's
     and lets the verify pass reuse them.  The carve is pure metadata:
     the engine uses it to size the truncated ``ppermute`` cycle, and
-    serve_bench uses ``cost_fraction`` to price a draft token against a
-    target token when reporting the speculative speedup model.
+    ``cost_fraction`` prices a draft token against a target token.
     """
     stages: int            # pipeline stages the draft runs (1 .. pp)
     pp: int                # target pipeline depth it was carved from

@@ -126,8 +126,7 @@ def cached_lowering(key, fn: Callable, *args):
     """AOT variant: lower + compile ``fn`` for ``args`` once per ``key`` and
     return the executable.  Use when the call site owns concrete arguments
     and wants XLA's compiled program (cost analysis, HLO text) rather than
-    a jit wrapper — bench.py's step and the roofline probes compile here so
-    a re-run within the process never pays tracing twice."""
+    a jit wrapper: a re-run within the process never pays tracing twice."""
     def build():
         return fn.lower(*args).compile()
     return cached_program(key, build)
@@ -545,7 +544,7 @@ def devices() -> np.ndarray:
 
 
 # The active composed-parallelism carving (a parallel.compose.Mesh3D), set
-# by compose_parallelism() so tools (lm_bench, flight postmortems) can read
+# by compose_parallelism() so tools (autotune, flight postmortems) can read
 # the axis split without threading it through every call.  Cleared on
 # init/shutdown: a carving is only meaningful against the mesh it divided.
 _active_compose = None

@@ -1948,8 +1948,7 @@ class ServeEngine:
         The remainder chunk attends through the page indirection, writes
         its own kv into the private ``slot`` (positions ``start ..``),
         and returns the request's first greedy token.  Cost is one chunk
-        of ``len(tokens)`` instead of the whole prompt — the TTFT win
-        serve_bench measures.
+        of ``len(tokens)`` instead of the whole prompt.
         """
         if not 0 <= slot < self.scfg.slots:
             raise ValueError(f"slot {slot} out of range "
@@ -2290,8 +2289,7 @@ class ServeEngine:
         ``counts`` (raw live token-layer counts), ``entropy`` (mean
         live-token router entropy, nats) and ``tokens`` (live token-layer
         count).  ``None`` for dense engines or before the first call with
-        a live lane — the expert-load-aware scheduler and serve_bench's
-        hot-expert histogram read this."""
+        a live lane — the expert-load-aware scheduler reads this."""
         if not self._routed or self._route_stats is None:
             return None
         E = self.cfg.num_experts
@@ -2310,11 +2308,11 @@ class ServeEngine:
 
     def decode_lowered_text(self, batch: Optional[int] = None) -> str:
         """Pre-optimization StableHLO of one fused-decode bucket (the
-        largest by default) — serve_bench and the AOT tests classify its
-        collectives with :func:`~bluefog_tpu.utils.hlo_bytes.
-        stablehlo_wire_stats` to prove the MoE dispatch/combine
-        all_to_alls (and the pp/tp collectives) stay ICI-side.  Lowering
-        only: nothing executes and the donated cache stays alive."""
+        largest by default), for a test that reads the program itself:
+        which collectives it holds
+        (:func:`~bluefog_tpu.utils.hlo_bytes.stablehlo_wire_stats`), what
+        its cache read slices.  Lowering only: nothing executes and the
+        donated cache stays alive."""
         S = batch if batch is not None else self.scfg.batch_buckets[-1]
         if S not in self.scfg.batch_buckets:
             raise ValueError(f"batch lane count {S} is not a declared "

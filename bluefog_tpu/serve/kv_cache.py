@@ -303,8 +303,8 @@ class KVCacheConfig:
         return payload + scales
 
     def bytes_per_token(self) -> int:
-        """Device bytes one cached token costs (k + v + scales), the
-        serve_bench ``kv_bytes_per_token`` row's per-device term."""
+        """Device bytes one cached token costs (k + v + scales): what the
+        ``bluefog_serve_cache_bytes_per_token`` gauge shows."""
         per_head = self.head_dim * \
             jnp.dtype(store_dtype(self.store, self.dtype)).itemsize
         if self.quantized:

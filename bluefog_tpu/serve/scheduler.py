@@ -32,8 +32,8 @@ and ``tools/metrics_report.py`` pick them up with no schema changes):
 ``bluefog_requests_total{status=...}``, ``bluefog_tokens_generated_total``,
 the ``bluefog_serve_token_latency_seconds`` histogram (p50/p99 via
 ``histogram().percentile``), and the paired
-``bluefog_serve_ttft_{hit,cold}_seconds`` histograms — the serve_bench
-TTFT-under-prefix-hits row.  A ``serve`` flight-bundle block
+``bluefog_serve_ttft_{hit,cold}_seconds`` histograms (time to first
+token with and without a prefix hit).  A ``serve`` flight-bundle block
 (:func:`bluefog_tpu.utils.flight.register_block`) carries the last
 request ids per replica plus the resident prefix pages so
 ``tools/postmortem.py`` can blame the replica that died mid-stream.

@@ -1,9 +1,9 @@
 """Per-chip wire-byte accounting from compiled (SPMD) HLO text.
 
-Shared by ``tools/strategy_bench.py`` (the published strategy table) and
-:mod:`bluefog_tpu.autotune` (the cost model's tier-1 evidence): both need
-the SAME accounting so a plan's predicted bytes and the bench table's
-measured bytes can never disagree about what "wire bytes" means.
+Shared by :mod:`bluefog_tpu.autotune` (the cost model's tier-1 evidence)
+and the byte proofs among the tests: one accounting, so that a plan's
+predicted bytes and a test's measured bytes can never disagree about what
+"wire bytes" means.
 
 The counter parses *result* shapes (operand shapes are not always printed
 by ``Compiled.as_text()``) and applies per-collective-kind accounting —
@@ -399,8 +399,8 @@ def stablehlo_wire_stats(stablehlo_txt: str, slice_size: int):
 # ---------------------------------------------------------------------------
 # dot FLOP accounting from PRE-optimization StableHLO.  Pre-opt is again
 # the honest layer: it counts the matmul work the *program* states (the
-# grouped-vs-capacity MoE comparison lm_bench grades), before the CPU
-# backend's algebraic simplifications can hide padding waste.
+# grouped-vs-capacity MoE comparison of tests/test_moe_dropless.py), before
+# the CPU backend's algebraic simplifications can hide padding waste.
 # ---------------------------------------------------------------------------
 
 # pretty form: `stablehlo.dot_general %a, %b, [batching_dims = [..] x

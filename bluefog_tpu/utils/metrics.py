@@ -573,7 +573,7 @@ def maybe_start_from_env() -> None:
 
 
 # ---------------------------------------------------------------------------
-# Artifact summary (bench.py embeds this)
+# Artifact summary (flight bundles embed this)
 # ---------------------------------------------------------------------------
 
 def metrics_summary() -> dict:

@@ -30,11 +30,10 @@ import traceback
 
 SEED = 0
 
-# The widths the repo's graders use on the chip (bench.py, tools/lm_bench.py,
-# tools/serve_bench.py).  A CPU rehearsal passes smaller ones to the phase
-# functions; the command line has no way to.  The LM keeps lm_bench's widths
-# (d_model, heads, seq, vocab) and cuts its pipeline fill and batch: at
-# lm_bench's own micro 4*pp x batch 4 the [micro, batch, seq, vocab] f32
+# Widths of the size a deployment runs on the chip.  A CPU rehearsal passes
+# smaller ones to the phase functions; the command line has no way to.  The
+# LM (d_model 1024, 16 heads, seq 2048, vocab 32768) keeps its pipeline fill
+# and batch small: at micro 4*pp x batch 4 the [micro, batch, seq, vocab] f32
 # logits and their gradient alone pass a v5e's 16 GB, and with dense
 # attention the [batch, heads, seq, seq] scores do at any fill, so the LM
 # runs the flash kernel (both found by compiling for v5e:2x2).

@@ -43,7 +43,7 @@ def main():
     parser.add_argument("--num-iters", type=int, default=10)
     parser.add_argument("--steps-per-call", type=int, default=1,
                         help="scan K optimizer steps into one compiled "
-                             "program (amortizes dispatch; see bench.py)")
+                             "program (amortizes dispatch)")
     parser.add_argument("--profile", default=None,
                         help="write a timeline to this path prefix")
     args = parser.parse_args()

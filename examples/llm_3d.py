@@ -26,8 +26,8 @@ retrace sentinel all survive composition:
 A copy-task LM (predict the token ``lag`` positions back) trains to low
 loss, proving gradients flow through every stage boundary, tp psum, sp
 all_to_all, AND the gossip mixing at once.  The same model/recipe is what
-``tools/lm_bench.py`` grades and ``tests/test_compose.py`` pins against
-float64 oracles.
+the benchmark's ``pythia-410m`` training cells run at published widths and
+``tests/test_compose.py`` pins against float64 oracles.
 
 Run:  python examples/llm_3d.py --virtual-cpu --steps 60
       python examples/llm_3d.py --virtual-cpu --sp 2 --tp 1 --wire fp8@64

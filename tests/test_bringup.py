@@ -34,18 +34,6 @@ def test_chip_smoke_refuses_cpu_before_compiling():
     assert "phase" not in p.stdout
 
 
-def test_bench_refuses_cpu_without_the_opt_in():
-    env = {k: v for k, v in os.environ.items()
-           if k != "BLUEFOG_BENCH_FORCE_CPU"}
-    p = subprocess.run(
-        [sys.executable, os.path.join(_ROOT, "bench.py")],
-        env={**env, "JAX_PLATFORMS": "cpu"},
-        capture_output=True, text=True, timeout=120)
-    assert p.returncode != 0
-    assert "no TPU" in p.stderr
-    assert p.stdout.strip() == ""              # no metric line
-
-
 @pytest.fixture
 def restore_cache_config():
     old = (jax.config.jax_compilation_cache_dir,

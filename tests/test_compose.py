@@ -440,6 +440,18 @@ from bluefog_tpu.parallel import compose
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 
 
+def _run_script(script):
+    """Run ``script`` in a clean subprocess (it sizes its own device count:
+    the conftest's XLA_FLAGS must not leak) and return its last line's JSON."""
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("BLUEFOG_") and k != "XLA_FLAGS"}
+    p = subprocess.run([sys.executable, "-c", script],
+                       cwd=REPO, capture_output=True, text=True,
+                       timeout=420, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
 def test_compose_contract_errors(cpu_devices):
     """Every carving mistake fails eagerly at compose_parallelism, with a
     message naming the rule — not at trace time deep inside shard_map."""
@@ -525,17 +537,98 @@ print(json.dumps({
 def test_full_four_axis_donation_and_sentinel():
     """dp=2 x pp=2 x tp=2 x sp=2 (16 chips, all four axes live): buffer
     donation survives the composed step and the retrace sentinel stays 0
-    after warmup — the invariants lm_bench grades, pinned here directly."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("BLUEFOG_") and k != "XLA_FLAGS"}
-    p = subprocess.run([sys.executable, "-c", _FULL_AXIS_SCRIPT],
-                       cwd=REPO, capture_output=True, text=True,
-                       timeout=420, env=env)
-    assert p.returncode == 0, p.stderr[-3000:]
-    doc = json.loads(p.stdout.strip().splitlines()[-1])
+    after warmup."""
+    doc = _run_script(_FULL_AXIS_SCRIPT)
     assert doc["donation_intact"] is True
     assert doc["retraces"] == 0
     assert doc["losses"][-1] < doc["losses"][0], doc["losses"]
+
+
+_AOT_BYTES_SCRIPT = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=16"
+os.environ["JAX_PLATFORMS"] = "cpu"
+import json
+import jax
+import optax
+import bluefog_tpu as bf
+from bluefog_tpu import optimizers as bfopt
+from bluefog_tpu.parallel import compose
+from bluefog_tpu.utils.hlo_bytes import stablehlo_wire_stats
+
+bf.init(platform="cpu")
+
+
+def lower(dp, pp, tp, sp, wire):
+    m = compose.compose_parallelism(
+        dp, pp, tp, sp, wire=wire,
+        devices=jax.devices()[:dp * pp * tp * sp])
+    cfg = compose.LMConfig(layers=pp, micro=2 * pp)
+    cfg.validate(m)
+    step, strategy = compose.make_train_step(
+        m, compose.make_lm_grad_fn(cfg, m), optax.adam(5e-3), delayed=True)
+    params = compose.init_lm_params(cfg, m)
+    state = bfopt.init_distributed(strategy, params)
+    toks = compose.make_lm_batch(cfg, m)
+    params = compose.device_put(m, params)
+    st = stablehlo_wire_stats(step.lower(params, state, toks).as_text(),
+                              m.slice_size)
+    st["slice_size"] = m.slice_size
+    st["mesh"] = m.describe()
+    return st
+
+print(json.dumps({"dp4": lower(4, 2, 1, 1, "bf16"),
+                  "dp8": lower(8, 2, 1, 1, "bf16"),
+                  "four_axis": lower(2, 2, 2, 2, "fp8@64")}))
+"""
+
+
+@pytest.fixture(scope="module")
+def aot_wire_bytes():
+    """Three carvings of the composed LM step lowered, never run, in one
+    16-device subprocess: pre-optimization StableHLO states the wire dtypes
+    honestly even where the CPU backend would constant-fold the cast."""
+    return _run_script(_AOT_BYTES_SCRIPT)
+
+
+def test_aot_dcn_bytes_follow_leader_degree(aot_wire_bytes):
+    """The pod-scale scaling law at the heart of the decentralized claim:
+    cross-slice bytes follow DP-leader out-degree (log2 dp for Exp2), not
+    total rank count.  dp=4 -> dp=8 doubles the chips but moves the DCN
+    byte bill only by 3/2 (degree 2 -> 3), at identical per-round bytes."""
+    a, b = aot_wire_bytes["dp4"], aot_wire_bytes["dp8"]
+    assert a["mesh"]["n_chips"] == 8 and b["mesh"]["n_chips"] == 16
+    assert a["mesh"]["leader_degree"] == 2
+    assert b["mesh"]["leader_degree"] == 3
+
+    da, db = a["dcn"], b["dcn"]
+    assert set(da) == set(db) == {"collective_permute"}
+    # one cross-slice permute per gossip round == per out-edge
+    assert da["collective_permute"]["count"] == 2
+    assert db["collective_permute"]["count"] == 3
+    # same per-chip model shards -> identical bytes per round; the total
+    # scales as degree (3/2), NOT as rank count (2x)
+    per_round_a = da["collective_permute"]["bytes"] // 2
+    per_round_b = db["collective_permute"]["bytes"] // 3
+    assert per_round_a == per_round_b > 0
+    assert (db["collective_permute"]["bytes"] * 2
+            == da["collective_permute"]["bytes"] * 3)
+
+
+def test_aot_pp_tp_sp_stay_intra_slice(aot_wire_bytes):
+    """Full 4-axis carving at 16 chips: every PP ppermute, TP/stage psum
+    and Ulysses all_to_all is classified intra-slice at f32; the DCN side
+    holds only the gossip permutes, carrying the fp8 codec payload."""
+    wb = aot_wire_bytes["four_axis"]
+    assert wb["slice_size"] == 8
+    assert set(wb["dcn"]) == {"collective_permute"}
+    assert "f8E4M3FN" in wb["dcn_dtypes"]     # fp8 payload (+ f32 scales)
+    # PP activations, TP/stage reductions and Ulysses head scatter all on
+    # the intra-slice side, none downcast by the gossip codec
+    assert set(wb["ici"]) >= {"all_reduce", "collective_permute",
+                              "all_to_all"}
+    assert wb["ici_dtypes"] == ["f32"]
+    assert not wb["unknown"]
 
 
 _ORACLE_SCRIPT = """
@@ -582,13 +675,7 @@ def test_float64_trajectory_oracle_dp_x_pp_vs_flat_dp():
     scale bug in the pipelined backward (double-psum, missing stage mask,
     mis-seeded cotangent) shows up at step 1; any gossip/layout bug in the
     composed mixing diverges the tail."""
-    env = {k: v for k, v in os.environ.items()
-           if not k.startswith("BLUEFOG_") and k != "XLA_FLAGS"}
-    p = subprocess.run([sys.executable, "-c", _ORACLE_SCRIPT],
-                       cwd=REPO, capture_output=True, text=True,
-                       timeout=420, env=env)
-    assert p.returncode == 0, p.stderr[-3000:]
-    doc = json.loads(p.stdout.strip().splitlines()[-1])
+    doc = _run_script(_ORACLE_SCRIPT)
     a, b = doc["composed"], doc["flat"]
     assert len(a) == len(b) == 6
     np.testing.assert_allclose(a, b, rtol=0, atol=1e-9)

@@ -272,11 +272,15 @@ def _lr0_step(metrics_every_k=None):
     return step, params, state, jnp.zeros((N, D), jnp.float32)
 
 
-def test_chaos_throttle_shows_up_as_live_straggler(ctx, tmp_path):
-    """Acceptance: throttle rank 3, run a probed loop — the detector names
-    rank 3 through the piggybacked probe, the gauges agree, and a
-    postmortem over this process's bundle agrees again."""
-    chaos.install("throttle:from=1,until=99,t=0.05,rank=3")
+@pytest.mark.parametrize("rank,onset", [(3, 1), (5, 2)])
+def test_chaos_throttle_shows_up_as_live_straggler(ctx, tmp_path, rank,
+                                                   onset):
+    """Acceptance: throttle one rank from step ``onset``, run a probed
+    loop — the detector names that rank through the piggybacked probe, the
+    gauges agree, and a postmortem over this process's bundle agrees
+    again: the right rank AND, by the bundle's chaos events, the right
+    onset step."""
+    chaos.install(f"throttle:from={onset},until=99,t=0.05,rank={rank}")
     step, params, state, batch = _lr0_step(metrics_every_k=2)
     for _ in range(6):
         params, state, loss = step(params, state, batch)
@@ -284,20 +288,44 @@ def test_chaos_throttle_shows_up_as_live_straggler(ctx, tmp_path):
 
     t = bfdiag.last_step_times()
     assert t is not None and t.shape == (N,)
-    assert t[3] > 2.0 * np.median(np.delete(t, 3))
-    assert bfdiag.detect_stragglers() == (3,)
-    assert bfm.gauge("bluefog_straggler_rank").value() == 3.0
+    assert t[rank] > 2.0 * np.median(np.delete(t, rank))
+    assert bfdiag.detect_stragglers() == (rank,)
+    assert bfm.gauge("bluefog_straggler_rank").value() == float(rank)
     assert bfm.gauge("bluefog_step_time_skew").value() >= 0.05 * 0.9
     probes = [e for e in flight.events() if e["kind"] == "consensus"]
-    assert probes and probes[-1]["stragglers"] == [3]
+    assert probes and probes[-1]["stragglers"] == [rank]
     assert len(probes[-1]["step_times"]) == N
 
     # the postmortem's single-bundle fallback reads the same probe table
-    doc = postmortem.report_from_files(
-        [flight.dump(str(tmp_path / "flight_rank0.json"))])
-    assert doc["step_time"]["straggler_rank"] == 3
+    bundle = flight.dump(str(tmp_path / "flight_rank0.json"))
+    doc = postmortem.report_from_files([bundle])
+    assert doc["ok"] is True
+    assert doc["step_time"]["straggler_rank"] == rank
     assert doc["step_time"]["skew_s"] >= 0.05 * 0.9
     assert doc["consensus"]                     # trajectory present
+    injected = [e for e in json.load(open(bundle))["events"]
+                if e.get("kind") == "chaos"]
+    assert injected and min(e["step"] for e in injected) == onset
+    assert all(e["rank"] == rank for e in injected)
+
+
+def test_postmortem_blames_committed_fixture():
+    """Deterministic (no subprocess): the committed straggler bundle is
+    blamed with rank 5, onset step 2 — schema drift in either the flight
+    recorder or the postmortem tool breaks this first."""
+    fixture = os.path.join(FIXTURES, "flight_straggler.json")
+    rep = postmortem.report_from_files([fixture])
+    assert rep["schema"] == "bluefog-flight-1"
+    st = rep["step_time"]
+    assert st["straggler_rank"] == 5
+    assert st["skew_s"] == pytest.approx(0.05, rel=0.25)
+    bundle = json.load(open(fixture))
+    chaos_events = [e for e in bundle["events"] if e.get("kind") == "chaos"]
+    assert min(e["step"] for e in chaos_events) == 2
+    assert {e["rank"] for e in chaos_events} == {5}
+    # the in-bundle consensus probe saw the same skew the report blames
+    cons = [e for e in bundle["events"] if e.get("kind") == "consensus"]
+    assert cons[-1]["stragglers"] == [5]
 
 
 def test_probe_without_step_times_unchanged(ctx):

@@ -1,7 +1,7 @@
 """Unit tests for the shared wire-byte counter (bluefog_tpu.utils.hlo_bytes).
 
 Hand-written HLO lines pin the per-collective accounting rules the cost
-model and the strategy bench both rely on: sync/async double-count
+model and the benchmark's wire-byte reader both rely on: sync/async double-count
 avoidance, tuple results, tile annotations, and group-size parsing.  The
 "counter agrees with a real compile" cross-check lives in
 tests/test_autotune.py, where the cost model's predicted bytes are
@@ -127,3 +127,45 @@ def test_total_is_sum_across_kinds():
            "  %cp = f32[1024]{1,0} collective-permute(f32[1024] %y), "
            "source_target_pairs={{0,1}}\n")
     assert total_wire_bytes(txt) == 8192 + 4096
+
+
+def test_wire_stats_per_collective_accounting():
+    """wire_stats derives per-chip wire bytes per collective kind: permute
+    counts the transferred buffer once (also for the -start (in, out, sync)
+    tuple), all-gather counts out - in (-start tuple double-counts the
+    operand), reduce-scatter counts in - out, and all-reduce-start counts
+    the payload once, NOT halved (round-3 advisor item)."""
+    hlo = "\n".join([
+        # permute: 1024 f32 = 4096 B moved once
+        "  %cp = f32[1024]{0} collective-permute(%a), "
+        "source_target_pairs={{0,1}}",
+        # permute-start: (in, out, sync, sync) tuple — still 4096 B
+        "  %cps = (f32[1024]{0:T(8)}, f32[1024]{0:T(8)}, u32[], u32[]) "
+        "collective-permute-start(%b), source_target_pairs={{0,1}}",
+        # all-gather over 8 chips: out 8192 f32 -> wire = out*7/8 = 7*4096 B
+        "  %ag = f32[8192]{0} all-gather(%c), dimensions={0}, "
+        "replica_groups={{0,1,2,3,4,5,6,7}}",
+        # all-gather-start result tuple (in, out): out - in = 7*4096 B
+        "  %ags = (f32[1024]{0}, f32[8192]{0}) all-gather-start(%d), "
+        "dimensions={0}, replica_groups=[1,8]<=[8]",
+        # reduce-scatter over 8: out 1024 f32 -> wire = out*7 = 7*4096 B
+        "  %rs = f32[1024]{0} reduce-scatter(%e), dimensions={0}, "
+        "replica_groups={{0,1,2,3,4,5,6,7}}, to_apply=%add",
+        # all-reduce-start: result IS the payload shape — not halved
+        "  %ars = f32[1024]{0} all-reduce-start(%f), to_apply=%add",
+        # combined multi-buffer permute-start (XLA's combiner): tuple is
+        # (in f32, in bf16, out f32, out bf16, syncs) -> 4096 + 1024 B
+        "  %cpm = (f32[1024]{0}, bf16[512]{0}, f32[1024]{0}, bf16[512]{0}, "
+        "u32[], u32[]) collective-permute-start(%i, %j), "
+        "source_target_pairs={{0,1}}",
+        # fused all-reduce over two buffers: payload is their sum
+        "  %ar = (f32[1024]{0}, bf16[512]{0}) all-reduce(%g, %h), "
+        "to_apply=%add",
+    ])
+    counts, bytes_ = wire_stats(hlo)
+    assert counts == {"collective-permute": 3, "all-gather": 2,
+                      "reduce-scatter": 1, "all-reduce": 2}
+    assert bytes_["collective-permute"] == 2 * 4096 + (4096 + 1024)
+    assert bytes_["all-gather"] == 2 * 7 * 4096
+    assert bytes_["reduce-scatter"] == 7 * 4096
+    assert bytes_["all-reduce"] == 4096 + (4096 + 1024)

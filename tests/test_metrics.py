@@ -498,7 +498,7 @@ def test_training_loop_full_telemetry(ctx, tmp_path):
     assert all(b <= a + 1e-6 for a, b in zip(dist, dist[1:])), dist
     assert dist[-1] < 0.5 * dist[0], dist        # it genuinely contracted
 
-    # the artifact summary block bench.py embeds is complete
+    # the artifact summary block is complete
     ms = bfm.metrics_summary()
     assert ms["step_time_s"]["count"] == 6
     assert ms["step_time_s"]["p50"] is not None

@@ -444,7 +444,8 @@ import bluefog_tpu.optimizers as bfopt
 from bluefog_tpu.moe import MoELMConfig, init_moe_params, make_moe_batch, \\
     make_moe_grad_fn
 from bluefog_tpu.parallel import compose
-from bluefog_tpu.utils.hlo_bytes import stablehlo_wire_stats
+from bluefog_tpu.utils.hlo_bytes import (stablehlo_dot_flops,
+                                         stablehlo_wire_stats)
 
 bf.init(platform="cpu")
 m = compose.compose_parallelism(2, 2, 1, 1, 2, num_experts=4, wire="bf16")
@@ -458,37 +459,54 @@ def stats(cfg):
     params = compose.device_put(m, init_moe_params(cfg, m))
     state = bfopt.init_distributed(strategy, params)
     toks = compose.device_put(m, make_moe_batch(cfg, m))
-    return stablehlo_wire_stats(step.lower(params, state, toks).as_text(),
-                                m.slice_size)
+    shlo = step.lower(params, state, toks).as_text()
+    return dict(stablehlo_wire_stats(shlo, m.slice_size),
+                dot_flops=stablehlo_dot_flops(shlo))
 
+cfgs = {
+    "capacity": base,
+    "dropless_topk": dataclasses.replace(base, dispatch="dropless"),
+    "dropless_ec": dataclasses.replace(base, dispatch="dropless",
+                                       router_mode="expert_choice")}
 out = {}
-for name, cfg in (
-        ("capacity", base),
-        ("dropless_topk", dataclasses.replace(base, dispatch="dropless")),
-        ("dropless_ec", dataclasses.replace(base, dispatch="dropless",
-                                            router_mode="expert_choice"))):
+for name, cfg in cfgs.items():
     s = stats(cfg)
     out[name] = {"dcn": sorted(s["dcn"]), "ici": sorted(s["ici"]),
                  "dcn_bytes": s["dcn_bytes"],
                  "a2a_ici": s["ici"].get("all_to_all", {}).get("count", 0),
-                 "a2a_dcn": s["dcn"].get("all_to_all", {}).get("count", 0)}
+                 "a2a_dcn": s["dcn"].get("all_to_all", {}).get("count", 0),
+                 "a2a_ici_bytes":
+                     s["ici"].get("all_to_all", {}).get("bytes", 0),
+                 "dot_flops": s["dot_flops"]}
+out["rows"] = {
+    "dropless_ec": (base.num_experts // m.ep) * m.ep * (base.batch // m.ep)
+                   * cfgs["dropless_ec"].ec_capacity(m),
+    "capacity": base.num_experts * base.top_k * base.capacity(m),
+    "f_local": base.ffn_mult * base.d_model // m.tp}
 print(json.dumps(out))
 """
 
 
-def test_dropless_all_to_all_stays_ici_dcn_bytes_identical():
-    """dp2 x pp2 x ep2: under BOTH dropless modes every expert all_to_all
-    (data + the topk path's counts exchange) stays ICI-classified, DCN
-    still carries only the gossip permutes, and cross-slice bytes are
-    byte-identical to the capacity path — the dispatch scheme moves data
-    inside the slice only."""
+@pytest.fixture(scope="module")
+def dispatch_programs():
+    """The dp2 x pp2 x ep2 step lowered under each dispatch scheme, never
+    run, in one subprocess."""
     env = {k: v for k, v in os.environ.items()
            if not k.startswith("BLUEFOG_") and k != "XLA_FLAGS"}
     p = subprocess.run([sys.executable, "-c", _BYTES_SCRIPT],
                        cwd=REPO, capture_output=True, text=True,
                        timeout=540, env=env)
     assert p.returncode == 0, p.stderr[-3000:]
-    doc = json.loads(p.stdout.strip().splitlines()[-1])
+    return json.loads(p.stdout.strip().splitlines()[-1])
+
+
+def test_dropless_all_to_all_stays_ici_dcn_bytes_identical(dispatch_programs):
+    """dp2 x pp2 x ep2: under BOTH dropless modes every expert all_to_all
+    (data + the topk path's counts exchange) stays ICI-classified, DCN
+    still carries only the gossip permutes, and cross-slice bytes are
+    byte-identical to the capacity path — the dispatch scheme moves data
+    inside the slice only."""
+    doc = dispatch_programs
     for name in ("capacity", "dropless_topk", "dropless_ec"):
         assert doc[name]["dcn"] == ["collective_permute"], (name, doc[name])
         assert doc[name]["a2a_dcn"] == 0
@@ -498,3 +516,26 @@ def test_dropless_all_to_all_stays_ici_dcn_bytes_identical():
     assert doc["dropless_ec"]["dcn_bytes"] == doc["capacity"]["dcn_bytes"]
     # the topk dropless wire protocol adds the tiny counts all_to_all
     assert doc["dropless_topk"]["a2a_ici"] > doc["capacity"]["a2a_ici"]
+
+
+def test_expert_choice_dropless_beats_capacity_by_the_padding(
+        dispatch_programs):
+    """The dispatch head-to-head on the SAME carving: expert choice's
+    static groups pad nothing, so against the capacity twin (cf 1.25) it
+    runs the padding fraction fewer grouped-GEMM rows, its step's
+    dot_general FLOPs fall by at least one forward FFN at that row delta
+    (everything outside the MoE sublayer is program-identical), and its
+    intra-slice all_to_alls move exactly that fraction fewer bytes."""
+    doc = dispatch_programs
+    ec, cap, rows = doc["dropless_ec"], doc["capacity"], doc["rows"]
+    padding_fraction = 1.0 - 1.0 / 1.25
+    assert rows["dropless_ec"] < rows["capacity"]
+    assert (rows["dropless_ec"] / rows["capacity"]
+            <= 1.0 - padding_fraction + 1e-9)
+    d_model = 32
+    min_expected_delta = (4 * d_model * rows["f_local"]
+                          * (rows["capacity"] - rows["dropless_ec"]))
+    assert ec["dot_flops"] < cap["dot_flops"]
+    assert cap["dot_flops"] - ec["dot_flops"] >= min_expected_delta > 0
+    assert (ec["a2a_ici_bytes"] * rows["capacity"]
+            == cap["a2a_ici_bytes"] * rows["dropless_ec"])

@@ -27,10 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from bluefog_tpu import optimizers as bfopt
 from bluefog_tpu import schedule as sch
 from bluefog_tpu import topology as tu
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                "tools"))
-from strategy_bench import wire_stats  # noqa: E402
+from bluefog_tpu.utils.hlo_bytes import wire_stats
 
 
 @pytest.mark.parametrize("n", [64, 256, 1024, 4096])

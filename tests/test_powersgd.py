@@ -5,9 +5,6 @@ problem (error feedback makes the rank-r approximation error decay),
 projection exactness at full rank, rank lock-step, small-leaf exactness,
 and the wire-bytes cut in the compiled v5e schedule.
 """
-import os
-import sys
-
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -18,10 +15,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import bluefog_tpu as bf
 from bluefog_tpu import optimizers as bfopt
 from bluefog_tpu import topology as tu
-
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir,
-                                "tools"))
-from strategy_bench import wire_stats  # noqa: E402
+from bluefog_tpu.utils.hlo_bytes import wire_stats
 
 N, D, C = 8, 8, 16
 

@@ -506,7 +506,7 @@ def test_scan_layers_matches_unrolled():
     np.testing.assert_allclose(np.asarray(out_u), np.asarray(out_s),
                                rtol=1e-5, atol=1e-5)
 
-    # gradients too: lm_bench TRAINS through the scanned stack by default,
+    # gradients too: training goes through the scanned stack by default,
     # so the backward through nn.scan must match the unrolled backward
     # (stacked-grads vs per-layer grads, plus the shared embed/head)
     def loss_u(p):

@@ -518,10 +518,10 @@ def test_strategy_comm_patterns_on_tpu_schedule(tpu_mesh):
 
 
 def test_flagship_resnet_gossip_step_tpu_schedule(tpu_mesh):
-    """The headline bench path (ResNet + neighbor-allreduce CTA, the shape
-    bench.py builds) compiles for v5e with bf16 convolutions feeding the
-    MXU and the gossip as async fused permutes — the TPU schedule of the
-    graded benchmark, proven without hardware."""
+    """The headline path (ResNet + neighbor-allreduce CTA, the wiring of
+    perfbench's ``resnet50.train-b256`` cell) compiles for v5e with bf16
+    convolutions feeding the MXU and the gossip as async fused permutes —
+    the TPU schedule of the graded benchmark, proven without hardware."""
     from bluefog_tpu import models
 
     model = models.ResNet18(num_classes=10, num_filters=16)
@@ -874,15 +874,15 @@ def test_flash_decode_kernel_lowers_for_tpu(tpu_mesh):
 
 
 @pytest.mark.parametrize("scan_layers,remat", [
-    (False, False),       # stage-0 lm_bench_pallas default (pre-scan era)
-    (True, False),        # lm_bench default: scan_layers on
-    (True, True),         # stage-1 lm_bench_long_pallas: scan + remat
+    (False, False),       # unrolled layers
+    (True, False),        # the default: scan_layers on
+    (True, True),         # long context: scan + remat
 ])
 def test_single_device_lm_pallas_lowers_for_tpu(tpu_mesh, scan_layers,
                                                 remat):
-    """The battery's Pallas LM rows (tools/lm_bench.py on ONE chip:
-    RingTransformerLM with axis=None + use_pallas, scanned and/or
-    rematerialized) fwd+bwd compile through Mosaic for v5e — proven here
+    """The Pallas LM on ONE chip (RingTransformerLM with axis=None +
+    use_pallas, scanned and/or rematerialized): fwd+bwd compile through
+    Mosaic for v5e — proven here
     so the first real-hardware run of local_flash_attention cannot die
     on a lowering bug mid-window.  Compiled replicated over the AOT
     mesh: no collectives, same local program a single chip runs."""

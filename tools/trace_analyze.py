@@ -1,13 +1,14 @@
-"""Step-time decomposition from a jax.profiler trace (round-5 verdict #2).
+"""Step-time decomposition from a jax.profiler trace.
 
-Parses the Chrome-trace JSON that ``jax.profiler.trace`` (invoked by
-``tools/step_sweep.py --trace``) writes, and attributes device time to
-COMPUTE vs COMM, measuring how much communication is EXPOSED (not
-overlapped by compute).  This is the trace-derived evidence behind the
-overlap story: the reference's >=95% scaling claim
-(``README.rst:26-34``) rests on gossip permutes hiding behind backward
-compute, and the same must hold for the XLA async-collective schedule
-this framework relies on (``docs/PERFORMANCE.md`` "overlap proof").
+Parses the Chrome-trace JSON that ``jax.profiler.trace`` writes (a traced
+benchmark run leaves one under ``perfbench_out/trace/<cell>/``), and
+attributes device time to COMPUTE vs COMM, measuring how much
+communication is EXPOSED (not overlapped by compute).  The reference's
+>=95% scaling claim (``README.rst:26-34``) rests on gossip permutes hiding
+behind backward compute; whether the XLA async-collective schedule hides
+them here is what this tool reads off a trace (the benchmark's
+``gossip.exposed_s_per_step`` is the same arithmetic; PERF.md has the
+chip's readings).
 
 Method: take the device track(s) (process names matching TPU/device;
 fallback: the busiest track), classify complete events by op name

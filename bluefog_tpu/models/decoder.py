@@ -748,11 +748,14 @@ def hybrid_param_count(cfg: HybridConfig) -> int:
 
 # ---------------------------------------------------------------------------
 # The single-mixer block: every layer ONE mixer behind one RMSNorm, by a
-# static plan a Mamba-2 mixer, attention that turns nothing, or relu^2
-# experts in a latent
+# static plan a recurrent mixer (Mamba-2, or the gated delta rule with a
+# decay per channel), attention that turns nothing, or sigmoid-routed experts
 # ---------------------------------------------------------------------------
 
-MIXER_KINDS = ("ssm", "full", "experts")
+MIXER_KINDS = ("ssm", "delta", "full", "experts")
+# the kinds that keep a recurrent state a slot; a plan holds at most one
+RECURRENT_KINDS = ("ssm", "delta")
+EXPERT_FORMS = ("relu2", "gated_silu")
 # leaves kept in float32 whatever the served dtype: the router and its
 # selection bias (near-ties), and what the scan's exponents are made of
 FLOAT32_LEAVES = frozenset(("wr", "eb", "dt_bias", "A_log", "Dskip"))
@@ -761,20 +764,29 @@ FLOAT32_LEAVES = frozenset(("wr", "eb", "dt_bias", "A_log", "Dskip"))
 @dataclasses.dataclass(frozen=True)
 class SsmConfig:
     """Sizes of a decoder whose every layer is one mixer, ``x += mixer(
-    RMS(x))``, as ONE chip of an expert-parallel deployment holds it.
-    ``plan`` names each layer's mixer: ``"ssm"`` a Mamba-2 mixer
+    RMS(x))``, as ONE chip of an expert-parallel deployment holds it (a
+    pre-norm layer of two sublayers is two entries).  ``plan`` names each
+    layer's mixer.  Two kinds are recurrent: per sequence a state
+    ``[ssm_heads, ssm_head_dim, ssm_state]`` in float32 and the last
+    ``conv_kernel - 1`` inputs of a causal depthwise convolution, no
+    positions; a plan holds one of the two.  ``"ssm"`` is a Mamba-2 mixer
     (``ssm_heads`` heads of ``ssm_head_dim`` channels, ``B`` and ``C`` in
-    ``ssm_groups`` groups of ``ssm_state``, a causal depthwise convolution
-    of ``conv_kernel`` taps, prompts scanned in chunks of ``chunk``: per
-    sequence a recurrent state ``[ssm_heads, ssm_head_dim, ssm_state]`` and
-    the convolution's last ``conv_kernel - 1`` inputs, no positions),
-    ``"full"`` causal attention of ``heads`` queries on ``kv_heads`` keys
-    and values with no position signal (the state-space layers carry the
-    order), ``"experts"`` the sigmoid-routed layer whose ``relu^2`` experts
-    of width ``expert_ffn`` read and write a ``latent``-wide projection of
-    the hidden state, beside a shared expert of width ``shared_ffn`` on the
-    hidden state itself (the held-experts contract of
-    :class:`LatentConfig`)."""
+    ``ssm_groups`` groups of ``ssm_state``, one scalar decay a head).
+    ``"delta"`` is the gated delta rule with a decay per CHANNEL (Kimi
+    Delta Attention): ``ssm_heads`` heads whose keys and queries have
+    ``ssm_head_dim`` channels and whose values have ``ssm_state``, q, k and
+    v convolved side by side and q, k L2-normed, the decay and the output
+    gate through low-rank pairs of ``delta_rank``, ``beta = delta_beta_max
+    * sigmoid``.  Either scans a prompt in chunks of ``chunk``.  ``"full"``
+    is causal attention of ``heads`` queries on ``kv_heads`` keys and
+    values with no position signal (the recurrent layers carry the order),
+    its output under an elementwise sigmoid gate where ``attn_gate``.
+    ``"experts"`` is the sigmoid-routed layer whose experts of width
+    ``expert_ffn`` (``expert_form``: ``"relu2"`` with no gate, or
+    ``"gated_silu"``) read and write a ``latent``-wide projection of the
+    hidden state, or the hidden state itself where ``latent`` is 0, beside
+    a shared expert of width ``shared_ffn`` of the same form on the hidden
+    state (the held-experts contract of :class:`LatentConfig`)."""
     vocab: int
     d_model: int
     plan: Tuple[str, ...]
@@ -797,7 +809,11 @@ class SsmConfig:
     conv_kernel: int = 4
     chunk: int = 128
     eps: float = 1e-5               # every layer's RMSNorm and the final one
-    ssm_eps: float = 1e-5           # the gated norm inside a Mamba mixer
+    ssm_eps: float = 1e-5           # the norm inside a recurrent mixer
+    expert_form: str = "relu2"
+    attn_gate: bool = False         # sigmoid(h wgate) on attention's output
+    delta_rank: int = 0             # the delta mixer's low-rank pairs
+    delta_beta_max: float = 1.0     # 2: the state's map may turn a key round
     # no fields: this router has no group step (held_moe_ffn reads them)
     n_group = 1
     topk_group = 1
@@ -815,8 +831,16 @@ class SsmConfig:
         return self.ssm_heads * self.ssm_head_dim
 
     @property
+    def recurrent(self) -> Optional[str]:
+        """The plan's recurrent kind, where it has one."""
+        return next((k for k in RECURRENT_KINDS if k in self.plan), None)
+
+    @property
     def conv_dim(self) -> int:
-        """Channels the convolution runs over: x, B and C side by side."""
+        """Channels the convolution runs over: x, B and C side by side (a
+        delta mixer's q, k and v)."""
+        if self.recurrent == "delta":
+            return self.ssm_heads * (2 * self.ssm_head_dim + self.ssm_state)
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
     def layers_of(self, kind: str) -> int:
@@ -830,7 +854,7 @@ class SsmConfig:
     def validate(self, m: Any) -> None:
         for name in ("vocab", "d_model", "ssm_heads", "ssm_head_dim",
                      "ssm_groups", "ssm_state", "heads", "kv_heads",
-                     "head_dim", "latent", "expert_ffn", "shared_ffn",
+                     "head_dim", "expert_ffn", "shared_ffn",
                      "num_experts", "held_experts", "top_k", "chunk"):
             if getattr(self, name) < 1:
                 raise ValueError(f"SsmConfig.{name} must be >= 1")
@@ -838,6 +862,26 @@ class SsmConfig:
             raise ValueError(
                 f"ssm_layer_plan: every layer is one mixer of {MIXER_KINDS}, "
                 f"got {self.plan!r}")
+        if all(k in self.plan for k in RECURRENT_KINDS):
+            raise ValueError(
+                f"ssm_recurrent_kinds: a slot keeps ONE shape of state; the "
+                f"plan holds both of {RECURRENT_KINDS}")
+        if self.expert_form not in EXPERT_FORMS:
+            raise ValueError(
+                f"ssm_expert_form: {self.expert_form!r} is none of "
+                f"{EXPERT_FORMS}")
+        if self.latent < 0:
+            raise ValueError(
+                f"ssm_latent: a latent of {self.latent} (0: the experts "
+                "read the hidden state itself)")
+        if "delta" in self.plan and self.delta_rank < 1:
+            raise ValueError(
+                "ssm_delta_rank: a delta mixer's decay and gate go through "
+                f"low-rank pairs; their rank is {self.delta_rank}")
+        if "delta" in self.plan and self.chunk & (self.chunk - 1):
+            raise ValueError(
+                f"ssm_delta_chunk: the delta rule halves a chunk down to "
+                f"single positions; {self.chunk} is no power of two")
         if self.ssm_heads % self.ssm_groups:
             raise ValueError(
                 f"ssm_head_groups: {self.ssm_heads} state-space heads do not "
@@ -878,10 +922,11 @@ def mamba_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
 
 
 def _silu_conv(cfg: SsmConfig, lp: Dict[str, jax.Array], taps):
-    """``silu(b_conv + sum_j w_conv[:, j] taps[j])`` in float32; ``taps``
-    oldest first, the current input last."""
+    """``silu(b_conv + sum_j w_conv[:, j] taps[j])`` in float32 (no
+    ``b_conv`` where the layer has none); ``taps`` oldest first, the
+    current input last."""
     w = lp["w_conv"].astype(jnp.float32)
-    acc = lp["b_conv"].astype(jnp.float32)
+    acc = lp["b_conv"].astype(jnp.float32) if "b_conv" in lp else 0.0
     for j, tap in enumerate(taps):
         acc = acc + w[:, j] * tap.astype(jnp.float32)
     return jax.nn.silu(acc)
@@ -1036,6 +1081,218 @@ def mamba_gate_out(cfg: SsmConfig, lp: Dict[str, jax.Array], y: jax.Array,
         return v.astype(z.dtype) @ lp["w_out"]
 
 
+def delta_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
+    """The delta mixer's projections of the normed activation ``h`` ``[...,
+    D]``: ``(qkv [..., conv_dim]`` what the convolution runs over, q, k and
+    v side by side, ``f [..., heads * head_dim]`` the raw decay of every key
+    channel through its low-rank pair, ``b [..., heads]`` the raw ``beta``,
+    ``z [..., delta_rank]`` the output gate's input)."""
+    with jax.named_scope("ssm.project"):
+        return (h @ lp["w_in"], (h @ lp["wfa"]) @ lp["wfb"], h @ lp["wb"],
+                h @ lp["wga"])
+
+
+def delta_split(cfg: SsmConfig, qkv: jax.Array):
+    """The convolved channels as ``(q, k [..., heads, head_dim], v [...,
+    heads, state])``, q and k each scaled to unit length per head (``1e-6``
+    under the root) and q by ``head_dim ** -0.5``; in ``qkv``'s dtype."""
+    with jax.named_scope("ssm.project"):
+        H, K, V = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+        lead = qkv.shape[:-1]
+
+        def unit(t, scale):
+            t = t.reshape(lead + (H, K)).astype(jnp.float32)
+            t = t * (scale * lax.rsqrt(
+                jnp.sum(t * t, -1, keepdims=True) + 1e-6))
+            return t.astype(qkv.dtype)
+        return (unit(qkv[..., :H * K], K ** -0.5),
+                unit(qkv[..., H * K:2 * H * K], 1.0),
+                qkv[..., 2 * H * K:].reshape(lead + (H, V)))
+
+
+def delta_discretize(cfg: SsmConfig, lp: Dict[str, jax.Array], f: jax.Array,
+                     b: jax.Array, live: Optional[jax.Array] = None):
+    """Per head and key channel the log decay ``g = -exp(A_log) *
+    softplus(f + dt_bias)`` (so ``<= 0``) and per head ``beta =
+    delta_beta_max * sigmoid(b)``: ``(g [..., H, K], beta [..., H])``,
+    float32.  Where ``live`` is false both are 0: the state passes such a
+    position unchanged (a prompt's padding)."""
+    f32 = jnp.float32
+    lead = b.shape[:-1]
+    g = jax.nn.softplus(f.astype(f32) + lp["dt_bias"].astype(f32)).reshape(
+        lead + (cfg.ssm_heads, cfg.ssm_head_dim)) \
+        * -jnp.exp(lp["A_log"].astype(f32))[:, None]
+    beta = cfg.delta_beta_max * jax.nn.sigmoid(b.astype(f32))
+    if live is not None:
+        g = jnp.where(live[..., None, None], g, 0.0)
+        beta = jnp.where(live[..., None], beta, 0.0)
+    return g, beta
+
+
+# the chunked delta rule runs over this many positions at a time: what a
+# block holds in float32 (q, k, v, the decays and the chunk's solved system,
+# [block, heads, head_dim] each) stays in the tens of MB at any prompt length
+_DELTA_BLOCK = 1024
+
+
+def _delta_system(q: jax.Array, k: jax.Array, G: jax.Array, beta: jax.Array):
+    """For chunks ``[..., C, K]`` (``G`` the inclusive cumulative log decay,
+    ``beta`` ``[..., C]``): ``(T, Aqk)`` ``[..., C, C]``, the inverse of ``I
+    + diag(beta) tril(A, -1)`` with ``A[t, s] = sum_d k_t[d] k_s[d] exp(G_t[d]
+    - G_s[d])``, and ``Aqk[t, s] = sum_d q_t[d] k_s[d] exp(G_t[d] -
+    G_s[d])`` for ``s <= t``.  Both grow from single positions by doubling:
+    a block of ``2m`` is its two halves and the quadrant between them, whose
+    exponents are taken against the earlier half's LAST position, ``exp(G_t
+    - G_ref)`` on the later half's rows and ``exp(G_ref - G_s)`` on the
+    earlier half's columns, each ``<= 0`` whatever the decay; and the
+    inverse of a block lower-triangular matrix is ``[[T1, 0], [-T2 M21 T1,
+    T2]]``, exact, with no power of ``A`` taken."""
+    C = q.shape[-2]
+    lead = q.shape[:-2]
+    T = jnp.ones(lead + (C, 1, 1), jnp.float32)
+    Aqk = jnp.sum(q * k, -1)[..., None, None]
+    m = 1
+    while m < C:
+        nb = C // (2 * m)
+        halves = lambda t: t.reshape(lead + (nb, 2, m) + t.shape[len(lead) + 1:])
+        Gh, kh, qh = halves(G), halves(k), halves(q)
+        ref = Gh[..., 0, m - 1:, :]                      # [..., nb, 1, K]
+        col = kh[..., 0, :, :] * jnp.exp(ref - Gh[..., 0, :, :])
+        row = jnp.exp(Gh[..., 1, :, :] - ref)
+        between = lambda a: jnp.einsum(
+            "...tk,...sk->...ts", a[..., 1, :, :] * row, col)  # [.., m, m]
+
+        def grown(blocks, quadrant):    # [..., nb, 2, m, m] -> [.., 2m, 2m]
+            top = jnp.concatenate(
+                [blocks[..., 0, :, :], jnp.zeros_like(quadrant)], -1)
+            return jnp.concatenate(
+                [top, jnp.concatenate([quadrant, blocks[..., 1, :, :]], -1)],
+                -2)
+        Th = T.reshape(lead + (nb, 2, m, m))
+        M21 = halves(beta)[..., 1, :, None] * between(kh)
+        T = grown(Th, -(Th[..., 1, :, :] @ M21 @ Th[..., 0, :, :]))
+        Aqk = grown(Aqk.reshape(Th.shape), between(qh))
+        m *= 2
+    return T[..., 0, :, :], Aqk[..., 0, :, :]
+
+
+def delta_scan_chunked(cfg: SsmConfig, lp: Dict[str, jax.Array],
+                       qkv: jax.Array, f: jax.Array, b: jax.Array,
+                       true_len: jax.Array):
+    """The gated delta rule ``S_t = (I - beta_t k_t k_t^T) diag(exp g_t)
+    S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` (``S_{-1} = 0``) over
+    one prompt, from the RAW projections of :func:`delta_project` (``qkv``
+    ``[T, conv_dim]`` with zeros before it, ``f``, ``b``).  In chunks of
+    ``cfg.chunk``: with ``u_t = beta_t (v_t - decayed S^T k_t)`` a chunk's
+    ``u`` solve the unit lower triangular system :func:`_delta_system`
+    inverts, so from the state ``S`` a chunk starts with ``U = T beta V - (T
+    beta (K o exp G)) S``, ``O = (Q o exp G) S + Aqk U`` and the next ``S =
+    exp(G_C) o S + (K o exp(G_C - G))^T U``.  The prompt passes in blocks
+    of ``_DELTA_BLOCK`` positions: a block is convolved behind the last
+    inputs of the block before it (:func:`mamba_conv`'s sum and SiLU; a
+    16,384-token prompt convolved whole would hold its 24,576 channels
+    three times over), split and normed (:func:`delta_split`), everything
+    of its chunks that no state enters is made at once, and only the three
+    products with the state run chunk after chunk.  EVERY exponent is a
+    difference of cumulative ``g`` that is ``<= 0``, so a decay of
+    ``exp(-20)`` a step underflows to 0 and overflows nowhere.  Float32
+    throughout.  Positions from ``true_len`` on take ``g = 0`` and ``beta =
+    0``, so the returned state ``[heads, head_dim, state]`` is the one
+    after the last REAL token.  Returns ``(o [T, heads, state]`` in
+    ``qkv``'s dtype, state float32, the ``conv_kernel - 1`` raw inputs
+    before ``true_len``: zeros where the prompt is shorter)."""
+    with jax.named_scope("ssm.scan"):
+        T, H = qkv.shape[0], cfg.ssm_heads
+        V, C, taps = cfg.ssm_state, cfg.chunk, cfg.conv_kernel - 1
+        block = C * max(1, _DELTA_BLOCK // C)
+        Tp = T + (-T) % (C if T <= block else block)
+        block = min(block, Tp)
+        n = block // C
+
+        def blocked(a):                 # [Tp // block, block, ...]
+            if a.shape[0] < Tp:         # behind every real position
+                a = jnp.pad(a, ((0, Tp - T),) + ((0, 0),) * (a.ndim - 1))
+            return a.reshape((Tp // block, block) + a.shape[1:])
+
+        def chunked(a):                 # [block, H, X] -> [n, H, C, X]
+            return a.astype(jnp.float32).reshape(
+                (n, C) + a.shape[1:]).transpose(0, 2, 1, 3)
+
+        def over_block(carry, xs):
+            S, tail = carry
+            raw, fb, bb, alive = xs
+            with jax.named_scope("ssm.conv"):
+                window = jnp.concatenate([tail, raw])
+                conv = _silu_conv(cfg, lp, [window[j:j + block]
+                                            for j in range(taps + 1)])
+            q, k, v = delta_split(cfg, conv.astype(raw.dtype))
+            g, beta = delta_discretize(cfg, lp, fb, bb, alive)
+            qc, kc, vc, G = chunked(q), chunked(k), chunked(v), \
+                jnp.cumsum(chunked(g), axis=2)
+            beta = beta.reshape(n, C, H).transpose(0, 2, 1)     # [n, H, C]
+            inv, Aqk = _delta_system(qc, kc, G, beta)
+            decayed = jnp.exp(G)
+            rhs = beta[..., None] * jnp.concatenate(
+                [vc, kc * decayed], -1)                  # [n, H, C, V + K]
+            solved = inv @ rhs
+            total = G[:, :, -1]                          # [n, H, K]
+            parts = (solved[..., :V], solved[..., V:], Aqk, qc * decayed,
+                     kc * jnp.exp(total[:, :, None] - G), jnp.exp(total))
+
+            def over_chunk(S, part):
+                Uv, W, Aqk, Qg, Kd, total = part
+                U = Uv - W @ S                           # [H, C, V]
+                o = Qg @ S + Aqk @ U
+                return total[..., None] * S + jnp.einsum(
+                    "hck,hcv->hkv", Kd, U), o
+            S, o = lax.scan(over_chunk, S, parts)        # o [n, H, C, V]
+            return (S, raw[block - taps:]), o.transpose(0, 2, 1, 3).reshape(
+                block, H, V).astype(qkv.dtype)
+        live = jnp.arange(Tp) < jnp.minimum(true_len, T)
+        (S, _), o = lax.scan(
+            over_block,
+            (jnp.zeros((H, cfg.ssm_head_dim, V), jnp.float32),
+             jnp.zeros((taps, qkv.shape[1]), qkv.dtype)),
+            tuple(blocked(a) for a in (qkv, f, b, live)))
+        with jax.named_scope("ssm.conv"):
+            at = true_len - taps + jnp.arange(taps)
+            kept = jnp.where((at >= 0)[:, None],
+                             qkv[jnp.clip(at, 0, T - 1)], 0)
+        return o.reshape(Tp, H, V)[:T], S, kept
+
+
+def delta_step(state: jax.Array, g: jax.Array, beta: jax.Array,
+               q: jax.Array, k: jax.Array, v: jax.Array):
+    """One step of the delta rule for a batch of states ``[R, heads,
+    head_dim, state]`` (float32): ``S <- diag(exp g) S``, then ``S <- S +
+    beta k (x) (v - S^T k)``, ``o = S^T q``, with ``g`` ``[R, heads,
+    head_dim]`` and ``beta`` ``[R, heads]`` from :func:`delta_discretize``
+    and ``q``/``k``/``v`` from :func:`delta_split`.  A batch entry with ``g
+    = 0`` and ``beta = 0`` keeps its state as it is.  The decayed state is
+    read once for both of its products (``S^T k`` and ``S^T q``; the new
+    state's read-out is theirs plus ``beta (k . q) u``) and once more to be
+    written.  Returns ``(o [R, heads, state] float32, the new states)``."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    decayed = jnp.exp(g)[..., None] * state
+    u = beta[..., None] * (v - jnp.sum(decayed * k[..., None], axis=-2))
+    o = jnp.sum(decayed * q[..., None], axis=-2) \
+        + jnp.sum(k * q, -1, keepdims=True) * u
+    return o, decayed + k[..., None] * u[..., None, :]
+
+
+def delta_gate_out(cfg: SsmConfig, lp: Dict[str, jax.Array], o: jax.Array,
+                   z: jax.Array) -> jax.Array:
+    """What leaves the delta mixer: ``o`` ``[..., heads, state]`` RMS-normed
+    per head under the scale ``g_o`` FIRST, then gated by ``sigmoid(z
+    wgb)`` (``z`` the gate's low-rank input), then ``w_out``."""
+    with jax.named_scope("ssm.project"):
+        y = rms_norm(o.astype(jnp.float32), lp["g_o"], cfg.ssm_eps)
+        gate = jax.nn.sigmoid((z @ lp["wgb"]).astype(jnp.float32))
+        return (y.reshape(z.shape[:-1] + (-1,)) * gate).astype(z.dtype) \
+            @ lp["w_out"]
+
+
 def gqa_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
     """The attention mixer's projections of the normed activation ``h``:
     ``(q [..., heads, head_dim], k, v [..., kv_heads, head_dim])``; nothing
@@ -1047,10 +1304,23 @@ def gqa_project(cfg: SsmConfig, lp: Dict[str, jax.Array], h: jax.Array):
                 (h @ lp["wv"]).reshape(lead + (cfg.kv_heads, cfg.head_dim)))
 
 
+def gqa_out(cfg: SsmConfig, lp: Dict[str, jax.Array], att: jax.Array,
+            h: jax.Array) -> jax.Array:
+    """What leaves the attention mixer: the heads' outputs ``att`` ``[T,
+    heads, head_dim]`` side by side, under ``sigmoid(h wgate)`` element by
+    element where the configuration has the gate, through ``wo``."""
+    with jax.named_scope("attn.project"):
+        y = att.reshape(h.shape[0], -1)
+        if cfg.attn_gate:
+            y = y * jax.nn.sigmoid(h @ lp["wgate"])
+        return y @ lp["wo"]
+
+
 # per kind of layer: the device scope round the whole layer (its mixer's
-# own scopes lie inside; a Mamba mixer's parts name themselves), and the
+# own scopes lie inside; a recurrent mixer's parts name themselves), and the
 # one the layer's norm and residual run under
 _MIXER_SCOPES = {"ssm": (None, "ssm.project"),
+                 "delta": (None, "ssm.project"),
                  "full": ("attn.full", "attn.project"),
                  "experts": ("ffn", None)}
 
@@ -1063,8 +1333,8 @@ def mixer_block(cfg: SsmConfig, lp: Dict[str, jax.Array], x: jax.Array,
                 kind: str, mix: Callable) -> Tuple[jax.Array, Any]:
     """One layer on ``x`` ``[..., D]``: ``x + mix(RMS(x; g))``.  ``mix(h)
     -> (y, aux)`` is the layer's one mixer on the normed activation, built
-    by the caller from this file's parts (a Mamba mixer over a prompt or a
-    state, attention over a sequence or a cache, the expert layer); the
+    by the caller from this file's parts (a recurrent mixer over a prompt or
+    a state, attention over a sequence or a cache, the expert layer); the
     layer runs under the ``kind``'s own device scopes."""
     whole, own = _MIXER_SCOPES[kind]
     with _scoped(whole):
@@ -1082,6 +1352,14 @@ def ssm_param_shapes(cfg: SsmConfig) -> Dict[str, Any]:
     float32."""
     D, H = cfg.d_model, cfg.ssm_heads
     F, Eh, La = cfg.expert_ffn, cfg.held_experts, cfg.latent
+    keys, r = H * cfg.ssm_head_dim, cfg.delta_rank
+    In = La or D                    # what the routed experts read and write
+    experts = {"we1": (Eh, In, F), "we2": (Eh, F, In),
+               "ws1": (D, cfg.shared_ffn), "ws2": (cfg.shared_ffn, D)} \
+        if cfg.expert_form == "relu2" else {
+            "weg": (Eh, In, F), "weu": (Eh, In, F), "wed": (Eh, F, In),
+            "wsg": (D, cfg.shared_ffn), "wsu": (D, cfg.shared_ffn),
+            "wsd": (cfg.shared_ffn, D)}
     kinds = {
         "ssm": {"g": (D,),
                 "w_in": (D, 2 * cfg.d_inner
@@ -1090,14 +1368,22 @@ def ssm_param_shapes(cfg: SsmConfig) -> Dict[str, Any]:
                 "b_conv": (cfg.conv_dim,), "dt_bias": (H,), "A_log": (H,),
                 "Dskip": (H,), "g_y": (cfg.d_inner,),
                 "w_out": (cfg.d_inner, D)},
+        "delta": {"g": (D,), "w_in": (D, cfg.conv_dim),
+                  "w_conv": (cfg.conv_dim, cfg.conv_kernel),
+                  "wfa": (D, r), "wfb": (r, keys), "A_log": (H,),
+                  "dt_bias": (keys,), "wb": (D, H), "wga": (D, r),
+                  "wgb": (r, H * cfg.ssm_state), "g_o": (cfg.ssm_state,),
+                  "w_out": (H * cfg.ssm_state, D)},
         "full": {"g": (D,), "wq": (D, cfg.heads * cfg.head_dim),
                  "wk": (D, cfg.kv_heads * cfg.head_dim),
                  "wv": (D, cfg.kv_heads * cfg.head_dim),
                  "wo": (cfg.heads * cfg.head_dim, D)},
+        # (a leaf's place in its group seeds its draw: the order stands)
         "experts": {"g": (D,), "wr": (D, cfg.num_experts),
-                    "wdn": (D, La), "wup": (La, D),
-                    "we1": (Eh, La, F), "we2": (Eh, F, La),
-                    "ws1": (D, cfg.shared_ffn), "ws2": (cfg.shared_ffn, D)}}
+                    **({"wdn": (D, La), "wup": (La, D)} if La else {}),
+                    **experts}}
+    if cfg.attn_gate:
+        kinds["full"]["wgate"] = (D, cfg.heads * cfg.head_dim)
     if cfg.route_bias:
         kinds["experts"]["eb"] = (cfg.num_experts,)
     return {"layers": tuple(dict(kinds[k]) for k in cfg.plan),

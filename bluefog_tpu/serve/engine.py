@@ -84,13 +84,15 @@ slot's rows in the full layers, its rings in the window layers): the plan
 is unrolled, a prompt's attention is blocked, decode reads the cache in
 place, and the same fast paths and carvings are refused by name.
 
-A **state-space model** (:class:`~bluefog_tpu.models.decoder.SsmConfig`:
-every layer ONE mixer, by a static plan a Mamba-2 mixer, attention that
-turns nothing, or ``relu^2`` experts in a latent under the same full-width
-router) is the fourth pair of programs, over a cache with a third kind of
+A **single-mixer model** (:class:`~bluefog_tpu.models.decoder.SsmConfig`:
+every layer ONE mixer, by a static plan a recurrent mixer (Mamba-2, or the
+gated delta rule with a decay per channel), attention that turns nothing
+(gated where the configuration says so), or experts under the same
+full-width router, ``relu^2`` in a latent or gated SiLU on the hidden
+state) is the fourth pair of programs, over a cache with a third kind of
 tensor (:class:`.kv_cache.SsmCacheConfig`): a slot owns, beside its rows in
 the attention layers, a fixed-size recurrent state and the convolution's
-kept inputs in every state-space layer.  A prompt is scanned in chunks and
+kept inputs in every recurrent layer.  A prompt is scanned in chunks and
 overwrites the slot's state whole; a decode step updates every row's state
 where it lies.  The scheduler needs nothing new: a state lives on the
 device between calls, so a call staged one ahead finds it there.
@@ -98,6 +100,7 @@ device between calls, so a call staged one ahead finds it there.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import os
 from typing import Any, NamedTuple, Optional, Sequence, Tuple
 
@@ -481,7 +484,7 @@ class ServeEngine:
     (``first`` / ``blocks`` / ``shared``) and the latent programs run.
     """
 
-    # a state-space model's programs (set per engine; the class's word is
+    # a single-mixer model's programs (set per engine; the class's word is
     # what an engine built before the family, or around it, goes by)
     _ssm = False
 
@@ -564,14 +567,8 @@ class ServeEngine:
             window_layers=cfg.layers_of("window"), slots=scfg.slots,
             max_len=scfg.max_len, window=cfg.window, kv_heads=cfg.kv_heads,
             head_dim=cfg.head_dim, dtype=scfg.dtype) \
-            if self._hybrid else _kv.SsmCacheConfig(
-            full_layers=cfg.layers_of("full"),
-            ssm_layers=cfg.layers_of("ssm"), slots=scfg.slots,
-            max_len=scfg.max_len, kv_heads=cfg.kv_heads,
-            head_dim=cfg.head_dim, ssm_heads=cfg.ssm_heads,
-            ssm_head_dim=cfg.ssm_head_dim, ssm_state=cfg.ssm_state,
-            conv_taps=cfg.conv_kernel - 1, conv_dim=cfg.conv_dim,
-            dtype=scfg.dtype) \
+            if self._hybrid else _kv.SsmCacheConfig.of(
+            cfg, scfg.slots, scfg.max_len, scfg.dtype) \
             if self._ssm else _kv.KVCacheConfig(
             layers=cfg.layers // m.pp, slots=scfg.slots,
             max_len=scfg.max_len, kv_heads=cfg.heads // m.tp,
@@ -586,7 +583,7 @@ class ServeEngine:
 
         def _zeros():
             if self._share:
-                # a state-space layer's state is float32 whatever is served
+                # a recurrent layer's state is float32 whatever is served
                 dtypes = cc.dtypes() if self._ssm else {}
                 return {name: jnp.zeros((1,) + shape,
                                         dtypes.get(name, cc.dtype))
@@ -627,7 +624,8 @@ class ServeEngine:
                     "bluefog_serve_cache_bytes_per_slot",
                     "device bytes of the cache a slot owns, by kind of "
                     "layer (full: every position; window: a ring; ssm: a "
-                    "recurrent state and its convolution's kept inputs)"
+                    "recurrent layer's state and its convolution's kept "
+                    "inputs)"
                 ).set(float(size), kind=kind)
         self._chunk_jit = self._build(self._chunk_body) \
             if (scfg.spec_decode or scfg.prefix_pages) else None
@@ -1467,8 +1465,19 @@ class ServeEngine:
         return jax.tree.map(lambda t: t[None],
                             (gen, st, logits, table, cache))
 
-    @staticmethod
-    def _flash_causal(q, k, v):
+    # keys one call of the flash forward kernel takes: a head's K and V
+    # lie whole in VMEM there (16,384 keys of 128 channels are the kernel's
+    # whole scoped limit before a score is held)
+    _FLASH_KEY_BLOCK = 8192
+
+    @classmethod
+    def _key_blocks(cls, Tpad: int) -> int:
+        """Key blocks a full layer's attention over a prompt padded to
+        ``Tpad`` takes (:meth:`_flash_causal`)."""
+        return -(-Tpad // cls._FLASH_KEY_BLOCK)
+
+    @classmethod
+    def _flash_causal(cls, q, k, v):
         """Causal attention of one whole prompt on a full layer, ``q``
         ``[T, H, Dh]`` on compact ``k``/``v`` ``[T, Hkv, Dh]``: the flash
         forward kernel (K and V of a head whole in VMEM, queries in
@@ -1476,11 +1485,34 @@ class ServeEngine:
         v5e it takes 21.5 ms where XLA's blocked form took 58.7; under a
         window of 128 the kernel still meets every key (21.4 ms) and the
         band of :func:`decoder.window_attention` takes 2.9 (PERF.md §6,
-        PR 35), so window layers do not come here."""
-        o, l, _ = _pa.attention_block_partial(
-            q[None], k[None], v[None], jnp.int32(0), jnp.int32(0),
-            causal=True, scale=q.shape[-1] ** -0.5)
-        return (o / l[..., None])[0].astype(q.dtype)
+        PR 35), so window layers do not come here.  A prompt past
+        ``_FLASH_KEY_BLOCK`` positions meets its keys a block at a time:
+        a block of queries meets every key block up to its own (causal
+        inside that one, whole for the earlier ones), and its partials
+        ``(o, l, m)`` over them are merged under their common maximum."""
+        T, B = q.shape[0], cls._FLASH_KEY_BLOCK
+        part = functools.partial(_pa.attention_block_partial, causal=True,
+                                 scale=q.shape[-1] ** -0.5)
+        if T <= B:
+            o, l, _ = part(q[None], k[None], v[None], jnp.int32(0),
+                           jnp.int32(0))
+            return (o / l[..., None])[0].astype(q.dtype)
+        outs = []
+        for qa in range(0, T, B):           # a block of queries at a time
+            o = l = m = None
+            for ka in range(0, qa + 1, B):
+                ob, lb, mb = part(q[None, qa:qa + B], k[None, ka:ka + B],
+                                  v[None, ka:ka + B], jnp.int32(qa),
+                                  jnp.int32(ka))
+                if o is None:
+                    o, l, m = ob, lb, mb
+                    continue
+                top = jnp.maximum(m, mb)    # finite: a query meets itself
+                was, now = jnp.exp(m - top), jnp.exp(mb - top)
+                o = o * was[..., None] + ob * now[..., None]
+                l, m = l * was + lb * now, top
+            outs.append((o / l[..., None])[0].astype(q.dtype))
+        return jnp.concatenate(outs)
 
     def _hybrid_prefill_body(self, params, cache, keys, staged):
         """One padded prompt, its attention blocked (the flash forward
@@ -1516,25 +1548,38 @@ class ServeEngine:
         return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
 
     # ------------------------------------------------------------------
-    # the state-space model's programs (one mixer a layer, one chip's share)
+    # the single-mixer model's programs (one mixer a layer, by the plan
+    # recurrent, attention or experts; one chip's share)
     # ------------------------------------------------------------------
 
     def _ssm_ffn(self, live, grouped):
         """An expert layer's mixer: the full-width router under its
-        selection bias, the held ``relu^2`` experts in the latent, the
+        selection bias, the held experts in the configuration's form
+        (``relu^2`` or gated SiLU; in a latent where it has one), the
         shared expert (:func:`~bluefog_tpu.moe.layers.held_moe_ffn`).
-        ``grouped``: the pairs through the grouped kernel (a prompt), else
-        every token through every held expert (a decode step).  ``aux`` is ``(carrier [E + 4], chosen [tokens,
-        top_k])``."""
+        ``grouped``: the pairs through the grouped kernel (a prompt, past
+        :attr:`_PROMPT_FFN_CHUNK` tokens that many at a time), else every
+        token through every held expert (a decode step).  ``aux`` is
+        ``(carrier [E + 4], chosen [tokens, top_k])``."""
         cfg = self.cfg
         held = self._held_mask()
+        stacked = ("we1", "we2") if cfg.expert_form == "relu2" \
+            else ("weg", "weu", "wed")
 
         def ffn(lp, h):
             if grouped:
-                lp = {**lp, "we1": lp["we1"][None], "we2": lp["we2"][None]}
-            y, idx, weight = held_moe_ffn(
-                cfg, lp, h, live, jnp.int32(0) if grouped else None,
-                form="relu2")
+                lp = {**lp, **{k: lp[k][None] for k in stacked}}
+            part = lambda hl: held_moe_ffn(
+                cfg, lp, hl[0], hl[1], jnp.int32(0) if grouped else None,
+                form=cfg.expert_form)
+            T, C = h.shape[0], self._PROMPT_FFN_CHUNK
+            if not grouped or T <= C or T % C:
+                y, idx, weight = part((h, live))
+            else:
+                y, idx, weight = jax.tree.map(
+                    lambda t: t.reshape((T,) + t.shape[2:]),
+                    lax.map(part, (h.reshape(T // C, C, -1),
+                                   live.reshape(T // C, C))))
             return y, (self._carrier(idx, weight, live, held), idx)
         return ffn
 
@@ -1571,9 +1616,10 @@ class ServeEngine:
         return x, cache, news, acc, jnp.stack(chosen)
 
     def _ssm_decode_body(self, params, cache, keys, lanes):
-        """Fused decode over the state cache: a state-space layer moves
-        every row's state on by its lane's token where the state lies (its
-        convolution's kept inputs too), an attention layer attends over its
+        """Fused decode over the state cache: a recurrent layer (a Mamba
+        mixer or a delta-rule mixer, by the plan) moves every row's state
+        on by its lane's token where the state lies (its convolution's kept
+        inputs too), an attention layer attends over its
         lanes' rows plus the token's own K and V, which land once per lane
         and tensor after the loop.  Beside the tokens it hands out every
         fused step's logits and the experts each expert layer chose (left
@@ -1609,10 +1655,24 @@ class ServeEngine:
                             q, cache["k"][index], cache["v"][index],
                             slot_ids, lens, new)
                         met[0] += read
-                        with jax.named_scope("attn.project"):
-                            y = out.reshape(h.shape[0], -1) @ lp["wo"]
-                        return y, (cache, new, None, None)
+                        return decoder.gqa_out(cfg, lp, out, h), (
+                            cache, new, None, None)
                     return attention
+                conv = lambda x, prev: decoder.mamba_conv(cfg, lp, x, prev)
+
+                def delta(h):
+                    qkv, f, b, z = decoder.delta_project(cfg, lp, h)
+                    qkv, nc = _kv.ssm_conv_step(cache, index, slot_ids, qkv,
+                                                conv)
+                    with jax.named_scope("ssm.scan"):
+                        g, beta = decoder.delta_discretize(cfg, lp, f, b)
+                    o, nc = _kv.ssm_state_step(
+                        nc, index, slot_ids, decoder.delta_step, g, beta,
+                        *decoder.delta_split(cfg, qkv))
+                    return decoder.delta_gate_out(cfg, lp, o, z), (
+                        nc, None, None, None)
+                if kind == "delta":
+                    return delta
 
                 def mamba(h):
                     z, xbc, dt = decoder.mamba_project(cfg, lp, h)
@@ -1661,11 +1721,13 @@ class ServeEngine:
         return out
 
     def _ssm_prefill_body(self, params, cache, keys, staged):
-        """One padded prompt: a state-space layer scans it in chunks and
-        leaves in the slot the state after its last REAL token and the
-        convolution inputs before ``true_len``, both overwritten whole; an
-        attention layer's K and V land in the slot's row under the flash
-        forward kernel.  Padding is routed to no expert, and only the last
+        """One padded prompt: a recurrent layer (a Mamba mixer or a
+        delta-rule mixer, by the plan) scans it in chunks and leaves in the
+        slot the state after its last REAL token and the convolution inputs
+        before ``true_len``, both overwritten whole; an attention layer's K
+        and V land in the slot's row under the flash forward kernel (a
+        prompt past one block of keys in several, :meth:`_flash_causal`).
+        Padding is routed to no expert, and only the last
         real position is read out; beside its logits the program hands
         out the experts each expert layer chose (:meth:`prefill_chosen`)."""
         params, cache, keys, staged = self._split_args(
@@ -1684,10 +1746,19 @@ class ServeEngine:
                     nc = _kv.hybrid_prefill(cache, "full", index, slot_id,
                                             k, v, true_len)
                     att = self._flash_causal(q, k, v)
-                    with jax.named_scope("attn.project"):
-                        y = att.reshape(h.shape[0], -1) @ lp["wo"]
-                    return y, (nc, None, None, None)
+                    return decoder.gqa_out(cfg, lp, att, h), (
+                        nc, None, None, None)
                 return attention
+
+            def delta(h):
+                qkv, f, b, z = decoder.delta_project(cfg, lp, h)
+                o, state, kept = decoder.delta_scan_chunked(
+                    cfg, lp, qkv, f, b, true_len)
+                nc = _kv.ssm_prefill(cache, index, slot_id, state, kept)
+                return decoder.delta_gate_out(cfg, lp, o, z), (
+                    nc, None, None, None)
+            if kind == "delta":
+                return delta
 
             def mamba(h):
                 z, xbc, dt = decoder.mamba_project(cfg, lp, h)
@@ -1741,15 +1812,15 @@ class ServeEngine:
         attrs = dict(pairs=pairs, rows=rows, experts_hit=hit,
                      positions=int(seen.sum()))
         if self._ssm:
-            # the live lanes' states, which every state-space layer reads
+            # the live lanes' states, which every recurrent layer reads
             # and writes whole in every fused step; and the positions the
             # attention layers' contraction met, as the hybrid family's
             attrs["state_lanes"] = int(live.sum())
             _metrics.counter(
                 "bluefog_serve_state_updates_total",
                 "recurrent states decode calls read and wrote (live lanes "
-                "x state-space layers x fused steps)").inc(
-                    attrs["state_lanes"] * cfg.layers_of("ssm")
+                "x recurrent layers x fused steps)").inc(
+                    attrs["state_lanes"] * self.cache_cfg.ssm_layers
                     * scfg.decode_steps_per_call)
             met = int(self._route_stats[:, E + 4].sum())
             attrs["positions_read_full"] = met // (
@@ -1919,8 +1990,17 @@ class ServeEngine:
         # a sealed prefix is no admission: no key, the count stays
         key_id, count = self._admission(replica, row) if admit \
             else (np.zeros((R,), np.int32),) * 2
+        attrs = {}
+        if (self._hybrid or self._ssm) and self.cfg.layers_of("full"):
+            # what the full layers' attention took of the flash kernel
+            attrs["key_blocks"] = self._key_blocks(Tpad)
+            _metrics.counter(
+                "bluefog_serve_prefill_key_blocks_total",
+                "key blocks the full layers' attention of prefill calls "
+                "took (one to a call up to the flash kernel's block of "
+                "keys)").inc(attrs["key_blocks"])
         with self._stage("prefill_call", Tpad=Tpad, tokens=len(tokens),
-                         replica=replica):
+                         replica=replica, **attrs):
             with self._stage("stage_in"):
                 args = self._args(self._expand("prefill", self._pack(
                     toks, slot_id, true_len, key_id, count)))
@@ -2388,9 +2468,9 @@ class ServeEngine:
         deferred = kind in ("decode", "draft") and self._defer_appends
         if self._ssm:
             # K and V of the attention layers a lane (a token's once after
-            # the loop), a state and its convolution inputs a state-space
+            # the loop), a state and its convolution inputs a recurrent
             # layer: per lane from a prompt, whole by a decode step
-            full, ssm = (self.cfg.layers_of(k) for k in ("full", "ssm"))
+            full, ssm = self.cache_cfg.full_layers, self.cache_cfg.ssm_layers
             return lanes * 2 * (full + ssm) if not deferred \
                 else steps * 2 * (lanes + ssm)
         if self._hybrid and not deferred:

@@ -117,9 +117,10 @@ is the cache of one compressed vector per token, which every head of a
 layer shares (:func:`latent_attend_slots`).
 
 **A third kind of tensor: a state with no positions**
-(:class:`SsmCacheConfig`).  A state-space layer keeps per slot a recurrent
-state ``[heads, head_dim, state]`` in float32 and the last inputs of its
-convolution: no length, overwritten whole by a prompt
+(:class:`SsmCacheConfig`).  A recurrent layer (a state-space mixer, or a
+delta-rule mixer whose state is a matrix of key by value channels a head)
+keeps per slot a state ``[heads, head_dim, state]`` in float32 and the last
+inputs of its convolution: no length, overwritten whole by a prompt
 (:func:`ssm_prefill`), read AND written whole by every decode step
 (:func:`ssm_conv_step`, :func:`ssm_state_step`: every row of a layer's
 states in one pass, where they lie, a row no lane names passing
@@ -1369,8 +1370,8 @@ def attend_slots(q: jax.Array, kt: jax.Array, vt: jax.Array,
 
 @dataclasses.dataclass(frozen=True)
 class SsmCacheConfig:
-    """Shapes of the cache of a model with state-space layers beside
-    attention layers::
+    """Shapes of the cache of a model with recurrent layers (state-space
+    or delta-rule: ``decoder.RECURRENT_KINDS``) beside attention layers::
 
         k, v: [full_layers, slots + 1, kv_heads, max_len, head_dim]
         ssm:  [ssm_layers,  slots + 1, ssm_heads, ssm_head_dim, ssm_state]
@@ -1381,7 +1382,9 @@ class SsmCacheConfig:
     rounding of it is fed back every step); ``conv`` the ``conv_taps`` raw
     inputs of the layer's convolution before the next token, in ``dtype``.
     Neither has a length.  The last row is the trash slot; no prefix pages
-    and no quantized store."""
+    and no quantized store.  The sizes are the recurrent mixer's own
+    (:meth:`of`): a Mamba mixer's heads, channels and ``B``/``C`` width, a
+    delta mixer's heads, key channels and value channels."""
     full_layers: int
     ssm_layers: int
     slots: int
@@ -1395,6 +1398,21 @@ class SsmCacheConfig:
     conv_dim: int
     dtype: Any = jnp.float32
     prefix_slots = 0                # what the engine's host code asks for
+
+    @classmethod
+    def of(cls, cfg: Any, slots: int, max_len: int,
+           dtype: Any) -> "SsmCacheConfig":
+        """The cache of the single-mixer model ``cfg``
+        (:class:`~bluefog_tpu.models.decoder.SsmConfig`): a state and kept
+        inputs for every layer of its plan's recurrent kind."""
+        return cls(
+            full_layers=cfg.layers_of("full"),
+            ssm_layers=cfg.layers_of(cfg.recurrent),
+            slots=slots, max_len=max_len, kv_heads=cfg.kv_heads,
+            head_dim=cfg.head_dim, ssm_heads=cfg.ssm_heads,
+            ssm_head_dim=cfg.ssm_head_dim, ssm_state=cfg.ssm_state,
+            conv_taps=cfg.conv_kernel - 1, conv_dim=cfg.conv_dim,
+            dtype=dtype)
 
     @property
     def rows(self) -> int:
@@ -1493,15 +1511,16 @@ def ssm_conv_step(cache: Dict[str, jax.Array], layer: int, slots: jax.Array,
 
 @jax.named_scope("ssm.scan")
 def ssm_state_step(cache: Dict[str, jax.Array], layer: int, slots: jax.Array,
-                   step, log_a: jax.Array, dx: jax.Array, B: jax.Array,
-                   C: jax.Array) -> Tuple[jax.Array, Dict[str, jax.Array]]:
-    """One decode token per lane through the ``layer``-th state-space
+                   step, *inputs: jax.Array
+                   ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One decode token per lane through the ``layer``-th recurrent
     layer's recurrence, the states updated WHERE THEY LIE: the lanes'
-    inputs (``log_a`` ``[S, heads]``, ``dx`` ``[S, heads, head_dim]``,
-    ``B``/``C`` ``[S, groups, state]``) are laid out by row, every row's
-    state takes ``step(states, log_a, dx, B, C) -> (y, states)`` in one
-    pass over the layer's slice of the donated tensor (a row no lane names
-    gets ``log_a = 0`` and ``dx = 0`` and passes unchanged), and the
+    ``inputs`` (``[S, ...]`` each: a Mamba mixer's ``log_a``, ``dx``, ``B``
+    and ``C``, a delta mixer's ``g``, ``beta``, ``q``, ``k`` and ``v``) are
+    laid out by row, every row's state takes ``step(states, *inputs) ->
+    (y, states)`` in one pass over the layer's slice of the donated tensor
+    (a row no lane names gets zeros, a step under which either recurrence
+    passes its state unchanged), and the
     lanes' rows of ``y`` are read back.  A gather of the lanes' states
     and a scatter behind it would move each state four times where this
     moves it twice; like the in-place attention read it is the form for a
@@ -1509,9 +1528,7 @@ def ssm_state_step(cache: Dict[str, jax.Array], layer: int, slots: jax.Array,
     t = cache["ssm"]
     at = (0,) * (t.ndim - 5) + (layer,)     # a leading axis of one or none
     rows = t.shape[-4]
-    y, new = step(t[at], _by_row(rows, slots, log_a, 0.0),
-                  _by_row(rows, slots, dx, 0.0), _by_row(rows, slots, B, 0.0),
-                  _by_row(rows, slots, C, 0.0))
+    y, new = step(t[at], *(_by_row(rows, slots, a, 0.0) for a in inputs))
     return y[slots], {**cache, "ssm": lax.dynamic_update_slice(
         t, new.reshape((1,) * len(at) + new.shape).astype(t.dtype),
         at + (0,) * 4)}

@@ -231,6 +231,11 @@ VOCABULARY["ssm"] = tuple(
     {"ssm.project", "ssm.conv", "ssm.scan", "attn.project", "cache.write",
      "ffn", "moe.route", "moe.latent", "moe.experts", "moe.shared",
      "readout"} | more for more in ({"cache.read"}, {"attn.full"}))
+# the same family's second recurrent kind: a delta-rule mixer lies under the
+# three scopes a Mamba mixer has (they mean the same parts), its experts read
+# the hidden state itself (no latent projections)
+VOCABULARY["delta"] = tuple(scopes - {"moe.latent"}
+                            for scopes in VOCABULARY["ssm"])
 # four residual streams round the latent block: the latent programs'
 # vocabulary with the stream maps' two scopes
 VOCABULARY["latent_streams"] = tuple(
@@ -247,9 +252,12 @@ def make_engine(family, cpu_devices):
                         max_len=16))
     import test_serve_hybrid
     import test_serve_latent
+    import test_serve_delta
     import test_serve_ssm
     if family == "ssm":
         return test_serve_ssm.make_engine(cpu_devices)
+    if family == "delta":
+        return test_serve_delta.make_engine(cpu_devices)
     if family == "latent_streams":
         return test_serve_latent.make_engine(cpu_devices,
                                              test_serve_latent.STREAMED)
@@ -349,7 +357,7 @@ def test_lm_train_step_splits_by_phase_direction_and_block_scope(
     assert step._cache_size() == size
 
 
-@pytest.mark.parametrize("family", ["latent", "hybrid", "ssm"])
+@pytest.mark.parametrize("family", ["latent", "hybrid", "ssm", "delta"])
 def test_held_work_mark_sits_under_the_decode_call_not_in_collect(
         family, cpu_devices, monkeypatch):
     """``collect`` closes before the mark opens, and the mark closes before
@@ -377,6 +385,52 @@ def test_held_work_mark_sits_under_the_decode_call_not_in_collect(
     assert order[-5:] == [("open", "collect"), ("close", "collect"),
                           ("open", "held_work"), ("close", "held_work"),
                           ("close", "decode_call")]
+
+
+def test_every_instruction_of_a_delta_layer_lies_under_the_recurrent_scopes():
+    """``ffn``, ``attn.*`` and ``cache.*`` keep meaning what they mean: a
+    delta-rule layer, over a prompt (projections, the blocks' convolution,
+    the L2 norms, the in-chunk system, the state's landing) and over a
+    state (one token a lane), compiles to instructions under
+    ``ssm.project``, ``ssm.conv`` and ``ssm.scan`` alone, all three."""
+    import test_serve_delta as delta
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.serve import kv_cache as kv
+    cfg = delta.CFG
+    lp = jax.tree.map(lambda a: a[0], delta.make_params(cfg, 1)["layers"][2])
+    cc = kv.SsmCacheConfig.of(cfg, 4, 16, jnp.float32)
+    cache = {k: jnp.zeros(s, cc.dtypes()[k]) for k, s in cc.shapes().items()}
+
+    def prompt(lp, x, cache):
+        def mix(h):
+            qkv, f, b, z = decoder.delta_project(cfg, lp, h)
+            o, state, kept = decoder.delta_scan_chunked(cfg, lp, qkv, f, b,
+                                                        jnp.int32(6))
+            return decoder.delta_gate_out(cfg, lp, o, z), kv.ssm_prefill(
+                cache, 1, jnp.int32(2), state, kept)
+        return decoder.mixer_block(cfg, lp, x, "delta", mix)
+
+    def token(lp, x, cache):
+        slots = jnp.array([2, 0, 4, 4])
+
+        def mix(h):
+            qkv, f, b, z = decoder.delta_project(cfg, lp, h)
+            qkv, nc = kv.ssm_conv_step(
+                cache, 1, slots, qkv,
+                lambda x, prev: decoder.mamba_conv(cfg, lp, x, prev))
+            with jax.named_scope("ssm.scan"):
+                g, beta = decoder.delta_discretize(cfg, lp, f, b)
+            o, nc = kv.ssm_state_step(nc, 1, slots, decoder.delta_step, g,
+                                      beta, *decoder.delta_split(cfg, qkv))
+            return decoder.delta_gate_out(cfg, lp, o, z), nc
+        return decoder.mixer_block(cfg, lp, x, "delta", mix)
+
+    for fn, T in ((prompt, 8), (token, 4)):
+        x = jnp.ones((T, cfg.d_model))
+        tab = tracing._scope_table(
+            jax.jit(fn).lower(lp, x, cache).compile().as_text())
+        scopes = {scope for scope, _ in tab["ops"].values()} - {""}
+        assert scopes == {"ssm.project", "ssm.conv", "ssm.scan"}, scopes
 
 
 def test_bluefog_trace_arms_at_init_and_flush_writes_the_tables(

@@ -49,46 +49,69 @@ def local_flash_attention(q, k, v, causal: bool, scale: float,
                           axis: Optional[Axis] = None):
     """Non-collective flash attention over this device's arrays.
 
-    Reuses the ring kernels with both offsets at 0: the forward keeps each
-    ``[block_q, T]`` score tile in VMEM (never HBM), the backward recomputes
-    scores blockwise (FlashAttention-2 recurrence).  VMEM bounds the usable
-    ``block_q x T`` product; for sequences past that, ring attention chunks
+    Both directions keep K and V rows whole in VMEM and walk the key axis
+    inside the kernel: a ``[block_q, block_k]`` score tile at a time (never
+    HBM), only over the key blocks the causal mask leaves a query block.
+    The forward folds them on-line into ``out`` and ``lse``; the backward
+    recomputes the scores (FlashAttention-2 recurrence).  The whole rows
+    bound the usable ``T``; for sequences past that, ring attention chunks
     K/V across devices instead.  ``axis``: the enclosing shard_map axis, if
-    any (only used to stamp the kernel's scalar offsets as axis-varying).
+    any (only used to stamp the backward kernel's scalar offsets as
+    axis-varying).
     """
-    out, _ = _local_fwd_impl(q, k, v, causal, scale, block_q, interpret, axis)
+    out, _ = _local_fwd_impl(q, k, v, causal, scale, block_q, interpret)
     return out
 
 
-def _local_fwd_impl(q, k, v, causal, scale, block_q, interpret, axis):
+def _publish_visited(direction, q, k, causal, block_q):
+    """Trace-time gauge: the share of the kernel's [block_q, block_k] tiles
+    its key-block loop runs over (both offsets are zero here)."""
+    from . import pallas_attention as pa
+    from ..utils import metrics
+
+    Tq, Tk = q.shape[1], k.shape[1]
+    qb, kb = pa._blocking(Tq, Tk, q.shape[-1], block_q,
+                          direction == "backward")
+    visited, total = pa.key_blocks_visited(Tq, Tk, qb, kb, causal=causal)
+    metrics.gauge(
+        "bluefog_flash_key_blocks_visited_share",
+        "share of the local flash kernel's [block_q, block_k] score tiles "
+        "its key-block loop computes, as last traced",
+    ).set(visited / total, **{"pass": direction})
+
+
+def _local_fwd_impl(q, k, v, causal, scale, block_q, interpret):
     from . import pallas_attention as pa
 
-    zero = _zero_offset(axis)
-    o, l, m = pa.attention_block_partial(
-        q, k, v, zero, zero, causal=causal, scale=scale,
-        block_q=block_q, interpret=interpret)
-    denom = jnp.where(l == 0.0, 1.0, l)
-    out = (o / denom[..., None]).astype(q.dtype)
-    lse = jnp.where(l == 0.0, -jnp.inf, m + jnp.log(denom))
-    return out, lse
+    _publish_visited("forward", q, k, causal, block_q)
+    return pa.attention_local_forward(
+        q, k, v, causal=causal, scale=scale, block_q=block_q,
+        interpret=interpret)
 
 
-def _local_fwd(q, k, v, causal, scale, block_q, interpret, axis):
-    out, lse = _local_fwd_impl(
-        q, k, v, causal, scale, block_q, interpret, axis)
-    return out, (q, k, v, out, lse)
-
-
-def _local_bwd(causal, scale, block_q, interpret, axis, res, g):
+def _local_bwd_impl(res, g, causal, scale, block_q, interpret, axis):
+    """f32 ``(dq, dk, dv)`` of the local attention from its residuals."""
     from . import pallas_attention as pa
 
     q, k, v, out, lse = res
     do = g.astype(jnp.float32)
     delta = jnp.sum(do * out.astype(jnp.float32), axis=-1)
     zero = _zero_offset(axis)
-    dq, dk, dv = pa.attention_block_backward(
+    _publish_visited("backward", q, k, causal, block_q)
+    return pa.attention_block_backward(
         q, k, v, do, lse, delta, zero, zero,
         causal=causal, scale=scale, block_q=block_q, interpret=interpret)
+
+
+def _local_fwd(q, k, v, causal, scale, block_q, interpret, axis):
+    out, lse = _local_fwd_impl(q, k, v, causal, scale, block_q, interpret)
+    return out, (q, k, v, out, lse)
+
+
+def _local_bwd(causal, scale, block_q, interpret, axis, res, g):
+    q, k, v = res[:3]
+    dq, dk, dv = _local_bwd_impl(
+        res, g, causal, scale, block_q, interpret, axis)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -238,8 +261,8 @@ def _pallas_ulysses(q, k, v, axis, causal, scale, block_q, interpret):
 
 def _ulysses_fwd_impl(q, k, v, axis, causal, scale, block_q, interpret):
     qg, kg, vg = (_scatter_heads(t, axis) for t in (q, k, v))
-    out_g, lse = _local_fwd_impl(
-        qg, kg, vg, causal, scale, block_q, interpret, axis)
+    out_g, lse = _local_fwd_impl(qg, kg, vg, causal, scale, block_q,
+                                 interpret)
     return _gather_heads(out_g, axis), (qg, kg, vg, out_g, lse)
 
 
@@ -250,20 +273,12 @@ def _ulysses_fwd(q, k, v, axis, causal, scale, block_q, interpret):
 
 
 def _ulysses_bwd(axis, causal, scale, block_q, interpret, res, g):
-    from . import pallas_attention as pa
-
-    qg, kg, vg, out_g, lse = res
     # the cotangent is sequence-sharded like the output; move it to the
     # head-sharded layout the kernel residuals live in
-    do = _scatter_heads(g, axis).astype(jnp.float32)
-    delta = jnp.sum(do * out_g.astype(jnp.float32), axis=-1)
-    zero = _zero_offset(axis)
-    dqg, dkg, dvg = pa.attention_block_backward(
-        qg, kg, vg, do, lse, delta, zero, zero,
-        causal=causal, scale=scale, block_q=block_q, interpret=interpret)
-    return tuple(
-        _gather_heads(d, axis).astype(t.dtype)
-        for d, t in ((dqg, qg), (dkg, kg), (dvg, vg)))
+    grads = _local_bwd_impl(res, _scatter_heads(g, axis), causal, scale,
+                            block_q, interpret, axis)
+    return tuple(_gather_heads(d, axis).astype(t.dtype)
+                 for d, t in zip(grads, res[:3]))
 
 
 _pallas_ulysses.defvjp(_ulysses_fwd, _ulysses_bwd)

@@ -364,3 +364,220 @@ class TestGQA:
             np.testing.assert_allclose(out, expected, rtol=1e-4, atol=1e-5)
         finally:
             bf.shutdown()
+
+
+# --- the key axis blocked inside the kernels (PR 48) -------------------------
+#
+# Blocks forced small so that SEVERAL key blocks are visited at lengths the
+# interpreter runs quickly: the private entry points take the block sizes
+# the public ones choose from the shapes.
+
+def _oracle(q, k, v, do, *, causal, q_off, k_off, window, scale):
+    """float32 autodiff of dense attention under the kernels' mask:
+    ``out, lse, (dq, dk, dv)``; a row no key is visible to gives out = 0,
+    lse = -inf and no gradient.  Grouped heads: k, v repeated, their
+    gradients summed over each group."""
+    G = q.shape[2] // k.shape[2]
+    qp = q_off + np.arange(q.shape[1])
+    kp = k_off + np.arange(k.shape[1])
+    keep = np.ones((q.shape[1], k.shape[1]), bool)
+    if causal:
+        keep = qp[:, None] >= kp[None, :]
+        if window:
+            keep &= qp[:, None] - kp[None, :] < window
+    keep = jnp.asarray(keep)[None, :, None, :]
+
+    def fwd(q_, k_, v_):
+        f32 = lambda t: t.astype(jnp.float32)
+        s = jnp.einsum("bihd,bjhd->bihj", f32(q_),
+                       jnp.repeat(f32(k_), G, axis=2)) * scale
+        s = jnp.where(keep, s, -jnp.inf)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        m = jnp.where(jnp.isneginf(m), 0.0, m)
+        p = jnp.exp(s - m)
+        l = jnp.sum(p, axis=-1, keepdims=True)
+        out = jnp.einsum("bihj,bjhd->bihd", p / jnp.where(l == 0, 1.0, l),
+                         jnp.repeat(f32(v_), G, axis=2))
+        lse = jnp.where(l == 0, -jnp.inf, m + jnp.log(jnp.where(l == 0, 1, l)))
+        return out, lse[..., 0]
+
+    (out, lse), vjp = jax.vjp(fwd, q, k, v)
+    grads = vjp((do.astype(jnp.float32), jnp.zeros_like(lse)))
+    return out, lse, grads
+
+
+def _qkv(seed, B, Tq, Tk, H, Hkv, D, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    q, do = (jnp.asarray(rng.normal(size=(B, Tq, H, D)), dtype)
+             for _ in range(2))
+    k, v = (jnp.asarray(rng.normal(size=(B, Tk, Hkv, D)), dtype)
+            for _ in range(2))
+    return q, k, v, do
+
+
+# (Tq, Tk, block_q, block_k, q_off, k_off, window, H, Hkv, causal, dtype)
+KEY_BLOCK_CASES = {
+    "kb8": (64, 64, 16, 8, 0, 0, 0, 2, 2, True, jnp.float32),
+    "kb16": (64, 64, 16, 16, 0, 0, 0, 2, 2, True, jnp.float32),
+    "kb_over_qb": (64, 64, 8, 16, 0, 0, 0, 2, 2, True, jnp.float32),
+    "under_diagonal": (64, 64, 16, 8, 64, 0, 0, 2, 2, True, jnp.float32),
+    "across_diagonal": (64, 64, 16, 8, 24, 0, 0, 2, 2, True, jnp.float32),
+    "keys_ahead": (64, 64, 16, 16, 0, 20, 0, 2, 2, True, jnp.float32),
+    "above_diagonal": (64, 64, 16, 8, 0, 64, 0, 2, 2, True, jnp.float32),
+    "window": (64, 64, 16, 8, 0, 0, 20, 2, 2, True, jnp.float32),
+    "window_offsets": (64, 64, 16, 16, 40, 0, 24, 2, 2, True, jnp.float32),
+    "window_under_block": (64, 64, 16, 16, 0, 0, 5, 2, 2, True, jnp.float32),
+    "grouped": (64, 64, 16, 8, 0, 0, 0, 4, 2, True, jnp.float32),
+    "no_block_divides": (50, 64, 16, 8, 0, 0, 0, 2, 2, True, jnp.float32),
+    "bfloat16": (64, 64, 16, 16, 0, 0, 0, 2, 2, True, jnp.bfloat16),
+    "non_causal": (64, 64, 16, 8, 0, 0, 0, 2, 2, False, jnp.float32),
+    "one_tile": (64, 64, 16, 64, 8, 0, 0, 2, 2, True, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KEY_BLOCK_CASES))
+def test_backward_over_key_blocks_matches_autodiff(case):
+    (Tq, Tk, qb, kb, q_off, k_off, window, H, Hkv, causal,
+     dtype) = KEY_BLOCK_CASES[case]
+    D, scale = 8, 8 ** -0.5
+    q, k, v, do = _qkv(30, 2, Tq, Tk, H, Hkv, D, dtype)
+    out, lse, want = _oracle(q, k, v, do, causal=causal, q_off=q_off,
+                             k_off=k_off, window=window, scale=scale)
+    delta = jnp.sum(do.astype(jnp.float32) * out, axis=-1)
+    got = pa._block_backward(
+        q, k, v, do, lse, delta, jnp.asarray(q_off), jnp.asarray(k_off),
+        causal=causal, scale=scale, interpret=True, block_q=qb, block_k=kb,
+        window=window)
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
+        rtol=1e-4, atol=1e-5)
+    for g, w in zip(got, want):
+        assert g.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), **tol)
+    if case == "above_diagonal":
+        assert all(not np.asarray(g).any() for g in got)
+
+
+LOCAL_FORWARD_CASES = {
+    name: KEY_BLOCK_CASES[name]
+    for name in ("kb8", "kb16", "kb_over_qb", "grouped", "no_block_divides",
+                 "bfloat16", "non_causal")}
+LOCAL_FORWARD_CASES["more_keys_than_queries"] = (
+    32, 64, 16, 8, 0, 0, 0, 2, 2, True, jnp.float32)
+
+
+@pytest.mark.parametrize("case", sorted(LOCAL_FORWARD_CASES))
+def test_local_forward_over_key_blocks_matches_dense(case):
+    Tq, Tk, qb, kb, _, _, _, H, Hkv, causal, dtype = LOCAL_FORWARD_CASES[case]
+    D, scale = 8, 8 ** -0.5
+    q, k, v, do = _qkv(31, 2, Tq, Tk, H, Hkv, D, dtype)
+    want_out, want_lse, _ = _oracle(q, k, v, do, causal=causal, q_off=0,
+                                    k_off=0, window=0, scale=scale)
+    out, lse = pa._local_forward(q, k, v, causal=causal, scale=scale,
+                                 interpret=True, block_q=qb, block_k=kb)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(
+        rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(want_out), **tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(want_lse),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_local_forward_chooses_its_blocks_from_the_shapes():
+    """The public entry: no block argument but the q block's upper bound,
+    and the same result as the forced-small blocks."""
+    q, k, v, _ = _qkv(32, 1, 48, 48, 2, 1, 8)
+    out, lse = pa.attention_local_forward(
+        q, k, v, causal=True, scale=0.3, interpret=True, block_q=16)
+    small = pa._local_forward(q, k, v, causal=True, scale=0.3,
+                              interpret=True, block_q=16, block_k=8)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(small[0]),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(small[1]),
+                               rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="not a multiple of kv heads"):
+        pa.attention_local_forward(q, jnp.tile(k, (1, 1, 3, 1)),
+                                   jnp.tile(v, (1, 1, 3, 1)), interpret=True)
+
+
+@pytest.mark.parametrize("Tk,want", [
+    (2048, 512), (1024, 512), (768, 384), (640, 128), (512, 512),
+    (384, 384), (100, 100), (1000, 1000), (8192, 512)])
+def test_key_block_is_a_lane_tiled_divisor_or_the_whole_row(Tk, want):
+    kb = pa._k_blocking(Tk)
+    assert kb == want and Tk % kb == 0
+    assert kb == Tk or (kb % 128 == 0 and kb <= 512)
+
+
+def test_q_block_is_no_longer_shrunk_by_long_rows():
+    """Score tiles are [block_q, block_k]: at the LM cell's 2,048 keys the
+    backward keeps the caller's 512 rows (256 while the tile spanned Tk)."""
+    assert pa._q_blocking(2048, 2048, 64, 512, True, block_k=512)[0] == 512
+    assert pa._q_blocking(2048, 2048, 64, 512, True)[0] == 256
+    assert pa._q_blocking(8192, 8192, 128, 512, True, block_k=512)[0] == 512
+    with pytest.raises(ValueError, match="scoped VMEM limit"):
+        pa._q_blocking(16384, 16384, 128, 512, True, block_k=512)
+
+
+@pytest.mark.parametrize("case", sorted(KEY_BLOCK_CASES))
+def test_key_blocks_visited_counts_the_unmasked_tiles(case):
+    """The counter against a brute-force count, on the grid of cases the
+    kernels are held to: a tile is visited iff the mask keeps one of its
+    (row, key) pairs (a window's tiles lie between two such edges)."""
+    Tq, Tk, qb, kb, q_off, k_off, window, _, _, causal, _ = (
+        KEY_BLOCK_CASES[case])
+    nq, nk = -(-Tq // qb), -(-Tk // kb)
+    qp = q_off + np.arange(nq * qb)[:, None]
+    kp = k_off + np.arange(nk * kb)[None, :]
+    keep = np.ones((nq * qb, nk * kb), bool)
+    if causal:
+        keep = qp >= kp
+        if window:
+            keep &= qp - kp < window
+    brute = int(keep.reshape(nq, qb, nk, kb).any(axis=(1, 3)).sum())
+    assert pa.key_blocks_visited(
+        Tq, Tk, qb, kb, q_off, k_off, causal, window) == (brute, nq * nk)
+
+
+def test_key_blocks_visited_at_the_lm_cells_shape():
+    assert pa.key_blocks_visited(2048, 2048, 512, 512) == (10, 16)
+    assert pa.key_blocks_visited(2048, 2048, 256, 256) == (36, 64)
+    assert pa.key_blocks_visited(2048, 2048, 512, 512, causal=False) == (
+        16, 16)
+
+
+def test_a_block_above_the_diagonal_is_never_read():
+    """K and V rows past a query block's last visited key block hold NaN:
+    dq, out and lse stay finite (a masked product would read 0 x NaN), and
+    dk, dv of those rows are the zeros nothing was added to."""
+    D, scale, qb, kb = 8, 8 ** -0.5, 16, 8
+    q, k, v, do = _qkv(33, 1, 16, 64, 2, 2, D)
+    q_off = 16                                   # rows 16..31: hi = 4 of 8
+    _, _, _, hi = pa._key_block_range(q_off, qb, kb, 64 // kb, True)
+    assert hi == 4
+    out, lse, want = _oracle(q, k, v, do, causal=True, q_off=q_off, k_off=0,
+                             window=0, scale=scale)
+    poison = lambda t: t.at[:, hi * kb:].set(jnp.nan)
+    kn, vn = poison(k), poison(v)
+    delta = jnp.sum(do * out, axis=-1)
+    dq, dk, dv = pa._block_backward(
+        q, kn, vn, do, lse, delta, jnp.asarray(q_off), jnp.asarray(0),
+        causal=True, scale=scale, interpret=True, block_q=qb, block_k=kb,
+        window=0)
+    np.testing.assert_allclose(np.asarray(dq), np.asarray(want[0]),
+                               rtol=1e-4, atol=1e-5)
+    assert not np.asarray(dk)[:, hi * kb:].any()
+    assert not np.asarray(dv)[:, hi * kb:].any()
+    np.testing.assert_allclose(np.asarray(dk)[:, :hi * kb],
+                               np.asarray(want[1])[:, :hi * kb],
+                               rtol=1e-4, atol=1e-5)
+    # the local forward sits at offset 0: 16 queries over 64 keys visit 2
+    out_f, lse_f = pa._local_forward(
+        q, k.at[:, 16:].set(jnp.nan), v.at[:, 16:].set(jnp.nan),
+        causal=True, scale=scale, interpret=True, block_q=qb, block_k=kb)
+    assert np.isfinite(np.asarray(out_f)).all()
+    assert np.isfinite(np.asarray(lse_f)).all()
+    want_f = _oracle(q, k, v, do, causal=True, q_off=0, k_off=0, window=0,
+                     scale=scale)
+    np.testing.assert_allclose(np.asarray(out_f), np.asarray(want_f[0]),
+                               rtol=1e-5, atol=1e-6)

@@ -32,10 +32,10 @@ N = 8
 # History: these flash-kernel lowerings used to xfail because the backward
 # kernel's bool [QB, 1] -> [QB, Tk] lane-broadcast (the isneginf(lse) guard)
 # lowered to a 'tpu.dynamic_gather' on vector<8x128xi1> that Mosaic cannot
-# legalize.  ops/pallas_attention.py now broadcasts lse to the score shape
-# as f32 BEFORE the -inf test (f32 lane-broadcasts legalize fine), so every
-# Pallas kernel in the repo compiles clean for v5e — a regression here
-# should go red, no xfail guard.
+# legalize.  ops/pallas_attention.py keeps such tests on [QB, 1] f32 columns
+# (a -inf lse becomes a shift of +1e30 that the exp turns into exact zeros),
+# so every Pallas kernel in the repo compiles clean for v5e — a regression
+# here should go red, no xfail guard.
 
 
 @pytest.fixture(scope="module")
@@ -795,17 +795,22 @@ def test_grouped_moe_kernel_lowers_at_production_width(tpu_mesh_2x2, tile,
     assert txt.count("tpu_custom_call") >= 1
 
 
-@pytest.mark.parametrize("head_dim", [64, 128])
-@pytest.mark.parametrize("local_len", [2048, 4096, 8192, 16384])
+@pytest.mark.parametrize("local_len,head_dim,heads,dtype", [
+    (local_len, head_dim, 2, jnp.bfloat16)
+    for head_dim in (64, 128) for local_len in (2048, 4096, 8192, 16384)
+] + [(2048, 64, 16, jnp.float32)],      # the LM training cells' own shape
+    ids=lambda v: getattr(v, "__name__", str(v)))
 def test_flash_attention_fits_vmem_or_says_so(tpu_mesh_2x2, local_len,
-                                              head_dim):
+                                              head_dim, heads, dtype):
     """Local flash attention fwd+bwd at the local lengths long-context
-    training uses: the kernels block queries only, so ``_q_blocking``
-    shrinks the q block to what 16 MiB of scoped VMEM allows at this
-    ``Tk`` — and where no block fits it raises ``ValueError`` at trace
-    time.  Mosaic's RESOURCE_EXHAUSTED must never reach the caller."""
+    training uses: K and V (dK, dV) rows stay whole in the 16 MiB of
+    scoped VMEM and both kernels walk the key axis inside, so the score
+    tiles are ``[block_q, block_k]`` whatever the length and the q block
+    stays at the caller's 512 rows — and where the whole rows do not fit
+    ``_q_blocking`` raises ``ValueError`` at trace time.  Mosaic's
+    RESOURCE_EXHAUSTED must never reach the caller."""
     n = tpu_mesh_2x2.size
-    B, H = 1, 2
+    B, H = 1, heads
 
     def loss(q, k, v):
         out = ops_ulysses.local_flash_attention(
@@ -821,15 +826,55 @@ def test_flash_attention_fits_vmem_or_says_so(tpu_mesh_2x2, local_len,
         per_rank, mesh=tpu_mesh_2x2, in_specs=(P("rank"),) * 3,
         out_specs=P("rank"), check_vma=False))
     sds = tuple(jax.ShapeDtypeStruct(
-        (n, B, local_len, H, head_dim), jnp.bfloat16,
+        (n, B, local_len, H, head_dim), dtype,
         sharding=NamedSharding(tpu_mesh_2x2, P("rank"))) for _ in range(3))
     try:
         txt = fn.lower(*sds).compile().as_text()
     except ValueError as e:
         # the backward keeps K, V, dK and dV rows whole: 8192 is its end
+        # (the forward's K and V alone reach 16384), float32 and bfloat16
+        # inputs alike
         assert "scoped VMEM limit" in str(e) and local_len > 8192, e
         return
     assert txt.count("tpu_custom_call") == 2       # forward + backward
+    from bluefog_tpu.ops import pallas_attention as pa
+    kb = pa._k_blocking(local_len)
+    assert kb == 512
+    for backward in (False, True):
+        assert pa._q_blocking(local_len, local_len, head_dim, 512, backward,
+                              block_k=kb)[0] == 512
+
+
+def test_flash_backward_with_offsets_window_and_groups_lowers(tpu_mesh_2x2):
+    """The ring's use of the backward kernel: traced q / k offsets, a
+    sliding window (so all three key-block loops are traced: the window's
+    edge, the blocks under the mask, the diagonal) and grouped K/V heads,
+    through Mosaic for v5e."""
+    from bluefog_tpu.ops import pallas_attention as pa
+
+    n = tpu_mesh_2x2.size
+    B, T, H, Hkv, D = 1, 2048, 4, 2, 128
+
+    def per_rank(q, k, v, do, lse, delta, offs):
+        q, k, v, do, lse, delta, offs = jax.tree.map(
+            lambda t: t[0], (q, k, v, do, lse, delta, offs))
+        grads = pa.attention_block_backward(
+            q, k, v, do, lse, delta, offs[0], offs[1], causal=True,
+            scale=D ** -0.5, interpret=False, window=1024)
+        return jax.tree.map(lambda t: t[None], grads)
+
+    fn = jax.jit(jax.shard_map(
+        per_rank, mesh=tpu_mesh_2x2, in_specs=(P("rank"),) * 7,
+        out_specs=P("rank"), check_vma=False))
+    sh = NamedSharding(tpu_mesh_2x2, P("rank"))
+    sds = [jax.ShapeDtypeStruct((n,) + shape, dtype, sharding=sh)
+           for shape, dtype in (
+               ((B, T, H, D), jnp.bfloat16), ((B, T, Hkv, D), jnp.bfloat16),
+               ((B, T, Hkv, D), jnp.bfloat16), ((B, T, H, D), jnp.float32),
+               ((B, T, H), jnp.float32), ((B, T, H), jnp.float32),
+               ((2,), jnp.int32))]
+    txt = fn.lower(*sds).compile().as_text()
+    assert txt.count("tpu_custom_call") == 1
 
 
 def test_flash_decode_kernel_lowers_for_tpu(tpu_mesh):

@@ -200,3 +200,37 @@ def test_local_flash_attention_vjp_matches_dense():
         for a, b in zip(gf, gd):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("causal,share", [(True, 15 / 25), (False, 1.0)])
+def test_local_flash_attention_walks_key_blocks_and_says_how_many(
+        causal, share):
+    """640 positions are five key blocks of 128 (the code's own choice):
+    value and gradients against the dense oracle, and the trace-time gauge
+    of the share of score tiles each direction's loop computes."""
+    from bluefog_tpu.ops import local_flash_attention, pallas_attention as pa
+    from bluefog_tpu.ops.ulysses import dense_attention
+    from bluefog_tpu.utils import metrics
+
+    T, D = 640, 8
+    assert pa._k_blocking(T) == 128
+    rng = np.random.default_rng(12)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, T, 2, D)).astype(np.float32))
+               for _ in range(3))
+
+    def loss(attend):
+        def f(a, b, c):
+            return jnp.sum(attend(a, b, c).astype(jnp.float32) ** 2)
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    metrics.reset_metrics()
+    lf, gf = loss(lambda a, b, c: local_flash_attention(
+        a, b, c, causal, D ** -0.5, 128, True, None))
+    ld, gd = loss(lambda a, b, c: dense_attention(a, b, c, causal, D ** -0.5))
+    np.testing.assert_allclose(float(lf), float(ld), rtol=1e-5)
+    for a, b in zip(gf, gd):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-5)
+    gauge = metrics.get_metric("bluefog_flash_key_blocks_visited_share")
+    for direction in ("forward", "backward"):
+        assert gauge.value(**{"pass": direction}) == pytest.approx(share)

@@ -110,7 +110,8 @@ def _members(mod):
         defined_here = getattr(obj, "__module__", None) == mod.__name__
         if not (names or defined_here):
             continue   # without __all__, skip re-exports
-        if inspect.isfunction(obj) or inspect.isclass(obj):
+        # a jitted function is a wrapper object round the function
+        if inspect.isfunction(inspect.unwrap(obj)) or inspect.isclass(obj):
             out.append((name, obj))
     return out
 

@@ -28,15 +28,22 @@ Fourth, the **single-mixer block** (:class:`SsmConfig`,
 :func:`mixer_block`): every layer is ONE mixer behind one RMSNorm, by a
 static plan a Mamba-2 state-space mixer (:func:`mamba_project`,
 :func:`mamba_conv`, :func:`mamba_scan_chunked` for a prompt and
-:func:`mamba_step` for a decode token, :func:`mamba_gate_out`), grouped-query
-attention that turns nothing (:func:`gqa_project`), or ``relu^2`` experts
-in a latent (:func:`bluefog_tpu.moe.layers.held_moe_ffn`).
+:func:`mamba_step` for a decode token, :func:`mamba_gate_out`), a gated
+delta-rule mixer with a decay per channel (:func:`delta_project`,
+:func:`delta_split`, :func:`delta_discretize`; a prompt's rule is the Pallas
+kernel :func:`bluefog_tpu.ops.pallas_delta.delta_rule`, which
+:func:`delta_scan_chunked` hands a block of positions at a time and which
+runs in interpreter mode off the TPU; a decode token's :func:`delta_step`;
+:func:`delta_gate_out`), grouped-query attention that turns nothing
+(:func:`gqa_project`), or ``relu^2`` experts in a latent
+(:func:`bluefog_tpu.moe.layers.held_moe_ffn`).
 Each block opens the device scopes of its parts (``attn.project`` or
 ``mla.project``, ``attn.window`` / ``attn.full``, ``ffn``, ``ssm.project``
 / ``ssm.conv`` / ``ssm.scan``; the read-outs ``readout``): plain ``jax.named_scope``s, metadata that changes no
 instruction and lets ``utils.tracing.device_scopes`` say which compiled
 instruction belongs to which part.
-Imports jax only: the callers import this module, never the reverse.
+Imports jax and that kernel's module: the callers import this module, never
+the reverse.
 """
 import contextlib
 import dataclasses
@@ -46,6 +53,8 @@ from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import pallas_delta
 
 ATTENTION_LEAVES = ("wqkv", "wo")
 FFN_LEAVES = ("w1", "w2")
@@ -1129,51 +1138,10 @@ def delta_discretize(cfg: SsmConfig, lp: Dict[str, jax.Array], f: jax.Array,
     return g, beta
 
 
-# the chunked delta rule runs over this many positions at a time: what a
-# block holds in float32 (q, k, v, the decays and the chunk's solved system,
+# a prompt passes the delta mixer this many positions at a time: what a block
+# holds between the convolution and the kernel (q, k, v and the decays,
 # [block, heads, head_dim] each) stays in the tens of MB at any prompt length
 _DELTA_BLOCK = 1024
-
-
-def _delta_system(q: jax.Array, k: jax.Array, G: jax.Array, beta: jax.Array):
-    """For chunks ``[..., C, K]`` (``G`` the inclusive cumulative log decay,
-    ``beta`` ``[..., C]``): ``(T, Aqk)`` ``[..., C, C]``, the inverse of ``I
-    + diag(beta) tril(A, -1)`` with ``A[t, s] = sum_d k_t[d] k_s[d] exp(G_t[d]
-    - G_s[d])``, and ``Aqk[t, s] = sum_d q_t[d] k_s[d] exp(G_t[d] -
-    G_s[d])`` for ``s <= t``.  Both grow from single positions by doubling:
-    a block of ``2m`` is its two halves and the quadrant between them, whose
-    exponents are taken against the earlier half's LAST position, ``exp(G_t
-    - G_ref)`` on the later half's rows and ``exp(G_ref - G_s)`` on the
-    earlier half's columns, each ``<= 0`` whatever the decay; and the
-    inverse of a block lower-triangular matrix is ``[[T1, 0], [-T2 M21 T1,
-    T2]]``, exact, with no power of ``A`` taken."""
-    C = q.shape[-2]
-    lead = q.shape[:-2]
-    T = jnp.ones(lead + (C, 1, 1), jnp.float32)
-    Aqk = jnp.sum(q * k, -1)[..., None, None]
-    m = 1
-    while m < C:
-        nb = C // (2 * m)
-        halves = lambda t: t.reshape(lead + (nb, 2, m) + t.shape[len(lead) + 1:])
-        Gh, kh, qh = halves(G), halves(k), halves(q)
-        ref = Gh[..., 0, m - 1:, :]                      # [..., nb, 1, K]
-        col = kh[..., 0, :, :] * jnp.exp(ref - Gh[..., 0, :, :])
-        row = jnp.exp(Gh[..., 1, :, :] - ref)
-        between = lambda a: jnp.einsum(
-            "...tk,...sk->...ts", a[..., 1, :, :] * row, col)  # [.., m, m]
-
-        def grown(blocks, quadrant):    # [..., nb, 2, m, m] -> [.., 2m, 2m]
-            top = jnp.concatenate(
-                [blocks[..., 0, :, :], jnp.zeros_like(quadrant)], -1)
-            return jnp.concatenate(
-                [top, jnp.concatenate([quadrant, blocks[..., 1, :, :]], -1)],
-                -2)
-        Th = T.reshape(lead + (nb, 2, m, m))
-        M21 = halves(beta)[..., 1, :, None] * between(kh)
-        T = grown(Th, -(Th[..., 1, :, :] @ M21 @ Th[..., 0, :, :]))
-        Aqk = grown(Aqk.reshape(Th.shape), between(qh))
-        m *= 2
-    return T[..., 0, :, :], Aqk[..., 0, :, :]
 
 
 def delta_scan_chunked(cfg: SsmConfig, lp: Dict[str, jax.Array],
@@ -1182,41 +1150,35 @@ def delta_scan_chunked(cfg: SsmConfig, lp: Dict[str, jax.Array],
     """The gated delta rule ``S_t = (I - beta_t k_t k_t^T) diag(exp g_t)
     S_{t-1} + beta_t k_t v_t^T``, ``o_t = S_t^T q_t`` (``S_{-1} = 0``) over
     one prompt, from the RAW projections of :func:`delta_project` (``qkv``
-    ``[T, conv_dim]`` with zeros before it, ``f``, ``b``).  In chunks of
-    ``cfg.chunk``: with ``u_t = beta_t (v_t - decayed S^T k_t)`` a chunk's
-    ``u`` solve the unit lower triangular system :func:`_delta_system`
-    inverts, so from the state ``S`` a chunk starts with ``U = T beta V - (T
-    beta (K o exp G)) S``, ``O = (Q o exp G) S + Aqk U`` and the next ``S =
-    exp(G_C) o S + (K o exp(G_C - G))^T U``.  The prompt passes in blocks
-    of ``_DELTA_BLOCK`` positions: a block is convolved behind the last
-    inputs of the block before it (:func:`mamba_conv`'s sum and SiLU; a
-    16,384-token prompt convolved whole would hold its 24,576 channels
-    three times over), split and normed (:func:`delta_split`), everything
-    of its chunks that no state enters is made at once, and only the three
-    products with the state run chunk after chunk.  EVERY exponent is a
-    difference of cumulative ``g`` that is ``<= 0``, so a decay of
-    ``exp(-20)`` a step underflows to 0 and overflows nowhere.  Float32
-    throughout.  Positions from ``true_len`` on take ``g = 0`` and ``beta =
-    0``, so the returned state ``[heads, head_dim, state]`` is the one
-    after the last REAL token.  Returns ``(o [T, heads, state]`` in
-    ``qkv``'s dtype, state float32, the ``conv_kernel - 1`` raw inputs
-    before ``true_len``: zeros where the prompt is shorter)."""
+    ``[T, conv_dim]`` with zeros before it, ``f``, ``b``).  The prompt passes
+    in blocks of ``_DELTA_BLOCK`` positions: a block is convolved behind the
+    last inputs of the block before it (:func:`mamba_conv`'s sum and SiLU; a
+    16,384-token prompt convolved whole would hold its 24,576 channels three
+    times over), split and normed (:func:`delta_split`), discretised
+    (:func:`delta_discretize`), and handed with the state the block before
+    left to the kernel :func:`bluefog_tpu.ops.pallas_delta.delta_rule`,
+    which makes everything else in VMEM: the chunks' systems, their
+    inverses and the products with the state (in chunks of ``cfg.chunk``,
+    several of them solved against each other before the state meets them;
+    EVERY exponent a sum of ``g`` that is ``<= 0``, so a decay of
+    ``exp(-20)`` a step underflows to 0 and overflows nowhere; exponents,
+    cumulative sums and the state in float32, the matrix products at the
+    backend's default precision).  Off the TPU the kernel runs in interpreter
+    mode.  Positions from ``true_len`` on take ``g = 0`` and ``beta = 0``, so
+    the returned state ``[heads, head_dim, state]`` is the one after the last
+    REAL token.  Returns ``(o [T, heads, state]`` in ``qkv``'s dtype, state
+    float32, the ``conv_kernel - 1`` raw inputs before ``true_len``: zeros
+    where the prompt is shorter)."""
     with jax.named_scope("ssm.scan"):
         T, H = qkv.shape[0], cfg.ssm_heads
-        V, C, taps = cfg.ssm_state, cfg.chunk, cfg.conv_kernel - 1
-        block = C * max(1, _DELTA_BLOCK // C)
-        Tp = T + (-T) % (C if T <= block else block)
-        block = min(block, Tp)
-        n = block // C
+        V, taps = cfg.ssm_state, cfg.conv_kernel - 1
+        block = min(_DELTA_BLOCK, T)
+        Tp = T + (-T) % block
 
         def blocked(a):                 # [Tp // block, block, ...]
             if a.shape[0] < Tp:         # behind every real position
                 a = jnp.pad(a, ((0, Tp - T),) + ((0, 0),) * (a.ndim - 1))
             return a.reshape((Tp // block, block) + a.shape[1:])
-
-        def chunked(a):                 # [block, H, X] -> [n, H, C, X]
-            return a.astype(jnp.float32).reshape(
-                (n, C) + a.shape[1:]).transpose(0, 2, 1, 3)
 
         def over_block(carry, xs):
             S, tail = carry
@@ -1225,29 +1187,12 @@ def delta_scan_chunked(cfg: SsmConfig, lp: Dict[str, jax.Array],
                 window = jnp.concatenate([tail, raw])
                 conv = _silu_conv(cfg, lp, [window[j:j + block]
                                             for j in range(taps + 1)])
-            q, k, v = delta_split(cfg, conv.astype(raw.dtype))
+            q, k, v = (a.reshape(block, -1) for a in delta_split(
+                cfg, conv.astype(raw.dtype)))
             g, beta = delta_discretize(cfg, lp, fb, bb, alive)
-            qc, kc, vc, G = chunked(q), chunked(k), chunked(v), \
-                jnp.cumsum(chunked(g), axis=2)
-            beta = beta.reshape(n, C, H).transpose(0, 2, 1)     # [n, H, C]
-            inv, Aqk = _delta_system(qc, kc, G, beta)
-            decayed = jnp.exp(G)
-            rhs = beta[..., None] * jnp.concatenate(
-                [vc, kc * decayed], -1)                  # [n, H, C, V + K]
-            solved = inv @ rhs
-            total = G[:, :, -1]                          # [n, H, K]
-            parts = (solved[..., :V], solved[..., V:], Aqk, qc * decayed,
-                     kc * jnp.exp(total[:, :, None] - G), jnp.exp(total))
-
-            def over_chunk(S, part):
-                Uv, W, Aqk, Qg, Kd, total = part
-                U = Uv - W @ S                           # [H, C, V]
-                o = Qg @ S + Aqk @ U
-                return total[..., None] * S + jnp.einsum(
-                    "hck,hcv->hkv", Kd, U), o
-            S, o = lax.scan(over_chunk, S, parts)        # o [n, H, C, V]
-            return (S, raw[block - taps:]), o.transpose(0, 2, 1, 3).reshape(
-                block, H, V).astype(qkv.dtype)
+            o, S = pallas_delta.delta_rule(
+                q, k, v, g.reshape(block, -1), beta, S, chunk=cfg.chunk)
+            return (S, raw[block - taps:]), o
         live = jnp.arange(Tp) < jnp.minimum(true_len, T)
         (S, _), o = lax.scan(
             over_block,

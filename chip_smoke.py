@@ -43,6 +43,7 @@ WIDTHS = {
     "attention": dict(B=4, T=2048, H=16, D=64),
     "decode": dict(lanes=8, rows=16, H=16, L=4096, D=64, block_k=128),
     "moe": dict(E=8, G=16, D=1024, F=4096),
+    "delta": dict(T=1024, H=4, K=128, chunk=16),
     "lm": dict(seq=2048, d_model=1024, heads=16, vocab=32768, batch=2,
                micro_per_stage=2, steps_per_call=4, calls=2),
     "serve": dict(d_model=1024, heads=16, layers=4, vocab=32768,
@@ -398,10 +399,46 @@ def _kernel_moe(a, interpret):
     return errs
 
 
+def _kernel_delta(a, interpret):
+    """A prompt's delta rule against the single step, token by token."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from bluefog_tpu.models import decoder
+    from bluefog_tpu.ops import pallas_delta
+
+    rng = np.random.default_rng(SEED + 5)
+    T, H, K = a["T"], a["H"], a["K"]
+    unit = lambda x: x / np.linalg.norm(x, axis=-1, keepdims=True)
+    q = jnp.asarray(unit(rng.normal(size=(T, H, K))) * K ** -0.5,
+                    jnp.bfloat16)
+    k = jnp.asarray(unit(rng.normal(size=(T, H, K))), jnp.bfloat16)
+    v = jnp.asarray(rng.normal(size=(T, H, K)), jnp.bfloat16)
+    g = jnp.asarray(-np.exp(rng.normal(size=(T, H, K)) - 3.0), jnp.float32)
+    beta = jnp.asarray(2.0 / (1.0 + np.exp(rng.normal(size=(T, H)))),
+                       jnp.float32)
+    zero = jnp.zeros((H, K, K), jnp.float32)
+    o, S = pallas_delta.delta_rule(
+        *(t.reshape(T, -1) for t in (q, k, v, g)), beta, zero,
+        chunk=a["chunk"], interpret=interpret)
+
+    def step(S_, x):
+        o_, S_ = decoder.delta_step(S_, *x)
+        return S_, o_[0]
+    Sw, ow = jax.jit(lambda *x: jax.lax.scan(step, zero[None], x))(
+        g[:, None], beta[:, None], q[:, None], k[:, None], v[:, None])
+    return {"out": float(np.round(close(
+                o.reshape(T, H, K), ow, TOL_BF16, "delta rule outputs"), 5)),
+            "state": float(np.round(close(
+                S, Sw[0], TOL_BF16, "delta rule state"), 5))}
+
+
 def phase_kernels(w, shared, interpret=False):
     out = {}
     for name, fn in (("attention", _kernel_attention),
-                     ("decode", _kernel_decode), ("moe", _kernel_moe)):
+                     ("decode", _kernel_decode), ("moe", _kernel_moe),
+                     ("delta", _kernel_delta)):
         out[name] = fn(w[name], interpret)
         say("kernels", f"{name}: max abs err vs the XLA path {out[name]} "
             f"(tolerance {TOL_BF16} of the largest reference value)")

@@ -15,6 +15,7 @@ import pytest
 
 from bluefog_tpu.models import decoder
 from bluefog_tpu.moe import layers as moe_layers
+from bluefog_tpu.ops import pallas_delta
 from bluefog_tpu.parallel import compose
 from bluefog_tpu.serve import Scheduler, ServeConfig, ServeEngine
 from bluefog_tpu.serve import kv_cache as kv
@@ -196,12 +197,14 @@ def _token_by_token(lp, qkv, f, b, true_len):
 def test_the_chunked_delta_rule_is_the_recurrence_for_any_length_and_decay(
         T, true_len, strong, monkeypatch):
     """Lengths that are no whole chunk, prompts that end before their
-    padding (one shorter than the convolution's taps), blocks of two chunks
-    (so five blocks hand a state and the convolution's last inputs on at
-    37), and decays down to ``exp(-200)`` a step: finite, equal to the
-    token-by-token recurrence, and the state and the kept inputs are those
-    after the last REAL token."""
-    monkeypatch.setattr(decoder, "_DELTA_BLOCK", 2 * CFG.chunk)
+    padding (one shorter than the convolution's taps), blocks of four chunks
+    in tiles of two (so three blocks hand a state and the convolution's last
+    inputs on at 37, each through two tiles of one span of two chunks), and
+    decays down to ``exp(-200)`` a step: finite, equal to the token-by-token
+    recurrence, and the state and the kept inputs are those after the last
+    REAL token."""
+    monkeypatch.setattr(decoder, "_DELTA_BLOCK", 4 * CFG.chunk)
+    monkeypatch.setattr(pallas_delta, "_TILE", 2 * CFG.chunk)
     lp, qkv, f, b = _delta_inputs(T, strong)
     o, S, kept = jax.jit(lambda *a: decoder.delta_scan_chunked(CFG, lp, *a))(
         qkv, f, b, jnp.int32(true_len))
@@ -217,7 +220,11 @@ def test_the_chunked_delta_rule_is_the_recurrence_for_any_length_and_decay(
 
 def test_every_exponent_the_chunked_form_takes_is_at_most_zero(monkeypatch):
     """No ``exp(-cumsum g)`` anywhere: each argument of ``exp`` in the
-    chunked form, under decays of ``exp(-200)`` a step, is ``<= 0``."""
+    chunked form, under decays past ``exp(-20)`` a step, is ``<= 0``.  The
+    kernel's arithmetic is a plain function of arrays
+    (``pallas_delta.span_terms``, which the kernel calls on what it reads
+    from its refs); it runs here eagerly on each head's span of four
+    chunks, the last three positions padding."""
     seen = []
     real = jnp.exp
 
@@ -225,10 +232,18 @@ def test_every_exponent_the_chunked_form_takes_is_at_most_zero(monkeypatch):
         seen.append(float(jnp.max(x)))
         return real(x)
     lp, qkv, f, b = _delta_inputs(16, strong=True)
+    lp = {**lp, "A_log": jnp.zeros_like(lp["A_log"])}
     monkeypatch.setattr(decoder.jnp, "exp", watched)
     with jax.disable_jit():
-        decoder.delta_scan_chunked(CFG, {**lp, "A_log": jnp.zeros_like(
-            lp["A_log"])}, qkv, f, b, jnp.int32(13))
+        q, k, v = decoder.delta_split(
+            CFG, decoder.mamba_conv(CFG, lp, qkv, true_len=jnp.int32(13))[0])
+        g, beta = decoder.delta_discretize(CFG, lp, f, b, jnp.arange(16) < 13)
+        assert float(g.min()) < -20.0
+        for h in range(CFG.ssm_heads):
+            terms, = pallas_delta.span_terms(
+                [q[:, h]], [k[:, h]], [v[:, h]], [g[:, h]],
+                [beta[:, h, None]])
+            assert all(bool(jnp.isfinite(t).all()) for t in terms)
     assert len(seen) >= 8 and max(seen) <= 0.0
 
 

@@ -802,6 +802,35 @@ def test_engine_programs_in_place_on_v5e(v5e_chip, program, store, fast):
         assert mem.temp_size_in_bytes < cc.bytes() // cc.layers
 
 
+def test_the_delta_rule_kernel_lowers_for_v5e_at_the_cells_sizes(v5e_chip):
+    """A prompt's delta rule at ``solar-open2``'s sizes (a block of 1,024
+    positions, 64 heads of 128 key and 128 value channels, chunks of 16,
+    bfloat16 activations) through Mosaic for the described chip: one kernel,
+    its blocks lane-aligned as the arrays lie and its VMEM under the scoped
+    limit, and nothing of ``[block, heads, 128]`` size made beside it (no
+    transpose before the kernel, no copy after it)."""
+    from jax.sharding import NamedSharding
+    from bluefog_tpu.ops import pallas_delta
+    T, H, K = 1024, 64, 128
+    sh = NamedSharding(v5e_chip.mesh, v5e_chip.spec)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        (1,) + shape, dtype, sharding=sh)
+    wide = lambda dtype: sds((T, H * K), dtype)
+
+    def per_chip(q, k, v, g, beta, state):
+        out = pallas_delta.delta_rule(q[0], k[0], v[0], g[0], beta[0],
+                                      state[0], chunk=16, interpret=False)
+        return jax.tree.map(lambda t: t[None], out)
+    compiled = jax.jit(jax.shard_map(
+        per_chip, mesh=v5e_chip.mesh, in_specs=(v5e_chip.spec,) * 6,
+        out_specs=v5e_chip.spec, check_vma=False)).lower(
+        wide(jnp.bfloat16), wide(jnp.bfloat16), wide(jnp.bfloat16),
+        wide(jnp.float32), sds((T, H), jnp.float32),
+        sds((H, K, K), jnp.float32)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < T * H * K
+
+
 @pytest.fixture(scope="module")
 def v5e_decode(v5e_chip):
     """The cell's decode program compiled for the described chip, once per

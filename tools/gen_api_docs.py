@@ -28,6 +28,8 @@ MODULES = [
     ("bluefog_tpu.ops.ulysses", "Ulysses attention (all-to-all SP)"),
     ("bluefog_tpu.ops.pallas_attention", "Pallas flash-attention kernels"),
     ("bluefog_tpu.ops.pallas_decode", "Paged flash-decode kernel (serving)"),
+    ("bluefog_tpu.ops.pallas_delta",
+     "Delta-rule kernel (a prompt's gated delta rule, serving)"),
     ("bluefog_tpu.parallel.context", "Mesh context (init/topology state)"),
     ("bluefog_tpu.parallel.exec_cache",
      "Warm executable pool (recompile-free regrowth)"),

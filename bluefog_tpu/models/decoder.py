@@ -178,7 +178,25 @@ class LatentConfig:
     streams, which every sublayer reads through a learned combination and
     writes back under a Sinkhorn-normalised remix (:func:`hc_coefficients`,
     ``sinkhorn_iters`` rounds on ``exp`` of logits clipped to
-    ``+-res_clamp``, ``hc_eps`` in every normalisation)."""
+    ``+-res_clamp``, ``hc_eps`` in every normalisation).
+
+    ``shortcut``: every layer is a shortcut-connected DOUBLE layer
+    (:func:`latent_double_block`): two latent attentions with their own
+    weights and their own cached vectors, two dense gated FFNs of width
+    ``dense_ffn``, and ONE expert layer that reads the first half's normed
+    activation and whose result joins after the second half's FFN; there
+    is then no leading dense layer (``dense_layers`` 0) and the cache
+    counts :attr:`attn_layers`, two a layer.  ``router``: ``"softmax"``
+    scores all ``num_experts`` outputs by one softmax, selects ``top_k`` by
+    ``score + e_bias`` (``route_bias``) and weighs by the raw scores times
+    ``route_scale``, with no groups and no renormalisation
+    (:func:`bluefog_tpu.moe.layers.router_softmax`).  ``zero_experts``:
+    the router's LAST that many outputs are identity experts: a selected
+    one adds ``weight * h`` and has no weights.  ``shared_expert`` False:
+    the expert layer has no shared expert.  ``q_scale`` / ``kv_scale``
+    multiply the query (both parts) and the normed compressed kv vector
+    (so the keys' unturned part and the values, never the rotary key) where
+    the source rescales its low-rank paths by ``sqrt(d_model / rank)``."""
     vocab: int
     d_model: int
     heads: int
@@ -210,10 +228,22 @@ class LatentConfig:
     sinkhorn_iters: int = 20
     hc_eps: float = 1e-6
     res_clamp: float = 30.0
+    shortcut: bool = False          # double layers, the experts' result late
+    router: str = "sigmoid_grouped"
+    zero_experts: int = 0           # identity outputs, the router's last
+    shared_expert: bool = True
+    q_scale: float = 1.0            # on the query behind its low-rank path
+    kv_scale: float = 1.0           # on the normed compressed kv vector
 
     @property
     def expert_layers(self) -> int:
         return self.layers - self.dense_layers
+
+    @property
+    def attn_layers(self) -> int:
+        """Attention sublayers, each with a cached vector a token of its
+        own: two a double layer."""
+        return self.layers * (2 if self.shortcut else 1)
 
     @property
     def latent_dim(self) -> int:
@@ -227,7 +257,13 @@ class LatentConfig:
                      "n_group", "topk_group"):
             if getattr(self, name) < 1:
                 raise ValueError(f"LatentConfig.{name} must be >= 1")
-        if not 1 <= self.dense_layers < self.layers:
+        if self.shortcut:
+            if self.dense_layers or self.streams != 1:
+                raise ValueError(
+                    f"latent_shortcut: a model of shortcut-connected double "
+                    f"layers has no leading dense layer ({self.dense_layers}"
+                    f") and one residual stream ({self.streams})")
+        elif not 1 <= self.dense_layers < self.layers:
             raise ValueError(
                 f"latent_dense_layers: {self.dense_layers} leading dense "
                 f"layers of {self.layers}: layers counts at least one dense "
@@ -240,6 +276,19 @@ class LatentConfig:
                 f"clamp {self.res_clamp}): every one must be positive")
         if self.rope_dim % 2:
             raise ValueError("rope_dim must be even")
+        if self.router not in LATENT_ROUTERS:
+            raise ValueError(
+                f"latent_router: {self.router!r} is none of {LATENT_ROUTERS}")
+        if self.router == "softmax" and (self.n_group, self.topk_group) \
+                != (1, 1):
+            raise ValueError(
+                f"latent_router: the softmax router selects over all its "
+                f"outputs; n_group {self.n_group} and topk_group "
+                f"{self.topk_group} must both be 1")
+        if not (0 < self.q_scale < math.inf and 0 < self.kv_scale < math.inf):
+            raise ValueError(
+                f"latent_mla_scales: q_scale {self.q_scale} and kv_scale "
+                f"{self.kv_scale} must be positive and finite")
         if self.num_experts % self.n_group or \
                 not self.topk_group <= self.n_group:
             raise ValueError(
@@ -254,6 +303,13 @@ class LatentConfig:
                 f"latent_held_experts: experts {self.held_start}.."
                 f"{self.held_start + self.held_experts - 1} are not among "
                 f"the router's {self.num_experts}")
+        if not 0 <= self.zero_experts <= self.num_experts - self.held_start \
+                - self.held_experts:
+            raise ValueError(
+                f"latent_zero_experts: the last {self.zero_experts} of the "
+                f"router's {self.num_experts} outputs cannot be identity "
+                f"experts beside held experts {self.held_start}.."
+                f"{self.held_start + self.held_experts - 1}")
 
     @property
     def softmax_scale(self) -> float:
@@ -263,6 +319,9 @@ class LatentConfig:
         if self.rope_factor > 1.0 and self.rope_mscale_all_dim:
             m = 0.1 * self.rope_mscale_all_dim * math.log(self.rope_factor) + 1
         return (self.nope_dim + self.rope_dim) ** -0.5 * m * m
+
+
+LATENT_ROUTERS = ("sigmoid_grouped", "softmax")
 
 
 def rms_norm(x: jax.Array, g: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -311,13 +370,19 @@ def mla_project(cfg: LatentConfig, lp: Dict[str, jax.Array], h: jax.Array,
     ``h`` ``[..., D]``: ``(q_nope [..., H, nope], q_rope [..., H, rope],
     latent [..., kv_rank + rope])``.  ``latent`` is what the cache holds
     per token: the normed compressed kv and ONE rotary key for all heads,
-    already turned."""
+    already turned.  ``cfg.q_scale`` multiplies both parts of the query
+    here; ``cfg.kv_scale`` is NOT in ``latent``: the forms that read it
+    apply it once (:func:`mla_unabsorbed` to the vector keys and values are
+    rebuilt from, :func:`mla_absorb_q` and :func:`mla_unabsorb_out` to what
+    crosses ``wkvb``), so the cache holds the vector as the norm left it."""
     with jax.named_scope("mla.project"):
         lead = h.shape[:-1]
         freqs = yarn_freqs(cfg)
         cq = rms_norm(h @ lp["wqa"], lp["gq"], cfg.eps)
         q = (cq @ lp["wqb"]).reshape(
             lead + (cfg.heads, cfg.nope_dim + cfg.rope_dim))
+        if cfg.q_scale != 1.0:
+            q = q * cfg.q_scale
         q_nope, q_rope = q[..., :cfg.nope_dim], q[..., cfg.nope_dim:]
         q_rope = rope(q_rope, positions, freqs=freqs)
         kv = h @ lp["wkva"]
@@ -356,6 +421,8 @@ def mla_unabsorbed(cfg: LatentConfig, lp: Dict[str, jax.Array],
     with jax.named_scope("mla.attend"):
         T, H = q_nope.shape[0], cfg.heads
         ckv, kr = latent[..., :cfg.kv_rank], latent[..., cfg.kv_rank:]
+        if cfg.kv_scale != 1.0:
+            ckv = ckv * jnp.asarray(cfg.kv_scale, ckv.dtype)
         causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
 
         def heads(args):
@@ -387,15 +454,20 @@ def mla_absorb_q(cfg: LatentConfig, lp: Dict[str, jax.Array],
                  q_nope: jax.Array) -> jax.Array:
     """``q~_i = q_nope_i (wkvb_i^K)^T``: the query moved into the
     compressed space ``[..., H, kv_rank]``, so that scores are taken
-    against the cached vector itself (decode)."""
-    return jnp.einsum("...hd,chd->...hc", q_nope, _wkvb(cfg, lp)[0])
+    against the cached vector itself (decode); times ``cfg.kv_scale``,
+    which the keys would have carried."""
+    q = jnp.einsum("...hd,chd->...hc", q_nope, _wkvb(cfg, lp)[0])
+    return q if cfg.kv_scale == 1.0 else q * jnp.asarray(cfg.kv_scale, q.dtype)
 
 
 def mla_unabsorb_out(cfg: LatentConfig, lp: Dict[str, jax.Array],
                      u: jax.Array) -> jax.Array:
     """``o_i = u_i wkvb_i^V`` for the attended compressed vectors ``u``
-    ``[..., H, kv_rank]``; returns ``[..., H * v]`` before ``wo``."""
+    ``[..., H, kv_rank]``, times ``cfg.kv_scale``, which the values would
+    have carried; returns ``[..., H * v]`` before ``wo``."""
     o = jnp.einsum("...hc,chd->...hd", u, _wkvb(cfg, lp)[1])
+    if cfg.kv_scale != 1.0:
+        o = o * jnp.asarray(cfg.kv_scale, o.dtype)
     return o.reshape(o.shape[:-2] + (-1,))
 
 
@@ -507,6 +579,39 @@ def latent_block(cfg: LatentConfig, lp: Dict[str, jax.Array], x: jax.Array,
     return x, aux, faux
 
 
+def latent_double_block(cfg: LatentConfig, lp: Dict[str, jax.Array],
+                        lp2: Dict[str, jax.Array], x: jax.Array,
+                        positions: jax.Array, attend: Callable,
+                        attend2_of: Callable, moe: Callable):
+    """One shortcut-connected double layer on ``x`` ``[..., D]``: two
+    :func:`latent_block` halves, each a latent attention and a dense gated
+    FFN with its own leaves (``lp``, ``lp2``) and its own ``attend`` hook
+    (its own cached vectors; the second half's is ``attend2_of(aux)``,
+    built from what the first half's hook handed back, so that it meets the
+    cache as the first half left it), and ONE expert layer ``moe(lp, h) -> (m,
+    faux)`` that reads the FIRST half's normed post-attention activation
+    and whose result ``m`` is added after the SECOND half's FFN::
+
+        x1 = x  + MLA_1(RMS(x;  g1_1));   h1 = RMS(x1; g2_1)
+        m  = MoE(h1);                     x2 = x1 + FFN_1(h1)
+        x3 = x2 + MLA_2(RMS(x2; g1_2))
+        x4 = x3 + FFN_2(RMS(x3; g2_2)) + m
+
+    Nothing between ``m``'s making and its use reads it: where the experts
+    lie on other chips, their exchange may run under the first FFN and the
+    whole second half.  Returns ``(x4, (aux, aux2), faux)``."""
+    def first_ffn(lp, h):
+        m, faux = moe(lp, h)
+        return dense_gated_ffn(lp, h)[0], (m, faux)
+
+    x, aux, (m, faux) = latent_block(cfg, lp, x, positions, attend,
+                                     first_ffn)
+    x, aux2, _ = latent_block(
+        cfg, lp2, x, positions, attend2_of(aux),
+        lambda lp, h: (dense_gated_ffn(lp, h)[0] + m, None))
+    return x, (aux, aux2), faux
+
+
 def latent_logits(cfg: LatentConfig, shared: Dict[str, jax.Array],
                   x: jax.Array) -> jax.Array:
     """Final RMSNorm and read-out over this chip's vocabulary slice."""
@@ -522,7 +627,11 @@ def latent_param_shapes(cfg: LatentConfig) -> Dict[str, Dict[str, tuple]]:
     ``wr`` is the router, kept in float32, as are its selection bias
     ``eb`` (``route_bias``) and the stream maps of each half (``streams``:
     ``h1p``/``h2p`` ``[n, D, n * n + 2 n]``, the three gains ``h1a``/``h2a``
-    and the biases ``h1b``/``h2b``)."""
+    and the biases ``h1b``/``h2b``).  A ``shortcut`` model has no
+    ``first``: ``blocks`` holds each double layer's first half (attention,
+    dense FFN ``wg``/``wu``/``wd``) with the expert layer's leaves, and
+    ``blocks2`` its second half, stacked alike; an identity expert has no
+    leaf, and without ``shared_expert`` there is no ``wsg``/``wsu``/``wsd``."""
     D, H = cfg.d_model, cfg.heads
     Fe, Eh, Lx = cfg.expert_ffn, cfg.held_experts, cfg.expert_layers
     attn = {"g1": (D,), "wqa": (D, cfg.q_rank), "gq": (cfg.q_rank,),
@@ -537,16 +646,19 @@ def latent_param_shapes(cfg: LatentConfig) -> Dict[str, Dict[str, tuple]]:
                          f"h{half}a": (3,), f"h{half}b": (n * n + 2 * n,)})
     first = dict(attn, wg=(D, cfg.dense_ffn), wu=(D, cfg.dense_ffn),
                  wd=(cfg.dense_ffn, D))
-    expert = dict(attn, wr=(D, cfg.num_experts), wsg=(D, Fe), wsu=(D, Fe),
-                  wsd=(Fe, D), weg=(Eh, D, Fe), weu=(Eh, D, Fe),
-                  wed=(Eh, Fe, D))
+    expert = dict(first if cfg.shortcut else attn, wr=(D, cfg.num_experts))
+    if cfg.shared_expert:
+        expert.update(wsg=(D, Fe), wsu=(D, Fe), wsd=(Fe, D))
+    expert.update(weg=(Eh, D, Fe), weu=(Eh, D, Fe), wed=(Eh, Fe, D))
     if cfg.route_bias:
         expert["eb"] = (cfg.num_experts,)
-    out = {"first": first}
+    out = {} if cfg.shortcut else {"first": first}
     if cfg.dense_layers > 1:
         out["dense"] = {k: (cfg.dense_layers - 1,) + v
                         for k, v in first.items()}
     out["blocks"] = {k: (Lx,) + v for k, v in expert.items()}
+    if cfg.shortcut:
+        out["blocks2"] = {k: (Lx,) + v for k, v in first.items()}
     out["shared"] = {"embed": (cfg.vocab, D), "head": (D, cfg.vocab),
                      "gf": (D,)}
     return out

@@ -42,7 +42,8 @@ from ..models.decoder import gated_ffn, relu2_ffn
 from ..parallel.expert import moe_apply_dropless, moe_combine, moe_dispatch
 from .dropless import grouped_ffn
 
-__all__ = ["router_topk", "router_sigmoid_grouped", "held_expert_ffn",
+__all__ = ["router_topk", "router_sigmoid_grouped", "router_softmax",
+           "zero_expert_part", "held_expert_ffn",
            "held_expert_ffn_grouped", "held_moe_ffn", "EXPERT_FORMS",
            "router_expert_choice", "moe_ffn_routed",
            "moe_ffn_dropless", "moe_dropless_combine",
@@ -101,6 +102,40 @@ def router_sigmoid_grouped(x: jax.Array, wr: jax.Array, *, top_k: int,
         if bias is not None:
             top = jnp.take_along_axis(s, idx, axis=-1)
         return s, idx, route_scale * top / jnp.sum(top, -1, keepdims=True)
+
+
+def router_softmax(x: jax.Array, wr: jax.Array, *, top_k: int,
+                   route_scale: float, bias: jax.Array | None = None):
+    """Softmax router over ALL of ``wr``'s outputs, no groups:
+    ``(scores [T, E], idx [T, k], weight [T, k])``.
+
+    Scores are ``softmax(x wr)`` in float32 at full matmul precision
+    whatever ``x``'s dtype.  The ``top_k`` outputs with the largest ``score
+    + bias`` are taken (``bias`` ``[E]``: it moves selections alone) and
+    weighed by their RAW scores times ``route_scale``, with no
+    renormalisation: the weights of a token sum to whatever its kept
+    scores sum to.  Which outputs are experts with weights, which are held
+    here and which are identity experts is not the router's to know
+    (:func:`held_moe_ffn`)."""
+    with jax.named_scope("moe.route"):
+        s = jax.nn.softmax(jnp.matmul(
+            x.astype(jnp.float32), wr.astype(jnp.float32),
+            precision=lax.Precision.HIGHEST), axis=-1)
+        by = s if bias is None else s + bias.astype(jnp.float32)
+        idx = lax.top_k(by, top_k)[1]
+        return s, idx, route_scale * jnp.take_along_axis(s, idx, axis=-1)
+
+
+def zero_expert_part(h: jax.Array, idx: jax.Array, weight: jax.Array,
+                     first: int) -> jax.Array:
+    """What the identity experts add for tokens ``h`` ``[T, D]`` routed as
+    ``idx`` / ``weight`` ``[T, k]``: the router's outputs from ``first`` on
+    compute nothing, and a selected one adds ``weight_e * h``.  No weights,
+    no rows in a grouped buffer; in a deployment the chip that attends a
+    token adds this for it, once, with no exchange."""
+    w = jnp.sum(jnp.where(idx >= first, weight.astype(jnp.float32), 0.0),
+                axis=-1, keepdims=True)
+    return (w * h.astype(jnp.float32)).astype(h.dtype)
 
 
 # what one expert computes: ``(silu(x wg) * (x wu)) wd``, or ``relu(x wg)^2
@@ -220,12 +255,24 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
     layer has latent projections (``wdn`` ``[D, latent]``, ``wup``
     ``[latent, D]``) the ROUTED experts read ``h wdn`` and their weighted
     sum goes through ``wup``, with nothing between a projection and the
-    experts; the router and the shared expert stay on ``h``.  Returns
-    ``(y, idx [T, k], weight [T, k])``."""
-    _, idx, weight = router_sigmoid_grouped(
-        h, lp["wr"], top_k=cfg.top_k, n_group=cfg.n_group,
-        topk_group=cfg.topk_group, route_scale=cfg.route_scale,
-        bias=lp.get("eb"))
+    experts; the router and the shared expert stay on ``h``.  Three fields
+    that only a :class:`~bluefog_tpu.models.decoder.LatentConfig` has
+    change the layer (the other configurations' are the defaults):
+    ``router`` ``"softmax"`` takes :func:`router_softmax` in the grouped
+    sigmoid router's place; the last ``zero_experts`` of the router's
+    outputs are identity experts, whose part (:func:`zero_expert_part`) is
+    added for every token whichever experts are held; ``shared_expert``
+    False leaves the shared expert out.  Returns ``(y, idx [T, k], weight
+    [T, k])``."""
+    if getattr(cfg, "router", "sigmoid_grouped") == "softmax":
+        _, idx, weight = router_softmax(
+            h, lp["wr"], top_k=cfg.top_k, route_scale=cfg.route_scale,
+            bias=lp.get("eb"))
+    else:
+        _, idx, weight = router_sigmoid_grouped(
+            h, lp["wr"], top_k=cfg.top_k, n_group=cfg.n_group,
+            topk_group=cfg.topk_group, route_scale=cfg.route_scale,
+            bias=lp.get("eb"))
     if live is not None:
         idx = jnp.where(live[:, None], idx, -1)
     x = h
@@ -245,6 +292,12 @@ def held_moe_ffn(cfg, lp: Dict[str, jax.Array], h: jax.Array,
     if "wup" in lp:
         with jax.named_scope("moe.latent"):
             y = y @ lp["wup"]
+    zero = getattr(cfg, "zero_experts", 0)
+    if zero:
+        with jax.named_scope("moe.zero"):
+            y = y + zero_expert_part(h, idx, weight, cfg.num_experts - zero)
+    if not getattr(cfg, "shared_expert", True):
+        return y, idx, weight
     with jax.named_scope("moe.shared"):
         shared = gated_ffn(h, lp["wsg"], lp["wsu"], lp["wsd"]) if gated \
             else relu2_ffn(h, lp["ws1"], lp["ws2"])

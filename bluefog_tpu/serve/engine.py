@@ -74,7 +74,16 @@ its own over a latent cache (one compressed vector per token and layer,
 decode in the absorbed form with the same deferred one-write-per-lane
 landing; the leading layer runs apart from the scan over the expert
 layers, one cache stacked over all.  Every fast path above and any
-carving with pp, tp or ep above 1 is refused for it by name.
+carving with pp, tp or ep above 1 is refused for it by name.  The SAME two
+programs serve a latent model of shortcut-connected double layers
+(``LatentConfig.shortcut``: two latent attentions with their own cached
+vectors, two dense FFNs, and one expert layer whose result joins a
+sublayer later; no leading layer, the double layer in the scan, the cache
+stacked over attention sublayers), under a softmax router whose last
+outputs are identity experts that compute nothing (``router``,
+``zero_experts``) and with no shared expert; its programs also hand out
+the experts each layer chose and the decode steps' logits
+(:meth:`ServeEngine.decode_chosen`), which only a comparison reads.
 
 A **hybrid model** (:class:`~bluefog_tpu.models.decoder.HybridConfig`:
 window and full attention layers by a static plan, grouped-query heads
@@ -549,8 +558,8 @@ class ServeEngine:
             self._moe_chunk_tile = cfg.group_tile   # prefill/verify shapes
         self._route_stats: Optional[np.ndarray] = None
         self._decode_logits = None      # (slots, device array): hybrid, ssm
-        self._decode_chosen = None      # (slots, device array): ssm
-        self._prefill_chosen = None     # device array: ssm
+        self._decode_chosen = None      # (slots, device array): ssm, and
+        self._prefill_chosen = None     # device array: a softmax router's
         self.m, self.cfg, self.scfg = m, cfg, scfg
         self.draft = draft_carve(m, cfg, scfg.spec_stages) \
             if scfg.spec_decode else None
@@ -560,7 +569,7 @@ class ServeEngine:
         # jit cache and cannot retrace the warmed buckets
         self.update_params(params)
         self.cache_cfg = _kv.LatentCacheConfig(
-            layers=cfg.layers, slots=scfg.slots, max_len=scfg.max_len,
+            layers=cfg.attn_layers, slots=scfg.slots, max_len=scfg.max_len,
             kv_rank=cfg.kv_rank, rope_dim=cfg.rope_dim, dtype=scfg.dtype) \
             if self._latent else _kv.HybridCacheConfig(
             full_layers=cfg.layers_of("full"),
@@ -1183,18 +1192,30 @@ class ServeEngine:
     # the latent model's programs (one chip's share of each layer)
     # ------------------------------------------------------------------
 
+    # the grouped kernel's buffers hold ``top_k`` rows a token: a prompt
+    # whose rows pass this many bytes goes through an expert layer
+    # :attr:`_PROMPT_FFN_CHUNK` tokens at a time (12 rows of 6,144 a token
+    # are 604 MB a buffer at 4,096 tokens)
+    _PROMPT_ROWS_BYTES = 320 << 20
+
     def _latent_ffn(self, live, experts=None):
-        """An expert layer's ``ffn`` hook: full-width sigmoid router with
-        group-limited selection, the held experts' part, the shared expert
-        for every token.  ``live`` ``[tokens]`` marks the tokens that
+        """An expert layer's ``ffn`` hook: the full-width router (sigmoid
+        with group-limited selection, or softmax, by the configuration),
+        the held experts' part, the identity experts' where the router has
+        such outputs, the shared expert where the model has one, for every
+        token.  ``live`` ``[tokens]`` marks the tokens that
         count: the rest (trash lanes, a prompt's padding) are routed to no
         expert and stay out of the carrier.  With ``experts``, the held
         experts' weights of ALL layers stacked as the tree holds them, the
         pairs go through the grouped kernel and ``lp["layer"]`` says which
-        layer's groups are meant; without, ``lp`` holds the layer's own
+        layer's groups are meant (a prompt whose rows pass
+        :attr:`_PROMPT_ROWS_BYTES` in chunks of tokens); without, ``lp``
+        holds the layer's own
         and every token goes through every held expert
         (:func:`~bluefog_tpu.moe.layers.held_moe_ffn`).  ``faux`` is the
-        layer's ``[E + 4]`` carrier (:meth:`_note_route_stats`)."""
+        layer's ``[E + 4]`` carrier (:meth:`_note_route_stats`), and where
+        the programs hand their selections out (:attr:`_hands_chosen`)
+        ``(carrier, idx [tokens, top_k])``."""
         cfg = self.cfg
         held = self._held_mask()
 
@@ -1202,9 +1223,20 @@ class ServeEngine:
             if experts is None:
                 y, idx, weight = held_moe_ffn(cfg, lp, h, live)
             else:
-                y, idx, weight = held_moe_ffn(cfg, {**lp, **experts}, h,
-                                              live, layer=lp["layer"])
-            return y, self._carrier(idx, weight, live, held)
+                lp = {**lp, **experts}
+                part = lambda hl: held_moe_ffn(cfg, lp, hl[0], hl[1],
+                                               layer=lp["layer"])
+                T, C = h.shape[0], self._PROMPT_FFN_CHUNK
+                if T <= C or T % C or T * cfg.top_k * h.shape[1] \
+                        * h.dtype.itemsize <= self._PROMPT_ROWS_BYTES:
+                    y, idx, weight = part((h, live))
+                else:
+                    y, idx, weight = jax.tree.map(
+                        lambda t: t.reshape((T,) + t.shape[2:]),
+                        lax.map(part, (h.reshape(T // C, C, -1),
+                                       live.reshape(T // C, C))))
+            vec = self._carrier(idx, weight, live, held)
+            return y, (vec, idx) if self._hands_chosen else vec
 
         return ffn
 
@@ -1239,7 +1271,10 @@ class ServeEngine:
         experts' form: a prompt's pairs through the grouped kernel, or
         (decode) every lane through every held expert
         (:meth:`_latent_ffn`).  Returns ``(x, cache, news [layers, ...] or
-        None, carrier)``."""
+        None, carrier)`` and, where the programs hand their selections out
+        (:attr:`_hands_chosen`), ``chosen [expert layers, tokens, top_k]``
+        behind them.  A model of double layers goes through
+        :meth:`_double_layers`."""
         cfg = self.cfg
         names = ("weg", "weu", "wed") if grouped else ()
         ffn = self._latent_ffn(
@@ -1247,6 +1282,9 @@ class ServeEngine:
         blocks = {k: v for k, v in params["blocks"].items()
                   if k not in names}
         blocks["layer"] = jnp.arange(cfg.expert_layers)
+        if cfg.shortcut:
+            return self._double_layers(params, blocks, x, cache, positions,
+                                       attend_with, ffn)
         x, (cache, new0), _ = decoder.latent_block(
             cfg, params["first"], x, positions,
             attend_with(params["first"], cache, 0), decoder.dense_gated_ffn)
@@ -1260,22 +1298,68 @@ class ServeEngine:
 
         def body(carry, lp):
             x, cache, acc = carry
-            x, (cache, new), vec = decoder.latent_block(
+            x, (cache, new), faux = decoder.latent_block(
                 cfg, lp, x, positions,
                 attend_with(lp, cache, lp["layer"] + cfg.dense_layers), ffn)
-            return (x, cache, acc + vec), new
+            vec, *idx = faux if self._hands_chosen else (faux,)
+            return (x, cache, acc + vec), (new, *idx)
 
-        (x, cache, acc), news = lax.scan(
+        (x, cache, acc), (news, *chosen) = lax.scan(
             body, (x, cache, jnp.zeros((cfg.num_experts + 4,), jnp.float32)),
             blocks)
         if new0 is not None:
             news = jnp.concatenate([new[None] for new in news0] + [news])
-        return x, cache, news, acc
+        return (x, cache, news, acc, *chosen)
+
+    def _double_layers(self, params, blocks, x, cache, positions,
+                       attend_with, ffn):
+        """:meth:`_latent_layers` for a model of shortcut-connected double
+        layers (:func:`~bluefog_tpu.models.decoder.latent_double_block`):
+        no leading layer, the scan over the double layers, each half with
+        a cache layer of its own (``2 l`` and ``2 l + 1``), the expert
+        layer's result handed from the first half's ``ffn`` hook to the
+        second's.  Returns ``(x, cache, news [attention sublayers, ...] or
+        None, carrier)`` and, where the programs hand their selections
+        out, ``chosen [layers, tokens, top_k]``."""
+        cfg = self.cfg
+
+        def body(carry, lps):
+            x, cache, acc = carry
+            lp, lp2 = lps
+            at = 2 * lp["layer"]
+            # the second half's hook meets the cache as the first left it
+            x, ((_, new), (cache, new2)), faux = decoder.latent_double_block(
+                cfg, lp, lp2, x, positions, attend_with(lp, cache, at),
+                lambda aux: attend_with(lp2, aux[0], at + 1), ffn)
+            vec, *idx = faux if self._hands_chosen else (faux,)
+            news = None if new is None else jnp.stack([new, new2])
+            return (x, cache, acc + vec), (news, *idx)
+
+        (x, cache, acc), (news, *chosen) = lax.scan(
+            body, (x, cache, jnp.zeros((cfg.num_experts + 4,), jnp.float32)),
+            (blocks, params["blocks2"]))
+        if news is not None:
+            news = news.reshape((-1,) + news.shape[2:])
+        return (x, cache, news, acc, *chosen)
+
+    @property
+    def _hands_chosen(self) -> bool:
+        """Whether the programs hand out, beside their tokens, the experts
+        every expert layer chose and the decode steps' logits
+        (:meth:`decode_chosen`, :meth:`prefill_chosen`,
+        :meth:`decode_logits`): the single-mixer family's, and a latent
+        model's whose router cuts its softmax scores where they lie a few
+        hundredths of their size apart, so that a comparison with a
+        reference can be made under the program's own selections."""
+        return self._ssm or (self._latent and self.cfg.router == "softmax")
 
     def _latent_decode_body(self, params, cache, keys, lanes):
         """Fused decode in the absorbed form: every layer attends over its
         lanes' cached vectors plus the token's own, and the tokens of all
-        layers land in the cache once per lane after the loop."""
+        layers land in the cache once per lane after the loop.  Where the
+        programs hand their selections out (:attr:`_hands_chosen`) the
+        experts each layer chose and the steps' logits stand between the
+        carrier and the key table, as in :meth:`_ssm_decode_body`."""
         params, cache, table, lanes = self._split_args(
             (params, cache, keys, lanes))
         toks, slot_ids, lens, _, _ = self._unpack_lanes(lanes)
@@ -1303,14 +1387,17 @@ class ServeEngine:
 
             with jax.named_scope("readout"):
                 x = shared["embed"][toks]
-            x, _, news, acc = self._latent_layers(
+            x, _, news, acc, *chosen = self._latent_layers(
                 params, decoder.hc_fan_out(cfg, x), None, lens, attend_with,
                 live, grouped=False)
             cache = _kv.latent_append_tokens(cache, slot_ids, lens, news)
-            nxt, keys = self._next_token(decoder.latent_logits(
-                cfg, shared, decoder.hc_collapse(cfg, x)), keys)
+            logits = decoder.latent_logits(
+                cfg, shared, decoder.hc_collapse(cfg, x))
+            nxt, keys = self._next_token(logits, keys)
             nxt = nxt.astype(toks.dtype)
-            return (nxt, lens + 1, cache, keys, st + acc), nxt
+            out = (nxt, chosen[0], logits.astype(jnp.float32)) if chosen \
+                else nxt
+            return (nxt, lens + 1, cache, keys, st + acc), out
 
         st0 = jnp.zeros((cfg.num_experts + 4,), jnp.float32)
         (_, _, cache, keys, st), gen = lax.scan(
@@ -1318,12 +1405,16 @@ class ServeEngine:
             length=self.scfg.decode_steps_per_call)
         with jax.named_scope("readout"):
             table = table.at[slot_ids].set(keys)
-        return jax.tree.map(lambda t: t[None], (gen, st, table, cache))
+        gen, *handed = gen if self._hands_chosen else (gen,)
+        return jax.tree.map(lambda t: t[None],
+                            (gen, st, *handed, table, cache))
 
     def _latent_prefill_body(self, params, cache, keys, staged):
         """One padded prompt in the unabsorbed form; every layer's vectors
         land in the slot as the layer runs.  Padding is routed to no
-        expert, and only the last real position is read out."""
+        expert, and only the last real position is read out.  Beside them
+        the experts each layer chose, where the programs hand them out
+        (:attr:`_hands_chosen`, :meth:`prefill_chosen`)."""
         params, cache, keys, staged = self._split_args(
             (params, cache, keys, staged))
         keys, toks, slot_id, true_len = self._unpack_prompt(keys, staged)
@@ -1339,7 +1430,7 @@ class ServeEngine:
 
         with jax.named_scope("readout"):
             x = shared["embed"][toks]
-        x, cache, _, _ = self._latent_layers(
+        x, cache, _, _, *chosen = self._latent_layers(
             params, decoder.hc_fan_out(cfg, x), cache, positions, attend_with,
             positions < true_len, grouped=True)
         with jax.named_scope("readout"):
@@ -1349,7 +1440,8 @@ class ServeEngine:
             last = decoder.latent_logits(cfg, shared, x[0]).astype(
                 jnp.float32)
             nxt = jnp.argmax(last, axis=-1).astype(toks.dtype)
-        return jax.tree.map(lambda t: t[None], (nxt, last, keys, cache))
+        return jax.tree.map(lambda t: t[None],
+                            (nxt, last, *chosen, keys, cache))
 
     # ------------------------------------------------------------------
     # the hybrid model's programs (window and full layers, one chip's share)
@@ -1788,7 +1880,8 @@ class ServeEngine:
         mark in the trace (directly under ``decode_call``, once ``collect``
         has closed) carrying them for this call beside the lanes'
         live cache ``positions`` (the benchmark's expert-layer and roofline
-        metrics read its attributes)."""
+        metrics read its attributes); of a model with identity experts
+        also ``zero_pairs`` and ``token_layers``."""
         cfg, scfg = self.cfg, self.scfg
         E = cfg.num_experts
         pairs = int(self._route_stats[:, E + 2].sum())
@@ -1811,6 +1904,18 @@ class ServeEngine:
         seen = np.asarray(lens)[live] + 1       # a lane's live positions
         attrs = dict(pairs=pairs, rows=rows, experts_hit=hit,
                      positions=int(seen.sum()))
+        zero = getattr(cfg, "zero_experts", 0)
+        if zero:
+            # the live pairs that fell on identity outputs (the carrier's
+            # counts of the router's last outputs, summed here) beside the
+            # live (token, expert layer) pairs they are a share of
+            attrs["zero_pairs"] = int(self._route_stats[:, E - zero:E].sum())
+            attrs["token_layers"] = int(self._route_stats[:, E + 1].sum())
+            _metrics.counter(
+                "bluefog_serve_moe_zero_pairs_total",
+                "token-expert pairs of live decode lanes that fell on "
+                "identity experts, which compute nothing").inc(
+                    attrs["zero_pairs"])
         if self._ssm:
             # the live lanes' states, which every recurrent layer reads
             # and writes whole in every fused step; and the positions the
@@ -1868,7 +1973,8 @@ class ServeEngine:
         cc, steps = self.cache_cfg, self.scfg.decode_steps_per_call
         in_place = self._read_form(lanes) == "in_place"
         rows = cc.rows if in_place else lanes
-        reserved = self.m.dp * self.cfg.layers * steps * rows * cc.max_len
+        layers = cc.layers if self._latent else self.cfg.layers
+        reserved = self.m.dp * layers * steps * rows * cc.max_len
         if self._latent or not in_place:
             return reserved, reserved
         bounds = _kv.live_bound(
@@ -2125,7 +2231,8 @@ class ServeEngine:
         lens = np.array(lens, np.int32)
         attrs = dict(S=int(S), cache_writes=writes, ahead=behind)
         read = None
-        if not self._hands_logits:
+        if not (self._hybrid or self._ssm):
+            # (their programs count what they met behind the carrier)
             read, reserved = self._decode_positions(S, slots, lens)
             if not self._share:
                 attrs.update(positions_read=read, positions_reserved=reserved)
@@ -2143,7 +2250,7 @@ class ServeEngine:
             if self._hands_logits and self._decode_logits is None:
                 # nothing has been collected yet: the call in flight's
                 self._decode_logits = (slots, out[-1])
-                if self._ssm:
+                if self._hands_chosen:
                     self._decode_chosen = (slots, out[-2])
             due, self._flying = (self._flying, call) if ahead \
                 else (call, None)
@@ -2206,7 +2313,7 @@ class ServeEngine:
             S, slots, lens, out, read = due
             if self._hands_logits:
                 self._decode_logits = (slots, out.pop())
-            if self._ssm:
+            if self._hands_chosen:
                 self._decode_chosen = (slots, out.pop())
             gen, *st = self._collect("decode", *out)
             if st:
@@ -2224,12 +2331,12 @@ class ServeEngine:
     def _hands_logits(self) -> bool:
         """Whether the decode program hands out every fused step's logits
         beside its tokens (:meth:`decode_logits`)."""
-        return self._hybrid or self._ssm
+        return self._hybrid or self._hands_chosen
 
     def decode_logits(self, replica: int
                       ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
         """What the last :meth:`decode` call COLLECTED of the hybrid or the
-        state-space family (the call whose tokens were last returned; before any has been,
+        state-space family or a softmax-routed latent model (the call whose tokens were last returned; before any has been,
         the call in flight, and converting them waits for it) chose its
         tokens from: ``replica``'s lanes' slots ``[S]`` and their
         logits ``[decode_steps_per_call, S, vocab]`` (float32), which stay
@@ -2250,7 +2357,8 @@ class ServeEngine:
 
     def decode_chosen(self, replica: int
                       ) -> Optional[Tuple[np.ndarray, "_DeviceRow"]]:
-        """Beside :meth:`decode_logits`, of the state-space family: the
+        """Beside :meth:`decode_logits`, of the state-space family and of
+        a softmax-routed latent model (:attr:`_hands_chosen`): the
         experts every expert layer chose for every lane in every fused step
         of that call, ``[decode_steps_per_call, expert layers, S, top_k]``
         int32 over the router's outputs (-1: a dead lane).  Left on the

@@ -1091,8 +1091,13 @@ class LatentCacheConfig:
     cache into the order the attention reads; apart, ``ckv`` keeps a
     token's values together (a token's write touches a few tiles: PR 29
     measured that a write costs the tiles it touches) and ``kr`` takes the
-    order :func:`_positions_minor` says.  The last row is the trash slot;
-    there are no prefix pages and no quantized store."""
+    order :func:`_positions_minor` says.  ``layers`` counts attention
+    SUBLAYERS: a shortcut-connected double layer
+    (:func:`..models.decoder.latent_double_block`) has two, each with a
+    cached vector a token of its own, and :func:`latent_append_tokens`
+    lands them all, ``LatentConfig.attn_layers`` a lane a step.  The last
+    row is the trash slot; there are no prefix pages and no quantized
+    store."""
     layers: int
     slots: int
     max_len: int

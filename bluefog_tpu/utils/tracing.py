@@ -254,7 +254,7 @@ DEVICE_SCOPES = frozenset((
     "GRADIENT", "ADAPT", "COMMUNICATE", "STATE_SYNC",      # the train step
     "attn", "attn.project", "attn.window", "attn.full",
     "mla.project", "mla.attend", "cache.read", "cache.write",
-    "ffn", "moe.route", "moe.experts", "moe.shared", "readout",
+    "ffn", "moe.route", "moe.experts", "moe.shared", "moe.zero", "readout",
     "hc.coef", "hc.mix",                    # the residual streams' maps
     # a state-space mixer: its two projections with the gate and grouped
     # norm, its convolution, its scan or single step with the state's read

@@ -497,8 +497,6 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
       loss) are ``pmean``'d over ``sp`` outside AD.
     """
     cfg.validate(m)
-    import optax
-
     from ..ops.ulysses import ulysses_attention
 
     Tl = cfg.seq_len // m.sp
@@ -529,8 +527,18 @@ def make_lm_grad_fn(cfg: LMConfig, m: Mesh3D, *, remat: bool = False,
             with jax.named_scope("readout"):
                 logits = decoder.lm_logits(q["shared"], out)
                 targets = jnp.roll(toks, cfg.lag, axis=-1)
-                loss = optax.softmax_cross_entropy_with_integer_labels(
-                    logits[:, :, cfg.lag:], targets[:, :, cfg.lag:]).mean()
+                # the mean cross-entropy over [:, :, lag:] (per sp shard),
+                # written so that the float32 logits are the one array of
+                # their size in HBM: the first `lag` positions weigh 0 (a
+                # slice is a copy of all the logits) and the label's logit
+                # is picked by a compare (take_along_axis comes back as a
+                # scatter-add into a zeroed array of the logits' size), so
+                # (softmax - onehot) fuses into both backward products
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                hit = targets[..., None] == jnp.arange(cfg.vocab)
+                ce = lse - jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+                loss = jnp.sum(jnp.where(jnp.arange(Tl) >= cfg.lag, ce, 0.0)
+                               ) / (ce.size // Tl * (Tl - cfg.lag))
                 return jnp.where(sid == S - 1, loss, 0.0) / TP
 
         loss, g = jax.value_and_grad(loss_fn)(params)

@@ -33,6 +33,7 @@ What is pinned here:
 import dataclasses
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -829,6 +830,45 @@ def test_the_delta_rule_kernel_lowers_for_v5e_at_the_cells_sizes(v5e_chip):
         sds((H, K, K), jnp.float32)).compile()
     assert compiled.as_text().count("tpu_custom_call") == 1
     assert compiled.memory_analysis().temp_size_in_bytes < T * H * K
+
+
+def test_the_lm_steps_read_out_holds_the_logits_once_on_v5e(v5e_chip):
+    """``make_lm_grad_fn`` at ``pythia-410m``'s read-out sizes (2,048
+    positions of 1,024 against 50,304 columns, float32, one layer) compiled
+    for the described chip: the float32 logits are the ONE array of their
+    size the gradient program writes.  The slice ``[:, :, lag:]`` was a copy
+    of all of them (2,046 rows) and ``take_along_axis`` came back as a
+    scatter-add into a second array and a bfloat16 copy of it; now
+    ``softmax - onehot`` is made inside both backward products."""
+    from jax.sharding import NamedSharding
+    m, T, D, V = v5e_chip, 2048, 1024, 50304
+    lm = compose.LMConfig(vocab=V, d_model=D, heads=16, layers=1, seq_len=T,
+                          micro=1, batch=1)
+    sh = NamedSharding(m.mesh, m.spec)
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(
+        (1,) + tuple(shape), dtype, sharding=sh)
+    params = {"blocks": {k: sds((1,) + v, jnp.float32) for k, v in
+                         decoder.block_param_shapes(lm).items()},
+              "shared": {"embed": sds((V, D), jnp.float32),
+                         "head": sds((D, V), jnp.float32)}}
+    grad_fn = compose.make_lm_grad_fn(lm, m)
+
+    def per_device(p, t):
+        loss, g = grad_fn(jax.tree.map(lambda v: v[0], p), t[0])
+        return loss[None], jax.tree.map(lambda v: v[None], g)
+    text = jax.jit(jax.shard_map(
+        per_device, mesh=m.mesh, in_specs=m.spec, out_specs=m.spec,
+        check_vma=False)).lower(
+            params, sds((1, 1, T), jnp.int32)).compile().as_text()
+    entry = text[text.index("ENTRY"):]
+    # what an instruction of the entry computation WRITES (a parameter, a
+    # bitcast, a tuple or an element of one names an array, it makes none)
+    made = [shape for shape, op in re.findall(
+        r" = (\(.*?\)|\S+) ([a-z\-]+)\(", entry)
+        if op not in ("parameter", "bitcast", "tuple", "get-tuple-element")]
+    sized = [a for shape in made
+             for a in re.findall(r"([a-z]+\d+)\[[\d,]*204[68],50304\]", shape)]
+    assert sized == ["f32"], sized
 
 
 @pytest.fixture(scope="module")

@@ -706,9 +706,10 @@ class ServeEngine:
         """``bf:engine.<name>`` in the profiler's trace (and the ring when
         armed).  Every device call is staged the same way: ``stage_in``
         (the call's one host array onto the mesh), ``dispatch`` (the
-        jitted call, until it returns to Python), ``collect`` (the wait
-        for the device, the read back of what the host reads and the
-        bookkeeping after it)."""
+        jitted call, until it returns to Python, and the request for what
+        the host will read of it), ``collect`` (the retrace check and the
+        bookkeeping, and beneath it ``wait``: until the device has the
+        call's outputs, and ``read_back``: until they are host arrays)."""
         return _tracing.stage(self._engine_trace, name, cat="engine",
                               **attrs)
 
@@ -2016,11 +2017,25 @@ class ServeEngine:
             arr = np.repeat(arr, self.m.slice_size, axis=0)
         return jax.device_put(arr, self._sharding)
 
+    @staticmethod
+    def _ask_back(outs: Sequence[jax.Array]) -> None:
+        """With the dispatch, ask for the copy to the host of exactly what
+        :meth:`_collect` will read of the call: the transfer follows the
+        program on the device with no word from the host between them, so
+        that telling ``wait`` from ``read_back`` adds no round trip."""
+        for a in outs:
+            a.copy_to_host_async()
+
     def _collect(self, program: str, *outs: jax.Array) -> list:
         """``[n_devices, ...]`` each -> ``[replicas, ...]`` host arrays
-        (slice rows agree), read back together."""
+        (slice rows agree), read back together: ``wait`` until the device
+        has them, ``read_back`` until the host has (:meth:`_ask_back` asked
+        for the copy when the call was dispatched)."""
         self._count_crossing(program, "out", len(outs))
-        return [a[::self.m.slice_size] for a in jax.device_get(outs)]
+        with self._stage("wait"):
+            jax.block_until_ready(outs)
+        with self._stage("read_back"):
+            return [a[::self.m.slice_size] for a in jax.device_get(outs)]
 
     def _args(self, staged: jax.Array) -> tuple:
         """A program's arguments (:meth:`_build`)."""
@@ -2113,6 +2128,7 @@ class ServeEngine:
             with self._stage("dispatch"):
                 nxt, logits, *chosen, self._keys, self.cache = \
                     self._prefill_jit(*args)
+                self._ask_back((nxt,))
                 if chosen:
                     self._prefill_chosen, = chosen
             with self._stage("collect"):
@@ -2170,6 +2186,7 @@ class ServeEngine:
                                          plens, *(admission or (zeros,) * 2))
             with self._stage("dispatch"):
                 *out, self._keys, self.cache = self._chunk_jit(*args)
+                self._ask_back(out)
             with self._stage("collect"):
                 self._check_program(f"chunk S={S} T={T}", self._chunk_jit,
                                     args, self._cache_writes("chunk", S))
@@ -2246,6 +2263,10 @@ class ServeEngine:
                     args = args[:-1] + (
                         self._feed_jit(args[-1], self._flying.out[0]),)
                 *out, self._keys, self.cache = self._decode_jit(*args)
+                # (the logits and selections a program hands out stay on
+                # the device: _collect_decode takes them off the list)
+                self._ask_back(out[:len(out) - self._hands_logits
+                                   - self._hands_chosen])
             call = _DecodeCall(S, slots, lens, out, read)
             if self._hands_logits and self._decode_logits is None:
                 # nothing has been collected yet: the call in flight's
@@ -2321,8 +2342,8 @@ class ServeEngine:
             if read is not None:
                 self._count_positions(
                     read, "latent" if self._latent else "full")
-        # a mark never goes inside a leaf stage: ``collect`` stays a
-        # span with none beneath it in every family
+        # a mark never goes inside a stage: beneath ``collect`` lie its
+        # ``wait`` and ``read_back`` and nothing else, in every family
         if self._share:
             self._count_held_work(S, lens, slots)
         return gen
@@ -2418,6 +2439,7 @@ class ServeEngine:
                                      prefix_rows, prefix_lens)
         with self._stage("dispatch"):
             drafts, self._keys, self.cache = self._draft_jit(*args)
+            self._ask_back((drafts,))
         with self._stage("collect"):
             self._check_program(f"draft S={S}", self._draft_jit, args,
                                 self._cache_writes("draft", S),

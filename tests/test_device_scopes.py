@@ -360,8 +360,9 @@ def test_lm_train_step_splits_by_phase_direction_and_block_scope(
 @pytest.mark.parametrize("family", ["latent", "hybrid", "ssm", "delta"])
 def test_held_work_mark_sits_under_the_decode_call_not_in_collect(
         family, cpu_devices, monkeypatch):
-    """``collect`` closes before the mark opens, and the mark closes before
-    ``decode_call`` does: a mark never goes inside a leaf stage."""
+    """``collect`` (over its ``wait`` and ``read_back`` and nothing else)
+    closes before the mark opens, and the mark closes before
+    ``decode_call`` does: a mark never goes inside a stage."""
     eng = make_engine(family, cpu_devices)
     eng.warmup()
     order, real = [], eng._stage
@@ -382,7 +383,10 @@ def test_held_work_mark_sits_under_the_decode_call_not_in_collect(
     S = eng.scfg.batch_buckets[0]
     trash = np.full((1, S), eng.cache_cfg.trash_slot, np.int32)
     eng.decode(np.zeros((1, S), np.int32), trash, np.zeros((1, S), np.int32))
-    assert order[-5:] == [("open", "collect"), ("close", "collect"),
+    assert order[-9:] == [("open", "collect"),
+                          ("open", "wait"), ("close", "wait"),
+                          ("open", "read_back"), ("close", "read_back"),
+                          ("close", "collect"),
                           ("open", "held_work"), ("close", "held_work"),
                           ("close", "decode_call")]
 

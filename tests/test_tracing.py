@@ -22,6 +22,7 @@ donation intact and the retrace sentinel at 0 (observability stays free).
 import importlib.util
 import json
 import os
+import sys
 import time
 
 import numpy as np
@@ -68,9 +69,8 @@ def test_disarmed_recorder_is_inert():
     assert not bftrace.enabled()
     assert bftrace.add_span("t", "x", 0.0, 1.0) == 0
     assert bftrace.mark("t", "m") == 0
-    with bftrace.span("t", "blk") as s:
+    with bftrace.stage("t", "blk", cat="serve"):
         pass
-    assert s.id == 0
     assert bftrace.spans() == [] and bftrace.dropped() == 0
 
 
@@ -83,9 +83,9 @@ def test_arm_record_flush_roundtrip(tmp_path):
     assert sid > 0
     bftrace.add_span(t1, "decode", 2.0, 2.5, cat="serve",
                      parent=sid, tokens=2)
-    with bftrace.span(t2, "prefill", cat="serve") as s:
+    with bftrace.stage(t2, "prefill", cat="serve") as s:
         s.attrs["hit"] = True
-    assert s.id > 0
+    assert bftrace.spans()[-1]["span"] > sid
     path = bftrace.flush()
     assert path == bftrace.bundle_path()
     lines = [json.loads(l) for l in open(path) if l.strip()]
@@ -376,6 +376,57 @@ def test_trace_report_torn_line_and_bad_schema(tmp_path):
     bad.write_text('{"kind": "meta", "schema": "nope", "mono": 0, "wall": 0}\n')
     with pytest.raises(ValueError):
         tr.load_bundle(str(bad))
+
+
+def test_trace_report_gains_the_stage_rings_view(tmp_path):
+    """``flush`` writes the stage ring beside the bundle and the report
+    shows it by stage and bucket with the largest excesses and their
+    paths, under the grouping rule the benchmark's reader keeps a copy of:
+    the two copies agree on the same records."""
+    tr = _load_tool("tools/trace_report")
+    bftrace.configure(str(tmp_path))
+    for i in range(12):
+        with bftrace.stage("s", "step", cat="serve"):
+            with bftrace.stage("s", "pack", cat="serve", lanes=2, S=4):
+                pass
+            with bftrace.stage("e", "decode_call", cat="engine", S=4):
+                with bftrace.stage("e", "dispatch", cat="engine"):
+                    pass
+                with bftrace.stage("e", "collect", cat="engine"):
+                    with bftrace.stage("e", "wait", cat="engine"):
+                        time.sleep(0.25 if i == 7 else 0.001)
+    records = [tuple(r) for r in bftrace.stage_records()]
+    doc, _ = tr.report_from_files([bftrace.flush()])
+    view = doc["stages"]["0"]
+    assert view["n_records"] == len(records) == 72 and view["dropped"] == 0
+    assert view["pauses"] == [] and view["unjudged_groups"] == 0
+    wait = "serve.step/engine.decode_call/collect/wait"
+    assert [g[:3] for g in view["groups"]][0] == [wait, 4, 12]
+    assert {(g[0], g[1]) for g in view["groups"]} == {
+        ("serve.step/pack", 4), (wait, 4), ("serve.step/(self)", None),
+        ("serve.step/engine.decode_call/dispatch", 4),
+        ("serve.step/engine.decode_call/collect/(self)", 4),
+        ("serve.step/engine.decode_call/(self)", 4)}
+    at, path, bucket, excess, median, n = view["largest_excesses"][0]
+    assert (path, bucket, n) == (wait, 4, 12)
+    assert 0.24 < excess < 0.35 and 0.001 <= median < 0.01
+    assert abs(at - time.time()) < 60               # on the wall clock
+    assert len(view["largest_excesses"]) == 10
+    # the benchmark's copy of the rule, on the same records
+    sys.path.insert(0, REPO)
+    try:
+        from perfbench.harness import stage_ring
+    finally:
+        sys.path.remove(REPO)
+    ana = stage_ring.Analysis(records, (records[0][2] - 1, records[-1][3] + 1))
+    assert {k: len(v) for k, v in ana.groups.items()} == {
+        (g[0], g[1]): g[2] for g in view["groups"]}
+    assert ana.excess_s_max == pytest.approx(excess, abs=1e-8)
+    assert [s.path for s in ana.stalls] == [wait]
+    # a window cuts the view as it cuts the spans
+    late, _ = tr.report_from_files([bftrace.bundle_path()],
+                                   since=time.time() + 60)
+    assert late["stages"]["0"]["n_records"] == 0
 
 
 # ---------------------------------------------------------------------------

@@ -19,6 +19,21 @@ job-level view:
   stamps, so ``total_s`` IS the request's measured E2E latency and
   ``queue + prefill + decode + gap == total`` by construction.
 
+* **stages** — the operator's view of the stage ring that ``flush``
+  writes beside each bundle (``<dir>/stages_rank<r>.json``, schema
+  ``bluefog-stages-1``: every ``tracing.stage``'s entry and exit, kept
+  with nothing armed): seconds by stage and bucket, and the ten largest
+  excesses with their paths.  The grouping rule is the one the benchmark's
+  reader states (``perfbench/harness/stage_ring.py`` keeps its own copy:
+  its files do not import the program's analysis): records are nested by
+  time; an INSTANCE is a stage with none beneath it, or the time a stage
+  spent outside those beneath it (``.../(self)``); instances are grouped by
+  path (``serve.step/engine.decode_call/collect/wait``) and bucket (the
+  nearest ``S``, ``Tpad`` or ``T`` up the path); an instance's EXCESS is
+  its seconds over its group's median, and a group of fewer than 5
+  instances is not judged.  ``bf:host.pause`` records (the armed
+  observer's late wakes) are listed apart.
+
 Run: python tools/trace_report.py <bundle.trace.jsonl> ... [--dir DIR]
      [--out report.json] [--chrome trace.json]
 
@@ -32,12 +47,20 @@ Output schema (stable, pinned by tests/test_tracing.py):
      "critical_path": [[trace_id, total_s, queue_s, prefill_s, decode_s,
                         gap_s], ...]   # slowest first
      "train": {"steps": int, "step_mean_s": float|None,
-               "probes": int}}
+               "probes": int},
+     "stages": {rank: {"n_records", "dropped", "unjudged_groups",
+                       "groups": [[path, bucket, n, p50_s, p90_s, p99_s,
+                                   max_s], ...],      # most time first
+                       "largest_excesses": [[wall_ts, path, bucket,
+                                             excess_s, median_s, n], ...],
+                       "pauses": [[wall_ts, seconds], ...]}}}
+     # "stages" only where a stages_rank<r>.json lies beside a bundle
 """
 import argparse
 import glob
 import json
 import os
+import statistics
 import sys
 import time
 
@@ -181,6 +204,86 @@ def train_summary(bundles):
             "probes": probes}
 
 
+PAUSE_NAME = "bf:host.pause"
+MIN_GROUP = 5
+
+
+def _quantile(sorted_vals, q):
+    return sorted_vals[min(len(sorted_vals) - 1, int(q * len(sorted_vals)))]
+
+
+def stage_view(doc, cut=None):
+    """One rank's stage ring (``stages_rank<r>.json``) -> its entry of the
+    report's ``stages``; the module docstring has the grouping rule.
+    ``cut``: keep records that ended at or after this wall-clock time."""
+    wall = lambda t: doc["wall"] + (t - doc["perf"])          # noqa: E731
+    names = doc["names"]
+    rows = sorted((r for r in doc["records"]
+                   if cut is None or wall(r[3]) >= cut),
+                  key=lambda r: (r[2], -r[3]))
+    pauses, groups, stack = [], {}, []   # stack: [t0, t1, path, bucket, cat,
+    #                                               seconds in children]
+
+    def close(node):
+        t0, t1, path, bucket, _cat, inside = node
+        key = (path + "/(self)", bucket) if inside else (path, bucket)
+        groups.setdefault(key, []).append((t1 - t0 - sum(inside), t0))
+
+    for name_id, bucket, t0, t1, _depth, _cpu in rows:
+        name = names[name_id]
+        if name == PAUSE_NAME:
+            pauses.append([round(wall(t0), 6), round(t1 - t0, 6)])
+            continue
+        while stack and stack[-1][1] <= t0:
+            close(stack.pop())
+        cat, _, short = name[len("bf:"):].partition(".")
+        path = cat + "." + short
+        if stack:
+            parent = stack[-1]
+            parent[5].append(t1 - t0)
+            # a stage's category is written where it differs from its
+            # parent's; the bucket is the nearest one up the path
+            path = parent[2] + "/" + (short if parent[4] == cat else path)
+            if bucket is None:
+                bucket = parent[3]
+        stack.append([t0, t1, path, bucket, cat, []])
+    while stack:
+        close(stack.pop())
+    table, excesses, unjudged = [], [], 0
+    for (path, bucket), members in groups.items():
+        secs = sorted(s for s, _ in members)
+        table.append([path, bucket, len(secs), _quantile(secs, 0.5),
+                      _quantile(secs, 0.9), _quantile(secs, 0.99), secs[-1],
+                      sum(secs)])
+        if len(secs) < MIN_GROUP:
+            unjudged += 1
+            continue
+        median = statistics.median(secs)
+        excesses += [[round(wall(t0), 6), path, bucket,
+                      round(s - median, 9), round(median, 9), len(secs)]
+                     for s, t0 in members]
+    table.sort(key=lambda row: -row[-1])
+    return {"n_records": len(rows), "dropped": doc.get("dropped", 0),
+            "unjudged_groups": unjudged,
+            "groups": [[*row[:3], *(round(v, 9) for v in row[3:7])]
+                       for row in table],
+            "largest_excesses": sorted(excesses, key=lambda e: -e[3])[:10],
+            "pauses": pauses}
+
+
+def stage_views(paths, cut=None):
+    """The ``stages`` of the report: one view per ``stages_rank<r>.json``
+    that lies beside a bundle of ``paths``."""
+    out = {}
+    for d in sorted({os.path.dirname(p) or "." for p in paths}):
+        for f in sorted(glob.glob(os.path.join(d, "stages_rank*.json"))):
+            with open(f) as fh:
+                doc = json.load(fh)
+            if doc.get("schema") == "bluefog-stages-1":
+                out[str(doc.get("rank", 0))] = stage_view(doc, cut)
+    return out
+
+
 def window_bounds(since=None, last=None, now=None):
     """``--since <wall-ts>`` / ``--last <secs>`` -> one lower wall-clock
     bound (None = keep everything; both given: later bound wins)."""
@@ -234,6 +337,9 @@ def report_from_files(paths, since=None, last=None):
         "critical_path": table,
         "train": train_summary(bundles),
     }
+    stages = stage_views(paths, cut)
+    if stages:
+        doc["stages"] = stages
     if cut is not None:
         doc["window"] = {"since_ts": cut}
     if notes:

@@ -34,6 +34,24 @@ makes every K/V block a natively-tiled ``[block_k, head_dim]`` VMEM tile
 through Mosaic for v5e.  Off-TPU the kernel runs in interpreter mode;
 under ``JAX_ENABLE_X64`` it accumulates in f64, which is what the oracle
 tests pin against the XLA path.
+
+**The dense engine's own read is a second kernel here**
+(:func:`attend_live_blocks`; :func:`bluefog_tpu.serve.kv_cache.attend_layer`
+takes it from the shapes alone, no option): one new token a lane over a
+layer of the STACKED token-row tensors ``[layers, rows, max_len, kv_heads
+* head_dim]``, taken whole as operands in HBM (no layer of them is copied
+and no logical view made), the token itself beside the pages (its write
+stays deferred) and the lanes as they come, nothing laid out by row.
+There is no grid: the live (lane, block) pairs of the layer are listed
+once and walked in one loop, a pair's K and V blocks coming by async
+copies started two pairs ahead, across the lanes' edges.  On the serving
+cell (24 layers x 33 rows x 1,024 positions x 1,024 lanes, bfloat16, lanes
+at 0.21 of a row on average) the decode program takes 2.80 ms with it
+where the batch's bound took 4.87, the kernel at 86 % of the chip's 819
+GB/s over what it fetches; a grid over rows with the loop inside a step
+took 0.59 ms more (every row waits for its first copy), a grid over (rows
+x blocks) 1.35 (6,336 steps a call, most of them skipped)
+(``docs/PERF_PR53_RECORD.md``).
 """
 from __future__ import annotations
 
@@ -45,7 +63,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["flash_attend_rows", "flash_attend_chunk"]
+__all__ = ["flash_attend_rows", "flash_attend_chunk", "attend_live_blocks"]
 
 
 def _vma_of(x: jax.Array):
@@ -292,3 +310,183 @@ def flash_attend_chunk(q: jax.Array, cl: Dict[str, jax.Array],
     return _flash_attend(q, cl, slots, lengths, float(scale),
                          prefix_slots, prefix_lens, block_k,
                          bool(interpret))
+
+
+# ---------------------------------------------------------------------------
+# The dense in-place decode read, a bound per LANE
+# ---------------------------------------------------------------------------
+
+# blocks of K and of V in flight or in use at once: the third took 0.08 ms
+# off the serving cell's decode program of 3.16 (docs/PERF_PR53_RECORD.md)
+_LIVE_BLOCKS_DEPTH = 3
+
+
+def _live_blocks_kernel(layer_ref, row_ref, nblk_ref, at_ref, q_ref, kn_ref,
+                        vn_ref, own_ref, k_hbm, v_hbm, out_ref, kbuf, vbuf,
+                        sem, lane_of, blk_of, q_s, sn_s, m_s, l_s, acc_s, *,
+                        block: int, depth: int):
+    """One layer's read: every live (lane, block) pair in ONE walk.
+
+    Lane ``i`` holds row ``row_ref[i]`` of the cache, of which
+    ``nblk_ref[i]`` blocks of ``block`` positions are live and
+    ``at_ref[i]`` positions valid.  The pairs are listed first (scalars),
+    then walked: a pair's blocks of ``k_hbm``/``v_hbm``, the stacked cache
+    tensors where they lie, reach ``kbuf``/``vbuf`` by async copies started
+    ``depth - 1`` pairs ahead of the one computed on, across the lanes'
+    edges, so only a layer's first copy is waited for whole.  At a lane's
+    first block its queries are spread over the lanes of a token row
+    (``q_s``) and its token's own score taken (``sn_s``), where the running
+    maximum ``m_s`` starts, so it is finite whatever the mask leaves;
+    ``l_s``/``acc_s`` carry the sums to the lane's last block, where the
+    token joins, one division follows and a head keeps the lanes that are
+    its own."""
+    layer, (G, H) = layer_ref[0], own_ref.shape[:2]
+    f32 = dict(preferred_element_type=jnp.float32)
+    exact = dict(f32, precision=jax.lax.Precision.HIGHEST)
+
+    def list_lane(i, t):
+        def list_block(b, t):
+            lane_of[t] = i
+            blk_of[t] = b
+            return t + 1
+        return jax.lax.fori_loop(0, nblk_ref[i], list_block, t)
+    total = jax.lax.fori_loop(0, nblk_ref.shape[0], list_lane, 0)
+    # a lane of no live block is its token alone
+    out_ref[...] = jnp.broadcast_to(vn_ref[...], out_ref.shape)
+
+    def copies(t):
+        at = pl.ds(pl.multiple_of(blk_of[t] * block, block), block)
+        return [pltpu.make_async_copy(
+            hbm.at[layer, row_ref[lane_of[t]], at], buf.at[t % depth],
+            sem.at[j, t % depth])
+            for j, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))]
+
+    def start(t):
+        @pl.when(t < total)
+        def _():
+            for c in copies(t):
+                c.start()
+    for t in range(depth - 1):
+        start(t)
+
+    def step(t, _):
+        start(t + depth - 1)
+        i, b = lane_of[t], blk_of[t]
+        k_copy, v_copy = copies(t)
+
+        @pl.when(b == 0)
+        def _():
+            q = q_ref[i].astype(jnp.float32)                    # [G, lanes]
+            spread = own_ref[0] * q[0:1]
+            for g in range(1, G):
+                spread = spread + own_ref[g] * q[g:g + 1]
+            sn = jnp.sum(spread * kn_ref[i], -1, keepdims=True)
+            q_s[...] = spread.astype(q_s.dtype)
+            sn_s[...] = sn
+            m_s[...] = sn
+            l_s[...] = jnp.zeros_like(l_s)
+            acc_s[...] = jnp.zeros_like(acc_s)
+        k_copy.wait()
+        q, k = q_s[...], kbuf[t % depth]
+        on_lanes = (((1,), (1,)), ((), ()))
+        if q.dtype == k.dtype == jnp.bfloat16:
+            s = jax.lax.dot_general(q, k, on_lanes, **f32)
+        else:
+            s = jax.lax.dot_general(q, k.astype(q.dtype), on_lanes, **exact)
+        pos = b * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < at_ref[i], s, -jnp.inf)
+        m_prev = m_s[...]
+        m = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        carried = jnp.exp(m_prev - m)
+        p = jnp.exp(s - m)
+        m_s[...] = m
+        l_s[...] = l_s[...] * carried + jnp.sum(p, -1, keepdims=True)
+        v_copy.wait()
+        v = vbuf[t % depth]
+        if v.dtype == jnp.bfloat16:
+            # the float32 probabilities as their three bfloat16 pieces
+            # (their sum is the float32 exactly) in ONE matmul, summed in
+            # float32
+            hi = p.astype(v.dtype)
+            rest = p - hi.astype(p.dtype)
+            mid = rest.astype(v.dtype)
+            lo = (rest - mid.astype(p.dtype)).astype(v.dtype)
+            wide = jnp.dot(jnp.concatenate([hi, mid, lo], 0), v, **f32)
+            pv = wide[:H] + wide[H:2 * H] + wide[2 * H:]
+        else:
+            pv = jnp.dot(p, v.astype(p.dtype), **exact)
+        acc_s[...] = acc_s[...] * carried + pv
+
+        @pl.when(b == nblk_ref[i] - 1)
+        def _():
+            pn = jnp.exp(sn_s[...] - m_s[...])
+            x = (acc_s[...] + pn * vn_ref[i]) / (l_s[...] + pn)
+            for g in range(G):
+                out_ref[i, pl.ds(g, 1), :] = jnp.sum(
+                    x * own_ref[g], 0, keepdims=True)
+    jax.lax.fori_loop(0, total, step, None)
+
+
+def attend_live_blocks(q: jax.Array, kn: jax.Array, vn: jax.Array,
+                       own: jax.Array, kl: jax.Array, vl: jax.Array,
+                       layer: jax.Array, rows: jax.Array, nblk: jax.Array,
+                       at: jax.Array, *, block: int,
+                       interpret: Optional[bool] = None) -> jax.Array:
+    """One new token a lane over ``layer`` of the stacked token-row tensors
+    ``kl``/``vl`` (``[layers, rows, max_len, lanes]``, ``lanes`` the kv
+    heads times ``head_dim``; taken whole as operands in HBM: no layer of
+    them is copied), lane ``i`` reading of its row ``rows[i]`` the blocks
+    ``0 .. nblk[i] - 1`` of ``block`` positions, of them positions ``0 ..
+    at[i] - 1``, and nothing else: a lane of no block reads nothing and is
+    its token alone.  The arithmetic is
+    :func:`bluefog_tpu.serve.kv_cache._attend_dense`'s: the scaled queries
+    spread block-diagonally over a token row's lanes, exact products, mask
+    by the lane's own length, float32 softmax, the float32 probabilities on
+    bfloat16 pages as their three bfloat16 pieces in one matmul, the
+    token's own score and value joined once at the end, one division; a
+    lane's sum is carried from block to block under a running maximum.
+
+    ``q`` ``[S, group, lanes]``: q head ``k * group + g`` in the lanes of
+    kv head ``k`` of entry ``g``, scaled; ``kn``/``vn`` ``[S, 1, lanes]``
+    float32: the token's key and value as a token row; ``own`` ``[group,
+    heads, lanes]`` float32, 1.0 where a lane is a head's own and the head
+    stands at place ``g`` of its group (``heads`` a multiple of 16: whole
+    tiles of bfloat16 probabilities; the spare ones own nothing).  Returns
+    ``[S, group, lanes]`` float32, laid out as ``q``."""
+    S, G, C = q.shape
+    H, depth = own.shape[1], _LIVE_BLOCKS_DEPTH
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    vmem = pl.BlockSpec(memory_space=pltpu.VMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    pairs = S * (kl.shape[2] // block)
+    # every lane's operands and results are held whole beside the blocks in
+    # flight: past the compiler's own allowance (16 MiB a kernel on a v5e)
+    # the call asks for what it holds
+    held = sum(a.size * a.dtype.itemsize for a in (q, kn, vn, own)) \
+        + 4 * (S * G + 2 * H) * C \
+        + depth * block * C * (kl.dtype.itemsize + vl.dtype.itemsize)
+    params = pltpu.CompilerParams(vmem_limit_bytes=held + (4 << 20)) \
+        if held > (12 << 20) else None
+    i32 = lambda a: a.astype(jnp.int32)
+    return pl.pallas_call(
+        functools.partial(_live_blocks_kernel, block=block, depth=depth),
+        compiler_params=params,
+        in_specs=[smem] * 4 + [vmem] * 4 + [hbm] * 2,
+        out_specs=vmem,
+        out_shape=jax.ShapeDtypeStruct((S, G, C), jnp.float32,
+                                       vma=_vma_of(q)),
+        scratch_shapes=[pltpu.VMEM((depth, block, C), kl.dtype),
+                        pltpu.VMEM((depth, block, C), vl.dtype),
+                        pltpu.SemaphoreType.DMA((2, depth)),
+                        pltpu.SMEM((pairs,), jnp.int32),
+                        pltpu.SMEM((pairs,), jnp.int32),
+                        pltpu.VMEM((H, C), q.dtype),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, 1), jnp.float32),
+                        pltpu.VMEM((H, C), jnp.float32)],
+        interpret=interpret,
+    )(i32(jnp.reshape(layer, (1,))), i32(rows), i32(nblk), i32(at), q, kn,
+      vn, own, kl, vl)

@@ -36,8 +36,11 @@ itself, has them written per layer.  That attention meets the layer's
 pages where they lie, every row its own query, in all three families
 (``program_memory()[...]["read"] == "in_place"``,
 ``bluefog_serve_cache_positions_read_total{kind}``), the dense family's
-only as far as the longest live lane of the call reaches, a bound the
-program chooses itself (:func:`.kv_cache.attend_layer`); shared prefix pages,
+only where its live lanes end: of token rows the blocks that hold each
+lane's own positions, fetched by a kernel that takes the stacked cache
+whole, of pages kept by head as far as the longest live lane of the call
+reaches, a bound the program chooses itself
+(:func:`.kv_cache.attend_layer`); shared prefix pages,
 a quantized store and a bucket under a third of the rows stage each
 lane's row first, as the cache's own properties say.
 Steady-state decode is a single cached program per (bucket,
@@ -1969,8 +1972,10 @@ class ServeEngine:
         sums its two kinds behind its carrier): the lanes' rows whole where
         the read is staged, every row of every layer where it is in place,
         and of a dense cache each fused step's rows only as far as the
-        program's own rule takes them (:func:`.kv_cache.live_bound` of
-        the lanes' positions as they advance, a bound per replica)."""
+        program's own rule takes them (:func:`.kv_cache.dense_positions_met`
+        of the lanes' positions as they advance: of token rows each live
+        lane's own blocks, else every row up to the longest live lane's
+        bound, a replica at a time)."""
         cc, steps = self.cache_cfg, self.scfg.decode_steps_per_call
         in_place = self._read_form(lanes) == "in_place"
         rows = cc.rows if in_place else lanes
@@ -1978,10 +1983,11 @@ class ServeEngine:
         reserved = self.m.dp * layers * steps * rows * cc.max_len
         if self._latent or not in_place:
             return reserved, reserved
-        bounds = _kv.live_bound(
+        met = _kv.dense_positions_met(
             lens[:, None, :] + np.arange(steps)[None, :, None],
-            (slots != cc.trash_slot)[:, None, :], cc.max_len)
-        return int(self.cfg.layers * rows * bounds.sum()), reserved
+            (slots != cc.trash_slot)[:, None, :], rows, cc.max_len,
+            cc.page_order == "token_rows")
+        return int(self.cfg.layers * met.sum()), reserved
 
     # ------------------------------------------------------------------
     # host-side surface (per-REPLICA shapes; the engine broadcasts each
@@ -2636,7 +2642,7 @@ class ServeEngine:
                 self._program_bytes[program]["read"] = read
             if read == "in_place" and not self._share:
                 self._program_bytes[program]["read_step"] = \
-                    _kv.read_bounds(self.cache_cfg.max_len)[0]
+                    self.cache_cfg.read_step
             if self._ssm:
                 cc = self.cache_cfg
                 self._program_bytes[program]["state_bytes"] = \
@@ -2682,6 +2688,7 @@ class ServeEngine:
         also says how its attention ``read``s the cache: ``"in_place"``
         (no staging buffer among its temporaries) or ``"staged"``; a dense
         program that reads in place adds ``read_step``, the positions its
-        read's bound advances by (:func:`.kv_cache.read_bounds`; ``max_len``
-        where every row is read whole)."""
+        read's bound advances by (:attr:`.kv_cache.KVCacheConfig.read_step`:
+        a lane's own block of token rows, else the step of the batch's
+        bound; ``max_len`` where every row is read whole)."""
         return {k: dict(v) for k, v in self._program_bytes.items()}

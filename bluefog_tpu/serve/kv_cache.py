@@ -74,18 +74,28 @@ are laid out by row, every row meets its own pages in one grouped einsum
 that takes the layer's slice of the stacked tensor as its operand
 (:func:`_layer_pages`), the token is attended beside the pages, and the
 lanes' rows of the result are read back: one pass over K and one over V a
-layer.  **The dense family's read stops at the batch's longest live
-position** (:func:`attend_layer`, :func:`_attend_dense`): the passes take
-positions ``[0, bound)`` of every row, ``bound`` the least multiple of a
-step that covers the longest lane that holds a request
+layer.  **The dense family's read stops where the live lanes end**
+(:func:`attend_layer`, :func:`_attend_dense`; one decode program a bucket
+as before, no option, and what is left out is what the mask gave a weight
+of exactly 0.0).  *Token rows stop at each LANE's own last position*: a
+Pallas kernel (:func:`bluefog_tpu.ops.pallas_decode.attend_live_blocks`)
+takes the stacked tensors whole, where they lie, and the lanes as they
+come (nothing is laid out by row), and walks ONE list of the layer's live
+(lane, block) pairs, a lane the blocks ``0 .. ceil(length / 128) - 1`` of
+its row (:func:`read_block`, :func:`live_blocks`) and a lane on the trash
+row nothing, so that a row no lane holds is never touched; a block is
+fetched by an async copy ahead of the one computed on and the lane's
+softmax is carried across its blocks.  It engages from the shapes alone: token rows, the read in place,
+a row of more than one whole block; a shorter row is read whole by XLA.
+*Pages kept by head stop at the batch's longest live position*: the passes
+take positions ``[0, bound)`` of every row, ``bound`` the least multiple
+of a step that covers the longest lane that holds a request
 (:func:`live_bound`; the step from ``max_len`` alone, an eighth of a row
 in whole 128s, so at most eight bounds: :func:`read_bounds`), chosen ON
 THE DEVICE from the call's lengths by a ``lax.switch`` in every layer
 whose branches are the two passes over a shorter slice of the layer's
-pages and nothing else: one decode program a bucket as before, no option,
-and what lies past the bound is what the mask gave a weight of exactly
-0.0.  A bound per LANE would need a kernel that takes the lengths; the
-latent and the hybrid family read every row whole.  The staged form
+pages and nothing else.  The latent and the hybrid family read every row
+whole.  The staged form
 (:func:`attend_rows` through
 :func:`_gather_pages`: each lane's row read into a buffer of its own,
 2 MB a lane and tensor, and read again by the attention) stays where
@@ -161,6 +171,7 @@ import numpy as np
 from jax import lax
 from jax.experimental.layout import Layout, with_layout_constraint
 
+from ..ops import pallas_decode
 from ..ops.collectives import _amax_scale
 from ..utils import metrics as _metrics
 
@@ -171,7 +182,8 @@ __all__ = ["KVCacheConfig", "LatentCacheConfig", "HybridCacheConfig",
            "latent_prefill",
            "latent_append_tokens", "latent_attend_slots", "init_cache",
            "attend_rows", "attend_layer", "read_in_place", "read_bounds",
-           "live_bound", "page_order", "logical_pages",
+           "live_bound", "read_block", "live_blocks", "dense_positions_met",
+           "page_order", "logical_pages",
            "attend_chunk", "token_pages", "append_tokens", "layer_append",
            "layer_append_chunk", "layer_prefill", "quantize_rows",
            "dequantize_rows", "store_dtype", "SlotAllocator", "PrefixCache"]
@@ -275,6 +287,15 @@ class KVCacheConfig:
     def page_order(self) -> str:
         """How a layer's pages of a row lie (:func:`page_order`)."""
         return page_order(self.kv_heads, self.head_dim, self.max_len)
+
+    @property
+    def read_step(self) -> int:
+        """The positions the in-place decode read's bound advances by: a
+        lane's own block of token rows (:func:`read_block`), else the step
+        of the batch's bound (:func:`read_bounds`)."""
+        if self.page_order == "token_rows":
+            return read_block(self.max_len)
+        return read_bounds(self.max_len)[0]
 
     def shapes(self) -> Dict[str, Tuple[int, ...]]:
         """The stored shape of every cache tensor: the payload by its
@@ -723,7 +744,8 @@ def read_in_place(lanes: int, rows: int) -> bool:
 
 
 def read_bounds(max_len: int) -> Tuple[int, ...]:
-    """The position counts a dense in-place decode read may stop at, from
+    """The position counts a dense in-place decode read of pages kept by
+    head may stop at (token rows stop by lane: :func:`read_block`), from
     ``max_len`` alone: multiples of a step of ``max_len / 8`` rounded up to
     whole 128s (a positions-minor page's lanes stay whole; at least 128),
     the last of them the whole row; so at most 8, and one (the whole row,
@@ -744,6 +766,50 @@ def live_bound(lengths, live, max_len: int):
     step = read_bounds(max_len)[0]
     longest = xp.max(xp.where(live, lengths, 0), -1)
     return xp.minimum(xp.maximum(-(-longest // step), 1) * step, max_len)
+
+
+_READ_BLOCK = 128
+
+
+def read_block(max_len: int) -> int:
+    """The positions a lane's read of token rows advances by, from
+    ``max_len`` alone: 128 (a tile of a bfloat16 page's sublanes eight
+    times over, 256 KB of a row of 1,024 lanes) where a row holds more than
+    one such block and only whole ones, else the whole row: no choice to
+    make, and no kernel."""
+    whole = max_len > _READ_BLOCK and max_len % _READ_BLOCK == 0
+    return _READ_BLOCK if whole else max_len
+
+
+def live_blocks(lengths, live, block: int):
+    """The blocks of ``block`` positions each lane's read of token rows
+    takes: those that hold its cached positions ``0 .. lengths[i] - 1``,
+    none for a lane that holds no request (``live`` false: its slot is the
+    trash row, whatever stale length it carries) and none for a length of
+    0.  The same integer arithmetic on numpy arrays (the host counting
+    what a call reads) and on traced ones (the kernel's trip counts)."""
+    xp = jnp if isinstance(lengths, jax.Array) else np
+    return xp.where(live, -(-lengths // block), 0)
+
+
+def dense_positions_met(lengths, live, rows: int, max_len: int,
+                        token_rows: bool):
+    """The cache positions ONE layer's dense in-place decode read meets,
+    by the read's own integer rule, on numpy arrays (the host's count) and
+    on traced ones (the program's): of token rows that hold more than one
+    block each live lane's own blocks (:func:`live_blocks` x
+    :func:`read_block`), else every one of ``rows`` rows up to the bound of
+    the longest live lane (:func:`live_bound`).  Summed over the last axis,
+    so a batch of calls gives a count each."""
+    block = read_block(max_len)
+    if token_rows and block < max_len:
+        xp = jnp if isinstance(lengths, jax.Array) else np
+        held = xp.minimum(lengths, max_len)      # a row holds no more
+        return live_blocks(held, live, block).sum(-1) * block
+    bound = live_bound(lengths, live, max_len)
+    if token_rows:          # a token row of one block is read whole
+        bound = 0 * bound + max_len
+    return rows * bound
 
 
 def _layer_pages(t: jax.Array, layer: jax.Array, pin) -> jax.Array:
@@ -891,27 +957,17 @@ def _attend_dense(q: jax.Array, kl: jax.Array, vl: jax.Array,
                   layer: jax.Array, slots: jax.Array, lengths: jax.Array,
                   kn: jax.Array, vn: jax.Array) -> Tuple[jax.Array, Any]:
     """The dense family's in-place decode read, :func:`_attend_by_row`'s
-    sums in three steps so that the middle one can stop at a bound: the
-    scaled queries ``q`` ``[S, heads, head_dim]`` of one new token a lane
-    over ``layer`` of the stacked tensors ``kl``/``vl``, the token (``kn``,
-    ``vn`` ``[S, kv_heads, head_dim]``) attended beside the pages.
+    sums in three steps so that the middle one can stop where the live
+    lanes end: the scaled queries ``q`` ``[S, heads, head_dim]`` of one new
+    token a lane over ``layer`` of the stacked tensors ``kl``/``vl``, the
+    token (``kn``, ``vn`` ``[S, kv_heads, head_dim]``) attended beside the
+    pages.
 
     *What no bound changes* is done once: the lanes' queries, tokens and
-    lengths laid out by row, the token's own score.  *The two passes over
-    the pages* (scores and mask, the softmax's maximum with the token's
-    score in it, the exponentials, the value product and their sum) take
-    positions ``[0, bound)`` of every row of the layer, sliced where the
-    stacked tensor lies as :func:`_layer_pages` slices a layer (the axis
-    before the last in all three page orders).  Where a
-    row holds more than one step (:func:`read_bounds`) the bound is
-    :func:`live_bound` of the call's lanes, a lane live unless its slot is
-    the trash row (the last), chosen on the device by a ``lax.switch``
-    over the bounds, the cache tensors its operands as they lie; a branch
-    holds the passes and nothing else, because every branch is traced,
-    lowered and loaded at set-up (whole reads in the branches cost the
-    serving cell 2.4 s of it).  The positions left out are those whose
-    score the mask sets to ``-inf``: the same float32 sum without its
-    exact zeros.  *The token's share and the division* follow once.
+    lengths laid out by row, the token's own score.  *The passes over the
+    pages* (scores and mask, the softmax's maximum with the token's score
+    in it, the exponentials, the value product and their sum) and *the
+    token's share and the division* follow, by the order the pages lie in.
 
     Pages of token rows (``[layers, rows, L, kv_heads * d]``:
     :func:`page_order`) are contracted AS THEY LIE, the lanes never split
@@ -927,15 +983,54 @@ def _attend_dense(q: jax.Array, kl: jax.Array, vl: jax.Array,
     bfloat16 pieces stacked into one matmul (the same float32 sum in ONE
     pass over each tile of pages, where the explicit precision makes
     three: 16 query rows a tile leave the matrix unit no pass to spare).
+    Where a row holds more than one block (:func:`read_block`) all three
+    steps are ONE kernel a layer
+    (:func:`~bluefog_tpu.ops.pallas_decode.attend_live_blocks`) that takes
+    both stacked tensors whole and the lanes as they come, nothing laid
+    out by row, and reads of each lane's row the blocks that hold the
+    lane's own positions (:func:`live_blocks`; none for a lane on the
+    trash row, so none of a row without a lane): the same arithmetic with
+    a lane's sum carried from block to block under a running maximum.  A
+    row of one block is read whole by the XLA passes below.
+
+    Pages kept by head take the XLA passes over positions ``[0, bound)``
+    of every row of the layer, sliced where the stacked tensor lies as
+    :func:`_layer_pages` slices a layer.  Where a row holds more than one
+    step (:func:`read_bounds`) the bound is :func:`live_bound` of the
+    call's lanes, a lane live unless its slot is the trash row (the last),
+    chosen on the device by a ``lax.switch`` over the bounds, the cache
+    tensors its operands as they lie; a branch holds the passes and
+    nothing else, because every branch is traced, lowered and loaded at
+    set-up.  The positions left out are those whose score the mask sets to
+    ``-inf``: the same float32 sum without its exact zeros.
 
     Returns the lanes' result ``[S, heads, head_dim]`` in float32 and the
-    cache positions met (rows x bound: traced where the bound is)."""
+    cache positions met (:func:`dense_positions_met`: traced where the
+    lengths decide them)."""
     rows = _token_rows(kl)
     (S, H, Dh), Hkv = q.shape, kn.shape[1]
     R, L = kl.shape[1], kl.shape[-2]
     if H % Hkv:
         raise ValueError(f"{H} q heads not a multiple of {Hkv} kv heads")
     G = H // Hkv
+    live = slots != R - 1
+    met = dense_positions_met(lengths, live, R, L, rows)
+    block = read_block(L) if rows else L
+    if block < L:
+        # a bound per lane: the kernel walks each lane's own live blocks
+        # of its row, the lanes as they come (nothing is laid out by row)
+        Hp = -(-H // 16) * 16          # whole bfloat16 tiles of heads
+        h = np.arange(Hp)[None, :, None]
+        own = ((np.arange(Hkv * Dh)[None, None, :] // Dh == h // G)
+               & (h % G == np.arange(G)[:, None, None]) & (h < H))
+        token_row = lambda a: a.reshape(S, 1, -1).astype(jnp.float32)
+        held = jnp.minimum(lengths, L)           # a row holds no more
+        out = pallas_decode.attend_live_blocks(
+            jnp.swapaxes(q.reshape(S, Hkv, G, Dh), 1, 2).reshape(S, G, -1),
+            token_row(kn), token_row(vn), own.astype(np.float32), kl, vl,
+            layer, slots, live_blocks(held, live, block), held, block=block)
+        out = jnp.swapaxes(out.reshape(S, G, Hkv, Dh), 1, 2)
+        return out.reshape(S, H, Dh), met
     by_row = lambda a: jnp.zeros((R,) + a.shape[1:], a.dtype).at[slots].set(a)
     q, kn, vn, at = by_row(q), by_row(kn), by_row(vn), by_row(lengths)
     valid = jnp.arange(L)[None, :] < at[:, None]                  # [R, L]
@@ -992,11 +1087,10 @@ def _attend_dense(q: jax.Array, kl: jax.Array, vl: jax.Array,
         p = jnp.exp(s - m[..., None])
         return m, weigh(p, vt), jnp.sum(p, -1)
     bounds = read_bounds(L)
-    if len(bounds) == 1:
-        bound = L
+    if rows or len(bounds) == 1:
         m, out, total = passes(L)
     else:
-        bound = live_bound(lengths, slots != R - 1, L)
+        bound = met // R        # pages kept by head: rows x the bound
         m, out, total = lax.switch((bound - 1) // bounds[0],
                                    [lambda b=b: passes(b) for b in bounds])
     if rows:        # a head keeps the lanes that are its own
@@ -1004,7 +1098,7 @@ def _attend_dense(q: jax.Array, kl: jax.Array, vl: jax.Array,
     pn = jnp.exp(sn - m)
     out = (out + pn[..., None] * vn.astype(jnp.float32)) \
         / (total + pn)[..., None]
-    return out.reshape(R, H, Dh)[slots], R * bound
+    return out.reshape(R, H, Dh)[slots], met
 
 
 @jax.named_scope("cache.read")
@@ -1021,8 +1115,10 @@ def attend_layer(q: jax.Array, kl: jax.Array, vl: jax.Array,
     arithmetic as the staged form (the scale folded into the queries,
     pages in their own dtype, exact products, float32 softmax and float32
     probabilities into the value product) in another order of summation,
-    over the positions the longest live lane reaches and no further
-    (:func:`_attend_dense`).  The queries are float32, but over token rows
+    over the blocks that hold each lane's own positions where the pages
+    are token rows of more than one block (a kernel, from the shapes
+    alone), else over the positions the longest live lane reaches and no
+    further (:func:`_attend_dense`).  The queries are float32, but over token rows
     they keep their own dtype where the scale is a power of two (0.125 for
     a ``head_dim`` of 64): the product with it is exact there, and a
     matrix unit then takes queries and pages in one dtype.  A bucket under

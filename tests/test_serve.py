@@ -295,7 +295,6 @@ def test_attend_layer_reads_in_place(case, against):
 
 _BOUNDED_READS = {
     # order: (kv heads, head_dim): three steps of 128 in a row of 384
-    "token_rows": (2, 64),
     "head_dim_minor": (2, 128),
     "positions_minor": (1, 64),
 }
@@ -305,17 +304,19 @@ _BOUNDED_READS = {
 @pytest.mark.parametrize("order", sorted(_BOUNDED_READS))
 def test_attend_layer_stops_at_the_longest_live_lane(order, longest,
                                                      monkeypatch):
-    """The dense in-place read takes positions ``[0, bound)`` of every
-    row, ``bound`` the least of ``read_bounds(max_len)`` that covers the
-    longest LIVE lane: in all three page orders, with that lane one under,
-    on and one over a step's edge (and at the next edge, and at the row's
-    end), beside a lane on the trash row that carries a stale length of
-    the whole row, in a cache of more rows than lanes.  Equal to the read
-    of the whole rows (the parent's program: one bound) to float32
+    """The dense in-place read of pages kept BY HEAD takes positions ``[0,
+    bound)`` of every row, ``bound`` the least of ``read_bounds(max_len)``
+    that covers the longest LIVE lane: in both by-head page orders, with
+    that lane one under, on and one over a step's edge (and at the next
+    edge, and at the row's end), beside a lane on the trash row that
+    carries a stale length of the whole row, in a cache of more rows than
+    lanes.  Equal to the read of the whole rows (one bound) to float32
     round-off, with every position past the bound NaN in the pages the
     bounded read is handed: none of them is read.  ``live_bound`` chooses
     the same bound from numpy arrays (the host's count) and traced ones
-    (the program's), and the positions met are rows x bound."""
+    (the program's), and the positions met are rows x bound.  (Token rows
+    stop at each LANE's own last block:
+    ``test_attend_layer_stops_at_each_lanes_own_last_block``.)"""
     import jax
     import jax.numpy as jnp
     from bluefog_tpu.serve import kv_cache as kv
@@ -324,9 +325,7 @@ def test_attend_layer_stops_at_the_longest_live_lane(order, longest,
     assert kv.read_bounds(L) == (128, 256, 384)
     rng = np.random.default_rng(longest)
     normal = lambda *shape: jnp.asarray(rng.normal(size=shape), jnp.float32)
-    shape = (layers, rows, L, Hkv * Dh) if order == "token_rows" \
-        else (layers, rows, Hkv, L, Dh)
-    kl, vl = normal(*shape), normal(*shape)
+    kl, vl = (normal(layers, rows, Hkv, L, Dh) for _ in range(2))
     slots = jnp.array([3, 5, 0, 1], jnp.int32)          # 5: the trash row
     lens = jnp.array([longest, L - 1, 0, 40], jnp.int32)
     live = np.asarray(slots) != rows - 1
@@ -339,18 +338,95 @@ def test_attend_layer_stops_at_the_longest_live_lane(order, longest,
     q = normal(4, Hkv * 2, Dh)
     new = token_pages(normal(4, Hkv, Dh), normal(4, Hkv, Dh), "raw",
                       jnp.float32)
-    past = (jnp.arange(L) >= bound).reshape((L, 1) if order == "token_rows"
-                                            else (1, L, 1))
+    past = (jnp.arange(L) >= bound).reshape(1, L, 1)
     got, met = jax.jit(kv.attend_layer)(
         q, jnp.where(past, jnp.nan, kl), jnp.where(past, jnp.nan, vl), 1,
         slots, lens, new)
     assert int(met) == rows * bound
+    assert int(met) == kv.dense_positions_met(
+        np.asarray(lens), live, rows, L, False)
     monkeypatch.setattr(kv, "read_bounds", lambda max_len: (max_len,))
     want, whole = kv.attend_layer(q, kl, vl, 1, slots, lens, new)
     assert whole == rows * L
     assert np.isfinite(np.asarray(got)[live]).all()
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                rtol=2e-6, atol=2e-7)
+
+
+_PER_LANE_READS = {
+    # case: (kv heads, head_dim, q heads a kv head, queries' dtype, pages')
+    "float32": (2, 64, 1, "float32", "float32"),
+    "float32_grouped": (2, 64, 3, "float32", "float32"),
+    "float32_on_bfloat16_pages": (2, 64, 1, "float32", "bfloat16"),
+    # the cell's: queries and pages in one dtype, the scale a power of two
+    "bfloat16": (2, 64, 1, "bfloat16", "bfloat16"),
+    "bfloat16_grouped": (4, 32, 2, "bfloat16", "bfloat16"),
+    # heads that fill no whole tile of 16: the spare ones are zero
+    "bfloat16_twenty_heads": (4, 32, 5, "bfloat16", "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PER_LANE_READS))
+def test_attend_layer_stops_at_each_lanes_own_last_block(case, monkeypatch):
+    """The dense in-place read of TOKEN ROWS that hold more than one block
+    takes, of each lane's row, the blocks of 128 positions that hold its
+    own cached positions and nothing else (the kernel
+    ``pallas_decode.attend_live_blocks``, here in interpreter mode): lanes
+    at 0, 1, 127, 128, 129, 640 and 1,023 positions in ONE batch beside a
+    dead lane on the trash row that carries a stale length of the whole
+    row, in a cache of two more rows than lanes; NaN in the pages past
+    EACH lane's own last block and in every position of the rows that hold
+    no lane and of the trash row: none of it is read.  Equal to the read of
+    the whole rows (a row of one block: XLA's, no kernel) to float32
+    round-off, or to the bit once both are rounded to bfloat16; the
+    positions met are the sum of the lanes' blocks, the same from numpy
+    arrays (the host's count), from traced ones and from the program."""
+    import jax
+    import jax.numpy as jnp
+    from bluefog_tpu.serve import kv_cache as kv
+    Hkv, Dh, G, qdt, pdt = _PER_LANE_READS[case]
+    L, layers = 1024, 2
+    assert kv.page_order(Hkv, Dh, L) == "token_rows"
+    assert kv.read_block(L) == 128
+    lens = np.array([0, 1, 127, 128, 129, 640, 1023, L - 1], np.int32)
+    slots = np.array([3, 8, 0, 6, 1, 7, 4, 9], np.int32)    # 9: the trash row
+    S, rows = len(slots), len(slots) + 2                # rows 2, 5: no lane
+    live = slots != rows - 1
+    blocks = -(-lens // 128) * live
+    assert blocks.tolist() == [0, 1, 1, 1, 2, 5, 8, 0]
+    np.testing.assert_array_equal(kv.live_blocks(lens, live, 128), blocks)
+    np.testing.assert_array_equal(jax.jit(
+        lambda n, a: kv.live_blocks(n, a, 128))(lens, live), blocks)
+    rng = np.random.default_rng(sorted(_PER_LANE_READS).index(case))
+    normal = lambda dt, *shape: jnp.asarray(
+        rng.normal(size=shape), jnp.float32).astype(dt)
+    kl, vl = (normal(pdt, layers, rows, L, Hkv * Dh) for _ in range(2))
+    own = np.zeros(rows, np.int32)
+    own[slots[live]] = 128 * blocks[live]
+    past = jnp.asarray(np.arange(L)[None, :] >= own[:, None])[None, :, :,
+                                                              None]
+    assert int(past.sum()) == rows * L - 128 * blocks.sum()
+    q = normal(qdt, S, Hkv * G, Dh)
+    new = token_pages(normal(pdt, S, Hkv, Dh), normal(pdt, S, Hkv, Dh),
+                      "raw", jnp.dtype(pdt))
+    got, met = jax.jit(kv.attend_layer)(
+        q, jnp.where(past, jnp.nan, kl), jnp.where(past, jnp.nan, vl), 1,
+        slots, lens, new)
+    assert int(met) == 128 * blocks.sum() == kv.dense_positions_met(
+        lens, live, rows, L, True)
+    monkeypatch.setattr(kv, "read_block", lambda max_len: max_len)
+    want, whole = kv.attend_layer(q, kl, vl, 1, slots, lens, new)
+    assert whole == rows * L == kv.dense_positions_met(
+        lens, live, rows, L, True)
+    assert got.dtype == want.dtype == jnp.dtype(qdt)
+    got, want = (np.asarray(a.astype(jnp.float32))[live] for a in (got, want))
+    assert np.isfinite(got).all()
+    if qdt == "bfloat16":
+        # float32 results a round-off apart may round to neighbours
+        assert (got != want).mean() < 0.01
+        np.testing.assert_allclose(got, want, rtol=2 ** -7, atol=1e-6)
+    else:
+        np.testing.assert_allclose(got, want, rtol=2e-6, atol=2e-7)
 
 
 _PAGE_ORDERS = {

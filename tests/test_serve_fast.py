@@ -51,7 +51,7 @@ from bluefog_tpu.serve.engine import _parse_buckets
 from bluefog_tpu.serve.kv_cache import (KVCacheConfig, PrefixCache,
                                         SlotAllocator, attend_rows,
                                         dequantize_rows, quantize_rows,
-                                        read_bounds, store_dtype)
+                                        store_dtype)
 from bluefog_tpu.utils import flight as bfflight
 from bluefog_tpu.utils import metrics as bfm
 
@@ -762,7 +762,12 @@ def _v5e_program(m, program, store, fast):
         "verify": (eng._chunk_body, i32(S, 5 + 6)),
         "chunk512": (eng._chunk_body, i32(1, 512 + 6)),
     }[program]
-    return eng._build(body).lower(params, cache, keys, staged).compile(), cc
+    with pytest.MonkeyPatch.context() as mp:
+        # the kernels' wrappers ask the default backend whether to lower
+        # for the chip or for the interpreter: the chip described, here
+        mp.setattr(jax, "default_backend", lambda: "tpu")
+        return eng._build(body).lower(params, cache, keys,
+                                      staged).compile(), cc
 
 
 _SLOW = pytest.mark.slow
@@ -936,11 +941,11 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
     (``kv_cache.page_order``): no instruction, inside a fusion or out,
     makes the lanes' staged rows ``[32, 1024, 1024]`` or the logical view
     of them ``[32, 16, 1024, 64]``, none outside a fusion a layer's pages
-    ``[33, 1024, 1024]``, and the loop's body hands the stacked tensor
-    itself to ONE conditional whose eight branches (a bound each,
-    ``kv_cache.read_bounds``) hand it to the two fusions that attend over
-    it as far as the bound (the scores over K, the weighted sum over V:
-    one pass each).  Each of the 64 windows
+    ``[33, 1024, 1024]``, and the loop's body holds NO conditional and ONE
+    kernel call (``pallas_decode.attend_live_blocks``, through Mosaic),
+    under the ``cache.read`` scope, whose operands the two stacked tensors
+    are, whole and as the loop carries them: a lane's blocks are fetched
+    from where they lie by the kernel's own copies.  one pass each).  Each of the 64 windows
     written is a token's row of every layer, ``[24, 1, 1, 1024]`` with
     the 1,024 minor as in the cache: 8 tiles a layer, where the same
     token in a cache with the positions minor touched 64, the property
@@ -996,30 +1001,18 @@ def test_decode_writes_after_layer_loop_on_v5e(v5e_decode, store, check):
             assert _results_of_shape(txt.splitlines(), staged) == []
         assert materialized(txt, 1, (cc.rows,) + row) == []
         assert materialized(txt, 1, (cc.layers, cc.rows) + row) == []
-        # the loop's body hands both stacked tensors to its one
-        # conditional, and each of its branches, a bound each, hands them
-        # to the two fusions that attend over them: the scores over K
-        # come out [rows, heads, bound]
-        def stacked_in(lines):
-            return [ln.split(" = ")[0].strip() for ln in
-                    _results_of_shape(lines, (cc.layers, cc.rows) + row)]
+        # the loop's body chooses nothing and hands both stacked tensors,
+        # as it carries them, to its one kernel call
         body = _loop_body(txt)
-        conds = [ln for ln in body if " conditional(" in ln]
-        assert len(stacked_in(body)) == 2 and len(conds) == 1
-        branches = re.search(r"branch_computations=\{([^}]*)\}",
-                             conds[0]).group(1).replace("%", "").split(", ")
-        bounds = read_bounds(cc.max_len)
-        assert bounds == tuple(range(128, 1025, 128))
-        assert len(branches) == len(bounds)
-        for name, bound in zip(branches, bounds):
-            lines = _computation(txt, name)
-            stacked = stacked_in(lines)
-            readers = [ln for ln in lines if " fusion(" in ln and any(
-                f"{t}," in ln or f"{t})" in ln for t in stacked)]
-            assert len(stacked) == 2 and len(readers) == 2, (
-                name, stacked, readers)
-            assert any(f"f32[{cc.rows},16,{bound}]" in ln
-                       for ln in readers), (name, bound)
+        stacked = [ln.split(" = ")[0].strip() for ln in _results_of_shape(
+            body, (cc.layers, cc.rows) + row)]
+        calls = [ln for ln in body if "tpu_custom_call" in ln]
+        assert [ln for ln in body if " conditional(" in ln] == []
+        assert len(stacked) == 2 and len(calls) == 1
+        assert all(f"{t})" in calls[0] or f"{t}," in calls[0]
+                   for t in stacked), calls[0][:400]
+        assert "cache.read/pallas_call" in calls[0]
+        assert txt.count("tpu_custom_call") == 1
     else:
         # the lanes' rows as they are staged: token rows, their lanes
         # split into heads on the way to the logical view
@@ -1383,72 +1376,86 @@ def test_token_row_engine_serves_the_by_head_engines_tokens(
 
 
 _BOUNDED_ENGINES = {
-    # name: (heads, d_model, fused steps a call): heads of 64 side by side
-    # are token rows, four heads of 8 are kept by head, positions minor
-    "token_rows": (2, 128, 1),
-    "token_rows_two_tokens_a_call": (2, 128, 2),
-    "by_head": (4, 32, 1),
+    # name: (heads, d_model, fused steps a call, (dp, pp, tp), more of the
+    # ServeConfig): heads of 64 side by side are token rows (two of them a
+    # tp rank where tp is 2), four heads of 8 are kept by head, positions
+    # minor
+    "token_rows": (2, 128, 1, (1, 1, 1), {}),
+    "token_rows_two_tokens_a_call": (2, 128, 2, (1, 1, 1), {}),
+    "token_rows_tp2": (4, 256, 1, (1, 1, 2), {}),
+    "token_rows_draft": (2, 128, 1, (1, 2, 1),
+                         dict(spec_decode=2, spec_stages=1)),
+    "by_head": (4, 32, 1, (1, 1, 1), {}),
 }
 
 
 @pytest.mark.parametrize("name", sorted(_BOUNDED_ENGINES))
-def test_dense_decode_read_stops_at_the_longest_live_lane(
+def test_dense_decode_read_stops_where_the_live_lanes_end(
         cpu_devices, monkeypatch, name):
-    """A dense engine whose rows hold two steps of 128 positions decodes
-    across the step's edge (a prompt of 120 tokens beside short ones, 13
+    """A dense engine whose rows hold two blocks of 128 positions decodes
+    across the block's edge (a prompt of 120 tokens beside short ones, 13
     new tokens each; with two tokens a call the edge falls between a
     call's two fused steps) and serves the greedy tokens of the engine
-    that reads every row whole (the parent's program: one bound).  The
-    bound is chosen inside the ONE decode program of the bucket: its
-    lowered text holds one conditional in its one layer loop, so the
-    slice of a layer's K and of its V pages at each of
-    ``read_bounds(max_len)`` stands in it once, a branch a bound (``case``
-    ops cannot be counted: every pinned layout is one, over the
-    platforms); ``program_memory`` names the step; and the positions counter advances, call by call, by layers
-    x rows x the bound the host reckons from the lengths it staged (each
-    fused step its own), which is under layers x rows x ``max_len`` a
-    step while the lanes are short, and is what the ``decode_call`` spans
-    carry as ``positions_read`` beside ``positions_reserved``."""
+    that reads every row whole (a row of one block: one bound, no kernel).
+    TOKEN ROWS stop at each lane's own last block, inside the kernel
+    (interpreter mode here) of the ONE decode program of the bucket, at tp
+    1 and 2 and as the speculative draft; pages kept BY HEAD at the
+    batch's longest live lane, chosen by the one conditional of the
+    program's one layer loop (the slice of a layer's K and of its V pages
+    at each of ``read_bounds(max_len)`` stands in its lowered text once).
+    ``program_memory`` names the step, and the positions counter advances,
+    call by call, by what the host reckons from the lengths it staged,
+    each fused step its own: layers x the live lanes' whole blocks, or
+    layers x rows x the bound; under layers x rows x ``max_len`` a step,
+    and what the ``decode_call`` spans carry as ``positions_read`` beside
+    ``positions_reserved``."""
     import re
     from bluefog_tpu.serve import kv_cache as kv
-    heads, d_model, steps = _BOUNDED_ENGINES[name]
+    heads, d_model, steps, (dp, pp, tp), extra = _BOUNDED_ENGINES[name]
+    by_head, draft = name == "by_head", "spec_decode" in extra
     cfg = compose.LMConfig(vocab=32, d_model=d_model, heads=heads, layers=2,
                            seq_len=32)
-    m = compose.compose_parallelism(1, 1, 1, 1, devices=cpu_devices[:1])
+    m = compose.compose_parallelism(dp, pp, tp, 1,
+                                    devices=cpu_devices[:dp * pp * tp])
     params = compose.init_lm_params(cfg, m, seed=3)
     scfg = ServeConfig(batch_buckets=(2,), prefill_buckets=(8, 128), slots=2,
-                       max_len=256, decode_steps_per_call=steps)
+                       max_len=256, decode_steps_per_call=steps, **extra)
     rng = np.random.default_rng(29)
     prompts = [rng.integers(0, 32, int(n)).tolist() for n in (120, 5, 7)]
-    bounds = kv.read_bounds(scfg.max_len)
-    assert bounds == (128, 256)
+    assert kv.read_bounds(scfg.max_len) == (128, 256)
+    assert kv.read_block(scfg.max_len) == 128
     read = bfm.counter("bluefog_serve_cache_positions_read_total")
     toks = {}
     for form in ("bounded", "whole"):
         if form == "whole":
             monkeypatch.setattr(kv, "read_bounds", lambda max_len: (max_len,))
+            monkeypatch.setattr(kv, "read_block", lambda max_len: max_len)
         eng = ServeEngine(m, cfg, params, scfg)
         eng.warmup()
         if form == "bounded":
-            text, cc = eng.decode_lowered_text(), eng.cache_cfg
-            lanes = cc.shapes()["k"][-1]
-            for b in bounds:
-                pages = f"1x{cc.rows}x{b}x{lanes}" if name != "by_head" \
-                    else f"1x{cc.rows}x{cc.kv_heads}x{b}x{lanes}"
-                assert len(re.findall(
-                    r"dynamic_slice[^\n]*-> tensor<%sx" % pages, text)) == 2
-            mem = eng.program_memory()["decode S=2"]
-            assert (mem["read"], mem["read_step"]) == ("in_place", 128)
+            cc = eng.cache_cfg
+            assert (cc.page_order == "token_rows") == (not by_head)
+            if by_head:
+                text, lanes = eng.decode_lowered_text(), cc.shapes()["k"][-1]
+                for b in (128, 256):
+                    pages = f"1x{cc.rows}x{cc.kv_heads}x{b}x{lanes}"
+                    assert len(re.findall(
+                        r"dynamic_slice[^\n]*-> tensor<%sx" % pages,
+                        text)) == 2
+            for program in ["decode S=2"] + ["draft S=2"] * draft:
+                mem = eng.program_memory()[program]
+                assert (mem["read"], mem["read_step"]) == ("in_place", 128)
             want, spans = [], []
             decode, stage = eng.decode, eng._stage
 
             def reckoned(tokens, slots, lens, *a, **k):
-                # by hand, replica 0 (the only one): a bound a fused step
+                # by hand, replica 0 (the only one), a fused step at a time
                 live = np.asarray(slots)[0] != cc.trash_slot
-                longest = [int((np.asarray(lens)[0][live] + i).max(initial=0))
-                           for i in range(steps)]
-                want.append(cfg.layers * cc.rows * sum(
-                    min(max(-(-n // 128), 1) * 128, 256) for n in longest))
+                at = [np.asarray(lens)[0][live] + i for i in range(steps)]
+                want.append(cfg.layers * sum(
+                    cc.rows * min(max(-(-int(n.max(initial=0)) // 128), 1)
+                                  * 128, 256) if by_head
+                    else int((-(-n // 128)).sum()) * 128 for n in at))
                 return decode(tokens, slots, lens, *a, **k)
 
             def staged(stage_name, **attrs):
@@ -1464,17 +1471,24 @@ def test_dense_decode_read_stops_at_the_longest_live_lane(
     assert bfm.counter("bluefog_retrace_after_warmup_total").total() == 0
     assert toks["bounded"] == toks["whole"]
     assert all(len(t) == 13 for t in toks["bounded"])
+    if draft:       # its rounds are draft and verify calls: no decode call
+        assert want == []
+        return
     # a call's count is published when ITS tokens are collected (a call
     # later while the scheduler runs one ahead): the sums agree
-    first, whole = (cfg.layers * cc.rows * steps * b for b in bounds)
+    whole = cfg.layers * cc.rows * steps * 256
     assert counted == sum(want) < whole * len(want)
     assert [a["positions_read"] for a in spans] == want
     assert {a["positions_reserved"] for a in spans} == {whole}
-    # the long lane reads one step until its 129th position is cached,
-    # then both; the short one that follows it one again; and with two
+    # the long lane reads one block until its 129th position is cached,
+    # then two; the short one that follows it one again; and with two
     # tokens a call one call straddles the edge
-    assert want[0] == want[-1] == first and whole in want
-    assert (steps == 1) == (set(want) == {first, whole})
+    lanes = cc.rows if by_head else 2
+    one, both = (cfg.layers * steps * 128 * n
+                 for n in ((lanes, 2 * lanes) if by_head else (2, 3)))
+    assert want[0] == one and both in want
+    assert want[-1] == (one if by_head else one // 2)   # the last lane alone
+    assert (steps == 1) == (set(want) <= {one // 2, one, both})
 
 
 @pytest.mark.parametrize("kind,form", [
